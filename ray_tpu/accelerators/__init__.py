@@ -37,14 +37,13 @@ def detect_node_accelerators() -> tuple:
     """(resources, labels) this host contributes, across all families.
 
     Called by the node daemon on startup; explicit user resources win.
+    A detection error propagates: a node that silently registers zero
+    chips runs everything on the CPU and makes ``{"TPU": 1}`` unplaceable.
     """
     resources: Dict[str, float] = {}
     labels: Dict[str, str] = {}
     for mgr in _MANAGERS.values():
-        try:
-            n = mgr.get_current_node_num_accelerators()
-        except Exception:
-            n = 0
+        n = mgr.get_current_node_num_accelerators()
         if n <= 0:
             continue
         resources[mgr.get_resource_name()] = float(n)

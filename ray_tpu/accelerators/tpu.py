@@ -15,7 +15,8 @@ from __future__ import annotations
 import glob
 import logging
 import os
-from typing import Callable, Dict, List, Optional
+import sys
+from typing import Any, Callable, Dict, List, Optional
 
 from ray_tpu.accelerators.base import AcceleratorManager
 
@@ -67,16 +68,107 @@ _PEAK_BF16_TFLOPS = {
 }
 
 
-def peak_bf16_tflops(device_kind: str) -> Optional[float]:
+def peak_bf16_tflops(device_kind: str) -> float:
     """Per-chip dense-bf16 peak for a jax ``device_kind`` string (e.g.
-    ``"TPU v5 lite"``); None when unknown."""
+    ``"TPU v5 lite"``). An unknown kind is an error, not a default: a
+    utilization over a guessed peak is not a measurement."""
     kind = device_kind.lower()
-    best = None
-    best_len = 0
-    for key, peak in _PEAK_BF16_TFLOPS.items():
-        if key in kind and len(key) > best_len:
-            best, best_len = peak, len(key)
-    return best
+    matches = [key for key in _PEAK_BF16_TFLOPS if key in kind]
+    if not matches:
+        raise ValueError(
+            f"no bf16 peak on record for device_kind {device_kind!r}; "
+            f"known: {sorted(_PEAK_BF16_TFLOPS)}"
+        )
+    return _PEAK_BF16_TFLOPS[max(matches, key=len)]
+
+
+# ---------------------------------------------------------------------------
+# What a process computes on. A chip belongs to one process at a time, so
+# launchers (drivers, daemons) stay off JAX and the process that was
+# granted chips says what it landed on.
+
+
+def _chip_device_files() -> List[str]:
+    """This host's chip device nodes: ``/dev/accel*`` (TPU VM images) or
+    ``/dev/vfio/<n>`` (the group nodes; ``/dev/vfio/vfio`` is the
+    container device, not a chip)."""
+    accel = glob.glob("/dev/accel*")
+    if accel:
+        return sorted(accel)
+    try:
+        return sorted(f"/dev/vfio/{f}" for f in os.listdir("/dev/vfio") if f != "vfio")
+    except OSError:
+        return []
+
+
+def jax_backend_initialized() -> bool:
+    """True once THIS process has initialized a JAX backend (and so holds
+    whatever chips it could see at that moment). Importing jax alone does
+    not count. Never imports jax itself."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def process_device_report() -> Dict[str, Any]:
+    """Platform, ``device_kind``, devices and device memory high-water
+    mark of THIS process as JAX reports them, plus the chip device files
+    it holds open. Initializes the JAX backend.
+
+    Under ``isolation_env`` every one-chip process sees its chip as
+    device 0, so ``device_ids`` cannot tell two replicas apart —
+    ``chip_files`` (the ``/dev/vfio/<n>`` or ``/dev/accel<n>`` node
+    libtpu opened) can.
+
+    Raises when the daemon granted this process chips
+    (``TPU_VISIBLE_CHIPS``) and JAX landed anywhere else — on another
+    platform (unless the operator's ``JAX_PLATFORMS`` excludes the TPU:
+    an explicit CPU run says ``cpu`` in the report instead), or on a
+    different number of chips than it was granted."""
+    import jax
+
+    devices = jax.local_devices()
+    chip_files = set(_chip_device_files())
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue  # closed between listdir and readlink
+        if target in chip_files:
+            held.add(target)
+    memory = [d.memory_stats() or {} for d in devices]  # {} on the CPU
+    report = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_ids": [d.id for d in devices],
+        "visible_chips": os.environ.get(TPU_VISIBLE_CHIPS_ENV),
+        "chip_files": sorted(held),
+        "pid": os.getpid(),
+        # the fullest device's high-water mark, against one device's limit
+        "peak_bytes_in_use": max(m.get("peak_bytes_in_use", 0) for m in memory),
+        "bytes_limit": memory[0].get("bytes_limit"),
+    }
+    granted = TPUAcceleratorManager.get_current_process_visible_accelerator_ids()
+    if granted:
+        platforms = os.environ.get("JAX_PLATFORMS", "").lower()
+        tpu_excluded = bool(platforms) and "tpu" not in platforms.split(",")
+        on_tpu = report["platform"] == "tpu"
+        if not on_tpu and not tpu_excluded:
+            raise RuntimeError(
+                f"this process was granted TPU chips {granted} but JAX "
+                f"initialized on {report['platform']!r} "
+                f"(JAX_PLATFORMS={platforms!r}): refusing to compute on "
+                "another device than the one scheduled"
+            )
+        if on_tpu and len(devices) != len(granted):
+            raise RuntimeError(
+                f"this process was granted TPU chips {granted} but JAX sees "
+                f"{len(devices)} local device(s): chip isolation did not hold"
+            )
+    return report
 
 
 # ---------------------------------------------------------------------------
@@ -177,16 +269,9 @@ class TPUAcceleratorManager(AcceleratorManager):
         override = os.environ.get(NUM_CHIPS_OVERRIDE_ENV)
         if override:
             return int(override)
-        accel = glob.glob("/dev/accel*")
-        if accel:
-            return len(accel)
-        try:
-            vfio = os.listdir("/dev/vfio")
-            chips = [f for f in vfio if f != "vfio"]
-            if chips:
-                return len(chips)
-        except OSError:
-            pass
+        chips = _chip_device_files()
+        if chips:
+            return len(chips)
         pod_type = TPUAcceleratorManager.get_current_node_tpu_pod_type()
         if pod_type:
             return min(

@@ -21,6 +21,7 @@ import asyncio
 import logging
 import os
 import pickle
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -2261,14 +2262,22 @@ class CoreWorker(RuntimeBackend):
 
     async def w_set_accelerator_env(self, payload, conn):
         """Daemon-assigned device isolation for pooled workers (dedicated
-        actor workers get it via spawn env). Effective as long as the
-        accelerator runtime hasn't initialized in this process yet."""
+        actor workers get it via spawn env). Only effective while this
+        process has not imported jax: jax reads ``JAX_PLATFORMS`` (the
+        spawn-time CPU pin) at import, so a worker that already has would
+        accept the chips and keep computing on the CPU. It REFUSES — the
+        daemon retires it and leases a fresh worker."""
         from ray_tpu.accelerators import get_accelerator_manager
 
         mgr = get_accelerator_manager(payload["resource"])
         if mgr is not None:
             ids = payload.get("ids")
             if ids:
+                if "jax" in sys.modules:
+                    raise RuntimeError(
+                        "jax is already imported in this pooled worker "
+                        f"(pinned to the CPU): it cannot take chips {ids}"
+                    )
                 # undo ONLY the daemon's chip-less CPU pin from spawn time
                 # (jax has not initialized yet — the daemon grants the
                 # lease only after this reply): restore the pre-pin value

@@ -146,9 +146,6 @@ class ShmChannel:
         num_slots: int = 8,
         num_readers: int = 1,
     ):
-        from ray_tpu.core.object_store import ensure_scrubbed_tracker
-
-        ensure_scrubbed_tracker()
         self.name = name
         if create:
             total = _HDR.size + 8 * num_readers + num_slots * (_SLOT_HDR.size + slot_size)

@@ -625,6 +625,14 @@ class InferenceEngine:
                 did_work = self.step()
             except Exception as e:  # noqa: BLE001 — fail in-flight, keep serving
                 self._fail_all(e)
+                if self.runner.cache["k"].is_deleted():
+                    # the step died AFTER its jit call consumed the donated
+                    # cache: every later step would fail on a deleted
+                    # buffer. Stop the loop — healthy() turns False and the
+                    # serve controller replaces the replica.
+                    logger.exception("engine step lost the donated KV cache; stopping")
+                    self._stop.set()
+                    return
             self._reap_abandoned_streams()
             if not did_work:
                 self._work.wait(timeout=0.005)

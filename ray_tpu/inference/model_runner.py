@@ -5,13 +5,14 @@ input shape, so the runner rounds every prefill chunk up to a length
 bucket and every decode batch up to a size bucket. After warmup the
 engine must see ZERO recompiles — the jit cache holds exactly one entry
 per bucket, asserted via ``recompiles_after_warmup()`` (backed by
-``PjitFunction._cache_size`` when jax exposes it, a shape-signature
-count otherwise).
+``PjitFunction._cache_size``).
 
-The device cache lives here as functional state: every step returns a
-new cache value and the runner swaps its reference — donation hands the
-buffer back on TPU (``donate_argnums``); on CPU/GPU test backends jax
-copies, which the toy config absorbs.
+The device cache lives here as functional state: every step donates the
+cache buffer (``donate_argnums``) and returns the new value, and the
+runner swaps its reference. Donation is unconditional — the CPU backend
+honours it too, so the tests run the same use-after-donate rules as the
+chip: a reference to ``runner.cache`` taken before a step is dead after
+it.
 """
 
 from __future__ import annotations
@@ -81,30 +82,28 @@ class PagedModelRunner:
             )
         self.cache = init_paged_kv_cache(cfg, num_blocks, block_size, cache_dtype)
 
-        # donation returns the cache buffer in place on TPU; CPU would
-        # warn-and-copy, so only donate where it's real
-        donate = (2,) if jax.default_backend() == "tpu" else ()
+        # argument 1 of the partials (cfg is bound) is the cache: donated,
+        # updated in place — at a real width a copied cache does not fit
         self._prefill_jit = jax.jit(
-            partial(paged_prefill_step, cfg), donate_argnums=donate
+            partial(paged_prefill_step, cfg), donate_argnums=(1,)
         )
         self._decode_jit = jax.jit(
-            partial(paged_decode_step, cfg), donate_argnums=donate
+            partial(paged_decode_step, cfg), donate_argnums=(1,)
         )
         # speculative verification: prefill-shaped, all-position logits.
         # Always constructed (an uncalled jit holds zero cache entries so
         # compile accounting is unchanged), only warmed when the engine
         # passes verify buckets.
         self._verify_jit = jax.jit(
-            partial(paged_verify_step, cfg), donate_argnums=donate
+            partial(paged_verify_step, cfg), donate_argnums=(1,)
         )
         # COW block duplication (prefix cache): cache is arg 0 here.
         # partial() gives THIS runner its own jit identity — a bare
         # module-level function would share one compiled-program cache
         # across every runner in the process, and another runner's cache
         # shape would show up in this one's recompile accounting
-        cow_donate = (0,) if jax.default_backend() == "tpu" else ()
         self._copy_jit = jax.jit(
-            partial(copy_paged_blocks), donate_argnums=cow_donate
+            partial(copy_paged_blocks), donate_argnums=(0,)
         )
         # KV-cache migration programs (disaggregated serving): the gather
         # reads blocks out (export — never donated, the cache stays
@@ -115,27 +114,23 @@ class PagedModelRunner:
         # in recompiles_after_warmup.
         self._gather_jit = jax.jit(partial(gather_paged_blocks))
         self._scatter_jit = jax.jit(
-            partial(scatter_paged_blocks), donate_argnums=cow_donate
+            partial(scatter_paged_blocks), donate_argnums=(0,)
         )
-        self._seen_shapes: set = set()
         self._warmup_compiles: Optional[int] = None
 
     # -- compile accounting ----------------------------------------------
     def _jit_cache_entries(self) -> int:
-        total = 0
-        for fn in (
-            self._prefill_jit,
-            self._decode_jit,
-            self._verify_jit,
-            self._copy_jit,
-            self._gather_jit,
-            self._scatter_jit,
-        ):
-            size = getattr(fn, "_cache_size", None)
-            if size is None:
-                return len(self._seen_shapes)
-            total += size()
-        return total
+        return sum(
+            fn._cache_size()
+            for fn in (
+                self._prefill_jit,
+                self._decode_jit,
+                self._verify_jit,
+                self._copy_jit,
+                self._gather_jit,
+                self._scatter_jit,
+            )
+        )
 
     def mark_warm(self) -> None:
         """Call after warmup: compiles past this point are regressions."""
@@ -163,7 +158,6 @@ class PagedModelRunner:
             self.cache, _ = self._prefill_jit(
                 self.params, self.cache, tokens, row, np.int32(0), np.int32(0)
             )
-            self._seen_shapes.add(("p", c))
         for b in buckets_decode if buckets_decode is not None else self.decode_buckets:
             self.cache, _ = self._decode_jit(
                 self.params,
@@ -173,7 +167,6 @@ class PagedModelRunner:
                 np.zeros((b, M), np.int32),
                 np.ones(b, np.int32),
             )
-            self._seen_shapes.add(("d", b))
         # speculative-verify windows (only when the engine opted in via
         # verify_buckets — plain engines keep their exact compile count).
         # The batch axis rides the decode buckets: every (B-bucket,
@@ -188,18 +181,14 @@ class PagedModelRunner:
                     np.zeros(b, np.int32),
                     np.zeros(b, np.int32),
                 )
-                self._seen_shapes.add(("v", b, c))
         # the COW copy program (all-null pairs write the null block's
         # trash back onto itself)
         pad = np.zeros(_COW_WIDTH, np.int32)
         self.cache = self._copy_jit(self.cache, pad, pad)
-        self._seen_shapes.add(("c", _COW_WIDTH))
         if kv_io:
             ids = np.zeros(_KV_IO_WIDTH, np.int32)
             kv = np.asarray(self._gather_jit(self.cache, ids))
             self.cache = self._scatter_jit(self.cache, ids, kv)
-            self._seen_shapes.add(("g", _KV_IO_WIDTH))
-            self._seen_shapes.add(("s", _KV_IO_WIDTH))
         self.mark_warm()
 
     # -- steps ------------------------------------------------------------
@@ -214,7 +203,6 @@ class PagedModelRunner:
             dst = np.zeros(_COW_WIDTH, np.int32)
             for j, (s, d) in enumerate(chunk):
                 src[j], dst[j] = s, d
-            self._seen_shapes.add(("c", _COW_WIDTH))
             self.cache = self._copy_jit(self.cache, src, dst)
 
     def gather_blocks(self, block_ids: Sequence[int]) -> np.ndarray:
@@ -228,7 +216,6 @@ class PagedModelRunner:
             chunk = block_ids[i : i + _KV_IO_WIDTH]
             ids = np.zeros(_KV_IO_WIDTH, np.int32)
             ids[: len(chunk)] = chunk
-            self._seen_shapes.add(("g", _KV_IO_WIDTH))
             out = self._gather_jit(self.cache, ids)
             outs.append(np.asarray(out)[:, :, : len(chunk)])
         if not outs:
@@ -252,7 +239,6 @@ class PagedModelRunner:
                 kv.shape[:2] + (_KV_IO_WIDTH,) + kv.shape[3:], kv.dtype
             )
             buf[:, :, : len(chunk)] = kv[:, :, i : i + len(chunk)]
-            self._seen_shapes.add(("s", _KV_IO_WIDTH))
             self.cache = self._scatter_jit(self.cache, ids, buf)
 
     def prefill_chunk(
@@ -268,7 +254,6 @@ class PagedModelRunner:
         padded = np.zeros(bucket, np.int32)
         padded[:true_len] = tokens
         row = np.asarray(block_row, np.int32)
-        self._seen_shapes.add(("p", bucket))
         self.cache, logits = self._prefill_jit(
             self.params, self.cache, padded, row,
             np.int32(ctx_len), np.int32(true_len),
@@ -300,7 +285,6 @@ class PagedModelRunner:
             tables[i] = block_rows[i]
             ctx[i] = ctx_lens[i]
             tl[i] = len(w)
-        self._seen_shapes.add(("v", bbucket, cbucket))
         self.cache, logits = self._verify_jit(
             self.params, self.cache, tokens, tables, ctx, tl
         )
@@ -327,6 +311,5 @@ class PagedModelRunner:
         p[:n] = positions
         bt[:n] = np.asarray(block_rows, np.int32)
         cl[:n] = ctx_lens
-        self._seen_shapes.add(("d", bucket))
         self.cache, logits = self._decode_jit(self.params, self.cache, t, p, bt, cl)
         return np.asarray(logits)[:n]

@@ -37,6 +37,7 @@ streams resumable.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
 
 from ray_tpu.core.streaming import TokenChunk
@@ -68,15 +69,24 @@ class LLMServer:
     ):
         import jax
 
+        from ray_tpu.accelerators.tpu import process_device_report
         from ray_tpu.core.config import GLOBAL_CONFIG
         from ray_tpu.inference.engine import EngineConfig, InferenceEngine
         from ray_tpu.models.llama import LlamaConfig, init_params
 
+        # raises here, before any weight exists, when the replica was
+        # granted chips and JAX landed elsewhere
+        process_device_report()
         if model_cfg is None:
             model_cfg = LlamaConfig.tiny()
         self.model_cfg = model_cfg
         if params is None:
-            params = init_params(model_cfg, jax.random.PRNGKey(seed))
+            # one compiled program: the draw, scale and cast of each weight
+            # fuse (no float32 copy of a bf16 model), and a restarted or
+            # sibling replica finds the program in the compile cache
+            params = jax.jit(partial(init_params, model_cfg))(
+                jax.random.PRNGKey(seed)
+            )
         self.engine = InferenceEngine(
             model_cfg, params, engine_cfg or EngineConfig()
         ).start()
@@ -486,7 +496,10 @@ class LLMServer:
         return self.engine.slo_snapshot()
 
     def engine_stats(self) -> Dict[str, Any]:
-        return self.engine.stats()
+        from ray_tpu.accelerators.tpu import process_device_report
+
+        # what this replica computes on, and its device memory high-water
+        return {**self.engine.stats(), "device": process_device_report()}
 
     def routing_stats(self) -> Dict[str, Any]:
         """Load + prefix-digest gossip consumed by the serve router's

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import flash_attention, flash_attention_sharded
 from ray_tpu.parallel.sharding import constrain
 
 
@@ -261,6 +261,13 @@ def apply_rope(x, cos, sin):
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def _axis_size(mesh, axes) -> int:
+    """Devices along a mesh axis name (or a tuple of them)."""
+    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
+    names = axes if isinstance(axes, tuple) else (axes,)
+    return math.prod(shape[a] for a in names)
+
+
 def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
     B, S, _ = x.shape
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
@@ -293,8 +300,7 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
         # seq-parallel impls rotate/exchange the small GQA heads and
         # repeat locally, keeping collective volume at 1/rep.
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        tensor_size = dict(zip(mesh.axis_names, mesh.devices.shape)).get(TENSOR, 1)
-        if rep > 1 and cfg.n_kv_heads % tensor_size != 0:
+        if rep > 1 and cfg.n_kv_heads % _axis_size(mesh, TENSOR) != 0:
             # Too few KV heads for the tensor axis: pre-repeat (rare).
             kt = jnp.repeat(kt, rep, axis=1)
             vt = jnp.repeat(vt, rep, axis=1)
@@ -307,7 +313,25 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
         # GQA K/V stay at n_kv_heads — the flash kernel maps q-head →
         # kv-head in its index map, so the repeat never touches HBM.
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        o = flash_attention(qt, kt, vt, causal=True, impl=cfg.attention_impl)
+        if mesh is not None and rules is not None:
+            # sharded step: each device attends over its own (batch,
+            # heads) block — the kernel goes through shard_map because
+            # GSPMD cannot partition it. The sequence stays whole here
+            # whatever ``act_seq`` says (that axis is ring/ulysses').
+            kv_axis = rules["act_kv_heads"]
+            if rep > 1 and kv_axis is not None and cfg.n_kv_heads % _axis_size(mesh, kv_axis):
+                # too few KV heads for the head axis: pre-repeat (rare)
+                kt = jnp.repeat(kt, rep, axis=1)
+                vt = jnp.repeat(vt, rep, axis=1)
+                kv_axis = rules["act_heads"]
+            o = flash_attention_sharded(
+                qt, kt, vt, mesh,
+                q_spec=rules.spec(("act_batch", "act_heads")),
+                kv_spec=jax.sharding.PartitionSpec(rules["act_batch"], kv_axis),
+                causal=True, impl=cfg.attention_impl,
+            )
+        else:
+            o = flash_attention(qt, kt, vt, causal=True, impl=cfg.attention_impl)
     o = o.transpose(0, 2, 1, 3)  # [B, S, H, hd]
     # attention EXIT pin + name: the flash output is the expensive tensor
     # the selective-remat policy saves (recompute elementwise, never the
@@ -460,10 +484,6 @@ def init_sharded(cfg: LlamaConfig, mesh, rules, rng, optimizer=None):
     max-abs 0.6 on the tiny config). Partitionable threefry is
     sharding-invariant, so init values match the unsharded path exactly
     whatever the mesh."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from ray_tpu.parallel.sharding import match_partition_rules
-
     shardings = param_shardings(cfg, mesh, rules)
     with jax.threefry_partitionable(True):
         params = jax.jit(partial(init_params, cfg), out_shardings=shardings)(rng)
@@ -479,15 +499,25 @@ def init_sharded(cfg: LlamaConfig, mesh, rules, rng, optimizer=None):
     # across meshes (the multichip dryrun inits on two), and a bare
     # ``optimizer.init`` would share one C++ jit cache across them — the
     # PR 6 ``copy_paged_blocks`` cache-pollution class.
+    oshard = _opt_state_shardings(cfg, mesh, rules, optimizer, params)
+    opt_state = jax.jit(partial(optimizer.init), out_shardings=oshard)(params)
+    return params, opt_state
+
+
+def _opt_state_shardings(cfg: LlamaConfig, mesh, rules, optimizer, params):
+    """NamedShardings for ``optimizer``'s state over ``params`` (arrays
+    or abstract), from the same matched rule table as the params."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ray_tpu.parallel.sharding import match_partition_rules
+
     abstract = jax.eval_shape(optimizer.init, params)
     ospecs = match_partition_rules(partition_rules(cfg, rules), abstract)
-    oshard = jax.tree_util.tree_map(
+    return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s),
         ospecs,
         is_leaf=lambda x: isinstance(x, PartitionSpec),
     )
-    opt_state = jax.jit(partial(optimizer.init), out_shardings=oshard)(params)
-    return params, opt_state
 
 
 # ---------------------------------------------------------------------------
@@ -813,4 +843,22 @@ def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = 
         params = constrain_tree(params, mesh, prules)
         return (params, opt_state), loss
 
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    out_shardings = None
+    if prules is not None and mesh is not None:
+        # The state leaves the step under exactly the specs it came in
+        # with (``init_sharded``'s). Left to XLA, a mesh axis of size 1
+        # (one chip) comes back normalized to an equivalent but
+        # different-looking spec, and the second call compiles again.
+        abstract = jax.eval_shape(
+            partial(init_params, cfg), jax.ShapeDtypeStruct((2,), jnp.uint32)
+        )
+        out_shardings = (
+            (
+                param_shardings(cfg, mesh, rules),
+                _opt_state_shardings(cfg, mesh, rules, optimizer, abstract),
+            ),
+            None,
+        )
+    return jax.jit(
+        step, donate_argnums=(0,) if donate else (), out_shardings=out_shardings
+    )

@@ -37,14 +37,6 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def _tpu_compiler_params(pltpu):
-    """jax renamed ``TPUCompilerParams`` -> ``CompilerParams`` (~0.5):
-    resolve whichever this jax ships so the kernels run on both."""
-    cp = getattr(pltpu, "CompilerParams", None)
-    return cp if cp is not None else pltpu.TPUCompilerParams
-
-
-
 def default_blocks(seq_q: int) -> tuple:
     """FORWARD blocks, tuned on v5e (round-5 sweep): (512, 1024) wins at
     s=2048 (67 vs 57 TFLOP/s) AND s=8192 (61 vs 56). The backward has its
@@ -254,7 +246,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: in
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
@@ -400,7 +392,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, h, hk, res, g):
         out_specs=pl.BlockSpec((1, block_q, d), q_idx),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
@@ -449,7 +441,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, h, hk, res, g):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
@@ -544,3 +536,26 @@ def flash_attention(
         block_q_bwd, block_k_bwd, h, hk,
     )
     return o.reshape(b, h, seq_q, d)
+
+
+def flash_attention_sharded(
+    q, k, v, mesh, *, q_spec, kv_spec, causal: bool = True, impl: str = "auto"
+):
+    """:func:`flash_attention` on arrays sharded over ``mesh``: every
+    device runs the kernel on its own (batch, heads) block.
+
+    GSPMD cannot partition a Mosaic kernel — jax refuses to lower one
+    under ``jit`` on more than one device ("wrap the call in a
+    shard_map") — so the sharded train step calls the kernel through
+    here. ``q_spec``/``kv_spec`` are the PartitionSpecs of the
+    ``[batch, heads, seq, head_dim]`` operands; sequence and head_dim
+    must be unsharded in them (dense attention is local to a sequence),
+    and a head axis must divide both the q and the kv heads."""
+    fn = jax.shard_map(
+        functools.partial(flash_attention, causal=causal, impl=impl),
+        mesh=mesh,
+        in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec,
+        check_vma=False,
+    )
+    return fn(q, k, v)

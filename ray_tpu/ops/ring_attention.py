@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.mesh import shard_map_compat, DATA, FSDP, SEQUENCE, TENSOR
+from ray_tpu.parallel.mesh import DATA, FSDP, SEQUENCE, TENSOR
 
 _NEG_INF = -1e30
 
@@ -174,7 +174,7 @@ def ring_attention_sharded(
     seq axis.
     """
     spec = P(batch_axes, head_axis, seq_axis, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention,
             axis_name=seq_axis,
@@ -253,7 +253,7 @@ def ulysses_attention_sharded(
     impl: str = "auto",
 ):
     spec = P(batch_axes, head_axis, seq_axis, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(
             ulysses_attention,
             axis_name=seq_axis,

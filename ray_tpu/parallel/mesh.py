@@ -151,28 +151,3 @@ def slice_topology_mesh(num_slices: int, per_slice_spec: MeshSpec, devices=None)
         stage=spec.stage,
     )
     return make_mesh(merged, devices)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map``: newer jax exposes ``jax.shard_map``
-    with ``check_vma``; 0.4.x ships ``jax.experimental.shard_map`` where
-    the same flag is named ``check_rep`` — and an intermediate window has
-    the public name with the OLD flag, so the kwarg is chosen by what the
-    resolved function accepts, not by which module exports it. Every
-    shard_map in this repo goes through here so a jax upgrade/downgrade
-    is a one-file event."""
-    import inspect
-
-    import jax as _jax
-
-    sm = getattr(_jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if check_vma is None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    flag = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{flag: check_vma})

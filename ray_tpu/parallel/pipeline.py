@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.mesh import shard_map_compat, STAGE
+from ray_tpu.parallel.mesh import STAGE
 
 
 def stack_stage_params(per_stage_params: list):
@@ -82,7 +82,7 @@ def pipeline_apply(
         mask = (s == num_stages - 1).astype(outputs.dtype)
         return jax.lax.psum(outputs * mask, stage_axis)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

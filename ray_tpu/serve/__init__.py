@@ -218,9 +218,45 @@ def run(app: Application, *, name: Optional[str] = None, _blocking_ready: bool =
             # the prefill pool must be routable too, or the first
             # requests burn their whole handoff budget waiting on a
             # replica that is still warming up
-            DeploymentHandle(pdep.name, controller)._router.choose_replica()
-        handle._router.choose_replica()  # wait for ≥1 replica
+            _wait_routable(controller, DeploymentHandle(pdep.name, controller))
+        _wait_routable(controller, handle)
     return handle
+
+
+#: start failures (the controller's ``restarts["start_failed"]``) seen
+#: during one ``serve.run`` wait before it gives up: one transient death
+#: is replaced and waited for; a replica that keeps dying is a crash loop
+_START_FAILURES_FATAL = 3
+
+
+def _wait_routable(controller, handle: DeploymentHandle) -> None:
+    """Block until the deployment has a routable replica.
+
+    There is no clock bound. A replica still constructing — parameter
+    init, then a warm-up compile of every bucket, minutes at a real width
+    on a cold chip — is alive and making progress, and the controller
+    already treats slow starters as normal (``_reconcile_once``). The
+    wait ends in an error only on what the controller knows to be fatal:
+    starters keep dying before they are ready (constructor raised,
+    process exited, nothing can host them), or the deployment is gone."""
+    name = handle._name
+
+    def _state():
+        st = ray_tpu.get(controller.status.remote(), timeout=60).get(name)
+        if st is None:
+            raise RuntimeError(f"deployment {name!r} was deleted while starting")
+        return st
+
+    failed0 = _state()["restarts"]["start_failed"]
+    while not handle._router.wait_for_replicas(timeout=1.0):
+        st = _state()
+        if st["target"] == 0:
+            return  # scaled to zero: no replica is coming, none is owed
+        if st["restarts"]["start_failed"] - failed0 >= _START_FAILURES_FATAL:
+            raise RuntimeError(
+                f"replicas of deployment {name!r} keep dying before they "
+                f"are ready: {st['last_start_error'] or 'no reason recorded'}"
+            )
 
 
 def get_deployment_handle(name: str) -> DeploymentHandle:

@@ -97,9 +97,10 @@ def _count_autoscale_decision(deployment: str, reason: str) -> None:
 
 
 def _count_replica_restart(state: "_DeploymentState", reason: str) -> None:
-    """A ready replica was killed for replacement: observed death or an
-    unhealthy self-report. Counted on the controller's /metrics registry
-    AND on the deployment state (surfaced via status())."""
+    """A replica was killed for replacement: observed death, an
+    unhealthy self-report, or a starter that died before it was ready.
+    Counted on the controller's /metrics registry AND on the deployment
+    state (surfaced via status())."""
     state.restarts[reason] = state.restarts.get(reason, 0) + 1
     try:
         from ray_tpu.observability.rpc_metrics import SERVE_REPLICA_RESTARTS
@@ -144,10 +145,17 @@ class _DeploymentState:
         #: last replica.health() poll sweep (proactive wedged-replica
         #: restart rides its own cadence, not every reconcile pass)
         self.last_health_ts = 0.0
-        #: ready replicas killed for replacement, by reason — mirrored
-        #: into status() so tests/operators see it without scraping the
-        #: controller process's /metrics
-        self.restarts: Dict[str, int] = {"death": 0, "unhealthy": 0}
+        #: replicas killed for replacement, by reason: ready ones that
+        #: died or reported unhealthy, and starters that died before
+        #: becoming ready — mirrored into status() so tests/operators see
+        #: it without scraping the controller process's /metrics
+        self.restarts: Dict[str, int] = {
+            "death": 0, "unhealthy": 0, "start_failed": 0,
+        }
+        #: why the last dead starter died (the runtime's actor-death
+        #: reason) — what ``serve.run`` raises with when replicas keep
+        #: dying before they become routable
+        self.last_start_error = ""
         #: last APPLIED autoscale decision ({"ts", "from", "to",
         #: "reason"}) — surfaced via status() so the load harness can
         #: measure autoscaler lag (burst start -> first target change)
@@ -231,6 +239,7 @@ class _ServeController:
                 state.starting = old.starting
                 state.draining = old.draining
                 state.restarts = old.restarts
+                state.last_start_error = old.last_start_error
             self._deployments[name] = state
         self._reconcile_once()
         return True
@@ -585,6 +594,7 @@ class _ServeController:
                     ),
                     "autoscaling": st.config.autoscaling is not None,
                     "restarts": dict(st.restarts),
+                    "last_start_error": st.last_start_error,
                     "last_scale": dict(st.last_scale_info),
                     **self._pressure_of(st),
                 }
@@ -703,10 +713,11 @@ class _ServeController:
                         # actors at its lease timeout, typically before
                         # our PENDING-age gate can observe them)
                         info = self._core_actor_info(r)
-                        if info and str(info.get("reason", "")).startswith(
-                            "no node can host"
-                        ):
+                        why = str((info or {}).get("reason", ""))
+                        if why.startswith("no node can host"):
                             st.unplaceable_ts = time.monotonic()
+                        _count_replica_restart(st, "start_failed")
+                        st.last_start_error = why
                         try:
                             ray_tpu.kill(r)
                         except Exception:

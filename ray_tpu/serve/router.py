@@ -308,9 +308,13 @@ class Router:
                 self._have_replicas.clear()
 
     # -- choice ----------------------------------------------------------
-    def choose_replica(self, model_id: str = "", request_args=None, wait_s: float = 30.0):
+    def wait_for_replicas(self, timeout: float) -> bool:
+        """True once at least one replica is routable."""
         self._ensure_poller()
-        if not self._have_replicas.wait(timeout=wait_s):
+        return self._have_replicas.wait(timeout=timeout)
+
+    def choose_replica(self, model_id: str = "", request_args=None, wait_s: float = 30.0):
+        if not self.wait_for_replicas(wait_s):
             raise RuntimeError(f"no replicas for deployment {self._deployment!r}")
         with self._replicas_lock:
             replicas = list(self._replicas)

@@ -31,13 +31,16 @@ class ScalingConfig:
 
     def worker_resources(self) -> Dict[str, float]:
         if self.resources_per_worker is not None:
-            res = dict(self.resources_per_worker)
+            # a worker is a process: it holds a CPU unless told otherwise
+            # (the worker group asks for one, so a bundle without it could
+            # never host its worker)
+            res = {"CPU": 1.0, **self.resources_per_worker}
             # An explicit TPU count wins; otherwise topology decides.
             if self.topology and "TPU" not in res:
                 res["TPU"] = self._chips_per_host()
         elif self.use_tpu or self.topology:
-            # Topology is authoritative: a v5e/v6e host has 8 chips, not
-            # the bare use_tpu default of 4.
+            # Topology is authoritative; a bare use_tpu asks the cluster
+            # what a host holds (see _chips_per_host).
             res = {"CPU": 1.0, "TPU": self._chips_per_host()}
         else:
             res = {"CPU": 1.0}
@@ -59,7 +62,21 @@ class ScalingConfig:
                     pod_type_num_chips(self.topology),
                 )
             )
-        return 4.0
+        # Bare use_tpu: every chip of a host, as the running cluster's
+        # nodes detected them. A fixed guess is unplaceable wherever it is
+        # wrong (4 on a one-chip node), so there is none.
+        import ray_tpu
+
+        counts = {
+            int(n["Resources"].get("TPU", 0)) for n in ray_tpu.nodes() if n["Alive"]
+        } - {0}
+        if not counts:
+            raise ValueError(
+                "ScalingConfig(use_tpu=True) asks for every chip of a host, "
+                "but no live node of this cluster reports TPU chips; state "
+                "resources_per_worker={'TPU': n} or a topology"
+            )
+        return float(min(counts))
 
     def resolved_num_workers(self) -> int:
         if self.topology:

@@ -89,6 +89,16 @@ class TrainWorker:
             try:
                 if setup_fn is not None:
                     setup_fn(context)
+                from ray_tpu.accelerators.tpu import (
+                    TPUAcceleratorManager,
+                    process_device_report,
+                )
+
+                if TPUAcceleratorManager.get_current_process_visible_accelerator_ids():
+                    # granted chips: fail before the loop starts if JAX
+                    # landed anywhere else (chip-less workers skip this
+                    # and never import jax on the loop's behalf)
+                    process_device_report()
                 if config is not None:
                     train_fn(config)
                 else:

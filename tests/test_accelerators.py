@@ -130,3 +130,37 @@ def test_daemon_chip_pool_allocation(tmp_path):
     assert daemon._allocate_tpu_chips(1) is None  # exhausted
     daemon._free_tpu_chips(a)
     assert daemon._allocate_tpu_chips(2) == [0, 1]
+
+
+def test_detection_errors_propagate(monkeypatch):
+    """A node that cannot tell how many chips it has must not register
+    zero and carry on: the error reaches whoever starts the daemon."""
+    monkeypatch.setenv(NUM_CHIPS_OVERRIDE_ENV, "four")
+    with pytest.raises(ValueError):
+        detect_node_accelerators()
+
+
+def test_peak_table_rejects_unknown_device_kind():
+    from ray_tpu.accelerators.tpu import peak_bf16_tflops
+
+    assert peak_bf16_tflops("TPU v5 lite") == 197.0
+    assert peak_bf16_tflops("TPU v5p") == 459.0  # longest key wins
+    with pytest.raises(ValueError, match="no bf16 peak"):
+        peak_bf16_tflops("cpu")
+
+
+def test_granted_process_must_land_on_its_chips(monkeypatch):
+    """A process the daemon granted chips raises when JAX landed
+    elsewhere — unless the operator's JAX_PLATFORMS excludes the TPU, in
+    which case the report simply says ``cpu``."""
+    from ray_tpu.accelerators.tpu import process_device_report
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert process_device_report()["visible_chips"] is None  # nothing granted
+    monkeypatch.setenv(TPU_VISIBLE_CHIPS_ENV, "0")
+    report = process_device_report()
+    assert report["platform"] == "cpu" and report["visible_chips"] == "0"
+    for platforms in ("", "tpu,cpu"):  # the TPU was wanted, the CPU answered
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        with pytest.raises(RuntimeError, match="granted TPU chips"):
+            process_device_report()

@@ -362,6 +362,36 @@ def test_engine_expired_deadline_fails_request(cfg, params):
         eng.stop()
 
 
+def test_engine_stops_when_a_step_loses_the_donated_cache(cfg, params):
+    """Every step donates the KV cache (on the CPU backend too). A step
+    that raises after its jit call consumed the buffer leaves nothing to
+    serve from: the request fails, the loop stops and healthy() turns
+    False (the serve controller's cue to replace the replica) — the
+    engine must not keep stepping on a deleted buffer."""
+    ec = EngineConfig(
+        num_blocks=32, block_size=8, prefill_buckets=(16,), decode_buckets=(1,),
+        max_decode_batch=1, warmup=False,
+    )
+    eng = InferenceEngine(cfg, params, ec)
+    real = eng.runner._prefill_jit
+
+    def consume_then_fail(p, cache, *rest):
+        real(p, cache, *rest)  # donates ``cache``; its new value is dropped
+        raise RuntimeError("device step failed after donation")
+
+    eng.runner._prefill_jit = consume_then_fail
+    eng.start()
+    try:
+        rid = eng.submit([1, 2, 3], max_new_tokens=2)
+        with pytest.raises(RuntimeError, match="after donation"):
+            list(eng.tokens(rid, timeout=30))
+        eng._thread.join(timeout=10)
+        assert not eng._thread.is_alive()
+        assert not eng.healthy()
+    finally:
+        eng.stop()
+
+
 def test_engine_rejects_batch_beyond_buckets(cfg, params):
     """A decode batch cap the compiled bucket set can't cover must fail
     at init, not as a repeated runtime fail-all inside step()."""

@@ -48,6 +48,46 @@ def test_function_deployment(cluster):
     serve.delete("double")
 
 
+def test_run_raises_when_replicas_keep_dying_at_start(cluster):
+    """serve.run has no clock bound (a replica compiling for minutes is
+    healthy); what ends the wait is the controller seeing starters die.
+    A constructor that raises must surface, with its reason, in seconds."""
+
+    @serve.deployment(ray_actor_options={"num_cpus": 0.25})
+    class Broken:
+        def __init__(self):
+            raise ValueError("constructor says no")
+
+        def __call__(self, x):
+            return x
+
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="constructor says no"):
+        serve.run(Broken.bind())
+    assert time.monotonic() - t0 < 25
+    assert serve.status()["Broken"]["restarts"]["start_failed"] >= 3
+    serve.delete("Broken")
+
+
+@pytest.mark.slow
+def test_run_waits_for_a_slow_starter(cluster):
+    """A replica whose construction outlasts the old fixed 30 s wait is
+    still awaited: it is alive, and nothing died."""
+
+    @serve.deployment(ray_actor_options={"num_cpus": 0.25})
+    class Slow:
+        def __init__(self):
+            time.sleep(33)
+
+        def __call__(self, x):
+            return x + 1
+
+    handle = serve.run(Slow.bind())
+    assert ray_tpu.get(handle.remote(1), timeout=60) == 2
+    assert serve.status()["Slow"]["restarts"]["start_failed"] == 0
+    serve.delete("Slow")
+
+
 def test_requests_spread_across_replicas(cluster):
     @serve.deployment(num_replicas=2, ray_actor_options={"num_cpus": 0.25})
     class WhoAmI:

@@ -199,11 +199,10 @@ def test_constrained_step_matches_unconstrained_reference():
         con_losses.append(float(loss))
 
     np.testing.assert_allclose(ref_losses, con_losses, rtol=2e-4)
-    size = getattr(con_step, "_cache_size", None)
-    if size is not None:
-        assert size() == 1, (
-            f"constrained step recompiled after warmup: {size()} cache entries"
-        )
+    assert con_step._cache_size() == 1, (
+        f"constrained step recompiled after warmup: "
+        f"{con_step._cache_size()} cache entries"
+    )
 
 
 def test_selective_remat_matches_no_remat():

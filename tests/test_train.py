@@ -216,3 +216,29 @@ def test_scaling_config_topology_bundles():
     assert all(b["TPU"] == 4.0 for b in bundles)
     assert bundles[0]["TPU-v4-32-head"] == 1.0
     assert sc.pg_strategy() == "STRICT_SPREAD"
+
+
+def test_scaling_config_bare_use_tpu_asks_the_cluster(monkeypatch):
+    """A bare use_tpu takes a host's chip count from what the cluster's
+    nodes detected (1 on a one-chip node, not a fixed 4), and says so
+    when no node reports a chip instead of asking for something
+    unplaceable."""
+    import ray_tpu
+
+    monkeypatch.setattr(
+        ray_tpu, "nodes",
+        lambda: [
+            {"Alive": True, "Resources": {"CPU": 8.0, "TPU": 1.0}},
+            {"Alive": False, "Resources": {"CPU": 8.0, "TPU": 4.0}},
+        ],
+    )
+    assert ScalingConfig(use_tpu=True).worker_resources() == {"CPU": 1.0, "TPU": 1.0}
+    # an explicit chip count keeps the worker's CPU (the bundle must fit
+    # what the worker group asks for)
+    explicit = ScalingConfig(resources_per_worker={"TPU": 4})
+    assert explicit.worker_resources() == {"CPU": 1.0, "TPU": 4}
+    monkeypatch.setattr(
+        ray_tpu, "nodes", lambda: [{"Alive": True, "Resources": {"CPU": 8.0}}]
+    )
+    with pytest.raises(ValueError, match="no live node"):
+        ScalingConfig(use_tpu=True).worker_resources()
