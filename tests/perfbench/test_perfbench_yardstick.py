@@ -1,0 +1,246 @@
+"""The benchmark's yardstick without a chip: the schedule, the estimators,
+the peaks and FLOP counts, the layer-metric readers, and BENCHMARK.json
+against the files it names. Seconds, no cluster, no JAX."""
+
+import json
+import math
+import os
+import re
+import statistics
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from perfbench.harness import cells, layer_metrics as lm, peaks, schedule as sch, stats  # noqa: E402
+
+BENCH = cells.benchmark()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+
+# -- the schedule ---------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def paced():
+    return cells.traffic_of("chat-paced")
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 11, 3_000_000_019])
+def test_schedule_is_a_pure_function_of_file_and_seed(paced, seed):
+    a = sch.paced_schedule(paced, seed, 51.0)
+    b = sch.paced_schedule(paced, seed, 51.0)
+    assert a == b
+    assert [sch.prompt_tokens(r, 32768) for r in a[:3]] == [sch.prompt_tokens(r, 32768) for r in b[:3]]
+
+
+def test_every_seed_offers_the_same_multiset_in_another_order(paced):
+    runs = [sch.paced_schedule(paced, s, 51.0) for s in (1, 2, 2**31 + 5)]
+    multisets = [sorted((r.prompt_len, r.output_len) for r in run if r.index >= 0) for run in runs]
+    assert multisets[0] == multisets[1] == multisets[2]
+    orders = [[(r.prompt_len, r.output_len) for r in run if r.index >= 0] for run in runs]
+    assert orders[0] != orders[1] and orders[1] != orders[2]
+    lead = [sorted((r.prompt_len, r.output_len) for r in run if r.index < 0) for run in runs]
+    assert lead[0] == lead[1] == lead[2] and len(lead[0]) == paced["lead_in_requests"]
+
+
+def test_one_arrival_in_every_slot(paced):
+    slot = 1.0 / paced["rate_per_s"]
+    run = sch.paced_schedule(paced, 5, 51.0)
+    measured = [r for r in run if r.index >= 0]
+    assert len(measured) == math.floor(51.0 * paced["rate_per_s"])
+    for r in run:
+        assert r.index * slot <= r.due_s < (r.index + 1) * slot
+    assert all(0.0 <= r.due_s < 51.0 for r in measured)
+
+
+def test_lengths_follow_the_file(paced):
+    pairs = sch.length_multiset(paced["lengths"], 400)
+    prompts = sorted(p for p, _ in pairs)
+    outputs = sorted(o for _, o in pairs)
+    assert prompts[0] >= 32 and prompts[-1] == 1024 and outputs[0] >= 16 and outputs[-1] == 256
+    assert abs(statistics.median(prompts) - 256) <= 4 and abs(statistics.median(outputs) - 128) <= 2
+    # the pairing does not depend on the run's seed, only on the file
+    assert pairs == sch.length_multiset(paced["lengths"], 400)
+
+
+@pytest.mark.parametrize("mix", ["chat-offline", "longprompt-batch"])
+def test_closed_stream_is_rounds_of_one_fixed_multiset(mix):
+    t = cells.traffic_of(mix)
+    n = t["multiset_size"]
+    a, b = sch.closed_stream(t, 3), sch.closed_stream(t, 2**31 + 9)
+    assert len(a) == n * t["rounds"] == len(b)
+    multiset = sorted(sch.length_multiset(t["lengths"], n))
+    for run in (a, b):
+        for r in range(t["rounds"]):  # every round offers the same work, in another order
+            chunk = run[r * n : (r + 1) * n]
+            assert sorted((q.prompt_len, q.output_len) for q in chunk) == multiset
+    assert [r.prompt_len for r in a] != [r.prompt_len for r in b]
+    assert [r.prompt_len for r in a[:n]] != [r.prompt_len for r in a[n : 2 * n]]
+    assert all(r.prompt_len + r.output_len <= 4096 for r in a)
+    assert len({r.token_seed for r in a}) == len(a)  # distinct prompts: no shared prefix
+
+
+# -- estimators ---------------------------------------------------------------------
+
+def test_harrell_davis_against_a_hand_worked_case():
+    # n = 3, q = 0.5: Beta(2, 2), I_x(2, 2) = 3x^2 - 2x^3 -> weights 7/27, 13/27, 7/27
+    assert stats.harrell_davis([1, 2, 6], 0.5) == pytest.approx((7 * 1 + 13 * 2 + 7 * 6) / 27)
+    # n = 2, q = 0.5: Beta(1.5, 1.5) is symmetric -> the mean
+    assert stats.harrell_davis([10, 20], 0.5) == pytest.approx(15.0)
+    assert stats._betainc(2, 3, 0.4) == pytest.approx(0.5248)  # x^2 (6 - 8x + 3x^2)
+
+
+def test_harrell_davis_is_a_p90_that_does_not_jump():
+    base = [100.0] * 80 + [250.0] * 20
+    moved = [100.0] * 81 + [250.0] * 19
+    assert stats.percentile(base, 0.9) == 250.0
+    hd = stats.harrell_davis(base, 0.9)
+    assert 100.0 < hd <= 250.0
+    assert abs(stats.harrell_davis(moved, 0.9) - hd) < 0.1 * 150.0  # a fraction of the step
+
+
+def test_quartile_spread_uses_the_statistics_module_quartiles():
+    values = [10.0, 11.0, 12.0, 13.0, 14.0, 15.0]
+    q1, _, q3 = statistics.quantiles(values, n=4)
+    assert stats.quartile_spread(values) == pytest.approx((q3 - q1) / 12.5)
+
+
+# -- peaks and operation counts ----------------------------------------------------
+
+def test_peaks_table_and_unknown_kind():
+    v5e = peaks.peaks_for("TPU v5 lite")
+    assert (v5e["bf16_flops_per_s"], v5e["hbm_bytes_per_s"], v5e["hbm_bytes"]) == (197e12, 819e9, 16e9)
+    assert v5e["source"]
+    with pytest.raises(KeyError, match="not in the benchmark's table"):
+        peaks.peaks_for("cpu")
+
+
+def test_parameter_and_flop_counts_match_the_issue():
+    mistral = cells.config_of(BENCH, "mistral-7b-v0.3-16l")
+    codestral = cells.config_of(BENCH, "codestral-22b-v0.1-8l-fsdp4")
+    assert peaks.layer_params(mistral) == pytest.approx(218e6, rel=0.005)
+    assert peaks.param_count(mistral) == pytest.approx(3.76e9, rel=0.005)
+    assert peaks.kv_bytes_per_token(mistral) == 64 * 1024
+    assert peaks.layer_params(codestral) == pytest.approx(390e6, rel=0.005)
+    assert peaks.param_count(codestral) == pytest.approx(3.52e9, rel=0.005)
+    assert peaks.matmul_params(codestral) == pytest.approx(3.32e9, rel=0.005)
+    # 6 x 3.32 B + attention = about 21 GFLOP a token at 2048
+    assert peaks.train_flops_per_token(codestral, 2048) == pytest.approx(20.5e9, rel=0.01)
+    # serving: 2 per matmul parameter (3.62 B without the embedding table) + attention over the context
+    assert peaks.forward_flops_per_token(mistral, 0) == pytest.approx(2 * 3.624e9, rel=0.005)
+    assert peaks.forward_flops_per_token(mistral, 1024) - peaks.forward_flops_per_token(mistral, 0) == 16 * 4 * 1024 * 4096
+
+
+def test_param_count_agrees_with_the_program():
+    from perfbench.harness.program import llama_config
+    from ray_tpu.models.llama import param_count
+
+    for name in ("mistral-7b-v0.3-16l", "codestral-22b-v0.1-8l-fsdp4"):
+        model = cells.config_of(BENCH, name)
+        assert param_count(llama_config(model, max_seq_len=2048)) == peaks.param_count(model)
+
+
+# -- layer-metric readers --------------------------------------------------------------
+
+def _observed():
+    return lm.Observed(
+        stats_start={"total_steps": 100, "scheduler": {"total_preempted": 1},
+                     "prefix_cache": {"hits_total": 0, "queries_total": 10}},
+        stats_end={"total_steps": 300, "scheduler": {"total_preempted": 4},
+                   "prefix_cache": {"hits_total": 5, "queries_total": 30},
+                   "device": {"peak_bytes_in_use": 14.5e9}},
+        stats_samples=[{"blocks": {"used_blocks": u, "num_blocks": 100}} for u in (10, 40, 25)],
+        series={"late_ms": [0.1 * i for i in range(101)], "client_ttft_ms": [50, 60, 70],
+                "replica_ttft_ms": [40, 45, 50], "step_ms": [700, 702, 698]},
+        scalars={"output_tokens": 4000.0, "train_tokens_per_s_steady": 24000.0, "flops_per_token": 20.5e9,
+                 "peak_flops_per_s": 197e12, "chips": 4.0},
+    )
+
+
+@pytest.mark.parametrize("name, want", [
+    ("tokens_per_engine_step.paced", 20.0),
+    ("preemptions.batch", 3.0),
+    ("prefix_hit_rate", 25.0),
+    ("kv_pool_peak_share.batch", 40.0),
+    ("peak_hbm_gb", 14.5),
+    ("loadgen_late_p99_ms", 9.9),
+    ("host_path_ttft_p50_ms", 15.0),
+    ("train_step_ms", 700.0),
+    ("train_mfu", 100.0 * 20.5e9 * 24000.0 / (4 * 197e12)),
+])
+def test_readers_on_worked_observations(name, want):
+    assert lm.read(cells.layer_metric_spec(name), _observed()) == pytest.approx(want)
+
+
+def test_a_reader_that_finds_nothing_returns_nothing():
+    empty = lm.Observed()
+    for m in BENCH["per_layer"]:
+        assert lm.read(cells.layer_metric_spec(m["name"]), empty) is None
+    assert lm.read_all({"x": cells.layer_metric_spec("train_mfu")}, empty) == {}
+    with pytest.raises(ValueError, match="unknown layer-metric kind"):
+        lm.read({"kind": "guess"}, empty)
+
+
+# -- BENCHMARK.json against the contract and the files it names ---------------------------
+
+def test_benchmark_json_shape():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
+    assert 1 <= BENCH["run_seconds"] <= 51 and isinstance(BENCH["run_seconds"], int)
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    names = [m["name"] for g in ("end_to_end", "per_layer") for m in BENCH[g]]
+    names += [w["name"] for w in BENCH["workloads"]] + [c["name"] for c in BENCH["configs"]]
+    assert all(NAME.match(n) for n in names), [n for n in names if not NAME.match(n)]
+    metric_names = [m["name"] for g in ("end_to_end", "per_layer") for m in BENCH[g]]
+    assert len(set(metric_names)) == len(metric_names)
+    for g in ("end_to_end", "per_layer"):
+        for m in BENCH[g]:
+            assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher"), m
+            assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
+    for m in BENCH["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
+        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= 1
+    assert all(w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200 for w in BENCH["workloads"])
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    used = {w["config"] for w in BENCH["workloads"]}
+    assert used == {c["name"] for c in BENCH["configs"]}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_every_cell_finds_its_files_and_its_arrows(cell):
+    w = cells.cell(BENCH, cell)
+    config = cells.config_of(BENCH, w["config"])
+    traffic = cells.traffic_of(w["traffic"])
+    assert traffic["kind"] in ("paced_open", "closed", "train_job")
+    entry = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+    assert sorted(entry["reduced"]) == sorted(config["reduced"])
+    for key in entry["reduced"]:
+        assert config[key] != config["published"][key]
+        assert not re.search(r"(_size|_dim|_rank|experts_per_tok)$", key), "a width may never be cut"
+    e2e = {m["name"] for m in cells.metrics_of(BENCH, cell, "end_to_end")}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    for name in e2e:
+        assert os.path.exists(os.path.join(cells.HERE, "end_to_end", f"{name}.json"))
+    layer = cells.metrics_of(BENCH, cell, "per_layer")
+    assert layer
+    for m in layer:
+        spec = cells.layer_metric_spec(m["name"])
+        assert spec["kind"] in lm.READERS
+        assert (spec["layer"], spec["unit"], spec["moves"]) == (m["layer"], m["unit"], m["moves"])
+        assert m["moves"] in e2e, f"{m['name']} moves {m['moves']}, which {cell} does not report"
+
+
+def test_every_file_under_paths_has_a_permitted_name():
+    ok = re.compile(r"^[A-Za-z0-9_./-]+$")
+    for path in BENCH["paths"]:
+        for root, dirs, files in os.walk(os.path.join(REPO, path)):
+            dirs[:] = [d for d in dirs if d != "__pycache__"]
+            for f in files:
+                rel = os.path.relpath(os.path.join(root, f), REPO)
+                assert ok.match(rel) and len(rel) <= 200, rel
+    assert all(not c.startswith("/") and ".." not in c for c in BENCH["command"])
+    json.dumps(BENCH)
