@@ -66,6 +66,13 @@ from ray_tpu.observability import tracing as _tracing
 
 _END = object()  # stream sentinel
 
+#: the step account's leaf phases (``stats()["step_phases"]["<phase>_s"]``,
+#: profiler annotation ``engine.<phase>``); README "Observability"
+STEP_PHASES = (
+    "schedule", "launch", "device_wait", "readback", "sample", "emit",
+    "bookkeeping", "loop_wait",
+)
+
 logger = logging.getLogger(__name__)
 
 # -- replica chaos (util/chaos.py::ReplicaFaultPlan) -------------------------
@@ -375,6 +382,10 @@ class InferenceEngine:
             self._tier_ns = GLOBAL_CONFIG.kv_tier_namespace or _model_kv_namespace(
                 model_cfg, params
             )
+        #: the step account: where the step-loop thread's time goes, phase
+        #: by phase; _step() hands it to the runner's calls, which add
+        #: their launch / device_wait / readback
+        self._clock = timeline.PhaseClock("engine", STEP_PHASES)
         self.runner = PagedModelRunner(
             model_cfg,
             params,
@@ -385,6 +396,21 @@ class InferenceEngine:
             verify_buckets=ec.resolved_verify_buckets(),
             cache_dtype=ec.cache_dtype,
         )
+        #: the start-up account, written once while the replica comes up
+        #: (LLMServer adds what it alone sees: the weights, and the whole
+        #: of its __init__)
+        self.startup: Dict[str, Any] = {
+            "cache_alloc_s": self.runner.cache_alloc_s,
+            "warmup_s": 0.0,
+            "warmup_programs": self.runner.warmup_programs,
+        }
+        #: the request account: at each first token, the engine's own TTFT
+        #: split into its three waits (sums over requests; the reader
+        #: differences them and divides by first_tokens)
+        self._request_stages = {
+            "first_tokens": 0, "queue_s": 0.0, "prefill_wait_s": 0.0,
+            "prefill_run_s": 0.0,
+        }
         self.blocks = PagedBlockManager(
             ec.num_blocks,
             ec.block_size,
@@ -547,9 +573,11 @@ class InferenceEngine:
             self.scheduler.spec_k_live = ec.speculative_k
         self.total_steps = 0
         if ec.warmup:
+            t0 = time.perf_counter()
             self.runner.warmup(kv_io=ec.kv_transfer_enabled or ec.kv_tier_enabled)
             if self.spec is not None and hasattr(self.spec, "warmup"):
                 self.spec.warmup()
+            self.startup["warmup_s"] = time.perf_counter() - t0
         else:
             self.runner.mark_warm()
             if self.spec is not None and hasattr(self.spec, "mark_warm"):
@@ -618,11 +646,12 @@ class InferenceEngine:
         self.detach_node_drain_listener()
 
     def _loop(self) -> None:
+        clock = self._clock
         while not self._stop.is_set():
             self._last_beat = time.monotonic()
             did_work = False
             try:
-                did_work = self.step()
+                did_work = self.step()  # settles its own part of the account
             except Exception as e:  # noqa: BLE001 — fail in-flight, keep serving
                 self._fail_all(e)
                 if self.runner.cache["k"].is_deleted():
@@ -633,10 +662,14 @@ class InferenceEngine:
                     logger.exception("engine step lost the donated KV cache; stopping")
                     self._stop.set()
                     return
-            self._reap_abandoned_streams()
+            with clock.phase("bookkeeping"):
+                self._reap_abandoned_streams()
             if not did_work:
-                self._work.wait(timeout=0.005)
-                self._work.clear()
+                with clock.phase("loop_wait"):
+                    self._work.wait(timeout=0.005)
+                    self._work.clear()
+            # from where step() settled to here: the loop's own overhead
+            clock.settle(clock.settled_at, "bookkeeping" if did_work else "loop_wait")
 
     # -- submission -------------------------------------------------------
     def submit(
@@ -955,72 +988,94 @@ class InferenceEngine:
     # -- the step ---------------------------------------------------------
     def step(self) -> bool:
         """One engine step: ≤N prefill chunks + the decode batch. Returns
-        whether any work ran."""
-        if self._draining and self._drain_deadline is not None and self._drain_deadline.expired:
-            self._fail_all(
-                RequestFailedError("engine drain grace expired mid-generation")
-            )
-        if self._migrate_on_drain:
-            self._migrate_inflight()
-        did_import = self._drain_kv_imports()
-        self._drain_tier_spills()
-        plan = self.scheduler.schedule()
-        for req in plan.reaped:
-            # every reap here is a deadline expiry (queued or running) —
-            # a fault-cost class the SLO report breaks out explicitly
-            self.metrics["deadline"].inc(
-                labels={"deployment": self.slo_deployment}
-            )
-            self._finish_request(
-                req,
-                req.state,
-                error=RequestFailedError(
-                    f"request {req.request_id} deadline expired before completion"
-                ),
-            )
-        if not plan.prefills and not plan.decodes:
-            return did_import or not plan.empty
-        self._consult_replica_chaos(plan)
-
+        whether any work ran. Every second of it lands in one phase of
+        the step account (``stats()["step_phases"]``): what no phase
+        claimed is this step's bookkeeping."""
+        since = time.perf_counter()
         # timeline timestamps share the module's wall-clock epoch so
         # engine_step events merge with every other process's trace
         t0_us = timeline._now_us()
+        did_work = False
+        try:
+            did_work = self._step(t0_us)
+            return did_work
+        finally:
+            self._clock.settle(since, "bookkeeping" if did_work else "schedule")
+
+    def _step(self, t0_us: float) -> bool:
+        clock = self._clock
+        with clock.phase("schedule", step=self.total_steps):
+            if self._draining and self._drain_deadline is not None and self._drain_deadline.expired:
+                self._fail_all(
+                    RequestFailedError("engine drain grace expired mid-generation")
+                )
+            if self._migrate_on_drain:
+                self._migrate_inflight()
+            did_import = self._drain_kv_imports()
+            self._drain_tier_spills()
+            plan = self.scheduler.schedule()
+            for req in plan.reaped:
+                # every reap here is a deadline expiry (queued or running) —
+                # a fault-cost class the SLO report breaks out explicitly
+                self.metrics["deadline"].inc(
+                    labels={"deployment": self.slo_deployment}
+                )
+                self._finish_request(
+                    req,
+                    req.state,
+                    error=RequestFailedError(
+                        f"request {req.request_id} deadline expired before completion"
+                    ),
+                )
+            if not plan.prefills and not plan.decodes:
+                return did_import or not plan.empty
+            self._consult_replica_chaos(plan)
+
         n_prefill_tokens = 0
         for req, start, chunk in plan.prefills:
             if req.pending_cow:
                 # prefix-cache COW: duplicate the shared block(s) BEFORE
                 # this chunk writes into the private copies, then drop
                 # the source pins (the copies are live in the table now)
-                self.runner.copy_blocks(req.pending_cow)
-                self.blocks.cow_copied(req.request_id)
-                req.pending_cow = []
-            row = self.blocks.table_row(req.request_id, self.runner.max_blocks_per_seq)
-            prompt = req.effective_prompt
-            logits = self.runner.prefill_chunk(
-                prompt[start : start + chunk], row, start
-            )
+                with clock.phase("schedule"):
+                    self.runner.copy_blocks(req.pending_cow)
+                    self.blocks.cow_copied(req.request_id)
+                    req.pending_cow = []
+            if req.prefill_started_at is None:
+                req.prefill_started_at = time.monotonic()
+            with clock.phase("launch"):
+                row = self.blocks.table_row(
+                    req.request_id, self.runner.max_blocks_per_seq
+                )
+                prompt = req.effective_prompt
+                tokens = prompt[start : start + chunk]
+            logits = self.runner.prefill_chunk(tokens, row, start, clock)
             req.prefill_pos = start + chunk
             n_prefill_tokens += chunk
             if req.prefill_done and req.prefill_done_at is None:
                 req.prefill_done_at = time.monotonic()
             if req.prefill_done:
-                # the prompt's K/V is fully written: index its full
-                # blocks so later requests sharing the prefix skip them
-                self.blocks.register_prefix(req.request_id, prompt)
-                if self.engine_cfg.kv_tier_enabled and not req.prefill_only:
-                    # tier write-back trigger 1: the prompt's full
-                    # blocks become cluster-recoverable the moment they
-                    # exist — a replica killed one token later already
-                    # left its prefill in the tier
-                    self._tier_writeback_full_blocks(req, prompt, "prefill")
-                if req.prefill_only:
-                    # KV-migration export: gather the full blocks to
-                    # host and hand the payload to the waiting exporter
-                    # — no token is ever sampled on this engine
-                    self._complete_prefill_export(req, prompt)
-                else:
-                    req.state = DECODE
-                    self._emit_token(req, self._sample(req, logits))
+                if not req.prefill_only:
+                    with clock.phase("sample"):
+                        token = self._sample(req, logits)
+                with clock.phase("emit"):
+                    # the prompt's K/V is fully written: index its full
+                    # blocks so later requests sharing the prefix skip them
+                    self.blocks.register_prefix(req.request_id, prompt)
+                    if self.engine_cfg.kv_tier_enabled and not req.prefill_only:
+                        # tier write-back trigger 1: the prompt's full
+                        # blocks become cluster-recoverable the moment they
+                        # exist — a replica killed one token later already
+                        # left its prefill in the tier
+                        self._tier_writeback_full_blocks(req, prompt, "prefill")
+                    if req.prefill_only:
+                        # KV-migration export: gather the full blocks to
+                        # host and hand the payload to the waiting exporter
+                        # — no token is ever sampled on this engine
+                        self._complete_prefill_export(req, prompt)
+                    else:
+                        req.state = DECODE
+                        self._emit_token(req, token)
 
         if plan.decodes:
             # speculative slots peel off the batch: each proposes drafts,
@@ -1032,51 +1087,80 @@ class InferenceEngine:
             # throughput lever, never a dependency.
             spec_slots: List[tuple] = []
             plain: List[Request] = []
-            for r in plan.decodes:
-                drafts = self._spec_propose(r) if r.spec_step_k > 0 else []
-                if drafts:
-                    spec_slots.append((r, drafts))
-                else:
-                    plain.append(r)
+            if self.spec is None:
+                plain = plan.decodes
+            else:
+                # proposing is deciding what this step runs: schedule's time
+                with clock.phase("schedule"):
+                    for r in plan.decodes:
+                        drafts = self._spec_propose(r) if r.spec_step_k > 0 else []
+                        if drafts:
+                            spec_slots.append((r, drafts))
+                        else:
+                            plain.append(r)
+            # each batch: sample every slot, then emit every slot, so that
+            # the two are two spans and not 2 x slots slivers; the tokens
+            # and their order are those of sampling and emitting in turn
             if plain:
-                toks = [r.generated[-1] for r in plain]
-                poss = [r.context_len - 1 for r in plain]
-                rows = [
-                    self.blocks.table_row(
-                        r.request_id, self.runner.max_blocks_per_seq
-                    )
-                    for r in plain
-                ]
-                cls = [r.context_len for r in plain]
-                logits = self.runner.decode(toks, poss, rows, cls)
-                for req, lg in zip(plain, logits):
-                    self._emit_token(req, self._sample(req, lg))
+                with clock.phase("launch"):
+                    toks = [r.generated[-1] for r in plain]
+                    poss = [r.context_len - 1 for r in plain]
+                    rows = [
+                        self.blocks.table_row(
+                            r.request_id, self.runner.max_blocks_per_seq
+                        )
+                        for r in plain
+                    ]
+                    cls = [r.context_len for r in plain]
+                logits = self.runner.decode(toks, poss, rows, cls, clock)
+                with clock.phase("sample"):
+                    sampled = [self._sample(req, lg) for req, lg in zip(plain, logits)]
+                with clock.phase("emit"):
+                    for req, token in zip(plain, sampled):
+                        self._emit_token(req, token)
             if spec_slots:
-                windows = [[r.generated[-1]] + d for r, d in spec_slots]
-                rows = [
-                    self.blocks.table_row(
-                        r.request_id, self.runner.max_blocks_per_seq
-                    )
-                    for r, _ in spec_slots
-                ]
-                ctxs = [r.context_len - 1 for r, _ in spec_slots]
-                all_logits = self.runner.verify_batch(windows, rows, ctxs)
-                for (req, drafts), logits in zip(spec_slots, all_logits):
-                    self._spec_accept(req, drafts, logits)
-        if n_prefill_tokens:
-            self._prefill_token_times.append((time.monotonic(), n_prefill_tokens))
+                with clock.phase("launch"):
+                    windows = [[r.generated[-1]] + d for r, d in spec_slots]
+                    rows = [
+                        self.blocks.table_row(
+                            r.request_id, self.runner.max_blocks_per_seq
+                        )
+                        for r, _ in spec_slots
+                    ]
+                    ctxs = [r.context_len - 1 for r, _ in spec_slots]
+                all_logits = self.runner.verify_batch(windows, rows, ctxs, clock)
+                with clock.phase("sample"):
+                    sampled = [
+                        self._spec_sample(req, drafts, logits)
+                        for (req, drafts), logits in zip(spec_slots, all_logits)
+                    ]
+                with clock.phase("emit"):
+                    for (req, drafts), tokens in zip(spec_slots, sampled):
+                        self._spec_commit(req, drafts, tokens)
+        with clock.phase("bookkeeping"):
+            if n_prefill_tokens:
+                self._prefill_token_times.append((time.monotonic(), n_prefill_tokens))
+            self._update_gauges(len(plan.decodes))
+            # one event a step: the whole of it, from the top of step(),
+            # with where its time went (bookkeeping, still running, is
+            # what the others leave of the event's duration)
+            timeline.record_event(
+                "engine_step",
+                "inference",
+                t0_us,
+                timeline._now_us(),
+                args={
+                    "step": self.total_steps,
+                    "prefill_tokens": n_prefill_tokens,
+                    "decode_batch": len(plan.decodes),
+                    "phases_us": {
+                        name: round(seconds * 1e6)
+                        for name, seconds in clock.lap.items()
+                        if seconds and name != "bookkeeping"
+                    },
+                },
+            )
         self.total_steps += 1
-        timeline.record_event(
-            "engine_step",
-            "inference",
-            t0_us,
-            timeline._now_us(),
-            args={
-                "prefill_tokens": n_prefill_tokens,
-                "decode_batch": len(plan.decodes),
-            },
-        )
-        self._update_gauges(len(plan.decodes))
         return True
 
     # -- speculative decoding (PR 19) -------------------------------------
@@ -1098,13 +1182,30 @@ class InferenceEngine:
             self.blocks.trim_to(req.request_id, req.context_len)
         return drafts
 
-    def _spec_accept(
+    def _spec_sample(
         self, req: Request, drafts: List[int], logits: np.ndarray
+    ) -> List[int]:
+        """The target's own tokens along one slot's verify window, up to
+        and including the first that differs from its draft: what
+        :meth:`_spec_commit` emits. Position ``i`` is sampled as if the
+        ``i`` tokens before it had been emitted, which they will have
+        been by the time it is (commit stops where the request ends)."""
+        base = len(req.prompt) + len(req.generated)
+        tokens: List[int] = []
+        for i in range(len(drafts) + 1):
+            tokens.append(self._sample(req, logits[i], base + i))
+            if i < len(drafts) and tokens[-1] != drafts[i]:
+                break
+        return tokens
+
+    def _spec_commit(
+        self, req: Request, drafts: List[int], tokens: List[int]
     ) -> None:
         """Commit the deterministically-accepted prefix of one slot's
         verify window ``[last_committed, d_1..d_k']`` from its
         all-position target logits (``logits[i]`` is the distribution
-        AFTER window position i; the batched verify already ran).
+        AFTER window position i; the batched verify already ran, and
+        :meth:`_spec_sample` realized ``tokens`` from them).
 
         Acceptance is exact-match: at each window position the target's
         token is realized with the engine's own (seed, absolute-position)
@@ -1128,15 +1229,11 @@ class InferenceEngine:
         derive from ``generated``."""
         m = self.metrics
         accepted = 0
-        for i in range(len(drafts) + 1):
+        for i, tok in enumerate(tokens):
             if req.finished:
                 break
-            tok = self._sample(req, logits[i])
-            matched = i < len(drafts) and tok == drafts[i]
             self._emit_token(req, tok)
-            if i < len(drafts):
-                if not matched:
-                    break
+            if i < len(drafts) and tok == drafts[i]:
                 accepted += 1
         self._spec_proposed += len(drafts)
         self._spec_accepted += accepted
@@ -1149,7 +1246,9 @@ class InferenceEngine:
         self.blocks.trim_to(req.request_id, req.context_len)
 
     # -- internals --------------------------------------------------------
-    def _sample(self, req: Request, logits: np.ndarray) -> int:
+    def _sample(
+        self, req: Request, logits: np.ndarray, pos: Optional[int] = None
+    ) -> int:
         """Deterministic continuation: the RNG is keyed on
         ``(request seed, absolute position)`` instead of a stateful
         per-request stream. ``len(prompt) + len(generated)`` equals the
@@ -1161,7 +1260,8 @@ class InferenceEngine:
         tests/test_stream_resume.py)."""
         if req.temperature <= 0.0:
             return int(np.argmax(logits))
-        pos = len(req.prompt) + len(req.generated)
+        if pos is None:
+            pos = len(req.prompt) + len(req.generated)
         seed = req.seed if req.seed is not None else 0
         rng = np.random.default_rng(
             np.random.SeedSequence([int(seed) & 0xFFFFFFFFFFFFFFFF, pos])
@@ -1599,6 +1699,18 @@ class InferenceEngine:
                     ttft = now - sub
                     self._ttft_tape.observe(ttft)
                     self._recent_ttfts.append((now, ttft))
+                    # the request account: the three parts sum to ttft
+                    admitted = req.admitted_at if req.admitted_at is not None else sub
+                    started = (
+                        req.prefill_started_at
+                        if req.prefill_started_at is not None
+                        else admitted
+                    )
+                    stages = self._request_stages
+                    stages["first_tokens"] += 1
+                    stages["queue_s"] += admitted - sub
+                    stages["prefill_wait_s"] += started - admitted
+                    stages["prefill_run_s"] += now - started
                     wire = self._trace_ctx.get(req.request_id)
                     if wire is not None:
                         first_span = (wire, ttft)
@@ -1744,6 +1856,17 @@ class InferenceEngine:
             stages["prefill"] = round(
                 max(0.0, req.prefill_done_at - req.admitted_at), 6
             )
+        if req.prefill_started_at is not None:
+            # the request account's split of what the first token waited for
+            # after admission: behind other requests' chunks, then its own
+            if req.admitted_at is not None:
+                stages["prefill_wait"] = round(
+                    max(0.0, req.prefill_started_at - req.admitted_at), 6
+                )
+            if first_token is not None:
+                stages["prefill_run"] = round(
+                    max(0.0, first_token - req.prefill_started_at), 6
+                )
         if first_token is not None:
             stages["decode"] = round(max(0.0, now - first_token), 6)
         entry = {
@@ -1962,6 +2085,12 @@ class InferenceEngine:
             ),
             "tokens_per_s": round(self._tokens_per_s(), 2),
             "ttft": {k: round(v, 6) for k, v in self._ttft_quantiles().items()},
+            "step_phases": self._step_phases(),
+            "request_stages": dict(self._request_stages),
+            "startup": {
+                **self.startup,
+                "warmup_programs": dict(self.startup["warmup_programs"]),
+            },
         }
         if self.spec is not None:
             prop, acc = self._spec_proposed, self._spec_accepted
@@ -1975,6 +2104,17 @@ class InferenceEngine:
                 "acceptance_rate": round(acc / prop, 4) if prop else 0.0,
             }
         return s
+
+    def _step_phases(self) -> Dict[str, float]:
+        """The step account: seconds of the step-loop thread's life per
+        leaf phase (monotonic, engine lifetime: a reader differences two
+        calls), their sum ``wall_s``, and ``host_serial_s``, the part in
+        which the device waits for the host."""
+        total = dict(self._clock.total)
+        out = {f"{name}_s": seconds for name, seconds in total.items()}
+        out["wall_s"] = sum(total.values())
+        out["host_serial_s"] = out["wall_s"] - total["device_wait"] - total["loop_wait"]
+        return out
 
     def routing_stats(self) -> Dict[str, Any]:
         """Compact replica load + cache-locality digest, gossiped to

@@ -17,6 +17,8 @@ it.
 
 from __future__ import annotations
 
+import logging
+import time
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -32,6 +34,9 @@ from ray_tpu.models.llama import (
     paged_verify_step,
     scatter_paged_blocks,
 )
+from ray_tpu.observability import timeline
+
+logger = logging.getLogger(__name__)
 
 #: block-copy pairs per compiled COW program (pairs pad with null->null)
 _COW_WIDTH = 4
@@ -63,6 +68,10 @@ class PagedModelRunner:
     ):
         import jax
 
+        #: where ``launch``, ``device_wait`` and ``readback`` of a call go
+        #: unless the caller hands in its own account (the engine does, for
+        #: the calls of its step loop: a PhaseClock is one thread's)
+        self.clock = timeline.PhaseClock("runner")
         self.cfg = cfg
         self.params = params
         self.block_size = block_size
@@ -80,7 +89,14 @@ class PagedModelRunner:
                 f"num_blocks={num_blocks} can't hold one max-length sequence "
                 f"({self.max_blocks_per_seq} blocks + null block)"
             )
-        self.cache = init_paged_kv_cache(cfg, num_blocks, block_size, cache_dtype)
+        t0 = time.perf_counter()
+        self.cache = jax.block_until_ready(
+            init_paged_kv_cache(cfg, num_blocks, block_size, cache_dtype)
+        )
+        #: start-up account: seconds to allocate the cache, and per warmed
+        #: program its compile (or load from the compile cache) and first run
+        self.cache_alloc_s = time.perf_counter() - t0
+        self.warmup_programs: Dict[str, float] = {}
 
         # argument 1 of the partials (cfg is bound) is the cache: donated,
         # updated in place — at a real width a copied cache does not fit
@@ -117,20 +133,14 @@ class PagedModelRunner:
             partial(scatter_paged_blocks), donate_argnums=(0,)
         )
         self._warmup_compiles: Optional[int] = None
+        #: jit cache entries per program, as :meth:`_run` (which every call
+        #: of a program goes through) last saw them: the one record of what
+        #: compiled, summed for the counts and compared to tell which call did
+        self._entries: Dict[str, int] = {}
 
     # -- compile accounting ----------------------------------------------
     def _jit_cache_entries(self) -> int:
-        return sum(
-            fn._cache_size()
-            for fn in (
-                self._prefill_jit,
-                self._decode_jit,
-                self._verify_jit,
-                self._copy_jit,
-                self._gather_jit,
-                self._scatter_jit,
-            )
-        )
+        return sum(self._entries.values())
 
     def mark_warm(self) -> None:
         """Call after warmup: compiles past this point are regressions."""
@@ -140,6 +150,37 @@ class PagedModelRunner:
         if self._warmup_compiles is None:
             return 0
         return max(0, self._jit_cache_entries() - self._warmup_compiles)
+
+    def _run(self, program: str, fn, *args):
+        """Call one of the compiled programs. A call that grows its jit
+        cache after :meth:`mark_warm` compiled inside serving: say which
+        program and which shapes, once, where the timeline shows it
+        beside the step that paid for it."""
+        start_us = timeline._now_us()
+        out = fn(*args)
+        entries = fn._cache_size()
+        if entries != self._entries.get(program, 0):
+            self._entries[program] = entries
+            if self._warmup_compiles is not None:
+                shapes = [list(a.shape) for a in args if hasattr(a, "shape")]
+                timeline.record_event(
+                    "recompile", "inference", start_us, timeline._now_us(),
+                    args={"program": program, "arg_shapes": shapes},
+                )
+                logger.warning(
+                    "%s compiled after warm-up for argument shapes %s", program, shapes
+                )
+        return out
+
+    def _warm(self, program: str, bucket, fn, *args):
+        """One warm-up call, timed to the end of its first run."""
+        import jax
+
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(self._run(program, fn, *args))
+        label = program if bucket is None else f"{program}[{bucket}]"
+        self.warmup_programs[label] = time.perf_counter() - t0
+        return out
 
     def compile_count(self) -> int:
         return self._jit_cache_entries()
@@ -155,11 +196,13 @@ class PagedModelRunner:
         for c in buckets_prefill if buckets_prefill is not None else self.prefill_buckets:
             tokens = np.zeros(c, np.int32)
             row = np.zeros(M, np.int32)
-            self.cache, _ = self._prefill_jit(
+            self.cache, _ = self._warm(
+                "paged_prefill_step", c, self._prefill_jit,
                 self.params, self.cache, tokens, row, np.int32(0), np.int32(0)
             )
         for b in buckets_decode if buckets_decode is not None else self.decode_buckets:
-            self.cache, _ = self._decode_jit(
+            self.cache, _ = self._warm(
+                "paged_decode_step", b, self._decode_jit,
                 self.params,
                 self.cache,
                 np.zeros(b, np.int32),
@@ -173,7 +216,8 @@ class PagedModelRunner:
         # window-bucket) pair a live engine can issue gets compiled here.
         for c in self.verify_buckets:
             for b in buckets_decode if buckets_decode is not None else self.decode_buckets:
-                self.cache, _ = self._verify_jit(
+                self.cache, _ = self._warm(
+                    "paged_verify_step", f"{b}x{c}", self._verify_jit,
                     self.params,
                     self.cache,
                     np.zeros((b, c), np.int32),
@@ -184,11 +228,17 @@ class PagedModelRunner:
         # the COW copy program (all-null pairs write the null block's
         # trash back onto itself)
         pad = np.zeros(_COW_WIDTH, np.int32)
-        self.cache = self._copy_jit(self.cache, pad, pad)
+        self.cache = self._warm(
+            "copy_paged_blocks", None, self._copy_jit, self.cache, pad, pad
+        )
         if kv_io:
             ids = np.zeros(_KV_IO_WIDTH, np.int32)
-            kv = np.asarray(self._gather_jit(self.cache, ids))
-            self.cache = self._scatter_jit(self.cache, ids, kv)
+            kv = np.asarray(
+                self._warm("gather_paged_blocks", None, self._gather_jit, self.cache, ids)
+            )
+            self.cache = self._warm(
+                "scatter_paged_blocks", None, self._scatter_jit, self.cache, ids, kv
+            )
         self.mark_warm()
 
     # -- steps ------------------------------------------------------------
@@ -203,7 +253,9 @@ class PagedModelRunner:
             dst = np.zeros(_COW_WIDTH, np.int32)
             for j, (s, d) in enumerate(chunk):
                 src[j], dst[j] = s, d
-            self.cache = self._copy_jit(self.cache, src, dst)
+            self.cache = self._run(
+                "copy_paged_blocks", self._copy_jit, self.cache, src, dst
+            )
 
     def gather_blocks(self, block_ids: Sequence[int]) -> np.ndarray:
         """Read whole cache blocks to host (KV-migration export):
@@ -216,7 +268,7 @@ class PagedModelRunner:
             chunk = block_ids[i : i + _KV_IO_WIDTH]
             ids = np.zeros(_KV_IO_WIDTH, np.int32)
             ids[: len(chunk)] = chunk
-            out = self._gather_jit(self.cache, ids)
+            out = self._run("gather_paged_blocks", self._gather_jit, self.cache, ids)
             outs.append(np.asarray(out)[:, :, : len(chunk)])
         if not outs:
             shape = self.cache["k"].shape  # [L, N, bs, kv, hd]
@@ -239,32 +291,49 @@ class PagedModelRunner:
                 kv.shape[:2] + (_KV_IO_WIDTH,) + kv.shape[3:], kv.dtype
             )
             buf[:, :, : len(chunk)] = kv[:, :, i : i + len(chunk)]
-            self.cache = self._scatter_jit(self.cache, ids, buf)
+            self.cache = self._run(
+                "scatter_paged_blocks", self._scatter_jit, self.cache, ids, buf
+            )
 
     def prefill_chunk(
         self,
         tokens: Sequence[int],
         block_row: Sequence[int],
         ctx_len: int,
+        clock: Optional[timeline.PhaseClock] = None,
     ) -> np.ndarray:
         """Run one prefill chunk; returns logits [vocab] (fp32 numpy) for
-        the chunk's last valid token."""
+        the chunk's last valid token. ``clock``: the caller's account for
+        the call's phases, if it keeps one (the runner's own otherwise)."""
+        clock = clock or self.clock
         true_len = len(tokens)
         bucket = _round_up_bucket(true_len, self.prefill_buckets)
-        padded = np.zeros(bucket, np.int32)
-        padded[:true_len] = tokens
-        row = np.asarray(block_row, np.int32)
-        self.cache, logits = self._prefill_jit(
-            self.params, self.cache, padded, row,
-            np.int32(ctx_len), np.int32(true_len),
-        )
-        return np.asarray(logits)
+        with clock.phase("launch", program="paged_prefill_step", bucket=bucket):
+            padded = np.zeros(bucket, np.int32)
+            padded[:true_len] = tokens
+            row = np.asarray(block_row, np.int32)
+            self.cache, logits = self._run(
+                "paged_prefill_step", self._prefill_jit,
+                self.params, self.cache, padded, row,
+                np.int32(ctx_len), np.int32(true_len),
+            )
+        return self._read(logits, clock)
+
+    @staticmethod
+    def _read(logits, clock: timeline.PhaseClock) -> np.ndarray:
+        """Wait for a step's logits, then copy them to the host: two
+        phases, so that the device's time is told from the copy's."""
+        with clock.phase("device_wait"):
+            logits.block_until_ready()
+        with clock.phase("readback"):
+            return np.asarray(logits)
 
     def verify_batch(
         self,
         windows: Sequence[Sequence[int]],
         block_rows: Sequence[Sequence[int]],
         ctx_lens: Sequence[int],
+        clock: Optional[timeline.PhaseClock] = None,
     ) -> List[np.ndarray]:
         """Run speculative-verify windows (``[last_committed, d_1..d_k]``
         each) for a batch of slots in ONE jitted step. Returns one
@@ -272,23 +341,28 @@ class PagedModelRunner:
         per valid window position. The batch axis pads to a decode
         bucket; padding slots carry ``true_len=0`` so every position is
         invalid and the writes land on the null block."""
+        clock = clock or self.clock
         n = len(windows)
         cbucket = _round_up_bucket(max(len(w) for w in windows), self.verify_buckets)
         bbucket = _round_up_bucket(n, self.decode_buckets)
         M = self.max_blocks_per_seq
-        tokens = np.zeros((bbucket, cbucket), np.int32)
-        tables = np.zeros((bbucket, M), np.int32)
-        ctx = np.zeros(bbucket, np.int32)
-        tl = np.zeros(bbucket, np.int32)
-        for i, w in enumerate(windows):
-            tokens[i, : len(w)] = w
-            tables[i] = block_rows[i]
-            ctx[i] = ctx_lens[i]
-            tl[i] = len(w)
-        self.cache, logits = self._verify_jit(
-            self.params, self.cache, tokens, tables, ctx, tl
-        )
-        out = np.asarray(logits)
+        with clock.phase(
+            "launch", program="paged_verify_step", bucket=f"{bbucket}x{cbucket}"
+        ):
+            tokens = np.zeros((bbucket, cbucket), np.int32)
+            tables = np.zeros((bbucket, M), np.int32)
+            ctx = np.zeros(bbucket, np.int32)
+            tl = np.zeros(bbucket, np.int32)
+            for i, w in enumerate(windows):
+                tokens[i, : len(w)] = w
+                tables[i] = block_rows[i]
+                ctx[i] = ctx_lens[i]
+                tl[i] = len(w)
+            self.cache, logits = self._run(
+                "paged_verify_step", self._verify_jit,
+                self.params, self.cache, tokens, tables, ctx, tl,
+            )
+        out = self._read(logits, clock)
         return [out[i, : len(w)] for i, w in enumerate(windows)]
 
     def decode(
@@ -297,19 +371,25 @@ class PagedModelRunner:
         positions: Sequence[int],
         block_rows: Sequence[Sequence[int]],
         ctx_lens: Sequence[int],
+        clock: Optional[timeline.PhaseClock] = None,
     ) -> np.ndarray:
         """Advance a decode batch one token; returns logits [n, vocab]
         for the n REAL slots (padding stripped)."""
+        clock = clock or self.clock
         n = len(tokens)
         bucket = _round_up_bucket(n, self.decode_buckets)
         M = self.max_blocks_per_seq
-        t = np.zeros(bucket, np.int32)
-        p = np.zeros(bucket, np.int32)
-        bt = np.zeros((bucket, M), np.int32)
-        cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
-        t[:n] = tokens
-        p[:n] = positions
-        bt[:n] = np.asarray(block_rows, np.int32)
-        cl[:n] = ctx_lens
-        self.cache, logits = self._decode_jit(self.params, self.cache, t, p, bt, cl)
-        return np.asarray(logits)[:n]
+        with clock.phase("launch", program="paged_decode_step", bucket=bucket):
+            t = np.zeros(bucket, np.int32)
+            p = np.zeros(bucket, np.int32)
+            bt = np.zeros((bucket, M), np.int32)
+            cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
+            t[:n] = tokens
+            p[:n] = positions
+            bt[:n] = np.asarray(block_rows, np.int32)
+            cl[:n] = ctx_lens
+            self.cache, logits = self._run(
+                "paged_decode_step", self._decode_jit,
+                self.params, self.cache, t, p, bt, cl,
+            )
+        return self._read(logits, clock)[:n]

@@ -112,6 +112,10 @@ class Request:
     #: readmissions after preemption keep the ORIGINAL stamp — the
     #: client-visible queue wait happened once)
     admitted_at: Optional[float] = None
+    #: first prefill chunk about to launch: until here the request waited,
+    #: admitted, behind other requests' chunks (kept across preemptions,
+    #: like admitted_at)
+    prefill_started_at: Optional[float] = None
     #: prompt K/V fully written (prefill stage ends here)
     prefill_done_at: Optional[float] = None
     #: last token emission (the engine derives per-token decode gaps)

@@ -37,6 +37,7 @@ streams resumable.
 
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
 
@@ -67,6 +68,7 @@ class LLMServer:
         params=None,
         export_metrics: bool = True,
     ):
+        t_enter = time.perf_counter()
         import jax
 
         from ray_tpu.accelerators.tpu import process_device_report
@@ -80,13 +82,19 @@ class LLMServer:
         if model_cfg is None:
             model_cfg = LlamaConfig.tiny()
         self.model_cfg = model_cfg
+        # the start-up account (engine_stats()["startup"]): the weights, and
+        # below the whole of this constructor (what is left of it is the
+        # import of JAX and the backend's first answer); the engine adds
+        # its cache and its warm-up
+        t0 = time.perf_counter()
         if params is None:
             # one compiled program: the draw, scale and cast of each weight
             # fuse (no float32 copy of a bf16 model), and a restarted or
             # sibling replica finds the program in the compile cache
-            params = jax.jit(partial(init_params, model_cfg))(
-                jax.random.PRNGKey(seed)
+            params = jax.block_until_ready(
+                jax.jit(partial(init_params, model_cfg))(jax.random.PRNGKey(seed))
             )
+        param_init_s = time.perf_counter() - t0
         self.engine = InferenceEngine(
             model_cfg, params, engine_cfg or EngineConfig()
         ).start()
@@ -101,6 +109,10 @@ class LLMServer:
             self._metrics_server = MetricsServer(
                 host=GLOBAL_CONFIG.metrics_bind_host, port=0
             )
+        self.engine.startup.update(
+            param_init_s=param_init_s,
+            replica_init_s=time.perf_counter() - t_enter,
+        )
 
     # -- request plumbing -------------------------------------------------
     @staticmethod

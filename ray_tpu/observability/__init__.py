@@ -9,17 +9,17 @@ the controller KV on exit; in-process events are always available.
 """
 
 from ray_tpu.observability.timeline import (
+    PhaseClock,
     ProfileEvent,
     dump_timeline,
-    profile,
     record_event,
     timeline_events,
 )
 
 __all__ = [
+    "PhaseClock",
     "ProfileEvent",
     "dump_timeline",
-    "profile",
     "record_event",
     "timeline_events",
     "tracing",

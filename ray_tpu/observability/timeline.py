@@ -17,11 +17,11 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -72,14 +72,69 @@ def record_event(
         _total_recorded += 1
 
 
-@contextmanager
-def profile(name: str, category: str = "task", **args):
-    """Context manager recording one complete event (cf. ray.profiling)."""
-    start = _now_us()
-    try:
-        yield
-    finally:
-        record_event(name, category, start, _now_us(), args=args or None)
+class PhaseClock:
+    """Where one thread's loop spends its time, phase by phase.
+
+    ``with clock.phase("sample"):`` adds the block's ``perf_counter``
+    seconds to ``clock.lap["sample"]`` and, in a process that has
+    imported JAX, wraps it in ``jax.profiler.TraceAnnotation
+    ("<prefix>.sample", **args)``: inert unless a profiler trace is
+    running, and then a span on the trace's own clock, beside the device's
+    operations. A process that has not imported JAX is not made to: there
+    the phase only accumulates.
+
+    Phases are leaves: they do not nest, so their seconds add up.
+    ``settle(since, rest)`` closes the account over ``[since, now]``:
+    what no phase claimed of that wall time goes to ``rest`` and the lap
+    is added to ``total``, so ``sum(total.values())`` is the wall time of
+    everything settled so far."""
+
+    def __init__(self, prefix: str, phases=()):
+        self.prefix = prefix
+        self.lap: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        self.total: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        #: perf_counter of the last settle: a loop resumes its account here
+        self.settled_at = time.perf_counter()
+
+    def phase(self, name: str, **args) -> "_Phase":
+        return _Phase(self, name, args)
+
+    def settle(self, since: float, rest: str) -> None:
+        now = time.perf_counter()
+        lap, total = self.lap, self.total
+        lap[rest] = lap.get(rest, 0.0) + max(0.0, now - since - sum(lap.values()))
+        for name, seconds in lap.items():
+            total[name] = total.get(name, 0.0) + seconds
+            lap[name] = 0.0
+        self.settled_at = now
+
+
+class _Phase:
+    __slots__ = ("_clock", "_name", "_span", "_t0")
+
+    def __init__(self, clock: PhaseClock, name: str, args: Dict[str, Any]):
+        self._clock = clock
+        self._name = name
+        # sys.modules, not an import: the driver and the benchmark's own
+        # process stay off JAX
+        profiler = sys.modules.get("jax.profiler")
+        self._span = (
+            profiler.TraceAnnotation(f"{clock.prefix}.{name}", **args)
+            if profiler is not None
+            else None
+        )
+
+    def __enter__(self) -> None:
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self._t0
+        lap = self._clock.lap
+        lap[self._name] = lap.get(self._name, 0.0) + seconds
+        if self._span is not None:
+            self._span.__exit__(*exc)
 
 
 def timeline_events() -> List[ProfileEvent]:
