@@ -2086,6 +2086,9 @@ class InferenceEngine:
             "tokens_per_s": round(self._tokens_per_s(), 2),
             "ttft": {k: round(v, 6) for k, v in self._ttft_quantiles().items()},
             "step_phases": self._step_phases(),
+            # how wide the decode and verify launches gathered (the target
+            # runner's own; a draft model's runner keeps its own count)
+            "decode_width": dict(self.runner.decode_width),
             "request_stages": dict(self._request_stages),
             "startup": {
                 **self.startup,

@@ -7,6 +7,19 @@ engine must see ZERO recompiles — the jit cache holds exactly one entry
 per bucket, asserted via ``recompiles_after_warmup()`` (backed by
 ``PjitFunction._cache_size``).
 
+Decode and verify have a second bucketed axis: the WIDTH of the block
+table. The step functions gather ``cache[layer, block_tables]`` for every
+slot, so a step costs what the table's width costs, whatever the contexts
+in it. Callers still hand in ``max_blocks_per_seq``-wide rows; the runner
+cuts them to the narrowest rung of :func:`table_width_ladder` that covers
+the longest context of the batch (2048 tokens, then doublings, then the
+full width: derived from ``max_seq_len`` and ``block_size``, not
+configured). Positions past a slot's context were masked before the
+softmax anyway, so the logits are those of the full width. ``warmup()``
+compiles every (batch bucket x rung) pair, so a batch that crosses a rung
+in either direction finds its program compiled; the cache argument has
+the same shape in all of them. ``decode_width`` counts what was chosen.
+
 The device cache lives here as functional state: every step donates the
 cache buffer (``donate_argnums``) and returns the new value, and the
 runner swaps its reference. Donation is unconditional — the CPU backend
@@ -44,6 +57,27 @@ _COW_WIDTH = 4
 #: blocks per compiled KV gather/scatter program (KV-cache migration);
 #: short chunks pad with the null block so the shape never varies
 _KV_IO_WIDTH = 8
+
+
+#: narrowest block-table width, in tokens, that decode and verify compile
+#: for. Every rung is one more program to load at start-up (1.6 s each at
+#: the benchmark's widths, PERF.md PR 25): a 1024 rung was measured and
+#: saved 8 ms a step under it, but cost start-up more than it was allowed
+_MIN_TABLE_WIDTH_TOKENS = 2048
+
+
+def table_width_ladder(max_seq_len: int, block_size: int) -> Tuple[int, ...]:
+    """Block-table widths, in BLOCKS, that decode and verify are compiled
+    for: ``min(2048, max_seq_len)`` tokens, doublings of it below the full
+    width, and last the full width ``ceil(max_seq_len / block_size)``
+    itself. One rung up to 2048 tokens; 128 and 256 blocks for 4096 / 16."""
+    full = -(-max_seq_len // block_size)
+    rungs = []
+    tokens = _MIN_TABLE_WIDTH_TOKENS
+    while -(-tokens // block_size) < full:
+        rungs.append(-(-tokens // block_size))
+        tokens *= 2
+    return (*rungs, full)
 
 
 def _round_up_bucket(n: int, buckets: Sequence[int]) -> int:
@@ -96,6 +130,14 @@ class PagedModelRunner:
         #: start-up account: seconds to allocate the cache, and per warmed
         #: program its compile (or load from the compile cache) and first run
         self.cache_alloc_s = time.perf_counter() - t0
+        #: block-table widths (blocks) decode and verify are compiled for
+        self.table_widths = table_width_ladder(cfg.max_seq_len, block_size)
+        #: running sums over decode and verify launches: the rung chosen
+        #: (tokens), the longest context that chose it, the contexts of
+        #: the real slots, and batch bucket x rung (what the program reads)
+        self.decode_width: Dict[str, int] = dict.fromkeys(
+            ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
+        )
         self.warmup_programs: Dict[str, float] = {}
 
         # argument 1 of the partials (cfg is bound) is the cache: donated,
@@ -189,10 +231,16 @@ class PagedModelRunner:
         self, buckets_prefill=None, buckets_decode=None, *, kv_io: bool = False
     ) -> None:
         """Compile every (or the given) bucket up front with trash inputs
-        aimed at the null block, then :meth:`mark_warm`. ``kv_io`` also
-        compiles the KV-migration gather/scatter programs (disaggregated
-        serving opts in; plain engines keep their compile count)."""
+        aimed at the null block, then :meth:`mark_warm`. Decode compiles
+        once per (batch bucket x table width of :attr:`table_widths`) and
+        verify once per (batch bucket x window bucket x table width): the
+        live batch picks its width from its longest context, so every
+        width it can pick is here and crossing a rung compiles nothing.
+        ``kv_io`` also compiles the KV-migration gather/scatter programs
+        (disaggregated serving opts in; plain engines keep their compile
+        count)."""
         M = self.max_blocks_per_seq
+        bs = self.block_size
         for c in buckets_prefill if buckets_prefill is not None else self.prefill_buckets:
             tokens = np.zeros(c, np.int32)
             row = np.zeros(M, np.int32)
@@ -200,31 +248,35 @@ class PagedModelRunner:
                 "paged_prefill_step", c, self._prefill_jit,
                 self.params, self.cache, tokens, row, np.int32(0), np.int32(0)
             )
-        for b in buckets_decode if buckets_decode is not None else self.decode_buckets:
-            self.cache, _ = self._warm(
-                "paged_decode_step", b, self._decode_jit,
-                self.params,
-                self.cache,
-                np.zeros(b, np.int32),
-                np.zeros(b, np.int32),
-                np.zeros((b, M), np.int32),
-                np.ones(b, np.int32),
-            )
+        batches = buckets_decode if buckets_decode is not None else self.decode_buckets
+        for b in batches:
+            for w in self.table_widths:
+                self.cache, _ = self._warm(
+                    "paged_decode_step", f"{b}x{w * bs}", self._decode_jit,
+                    self.params,
+                    self.cache,
+                    np.zeros(b, np.int32),
+                    np.zeros(b, np.int32),
+                    np.zeros((b, w), np.int32),
+                    np.ones(b, np.int32),
+                )
         # speculative-verify windows (only when the engine opted in via
         # verify_buckets — plain engines keep their exact compile count).
         # The batch axis rides the decode buckets: every (B-bucket,
-        # window-bucket) pair a live engine can issue gets compiled here.
+        # window-bucket, table-width) triple a live engine can issue gets
+        # compiled here.
         for c in self.verify_buckets:
-            for b in buckets_decode if buckets_decode is not None else self.decode_buckets:
-                self.cache, _ = self._warm(
-                    "paged_verify_step", f"{b}x{c}", self._verify_jit,
-                    self.params,
-                    self.cache,
-                    np.zeros((b, c), np.int32),
-                    np.zeros((b, M), np.int32),
-                    np.zeros(b, np.int32),
-                    np.zeros(b, np.int32),
-                )
+            for b in batches:
+                for w in self.table_widths:
+                    self.cache, _ = self._warm(
+                        "paged_verify_step", f"{b}x{c}x{w * bs}", self._verify_jit,
+                        self.params,
+                        self.cache,
+                        np.zeros((b, c), np.int32),
+                        np.zeros((b, w), np.int32),
+                        np.zeros(b, np.int32),
+                        np.zeros(b, np.int32),
+                    )
         # the COW copy program (all-null pairs write the null block's
         # trash back onto itself)
         pad = np.zeros(_COW_WIDTH, np.int32)
@@ -328,6 +380,22 @@ class PagedModelRunner:
         with clock.phase("readback"):
             return np.asarray(logits)
 
+    def _table_width(self, ctx_lens: Sequence[int], bucket: int) -> int:
+        """The width, in blocks, at which this decode or verify launch
+        gathers: the first rung of :attr:`table_widths` that covers the
+        longest of ``ctx_lens`` (the real slots' contexts INCLUDING what
+        this step writes; padding slots fit any width). Counts the choice
+        in :attr:`decode_width`."""
+        need = int(max(ctx_lens))
+        width = _round_up_bucket(-(-need // self.block_size), self.table_widths)
+        dw = self.decode_width
+        dw["launches"] += 1
+        dw["width_tokens"] += width * self.block_size
+        dw["needed_tokens"] += need
+        dw["live_tokens"] += int(sum(ctx_lens))
+        dw["gathered_tokens"] += bucket * width * self.block_size
+        return width
+
     def verify_batch(
         self,
         windows: Sequence[Sequence[int]],
@@ -340,14 +408,20 @@ class PagedModelRunner:
         logits array [len(window), vocab] (fp32 numpy) per slot, a row
         per valid window position. The batch axis pads to a decode
         bucket; padding slots carry ``true_len=0`` so every position is
-        invalid and the writes land on the null block."""
+        invalid and the writes land on the null block. ``block_rows`` are
+        ``max_blocks_per_seq`` wide; the step gathers them only as wide as
+        the rung of :attr:`table_widths` that covers the longest
+        ``ctx_len + len(window)``, a program :meth:`warmup` compiled."""
         clock = clock or self.clock
         n = len(windows)
         cbucket = _round_up_bucket(max(len(w) for w in windows), self.verify_buckets)
         bbucket = _round_up_bucket(n, self.decode_buckets)
-        M = self.max_blocks_per_seq
+        M = self._table_width(
+            [c + len(w) for c, w in zip(ctx_lens, windows)], bbucket
+        )
         with clock.phase(
-            "launch", program="paged_verify_step", bucket=f"{bbucket}x{cbucket}"
+            "launch", program="paged_verify_step",
+            bucket=f"{bbucket}x{cbucket}x{M * self.block_size}",
         ):
             tokens = np.zeros((bbucket, cbucket), np.int32)
             tables = np.zeros((bbucket, M), np.int32)
@@ -355,7 +429,7 @@ class PagedModelRunner:
             tl = np.zeros(bbucket, np.int32)
             for i, w in enumerate(windows):
                 tokens[i, : len(w)] = w
-                tables[i] = block_rows[i]
+                tables[i] = block_rows[i][:M]
                 ctx[i] = ctx_lens[i]
                 tl[i] = len(w)
             self.cache, logits = self._run(
@@ -374,19 +448,27 @@ class PagedModelRunner:
         clock: Optional[timeline.PhaseClock] = None,
     ) -> np.ndarray:
         """Advance a decode batch one token; returns logits [n, vocab]
-        for the n REAL slots (padding stripped)."""
+        for the n REAL slots (padding stripped). ``block_rows`` are
+        ``max_blocks_per_seq`` wide; the step gathers them only as wide as
+        the rung of :attr:`table_widths` that covers ``max(ctx_lens)``, a
+        program :meth:`warmup` compiled. What is cut off lay past every
+        slot's context, which the step masks: the logits are those of the
+        full width."""
         clock = clock or self.clock
         n = len(tokens)
         bucket = _round_up_bucket(n, self.decode_buckets)
-        M = self.max_blocks_per_seq
-        with clock.phase("launch", program="paged_decode_step", bucket=bucket):
+        M = self._table_width(ctx_lens, bucket)
+        with clock.phase(
+            "launch", program="paged_decode_step",
+            bucket=f"{bucket}x{M * self.block_size}",
+        ):
             t = np.zeros(bucket, np.int32)
             p = np.zeros(bucket, np.int32)
             bt = np.zeros((bucket, M), np.int32)
             cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
             t[:n] = tokens
             p[:n] = positions
-            bt[:n] = np.asarray(block_rows, np.int32)
+            bt[:n] = np.asarray([row[:M] for row in block_rows], np.int32)
             cl[:n] = ctx_lens
             self.cache, logits = self._run(
                 "paged_decode_step", self._decode_jit,

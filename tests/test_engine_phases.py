@@ -178,7 +178,7 @@ def test_startup_is_written_once(cfg):
             "param_init_s", "cache_alloc_s", "warmup_s", "warmup_programs", "replica_init_s",
         }
         assert set(first["warmup_programs"]) == {
-            "paged_prefill_step[16]", "paged_prefill_step[32]", "paged_decode_step[4]",
+            "paged_prefill_step[16]", "paged_prefill_step[32]", "paged_decode_step[4x64]",
             "copy_paged_blocks",
         }
         assert all(v > 0 for v in first["warmup_programs"].values())
@@ -271,7 +271,8 @@ def test_profiler_trace_holds_the_phases_and_no_enclosing_step(engine, tmp_path)
     assert "engine.step" not in events and "engine_step" not in events
     launches = [stats for _s, _d, stats in events["engine.launch"] if stats]
     assert {s["program"] for s in launches} == {"paged_prefill_step", "paged_decode_step"}
-    assert {int(s["bucket"]) for s in launches} <= {16, 32, 4}
+    # prefill: the chunk bucket; decode: batch bucket x table width in tokens
+    assert {str(s["bucket"]) for s in launches} <= {"16", "32", "4x64"}
     # leaves: on the engine's thread no phase begins inside another
     spans = sorted((s, s + d) for evs in events.values() for s, d, _ in evs)
     assert all(b[0] >= a[1] - 1 for a, b in zip(spans, spans[1:]))
