@@ -2095,6 +2095,9 @@ class InferenceEngine:
                 "warmup_programs": dict(self.startup["warmup_programs"]),
             },
         }
+        if self.runner.moe is not None:
+            # what the experts saw (the target runner's; absent for a dense model)
+            s["moe"] = {kind: dict(acc) for kind, acc in self.runner.moe.items()}
         if self.spec is not None:
             prop, acc = self._spec_proposed, self._spec_accepted
             s["speculative"] = {

@@ -20,6 +20,12 @@ compiles every (batch bucket x rung) pair, so a batch that crosses a rung
 in either direction finds its program compiled; the cache argument has
 the same shape in all of them. ``decode_width`` counts what was chosen.
 
+A MoE config's steps return a third output, the expert loads
+``[n_layers, E]`` of the launch's real rows. It is copied to the host with
+the logits, inside ``readback`` (the device has finished by then: no second
+sync), and summed into ``moe`` (:meth:`_account_moe`). A dense config's
+steps have two outputs and no such account.
+
 The device cache lives here as functional state: every step donates the
 cache buffer (``donate_argnums``) and returns the new value, and the
 runner swaps its reference. Donation is unconditional — the CPU backend
@@ -138,6 +144,14 @@ class PagedModelRunner:
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
         )
+        #: MoE configs only: what the experts saw, as running sums over
+        #: decode and verify launches and, apart, prefill launches
+        #: (:meth:`_account_moe`); ``None`` for a dense model
+        self.moe: Optional[Dict[str, Dict[str, float]]] = None
+        if cfg.moe_experts > 0:
+            keys = ("launches", "assignments", "expert_slots", "experts_touched",
+                    "max_load", "mean_load")
+            self.moe = {kind: dict.fromkeys(keys, 0) for kind in ("decode", "prefill")}
         self.warmup_programs: Dict[str, float] = {}
 
         # argument 1 of the partials (cfg is bound) is the cache: donated,
@@ -214,7 +228,7 @@ class PagedModelRunner:
                 )
         return out
 
-    def _warm(self, program: str, bucket, fn, *args):
+    def _warm(self, program: str, fn, *args, bucket=None):
         """One warm-up call, timed to the end of its first run."""
         import jax
 
@@ -223,6 +237,13 @@ class PagedModelRunner:
         label = program if bucket is None else f"{program}[{bucket}]"
         self.warmup_programs[label] = time.perf_counter() - t0
         return out
+
+    def _step(self, run, program: str, fn, *args):
+        """One paged step through ``run`` (:meth:`_run` or :meth:`_warm`):
+        keeps the new cache and returns ``(logits, loads)``, both still on
+        the device; ``loads`` is None for a dense model."""
+        self.cache, logits, *loads = run(program, fn, self.params, self.cache, *args)
+        return logits, (loads[0] if loads else None)
 
     def compile_count(self) -> int:
         return self._jit_cache_entries()
@@ -244,17 +265,16 @@ class PagedModelRunner:
         for c in buckets_prefill if buckets_prefill is not None else self.prefill_buckets:
             tokens = np.zeros(c, np.int32)
             row = np.zeros(M, np.int32)
-            self.cache, _ = self._warm(
-                "paged_prefill_step", c, self._prefill_jit,
-                self.params, self.cache, tokens, row, np.int32(0), np.int32(0)
+            self._step(
+                partial(self._warm, bucket=c), "paged_prefill_step", self._prefill_jit,
+                tokens, row, np.int32(0), np.int32(0),
             )
         batches = buckets_decode if buckets_decode is not None else self.decode_buckets
         for b in batches:
             for w in self.table_widths:
-                self.cache, _ = self._warm(
-                    "paged_decode_step", f"{b}x{w * bs}", self._decode_jit,
-                    self.params,
-                    self.cache,
+                self._step(
+                    partial(self._warm, bucket=f"{b}x{w * bs}"),
+                    "paged_decode_step", self._decode_jit,
                     np.zeros(b, np.int32),
                     np.zeros(b, np.int32),
                     np.zeros((b, w), np.int32),
@@ -268,10 +288,9 @@ class PagedModelRunner:
         for c in self.verify_buckets:
             for b in batches:
                 for w in self.table_widths:
-                    self.cache, _ = self._warm(
-                        "paged_verify_step", f"{b}x{c}x{w * bs}", self._verify_jit,
-                        self.params,
-                        self.cache,
+                    self._step(
+                        partial(self._warm, bucket=f"{b}x{c}x{w * bs}"),
+                        "paged_verify_step", self._verify_jit,
                         np.zeros((b, c), np.int32),
                         np.zeros((b, w), np.int32),
                         np.zeros(b, np.int32),
@@ -281,15 +300,15 @@ class PagedModelRunner:
         # trash back onto itself)
         pad = np.zeros(_COW_WIDTH, np.int32)
         self.cache = self._warm(
-            "copy_paged_blocks", None, self._copy_jit, self.cache, pad, pad
+            "copy_paged_blocks", self._copy_jit, self.cache, pad, pad
         )
         if kv_io:
             ids = np.zeros(_KV_IO_WIDTH, np.int32)
             kv = np.asarray(
-                self._warm("gather_paged_blocks", None, self._gather_jit, self.cache, ids)
+                self._warm("gather_paged_blocks", self._gather_jit, self.cache, ids)
             )
             self.cache = self._warm(
-                "scatter_paged_blocks", None, self._scatter_jit, self.cache, ids, kv
+                "scatter_paged_blocks", self._scatter_jit, self.cache, ids, kv
             )
         self.mark_warm()
 
@@ -364,21 +383,42 @@ class PagedModelRunner:
             padded = np.zeros(bucket, np.int32)
             padded[:true_len] = tokens
             row = np.asarray(block_row, np.int32)
-            self.cache, logits = self._run(
-                "paged_prefill_step", self._prefill_jit,
-                self.params, self.cache, padded, row,
-                np.int32(ctx_len), np.int32(true_len),
+            out = self._step(
+                self._run, "paged_prefill_step", self._prefill_jit,
+                padded, row, np.int32(ctx_len), np.int32(true_len),
             )
-        return self._read(logits, clock)
+        return self._read(out, clock, "prefill")
 
-    @staticmethod
-    def _read(logits, clock: timeline.PhaseClock) -> np.ndarray:
+    def _read(self, out, clock: timeline.PhaseClock, kind: str) -> np.ndarray:
         """Wait for a step's logits, then copy them to the host: two
-        phases, so that the device's time is told from the copy's."""
+        phases, so that the device's time is told from the copy's. A MoE
+        step's expert loads come over in the same ``readback`` and go into
+        the ``kind`` (``decode`` or ``prefill``) half of :attr:`moe`."""
+        logits, loads = out
         with clock.phase("device_wait"):
             logits.block_until_ready()
         with clock.phase("readback"):
-            return np.asarray(logits)
+            host = np.asarray(logits)
+            if loads is not None:
+                self._account_moe(kind, np.asarray(loads))
+        return host
+
+    def _account_moe(self, kind: str, loads: np.ndarray) -> None:
+        """Add one launch's expert loads ``[n_layers, E]`` (assignments of
+        its real rows per layer and expert) to the running sums: every
+        field but ``launches`` is a sum over launch AND layer.
+        ``assignments`` = real rows x top_k x layers; ``expert_slots`` =
+        layers x E; ``experts_touched`` = slots with a load above 0 (what
+        sets the expert bytes a launch reads); ``max_load`` / ``mean_load``
+        = the largest and the mean load of a layer (their ratio is the
+        imbalance a grouped matmul pays for)."""
+        acc = self.moe[kind]
+        acc["launches"] += 1
+        acc["assignments"] += int(loads.sum())
+        acc["expert_slots"] += loads.size
+        acc["experts_touched"] += int(np.count_nonzero(loads))
+        acc["max_load"] += int(loads.max(axis=1).sum())
+        acc["mean_load"] += float(loads.mean(axis=1).sum())
 
     def _table_width(self, ctx_lens: Sequence[int], bucket: int) -> int:
         """The width, in blocks, at which this decode or verify launch
@@ -432,11 +472,10 @@ class PagedModelRunner:
                 tables[i] = block_rows[i][:M]
                 ctx[i] = ctx_lens[i]
                 tl[i] = len(w)
-            self.cache, logits = self._run(
-                "paged_verify_step", self._verify_jit,
-                self.params, self.cache, tokens, tables, ctx, tl,
+            out = self._step(
+                self._run, "paged_verify_step", self._verify_jit, tokens, tables, ctx, tl
             )
-        out = self._read(logits, clock)
+        out = self._read(out, clock, "decode")
         return [out[i, : len(w)] for i, w in enumerate(windows)]
 
     def decode(
@@ -470,8 +509,7 @@ class PagedModelRunner:
             p[:n] = positions
             bt[:n] = np.asarray([row[:M] for row in block_rows], np.int32)
             cl[:n] = ctx_lens
-            self.cache, logits = self._run(
-                "paged_decode_step", self._decode_jit,
-                self.params, self.cache, t, p, bt, cl,
+            out = self._step(
+                self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl
             )
-        return self._read(logits, clock)[:n]
+        return self._read(out, clock, "decode")[:n]

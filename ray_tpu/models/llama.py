@@ -46,10 +46,21 @@ class LlamaConfig:
     #: "ring" | "ulysses" (sequence-parallel over the mesh's seq axis —
     #: pass the mesh to ``forward``/``make_train_step``)
     attention_impl: str = "auto"
-    #: >0 turns every MLP block into a MoE FFN with this many experts
-    #: (expert dim shards over the ``expert`` mesh axis — see ops/moe.py)
+    #: RMS-normalise the q and the k projection, each over its WHOLE width
+    #: (all heads together), before the split into heads and the rotary
+    #: embedding (OLMoE, OLMo-2); adds ``q_norm`` / ``k_norm`` to a layer
+    qk_norm: bool = False
+    #: >0 turns every MLP block into a MoE FFN with this many experts of
+    #: width ``mlp_hidden`` (see ops/moe.py: dropless and grouped by expert
+    #: wherever the experts live on one device; GShard dense dispatch over
+    #: an ``expert`` mesh axis larger than 1)
     moe_experts: int = 0
     moe_top_k: int = 2
+    #: whether the ``moe_top_k`` kept gates are divided by their sum
+    #: (Mixtral, GShard) or stay the softmax's own values (OLMoE)
+    moe_renormalize: bool = True
+    #: read by the expert-parallel path (``ops.moe.moe_ffn``) ALONE: the
+    #: dropless path has no capacity and drops nothing
     moe_capacity_factor: float = 1.25
     moe_aux_loss_coeff: float = 0.01
 
@@ -109,6 +120,9 @@ def _layer_shapes(cfg: LlamaConfig) -> Dict[str, Tuple[int, ...]]:
                 "w_down": (cfg.mlp_hidden, cfg.dim),
             }
         )
+    if cfg.qk_norm:
+        # last, so that the other weights of a layer draw the same keys
+        shapes.update({"q_norm": (cfg.n_heads * hd,), "k_norm": (cfg.n_kv_heads * hd,)})
     return shapes
 
 
@@ -134,6 +148,8 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
                 "w_down": ("mlp", "embed"),
             }
         )
+    if cfg.qk_norm:
+        layer.update({"q_norm": (None,), "k_norm": (None,)})
     return {
         "embed": ("vocab", "embed"),
         "layers": [dict(layer) for _ in range(cfg.n_layers)],
@@ -203,7 +219,7 @@ def partition_rules(cfg: LlamaConfig, rules) -> list:
         # replicated by NAME, in front of the param rules
         (r"(^|/)v_(row|col)(/|$)", sp((None,))),
         (r"(^|/)embed$", sp(("vocab", "embed"))),
-        (r"(attn_norm|mlp_norm|final_norm)$", sp((None,))),
+        (r"(attn_norm|mlp_norm|final_norm|q_norm|k_norm)$", sp((None,))),
         (r"wq$", sp(("embed", "heads", "head_dim"))),
         (r"(wk|wv)$", sp(("embed", "kv_heads", "head_dim"))),
         (r"wo$", sp(("heads", "head_dim", "embed"))),
@@ -268,12 +284,65 @@ def _axis_size(mesh, axes) -> int:
     return math.prod(shape[a] for a in names)
 
 
+def _qkv(cfg: LlamaConfig, p, h):
+    """The q, k, v projections of one block on normed activations
+    ``h [..., D]``: ``(q [..., H, hd], k [..., KV, hd], v [..., KV, hd])``,
+    before the rotary embedding. With ``cfg.qk_norm`` q and k are
+    RMS-normalised over their whole projection (all heads together), as
+    ``modeling_olmoe`` does, not per head."""
+    q = jnp.einsum("...d,dhk->...hk", h, p["wq"])
+    k = jnp.einsum("...d,dhk->...hk", h, p["wk"])
+    v = jnp.einsum("...d,dhk->...hk", h, p["wv"])
+    if cfg.qk_norm:
+        lead = h.shape[:-1]
+        q = rms_norm(q.reshape(*lead, -1), p["q_norm"], cfg.norm_eps).reshape(q.shape)
+        k = rms_norm(k.reshape(*lead, -1), p["k_norm"], cfg.norm_eps).reshape(k.shape)
+    return q, k, v
+
+
+def _expert_parallel(mesh) -> bool:
+    from ray_tpu.parallel.mesh import EXPERT
+
+    return mesh is not None and EXPERT in mesh.axis_names and _axis_size(mesh, EXPERT) > 1
+
+
+def _ffn(cfg: LlamaConfig, p, h, valid=None, mesh=None, rules=None):
+    """The FFN of one block on normed activations ``h [..., D]``: returns
+    ``(ffn(h) [..., D], aux)``, the residual not added. Dense: the gated
+    SiLU MLP, ``aux`` None. MoE: ``aux["aux_loss"]`` and, on the dropless
+    path, ``aux["load"]`` ``[E]`` int32. ``valid [...]`` bool marks the
+    real rows of a padded serving step: padding rows reach no expert and
+    are not counted (the dense MLP is row-wise and needs no mask).
+
+    ``perfbench/families/olmoe/server.py`` calls this by name: the expert
+    FFN's own correctness reading runs what the steps run."""
+    if cfg.moe_experts > 0:
+        from ray_tpu.ops.moe import dropless_moe_ffn, moe_ffn
+
+        experts = {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
+        if _expert_parallel(mesh):
+            return moe_ffn(
+                experts, h, top_k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
+                capacity_factor=cfg.moe_capacity_factor,
+            )
+        out, aux = dropless_moe_ffn(
+            experts, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k,
+            renormalize=cfg.moe_renormalize,
+            valid=None if valid is None else valid.reshape(-1),
+        )
+        return out.reshape(h.shape), aux
+    gate = jnp.einsum("...d,dm->...m", h, p["w_gate"])
+    up = jnp.einsum("...d,dm->...m", h, p["w_up"])
+    # Megatron split: the hidden activation shards over tensor, the
+    # down-projection's output all-reduces back to the replicated stream
+    gate = constrain(gate, mesh, rules, ("act_batch", "act_seq", "act_mlp"))
+    up = constrain(up, mesh, rules, ("act_batch", "act_seq", "act_mlp"))
+    return jnp.einsum("...m,md->...d", jax.nn.silu(gate) * up, p["w_down"]), None
+
+
 def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
     B, S, _ = x.shape
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, p["wv"])
+    q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
     # attention ENTRY pin: q/k/v leave the projection in the head-sharded
     # layout the attention impl expects (ring attention's shard_map specs
     # are exactly these) — without it GSPMD picks per-op and the bwd
@@ -343,33 +412,17 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
 
 
 def _mlp_block(cfg: LlamaConfig, p, x, mesh=None, rules=None):
-    """Dense or MoE FFN. Returns (x, aux_loss)."""
+    """Dense or MoE FFN with its norm and residual. Returns (x, aux_loss)."""
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.moe_experts > 0:
-        from ray_tpu.ops.moe import moe_ffn
-
         # entry/exit pins bracket the expert compute (interior shardings
         # over the ``expert`` axis are moe_ffn's own business) so the
         # MoE FFN keeps the same replicated-residual contract as the
         # dense branch and fwd/bwd agree across the remat boundary
         h = constrain(h, mesh, rules, ("act_batch", "act_seq", "act_embed"))
-        out, aux = moe_ffn(
-            {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")},
-            h,
-            top_k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity_factor,
-        )
-        out = x + out
-        out = constrain(out, mesh, rules, ("act_batch", "act_seq", "act_embed"))
-        return out, aux["aux_loss"]
-    gate = jnp.einsum("bsd,dm->bsm", h, p["w_gate"])
-    up = jnp.einsum("bsd,dm->bsm", h, p["w_up"])
-    # Megatron split: the hidden activation shards over tensor, the
-    # down-projection's output all-reduces back to the replicated stream
-    gate = constrain(gate, mesh, rules, ("act_batch", "act_seq", "act_mlp"))
-    up = constrain(up, mesh, rules, ("act_batch", "act_seq", "act_mlp"))
-    out = x + jnp.einsum("bsm,md->bsd", jax.nn.silu(gate) * up, p["w_down"])
-    return constrain(out, mesh, rules, ("act_batch", "act_seq", "act_embed")), 0.0
+    out, aux = _ffn(cfg, p, h, mesh=mesh, rules=rules)
+    out = constrain(x + out, mesh, rules, ("act_batch", "act_seq", "act_embed"))
+    return out, 0.0 if aux is None else aux["aux_loss"]
 
 
 def _remat_policy(remat):
@@ -609,6 +662,24 @@ def _scatter_kv(cache, layer: int, blk, off, k, v):
     }
 
 
+def _ffn_residual(cfg: LlamaConfig, p, x, valid, loads: list):
+    """``x + ffn(norm(x))`` of one block in a paged step; a MoE block's
+    expert load of the ``valid`` rows is appended to ``loads``."""
+    out, aux = _ffn(cfg, p, rms_norm(x, p["mlp_norm"], cfg.norm_eps), valid)
+    if aux is not None:
+        loads.append(aux["load"])
+    return x + out
+
+
+def _step_outputs(cache, logits, loads: list):
+    """What a paged step returns: ``(cache, logits)``, and for a MoE
+    config a third output, the expert loads ``[n_layers, E]`` int32 of the
+    step's valid rows (the runner reads them with the logits)."""
+    if loads:
+        return cache, logits, jnp.stack(loads)
+    return cache, logits
+
+
 def paged_prefill_step(
     cfg: LlamaConfig, params, cache, tokens, block_table, ctx_len, true_len
 ):
@@ -619,10 +690,10 @@ def paged_prefill_step(
     prefill: >0 from the second chunk on), true_len: scalar int32 valid
     tokens in this chunk. Writes the chunk's K/V into the cache, attends
     causally over cached-context + chunk, and returns
-    ``(cache, logits[vocab])`` for the chunk's last valid token.
+    ``(cache, logits[vocab])`` for the chunk's last valid token; a MoE
+    config adds the expert loads of the valid rows (``_step_outputs``).
+    Padding rows (``idx >= true_len``) reach no expert.
     """
-    if cfg.moe_experts > 0:
-        raise NotImplementedError("paged decode does not support MoE FFNs yet")
     C = tokens.shape[0]
     M = block_table.shape[0]
     bs = cache["k"].shape[2]
@@ -640,11 +711,9 @@ def paged_prefill_step(
     mask = key_pos[None, :] <= pos[:, None]  # [C, M*bs]
 
     x = params["embed"][tokens]  # [C, D]
+    loads = []
     for layer, p in enumerate(params["layers"]):
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("cd,dhk->chk", h, p["wq"])
-        k = jnp.einsum("cd,dhk->chk", h, p["wk"])
-        v = jnp.einsum("cd,dhk->chk", h, p["wv"])
+        q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
         cache = _scatter_kv(cache, layer, blk, off, k, v)
@@ -658,14 +727,11 @@ def paged_prefill_step(
         o = jnp.einsum("cgrs,sgh->cgrh", pattn.astype(vs.dtype), vs)
         o = o.reshape(C, cfg.n_heads, -1)
         x = x + jnp.einsum("chk,hkd->cd", o.astype(x.dtype), p["wo"])
-        hm = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        gate = jnp.einsum("cd,dm->cm", hm, p["w_gate"])
-        up = jnp.einsum("cd,dm->cm", hm, p["w_up"])
-        x = x + jnp.einsum("cm,md->cd", jax.nn.silu(gate) * up, p["w_down"])
+        x = _ffn_residual(cfg, p, x, valid, loads)
     last = jnp.maximum(true_len - 1, 0)
     h_last = rms_norm(x[last], params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("d,dv->v", h_last, params["lm_head"]).astype(jnp.float32)
-    return cache, logits
+    return _step_outputs(cache, logits, loads)
 
 
 def paged_verify_step(
@@ -682,7 +748,8 @@ def paged_verify_step(
     already cached per slot, true_lens: [B] int32 valid window lengths
     (0 for padding slots: every position masks invalid, writes land on
     the null block). Returns logits for EVERY window position,
-    ``(cache, logits [B, C, vocab])``, so the host accepts or rejects
+    ``(cache, logits [B, C, vocab])`` (and a MoE config's expert loads,
+    ``_step_outputs``), so the host accepts or rejects
     each drafted token independently — B slots verify k+1 positions each
     in ONE step, where plain decode would spend B*(k+1) batched steps.
 
@@ -691,8 +758,6 @@ def paged_verify_step(
     prefill/verify on ``key_pos <= pos``, so nothing past the committed
     context is ever read, and re-verification overwrites in place).
     """
-    if cfg.moe_experts > 0:
-        raise NotImplementedError("paged decode does not support MoE FFNs yet")
     B, C = tokens.shape
     M = block_tables.shape[1]
     bs = cache["k"].shape[2]
@@ -715,11 +780,9 @@ def paged_verify_step(
     mask = key_pos[None, None, :] <= pos[:, :, None]  # [B, C, M*bs]
 
     x = params["embed"][tokens]  # [B, C, D]
+    loads = []
     for layer, p in enumerate(params["layers"]):
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bcd,dhk->bchk", h, p["wq"])
-        k = jnp.einsum("bcd,dhk->bchk", h, p["wk"])
-        v = jnp.einsum("bcd,dhk->bchk", h, p["wv"])
+        q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
         hd = q.shape[-1]
         q = _apply_rope_flat(q.reshape(B * C, cfg.n_heads, hd), cos, sin)
         k = _apply_rope_flat(k.reshape(B * C, cfg.n_kv_heads, hd), cos, sin)
@@ -737,12 +800,10 @@ def paged_verify_step(
         o = jnp.einsum("bcgrs,bsgh->bcgrh", pattn.astype(vs.dtype), vs)
         o = o.reshape(B, C, cfg.n_heads, -1)
         x = x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
-        hm = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        gate = jnp.einsum("bcd,dm->bcm", hm, p["w_gate"])
-        up = jnp.einsum("bcd,dm->bcm", hm, p["w_up"])
-        x = x + jnp.einsum("bcm,md->bcd", jax.nn.silu(gate) * up, p["w_down"])
+        x = _ffn_residual(cfg, p, x, valid, loads)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return cache, jnp.einsum("bcd,dv->bcv", x, params["lm_head"]).astype(jnp.float32)
+    logits = jnp.einsum("bcd,dv->bcv", x, params["lm_head"]).astype(jnp.float32)
+    return _step_outputs(cache, logits, loads)
 
 
 def paged_decode_step(
@@ -755,10 +816,10 @@ def paged_decode_step(
     int32 (visible context length INCLUDING this token = positions+1 for
     active slots; inactive padding slots carry ctx_len=1 and null blocks
     so the softmax stays finite). Writes K/V, returns
-    ``(cache, logits [B, vocab])``.
+    ``(cache, logits [B, vocab])``, and for a MoE config the expert loads
+    of the active slots (``_step_outputs``): a slot whose K/V write lands
+    on the null block is padding and reaches no expert.
     """
-    if cfg.moe_experts > 0:
-        raise NotImplementedError("paged decode does not support MoE FFNs yet")
     B, M = block_tables.shape
     bs = cache["k"].shape[2]
     rep = cfg.n_heads // cfg.n_kv_heads
@@ -771,12 +832,12 @@ def paged_decode_step(
     key_pos = jnp.arange(M * bs, dtype=jnp.int32)
     mask = key_pos[None, :] < ctx_lens[:, None]  # [B, M*bs]
 
+    # a slot whose token is written to the null block is padding
+    valid = blk != 0
     x = params["embed"][tokens]  # [B, D]
+    loads = []
     for layer, p in enumerate(params["layers"]):
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bd,dhk->bhk", h, p["wq"])
-        k = jnp.einsum("bd,dhk->bhk", h, p["wk"])
-        v = jnp.einsum("bd,dhk->bhk", h, p["wv"])
+        q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
         cache = _scatter_kv(cache, layer, blk, off, k, v)
@@ -789,12 +850,10 @@ def paged_decode_step(
         o = jnp.einsum("bgrs,bsgh->bgrh", pattn.astype(vs.dtype), vs)
         o = o.reshape(B, cfg.n_heads, -1)
         x = x + jnp.einsum("bhk,hkd->bd", o.astype(x.dtype), p["wo"])
-        hm = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        gate = jnp.einsum("bd,dm->bm", hm, p["w_gate"])
-        up = jnp.einsum("bd,dm->bm", hm, p["w_up"])
-        x = x + jnp.einsum("bm,md->bd", jax.nn.silu(gate) * up, p["w_down"])
+        x = _ffn_residual(cfg, p, x, valid, loads)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return cache, jnp.einsum("bd,dv->bv", x, params["lm_head"]).astype(jnp.float32)
+    logits = jnp.einsum("bd,dv->bv", x, params["lm_head"]).astype(jnp.float32)
+    return _step_outputs(cache, logits, loads)
 
 
 def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = True,

@@ -1,16 +1,32 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Mixture-of-Experts FFNs: one routing function, two ways to run the experts.
 
-Reference: absent (SURVEY §2.4 — EP is a build-new item). Design is the
-GSPMD dense-dispatch recipe (Switch/GShard): top-k routing produces a
-capacity-limited one-hot dispatch tensor; dispatch/combine are einsums,
-expert FFNs run batched over the expert dim, and sharding the expert
-dim over the ``expert`` mesh axis makes XLA insert the all-to-alls over
-ICI — no hand-written collectives (scaling-book recipe).
+:func:`route` is the one routing of every MoE path: float32 router logits,
+softmax over the experts, ``top_k``, and the kept gates renormalised only
+where the architecture says so (``renormalize``; OLMoE does not).
 
-Capacity semantics: each expert processes at most
-``capacity = ceil(tokens/experts * capacity_factor)`` tokens; overflow
-tokens pass through unchanged (their combine weight is zero) — the
-standard Switch Transformer drop policy."""
+:func:`dropless_moe_ffn` is what runs wherever the experts live on one
+device (serving's three paged steps, ``forward`` without an ``expert`` mesh
+axis): the ``T x k`` assignments are flattened, sorted by expert, pushed
+through the three expert matmuls GROUPED by expert (:func:`grouped_matmul`:
+on a TPU the Pallas grouped matmul of ``megablox``, elsewhere
+``jax.lax.ragged_dot``), unsorted, weighted by the gates and summed over
+``k``. No capacity, no dropped token, no ``[T, E, C]`` tensor; a ``valid``
+row mask keeps padding rows out of every group, so what shares a batch
+with a request cannot change its answer. It returns the per-expert load of
+the valid rows.
+
+:func:`moe_ffn` is the expert-PARALLEL path (training over an ``expert``
+mesh axis larger than 1). Reference: absent (SURVEY §2.4 — EP is a
+build-new item). Design is the GSPMD dense-dispatch recipe (Switch/GShard):
+the routing produces a capacity-limited one-hot dispatch tensor;
+dispatch/combine are einsums, expert FFNs run batched over the expert dim,
+and sharding the expert dim over the ``expert`` mesh axis makes XLA insert
+the all-to-alls over ICI — no hand-written collectives (scaling-book
+recipe). Capacity semantics: each expert processes at most
+``capacity = ceil(top_k * tokens / experts * capacity_factor)``
+assignments; overflow assignments are dropped (their combine weight is
+zero and the token falls back to the residual stream) — the standard
+Switch Transformer drop policy, which only this path has."""
 
 from __future__ import annotations
 
@@ -51,20 +67,140 @@ def moe_logical_axes() -> Dict[str, Tuple[Optional[str], ...]]:
     }
 
 
+def route(
+    router: jnp.ndarray,
+    x: jnp.ndarray,
+    *,
+    top_k: int,
+    renormalize: bool,
+    router_noise: float = 0.0,
+    rng: Optional[jax.Array] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The routing of every MoE path. x: [T, d], router: [d, E] →
+    ``(gates [T, k] float32, experts [T, k] int32, probs [T, E] float32)``.
+
+    Router logits, softmax and ``top_k`` are float32 at the matmul's
+    highest precision: the 8th and 9th expert of a token can be close, and
+    a TPU's default precision would round the products to bfloat16.
+    ``renormalize`` divides the kept gates by their sum (Mixtral, GShard);
+    without it the gates are the softmax's own values (OLMoE:
+    ``norm_topk_prob`` false)."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )  # [T, E]
+    if router_noise > 0.0 and rng is not None:
+        logits = logits + router_noise * jax.random.normal(rng, logits.shape)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)  # [T, k]
+    if renormalize:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, experts.astype(jnp.int32), probs
+
+
+def load_balance_loss(probs: jnp.ndarray, experts: jnp.ndarray) -> jnp.ndarray:
+    """Switch load-balance aux loss ``E * sum_e f_e * p_e``: mean router
+    probability per expert times the fraction of tokens whose FIRST choice
+    it is."""
+    E = probs.shape[-1]
+    first = jax.nn.one_hot(experts[:, 0], E, dtype=jnp.float32).mean(axis=0)
+    return E * jnp.sum(probs.mean(axis=0) * first)
+
+
+def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """``xs [m, k]`` (rows sorted by group) times ``w [g, k, n]``, each row
+    against its group's matrix → ``[m, n]``; ``group_sizes [g]`` int32 may
+    sum to less than ``m`` (what the rows behind the last group hold is
+    unspecified).
+
+    On a TPU: the Pallas grouped matmul ``megablox.gmm`` (device operations
+    ``gmm.N``) at the tiling read on the chip at OLMoE's widths (PERF.md,
+    PR 27): against ``jax.lax.ragged_dot``'s own Mosaic kernel 1.09 against
+    1.63 ms a layer's three matmuls at 4 rows an expert and 1.86 against
+    3.15 ms at 128, where an ``m`` tile of 256 beat 128 (2.08). ``gmm``
+    wants ``m`` in whole tiles: the rows are padded up to one (behind the
+    last group, in none), so every bucket runs the one kernel (64 rows,
+    one matmul: 0.36 against 0.42 ms). Elsewhere,
+    and for widths that are not whole lanes: ``jax.lax.ragged_dot``."""
+    m, k = xs.shape
+    n = w.shape[2]
+    if jax.default_backend() == "tpu" and k % 128 == 0 and n % 128 == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        tm = 256 if m >= 4096 else 128
+        out = gmm(
+            jnp.pad(xs, ((0, -m % tm), (0, 0))), w, group_sizes,
+            preferred_element_type=xs.dtype, tiling=(tm, min(k, 1024), min(n, 1024)),
+        )
+        return out[:m]
+    return jax.lax.ragged_dot(xs, w, group_sizes)
+
+
+def dropless_moe_ffn(
+    params: Dict[str, Any],
+    x: jnp.ndarray,
+    *,
+    top_k: int,
+    renormalize: bool,
+    valid: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """x: [T, d] → (out [T, d], aux) with every valid row through all
+    ``top_k`` of its experts: none dropped, no capacity.
+
+    ``valid``: [T] bool, absent = all. A row that is not valid is in no
+    expert's group (its assignments sort behind the last group), costs no
+    expert FLOPs beyond the grouped matmul's own tile padding, comes back
+    as zeros and is not counted. ``aux``: ``load`` [E] int32 (assignments
+    of the valid rows per expert; sums to ``valid.sum() * top_k``) and
+    ``aux_loss`` (Switch load-balance loss over ALL rows' routing: a
+    training regulariser, and training has no padding rows)."""
+    T, d = x.shape
+    E = params["router"].shape[1]
+    with jax.named_scope("moe.route"):
+        gates, experts, probs = route(
+            params["router"], x, top_k=top_k, renormalize=renormalize
+        )
+    with jax.named_scope("moe.dispatch"):
+        flat = experts.reshape(T * top_k)
+        if valid is not None:
+            # expert id E: behind every group, in none
+            flat = jnp.where(jnp.repeat(valid, top_k), flat, E)
+        order = jnp.argsort(flat, stable=True)  # assignment ids, by expert
+        load = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+        xs = x[order // top_k]  # [T*k, d]: each token's row, once per expert
+    with jax.named_scope("moe.experts"):
+        gate = grouped_matmul(xs, params["w_gate"], load)
+        up = grouped_matmul(xs, params["w_up"], load)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, params["w_down"], load)
+    with jax.named_scope("moe.combine"):
+        y = jnp.zeros_like(ys).at[order].set(ys).reshape(T, top_k, d)
+        out = jnp.einsum(
+            "tkd,tk->td", y, gates.astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        ).astype(x.dtype)
+        if valid is not None:
+            # all k assignments of a padding row lie behind the last group:
+            # whatever the grouped matmul left there is replaced, not scaled
+            out = jnp.where(valid[:, None], out, 0)
+    return out, {"load": load, "aux_loss": load_balance_loss(probs, experts)}
+
+
 def moe_ffn(
     params: Dict[str, Any],
     x: jnp.ndarray,
     *,
     top_k: int = 2,
+    renormalize: bool = True,
     capacity_factor: float = 1.25,
     router_noise: float = 0.0,
     rng: Optional[jax.Array] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """x: [B, S, d] → (out [B, S, d], aux dict with load-balance loss).
+    """The expert-parallel path. x: [B, S, d] → (out [B, S, d], aux dict
+    with load-balance loss).
 
     Dense dispatch: one-hot [T, E, C] tensors route tokens to expert
     slots; dropped (over-capacity) tokens contribute zero and fall back
-    to the residual stream."""
+    to the residual stream. ``renormalize``: as in :func:`route`."""
     B, S, d = x.shape
     E = params["router"].shape[1]
     T = B * S
@@ -74,15 +210,10 @@ def moe_ffn(
     capacity = max(1, int(math.ceil(top_k * T / E * capacity_factor)))
 
     xt = x.reshape(T, d)
-    logits = (xt.astype(jnp.float32) @ params["router"])  # [T, E]
-    if router_noise > 0.0 and rng is not None:
-        logits = logits + router_noise * jax.random.normal(rng, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    # top-k expert choices per token
-    gate_vals, expert_idx = jax.lax.top_k(probs, top_k)  # [T, k]
-    # renormalize the kept gates
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, expert_idx, probs = route(
+        params["router"], xt, top_k=top_k, renormalize=renormalize,
+        router_noise=router_noise, rng=rng,
+    )
 
     # per-(token, choice) slot position within the chosen expert, by
     # arrival order: cumsum of one-hot over the flattened (T*k) axis
@@ -109,9 +240,7 @@ def moe_ffn(
 
     out = jnp.einsum("tec,ecd->td", combine, expert_out.astype(jnp.float32))
     out = out.reshape(B, S, d).astype(x.dtype)
-
-    # Switch load-balance aux loss: E * sum_e f_e * p_e
-    me = probs.mean(axis=0)  # mean router prob per expert
-    ce = choice_onehot[:, 0, :].astype(jnp.float32).mean(axis=0)  # top-1 fraction
-    aux_loss = E * jnp.sum(me * ce)
-    return out, {"aux_loss": aux_loss, "dropped_fraction": 1.0 - kept.astype(jnp.float32).mean()}
+    return out, {
+        "aux_loss": load_balance_loss(probs, expert_idx),
+        "dropped_fraction": 1.0 - kept.astype(jnp.float32).mean(),
+    }

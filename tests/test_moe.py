@@ -1,5 +1,8 @@
 """MoE / expert parallelism (SURVEY §2.4 build-new: EP over the
-``expert`` mesh axis with GSPMD-inserted all-to-alls)."""
+``expert`` mesh axis with GSPMD-inserted all-to-alls): ``ops.moe.moe_ffn``,
+the capacity-limited GShard path that training over an ``expert`` axis
+larger than 1 takes. The dropless path of serving and of ``forward`` on one
+device is in ``tests/test_olmoe.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +45,7 @@ def test_moe_matches_reference_when_uncapped():
 
 
 def test_moe_capacity_drops_overflow():
+    """Capacity exists on the expert-parallel path alone."""
     rng = jax.random.PRNGKey(0)
     params = init_moe_params(rng, dim=8, hidden=16, num_experts=2)
     # force every token to expert 0: positive inputs x biased router

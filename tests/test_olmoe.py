@@ -1,0 +1,415 @@
+"""The OLMoE block on the normal path, at toy sizes on the CPU in float32
+with seeded weights: QK-norm and the dropless top-k experts in ``forward``
+and in the three paged steps, against the plain reference of the benchmark's
+``olmoe`` family (``perfbench/families/olmoe/reference.py``: a loop over the
+experts with a mask each, no sort, no cache, nothing of the program).
+
+Tolerances. Both sides compute in float32 from the same weights; they differ
+in the order of summation (grouped by expert and summed over k here, summed
+over experts there; paged attention over a padded table here, a full causal
+softmax there). Logits are O(1); 2e-4 of the largest reference logit is some
+hundred float32 roundings through two layers and a thousand times tighter
+than what leaving out a gate's renormalisation, a norm or an expert moves
+(the last three tests of this file read 1e-2 and more)."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "tests", "perfbench"))
+
+import olmoe_controls as controls  # noqa: E402 - the reference's twin with the wrong models
+from perfbench.families.olmoe import reference  # noqa: E402
+from ray_tpu.inference.model_runner import PagedModelRunner  # noqa: E402
+from ray_tpu.models import llama as L  # noqa: E402
+from ray_tpu.ops.moe import dropless_moe_ffn, init_moe_params, moe_ffn, route  # noqa: E402
+
+REL_TOL = 2e-4
+
+MODEL = {  # the reference reads these keys of a configuration file
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-5, "num_experts_per_tok": 2, "vocab_size": 256,
+}
+
+
+def _cfg(**overrides):
+    base = dict(
+        mlp_hidden=32, max_seq_len=128, qk_norm=True, moe_experts=4, moe_top_k=2,
+        moe_renormalize=False,
+    )
+    base.update(overrides)
+    return L.LlamaConfig.tiny(**base)
+
+
+def _params(cfg, seed=0):
+    """Seeded weights; the norm vectors are drawn too (``init_params`` sets
+    them to 1, under which a forgotten norm WEIGHT would pass)."""
+    params = L.init_params(cfg, jax.random.PRNGKey(seed))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 4 * cfg.n_layers + 1))
+    for p in params["layers"]:
+        for name in [n for n in p if n.endswith("norm")]:
+            p[name] = 1.0 + 0.3 * jax.random.normal(next(keys), p[name].shape, jnp.float32)
+    return params
+
+
+def _rel(have, want):
+    return float(np.max(np.abs(np.asarray(have) - np.asarray(want))) / np.max(np.abs(want)))
+
+
+def _tokens(seed, shape):
+    return np.random.default_rng(seed).integers(1, 256, size=shape).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One toy OLMoE runner with its weights, warmed."""
+    cfg = _cfg()
+    params = _params(cfg)
+    runner = PagedModelRunner(
+        cfg, params, num_blocks=64, block_size=8, prefill_buckets=(16, 32),
+        decode_buckets=(4,), verify_buckets=(4,),
+    )
+    runner.warmup()
+    return cfg, params, runner
+
+
+def _row(runner, first, n_tokens):
+    row = np.zeros(runner.max_blocks_per_seq, np.int32)
+    need = -(-n_tokens // runner.block_size)
+    row[:need] = np.arange(first, first + need)
+    return row
+
+
+# -- forward ------------------------------------------------------------------
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_forward_matches_the_plain_reference(seed):
+    cfg = _cfg()
+    params = _params(cfg, seed)
+    tokens = _tokens(seed, (2, 24))
+    have = L.forward(cfg, params, jnp.asarray(tokens))
+    picks = [(b, t) for b in range(2) for t in range(24)]
+    want = reference.logits_at(MODEL, params, tokens, picks).reshape(2, 24, -1)
+    assert _rel(have, want) < REL_TOL
+
+
+@pytest.mark.parametrize("variant", controls.VARIANTS, ids=lambda v: v or "the_model_itself")
+def test_each_control_of_the_reference_is_another_model(variant):
+    """What the chip's check must read as NOT correct is far from the model
+    at float32 too: the limit is not what tells them apart here. The
+    controls are a twin of the reference kept by the tests; without a
+    variant the twin IS the reference."""
+    cfg = _cfg()
+    params = _params(cfg)
+    tokens = _tokens(3, (2, 24))
+    picks = [(b, t) for b in range(2) for t in range(24)]
+    twin = controls.logits_at(MODEL, params, tokens, picks, variant=variant)
+    if variant is None:
+        assert np.array_equal(twin, reference.logits_at(MODEL, params, tokens, picks))
+        return
+    have = L.forward(cfg, params, jnp.asarray(tokens)).reshape(48, -1)
+    assert _rel(have, twin) > 1e-2  # 50 times the tolerance
+
+
+@pytest.mark.parametrize("variant", controls.VARIANTS[1:-1])
+def test_the_expert_ffn_alone_tells_each_control_of_the_experts(variant):
+    """The second reading of the chip's check (``perfbench/families/olmoe/
+    server.py``): the program's FFN of a block and the reference's on the
+    same activations, so that no expert can flip. At float32 the model
+    reads under 1e-5 and every control that touches the FFN 1e-2 or more."""
+    cfg = _cfg()
+    p = _params(cfg)["layers"][0]
+    h = jnp.asarray(np.random.default_rng(5).standard_normal((1, 24, cfg.dim)), jnp.float32)
+    have = L._ffn(cfg, p, h[0])[0]
+    want, margin = reference.expert_ffn(p, h, top_k=cfg.moe_top_k)
+    assert float(margin.min()) > 1e-4 and _rel(have, want[0]) < 1e-5
+    wrong, _ = controls.expert_ffn(p, h, top_k=cfg.moe_top_k, variant=variant)
+    assert _rel(have, wrong[0]) > 1e-2
+
+
+def test_qk_norm_on_a_dense_config():
+    """QK-norm is a property of the attention alone: a dense MLP with it
+    trains a norm's worth of new weights and moves the logits."""
+    cfg = L.LlamaConfig.tiny(qk_norm=True)
+    plain = L.LlamaConfig.tiny()
+    params = _params(cfg)
+    assert params["layers"][0]["q_norm"].shape == (cfg.n_heads * cfg.head_dim,)
+    assert params["layers"][0]["k_norm"].shape == (cfg.n_kv_heads * cfg.head_dim,)
+    assert L.param_count(cfg) == L.param_count(plain) + cfg.n_layers * (
+        cfg.n_heads + cfg.n_kv_heads) * cfg.head_dim
+    assert set(L.logical_axes(cfg)["layers"][0]) == set(params["layers"][0])
+    tokens = jnp.asarray(_tokens(5, (2, 16)))
+    with_norm = L.forward(cfg, params, tokens)
+    without = L.forward(plain, params, tokens)  # the same weights, the two norms not applied
+    assert _rel(with_norm, without) > 1e-2
+    # by hand, one layer's q: the norm runs over all heads together
+    p = params["layers"][0]
+    h = L.rms_norm(params["embed"][tokens], p["attn_norm"], cfg.norm_eps)
+    q, k, _ = L._qkv(cfg, p, h)
+    flat = jnp.einsum("bsd,dhk->bshk", h, p["wq"]).reshape(2, 16, -1)
+    want = flat * jax.lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True) + cfg.norm_eps) * p["q_norm"]
+    np.testing.assert_allclose(np.asarray(q).reshape(2, 16, -1), np.asarray(want), atol=1e-5)
+    # and the paged steps take the same path: prefill's logits are forward's
+    runner = PagedModelRunner(cfg, params, num_blocks=16, block_size=8, prefill_buckets=(16,),
+                              decode_buckets=(2,))
+    logits = runner.prefill_chunk(np.asarray(tokens[0]), _row(runner, 1, 16), 0)
+    assert _rel(logits, with_norm[0, -1]) < REL_TOL
+    assert runner.moe is None  # a dense model keeps no expert account
+
+
+# -- the paged steps ------------------------------------------------------------
+
+def test_chunked_prefill_then_decode_matches_the_reference_full_pass(served):
+    cfg, params, runner = served
+    prompt_lens, steps = [40, 12], 2
+    totals = [n + steps for n in prompt_lens]
+    tokens = _tokens(7, (2, max(totals)))
+    rows = [_row(runner, 1, totals[0]), _row(runner, 10, totals[1])]
+    got = []
+    for i, n in enumerate(prompt_lens):
+        start = 0
+        while start < n:  # chunks of the largest bucket: 32 then 8
+            c = min(32, n - start)
+            logits = runner.prefill_chunk(tokens[i, start : start + c], rows[i], start)
+            start += c
+        got.append((i, n - 1, logits))
+    for d in range(steps):
+        poss = [n + d for n in prompt_lens]
+        logits = runner.decode(
+            [int(tokens[i, p]) for i, p in enumerate(poss)], poss, rows, [p + 1 for p in poss]
+        )
+        got += [(i, p, logits[i]) for i, p in enumerate(poss)]
+    want = reference.logits_at(MODEL, params, tokens, [(i, p) for i, p, _ in got])
+    for (_, _, have), ref in zip(got, want):
+        assert _rel(have, ref) < REL_TOL
+    assert runner.recompiles_after_warmup() == 0
+
+
+def test_verify_step_matches_decode_repeated(served):
+    cfg, params, runner = served
+    tokens = _tokens(11, (2, 20))
+    rows = [_row(runner, 20, 20), _row(runner, 30, 20)]
+    for i in range(2):
+        runner.prefill_chunk(tokens[i, :16], rows[i], 0)
+    # windows of 3 and 2 positions in one verify launch ...
+    windows = [list(tokens[0, 16:19]), list(tokens[1, 16:18])]
+    verified = runner.verify_batch(windows, rows, [16, 16])
+    # ... against the same positions one decode step at a time
+    for i, window in enumerate(windows):
+        for j, tok in enumerate(window):
+            one = runner.decode([int(tok)], [16 + j], [rows[i]], [17 + j])
+            assert _rel(verified[i][j], one[0]) < REL_TOL
+
+
+def test_loads_come_back_with_the_logits_and_are_accounted(served):
+    cfg, params, runner = served
+    before = {k: dict(v) for k, v in runner.moe.items()}
+    tokens = _tokens(13, (1, 24))[0]
+    row = _row(runner, 40, 24)
+    runner.prefill_chunk(tokens[:20], row, 0)  # 20 real rows in a bucket of 32
+    runner.decode([int(tokens[20])], [20], [row], [21])  # 1 real slot of 4
+    pre = {k: runner.moe["prefill"][k] - before["prefill"][k] for k in before["prefill"]}
+    dec = {k: runner.moe["decode"][k] - before["decode"][k] for k in before["decode"]}
+    layers, E, k = cfg.n_layers, cfg.moe_experts, cfg.moe_top_k
+    assert (pre["launches"], pre["assignments"], pre["expert_slots"]) == (1, 20 * k * layers, layers * E)
+    assert (dec["launches"], dec["assignments"], dec["expert_slots"]) == (1, 1 * k * layers, layers * E)
+    assert dec["experts_touched"] == k * layers and dec["max_load"] == layers
+    assert pre["mean_load"] == pytest.approx(layers * 20 * k / E)
+    assert pre["mean_load"] <= pre["max_load"] <= 20 * layers
+    # the step itself: a third output, [layers, E] int32, counting real rows only
+    padded = np.zeros(32, np.int32)
+    padded[:20] = tokens[:20]
+    runner.cache, _, loads = runner._prefill_jit(
+        params, runner.cache, padded, row, np.int32(0), np.int32(20)
+    )
+    assert loads.shape == (layers, E) and loads.dtype == jnp.int32
+    assert np.asarray(loads).sum(axis=1).tolist() == [20 * k] * layers
+
+
+def test_paged_steps_of_a_dense_config_have_two_outputs():
+    cfg = L.LlamaConfig.tiny()
+    params = L.init_params(cfg, jax.random.PRNGKey(0))
+    cache = L.init_paged_kv_cache(cfg, 8, 8)
+    z = np.zeros
+    assert len(L.paged_prefill_step(cfg, params, cache, z(8, np.int32), z(8, np.int32),
+                                    np.int32(0), np.int32(4))) == 2
+    assert len(L.paged_decode_step(cfg, params, cache, z(2, np.int32), z(2, np.int32),
+                                   z((2, 8), np.int32), np.ones(2, np.int32))) == 2
+    assert len(L.paged_verify_step(cfg, params, cache, z((2, 4), np.int32), z((2, 8), np.int32),
+                                   z(2, np.int32), z(2, np.int32))) == 2
+
+
+def test_what_shares_a_decode_batch_cannot_change_a_slot(served):
+    """Dropless: a request's logits do not depend on who shares its batch."""
+    cfg, params, runner = served
+    tokens = _tokens(17, (4, 12))
+    rows = [_row(runner, 45 + 2 * i, 12) for i in range(4)]
+    for i in range(4):
+        runner.prefill_chunk(tokens[i, :11], rows[i], 0)
+    alone = runner.decode([int(tokens[0, 11])], [11], [rows[0]], [12])
+    crowd = runner.decode([int(t) for t in tokens[:, 11]], [11] * 4, rows, [12] * 4)
+    assert _rel(crowd[0], alone[0]) < 1e-6
+
+
+# -- the dropless FFN on hand-made routings ----------------------------------------
+
+def _loop_ffn(params, x, gates, experts):
+    """Per-expert loop with a mask: float64, the routing handed in."""
+    x = np.asarray(x, np.float64)
+    out = np.zeros_like(x)
+    for e in range(params["router"].shape[1]):
+        wg, wu, wd = (np.asarray(params[n][e], np.float64) for n in ("w_gate", "w_up", "w_down"))
+        g = np.where(np.asarray(experts) == e, np.asarray(gates, np.float64), 0.0).sum(-1)
+        h = x @ wg
+        out += g[:, None] * (((h / (1 + np.exp(-h))) * (x @ wu)) @ wd)
+    return out
+
+
+def _forced(E, d, scores):
+    """A router under which token t's softmax is ``softmax(scores[t])``:
+    the tokens are one-hot rows, so x @ router picks a row of it."""
+    T = len(scores)
+    assert T <= d
+    router = np.zeros((d, E), np.float32)
+    router[:T] = scores
+    return jnp.asarray(router), jnp.eye(T, d, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("case", ["every_token_to_one_expert", "an_expert_with_no_token",
+                                  "more_padding_than_real_rows", "random_routing"])
+def test_dropless_ffn_against_the_per_expert_loop(case):
+    E, d, hidden, k = 4, 16, 32, 2
+    params = init_moe_params(jax.random.PRNGKey(0), d, hidden, E)
+    rng = np.random.default_rng(1)
+    T, valid = 12, None
+    if case == "every_token_to_one_expert":
+        k = 1
+        params["router"], x = _forced(E, d, np.tile([0.0, 9.0, 0.0, 0.0], (T, 1)))
+        want_load = [0, T, 0, 0]
+    elif case == "an_expert_with_no_token":
+        scores = rng.normal(size=(T, E))
+        scores[:, 2] = -30.0  # never among the top 2 of 4
+        params["router"], x = _forced(E, d, scores)
+        want_load = None
+    elif case == "more_padding_than_real_rows":
+        x = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
+        valid = jnp.asarray([True, False, False, True, False, False, False, True,
+                             False, False, False, False])
+        want_load = None
+    else:
+        x = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
+        want_load = None
+    out, aux = jax.jit(
+        lambda p, x, v: dropless_moe_ffn(p, x, top_k=k, renormalize=False, valid=v)
+    )(params, x, valid)
+    gates, experts, _ = route(params["router"], x, top_k=k, renormalize=False)
+    keep = np.ones(T, bool) if valid is None else np.asarray(valid)
+    want = _loop_ffn(params, x, gates, experts) * keep[:, None]
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5, rtol=2e-5)
+    # the loads are a bincount of the valid rows' choices
+    counted = np.bincount(np.asarray(experts)[keep].reshape(-1), minlength=E)
+    assert np.asarray(aux["load"]).tolist() == counted.tolist()
+    assert int(aux["load"].sum()) == int(keep.sum()) * k
+    if want_load is not None:
+        assert counted.tolist() == want_load
+    if case == "an_expert_with_no_token":
+        assert counted[2] == 0 and (counted[[0, 1, 3]] > 0).all()
+
+
+def test_a_real_row_does_not_change_when_the_padding_rows_do():
+    E, d, hidden, k, T = 4, 16, 32, 2, 10
+    params = init_moe_params(jax.random.PRNGKey(2), d, hidden, E)
+    rng = np.random.default_rng(3)
+    valid = jnp.asarray([True] * 3 + [False] * 7)
+    x1 = rng.normal(size=(T, d)).astype(np.float32)
+    x2 = x1.copy()
+    x2[3:] = 50.0 * rng.normal(size=(T - 3, d))  # other routing, other magnitudes
+    fn = jax.jit(lambda x: dropless_moe_ffn(params, x, top_k=k, renormalize=False, valid=valid))
+    (o1, a1), (o2, a2) = fn(jnp.asarray(x1)), fn(jnp.asarray(x2))
+    assert np.array_equal(np.asarray(o1[:3]), np.asarray(o2[:3]))  # bit for bit
+    assert np.array_equal(np.asarray(a1["load"]), np.asarray(a2["load"]))
+    assert not np.asarray(o1[3:]).any() and not np.asarray(o2[3:]).any()
+
+
+@pytest.mark.parametrize("renormalize", [False, True])
+def test_one_routing_for_both_expert_paths(renormalize):
+    """``moe_ffn`` (expert-parallel, capacity) with room for everything and
+    the dropless path compute the same FFN from the same ``route``."""
+    params = init_moe_params(jax.random.PRNGKey(4), 16, 32, 4)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 8, 16), jnp.float32)
+    capped, aux = moe_ffn(params, x, top_k=2, renormalize=renormalize, capacity_factor=8.0)
+    assert float(aux["dropped_fraction"]) == 0.0
+    free, aux2 = dropless_moe_ffn(params, x.reshape(16, 16), top_k=2, renormalize=renormalize)
+    np.testing.assert_allclose(np.asarray(capped).reshape(16, 16), np.asarray(free), atol=2e-5)
+    assert float(aux["aux_loss"]) == pytest.approx(float(aux2["aux_loss"]), rel=1e-6)
+    gates, _, probs = route(params["router"], x.reshape(16, 16), top_k=2, renormalize=renormalize)
+    sums = np.asarray(gates.sum(-1))
+    assert np.allclose(sums, 1.0) if renormalize else (sums < 1.0 - 1e-3).all()
+    assert gates.dtype == jnp.float32 and probs.dtype == jnp.float32
+
+
+def test_forward_without_an_expert_axis_drops_nothing_and_trains():
+    """``forward`` on one device runs the dropless path: a capacity factor
+    that would drop most assignments changes nothing, and gradients reach
+    the router and every expert that received a token."""
+    cfg = _cfg()
+    tight = _cfg(moe_capacity_factor=0.01)
+    params = _params(cfg)
+    tokens = jnp.asarray(_tokens(19, (2, 16)))
+    assert np.array_equal(np.asarray(L.forward(cfg, params, tokens)),
+                          np.asarray(L.forward(tight, params, tokens)))
+    grads = jax.grad(lambda p: L.next_token_loss(cfg, p, tokens, tokens))(params)
+    g0 = grads["layers"][0]
+    assert float(jnp.abs(g0["router"]).max()) > 0 and float(jnp.abs(g0["q_norm"]).max()) > 0
+    assert all(float(jnp.abs(g0["w_down"][e]).max()) > 0 for e in range(cfg.moe_experts))
+
+
+# -- the chip's branch of the grouped matmul, compiled for a described v5e -------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: the TPU compiler is installed
+    here. Made inside a fixture, never at import (only one process may hold
+    libtpu; under xdist every worker imports this file)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows, tile", [(32 * 8, 128), (1024 * 8, 256), (8 * 8, 128)],
+                         ids=["decode_4_rows_an_expert", "prefill_128_rows_an_expert",
+                              "a_bucket_under_a_tile_is_padded_to_one"])
+def test_the_grouped_matmul_compiles_for_the_chip_at_olmoe_widths(one_chip, monkeypatch, rows, tile):
+    """On a TPU ``grouped_matmul`` is the Pallas kernel ``megablox.gmm``; the
+    CPU tests above never reach that branch. Compile it at the published
+    widths for the real chip (nothing runs): Mosaic refuses here what it
+    would refuse there (tiling, VMEM)."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the branch; the test's business
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # such a compile cannot be read back without a chip
+    try:
+        shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        for k, n in ((2048, 1024), (1024, 2048)):  # gate/up, then down
+            compiled = jax.jit(moe.grouped_matmul).lower(
+                shape((rows, k), jnp.bfloat16), shape((64, k, n), jnp.bfloat16), shape((64,), jnp.int32)
+            ).compile()
+            text = compiled.as_text()
+            assert "tpu_custom_call" in text and "gmm" in text and "ragged-dot" not in text
+            assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+            assert compiled.out_info.shape == (rows, n)
+        assert rows % tile == 0 or rows < tile
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
