@@ -4,7 +4,9 @@ One engine per replica/process. A background step-loop thread drives
 ``step()``: each step runs at most one prefill chunk plus the standing
 decode batch (``scheduler.StepPlan``), samples the new tokens host-side,
 and pushes them into per-request queues that :meth:`generate` drains —
-so tokens stream to the caller WHILE other requests keep decoding.
+so tokens stream to the caller WHILE other requests keep decoding. The
+push waits until the NEXT launch is on its way (``_hold`` /
+``_deliver_held``): the streams it wakes then run while the device does.
 
 Request lifecycle hooks the rest of the runtime:
 
@@ -425,6 +427,23 @@ class InferenceEngine:
             max_queue_depth=ec.max_queue_depth,
         )
         self._out: Dict[str, queue.Queue] = {}
+        #: emit commits, the wake-ups wait: every item the step thread has
+        #: for a request's out-queue goes here as (queue, item, committed
+        #: at), in order, and :meth:`_deliver_held` puts them once the next
+        #: launch is on its way (or there is none to wait for). The woken
+        #: consumers then want the GIL while the device runs and this
+        #: thread waits, not while it launches.
+        self._held: List[tuple] = []
+        #: orders appends and deliveries: cancel() and stop() finish
+        #: requests from their callers' threads
+        self._held_lock = threading.Lock()
+        #: every item delivered, by where (monotonic; stats()["wakes"]):
+        #: after a launch, at a step with nothing to launch, or directly (a
+        #: step() from outside the loop, cancel, stop, a failure); held_s
+        #: sums delivery instant - commit instant over the items
+        self._wakes = {
+            "items": 0, "after_launch": 0, "at_idle": 0, "direct": 0, "held_s": 0.0,
+        }
         # request id -> submitter's (trace_id, span_id): the step-loop
         # thread stamps per-request spans (admission→first-token,
         # admission→finish) under the serve caller's trace
@@ -651,7 +670,9 @@ class InferenceEngine:
             self._last_beat = time.monotonic()
             did_work = False
             try:
-                did_work = self.step()  # settles its own part of the account
+                # settles its own part of the account; what it commits is
+                # woken after the next step's first launch
+                did_work = self.step(hold_wakes=True)
             except Exception as e:  # noqa: BLE001 — fail in-flight, keep serving
                 self._fail_all(e)
                 if self.runner.cache["k"].is_deleted():
@@ -920,6 +941,8 @@ class InferenceEngine:
                 q.put(_END)
             return False
         self._finish_request(req, CANCELLED, error=None)
+        # the caller is not the step loop: nothing to launch before its _END
+        self._deliver_held("direct")
         return True
 
     # -- drain ------------------------------------------------------------
@@ -986,11 +1009,16 @@ class InferenceEngine:
         self._node_listener = None
 
     # -- the step ---------------------------------------------------------
-    def step(self) -> bool:
+    def step(self, hold_wakes: bool = False) -> bool:
         """One engine step: ≤N prefill chunks + the decode batch. Returns
         whether any work ran. Every second of it lands in one phase of
         the step account (``stats()["step_phases"]``): what no phase
-        claimed is this step's bookkeeping."""
+        claimed is this step's bookkeeping.
+
+        What the step commits reaches the requests' queues before it
+        returns, unless ``hold_wakes``: the step loop's own calls leave the
+        last launch's items held, for the next step to deliver after ITS
+        first launch."""
         since = time.perf_counter()
         # timeline timestamps share the module's wall-clock epoch so
         # engine_step events merge with every other process's trace
@@ -1000,6 +1028,8 @@ class InferenceEngine:
             did_work = self._step(t0_us)
             return did_work
         finally:
+            if not hold_wakes:
+                self._wake("direct")
             self._clock.settle(since, "bookkeeping" if did_work else "schedule")
 
     def _step(self, t0_us: float) -> bool:
@@ -1027,9 +1057,14 @@ class InferenceEngine:
                         f"request {req.request_id} deadline expired before completion"
                     ),
                 )
-            if not plan.prefills and not plan.decodes:
-                return did_import or not plan.empty
-            self._consult_replica_chaos(plan)
+            idle = not plan.prefills and not plan.decodes
+            if not idle:
+                self._consult_replica_chaos(plan)
+        if idle:
+            # no launch to wait for: nothing stays held
+            self._wake("at_idle")
+            return did_import or not plan.empty
+        wake = self._wake_after_launch
 
         n_prefill_tokens = 0
         for req, start, chunk in plan.prefills:
@@ -1049,7 +1084,7 @@ class InferenceEngine:
                 )
                 prompt = req.effective_prompt
                 tokens = prompt[start : start + chunk]
-            logits = self.runner.prefill_chunk(tokens, row, start, clock)
+            logits = self.runner.prefill_chunk(tokens, row, start, clock, wake)
             req.prefill_pos = start + chunk
             n_prefill_tokens += chunk
             if req.prefill_done and req.prefill_done_at is None:
@@ -1112,7 +1147,7 @@ class InferenceEngine:
                         for r in plain
                     ]
                     cls = [r.context_len for r in plain]
-                logits = self.runner.decode(toks, poss, rows, cls, clock)
+                logits = self.runner.decode(toks, poss, rows, cls, clock, wake)
                 with clock.phase("sample"):
                     sampled = [self._sample(req, lg) for req, lg in zip(plain, logits)]
                 with clock.phase("emit"):
@@ -1128,7 +1163,7 @@ class InferenceEngine:
                         for r, _ in spec_slots
                     ]
                     ctxs = [r.context_len - 1 for r, _ in spec_slots]
-                all_logits = self.runner.verify_batch(windows, rows, ctxs, clock)
+                all_logits = self.runner.verify_batch(windows, rows, ctxs, clock, wake)
                 with clock.phase("sample"):
                     sampled = [
                         self._spec_sample(req, drafts, logits)
@@ -1390,7 +1425,7 @@ class InferenceEngine:
             with self._lock:
                 q = self._out.get(req.request_id)
             if q is not None:
-                q.put(("kv_export", payload))
+                self._hold(q, ("kv_export", payload))
         if self.scheduler.finish(req, FINISHED):
             self._finish_request(req, FINISHED, error=None)
 
@@ -1744,7 +1779,7 @@ class InferenceEngine:
                 cached_prefix_tokens=req.cached_prefix_tokens,
             )
         if q is not None:
-            q.put(token)
+            self._hold(q, token)
         done = (
             len(req.generated) >= req.max_new_tokens
             or (req.eos_token is not None and token == req.eos_token)
@@ -1797,7 +1832,7 @@ class InferenceEngine:
                 preemptions=req.preemptions,
             )
         if q is not None:
-            q.put(error if error is not None else _END)
+            self._hold(q, error if error is not None else _END)
         self.metrics["requests_total"].inc(labels={"outcome": outcome})
 
     def _close_ledger(
@@ -1901,6 +1936,47 @@ class InferenceEngine:
             self.blocks.free(req.request_id)
             req.state = FAILED
             self._finish_request(req, FAILED, error=error)
+        # no launch follows a failure: the errors, and whatever an earlier
+        # step left held, go out now
+        self._deliver_held("direct")
+
+    # -- held wake-ups ----------------------------------------------------
+    def _hold(self, q: "queue.Queue", item: Any) -> None:
+        """What ``q.put(item)`` was on the step thread: the item is
+        committed, its consumer is woken by the next delivery."""
+        with self._held_lock:
+            self._held.append((q, item, time.perf_counter()))
+
+    def _deliver_held(self, where: str) -> None:
+        """Put every held item, in the order it was committed, and count
+        it under ``where``. The puts run under the lock, so that two
+        deliverers cannot interleave one stream's items."""
+        with self._held_lock:
+            held = self._held
+            if not held:
+                return
+            self._held = []
+            committed = 0.0
+            for q, item, at in held:
+                q.put(item)
+                committed += at
+            wakes = self._wakes
+            wakes["items"] += len(held)
+            wakes[where] += len(held)
+            wakes["held_s"] += len(held) * time.perf_counter() - committed
+
+    def _wake(self, where: str) -> None:
+        """:meth:`_deliver_held` on the step account, for a caller that is
+        in no phase: the wake-ups are ``emit``'s second half."""
+        if self._held:
+            with self._clock.phase("emit"):
+                self._deliver_held(where)
+
+    def _wake_after_launch(self) -> None:
+        """The runner's ``launched`` hook: the program is on its way and
+        this thread is about to wait for it, so the consumers of the LAST
+        launch run while the device does."""
+        self._wake("after_launch")
 
     def _reap_abandoned_streams(self) -> None:
         ttl = self.engine_cfg.finished_stream_ttl_s
@@ -2086,6 +2162,8 @@ class InferenceEngine:
             "tokens_per_s": round(self._tokens_per_s(), 2),
             "ttft": {k: round(v, 6) for k, v in self._ttft_quantiles().items()},
             "step_phases": self._step_phases(),
+            # where the step thread's puts were delivered, and how long held
+            "wakes": dict(self._wakes),
             # how wide the decode and verify launches gathered (the target
             # runner's own; a draft model's runner keeps its own count)
             "decode_width": dict(self.runner.decode_width),

@@ -39,7 +39,7 @@ from __future__ import annotations
 import logging
 import time
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -372,10 +372,15 @@ class PagedModelRunner:
         block_row: Sequence[int],
         ctx_len: int,
         clock: Optional[timeline.PhaseClock] = None,
+        launched: Optional[Callable[[], None]] = None,
     ) -> np.ndarray:
         """Run one prefill chunk; returns logits [vocab] (fp32 numpy) for
         the chunk's last valid token. ``clock``: the caller's account for
-        the call's phases, if it keeps one (the runner's own otherwise)."""
+        the call's phases, if it keeps one (the runner's own otherwise).
+        ``launched``: called once when the program is on its way to the
+        device, before this thread waits for it (here and in
+        :meth:`decode` and :meth:`verify_batch`): the caller's chance to do
+        host work that the device's run hides."""
         clock = clock or self.clock
         true_len = len(tokens)
         bucket = _round_up_bucket(true_len, self.prefill_buckets)
@@ -387,14 +392,21 @@ class PagedModelRunner:
                 self._run, "paged_prefill_step", self._prefill_jit,
                 padded, row, np.int32(ctx_len), np.int32(true_len),
             )
-        return self._read(out, clock, "prefill")
+        return self._read(out, clock, "prefill", launched)
 
-    def _read(self, out, clock: timeline.PhaseClock, kind: str) -> np.ndarray:
+    def _read(
+        self, out, clock: timeline.PhaseClock, kind: str,
+        launched: Optional[Callable[[], None]] = None,
+    ) -> np.ndarray:
         """Wait for a step's logits, then copy them to the host: two
         phases, so that the device's time is told from the copy's. A MoE
         step's expert loads come over in the same ``readback`` and go into
-        the ``kind`` (``decode`` or ``prefill``) half of :attr:`moe`."""
+        the ``kind`` (``decode`` or ``prefill``) half of :attr:`moe`.
+        ``launched`` runs first: the launch has returned, the wait has not
+        begun."""
         logits, loads = out
+        if launched is not None:
+            launched()
         with clock.phase("device_wait"):
             logits.block_until_ready()
         with clock.phase("readback"):
@@ -442,6 +454,7 @@ class PagedModelRunner:
         block_rows: Sequence[Sequence[int]],
         ctx_lens: Sequence[int],
         clock: Optional[timeline.PhaseClock] = None,
+        launched: Optional[Callable[[], None]] = None,
     ) -> List[np.ndarray]:
         """Run speculative-verify windows (``[last_committed, d_1..d_k]``
         each) for a batch of slots in ONE jitted step. Returns one
@@ -475,7 +488,7 @@ class PagedModelRunner:
             out = self._step(
                 self._run, "paged_verify_step", self._verify_jit, tokens, tables, ctx, tl
             )
-        out = self._read(out, clock, "decode")
+        out = self._read(out, clock, "decode", launched)
         return [out[i, : len(w)] for i, w in enumerate(windows)]
 
     def decode(
@@ -485,6 +498,7 @@ class PagedModelRunner:
         block_rows: Sequence[Sequence[int]],
         ctx_lens: Sequence[int],
         clock: Optional[timeline.PhaseClock] = None,
+        launched: Optional[Callable[[], None]] = None,
     ) -> np.ndarray:
         """Advance a decode batch one token; returns logits [n, vocab]
         for the n REAL slots (padding stripped). ``block_rows`` are
@@ -512,4 +526,4 @@ class PagedModelRunner:
             out = self._step(
                 self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl
             )
-        return self._read(out, clock, "decode")[:n]
+        return self._read(out, clock, "decode", launched)[:n]

@@ -85,9 +85,14 @@ def test_step_account_closes_and_is_monotonic(engine):
     assert last["host_serial_s"] == pytest.approx(
         last["wall_s"] - last["device_wait_s"] - last["loop_wait_s"], abs=1e-9
     )
+    # emit's second half, the wake-ups held for the next launch (ISSUE 28),
+    # is inside the account that closed above: every item went out somewhere
+    wakes = seen[-1]["wakes"]
+    assert wakes["items"] == wakes["after_launch"] + wakes["at_idle"] + wakes["direct"] > 0
+    assert wakes["after_launch"] > 0 and wakes["direct"] == 0 and wakes["held_s"] > 0
     # the loop's wall time is the thread's life, not only its steps
     for a, b in zip(seen, seen[1:]):
-        for group in ("step_phases", "request_stages"):
+        for group in ("step_phases", "request_stages", "wakes"):
             for key, value in a[group].items():
                 assert b[group][key] >= value, (group, key)
         assert b["total_steps"] >= a["total_steps"]
@@ -120,6 +125,9 @@ def test_direct_steps_close_the_account_too(cfg, params):
     p = eng.stats()["step_phases"]
     assert _leaves(p) == pytest.approx(p["wall_s"], rel=1e-6)
     assert p["loop_wait_s"] == 0.0 and p["device_wait_s"] > 0 and p["schedule_s"] > 0
+    # and delivers what it committed before it returns, inside the account
+    wakes = eng.stats()["wakes"]
+    assert wakes["items"] == wakes["direct"] + wakes["after_launch"] == 5 and not eng._held
 
 
 def test_runner_calls_off_the_loop_stay_out_of_the_step_account(engine):

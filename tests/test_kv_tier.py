@@ -657,7 +657,9 @@ def test_migrate_mid_prefill_publishes_only_written_blocks(cfg, params):
         # 8-token prefill chunk lands -> prefill_pos=8, prefill NOT done
         assert eng.step()
         eng._migrate_on_drain = True
-        eng._migrate_inflight()
+        # the next step migrates first, finds nothing left to launch and
+        # delivers the handoff it held
+        assert not eng.step()
         adverts = eng.routing_stats()["kv_tier"]
         chain = _digests(prompt)
         # only the chunk that was truly written is tier-resident; the
