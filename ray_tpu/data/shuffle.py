@@ -24,8 +24,7 @@ move when each reducer's arg-fetch pulls its slices through the
 daemon↔daemon chunk transfer, which since the zero-copy data plane PR
 rides RAW frames end to end — sender segments scatter-gather onto the
 socket, receivers land chunks straight in the destination segment
-(``core/rpc.py`` kind 5, ``core/pull_manager.py``). ``bench.py``'s
-``shuffle_gbps`` phase measures this exchange across a 2-node cluster;
+(``core/rpc.py`` kind 5, ``core/pull_manager.py``).
 ``raytpu_shuffle_*`` counters surface exchange activity on /metrics.
 """
 

@@ -635,26 +635,26 @@ def scatter_paged_blocks(cache, blocks, kv):
 
 
 def _rope_at(cfg: LlamaConfig, positions):
-    """cos/sin tables at arbitrary int positions: [N] -> ([N, hd/2] x2)."""
+    """cos/sin tables at arbitrary int positions: [...] -> ([..., hd/2] x2)."""
     hd = cfg.head_dim
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
-    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
     return jnp.cos(ang), jnp.sin(ang)
 
 
 def _apply_rope_flat(x, cos, sin):
-    """x: [N, H, hd] with per-row position tables [N, hd/2]."""
+    """x: [..., H, hd] with per-row position tables [..., hd/2]."""
     x1, x2 = x[..., ::2], x[..., 1::2]
-    c = cos[:, None, :]
-    s = sin[:, None, :]
+    c = cos[..., None, :]
+    s = sin[..., None, :]
     out1 = x1 * c - x2 * s
     out2 = x2 * c + x1 * s
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def _scatter_kv(cache, layer: int, blk, off, k, v):
-    """Write per-token K/V into their cache slots. blk/off: [N] int32,
-    k/v: [N, n_kv, hd]. Padding rows target the null block — colliding
+    """Write per-token K/V into their cache slots. blk/off: [...] int32,
+    k/v: [..., n_kv, hd]. Padding rows target the null block — colliding
     trash writes are fine, nothing masked-in ever reads them."""
     return {
         "k": cache["k"].at[layer, blk, off].set(k),
@@ -680,180 +680,143 @@ def _step_outputs(cache, logits, loads: list):
     return cache, logits
 
 
-def paged_prefill_step(
-    cfg: LlamaConfig, params, cache, tokens, block_table, ctx_len, true_len
-):
-    """One prefill chunk for ONE request, fixed shapes.
+def _block_at(block_tables, pos, bs: int):
+    """Id of the block that holds position ``pos[b, c]`` of slot ``b``:
+    ``block_tables [B, M]``, ``pos [B, C]`` -> ``[B, C]`` (a position past
+    the table reads its last column)."""
+    M = block_tables.shape[1]
+    return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
 
-    tokens: [C] int32 (right-padded chunk), block_table: [M] int32 (padded
-    with 0 = null), ctx_len: scalar int32 tokens ALREADY cached (chunked
-    prefill: >0 from the second chunk on), true_len: scalar int32 valid
-    tokens in this chunk. Writes the chunk's K/V into the cache, attends
-    causally over cached-context + chunk, and returns
-    ``(cache, logits[vocab])`` for the chunk's last valid token; a MoE
-    config adds the expert loads of the valid rows (``_step_outputs``).
-    Padding rows (``idx >= true_len``) reach no expert.
-    """
-    C = tokens.shape[0]
-    M = block_table.shape[0]
+
+def _paged_attention(cfg: LlamaConfig, q, cache, layer: int, block_tables, pos):
+    """Causal attention of ``q [B, C, H, hd]`` (rope applied) over the
+    cached context of its slot: gathers ``cache[layer, block_tables]``
+    (``block_tables [B, M]``), so K/V of the step's own tokens must be in
+    the cache already. Query ``(b, c)`` at global position ``pos[b, c]``
+    sees key position ``j`` of its slot iff ``j <= pos[b, c]``. Returns
+    ``[B, C, H, hd]``. GQA stays grouped ``[n_kv, rep]``; scores, mask and
+    softmax are float32.
+
+    The ONE place a serving step reads the cache for attention: a
+    paged-attention kernel or a flash prefill replaces this function."""
+    B, C = pos.shape
+    M = block_tables.shape[1]
     bs = cache["k"].shape[2]
     rep = cfg.n_heads // cfg.n_kv_heads
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    ks = cache["k"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
+    vs = cache["v"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
+    qg = q.reshape(B, C, cfg.n_kv_heads, rep, -1)
+    s = jnp.einsum("bcgrh,bsgh->bcgrs", qg, ks).astype(jnp.float32)
+    s = s * (1.0 / math.sqrt(cfg.head_dim))
+    key_pos = jnp.arange(M * bs, dtype=jnp.int32)
+    mask = key_pos <= pos[:, :, None]  # [B, C, M*bs]
+    s = jnp.where(mask[:, :, None, None, :], s, -1e30)
+    pattn = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bcgrs,bsgh->bcgrh", pattn.astype(vs.dtype), vs)
+    return o.reshape(B, C, cfg.n_heads, -1)
 
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = ctx_len + idx  # global positions of the chunk's tokens
-    valid = idx < true_len
-    blk = jnp.where(valid, block_table[jnp.minimum(pos // bs, M - 1)], 0)
+
+def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
+    """Every block of the model over a paged cache: the body of the three
+    serving steps. ``x [B, C, D]`` embedded tokens, ``pos [B, C]`` their
+    global positions, ``valid [B, C]`` bool (padding rows write K/V to the
+    null block and reach no expert), ``block_tables [B, M]``. Per layer:
+    norm, q/k/v, rope at ``pos``, K/V written to the cache, attention over
+    the cache (:func:`_paged_attention`, after the write so a window
+    attends to itself), ``wo``, the FFN. Returns ``(cache, x, loads)``,
+    ``loads`` a list of a MoE block's expert loads a layer.
+
+    Positions past a slot's committed context may hold stale K/V (the
+    rejected tail of a verify window, a preempted chunk); that is safe by
+    construction: every read masks on ``key_pos <= pos``, so nothing past
+    the querying token is ever read, and the next write to a position
+    overwrites it in place."""
+    bs = cache["k"].shape[2]
+    blk = jnp.where(valid, _block_at(block_tables, pos, bs), 0)
     off = pos % bs
     cos, sin = _rope_at(cfg, pos)
-    # key j (global position) visible to chunk query i iff j <= ctx_len+i
-    key_pos = jnp.arange(M * bs, dtype=jnp.int32)
-    mask = key_pos[None, :] <= pos[:, None]  # [C, M*bs]
-
-    x = params["embed"][tokens]  # [C, D]
     loads = []
     for layer, p in enumerate(params["layers"]):
         q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
         cache = _scatter_kv(cache, layer, blk, off, k, v)
-        # gather AFTER the scatter so the chunk attends to itself
-        ks = cache["k"][layer, block_table].reshape(M * bs, cfg.n_kv_heads, -1)
-        vs = cache["v"][layer, block_table].reshape(M * bs, cfg.n_kv_heads, -1)
-        qg = q.reshape(C, cfg.n_kv_heads, rep, -1)
-        s = jnp.einsum("cgrh,sgh->cgrs", qg, ks).astype(jnp.float32) * scale
-        s = jnp.where(mask[:, None, None, :], s, -1e30)
-        pattn = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("cgrs,sgh->cgrh", pattn.astype(vs.dtype), vs)
-        o = o.reshape(C, cfg.n_heads, -1)
-        x = x + jnp.einsum("chk,hkd->cd", o.astype(x.dtype), p["wo"])
+        o = _paged_attention(cfg, q, cache, layer, block_tables, pos)
+        x = x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
         x = _ffn_residual(cfg, p, x, valid, loads)
-    last = jnp.maximum(true_len - 1, 0)
-    h_last = rms_norm(x[last], params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("d,dv->v", h_last, params["lm_head"]).astype(jnp.float32)
+    return cache, x, loads
+
+
+def _lm_head(cfg: LlamaConfig, params, x):
+    """``x [..., D]`` -> float32 logits ``[..., vocab]`` through the final norm."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(jnp.float32)
+
+
+def paged_prefill_step(
+    cfg: LlamaConfig, params, cache, tokens, block_table, ctx_len, true_len
+):
+    """One prefill chunk for ONE request (``B = 1`` of :func:`_paged_layers`).
+
+    tokens: [C] int32 (right-padded chunk), block_table: [M] int32 (padded
+    with 0 = null), ctx_len: scalar int32 tokens ALREADY cached (chunked
+    prefill: >0 from the second chunk on), true_len: scalar int32 valid
+    tokens in this chunk (``valid = idx < true_len``). Head: the chunk's
+    last valid row only. Returns ``(cache, logits [vocab])``, and a MoE
+    config's expert loads (``_step_outputs``).
+    """
+    idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    cache, x, loads = _paged_layers(
+        cfg, params, cache, params["embed"][tokens][None],
+        (ctx_len + idx)[None], (idx < true_len)[None], block_table[None],
+    )
+    logits = _lm_head(cfg, params, x[0, jnp.maximum(true_len - 1, 0)])
     return _step_outputs(cache, logits, loads)
 
 
 def paged_verify_step(
     cfg: LlamaConfig, params, cache, tokens, block_tables, ctx_lens, true_lens
 ):
-    """Speculative verification for a BATCH of slots, fixed shapes.
+    """Speculative verification for a BATCH of slots: windows of C
+    positions a slot, :func:`_paged_layers` as it is.
 
-    The batched cross between :func:`paged_prefill_step` (a window of C
-    positions per sequence, ``key_pos <= pos`` causal masking, K/V
-    written as it goes) and :func:`paged_decode_step` (a batch axis over
-    independent slots sharing one jit call). tokens: [B, C] int32
-    (right-padded verify windows ``[last_committed, d_1..d_k]`` per
-    slot), block_tables: [B, M] int32, ctx_lens: [B] int32 tokens
-    already cached per slot, true_lens: [B] int32 valid window lengths
-    (0 for padding slots: every position masks invalid, writes land on
-    the null block). Returns logits for EVERY window position,
-    ``(cache, logits [B, C, vocab])`` (and a MoE config's expert loads,
-    ``_step_outputs``), so the host accepts or rejects
-    each drafted token independently — B slots verify k+1 positions each
-    in ONE step, where plain decode would spend B*(k+1) batched steps.
-
-    Rejected tail positions leave stale K/V behind; that is safe by
-    construction (decode masks on ``key_pos < ctx_len`` and
-    prefill/verify on ``key_pos <= pos``, so nothing past the committed
-    context is ever read, and re-verification overwrites in place).
+    tokens: [B, C] int32 (right-padded verify windows ``[last_committed,
+    d_1..d_k]`` per slot), block_tables: [B, M] int32, ctx_lens: [B] int32
+    tokens already cached per slot, true_lens: [B] int32 valid window
+    lengths (``valid = idx < true_len``; 0 for a padding slot). Head:
+    EVERY row, so the host accepts or rejects each drafted token by
+    itself. Returns ``(cache, logits [B, C, vocab])``, and a MoE config's
+    expert loads (``_step_outputs``).
     """
-    B, C = tokens.shape
-    M = block_tables.shape[1]
-    bs = cache["k"].shape[2]
-    rep = cfg.n_heads // cfg.n_kv_heads
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = ctx_lens[:, None] + idx[None, :]  # [B, C] global positions
-    valid = idx[None, :] < true_lens[:, None]
-    brange = jnp.arange(B, dtype=jnp.int32)
-    blk = jnp.where(
-        valid,
-        block_tables[brange[:, None], jnp.minimum(pos // bs, M - 1)],
-        0,
+    idx = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    cache, x, loads = _paged_layers(
+        cfg, params, cache, params["embed"][tokens],
+        ctx_lens[:, None] + idx, idx < true_lens[:, None], block_tables,
     )
-    off = pos % bs
-    flat_pos = pos.reshape(B * C)
-    cos, sin = _rope_at(cfg, flat_pos)
-    key_pos = jnp.arange(M * bs, dtype=jnp.int32)
-    mask = key_pos[None, None, :] <= pos[:, :, None]  # [B, C, M*bs]
-
-    x = params["embed"][tokens]  # [B, C, D]
-    loads = []
-    for layer, p in enumerate(params["layers"]):
-        q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
-        hd = q.shape[-1]
-        q = _apply_rope_flat(q.reshape(B * C, cfg.n_heads, hd), cos, sin)
-        k = _apply_rope_flat(k.reshape(B * C, cfg.n_kv_heads, hd), cos, sin)
-        cache = _scatter_kv(
-            cache, layer, blk.reshape(B * C), off.reshape(B * C),
-            k, v.reshape(B * C, cfg.n_kv_heads, hd),
-        )
-        # gather AFTER the scatter so each window attends to itself
-        ks = cache["k"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
-        vs = cache["v"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
-        qg = q.reshape(B, C, cfg.n_kv_heads, rep, hd)
-        s = jnp.einsum("bcgrh,bsgh->bcgrs", qg, ks).astype(jnp.float32) * scale
-        s = jnp.where(mask[:, :, None, None, :], s, -1e30)
-        pattn = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bcgrs,bsgh->bcgrh", pattn.astype(vs.dtype), vs)
-        o = o.reshape(B, C, cfg.n_heads, -1)
-        x = x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
-        x = _ffn_residual(cfg, p, x, valid, loads)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bcd,dv->bcv", x, params["lm_head"]).astype(jnp.float32)
-    return _step_outputs(cache, logits, loads)
+    return _step_outputs(cache, _lm_head(cfg, params, x), loads)
 
 
 def paged_decode_step(
     cfg: LlamaConfig, params, cache, tokens, positions, block_tables, ctx_lens
 ):
-    """One decode step for a BATCH of slots, fixed shapes.
+    """One decode step for a BATCH of slots (``C = 1`` of :func:`_paged_layers`).
 
     tokens: [B] int32 (this step's input token per slot), positions: [B]
     int32 (its global position), block_tables: [B, M] int32, ctx_lens: [B]
-    int32 (visible context length INCLUDING this token = positions+1 for
-    active slots; inactive padding slots carry ctx_len=1 and null blocks
-    so the softmax stays finite). Writes K/V, returns
-    ``(cache, logits [B, vocab])``, and for a MoE config the expert loads
-    of the active slots (``_step_outputs``): a slot whose K/V write lands
-    on the null block is padding and reaches no expert.
+    int32, ``positions + 1`` on a real slot (the mask is taken from
+    ``positions``; the argument stays for the callers). A slot whose token
+    would be written to the null block is padding: ``valid`` is taken
+    from the block table. Head: the one row a slot. Returns
+    ``(cache, logits [B, vocab])``, and a MoE config's expert loads
+    (``_step_outputs``).
     """
-    B, M = block_tables.shape
-    bs = cache["k"].shape[2]
-    rep = cfg.n_heads // cfg.n_kv_heads
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-
-    brange = jnp.arange(B, dtype=jnp.int32)
-    blk = block_tables[brange, jnp.minimum(positions // bs, M - 1)]
-    off = positions % bs
-    cos, sin = _rope_at(cfg, positions)
-    key_pos = jnp.arange(M * bs, dtype=jnp.int32)
-    mask = key_pos[None, :] < ctx_lens[:, None]  # [B, M*bs]
-
-    # a slot whose token is written to the null block is padding
-    valid = blk != 0
-    x = params["embed"][tokens]  # [B, D]
-    loads = []
-    for layer, p in enumerate(params["layers"]):
-        q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
-        q = _apply_rope_flat(q, cos, sin)
-        k = _apply_rope_flat(k, cos, sin)
-        cache = _scatter_kv(cache, layer, blk, off, k, v)
-        ks = cache["k"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
-        vs = cache["v"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
-        qg = q.reshape(B, cfg.n_kv_heads, rep, -1)
-        s = jnp.einsum("bgrh,bsgh->bgrs", qg, ks).astype(jnp.float32) * scale
-        s = jnp.where(mask[:, None, None, :], s, -1e30)
-        pattn = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bgrs,bsgh->bgrh", pattn.astype(vs.dtype), vs)
-        o = o.reshape(B, cfg.n_heads, -1)
-        x = x + jnp.einsum("bhk,hkd->bd", o.astype(x.dtype), p["wo"])
-        x = _ffn_residual(cfg, p, x, valid, loads)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bd,dv->bv", x, params["lm_head"]).astype(jnp.float32)
-    return _step_outputs(cache, logits, loads)
+    del ctx_lens
+    pos = positions[:, None]
+    valid = _block_at(block_tables, pos, cache["k"].shape[2]) != 0
+    cache, x, loads = _paged_layers(
+        cfg, params, cache, params["embed"][tokens][:, None], pos, valid, block_tables
+    )
+    return _step_outputs(cache, _lm_head(cfg, params, x[:, 0]), loads)
 
 
 def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = True,

@@ -53,9 +53,8 @@ def default_blocks(seq_q: int) -> tuple:
 #: wants SMALLER q tiles than the forward to keep double-buffering room,
 #: while big K tiles keep the MXU fed. Running forward-shaped blocks in
 #: the backward is where the r05 51% (fwd) → 28-34% (fwd+bwd) MFU cliff
-#: lived. Table seeded from the v5e VMEM model; bench.py emits the
-#: per-bucket choice + measured fwd+bwd MFU so real-chip sweeps can
-#: re-anchor it.
+#: lived. Table seeded from the v5e VMEM model; the kernels' share of a
+#: training step on the chip is ``flash_kernel_time_share`` (PERF.md).
 BWD_BLOCK_BUCKETS = (
     (1024, (256, 512)),
     (2048, (256, 1024)),

@@ -195,8 +195,8 @@ def test_warm_prefix_ttft_and_hit_rate_smoke():
 
 
 def test_ingress_http_path_smoke():
-    """HTTP ingress floor (bench.py's serve_http_ttft_p50_p99 /
-    ingress_goodput phase, floored): 4 concurrent SSE streams through
+    """HTTP ingress floor (TTFT through the real door and delivered
+    tokens/s): 4 concurrent SSE streams through
     the full stack — urllib → aiohttp ingress (bucket + shed policy) →
     router → streaming replica → engine. Warm numbers on this box are
     ~40-150 ms TTFT p50 and hundreds of delivered tokens/s; the floors
