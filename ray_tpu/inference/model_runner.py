@@ -7,18 +7,28 @@ engine must see ZERO recompiles — the jit cache holds exactly one entry
 per bucket, asserted via ``recompiles_after_warmup()`` (backed by
 ``PjitFunction._cache_size``).
 
-Decode and verify have a second bucketed axis: the WIDTH of the block
-table. The step functions gather ``cache[layer, block_tables]`` for every
-slot, so a step costs what the table's width costs, whatever the contexts
-in it. Callers still hand in ``max_blocks_per_seq``-wide rows; the runner
-cuts them to the narrowest rung of :func:`table_width_ladder` that covers
-the longest context of the batch (2048 tokens, then doublings, then the
-full width: derived from ``max_seq_len`` and ``block_size``, not
-configured). Positions past a slot's context were masked before the
-softmax anyway, so the logits are those of the full width. ``warmup()``
-compiles every (batch bucket x rung) pair, so a batch that crosses a rung
-in either direction finds its program compiled; the cache argument has
-the same shape in all of them. ``decode_width`` counts what was chosen.
+Decode and verify read the cache one of two ways (``models/llama.py::
+_paged_attention``), and the runner asks the same predicate which
+(``ops/paged_attention.py::kernel_serves``, from shapes and the backend):
+
+* the Pallas kernel (a TPU, whole tiles): every slot reads its own live
+  blocks and no other, so the table's width costs nothing. The runner hands
+  the full-width table, ``table_widths`` is the single full rung and there
+  is ONE program per batch bucket.
+* the gather (everywhere else): the step gathers ``cache[layer,
+  block_tables]`` for every slot, so it costs what the table's width costs,
+  whatever the contexts in it. Callers still hand in
+  ``max_blocks_per_seq``-wide rows; the runner cuts them to the narrowest
+  rung of :func:`table_width_ladder` that covers the longest context of the
+  batch (2048 tokens, then doublings, then the full width: derived from
+  ``max_seq_len`` and ``block_size``, not configured). Positions past a
+  slot's context were masked before the softmax anyway, so the logits are
+  those of the full width. ``warmup()`` compiles every (batch bucket x rung)
+  pair, so a batch that crosses a rung in either direction finds its program
+  compiled; the cache argument has the same shape in all of them.
+
+``decode_width`` counts what was handed over and what the launched program
+reads of the cache, either way.
 
 A MoE config's steps return a third output, the expert loads
 ``[n_layers, E]`` of the launch's real rows. It is copied to the host with
@@ -54,6 +64,7 @@ from ray_tpu.models.llama import (
     scatter_paged_blocks,
 )
 from ray_tpu.observability import timeline
+from ray_tpu.ops import paged_attention as paged_attn
 
 logger = logging.getLogger(__name__)
 
@@ -136,11 +147,22 @@ class PagedModelRunner:
         #: start-up account: seconds to allocate the cache, and per warmed
         #: program its compile (or load from the compile cache) and first run
         self.cache_alloc_s = time.perf_counter() - t0
-        #: block-table widths (blocks) decode and verify are compiled for
+        #: per query window (1 = decode, then the verify buckets): whether its
+        #: program runs the paged-attention kernel, which reads each slot's
+        #: own live blocks whatever the table's width
+        self.reads_live_blocks: Dict[int, bool] = {
+            c: paged_attn.kernel_serves(c, cfg.n_heads, self.cache["k"])
+            for c in (1, *self.verify_buckets)
+        }
+        #: block-table widths (blocks) decode and verify are compiled for:
+        #: the full width alone where the width costs none of them anything
         self.table_widths = table_width_ladder(cfg.max_seq_len, block_size)
-        #: running sums over decode and verify launches: the rung chosen
-        #: (tokens), the longest context that chose it, the contexts of
-        #: the real slots, and batch bucket x rung (what the program reads)
+        if all(self.reads_live_blocks.values()):
+            self.table_widths = self.table_widths[-1:]
+        #: running sums over decode and verify launches: the width handed
+        #: over (tokens), the longest context of the batch, the contexts of
+        #: the real slots, and the positions the program reads (the gather:
+        #: batch bucket x rung; the kernel: each real slot's live blocks)
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
         )
@@ -432,20 +454,26 @@ class PagedModelRunner:
         acc["max_load"] += int(loads.max(axis=1).sum())
         acc["mean_load"] += float(loads.mean(axis=1).sum())
 
-    def _table_width(self, ctx_lens: Sequence[int], bucket: int) -> int:
-        """The width, in blocks, at which this decode or verify launch
-        gathers: the first rung of :attr:`table_widths` that covers the
-        longest of ``ctx_lens`` (the real slots' contexts INCLUDING what
-        this step writes; padding slots fit any width). Counts the choice
-        in :attr:`decode_width`."""
+    def _table_width(self, ctx_lens: Sequence[int], bucket: int, window: int = 1) -> int:
+        """The width, in blocks, of the table this decode (``window`` 1) or
+        verify launch is handed: the first rung of :attr:`table_widths` that
+        covers the longest of ``ctx_lens`` (the real slots' contexts
+        INCLUDING what this step writes; padding slots fit any width).
+        Counts the choice, and what the program reads at it, in
+        :attr:`decode_width`."""
+        bs = self.block_size
         need = int(max(ctx_lens))
-        width = _round_up_bucket(-(-need // self.block_size), self.table_widths)
+        width = _round_up_bucket(-(-need // bs), self.table_widths)
+        if self.reads_live_blocks[window]:
+            read = sum(-(-int(c) // bs) for c in ctx_lens)  # a padding slot reads none
+        else:
+            read = bucket * width
         dw = self.decode_width
         dw["launches"] += 1
-        dw["width_tokens"] += width * self.block_size
+        dw["width_tokens"] += width * bs
         dw["needed_tokens"] += need
         dw["live_tokens"] += int(sum(ctx_lens))
-        dw["gathered_tokens"] += bucket * width * self.block_size
+        dw["gathered_tokens"] += read * bs
         return width
 
     def verify_batch(
@@ -462,7 +490,7 @@ class PagedModelRunner:
         per valid window position. The batch axis pads to a decode
         bucket; padding slots carry ``true_len=0`` so every position is
         invalid and the writes land on the null block. ``block_rows`` are
-        ``max_blocks_per_seq`` wide; the step gathers them only as wide as
+        ``max_blocks_per_seq`` wide; the step is handed them only as wide as
         the rung of :attr:`table_widths` that covers the longest
         ``ctx_len + len(window)``, a program :meth:`warmup` compiled."""
         clock = clock or self.clock
@@ -470,7 +498,7 @@ class PagedModelRunner:
         cbucket = _round_up_bucket(max(len(w) for w in windows), self.verify_buckets)
         bbucket = _round_up_bucket(n, self.decode_buckets)
         M = self._table_width(
-            [c + len(w) for c, w in zip(ctx_lens, windows)], bbucket
+            [c + len(w) for c, w in zip(ctx_lens, windows)], bbucket, cbucket
         )
         with clock.phase(
             "launch", program="paged_verify_step",
@@ -502,7 +530,7 @@ class PagedModelRunner:
     ) -> np.ndarray:
         """Advance a decode batch one token; returns logits [n, vocab]
         for the n REAL slots (padding stripped). ``block_rows`` are
-        ``max_blocks_per_seq`` wide; the step gathers them only as wide as
+        ``max_blocks_per_seq`` wide; the step is handed them only as wide as
         the rung of :attr:`table_widths` that covers ``max(ctx_lens)``, a
         program :meth:`warmup` compiled. What is cut off lay past every
         slot's context, which the step masks: the logits are those of the

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import paged_attention as paged_attn
 from ray_tpu.ops.attention import flash_attention, flash_attention_sharded
 from ray_tpu.parallel.sharding import constrain
 
@@ -690,16 +691,25 @@ def _block_at(block_tables, pos, bs: int):
 
 def _paged_attention(cfg: LlamaConfig, q, cache, layer: int, block_tables, pos):
     """Causal attention of ``q [B, C, H, hd]`` (rope applied) over the
-    cached context of its slot: gathers ``cache[layer, block_tables]``
-    (``block_tables [B, M]``), so K/V of the step's own tokens must be in
-    the cache already. Query ``(b, c)`` at global position ``pos[b, c]``
-    sees key position ``j`` of its slot iff ``j <= pos[b, c]``. Returns
-    ``[B, C, H, hd]``. GQA stays grouped ``[n_kv, rep]``; scores, mask and
-    softmax are float32.
+    cached context of its slot through ``block_tables [B, M]``, so K/V of
+    the step's own tokens must be in the cache already. Query ``(b, c)`` at
+    global position ``pos[b, c]`` sees key position ``j`` of its slot iff
+    ``j <= pos[b, c]``. Returns ``[B, C, H, hd]``. GQA stays grouped
+    ``[n_kv, rep]``; scores, mask and softmax are float32.
 
-    The ONE place a serving step reads the cache for attention: a
-    paged-attention kernel or a flash prefill replaces this function."""
+    The ONE place a serving step reads the cache for attention, two ways,
+    chosen at trace time from the shapes
+    (``ops/paged_attention.py::kernel_serves``): a short window (decode,
+    verify) on a TPU runs the Pallas kernel, which reads each slot's own
+    live blocks out of the whole cache and gathers nothing; a prefill chunk,
+    and everything off the chip, gathers ``cache[layer, block_tables]`` for
+    every slot as wide as the table (a flash prefill over context + chunk
+    is what would replace that half)."""
     B, C = pos.shape
+    if paged_attn.kernel_serves(C, cfg.n_heads, cache["k"]):
+        return paged_attn.paged_attention(
+            q, cache["k"], cache["v"], layer, block_tables, pos
+        )
     M = block_tables.shape[1]
     bs = cache["k"].shape[2]
     rep = cfg.n_heads // cfg.n_kv_heads

@@ -1,0 +1,271 @@
+"""Paged attention for a SHORT query window (decode, speculative verify):
+a Pallas TPU kernel that reads, for each slot, only the cache blocks that
+hold positions its queries may see, straight from the paged cache through
+the block table. Nothing of the cache is gathered, sliced or copied outside
+the kernel.
+
+The cache keeps its layout ``[n_layers, num_blocks, bs, n_kv, hd]`` (K and V
+apart): one block of one layer is contiguous, and read as rows it is
+``[bs * n_kv, hd]``, token-major, KV head minor. The kernel never parts the
+heads. A wave of ``P`` blocks is one ``[P * bs * n_kv, hd]`` matrix; EVERY
+query head is multiplied against EVERY row of it, and the columns of another
+KV head are masked with the positions past the query's own. That spends
+``n_kv`` times the FLOPs the mathematics needs and saves every relayout:
+decode attention is bound by the bytes of K and V, each row of a wave goes
+through the MXU once whichever heads ride along, and only the softmax's
+vector work grows.
+
+One invocation a layer, no grid: a loop over the slots and, inside, over the
+slot's OWN waves (it ends at the slot's last live block, whatever the
+table's width). The block table, the positions and the layer's index are
+scalar-prefetched; each live block of a wave is one DMA for K and one for V
+into one of two VMEM buffers, and the next wave (of this slot, or the first
+of the next slot that has any) is in flight while this one is multiplied.
+Online softmax with float32 scores, state and accumulator; ``P`` is cast to
+the cache's dtype for the second matmul, as the gather path does.
+
+A PADDING slot (its table starts on the null block: block 0 is never
+allocated, so no request's does) reads nothing and comes back as zeros. What
+may be stale or never written (the tail of a slot's last block, the blocks
+of a wave that were not fetched) is masked out of the scores and zeroed in V
+before the second matmul: a NaN there cannot reach the output.
+
+The layer is an operand, not a constant of the kernel, and the call is
+jitted by itself: the model's 16 calls are one traced and lowered kernel,
+which is what keeps a decode program's start-up at the gather's.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+#: what a masked score is set to (the gather path's value)
+_MASKED = -1e30
+
+#: query rows (window x heads) up to which the kernel serves; a prefill chunk
+#: (256 x 32 rows) wants a flash kernel over context + chunk, not this one
+_MAX_QUERY_ROWS = 256
+
+#: rows ``[bs * n_kv a block, hd]`` a DMA wave brings in, as whole blocks: on the
+#: chip 2048 beat 1024 on 8 KV heads (a table full of context at 86% against
+#: 72% of the HBM roofline) and tied 4096 on 16 (PERF.md, PR 30)
+_WAVE_ROWS = 2048
+
+
+def kernel_serves(window: int, n_heads: int, k_cache, backend: str | None = None) -> bool:
+    """Whether :func:`paged_attention` runs the kernel for a query window of
+    ``window`` positions a slot over ``k_cache`` (anything with the shape
+    ``[n_layers, num_blocks, bs, n_kv, hd]`` and the dtype of the paged
+    cache): on a TPU, for a short window, where the heads of a token are
+    whole ``(8, 128)`` tiles and a block read as ``[bs * n_kv, hd]`` rows is
+    whole ``(16, 128)`` ones (what Mosaic compiles: probed for a described
+    v5e, PERF.md PR 30), in a dtype the MXU multiplies. Everything else (the
+    CPU, a prefill chunk, odd widths) takes the gather. Decided at trace
+    time; the model runner asks the same question to know what a launch
+    reads."""
+    backend = backend or jax.default_backend()
+    _, _, bs, n_kv, hd = k_cache.shape
+    return (
+        backend == "tpu"
+        and window * n_heads <= _MAX_QUERY_ROWS
+        and k_cache.dtype in (jnp.bfloat16, jnp.float32)
+        and hd % 128 == 0
+        and n_kv % 8 == 0
+        and (bs * n_kv) % 16 == 0
+        and n_heads % n_kv == 0
+    )
+
+
+def _kernel(
+    tables_ref,  # SMEM [B * M] int32
+    pos_ref,  # SMEM [B * C] int32
+    nblk_ref,  # SMEM [B] int32: live blocks of the slot, 0 for a padding slot
+    next_ref,  # SMEM [B + 1] int32: the first slot >= i that has live blocks (B: none)
+    layer_ref,  # SMEM [1] int32
+    q_ref,  # VMEM [B, C * H, hd]
+    k_hbm,  # ANY [L, N, bs, n_kv, hd]
+    v_hbm,
+    o_ref,  # VMEM [B, C * H, hd]
+    kbuf,  # VMEM [2, P, bs, n_kv, hd]
+    vbuf,
+    sems,  # DMA [2 (k, v), 2 (buffer)]
+    *,
+    window: int,
+    table_width: int,
+):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, rows, hd = q_ref.shape
+    _, P, bs, n_kv, _ = kbuf.shape
+    C, M = window, table_width
+    H = rows // C
+    rep = H // n_kv
+    R = P * bs * n_kv
+    scale = 1.0 / math.sqrt(hd)
+    layer = layer_ref[0]
+
+    def wave_copies(b, w, buf, i):
+        blk = tables_ref[b * M + w * P + i]
+        return (
+            pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[buf, i], sems.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[buf, i], sems.at[1, buf]),
+        )
+
+    def each_live_block(b, w, buf, do):
+        def body(i, carry):
+            for copy in wave_copies(b, w, buf, i):
+                do(copy)
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(P, nblk_ref[b] - w * P), body, 0)
+
+    def start_wave(b, w, buf):
+        each_live_block(b, w, buf, lambda copy: copy.start())
+
+    def wait_wave(b, w, buf):
+        each_live_block(b, w, buf, lambda copy: copy.wait())
+
+    # static over the call: which KV head a score's row and column belong to,
+    # and the token of a column / of a V row inside its wave
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, R), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, R), 1)
+    same_head = (row % H) // rep == col % n_kv
+    col_tok = col // n_kv
+    vrow_tok = jax.lax.broadcasted_iota(jnp.int32, (R, hd), 0) // n_kv
+    row_c = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // H
+
+    @pl.when(next_ref[0] < B)
+    def _():
+        start_wave(next_ref[0], 0, 0)
+
+    def slot(b, buf):
+        n_waves = pl.cdiv(nblk_ref[b], P)
+        q = q_ref[b]
+        # the last position each query row may see, and the slot's own last
+        limit = jnp.full((rows, 1), pos_ref[b * C], jnp.int32)
+        last = pos_ref[b * C]
+        for c in range(1, C):
+            limit = jnp.where(row_c == c, pos_ref[b * C + c], limit)
+            last = jnp.maximum(last, pos_ref[b * C + c])
+
+        def wave(w, carry):
+            m, l, acc, buf = carry
+            ends_slot = w + 1 == n_waves
+            nb = jnp.where(ends_slot, next_ref[b + 1], b)
+
+            @pl.when(nb < B)
+            def _():
+                start_wave(nb, jnp.where(ends_slot, 0, w + 1), 1 - buf)
+
+            wait_wave(b, w, buf)
+            base = w * (P * bs)
+
+            @pl.when(base + P * bs > last + 1)
+            def _():  # rows past the slot's context: stale, or never fetched
+                v = vbuf[buf].reshape(R, hd)
+                vbuf[buf] = jnp.where(vrow_tok <= last - base, v, 0).reshape(vbuf.shape[1:])
+
+            k = kbuf[buf].reshape(R, hd)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            s = jnp.where(same_head & (col_tok <= limit - base), s * scale, _MASKED)
+            m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + p.sum(axis=1, keepdims=True)
+            v = vbuf[buf].reshape(R, hd)
+            acc = alpha * acc + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - buf
+
+        # wave 0 holds position 0, which every row sees: m is real after it
+        m, l, acc, buf = jax.lax.fori_loop(
+            0, n_waves, wave,
+            (
+                jnp.full((rows, 1), _MASKED, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32),
+                jnp.zeros((rows, hd), jnp.float32),
+                buf,
+            ),
+        )
+        # a padding slot ran no wave: zeros, not 0 / 0
+        o_ref[b] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+        return buf
+
+    jax.lax.fori_loop(0, B, slot, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("wave_blocks", "interpret"))
+def _call(q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks: int, interpret: bool):
+    # imported here, as ops/attention.py does: a second of import that only a
+    # process which runs the kernel pays (the model module is imported by all)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, C, H, hd = q.shape
+    _, _, bs, n_kv, _ = k_cache.shape
+    M, P = block_tables.shape[1], wave_blocks
+    # block 0 is the null block: a table that starts on it is a padding slot's
+    nblk = jnp.where(block_tables[:, 0] == 0, 0, jnp.minimum(pos.max(axis=1) // bs + 1, M))
+    slots = jnp.arange(B, dtype=jnp.int32)
+    first_live_from = jax.lax.cummin(jnp.where(nblk > 0, slots, B), reverse=True)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_kernel, window=C, table_width=M),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(),
+            in_specs=[vmem, any_space, any_space],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, P, bs, n_kv, hd), k_cache.dtype),
+                pltpu.VMEM((2, P, bs, n_kv, hd), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, C * H, hd), q.dtype),
+        name="paged_attn",
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(
+        block_tables.reshape(-1),
+        pos.reshape(-1),
+        nblk,
+        jnp.append(first_live_from, B),
+        layer.reshape(1),
+        q.reshape(B, C * H, hd),
+        k_cache,
+        v_cache,
+    )
+    return out.reshape(B, C, H, hd)
+
+
+def paged_attention(
+    q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks=None, interpret=None
+):
+    """Causal attention of ``q [B, C, H, hd]`` over each slot's cached
+    context: query ``(b, c)`` sees key position ``j`` of slot ``b`` iff
+    ``j <= pos[b, c]``. ``k_cache`` / ``v_cache`` are the WHOLE paged caches
+    ``[n_layers, num_blocks, bs, n_kv, hd]`` (``layer`` is indexed inside the
+    kernel), ``block_tables [B, M]`` int32, ``pos [B, C]`` int32. Returns
+    ``[B, C, H, hd]`` in ``q``'s dtype. A slot reads
+    ``min(max_c pos[b, c] // bs + 1, M)`` blocks and no other; a padding slot
+    (``block_tables[b, 0] == 0``) reads none and returns zeros.
+
+    ``wave_blocks``: blocks a DMA wave (default: ``_WAVE_ROWS`` rows of K).
+    ``interpret``: run the kernel in Pallas' TPU interpreter (what the CPU
+    tests do); by default wherever the backend is not a TPU."""
+    _, _, bs, n_kv, _ = k_cache.shape
+    M = block_tables.shape[1]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _call(
+        q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), block_tables, pos,
+        wave_blocks=wave_blocks or min(M, max(1, _WAVE_ROWS // (bs * n_kv))),
+        interpret=bool(interpret),
+    )

@@ -2,7 +2,13 @@
 gather the block table only as wide as the rung that covers the batch's
 longest context. Cluster-free and CPU-runnable; the model is tiny in every
 width but ``max_seq_len`` 4096, so the ladder has the benchmark's two rungs
-(2048 and 4096 tokens at ``block_size`` 16)."""
+(2048 and 4096 tokens at ``block_size`` 16).
+
+Where the paged-attention kernel serves decode and verify (ISSUE 30) the
+width costs nothing: one full-width program a batch bucket, and the counter
+says what the kernel reads. The last cases force that path on the CPU by
+patching the selection predicate (the kernel then runs in Pallas' TPU
+interpreter); the program has no option for it."""
 
 import numpy as np
 import pytest
@@ -13,6 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 from ray_tpu.inference.engine import EngineConfig, InferenceEngine  # noqa: E402
 from ray_tpu.inference.model_runner import PagedModelRunner, table_width_ladder  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.ops import paged_attention as PA  # noqa: E402
 
 BS = 16
 NUM_BLOCKS = 400
@@ -181,15 +188,19 @@ def test_counter_matches_a_hand_count_for_a_scripted_batch(cfg, params):
     assert runner.compile_count() == 2
 
 
-@pytest.fixture(scope="module")
-def engine(cfg, params):
-    eng = InferenceEngine(
+def _engine(cfg, params):
+    return InferenceEngine(
         cfg, params,
         EngineConfig(
             num_blocks=NUM_BLOCKS, block_size=BS, prefill_buckets=(64, 256), decode_buckets=(4,),
             max_decode_batch=4, prefix_cache_enabled=False,
         ),
     ).start()
+
+
+@pytest.fixture(scope="module")
+def engine(cfg, params):
+    eng = _engine(cfg, params)
     try:
         yield eng
     finally:
@@ -234,3 +245,96 @@ def test_a_request_growing_over_a_rung_compiles_nothing(engine):
     assert got["live_tokens"] <= got["gathered_tokens"]
     assert engine.stats()["recompiles_after_warmup"] == 0
     assert runner.compile_count() == 2 + 1 * 2 + 1
+
+
+# -- the kernel path, forced: the width costs nothing ---------------------------------
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """The predicate as it reads on a TPU at whole tiles: short windows take
+    the kernel, a prefill chunk the gather."""
+    monkeypatch.setattr(
+        PA, "kernel_serves",
+        lambda window, n_heads, k_cache, backend=None: window * n_heads <= 64,
+    )
+
+
+@pytest.mark.parametrize(
+    "script",
+    [[(5, 300, 2048, 1000), (5, 300, 2049, 1000)], [(17, 4096, 2, 33), (2047, 40, 2049, 900)]],
+    ids=["over_the_2048_rung", "the_full_width_and_back"],
+)
+def test_kernel_decode_is_one_program_and_counts_live_blocks(cfg, params, runners, kernel_forced, script):
+    """Full buckets whose longest context crosses what used to be a rung: the
+    logits are the ladder's, ONE program serves both, and the counter reads
+    each slot's live blocks."""
+    runner = _runner(cfg, params)
+    assert runner.reads_live_blocks == {1: True, 4: True} and runner.table_widths == (256,)
+    for ctx_lens in script:
+        call = lambda r: r.decode(  # noqa: E731
+            [7, 8, 9, 10], [c - 1 for c in ctx_lens], _rows(r, ctx_lens), list(ctx_lens)
+        )
+        kernel, ladder = _both((runner, runners[0]), 2, call)
+        _assert_same(kernel, ladder)
+        blocks = sum(-(-c // BS) for c in ctx_lens)
+        assert kernel[3] == {
+            "launches": 1, "width_tokens": 4096, "needed_tokens": max(ctx_lens),
+            "live_tokens": sum(ctx_lens), "gathered_tokens": blocks * BS,
+        }
+        mean = sum(ctx_lens) / len(ctx_lens)
+        assert kernel[3]["live_tokens"] / kernel[3]["gathered_tokens"] >= 1 - BS / mean
+    assert runner.compile_count() == 1  # the ladder compiled one a rung
+    assert runners[0].reads_live_blocks == {1: False, 4: False}
+
+
+def test_kernel_reads_nothing_for_a_padding_slot_and_serves_verify(cfg, params, runners, kernel_forced):
+    runner = _runner(cfg, params)
+    windows = [[3, 4, 5, 6], [9, 8]]
+    call = lambda r: np.concatenate(  # noqa: E731
+        r.verify_batch(windows, _rows(r, [2046 + 4, 42]), [2046, 40])
+    )
+    kernel, ladder = _both((runner, runners[0]), 1, call)
+    # the null block is where the two differ: a padding slot's trash is the
+    # kernel's zeros through a layer there, the gather's attention elsewhere
+    null = lambda written: {w for w in written if w[2] == 0}  # noqa: E731
+    assert null(kernel[1]) == null(ladder[1])
+    sides = [
+        (logits, written - null(written), [np.delete(x, 0, axis=1) for x in cache])
+        for logits, written, cache, _ in (kernel, ladder)
+    ]
+    _assert_same(*sides)
+    assert ladder[3]["width_tokens"] == 4096  # 2046 + 4 crossed the rung there
+    # 129 + 3 live blocks; the two padding slots read nothing
+    assert kernel[3] == {
+        "launches": 1, "width_tokens": 4096, "needed_tokens": 2050,
+        "live_tokens": 2050 + 42, "gathered_tokens": (129 + 3) * BS,
+    }
+
+
+def test_kernel_engine_warms_one_decode_program_and_never_recompiles(cfg, params, engine, kernel_forced):
+    """The request of ``test_a_request_growing_over_a_rung_compiles_nothing``
+    on an engine whose decode takes the kernel: one decode program instead of
+    two, the same greedy tokens, nothing compiled on the way over 2048."""
+    eng = _engine(cfg, params)
+    try:
+        runner = eng.runner
+        assert runner.table_widths == (256,)
+        assert runner.compile_count() == 2 + 1 + 1  # prefill buckets, ONE decode program, the COW copy
+        programs = set(eng.stats()["startup"]["warmup_programs"])
+        assert "paged_decode_step[4x4096]" in programs and "paged_decode_step[4x2048]" not in programs
+        prompt = [int(t) for t in np.random.RandomState(0).randint(1, 256, size=2034)]
+        start = eng.stats()["decode_width"]
+        tokens = list(eng.generate(prompt, max_new_tokens=30))
+        end = eng.stats()["decode_width"]
+        assert tokens == list(engine.generate(prompt, max_new_tokens=30))  # the ladder's engine
+        got = {k: end[k] - start[k] for k in COUNTERS}
+        contexts = range(2035, 2064)
+        assert got == {
+            "launches": 29, "width_tokens": 29 * 4096, "needed_tokens": sum(contexts),
+            "live_tokens": sum(contexts),
+            "gathered_tokens": sum(-(-c // BS) for c in contexts) * BS,  # three padding slots read nothing
+        }
+        assert eng.stats()["recompiles_after_warmup"] == 0
+        assert runner.compile_count() == 2 + 1 + 1
+    finally:
+        eng.stop()

@@ -413,3 +413,39 @@ def test_the_grouped_matmul_compiles_for_the_chip_at_olmoe_widths(one_chip, monk
         assert rows % tile == 0 or rows < tile
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize(
+    "layers, blocks, n_kv, heads, window",
+    [(16, 6144, 8, 32, 1), (16, 6144, 8, 32, 8), (12, 2240, 16, 16, 1), (12, 2240, 16, 16, 4)],
+    ids=["mistral_decode", "mistral_verify_8", "olmoe_decode", "olmoe_verify_4"],
+)
+def test_the_paged_attention_kernel_compiles_for_the_chip_at_the_benchmark_widths(
+    one_chip, layers, blocks, n_kv, heads, window
+):
+    """The other kernel of the serving path (``ops/paged_attention.py``; here
+    because a second file of such compiles would go to another xdist worker
+    and skip): both configurations' whole caches as the benchmark sizes them,
+    32 slots, the full-width table. The cache goes in as it lies (no copy of
+    it among the temporaries) and there is one Mosaic call."""
+    from ray_tpu.ops import paged_attention as PA
+
+    cache_like = jax.ShapeDtypeStruct((layers, blocks, 16, n_kv, 128), jnp.bfloat16)
+    assert PA.kernel_serves(window, heads, cache_like, backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        cache = shape((layers, blocks, 16, n_kv, 128), jnp.bfloat16)
+        compiled = jax.jit(
+            lambda q, k, v, tables, pos: PA.paged_attention(q, k, v, layers - 1, tables, pos, interpret=False)
+        ).lower(
+            shape((32, window, heads, 128), jnp.bfloat16), cache, cache,
+            shape((32, 256), jnp.int32), shape((32, window), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "paged_attn" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+        assert compiled.out_info.shape == (32, window, heads, 128)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)

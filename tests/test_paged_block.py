@@ -164,3 +164,71 @@ def test_every_entry_point_attends_through_the_one_seam(monkeypatch, step):
 
     monkeypatch.setattr(L, "_paged_attention", lambda cfg_, q, *rest: jnp.zeros_like(q))
     assert _rel(fn(cfg, params, cache, *args)[1], logits) > 1e-2
+
+
+# -- the two ways through the one door (ISSUE 30) ---------------------------------------
+
+def _tile_model():
+    """Tiny, but with whole tiles: ``head_dim`` 128 and 8 KV heads, so that on
+    a TPU the predicate looks at the WINDOW."""
+    cfg = L.LlamaConfig.tiny(dim=1024, n_heads=8, n_kv_heads=8, max_seq_len=8 * WIDTH)
+    shapes = jax.eval_shape(partial(L.init_params, cfg), jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return cfg, shapes, jax.eval_shape(partial(L.init_paged_kv_cache, cfg, NUM_BLOCKS, 8))
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+@pytest.mark.parametrize("chunk", [256, 1024])
+def test_a_prefill_chunk_lowers_with_no_pallas_call_even_on_a_tpu(monkeypatch, chunk):
+    """A chunk-sized window keeps the gather whatever the backend: selected on
+    ``C`` at trace time, so prefill's program is what it was before there was
+    a kernel (its StableHLO at the benchmark's widths is the parent's, PERF.md
+    PR 30)."""
+    cfg, params, cache = _tile_model()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the predicate; the test's business
+    assert L.paged_attn.kernel_serves(1, cfg.n_heads, cache["k"])
+    assert not L.paged_attn.kernel_serves(chunk, cfg.n_heads, cache["k"])
+    monkeypatch.setattr(
+        L.paged_attn, "paged_attention",
+        lambda *a, **kw: pytest.fail("a prefill chunk reached the paged-attention kernel"),
+    )
+    text = jax.jit(partial(L.paged_prefill_step, cfg)).lower(
+        params, cache, _i32(chunk), _i32(WIDTH), _i32(), _i32()
+    ).as_text()
+    assert "custom_call" not in text and "gather" in text
+
+
+@pytest.mark.parametrize("step", ["verify", "decode"])
+def test_the_kernel_path_is_still_the_one_door(monkeypatch, step):
+    """Where the predicate says kernel, ``_paged_attention`` is still called
+    once a layer and is the only caller of the kernel: once a layer, with the
+    WHOLE cache (no layer of it sliced off outside) and the layer's index."""
+    cfg, params, cache = _tile_model()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    doors, kernels = [], []
+    real = L._paged_attention
+
+    def door(cfg_, q, cache_, layer, block_tables, pos):
+        doors.append(layer)
+        return real(cfg_, q, cache_, layer, block_tables, pos)
+
+    def kernel(q, k_cache, v_cache, layer, block_tables, pos):
+        kernels.append((layer, len(doors), k_cache.shape, v_cache.shape, q.shape, pos.shape))
+        return jnp.zeros_like(q)
+
+    monkeypatch.setattr(L, "_paged_attention", door)
+    monkeypatch.setattr(L.paged_attn, "paged_attention", kernel)
+    args = {
+        "verify": (_i32(2, 4), _i32(2, WIDTH), _i32(2), _i32(2)),
+        "decode": (_i32(2), _i32(2), _i32(2, WIDTH), _i32(2)),
+    }[step]
+    jax.eval_shape(partial(getattr(L, f"paged_{step}_step"), cfg), params, cache, *args)
+    assert doors == list(range(cfg.n_layers))
+    window = 4 if step == "verify" else 1
+    assert kernels == [
+        (layer, layer + 1, cache["k"].shape, cache["v"].shape,
+         (2, window, cfg.n_heads, cfg.head_dim), (2, window))
+        for layer in range(cfg.n_layers)
+    ]
