@@ -560,9 +560,9 @@ class InferenceEngine:
                 if draft_params is None:
                     import jax
 
-                    from ray_tpu.models.llama import init_params
+                    from ray_tpu.models.interface import model_of
 
-                    draft_params = init_params(
+                    draft_params = model_of(ec.draft_config).init_params(
                         ec.draft_config, jax.random.PRNGKey(ec.draft_seed)
                     )
                 self.spec = DraftModelProposer(
@@ -675,7 +675,7 @@ class InferenceEngine:
                 did_work = self.step(hold_wakes=True)
             except Exception as e:  # noqa: BLE001 — fail in-flight, keep serving
                 self._fail_all(e)
-                if self.runner.cache["k"].is_deleted():
+                if any(a.is_deleted() for a in self.runner.cache.values()):
                     # the step died AFTER its jit call consumed the donated
                     # cache: every later step would fail on a deleted
                     # buffer. Stop the loop — healthy() turns False and the
@@ -2167,6 +2167,8 @@ class InferenceEngine:
             # how wide the decode and verify launches gathered (the target
             # runner's own; a draft model's runner keeps its own count)
             "decode_width": dict(self.runner.decode_width),
+            # what a token leaves in the cache (the model's description)
+            "kv_layout": self.runner.cache_layout.describe(),
             "request_stages": dict(self._request_stages),
             "startup": {
                 **self.startup,

@@ -1,10 +1,11 @@
 """Paged KV cache management: the host-side block allocator.
 
 vLLM-style paging (PAPERS.md: TPU serving stacks win by packing many
-requests into one fixed-shape KV cache): the device holds
-``[n_layers, num_blocks, block_size, n_kv_heads, head_dim]`` K and V
-tensors (``models.llama.init_paged_kv_cache``); this module owns the
-*accounting* — which request holds which block ids, what is free, and
+requests into one fixed-shape KV cache): the device holds what the model's
+cache description says a token leaves in each layer
+(``models/interface.py::CacheLayout``: ``[n_layers, num_blocks, block_size,
+n_kv_heads, head_dim]`` K and V tensors, or one latent row a token); this
+module owns the *accounting* — which request holds which block ids, what is free, and
 when a new request must wait in the admission queue instead.
 
 Block id 0 is reserved as the NULL block: padding positions in the

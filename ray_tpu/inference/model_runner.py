@@ -7,9 +7,16 @@ engine must see ZERO recompiles — the jit cache holds exactly one entry
 per bucket, asserted via ``recompiles_after_warmup()`` (backed by
 ``PjitFunction._cache_size``).
 
-Decode and verify read the cache one of two ways (``models/llama.py::
-_paged_attention``), and the runner asks the same predicate which
-(``ops/paged_attention.py::kernel_serves``, from shapes and the backend):
+The model is the runner's only through ``models/interface.py``: ``model_of(
+cfg)`` gives the three paged entry points it jits, the cache description
+(``CacheLayout``: what it allocates, copies, exports and imports) and the
+attention path each query window takes (``Model.attention_path``, asked
+once a window: ``attention_paths``).
+
+Decode and verify read the cache one of these ways (``AttentionPath.reads``;
+for ``models/llama.py`` the predicate ``ops/paged_attention.py::
+kernel_serves`` says which, from shapes and the backend; ``models/xing4.py``'s
+latent paths both gather, the absorbed one for the real slots alone):
 
 * the Pallas kernel (a TPU, whole tiles): every slot reads its own live
   blocks and no other, so the table's width costs nothing. The runner hands
@@ -31,7 +38,8 @@ _paged_attention``), and the runner asks the same predicate which
 reads of the cache, either way.
 
 A MoE config's steps return a third output, the expert loads
-``[n_layers, E]`` of the launch's real rows. It is copied to the host with
+``[n_layers, E]`` of the launch's real rows (or a dict with them under
+``load`` beside further counters a layer). It is copied to the host with
 the logits, inside ``readback`` (the device has finished by then: no second
 sync), and summed into ``moe`` (:meth:`_account_moe`). A dense config's
 steps have two outputs and no such account.
@@ -53,18 +61,14 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ray_tpu.models.llama import (
-    LlamaConfig,
+from ray_tpu.models.interface import (
+    AttentionPath,
     copy_paged_blocks,
     gather_paged_blocks,
-    init_paged_kv_cache,
-    paged_decode_step,
-    paged_prefill_step,
-    paged_verify_step,
+    model_of,
     scatter_paged_blocks,
 )
 from ray_tpu.observability import timeline
-from ray_tpu.ops import paged_attention as paged_attn
 
 logger = logging.getLogger(__name__)
 
@@ -107,7 +111,7 @@ def _round_up_bucket(n: int, buckets: Sequence[int]) -> int:
 class PagedModelRunner:
     def __init__(
         self,
-        cfg: LlamaConfig,
+        cfg,
         params,
         *,
         num_blocks: int,
@@ -124,6 +128,10 @@ class PagedModelRunner:
         #: the calls of its step loop: a PhaseClock is one thread's)
         self.clock = timeline.PhaseClock("runner")
         self.cfg = cfg
+        #: the model module's side of the interface (``models/interface.py``):
+        #: the three paged entry points, the cache description, what a
+        #: window's attention reads
+        self.model = model_of(cfg)
         self.params = params
         self.block_size = block_size
         self.num_blocks = num_blocks
@@ -140,29 +148,33 @@ class PagedModelRunner:
                 f"num_blocks={num_blocks} can't hold one max-length sequence "
                 f"({self.max_blocks_per_seq} blocks + null block)"
             )
+        #: what a token leaves in the cache, owned by the model: the device
+        #: tensors, the block copy, the export / import / tier payload and
+        #: the pool's bytes all follow it
+        self.cache_layout = self.model.cache_layout(cfg, block_size, cache_dtype)
         t0 = time.perf_counter()
-        self.cache = jax.block_until_ready(
-            init_paged_kv_cache(cfg, num_blocks, block_size, cache_dtype)
-        )
+        self.cache = jax.block_until_ready(self.cache_layout.init(num_blocks))
         #: start-up account: seconds to allocate the cache, and per warmed
         #: program its compile (or load from the compile cache) and first run
         self.cache_alloc_s = time.perf_counter() - t0
-        #: per query window (1 = decode, then the verify buckets): whether its
-        #: program runs the paged-attention kernel, which reads each slot's
-        #: own live blocks whatever the table's width
-        self.reads_live_blocks: Dict[int, bool] = {
-            c: paged_attn.kernel_serves(c, cfg.n_heads, self.cache["k"])
-            for c in (1, *self.verify_buckets)
+        #: per query window (1 = decode, the verify buckets; a prefill bucket
+        #: at its first launch: :meth:`_path`): the attention path its program
+        #: takes, as the model names it (the launch span's ``path``), and what
+        #: a decode or verify launch reads of the cache on it
+        #: (:meth:`_table_width`). Fixed a program, so asked once a window
+        self.attention_paths: Dict[int, AttentionPath] = {
+            c: self.model.attention_path(cfg, c, self.cache) for c in (1, *self.verify_buckets)
         }
         #: block-table widths (blocks) decode and verify are compiled for:
         #: the full width alone where the width costs none of them anything
         self.table_widths = table_width_ladder(cfg.max_seq_len, block_size)
-        if all(self.reads_live_blocks.values()):
+        if all(self.attention_paths[c].reads == "blocks" for c in (1, *self.verify_buckets)):
             self.table_widths = self.table_widths[-1:]
         #: running sums over decode and verify launches: the width handed
         #: over (tokens), the longest context of the batch, the contexts of
         #: the real slots, and the positions the program reads (the gather:
-        #: batch bucket x rung; the kernel: each real slot's live blocks)
+        #: batch bucket x rung, or real slots x rung where a padding slot
+        #: reads nothing; the kernel: each real slot's live blocks)
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
         )
@@ -170,26 +182,29 @@ class PagedModelRunner:
         #: decode and verify launches and, apart, prefill launches
         #: (:meth:`_account_moe`); ``None`` for a dense model
         self.moe: Optional[Dict[str, Dict[str, float]]] = None
-        if cfg.moe_experts > 0:
-            keys = ("launches", "assignments", "expert_slots", "experts_touched",
-                    "max_load", "mean_load")
+        #: the range of experts this process holds (all of them unless the
+        #: model is one chip's share of an expert-parallel deployment)
+        self.held_experts: Optional[Tuple[int, int]] = self.model.held_experts(cfg)
+        if self.held_experts is not None:
+            keys = ("launches", "assignments", "held_assignments", "bias_changed",
+                    "expert_slots", "experts_touched", "max_load", "mean_load")
             self.moe = {kind: dict.fromkeys(keys, 0) for kind in ("decode", "prefill")}
         self.warmup_programs: Dict[str, float] = {}
 
         # argument 1 of the partials (cfg is bound) is the cache: donated,
         # updated in place — at a real width a copied cache does not fit
         self._prefill_jit = jax.jit(
-            partial(paged_prefill_step, cfg), donate_argnums=(1,)
+            partial(self.model.paged_prefill_step, cfg), donate_argnums=(1,)
         )
         self._decode_jit = jax.jit(
-            partial(paged_decode_step, cfg), donate_argnums=(1,)
+            partial(self.model.paged_decode_step, cfg), donate_argnums=(1,)
         )
         # speculative verification: prefill-shaped, all-position logits.
         # Always constructed (an uncalled jit holds zero cache entries so
         # compile accounting is unchanged), only warmed when the engine
         # passes verify buckets.
         self._verify_jit = jax.jit(
-            partial(paged_verify_step, cfg), donate_argnums=(1,)
+            partial(self.model.paged_verify_step, cfg), donate_argnums=(1,)
         )
         # COW block duplication (prefix cache): cache is arg 0 here.
         # partial() gives THIS runner its own jit identity — a bare
@@ -266,6 +281,15 @@ class PagedModelRunner:
         the device; ``loads`` is None for a dense model."""
         self.cache, logits, *loads = run(program, fn, self.params, self.cache, *args)
         return logits, (loads[0] if loads else None)
+
+    def _path(self, window: int) -> AttentionPath:
+        """The attention path of the programs of that query window."""
+        path = self.attention_paths.get(window)
+        if path is None:
+            path = self.attention_paths[window] = self.model.attention_path(
+                self.cfg, window, self.cache
+            )
+        return path
 
     def compile_count(self) -> int:
         return self._jit_cache_entries()
@@ -352,8 +376,10 @@ class PagedModelRunner:
 
     def gather_blocks(self, block_ids: Sequence[int]) -> np.ndarray:
         """Read whole cache blocks to host (KV-migration export):
-        returns ``[2, n_layers, len(block_ids), block_size, n_kv,
-        head_dim]`` numpy in the cache dtype. Runs in _KV_IO_WIDTH
+        returns the cache layout's payload (``CacheLayout.payload_shape``:
+        ``[2, n_layers, len(block_ids), block_size, n_kv, head_dim]`` for a
+        KV cache, ``[1, n_layers, len(block_ids), block_size, row]`` for a
+        latent one) as numpy in the cache dtype. Runs in _KV_IO_WIDTH
         chunks padded with the null block so the compiled shape never
         varies; padding rows are sliced off before concatenation."""
         outs = []
@@ -364,11 +390,8 @@ class PagedModelRunner:
             out = self._run("gather_paged_blocks", self._gather_jit, self.cache, ids)
             outs.append(np.asarray(out)[:, :, : len(chunk)])
         if not outs:
-            shape = self.cache["k"].shape  # [L, N, bs, kv, hd]
-            return np.zeros(
-                (2, shape[0], 0, shape[2], shape[3], shape[4]),
-                np.asarray(self.cache["k"]).dtype,
-            )
+            layout = self.cache_layout
+            return np.zeros(layout.payload_shape(0), np.dtype(layout.dtype))
         return np.concatenate(outs, axis=2) if len(outs) > 1 else outs[0]
 
     def scatter_blocks(self, block_ids: Sequence[int], kv: np.ndarray) -> None:
@@ -406,7 +429,10 @@ class PagedModelRunner:
         clock = clock or self.clock
         true_len = len(tokens)
         bucket = _round_up_bucket(true_len, self.prefill_buckets)
-        with clock.phase("launch", program="paged_prefill_step", bucket=bucket):
+        with clock.phase(
+            "launch", program="paged_prefill_step", bucket=bucket,
+            path=self._path(bucket).name,
+        ):
             padded = np.zeros(bucket, np.int32)
             padded[:true_len] = tokens
             row = np.asarray(block_row, np.int32)
@@ -434,21 +460,32 @@ class PagedModelRunner:
         with clock.phase("readback"):
             host = np.asarray(logits)
             if loads is not None:
-                self._account_moe(kind, np.asarray(loads))
+                counters = loads if isinstance(loads, dict) else {"load": loads}
+                self._account_moe(kind, {k: np.asarray(v) for k, v in counters.items()})
         return host
 
-    def _account_moe(self, kind: str, loads: np.ndarray) -> None:
+    def _account_moe(self, kind: str, counters: Dict[str, np.ndarray]) -> None:
         """Add one launch's expert loads ``[n_layers, E]`` (assignments of
-        its real rows per layer and expert) to the running sums: every
-        field but ``launches`` is a sum over launch AND layer.
-        ``assignments`` = real rows x top_k x layers; ``expert_slots`` =
-        layers x E; ``experts_touched`` = slots with a load above 0 (what
-        sets the expert bytes a launch reads); ``max_load`` / ``mean_load``
-        = the largest and the mean load of a layer (their ratio is the
-        imbalance a grouped matmul pays for)."""
+        its real rows per layer and expert, over ALL the experts the router
+        chooses among) to the running sums: every field but ``launches`` is
+        a sum over launch AND layer. ``assignments`` = real rows x top_k x
+        layers; ``held_assignments`` = those to the experts this process
+        holds (all of them unless it is one chip's share of an
+        expert-parallel deployment); ``expert_slots`` = layers x E;
+        ``experts_touched`` = slots with a load above 0 (what sets the
+        expert bytes a launch reads); ``max_load`` / ``mean_load`` = the
+        largest and the mean load of a layer (their ratio is the imbalance a
+        grouped matmul pays for). ``counters`` holds that array under
+        ``load`` and, where the router's choice adds a per-expert bias to the
+        scores, ``bias_changed`` ``[n_layers]``: real rows whose kept set
+        differs from the top-k of the scores alone."""
+        loads = counters["load"]
+        lo, hi = self.held_experts
         acc = self.moe[kind]
         acc["launches"] += 1
         acc["assignments"] += int(loads.sum())
+        acc["held_assignments"] += int(loads[:, lo:hi].sum())
+        acc["bias_changed"] += int(np.sum(counters.get("bias_changed", 0)))
         acc["expert_slots"] += loads.size
         acc["experts_touched"] += int(np.count_nonzero(loads))
         acc["max_load"] += int(loads.max(axis=1).sum())
@@ -464,10 +501,11 @@ class PagedModelRunner:
         bs = self.block_size
         need = int(max(ctx_lens))
         width = _round_up_bucket(-(-need // bs), self.table_widths)
-        if self.reads_live_blocks[window]:
+        reads = self._path(window).reads
+        if reads == "blocks":
             read = sum(-(-int(c) // bs) for c in ctx_lens)  # a padding slot reads none
         else:
-            read = bucket * width
+            read = (len(ctx_lens) if reads == "slots" else bucket) * width
         dw = self.decode_width
         dw["launches"] += 1
         dw["width_tokens"] += width * bs
@@ -503,6 +541,7 @@ class PagedModelRunner:
         with clock.phase(
             "launch", program="paged_verify_step",
             bucket=f"{bbucket}x{cbucket}x{M * self.block_size}",
+            path=self._path(cbucket).name,
         ):
             tokens = np.zeros((bbucket, cbucket), np.int32)
             tables = np.zeros((bbucket, M), np.int32)
@@ -542,6 +581,7 @@ class PagedModelRunner:
         with clock.phase(
             "launch", program="paged_decode_step",
             bucket=f"{bucket}x{M * self.block_size}",
+            path=self._path(1).name,
         ):
             t = np.zeros(bucket, np.int32)
             p = np.zeros(bucket, np.int32)

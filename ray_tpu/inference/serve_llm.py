@@ -74,12 +74,14 @@ class LLMServer:
         from ray_tpu.accelerators.tpu import process_device_report
         from ray_tpu.core.config import GLOBAL_CONFIG
         from ray_tpu.inference.engine import EngineConfig, InferenceEngine
-        from ray_tpu.models.llama import LlamaConfig, init_params
+        from ray_tpu.models.interface import model_of
 
         # raises here, before any weight exists, when the replica was
         # granted chips and JAX landed elsewhere
         process_device_report()
         if model_cfg is None:
+            from ray_tpu.models import LlamaConfig
+
             model_cfg = LlamaConfig.tiny()
         self.model_cfg = model_cfg
         # the start-up account (engine_stats()["startup"]): the weights, and
@@ -92,7 +94,9 @@ class LLMServer:
             # fuse (no float32 copy of a bf16 model), and a restarted or
             # sibling replica finds the program in the compile cache
             params = jax.block_until_ready(
-                jax.jit(partial(init_params, model_cfg))(jax.random.PRNGKey(seed))
+                jax.jit(partial(model_of(model_cfg).init_params, model_cfg))(
+                    jax.random.PRNGKey(seed)
+                )
             )
         param_init_s = time.perf_counter() - t0
         self.engine = InferenceEngine(
@@ -297,16 +301,15 @@ class LLMServer:
         eng = self.engine
         try:
             shape = tuple(desc.get("shape") or ())
-            cache_k = eng.runner.cache["k"]  # [L, N, bs, n_kv, hd]
-            expect = (
-                2, cache_k.shape[0], None, cache_k.shape[2],
-                cache_k.shape[3], cache_k.shape[4],
-            )
+            # the payload this engine's cache layout exports and imports
+            # (any number of blocks): [arrays, L, P, bs, *row]
+            layout = eng.runner.cache_layout
+            expect = layout.payload_shape(None)
             if (
-                len(shape) != 6
+                len(shape) != len(expect)
                 or int(desc.get("block_size") or 0) != eng.blocks.block_size
                 or any(e is not None and s != e for s, e in zip(shape, expect))
-                or str(desc.get("dtype")) != str(cache_k.dtype)
+                or str(desc.get("dtype")) != layout.dtype_name
             ):
                 kv_transfer.count_failure("shape")
                 kv_transfer.count_fallback("shape_mismatch")
@@ -359,11 +362,8 @@ class LLMServer:
         if not blocks:
             return 0
         bs = eng.blocks.block_size
-        cache_k = eng.runner.cache["k"]  # [L, N, bs, n_kv, hd]
-        expect = (
-            2, cache_k.shape[0], 1, cache_k.shape[2],
-            cache_k.shape[3], cache_k.shape[4],
-        )
+        layout = eng.runner.cache_layout
+        expect = layout.payload_shape(1)  # one block: [arrays, L, 1, bs, *row]
         fetched: List[Any] = []
         try:
             for _digest_hex, desc in blocks:
@@ -379,10 +379,10 @@ class LLMServer:
                     break
                 shape = tuple(desc.get("shape") or ())
                 if (
-                    len(shape) != 6
+                    len(shape) != len(expect)
                     or int(desc.get("block_size") or 0) != bs
                     or any(s != e for s, e in zip(shape, expect))
-                    or str(desc.get("dtype")) != str(cache_k.dtype)
+                    or str(desc.get("dtype")) != layout.dtype_name
                 ):
                     rpc_metrics.KV_TIER_FALLBACKS.inc(
                         labels={"reason": "shape"}

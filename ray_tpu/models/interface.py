@@ -1,0 +1,217 @@
+"""What the runtime knows of a model: the smallest interface between the
+serving engine (and the train programs) and a model module, and the
+description of the paged cache a model owns.
+
+Two small records and one dict, no plugin system:
+
+* :class:`CacheLayout` says what ONE token leaves in the paged cache of
+  each layer: named arrays ``[n_layers, num_blocks, block_size, *row]``
+  (``k`` and ``v`` rows ``[n_kv, hd]`` for a KV cache; one ``latent`` row
+  of ``kv_lora_rank + rope`` numbers for a latent cache, a block's rows
+  stored as one: ``flat_blocks``). Block ids stay
+  layer-agnostic: a block id names ``block_size`` positions of a sequence in
+  EVERY layer and every array. The device tensors, the COW copy, the export
+  / import / tier payload (:func:`gather_paged_blocks` /
+  :func:`scatter_paged_blocks`: the arrays stacked,
+  ``[n_arrays, n_layers, P, *block]``) and the pool's byte
+  arithmetic all read this one description.
+* :class:`Model` is what a model module registers: config -> params, the
+  cache description, the three paged entry points, ``forward`` and the
+  logical axes, plus the one thing the runner asks about a step's attention
+  (:class:`AttentionPath`: which path a window takes and what it reads).
+* :func:`model_of` finds a config object's model by the config's type.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+
+
+@dataclass(frozen=True)
+class CacheLayout:
+    #: ``"kv"`` (per-head keys and values) or ``"latent"`` (one compressed
+    #: row a token, K and V at once)
+    kind: str
+    n_layers: int
+    block_size: int
+    #: name -> the shape of one token's row in one layer, in payload order
+    arrays: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    dtype: Any
+    #: whether a block of a layer is stored as ONE row of ``block_size x
+    #: row`` numbers (``[n_layers, num_blocks, block_size * row]``) instead of
+    #: ``[n_layers, num_blocks, block_size, *row]``: for a row that is not
+    #: whole lanes of 128 (a latent row of 576), the tiled device layout of
+    #: the latter pads every row and XLA re-lays the whole cache out to gather
+    #: from it; a block of 16 x 576 = 9216 numbers is 72 whole lanes
+    flat_blocks: bool = False
+
+    @property
+    def row_shape(self) -> Tuple[int, ...]:
+        """The one row shape all arrays share (what lets the payload be one
+        stacked array)."""
+        shapes = {shape for _, shape in self.arrays}
+        if len(shapes) != 1:
+            raise ValueError(f"arrays of different rows cannot share a payload: {self.arrays}")
+        return next(iter(shapes))
+
+    @property
+    def row_width(self) -> int:
+        """Numbers a token leaves in one layer, all arrays together."""
+        return sum(math.prod(shape) for _, shape in self.arrays)
+
+    @property
+    def dtype_name(self) -> str:
+        """The dtype as a transfer descriptor spells it (``"bfloat16"``)."""
+        import numpy as np
+
+        return str(np.dtype(self.dtype))
+
+    @property
+    def bytes_per_token(self) -> int:
+        import numpy as np
+
+        return self.n_layers * self.row_width * np.dtype(self.dtype).itemsize
+
+    @property
+    def block_bytes(self) -> int:
+        return self.block_size * self.bytes_per_token
+
+    def block_shape(self, row: Tuple[int, ...]) -> Tuple[int, ...]:
+        """The shape of one block of one layer in a device array of that row."""
+        if self.flat_blocks:
+            return (self.block_size * math.prod(row),)
+        return (self.block_size, *row)
+
+    def init(self, num_blocks: int) -> Dict[str, Any]:
+        """The device-side cache: zeros, block 0 reserved as the null block."""
+        import jax.numpy as jnp
+
+        return {
+            name: jnp.zeros((self.n_layers, num_blocks, *self.block_shape(row)), self.dtype)
+            for name, row in self.arrays
+        }
+
+    def payload_shape(self, n_blocks: Optional[int]) -> Tuple[Optional[int], ...]:
+        """Shape of the export / import / tier payload of ``n_blocks`` blocks."""
+        return (len(self.arrays), self.n_layers, n_blocks, *self.block_shape(self.row_shape))
+
+    def describe(self) -> Dict[str, Any]:
+        """What ``engine_stats()["kv_layout"]`` says."""
+        return {
+            "kind": self.kind,
+            "row_width": self.row_width,
+            "bytes_per_token": self.bytes_per_token,
+        }
+
+
+def _rows_of(a, blocks):
+    """An array of the cache seen as ``[layers x blocks, *block]`` (a free
+    reshape) and the rows of ``blocks`` ([P] int32) in every layer, ``[L *
+    P]``: gathers and scatters of whole ROWS of that view are in place and
+    copy nothing, whatever a block's shape (indexed ``a[:, blocks]``, a
+    flat-block latent cache was copied whole, 3 GB of temporaries: the
+    compile-only probe of PR 31; a K/V cache was not, either way)."""
+    import jax.numpy as jnp
+
+    L, N = a.shape[:2]
+    rows = (jnp.arange(L, dtype=jnp.int32)[:, None] * N + blocks[None]).reshape(-1)
+    return a.reshape(L * N, *a.shape[2:]), rows
+
+
+def copy_paged_blocks(cache, src, dst):
+    """Duplicate whole cache blocks device-side (prefix-cache COW):
+    ``src``/``dst`` are [P] int32 block ids; every layer's rows at ``dst``
+    become copies of ``src``, in every array. Padding pairs point both ids
+    at the null block (0): writing the null block's own trash back onto
+    itself keeps the shape static and the content inert."""
+    out = {}
+    for name, a in cache.items():
+        flat, from_rows = _rows_of(a, src)
+        _, to_rows = _rows_of(a, dst)
+        out[name] = flat.at[to_rows].set(flat[from_rows]).reshape(a.shape)
+    return out
+
+
+def gather_paged_blocks(cache, blocks):
+    """Pull whole cache blocks off the device (KV-cache migration export):
+    ``blocks`` is [P] int32 block ids (padded with 0 = null); returns the
+    arrays stacked in the layout's order, ``[n_arrays, n_layers, P,
+    *block]`` (``CacheLayout.payload_shape``): the contiguous host window
+    the transfer path ships replica to replica. Padding rows carry
+    null-block trash the caller slices off host-side."""
+    import jax.numpy as jnp
+
+    out = []
+    for a in cache.values():
+        flat, rows = _rows_of(a, blocks)
+        out.append(flat[rows].reshape(a.shape[0], blocks.shape[0], *a.shape[2:]))
+    return jnp.stack(out)
+
+
+def scatter_paged_blocks(cache, blocks, payload):
+    """Write migrated blocks into the device cache (the import side):
+    ``payload`` is the :func:`gather_paged_blocks` layout. Padding entries
+    point at the null block: duplicate index-0 writes land trash on trash,
+    keeping the compiled shape static and the content inert."""
+    out = {}
+    for i, (name, a) in enumerate(cache.items()):
+        flat, rows = _rows_of(a, blocks)
+        out[name] = flat.at[rows].set(payload[i].reshape(-1, *a.shape[2:])).reshape(a.shape)
+    return out
+
+
+class AttentionPath(NamedTuple):
+    """How a program of one query window attends over the paged cache."""
+
+    #: the model's name for the path, for the runner's launch span
+    name: str
+    #: what a decode or verify launch reads of the cache: ``"table"`` (the
+    #: table as wide as it is handed over, for every slot of the batch
+    #: bucket), ``"slots"`` (as wide, for the real slots alone: a padding
+    #: slot reads nothing) or ``"blocks"`` (each real slot's own live blocks,
+    #: whatever the table's width: a kernel)
+    reads: str
+
+
+@dataclass(frozen=True)
+class Model:
+    """What a model module registers (``MODEL`` at its end)."""
+
+    #: the family's name, for logs and ``engine_stats()``
+    name: str
+    init_params: Callable        # (cfg, rng) -> params
+    forward: Callable            # (cfg, params, tokens [B, S], **kw) -> logits [B, S, V] float32
+    logical_axes: Callable       # (cfg) -> pytree of logical axis names, as params
+    param_count: Callable        # (cfg) -> int
+    cache_layout: Callable       # (cfg, block_size, dtype=None) -> CacheLayout
+    #: the three paged entry points, ``(cfg, params, cache, ...)`` as
+    #: ``models/llama.py`` documents them; each returns ``(cache, logits)``
+    #: and, where experts are routed, a third output: the expert loads
+    #: ``[n_expert_layers, E]`` int32, or a dict with ``load`` and further
+    #: per-layer counters
+    paged_prefill_step: Callable
+    paged_verify_step: Callable
+    paged_decode_step: Callable
+    #: (cfg, window, cache) -> the :class:`AttentionPath` a program of that
+    #: query window (a prefill chunk's bucket, 1 for decode, a verify bucket)
+    #: takes: fixed a program, so the runner asks once a window
+    attention_path: Callable
+    #: (cfg) -> ``None`` for a model without routed experts, else
+    #: ``(lo, hi)``: the range of experts this process holds
+    held_experts: Callable
+
+
+def model_of(cfg) -> Model:
+    """The model a config object belongs to, by the config's type."""
+    from ray_tpu.models import llama, xing4
+
+    models = {llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL}
+    try:
+        return models[type(cfg)]
+    except KeyError:
+        raise TypeError(
+            f"no model is registered for a {type(cfg).__name__} "
+            f"(known: {sorted(t.__name__ for t in models)})"
+        ) from None

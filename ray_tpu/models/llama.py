@@ -26,6 +26,14 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models.interface import (  # noqa: F401 - the block functions are this module's too
+    AttentionPath,
+    CacheLayout,
+    Model,
+    copy_paged_blocks,
+    gather_paged_blocks,
+    scatter_paged_blocks,
+)
 from ray_tpu.ops import paged_attention as paged_attn
 from ray_tpu.ops.attention import flash_attention, flash_attention_sharded
 from ray_tpu.parallel.sharding import constrain
@@ -592,47 +600,21 @@ def _opt_state_shardings(cfg: LlamaConfig, mesh, rules, optimizer, params):
 # trash in-band is what lets every step run with fully static shapes.
 
 
+def cache_layout(cfg: LlamaConfig, block_size: int, dtype=None) -> CacheLayout:
+    """The cache description of this block (``models/interface.py``): a K
+    and a V row ``[n_kv_heads, head_dim]`` a token a layer."""
+    row = (cfg.n_kv_heads, cfg.head_dim)
+    return CacheLayout(
+        kind="kv", n_layers=cfg.n_layers, block_size=block_size,
+        arrays=(("k", row), ("v", row)), dtype=dtype or cfg.dtype,
+    )
+
+
 def init_paged_kv_cache(
     cfg: LlamaConfig, num_blocks: int, block_size: int, dtype=None
 ) -> Dict[str, jax.Array]:
     """Device-side paged KV cache (zeros; block 0 reserved as null)."""
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
-    dt = dtype or cfg.dtype
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-
-
-def copy_paged_blocks(cache, src, dst):
-    """Duplicate whole cache blocks device-side (prefix-cache COW):
-    ``src``/``dst`` are [P] int32 block ids; every layer's K/V rows at
-    ``dst`` become copies of ``src``. Padding pairs point both ids at
-    the null block (0) — writing the null block's own trash back onto
-    itself keeps the shape static and the content inert."""
-    return {
-        "k": cache["k"].at[:, dst].set(cache["k"][:, src]),
-        "v": cache["v"].at[:, dst].set(cache["v"][:, src]),
-    }
-
-
-def gather_paged_blocks(cache, blocks):
-    """Pull whole cache blocks off the device (KV-cache migration
-    export): ``blocks`` is [P] int32 block ids (padded with 0 = null);
-    returns one stacked array ``[2, n_layers, P, block_size, n_kv,
-    head_dim]`` (K at index 0, V at 1) — the contiguous host window the
-    transfer path ships replica→replica. Padding rows carry null-block
-    trash the caller slices off host-side."""
-    return jnp.stack([cache["k"][:, blocks], cache["v"][:, blocks]])
-
-
-def scatter_paged_blocks(cache, blocks, kv):
-    """Write migrated KV blocks into the device cache (import side of
-    KV-cache migration): ``kv`` is the ``gather_paged_blocks`` layout
-    ``[2, n_layers, P, block_size, n_kv, head_dim]``. Padding entries
-    point at the null block — duplicate index-0 writes land trash on
-    trash, keeping the compiled shape static and the content inert."""
-    return {
-        "k": cache["k"].at[:, blocks].set(kv[0]),
-        "v": cache["v"].at[:, blocks].set(kv[1]),
-    }
+    return cache_layout(cfg, block_size, dtype).init(num_blocks)
 
 
 def _rope_at(cfg: LlamaConfig, positions):
@@ -894,3 +876,28 @@ def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = 
     return jax.jit(
         step, donate_argnums=(0,) if donate else (), out_shardings=out_shardings
     )
+
+
+# ---------------------------------------------------------------------------
+# what the runtime knows of this module (models/interface.py)
+
+
+def _attention_path(cfg: LlamaConfig, window: int, cache) -> AttentionPath:
+    if paged_attn.kernel_serves(window, cfg.n_heads, cache["k"]):
+        return AttentionPath("kernel", "blocks")
+    return AttentionPath("gather", "table")
+
+
+MODEL = Model(
+    name="llama",
+    init_params=init_params,
+    forward=forward,
+    logical_axes=logical_axes,
+    param_count=param_count,
+    cache_layout=cache_layout,
+    paged_prefill_step=paged_prefill_step,
+    paged_verify_step=paged_verify_step,
+    paged_decode_step=paged_decode_step,
+    attention_path=_attention_path,
+    held_experts=lambda cfg: (0, cfg.moe_experts) if cfg.moe_experts > 0 else None,
+)

@@ -1,0 +1,963 @@
+"""Xing4-family decoder LM: latent attention (MLA), a hyper-connected
+residual of ``hc_mult`` streams (mHC), leading dense layers and then
+sigmoid-routed experts beside a shared expert, of which this process may
+hold a range (one chip's share of an expert-parallel deployment).
+
+Not ``models/llama.py`` with options: the attention has two projections
+down and two up and a rotary part shared by all heads, the cache row is ONE
+latent vector a token a layer (``kv_lora_rank`` normed numbers + the
+rotated rope part: K and V at once), the residual state is ``[hc_mult, D]``
+a token, and the layers are of two kinds. What it shares with that module
+is the runtime's interface (``models/interface.py``: ``MODEL`` at the end),
+``ops/moe.py`` and the norms.
+
+A sublayer ``F`` (attention or FFN), with ``h`` its input after its RMS
+norm, acts on the residual state ``X [n, D]`` of a token (n = ``hc_mult``):
+
+    xbar   = rms(vec X)                                   # no weight
+    H_pre  = sigmoid(a_pre (xbar phi_pre) + b_pre)        # [n]
+    H_post = 2 sigmoid(a_post (xbar phi_post) + b_post)   # [n]
+    H_res  = sinkhorn(exp(clip(a_res mat(xbar phi_res) + b_res)))  # [n, n]
+    X      = H_res X + H_post^T (x) F(norm(H_pre X))
+
+maps and Sinkhorn-Knopp (rows, then columns, each divided by its sum +
+``hc_eps``, ``hc_sinkhorn_iters`` rounds) in float32. The state is the
+embedding repeated n times at the start and the n streams summed before the
+final norm.
+
+Attention: ``c_q = rms(h W_qa)``; ``[q_nope | q_rope] = c_q W_qb`` a head;
+``[c | k_rope] = h W_kva``, ``c = rms(c)``; ``[k_nope | v] = c W_kvb`` a
+head; ``q_rope`` and the one ``k_rope`` rotated at the token's position with
+YaRN's frequencies; scores ``(q_nope k_nope + q_rope k_rope) (dn + dr)^-1/2
+m^2``. The cache holds ``(c, rotated k_rope)``. Behind the one attention
+door of the paged body (:func:`_latent_attention`) there are two paths that
+are the same mathematics at different costs, chosen at trace time from the
+query window (:func:`absorbs`): a prefill chunk EXPANDS K and V of its
+context from the latent rows; a decode or verify window ABSORBS ``W_kvb``
+into the query and the output and attends over the latent rows directly.
+
+Layers of one kind are stacked on a leading axis and run under
+``jax.lax.scan`` (two scans: the dense layers, the expert layers), so that
+a 40-layer program traces and compiles two layer bodies.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Callable, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.interface import AttentionPath, CacheLayout, Model
+from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
+from ray_tpu.parallel.sharding import constrain
+
+F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclass(frozen=True)
+class Xing4Config:
+    vocab_size: int = 131072
+    dim: int = 3584
+    n_layers: int = 40
+    #: the first ``n_dense_layers`` have a dense MLP, the rest routed experts
+    n_dense_layers: int = 2
+    n_heads: int = 32
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mlp_hidden: int = 9216
+    #: width of one routed expert; the shared expert is ``n_shared_experts`` wide of it
+    moe_hidden: int = 1024
+    #: how many experts the ROUTER chooses among (its width)
+    n_routed_experts: int = 64
+    #: the range ``(lo, hi)`` of them this process holds and computes; all:
+    #: ``(0, n_routed_experts)``
+    held_experts: Tuple[int, int] = (0, 64)
+    n_shared_experts: int = 1
+    moe_top_k: int = 4
+    routed_scaling_factor: float = 2.0
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    #: ``mhc_h_res_clamp_max`` (the minimum is its negative)
+    hc_res_clamp: float = 30.0
+    max_seq_len: int = 8192
+    rope_theta: float = 10000.0
+    #: YaRN; ``rope_factor`` 1 is the plain table
+    rope_factor: float = 64.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+    moe_aux_loss_coeff: float = 0.0
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts[1] - self.held_experts[0]
+
+    @property
+    def latent_width(self) -> int:
+        """One token's cache row in one layer: the normed latent and the rotated rope part."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @staticmethod
+    def tiny(**overrides) -> "Xing4Config":
+        """CI-sized config: 2 dense + 2 expert layers, 8 experts of which
+        this process holds all unless told, 2 streams."""
+        base = dict(
+            vocab_size=256, dim=64, n_layers=4, n_dense_layers=2, n_heads=4,
+            q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, mlp_hidden=96, moe_hidden=32, n_routed_experts=8,
+            held_experts=(0, 8), moe_top_k=2, hc_mult=2, max_seq_len=64,
+            rope_factor=4.0, rope_original_max=32,
+        )
+        base.update(overrides)
+        return Xing4Config(**base)
+
+
+# ---------------------------------------------------------------------------
+# params (layers of one kind stacked on a leading axis) + logical axes
+
+
+def _group_shapes(cfg: Xing4Config, moe: bool) -> Dict[str, Tuple[int, ...]]:
+    """Shapes of ONE layer's weights of a kind (``moe``: an expert layer)."""
+    n, D, H = cfg.hc_mult, cfg.dim, cfg.n_heads
+    maps = 2 * n + n * n
+    shapes = {
+        "attn_norm": (D,),
+        "w_qa": (D, cfg.q_lora_rank),
+        "q_norm": (cfg.q_lora_rank,),
+        "w_qb": (cfg.q_lora_rank, H, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
+        "w_kva": (D, cfg.latent_width),
+        "kv_norm": (cfg.kv_lora_rank,),
+        "w_kvb": (cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim),
+        "wo": (H, cfg.v_head_dim, D),
+        "mlp_norm": (D,),
+    }
+    for sub in ("hc_attn", "hc_mlp"):
+        shapes.update({f"{sub}_phi": (n * D, maps), f"{sub}_b": (maps,), f"{sub}_alpha": (3,)})
+    if moe:
+        Fm, Fs = cfg.moe_hidden, cfg.n_shared_experts * cfg.moe_hidden
+        shapes.update({
+            "router": (D, cfg.n_routed_experts),
+            "router_bias": (cfg.n_routed_experts,),
+            "w_gate": (cfg.n_held, D, Fm),
+            "w_up": (cfg.n_held, D, Fm),
+            "w_down": (cfg.n_held, Fm, D),
+            "shared_gate": (D, Fs),
+            "shared_up": (D, Fs),
+            "shared_down": (Fs, D),
+        })
+    else:
+        shapes.update({
+            "w_gate": (D, cfg.mlp_hidden),
+            "w_up": (D, cfg.mlp_hidden),
+            "w_down": (cfg.mlp_hidden, D),
+        })
+    return shapes
+
+
+_AXES = {
+    "w_qa": ("embed", None), "w_qb": (None, "heads", "head_dim"),
+    "w_kva": ("embed", None), "w_kvb": (None, "heads", "head_dim"),
+    "wo": ("heads", "head_dim", "embed"),
+    "shared_gate": ("embed", "mlp"), "shared_up": ("embed", "mlp"), "shared_down": ("mlp", "embed"),
+}
+_DENSE_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+# the held experts stay whole on each device: an ``expert`` mesh axis is the
+# deployment's, of which this process is one rank
+_MOE_AXES = {"w_gate": (None, "embed", "mlp"), "w_up": (None, "embed", "mlp"),
+             "w_down": (None, "mlp", "embed")}
+
+
+def _groups(cfg: Xing4Config):
+    """``(name, stacked layers, is it an expert group)`` of the layer kinds present."""
+    return [
+        (name, count, moe)
+        for name, count, moe in (("dense", cfg.n_dense_layers, False), ("moe", cfg.n_moe_layers, True))
+        if count > 0
+    ]
+
+
+def logical_axes(cfg: Xing4Config) -> Dict[str, Any]:
+    """Pytree (same structure as params) of logical-axis-name tuples; the
+    leading axis of a layer group is the layer."""
+    out: Dict[str, Any] = {
+        "embed": ("vocab", "embed"), "final_norm": (None,), "lm_head": ("embed", "vocab"),
+    }
+    for name, _, moe in _groups(cfg):
+        own = {**_AXES, **(_MOE_AXES if moe else _DENSE_AXES)}
+        out[name] = {
+            k: (None, *own.get(k, (None,) * len(shape)))
+            for k, shape in _group_shapes(cfg, moe).items()
+        }
+    return out
+
+
+def init_params(cfg: Xing4Config, rng: jax.Array) -> Dict[str, Any]:
+    """Seeded weights under which what is new MATTERS: projections and
+    experts normal / sqrt(fan-in) in ``cfg.dtype``, each sublayer's LAST
+    projection (``wo``, ``w_down``, ``shared_down``) a further 1 / sqrt(2 x
+    layers) smaller and a ROUTED expert's a quarter of that, norm vectors
+    1. Why the quarter: with independent random experts a hard top-4
+    choice whose gates sum to 2 is discontinuous at full size, and the
+    rounding of bfloat16 flips a token's 4th / 5th expert about once in
+    twenty (token, layer) pairs; through 38 expert layers the flips feed on
+    each other and the logits read 0.09-0.34 against the float32 reference
+    on the chip at the published widths (PERF.md, PR 31; 40 toy layers:
+    0.2 at the median, against 0.015 with dense layers alone or with every
+    expert kept), where no limit parts the model from a wrong one. A trained
+    model's neighbouring experts are not independent draws. The
+    router and its bias, and the mHC maps, float32. mHC: ``phi`` normal /
+    sqrt(n D) (so ``xbar phi`` is of order 1; the ``res`` columns a quarter
+    of that), ``alpha`` uniform in [0.5, 1.5], ``b_pre`` / ``b_post``
+    normal, ``b_res`` 1 on the diagonal + normal x 0.2: ``H_res`` lands
+    tens of per cent away from the identity and from 1/n at a contrast
+    under which the published 20 Sinkhorn rounds converge to 1e-5 (a
+    diagonal of 1.5 with a spread of 1 did not: rows summed to 1 +- 0.03),
+    ``H_pre`` / ``H_post`` away from constants. The router's
+    bias is normal x 0.03, beside sigmoid scores whose 4th and 5th of 64
+    lie about 0.025 apart: it changes the kept set for a counted share of
+    tokens (``bias_changed``; normal x 0.1 changed it for 96%)."""
+    with jax.threefry_partitionable(True):
+        return _init_params(cfg, rng)
+
+
+def _init_params(cfg: Xing4Config, rng: jax.Array) -> Dict[str, Any]:
+    n = cfg.hc_mult
+    k_embed, k_head, k_dense, k_moe = jax.random.split(rng, 4)
+
+    def dense(key, shape, fan_in, dtype=cfg.dtype):
+        """Normal / sqrt(fan_in), drawn a slice of the leading axis at a
+        time: the float32 draw of a whole stacked weight (or of the
+        embedding) is gigabytes beside 12 GB of weights being made."""
+        def draw(k):
+            return (jax.random.normal(k, shape[1:], F32) / math.sqrt(fan_in)).astype(dtype)
+
+        return jax.lax.map(draw, jax.random.split(key, shape[0]))
+
+    def group(key, count: int, moe: bool):
+        shapes = _group_shapes(cfg, moe)
+        out = {}
+        for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes))):
+            full = (count, *shape)
+            if name.endswith("norm"):
+                out[name] = jnp.ones(full, cfg.dtype)
+            elif name.endswith("_phi"):
+                phi = dense(k, full, shape[0], F32)
+                out[name] = phi.at[..., 2 * n:].multiply(0.25)
+            elif name.endswith("_alpha"):
+                out[name] = jax.random.uniform(k, full, F32, 0.5, 1.5)
+            elif name.endswith("_b"):
+                b = jax.random.normal(k, full, F32)
+                res = 0.2 * b[:, 2 * n:] + jnp.eye(n, dtype=F32).reshape(-1)
+                out[name] = jnp.concatenate([b[:, : 2 * n], res], axis=1)
+            elif name == "router":
+                # routing logits are precision-sensitive: keep f32
+                out[name] = dense(k, full, shape[0], F32)
+            elif name == "router_bias":
+                out[name] = 0.03 * jax.random.normal(k, full, F32)
+            else:
+                # contraction dims: all but the last of a 2-D weight; the
+                # rank of the up-projections; heads x v of ``wo``; an
+                # expert's own input width
+                fan_in = {"w_qb": shape[0], "w_kvb": shape[0], "wo": shape[0] * shape[1]}.get(
+                    name, shape[-2]
+                )
+                if name in ("wo", "w_down", "shared_down"):
+                    # a sublayer's last projection, scaled down by the depth
+                    # (the 1 / sqrt(2 L) of GPT-2's initialisation)
+                    fan_in *= 2 * cfg.n_layers
+                if moe and name == "w_down":
+                    fan_in *= 16  # a ROUTED expert's output a quarter of that
+                out[name] = dense(k, full, fan_in)
+        return out
+
+    # the two vocabulary-sized matrices in (up to) 16 slices of their leading axis
+    v, d = math.gcd(16, cfg.vocab_size), math.gcd(16, cfg.dim)
+    params = {
+        "embed": dense(k_embed, (v, cfg.vocab_size // v, cfg.dim), cfg.dim).reshape(
+            cfg.vocab_size, cfg.dim
+        ),
+        "final_norm": jnp.ones((cfg.dim,), cfg.dtype),
+        "lm_head": dense(k_head, (d, cfg.dim // d, cfg.vocab_size), cfg.dim).reshape(
+            cfg.dim, cfg.vocab_size
+        ),
+    }
+    for (name, count, moe), key in zip(_groups(cfg), (k_dense, k_moe)):
+        params[name] = group(key, count, moe)
+    return params
+
+
+def param_count(cfg: Xing4Config) -> int:
+    layers = sum(
+        count * sum(math.prod(s) for s in _group_shapes(cfg, moe).values())
+        for _, count, moe in _groups(cfg)
+    )
+    return 2 * cfg.vocab_size * cfg.dim + layers + cfg.dim
+
+
+# ---------------------------------------------------------------------------
+# the pieces of a layer
+
+
+def rms_norm(x, weight, eps: float):
+    x32 = x.astype(F32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * inv).astype(x.dtype) * weight
+
+
+def yarn_inv_freq(cfg: Xing4Config):
+    """YaRN's rotary frequencies ``[dr / 2]``: the table's own ``theta^(-2i
+    / dr)`` where a pair turns more than ``beta_fast`` times over the
+    original context, those divided by ``rope_factor`` where it turns fewer
+    than ``beta_slow`` times, a linear ramp between."""
+    dr = cfg.qk_rope_head_dim
+    extra = 1.0 / (cfg.rope_theta ** (jnp.arange(0, dr, 2, dtype=F32) / dr))
+    if cfg.rope_factor == 1.0:
+        return extra
+
+    def correction_dim(rotations: float) -> float:
+        return (dr * math.log(cfg.rope_original_max / (rotations * 2 * math.pi))) / (
+            2 * math.log(cfg.rope_theta)
+        )
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dr - 1)
+    ramp = jnp.clip((jnp.arange(dr // 2, dtype=F32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / cfg.rope_factor * ramp + extra * (1.0 - ramp)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def softmax_scale(cfg: Xing4Config) -> float:
+    """``(dn + dr)^-1/2 m^2`` with ``m = 0.1 mscale_all_dim ln(factor) + 1``."""
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) if cfg.rope_mscale_all_dim else 1.0
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _rope(cfg: Xing4Config, x, pos):
+    """``x [..., dr]`` rotated at int positions ``pos``, broadcastable against
+    ``x``'s leading axes: (even, odd) neighbours are a pair, as in ``models/
+    llama.py``. The table's own attention factor (``mscale`` over
+    ``mscale_all_dim``) multiplies cos and sin."""
+    ang = pos.astype(F32)[..., None] * yarn_inv_freq(cfg)
+    att = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / _yarn_mscale(
+        cfg.rope_factor, cfg.rope_mscale_all_dim
+    )
+    cos, sin = jnp.cos(ang) * att, jnp.sin(ang) * att
+    x1, x2 = x[..., ::2].astype(F32), x[..., 1::2].astype(F32)
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _latent_qkv(cfg: Xing4Config, p, h, pos):
+    """The projections of one attention on normed activations ``h [B, C,
+    D]`` at positions ``pos [B, C]``: ``(q_nope [B, C, H, dn], q_rope [B, C,
+    H, dr]`` rotated, ``row [B, C, kr + dr])``, the row being what the cache
+    holds of the token: the normed latent and the rotated rope part."""
+    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla.q"):
+        c_q = rms_norm(h @ p["w_qa"], p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bcr,rhk->bchk", c_q, p["w_qb"])
+        q_nope, q_rope = q[..., :dn], _rope(cfg, q[..., dn:], pos[:, :, None])
+    with jax.named_scope("mla.latent"):
+        ckv = h @ p["w_kva"]
+        c = rms_norm(ckv[..., :kr], p["kv_norm"], cfg.norm_eps)
+        row = jnp.concatenate([c, _rope(cfg, ckv[..., kr:], pos)], axis=-1)
+    return q_nope, q_rope, row
+
+
+def absorbs(cfg: Xing4Config, window: int) -> bool:
+    """Whether a query window of ``window`` positions a slot attends over
+    the latent rows directly (``W_kvb`` absorbed into the query and the
+    output) or expands K and V of its context first. From the counts: a
+    (query, cached position) pair costs ``2 (kr + dr) + 2 kr`` a head
+    absorbed against ``2 (dn + dr) + 2 dv`` expanded, and the expansion
+    ``2 kr (dn + dv)`` a head a cached position a launch, which ``window``
+    queries share: absorbed while ``window (2 kr - dn - dv) < kr (dn +
+    dv)`` (under 171 queries at the published widths: decode and verify
+    absorb, a prefill chunk of 256 or 1024 expands)."""
+    kr, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+    return window * (2 * kr - dn - dv) < kr * (dn + dv)
+
+
+#: queries of a prefill chunk that attend at a time on the expanded path: the
+#: float32 scores of 1024 queries x 32 heads over a table of 8192 are 1.07 GB
+#: at once (the compile-only rehearsal's largest temporary), 0.27 GB a block
+_QUERY_BLOCK = 256
+
+
+def _probs(cfg: Xing4Config, s, mask, dtype):
+    """Causal softmax of float32 scores ``s [B, C, H, S]`` under ``mask [B,
+    C, S]``, scaled by :func:`softmax_scale`, as ``dtype``."""
+    s = jnp.where(mask[:, :, None, :], s * softmax_scale(cfg), -1e30)
+    return jax.nn.softmax(s, axis=-1).astype(dtype)
+
+
+def _absorb_query(cfg: Xing4Config, p, q_nope, q_rope):
+    """``W_kvb``'s key part absorbed into the query: ``[q_nope W_k | q_rope]
+    [B, C, H, kr + dr]``, to be multiplied against a whole latent row ``[c |
+    k_rope]``: one product over ``kr + dr``, and no slice of gathered rows."""
+    with jax.named_scope("mla.absorb"):
+        w_k = p["w_kvb"][..., : cfg.qk_nope_head_dim]
+        return jnp.concatenate([jnp.einsum("bchk,rhk->bchr", q_nope, w_k), q_rope], axis=-1)
+
+
+def _attend_rows(cfg: Xing4Config, q_row, rows, mask, own):
+    """Absorbed queries ``q_row [B, C, H, kr + dr]`` over latent rows
+    directly: ``Σ_j p_ij c_j`` ``[B, C, H, kr]``, before ``W_kvb``'s value
+    part. Two sets of keys under one softmax: ``rows [B, S, kr + dr]``, the
+    context BEFORE the window (``mask [B, C, S]`` says which of it), and ``own
+    [B, C, kr + dr]``, the window's own rows, query ``c`` seeing ``c' <= c``:
+    the gathered context is never copied to lay the window over it."""
+    kr = cfg.kv_lora_rank
+    with jax.named_scope("mla.attend"):
+        s = jnp.einsum("bchw,bsw->bchs", q_row, rows, preferred_element_type=F32)
+        S, C = rows.shape[1], own.shape[1]
+        s_own = jnp.einsum("bchw,bdw->bchd", q_row, own, preferred_element_type=F32)
+        within = jnp.broadcast_to(jnp.tril(jnp.ones((C, C), bool)), (mask.shape[0], C, C))
+        pr = _probs(
+            cfg, jnp.concatenate([s, s_own], axis=-1), jnp.concatenate([mask, within], axis=-1),
+            rows.dtype,
+        )
+        o_lat = jnp.einsum("bchs,bsw->bchw", pr[..., :S], rows)
+        return (o_lat + jnp.einsum("bchd,bdw->bchw", pr[..., S:], own))[..., :kr]
+
+
+def _absorb_output(cfg: Xing4Config, p, o_lat):
+    """``W_kvb``'s value part after the attention: ``[B, C, H, kr]`` ->
+    ``[B, C, H, dv]``."""
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("bchr,rhk->bchk", o_lat, p["w_kvb"][..., cfg.qk_nope_head_dim :])
+
+
+def _attend_expanded(cfg: Xing4Config, p, q_nope, q_rope, rows, mask):
+    """Attention of a window's queries over latent rows ``rows [B, S, kr +
+    dr]`` (``mask [B, C, S]``: which a query sees), K and V expanded from the
+    rows first: the same mathematics as ``W_kvb`` absorbed into the query and
+    the output (:func:`_absorb_query`, :func:`_attend_rows`,
+    :func:`_absorb_output`; :func:`absorbs` says which costs less for a
+    window). Scores and softmax float32. Returns ``[B, C, H, dv]``."""
+    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    w_k, w_v = p["w_kvb"][..., :dn], p["w_kvb"][..., dn:]
+    c, k_rope = rows[..., :kr], rows[..., kr:]
+    with jax.named_scope("mla.expand"):
+        # a head's key is [k_nope | the ONE k_rope]: one product over dn + dr
+        # a (query, key) pair instead of two passes over the float32 scores
+        k_nope = jnp.einsum("bsr,rhk->bshk", c, w_k)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (*k_nope.shape[:3], k_rope.shape[-1]))],
+            axis=-1,
+        )
+        v = jnp.einsum("bsr,rhk->bshk", c, w_v)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+
+    def attend(q, mask):
+        s = jnp.einsum("bchk,bshk->bchs", q, k, preferred_element_type=F32)
+        return jnp.einsum("bchs,bshk->bchk", _probs(cfg, s, mask, rows.dtype), v)
+
+    B, C = mask.shape[:2]
+    with jax.named_scope("mla.attend"):
+        if C <= _QUERY_BLOCK or C % _QUERY_BLOCK:
+            return attend(q, mask)
+        # a block of queries at a time, [blocks, B, block, ...] under lax.map
+
+        def split(a):
+            return jnp.moveaxis(a.reshape(B, C // _QUERY_BLOCK, _QUERY_BLOCK, *a.shape[2:]), 1, 0)
+
+        out = jax.lax.map(lambda t: attend(*t), (split(q), split(mask)))
+        return jnp.moveaxis(out, 0, 1).reshape(B, C, *out.shape[3:])
+
+
+def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
+    """The FFN of one layer on normed activations ``h [B, C, D]``:
+    ``(ffn(h), aux)``. A dense layer: the gated SiLU MLP, ``aux`` empty. An
+    expert layer: the shared expert on every row + this process's part of
+    the routed experts (``ops/moe.py::dropless_moe_ffn`` told
+    ``cfg.held_experts``: sigmoid scores, the choice with the bias, gates
+    normalised over the kept and scaled); ``aux``: ``load`` ``[E]``,
+    ``bias_changed``, ``aux_loss``. ``valid [B, C]`` marks the real rows of
+    a padded serving step."""
+    if not moe:
+        return gated_mlp(h, p["w_gate"], p["w_up"], p["w_down"]), {}
+    with jax.named_scope("moe.shared"):
+        shared = gated_mlp(h, p["shared_gate"], p["shared_up"], p["shared_down"])
+    experts = {k: p[k] for k in ("router", "router_bias", "w_gate", "w_up", "w_down")}
+    routed, aux = dropless_moe_ffn(
+        experts, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k, renormalize=True,
+        valid=None if valid is None else valid.reshape(-1),
+        scoring="sigmoid", scale=cfg.routed_scaling_factor,
+        held=None if cfg.n_held == cfg.n_routed_experts else cfg.held_experts,
+    )
+    return shared + routed.reshape(h.shape), aux
+
+
+def mhc_maps(cfg: Xing4Config, phi, b, alpha, X):
+    """The three maps of one sublayer from the residual state ``X [..., n,
+    D]``: ``(H_pre [..., n], H_post [..., n], H_res [..., n, n])`` float32,
+    ``H_res`` doubly stochastic by Sinkhorn-Knopp."""
+    n = cfg.hc_mult
+    with jax.named_scope("mhc.maps"):
+        x = X.reshape(*X.shape[:-2], -1).astype(F32)
+        xbar = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + cfg.norm_eps)
+        z = jnp.dot(xbar, phi.astype(F32), precision=_HIGHEST)
+        pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n : 2 * n] + b[n : 2 * n])
+        res = (alpha[2] * z[..., 2 * n :] + b[2 * n :]).reshape(*z.shape[:-1], n, n)
+    with jax.named_scope("mhc.sinkhorn"):
+        m = jnp.exp(jnp.clip(res, -cfg.hc_res_clamp, cfg.hc_res_clamp))
+        for _ in range(cfg.hc_sinkhorn_iters):
+            m = m / (m.sum(axis=-1, keepdims=True) + cfg.hc_eps)  # rows
+            m = m / (m.sum(axis=-2, keepdims=True) + cfg.hc_eps)  # then columns
+    return pre, post, m
+
+
+def _hyper(cfg: Xing4Config, p, sub: str, norm: str, X, F: Callable):
+    """One sublayer through the hyper-connected residual: ``X <- H_res X +
+    H_post^T (x) F(norm(H_pre X))``. ``F`` returns ``(y [..., D], extra)``;
+    returns ``(X, extra)``. The maps and the two mixes are float32 at the
+    matmul's highest precision; the state is kept in ``cfg.dtype``."""
+    pre, post, res = mhc_maps(cfg, p[f"{sub}_phi"], p[f"{sub}_b"], p[f"{sub}_alpha"], X)
+    with jax.named_scope("mhc.mix"):
+        x_in = jnp.einsum("...n,...nd->...d", pre, X.astype(F32), precision=_HIGHEST)
+    y, extra = F(rms_norm(x_in.astype(X.dtype), p[norm], cfg.norm_eps))
+    with jax.named_scope("mhc.mix"):
+        mixed = jnp.einsum("...ij,...jd->...id", res, X.astype(F32), precision=_HIGHEST)
+        out = mixed + post[..., None] * y.astype(F32)[..., None, :]
+    return out.astype(X.dtype), extra
+
+
+def _layer(cfg: Xing4Config, p, X, attention: Callable, valid, moe: bool):
+    """One layer on the residual state ``X [B, C, n, D]``: the attention
+    sublayer (``attention(p, h) -> (out [B, C, D], rows)``, ``rows`` what
+    the layer leaves for the cache, or None), then the FFN. Returns ``(X,
+    rows, aux)``."""
+    X, rows = _hyper(cfg, p, "hc_attn", "attn_norm", X, partial(attention, p))
+    X, aux = _hyper(cfg, p, "hc_mlp", "mlp_norm", X, lambda h: _ffn(cfg, p, h, valid, moe))
+    return X, rows, aux
+
+
+def _scan_layers(cfg: Xing4Config, params, X, attention: Callable, valid, wrap=None):
+    """Every layer over ``X``, the layers of a kind under one scan.
+    ``attention(p, h, layer) -> (out, rows)`` (``layer`` the layer's index
+    in the model, traced). Returns ``(X, rows, aux)``: ``rows`` what the
+    layers' attentions returned, stacked over ALL layers in order (None
+    where they return None), ``aux`` the expert layers' stacked ``load
+    [n_moe, E]``, ``bias_changed [n_moe]``, ``aux_loss [n_moe]`` (empty
+    without expert layers)."""
+    layer0 = 0
+    rows, aux = [], {}
+    for name, count, moe in _groups(cfg):
+        def body(carry, p, moe=moe):
+            X, layer = carry
+            X, layer_rows, layer_aux = _layer(
+                cfg, p, X, lambda p, h: attention(p, h, layer), valid, moe
+            )
+            return (X, layer + 1), (layer_rows, layer_aux)
+
+        if wrap is not None:
+            body = wrap(body)
+        (X, _), (group_rows, group_aux) = jax.lax.scan(
+            body, (X, jnp.int32(layer0)), params[name]
+        )
+        layer0 += count
+        rows.append(group_rows)
+        if moe:
+            aux = group_aux
+    rows = None if rows[0] is None else jnp.concatenate(rows)
+    return X, rows, aux
+
+
+def _lm_head(cfg: Xing4Config, params, X):
+    """The residual state ``X [..., n, D]`` -> float32 logits ``[..., vocab]``:
+    the streams summed, the final norm, the head."""
+    x = X.astype(F32).sum(axis=-2).astype(X.dtype)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(F32)
+
+
+def _embed(cfg: Xing4Config, params, tokens):
+    """The embedding of each token, repeated over the ``hc_mult`` streams."""
+    x = params["embed"][tokens]
+    return jnp.broadcast_to(x[..., None, :], (*x.shape[:-1], cfg.hc_mult, cfg.dim))
+
+
+# ---------------------------------------------------------------------------
+# forward (the full sequence: training, and the tests' other side)
+
+
+def forward(cfg: Xing4Config, params, tokens, *, remat=False, mesh=None, rules=None,
+            return_aux: bool = False):
+    """tokens [B, S] int32 -> logits [B, S, vocab] (f32): causal attention
+    over the sequence itself, K and V expanded (no cache). ``remat``: a
+    truthy value checkpoints each layer. With ``return_aux`` also returns
+    the summed load-balance loss of the expert layers."""
+    B, S = tokens.shape
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    causal = jnp.broadcast_to(jnp.tril(jnp.ones((S, S), bool)), (B, S, S))
+    emb = constrain(params["embed"], mesh, rules, (None, None))
+    X = _embed(cfg, {"embed": emb}, tokens)
+
+    def attention(p, h, layer):
+        q_nope, q_rope, rows = _latent_qkv(cfg, p, h, pos)
+        o = _attend_expanded(cfg, p, q_nope, q_rope, rows, causal)
+        return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), None
+
+    wrap = jax.checkpoint if remat not in (False, None) else None
+    X, _, aux = _scan_layers(cfg, params, X, attention, None, wrap)
+    logits = _lm_head(cfg, params, X)
+    logits = constrain(logits, mesh, rules, ("act_batch", "act_seq", "act_vocab"))
+    if return_aux:
+        return logits, (aux["aux_loss"].sum() if aux else jnp.zeros((), F32))
+    return logits
+
+
+def next_token_loss(cfg: Xing4Config, params, tokens, targets, *, remat=False,
+                    mesh=None, rules=None):
+    logits, aux = forward(
+        cfg, params, tokens, remat=remat, mesh=mesh, rules=rules, return_aux=True
+    )
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None].astype(jnp.int32), axis=-1)
+    return nll.mean() + cfg.moe_aux_loss_coeff * aux
+
+
+# ---------------------------------------------------------------------------
+# the latent paged cache and the three serving steps over ONE body
+#
+# One array ``latent [n_layers, num_blocks, block_size x (kr + dr)]`` shared
+# by every request: a token's row in a layer is its normed latent and its
+# rotated rope part, K and V at once (46,080 B a token over 40 layers at the
+# published widths, against 819,200 B if the expanded heads were cached), and
+# a block's 16 rows are stored as ONE row of 9216 numbers (``CacheLayout.
+# flat_blocks``: 576 is 4.5 lanes of 128; as ``[16, 576]`` the device layout
+# padded each row to 640 and XLA re-laid the whole 2 GB cache out, twice, to
+# gather from it, and copied it again to scatter into it; the compile-only
+# rehearsal of a prefill chunk then did not fit the chip).
+# Block ids are layer-agnostic as in ``models/llama.py`` (every layer is of
+# the one kind), block 0 is the null block, and every read masks on
+# ``key_pos <= pos``, so stale rows past a slot's context are never read.
+
+
+def cache_layout(cfg: Xing4Config, block_size: int, dtype=None) -> CacheLayout:
+    return CacheLayout(
+        kind="latent", n_layers=cfg.n_layers, block_size=block_size,
+        arrays=(("latent", (cfg.latent_width,)),), dtype=dtype or cfg.dtype, flat_blocks=True,
+    )
+
+
+def _block_at(block_tables, pos, bs: int):
+    """Id of the block that holds position ``pos[b, c]`` of slot ``b`` (a
+    position past the table reads its last column)."""
+    M = block_tables.shape[1]
+    return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
+
+
+def _window_blocks(cfg: Xing4Config, cache, window: int) -> int:
+    """Blocks a window of ``window`` CONTIGUOUS positions can touch."""
+    bs = _block_size(cfg, cache)
+    return (window + bs - 2) // bs + 1
+
+
+def _latent_attention(cfg: Xing4Config, p, q_nope, q_rope, row, cache, layer, block_tables, pos):
+    """Causal attention of a window's queries (``[B, C, H, .]``, rope
+    applied) over the cached context of their slots through ``block_tables
+    [B, M]`` AND the window's own rows ``row [B, C, kr + dr]``: the ONE
+    place a serving step reads the cache for attention. A slot's window is
+    CONTIGUOUS: ``pos[b, c] = pos[b, 0] + c`` (all three entry points).
+    Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
+    c]``. The gathered context ``cache[layer, block_tables]`` is as wide as
+    the table handed over (a kernel over latent rows would replace that).
+    A window attends to itself as after the write (:func:`_paged_layers`
+    says why the write itself comes last), by the path chosen at trace time
+    from the window (:func:`absorbs`): a prefill chunk lays its rows over
+    the positions they will be written to (one ``dynamic_update_slice``)
+    and expands K and V of that context from the latent rows; a decode or
+    verify window absorbs ``W_kvb``, attends over the gathered rows before
+    it directly and over its own rows beside them.
+
+    Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
+    ``blocks`` are the ``nblk`` (:func:`_window_blocks`) blocks from the
+    window's first on, old rows and new, as the cache must hold them after
+    the step."""
+    B, C = pos.shape
+    L, N, K = cache["latent"].shape
+    W, bs, nblk = cfg.latent_width, _block_size(cfg, cache), _window_blocks(cfg, cache, C)
+    # ``nblk`` null columns behind the table: a window that ends at the
+    # table's end (a padded last chunk) spills into the null block, and no
+    # slice below is clamped
+    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
+    key_pos = jnp.arange(tables.shape[1] * bs, dtype=jnp.int32)
+    first = pos[:, 0]
+
+    def context(table):
+        # ONE gather of whole blocks out of the cache seen as [layers x
+        # blocks, block] (a free reshape where num_blocks is a multiple of 8)
+        return cache["latent"].reshape(L * N, K)[layer * N + table].reshape(-1, W)
+
+    def window_blocks(rows, at):
+        return jax.lax.dynamic_slice(rows, (at // bs * bs, 0), (nblk * bs, W))
+
+    if absorbs(cfg, C):
+        # a short window over many slots, ONE SLOT AT A TIME: a padding slot
+        # (its table is the null block's, :func:`_paged_layers`) reads
+        # nothing, as under ``ops/paged_attention.py``; a real slot's
+        # gathered context (9.4 MB at a table of 8192) stays as it is, the
+        # window's rows are a second set of keys, and only the window's
+        # blocks are rebuilt. ``W_kvb`` is absorbed for all slots at once
+        q_row = _absorb_query(cfg, p, q_nope, q_rope)
+
+        def slot(args):
+            table, q, own, at = args
+
+            def read():
+                rows = context(table)
+                blocks = jax.lax.dynamic_update_slice(window_blocks(rows, at), own, (at % bs, 0))
+                mask = jnp.broadcast_to(key_pos < at, (1, C, key_pos.shape[0]))
+                return _attend_rows(cfg, q[None], rows[None], mask, own[None])[0], blocks
+
+            return jax.lax.cond(
+                table[0] != 0, read,
+                lambda: (jnp.zeros((*q.shape[:2], cfg.kv_lora_rank), q.dtype), jnp.zeros((nblk * bs, W), own.dtype)),
+            )
+
+        o_lat, blocks = jax.lax.map(slot, (tables, q_row, row, first))
+        return _absorb_output(cfg, p, o_lat), blocks
+    rows = jax.vmap(context)(tables)
+    rows = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a, 0)))(rows, row, first)
+    mask = key_pos <= pos[:, :, None]
+    return _attend_expanded(cfg, p, q_nope, q_rope, rows, mask), jax.vmap(window_blocks)(rows, first)
+
+
+def _block_size(cfg: Xing4Config, cache) -> int:
+    return cache["latent"].shape[2] // cfg.latent_width
+
+
+def _write_blocks(cfg: Xing4Config, cache, block_tables, first, blocks):
+    """Every layer's updated blocks of a step, ``blocks [n_layers, B, nblk
+    * block_size, kr + dr]`` (:func:`_latent_attention`), into the cache:
+    ONE scatter of whole rows of the cache seen as ``[layers x blocks,
+    block]``, in place in the donated argument (a scatter of one ``kr +
+    dr``-wide window a token was 40,960 sequential updates a prefill chunk:
+    160 ms on the chip; one whose window spans the layers copied the cache
+    whole). A block the window touches is rewritten with its old rows and
+    the new; what lies past a slot's blocks, and a padding slot, is the null
+    block: colliding trash writes are fine, nothing masked-in reads them."""
+    L, N, K = cache["latent"].shape
+    B = first.shape[0]
+    bs = _block_size(cfg, cache)
+    nblk = blocks.shape[2] // bs
+    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
+    ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at,), (nblk,)))(tables, first // bs)
+    rows = (jnp.arange(L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
+    flat = cache["latent"].reshape(L * N, K).at[rows].set(blocks.reshape(L * B * nblk, K))
+    return {"latent": flat.reshape(L, N, K)}
+
+
+def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tables):
+    """Every layer of the model over the latent paged cache: the body of
+    the three serving steps. ``tokens [B, C]``, ``pos [B, C]`` their global
+    positions (contiguous a slot), ``valid [B, C]`` bool (padding rows reach
+    no routed expert; what they leave in the cache lies past their slot's
+    context, or in the null block), ``block_tables [B, M]``. Per layer: the
+    projections, attention over the cache with the window's own rows laid
+    over it, ``wo``, the FFN, each through the hyper-connected residual.
+    Returns ``(cache, X [B, C, n, D], aux)``.
+
+    The layers READ the cache they were handed and the step writes every
+    layer's blocks in ONE scatter after the last layer: a cache carried
+    through the two scans and updated in each layer was copied whole by
+    XLA on its way into the loop (2.3 GB at the benchmark's sizes: the
+    compile-only rehearsal did not fit the chip), where one scatter of
+    whole blocks into the donated argument is in place. What a layer's
+    attention sees is the same either way."""
+    # a padding slot (no valid row) is pointed at the null block whatever
+    # its table holds
+    block_tables = jnp.where(valid.any(axis=1, keepdims=True), block_tables, 0)
+
+    def attention(p, h, layer):
+        q_nope, q_rope, row = _latent_qkv(cfg, p, h, pos)
+        o, blocks = _latent_attention(
+            cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos
+        )
+        return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), blocks
+
+    X, blocks, aux = _scan_layers(cfg, params, _embed(cfg, params, tokens), attention, valid)
+    return _write_blocks(cfg, cache, block_tables, pos[:, 0], blocks), X, aux
+
+
+def _step_outputs(cache, logits, aux):
+    """What a paged step returns: ``(cache, logits)`` and, with expert
+    layers, the counters the runner reads with the logits: ``load
+    [n_moe, E]`` and ``bias_changed [n_moe]`` of the step's valid rows."""
+    if aux:
+        return cache, logits, {"load": aux["load"], "bias_changed": aux["bias_changed"]}
+    return cache, logits
+
+
+def paged_prefill_step(cfg: Xing4Config, params, cache, tokens, block_table, ctx_len, true_len):
+    """One prefill chunk for ONE request: arguments and outputs as
+    ``models/llama.py::paged_prefill_step``. Head: the chunk's last valid row."""
+    idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    cache, X, aux = _paged_layers(
+        cfg, params, cache, tokens[None], (ctx_len + idx)[None], (idx < true_len)[None],
+        block_table[None],
+    )
+    logits = _lm_head(cfg, params, X[0, jnp.maximum(true_len - 1, 0)])
+    return _step_outputs(cache, logits, aux)
+
+
+def paged_verify_step(cfg: Xing4Config, params, cache, tokens, block_tables, ctx_lens, true_lens):
+    """Speculative verification for a batch of slots, windows of C positions
+    a slot: as ``models/llama.py::paged_verify_step``. Head: every row."""
+    idx = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    cache, X, aux = _paged_layers(
+        cfg, params, cache, tokens, ctx_lens[:, None] + idx, idx < true_lens[:, None],
+        block_tables,
+    )
+    return _step_outputs(cache, _lm_head(cfg, params, X), aux)
+
+
+def paged_decode_step(cfg: Xing4Config, params, cache, tokens, positions, block_tables, ctx_lens):
+    """One decode step for a batch of slots: as ``models/llama.py::
+    paged_decode_step`` (a slot whose token would be written to the null
+    block is padding). Head: the one row a slot."""
+    del ctx_lens
+    pos = positions[:, None]
+    valid = _block_at(block_tables, pos, _block_size(cfg, cache)) != 0
+    cache, X, aux = _paged_layers(cfg, params, cache, tokens[:, None], pos, valid, block_tables)
+    return _step_outputs(cache, _lm_head(cfg, params, X[:, 0]), aux)
+
+
+# ---------------------------------------------------------------------------
+# sharded training step (rehearsed at toy sizes: the benchmark serves this model)
+
+
+def partition_rules(cfg: Xing4Config, rules) -> list:
+    """Ordered ``(regex, PartitionSpec)`` pairs for every param by its path
+    (and so for grads and optimizer state, as ``models/llama.py::
+    partition_rules``), derived from :func:`logical_axes`."""
+    from ray_tpu.parallel.sharding import tree_path_names
+
+    axes = logical_axes(cfg)
+    is_axes = lambda x: isinstance(x, tuple)  # noqa: E731
+    names = tree_path_names(jax.tree_util.tree_map(lambda t: 0, axes, is_leaf=is_axes))
+    leaves = jax.tree_util.tree_leaves(axes, is_leaf=is_axes)
+    out = [(r"(^|/)v_(row|col)(/|$)", rules.spec((None,)))]
+    return out + [(f"(^|/){name}$", rules.spec(ax)) for name, ax in zip(names, leaves)]
+
+
+def param_shardings(cfg: Xing4Config, mesh, rules):
+    from jax.sharding import NamedSharding
+
+    return jax.tree_util.tree_map(
+        lambda axes: NamedSharding(mesh, rules.spec(axes)),
+        logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple),
+    )
+
+
+def _opt_state_shardings(cfg: Xing4Config, mesh, rules, optimizer, params):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ray_tpu.parallel.sharding import match_partition_rules
+
+    abstract = jax.eval_shape(optimizer.init, params)
+    specs = match_partition_rules(partition_rules(cfg, rules), abstract)
+    return jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs, is_leaf=lambda x: isinstance(x, PartitionSpec)
+    )
+
+
+def init_sharded(cfg: Xing4Config, mesh, rules, rng, optimizer=None):
+    """Params (and optimizer state) made directly onto the mesh, as
+    ``models/llama.py::init_sharded``."""
+    shardings = param_shardings(cfg, mesh, rules)
+    with jax.threefry_partitionable(True):
+        params = jax.jit(partial(init_params, cfg), out_shardings=shardings)(rng)
+    if optimizer is None:
+        return params
+    oshard = _opt_state_shardings(cfg, mesh, rules, optimizer, params)
+    return params, jax.jit(partial(optimizer.init), out_shardings=oshard)(params)
+
+
+def make_train_step(cfg: Xing4Config, optimizer, *, remat=False, donate: bool = True,
+                    mesh=None, rules=None):
+    """Jitted ``step((params, opt_state), batch) -> (state, loss)`` with
+    params, grads and optimizer state pinned to the one spec table, as
+    ``models/llama.py::make_train_step``."""
+    import optax
+
+    from ray_tpu.parallel.sharding import constrain_tree
+
+    prules = partition_rules(cfg, rules) if rules is not None else None
+    act = rules if mesh is not None else None
+
+    def step(state, batch):
+        params, opt_state = state
+        params = constrain_tree(params, mesh, prules)
+        tokens = constrain(batch["tokens"], mesh, act, ("act_batch", "act_seq"))
+        targets = constrain(batch["targets"], mesh, act, ("act_batch", "act_seq"))
+        loss, grads = jax.value_and_grad(
+            lambda p: next_token_loss(cfg, p, tokens, targets, remat=remat, mesh=mesh, rules=act)
+        )(params)
+        grads = constrain_tree(grads, mesh, prules)
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        opt_state = constrain_tree(opt_state, mesh, prules)
+        params = constrain_tree(optax.apply_updates(params, updates), mesh, prules)
+        return (params, opt_state), loss
+
+    out_shardings = None
+    if prules is not None and mesh is not None:
+        abstract = jax.eval_shape(partial(init_params, cfg), jax.ShapeDtypeStruct((2,), jnp.uint32))
+        out_shardings = (
+            (param_shardings(cfg, mesh, rules),
+             _opt_state_shardings(cfg, mesh, rules, optimizer, abstract)),
+            None,
+        )
+    return jax.jit(step, donate_argnums=(0,) if donate else (), out_shardings=out_shardings)
+
+
+def batch_sharding(mesh, rules):
+    from jax.sharding import NamedSharding
+
+    return NamedSharding(mesh, rules.spec(("batch", "seq")))
+
+
+# ---------------------------------------------------------------------------
+# what the runtime knows of this module (models/interface.py)
+
+MODEL = Model(
+    name="xing4",
+    init_params=init_params,
+    forward=forward,
+    logical_axes=logical_axes,
+    param_count=param_count,
+    cache_layout=cache_layout,
+    paged_prefill_step=paged_prefill_step,
+    paged_verify_step=paged_verify_step,
+    paged_decode_step=paged_decode_step,
+    # both latent paths gather the table as wide as it is handed over; the
+    # absorbed one a slot at a time, and nothing for a padding slot
+    attention_path=lambda cfg, window, cache: (
+        AttentionPath("latent.absorbed", "slots") if absorbs(cfg, window)
+        else AttentionPath("latent.expanded", "table")
+    ),
+    held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
+)

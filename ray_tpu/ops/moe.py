@@ -1,11 +1,16 @@
 """Mixture-of-Experts FFNs: one routing function, two ways to run the experts.
 
 :func:`route` is the one routing of every MoE path: float32 router logits,
-softmax over the experts, ``top_k``, and the kept gates renormalised only
-where the architecture says so (``renormalize``; OLMoE does not).
+a score an expert (``scoring``: a softmax over the experts, or a sigmoid
+each), ``top_k`` of the scores (for the choice alone, plus a per-expert
+``bias``), and the kept gates renormalised only where the architecture says
+so (``renormalize``; OLMoE does not) and scaled by ``scale``.
 
-:func:`dropless_moe_ffn` is what runs wherever the experts live on one
-device (serving's three paged steps, ``forward`` without an ``expert`` mesh
+:func:`dropless_moe_ffn` is what runs wherever the experts this process
+computes live on one device: all of them, or, TOLD a ``held`` range, one
+chip's share of an expert-parallel deployment (it routes over all the
+experts and computes its own experts' part; what the absent experts would
+have added is left out, nothing stands in for the exchange) (serving's three paged steps, ``forward`` without an ``expert`` mesh
 axis): the ``T x k`` assignments are flattened, sorted by expert, pushed
 through the three expert matmuls GROUPED by expert (:func:`grouped_matmul`:
 on a TPU the Pallas grouped matmul of ``megablox``, elsewhere
@@ -75,26 +80,44 @@ def route(
     renormalize: bool,
     router_noise: float = 0.0,
     rng: Optional[jax.Array] = None,
+    scoring: str = "softmax",
+    bias: Optional[jnp.ndarray] = None,
+    scale: float = 1.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The routing of every MoE path. x: [T, d], router: [d, E] →
     ``(gates [T, k] float32, experts [T, k] int32, probs [T, E] float32)``.
 
-    Router logits, softmax and ``top_k`` are float32 at the matmul's
+    Router logits, scores and ``top_k`` are float32 at the matmul's
     highest precision: the 8th and 9th expert of a token can be close, and
     a TPU's default precision would round the products to bfloat16.
+    ``scoring``: ``"softmax"`` over the experts, or ``"sigmoid"`` of each
+    logit by itself. ``bias`` [E] (a weight: the correction of auxiliary-
+    loss-free balancing) is added to the scores for the CHOICE of the
+    ``top_k`` alone; a kept expert's gate is its score without it.
     ``renormalize`` divides the kept gates by their sum (Mixtral, GShard);
-    without it the gates are the softmax's own values (OLMoE:
-    ``norm_topk_prob`` false)."""
+    without it the gates are the scores' own values (OLMoE:
+    ``norm_topk_prob`` false). ``scale`` multiplies the gates last."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )  # [T, E]
     if router_noise > 0.0 and rng is not None:
         logits = logits + router_noise * jax.random.normal(rng, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, top_k)  # [T, k]
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
+    if bias is None:
+        gates, experts = jax.lax.top_k(probs, top_k)  # [T, k]
+    else:
+        _, experts = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalize:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gates = gates * scale
     return gates, experts.astype(jnp.int32), probs
 
 
@@ -143,6 +166,9 @@ def dropless_moe_ffn(
     top_k: int,
     renormalize: bool,
     valid: Optional[jnp.ndarray] = None,
+    scoring: str = "softmax",
+    scale: float = 1.0,
+    held: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """x: [T, d] → (out [T, d], aux) with every valid row through all
     ``top_k`` of its experts: none dropped, no capacity.
@@ -150,28 +176,52 @@ def dropless_moe_ffn(
     ``valid``: [T] bool, absent = all. A row that is not valid is in no
     expert's group (its assignments sort behind the last group), costs no
     expert FLOPs beyond the grouped matmul's own tile padding, comes back
-    as zeros and is not counted. ``aux``: ``load`` [E] int32 (assignments
-    of the valid rows per expert; sums to ``valid.sum() * top_k``) and
-    ``aux_loss`` (Switch load-balance loss over ALL rows' routing: a
-    training regulariser, and training has no padding rows)."""
+    as zeros and is not counted. ``scoring``, ``scale`` and a
+    ``params["router_bias"]`` [E], where there is one: as in :func:`route`.
+
+    ``held = (lo, hi)``: this process holds the experts ``lo <= e < hi`` of
+    the ``E`` the router chooses among (one chip's share of an
+    expert-parallel deployment), and ``w_gate`` / ``w_up`` / ``w_down``
+    stack those ``hi - lo`` alone. Routing is over all ``E``; an assignment
+    to an absent expert is in no group, like a padding row's, and adds
+    nothing: the output is THIS share's part of the layer, and the shares
+    of all ranges sum to the whole layer. Absent = all ``E`` held.
+
+    ``aux``: ``load`` [E] int32 (assignments of the valid rows per expert,
+    over ALL ``E``; sums to ``valid.sum() * top_k``), ``aux_loss`` (Switch
+    load-balance loss over ALL rows' routing: a training regulariser, and
+    training has no padding rows) and, with a router bias, ``bias_changed``
+    (int32: valid rows whose kept set differs from the ``top_k`` of the
+    scores alone)."""
     T, d = x.shape
     E = params["router"].shape[1]
+    bias = params.get("router_bias")
     with jax.named_scope("moe.route"):
         gates, experts, probs = route(
-            params["router"], x, top_k=top_k, renormalize=renormalize
+            params["router"], x, top_k=top_k, renormalize=renormalize,
+            scoring=scoring, bias=bias, scale=scale,
         )
     with jax.named_scope("moe.dispatch"):
         flat = experts.reshape(T * top_k)
         if valid is not None:
             # expert id E: behind every group, in none
             flat = jnp.where(jnp.repeat(valid, top_k), flat, E)
-        order = jnp.argsort(flat, stable=True)  # assignment ids, by expert
+        lo, hi = held if held is not None else (0, E)
+        # the held experts' groups are numbered from 0; an assignment to an
+        # absent expert sorts behind them all, as a padding row's does
+        group = flat if held is None else jnp.where((flat >= lo) & (flat < hi), flat - lo, hi - lo)
+        order = jnp.argsort(group, stable=True)  # assignment ids, by expert
         load = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+        sizes = load if held is None else load[lo:hi]
         xs = x[order // top_k]  # [T*k, d]: each token's row, once per expert
     with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(xs, params["w_gate"], load)
-        up = grouped_matmul(xs, params["w_up"], load)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, params["w_down"], load)
+        gate = grouped_matmul(xs, params["w_gate"], sizes)
+        up = grouped_matmul(xs, params["w_up"], sizes)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, params["w_down"], sizes)
+        if held is not None:
+            # what the grouped matmul leaves behind the last group is
+            # unspecified: an absent expert's assignment adds exactly nothing
+            ys = jnp.where((jnp.arange(T * top_k) < sizes.sum())[:, None], ys, 0)
     with jax.named_scope("moe.combine"):
         y = jnp.zeros_like(ys).at[order].set(ys).reshape(T, top_k, d)
         out = jnp.einsum(
@@ -182,7 +232,24 @@ def dropless_moe_ffn(
             # all k assignments of a padding row lie behind the last group:
             # whatever the grouped matmul left there is replaced, not scaled
             out = jnp.where(valid[:, None], out, 0)
-    return out, {"load": load, "aux_loss": load_balance_loss(probs, experts)}
+    aux = {"load": load, "aux_loss": load_balance_loss(probs, experts)}
+    if bias is not None:
+        # the kept are the top_k of the scores alone unless an expert that
+        # was not kept scores above the lowest kept: no second top_k
+        kept = (experts[:, :, None] == jnp.arange(E, dtype=experts.dtype)).any(axis=1)
+        lowest_kept = jnp.take_along_axis(probs, experts, axis=-1).min(axis=-1)
+        changed = jnp.where(kept, -jnp.inf, probs).max(axis=-1) > lowest_kept
+        if valid is not None:
+            changed = changed & valid
+        aux["bias_changed"] = changed.sum().astype(jnp.int32)
+    return out, aux
+
+
+def gated_mlp(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray):
+    """The gated SiLU MLP every row goes through: a dense FFN, or the
+    SHARED expert beside routed ones (computed alike on every chip of an
+    expert-parallel deployment, so counted once when the shares are summed)."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
 def moe_ffn(

@@ -269,7 +269,7 @@ def test_kernel_decode_is_one_program_and_counts_live_blocks(cfg, params, runner
     logits are the ladder's, ONE program serves both, and the counter reads
     each slot's live blocks."""
     runner = _runner(cfg, params)
-    assert runner.reads_live_blocks == {1: True, 4: True} and runner.table_widths == (256,)
+    assert [runner.attention_paths[c] for c in (1, 4)] == [("kernel", "blocks")] * 2 and runner.table_widths == (256,)
     for ctx_lens in script:
         call = lambda r: r.decode(  # noqa: E731
             [7, 8, 9, 10], [c - 1 for c in ctx_lens], _rows(r, ctx_lens), list(ctx_lens)
@@ -284,7 +284,7 @@ def test_kernel_decode_is_one_program_and_counts_live_blocks(cfg, params, runner
         mean = sum(ctx_lens) / len(ctx_lens)
         assert kernel[3]["live_tokens"] / kernel[3]["gathered_tokens"] >= 1 - BS / mean
     assert runner.compile_count() == 1  # the ladder compiled one a rung
-    assert runners[0].reads_live_blocks == {1: False, 4: False}
+    assert [runners[0].attention_paths[c] for c in (1, 4)] == [("gather", "table")] * 2
 
 
 def test_kernel_reads_nothing_for_a_padding_slot_and_serves_verify(cfg, params, runners, kernel_forced):

@@ -26,17 +26,21 @@ from ray_tpu.inference.scheduler import (  # noqa: E402
     ContinuousBatchingScheduler,
     Request,
 )
-from ray_tpu.models.llama import LlamaConfig, forward, init_params  # noqa: E402
+from ray_tpu.models.interface import model_of  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.models.xing4 import Xing4Config  # noqa: E402
 
 
-@pytest.fixture(scope="module")
-def cfg():
-    return LlamaConfig.tiny()
+# every test that takes ``cfg`` runs on both cache layouts: K and V rows a
+# head (``models/llama.py``) and one latent row (``models/xing4.py``)
+@pytest.fixture(scope="module", params=["kv", "latent"])
+def cfg(request):
+    return LlamaConfig.tiny() if request.param == "kv" else Xing4Config.tiny()
 
 
 @pytest.fixture(scope="module")
 def params(cfg):
-    return init_params(cfg, jax.random.PRNGKey(0))
+    return model_of(cfg).init_params(cfg, jax.random.PRNGKey(0))
 
 
 _dense_fwd = {}
@@ -49,7 +53,7 @@ def _dense_greedy(cfg, params, prompt, n):
     fwd = _dense_fwd.get(cfg)
     if fwd is None:
         fwd = _dense_fwd[cfg] = jax.jit(
-            lambda p, t: forward(cfg, p, t)
+            lambda p, t: model_of(cfg).forward(cfg, p, t)
         )
     toks = list(prompt)
     out = []

@@ -37,20 +37,24 @@ import jax  # noqa: E402
 
 from ray_tpu.inference.engine import EngineConfig, InferenceEngine  # noqa: E402
 from ray_tpu.inference.kv_cache import PagedBlockManager, _chain_digest  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.models.interface import model_of  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.models.xing4 import Xing4Config  # noqa: E402
 
 #: 24 tokens = 3 full blocks at block_size 8
 SHARED = [12, 7, 3, 9, 1, 5, 2, 8] * 3
 
 
-@pytest.fixture(scope="module")
-def cfg():
-    return LlamaConfig.tiny()
+# every test that takes ``cfg`` runs on both cache layouts: K and V rows a
+# head (``models/llama.py``) and one latent row (``models/xing4.py``)
+@pytest.fixture(scope="module", params=["kv", "latent"])
+def cfg(request):
+    return LlamaConfig.tiny() if request.param == "kv" else Xing4Config.tiny()
 
 
 @pytest.fixture(scope="module")
 def params(cfg):
-    return init_params(cfg, jax.random.PRNGKey(0))
+    return model_of(cfg).init_params(cfg, jax.random.PRNGKey(0))
 
 
 def _ec(**overrides):
@@ -685,7 +689,7 @@ def test_tier_namespace_scopes_models(cfg, params):
     from ray_tpu.inference.serve_llm import LLMServer
     from ray_tpu.observability.rpc_metrics import KV_TIER_FALLBACKS
 
-    params2 = init_params(cfg, jax.random.PRNGKey(1))
+    params2 = model_of(cfg).init_params(cfg, jax.random.PRNGKey(1))
     a = InferenceEngine(cfg, params, _ec())
     b = InferenceEngine(cfg, params2, _ec())
     same = InferenceEngine(cfg, params, _ec())

@@ -1,0 +1,399 @@
+"""``models/xing4.py`` (latent attention, the hyper-connected residual,
+sigmoid routing with a shared expert over a held range of experts) against
+the plain reference of its family, ``perfbench/families/xing4/reference.py``,
+on the CPU at a small size: float32 against float32, seeded weights. And the
+latent cache layout through what handles blocks (export and import between
+two engines; the prefix cache, COW, preemption and the tier run on both
+layouts in ``test_inference.py`` / ``test_kv_tier.py``)."""
+
+import math
+import os
+import sys
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(HERE, "perfbench"))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+import rehearsal  # noqa: E402
+import xing4_controls as controls  # noqa: E402
+from perfbench import families  # noqa: E402
+from perfbench.families.xing4 import reference  # noqa: E402
+from ray_tpu.models import xing4  # noqa: E402
+from ray_tpu.models.interface import model_of  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.ops import moe as moe_ops  # noqa: E402
+
+CONFIG = "xing4.0-29b-a4b-ep8"
+TOL = 2e-4
+BS = 8
+
+
+@pytest.fixture(scope="module")
+def model():
+    return rehearsal.tiny_config(CONFIG)
+
+
+@pytest.fixture(scope="module")
+def cfg(model):
+    return families.of(model).model_config(model, max_seq_len=model["max_position_embeddings"])
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return xing4.init_params(cfg, jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(11).integers(1, 256, size=(2, 60)).astype(np.int32)
+
+
+def _rel(have, want):
+    return float(np.max(np.abs(np.asarray(have) - np.asarray(want))) / np.max(np.abs(np.asarray(want))))
+
+
+# -- the whole model through the latent paged cache -------------------------------------------
+
+def _prefill(cfg, params, cache, row_tokens, table, chunks, bucket=40):
+    step = jax.jit(lambda p, c, *a: xing4.paged_prefill_step(cfg, p, c, *a), donate_argnums=(1,))
+    start = 0
+    for c in chunks:
+        chunk = np.zeros(bucket, np.int32)
+        chunk[:c] = row_tokens[start : start + c]
+        cache, logits, _ = step(params, cache, chunk, table, np.int32(start), np.int32(c))
+        start += c
+    return cache, np.asarray(logits)
+
+
+@pytest.mark.parametrize("chunks", [(37,), (13, 24), (16, 16, 5), (7, 9, 11, 10), (32, 5)],
+                         ids=lambda c: "+".join(map(str, c)))
+def test_chunked_prefill_decode_and_verify_match_the_reference(model, cfg, params, tokens, chunks):
+    """Chunks whose boundaries split a block of 8, then a decode step, then
+    a verify window of 3, all through the latent cache, against the
+    reference's full forward pass: logits, not tokens."""
+    n = sum(chunks)
+    table = np.zeros(8, np.int32)
+    table[:8] = np.arange(1, 9)
+    cache = xing4.cache_layout(cfg, BS).init(16)
+    cache, got_prefill = _prefill(cfg, params, cache, tokens[0], table, chunks)
+    tables = np.zeros((4, 8), np.int32)
+    tables[1] = table  # slot 0 and 2, 3 are padding
+    decode = jax.jit(lambda p, c, *a: xing4.paged_decode_step(cfg, p, c, *a), donate_argnums=(1,))
+    toks, pos = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    toks[1], pos[1] = tokens[0, n], n
+    cache, got_decode, counters = decode(params, cache, toks, pos, tables, np.ones(4, np.int32))
+    assert int(counters["load"].sum()) == cfg.moe_top_k * cfg.n_moe_layers  # one real row
+    verify = jax.jit(lambda p, c, *a: xing4.paged_verify_step(cfg, p, c, *a), donate_argnums=(1,))
+    window = np.zeros((4, 4), np.int32)
+    window[1, :3] = tokens[0, n + 1 : n + 4]
+    ctx, true = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    ctx[1], true[1] = n + 1, 3
+    cache, got_verify, _ = verify(params, cache, window, tables, ctx, true)
+    picks = [(0, n - 1), (0, n)] + [(0, n + 1 + i) for i in range(3)]
+    want = reference.logits_at(model, params, tokens, picks)
+    have = [got_prefill, np.asarray(got_decode)[1]] + [np.asarray(got_verify)[1, i] for i in range(3)]
+    for h, w in zip(have, want):
+        assert _rel(h, w) < TOL
+
+
+def test_forward_matches_the_reference_and_the_counts(model, cfg, params, tokens):
+    full = np.asarray(jax.jit(lambda p, t: xing4.forward(cfg, p, t))(params, jnp.asarray(tokens)))
+    picks = [(0, 59), (1, 3), (1, 40)]
+    for (i, t), want in zip(picks, reference.logits_at(model, params, tokens, picks)):
+        assert _rel(full[i, t], want) < TOL
+    fam = families.of(model)
+    n = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert fam.param_count(model) == xing4.param_count(cfg) == n
+    layout = xing4.cache_layout(cfg, BS)
+    assert fam.kv_bytes_per_token(model, 4) == layout.bytes_per_token
+    assert layout.describe() == {"kind": "latent", "row_width": 24, "bytes_per_token": 4 * 24 * 4}
+    assert layout.payload_shape(3) == (1, 4, 3, BS * 24)  # a block of rows stored as one row
+
+
+@pytest.mark.parametrize("window", [1, 4])
+def test_absorbed_attention_equals_expanded_on_the_same_rows(cfg, params, window):
+    """The two latent paths are the same mathematics: ``W_kvb`` absorbed
+    into the query and the output against K and V expanded from the rows."""
+    rng = np.random.default_rng(window)
+    p = {k: v[0] for k, v in params["moe"].items()}
+    B, S = 3, 40
+    q_nope = jnp.asarray(rng.standard_normal((B, window, cfg.n_heads, cfg.qk_nope_head_dim)), jnp.float32)
+    q_rope = jnp.asarray(rng.standard_normal((B, window, cfg.n_heads, cfg.qk_rope_head_dim)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((B, S, cfg.latent_width)), jnp.float32)
+    pos = jnp.asarray(rng.integers(window, S, size=(B, 1)) - np.arange(window)[::-1][None], jnp.int32)
+    e = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, jnp.arange(S) <= pos[:, :, None])
+    # absorbed, as the paged step calls it: the context before the window, the window's own rows beside it
+    own = jnp.take_along_axis(rows, pos[:, :, None], axis=1)
+    before = jnp.broadcast_to((jnp.arange(S) < pos[:, :1])[:, None, :], (B, window, S))
+    q_row = xing4._absorb_query(cfg, p, q_nope, q_rope)
+    a = xing4._absorb_output(cfg, p, xing4._attend_rows(cfg, q_row, rows, before, own))
+    assert a.shape == (B, window, cfg.n_heads, cfg.v_head_dim)
+    assert _rel(a, e) < 1e-5
+    assert xing4.absorbs(cfg, window)
+
+
+@pytest.mark.parametrize("block", [8, 16])
+def test_the_expanded_path_attends_a_block_of_queries_at_a_time(cfg, params, monkeypatch, block):
+    """A prefill chunk's queries attend ``_QUERY_BLOCK`` at a time (the
+    float32 scores of a whole chunk over the full table would be the
+    program's largest temporary): the same numbers as all at once."""
+    rng = np.random.default_rng(block)
+    p = {k: v[0] for k, v in params["moe"].items()}
+    B, C, S = 2, 32, 48
+    q_nope = jnp.asarray(rng.standard_normal((B, C, cfg.n_heads, cfg.qk_nope_head_dim)), jnp.float32)
+    q_rope = jnp.asarray(rng.standard_normal((B, C, cfg.n_heads, cfg.qk_rope_head_dim)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((B, S, cfg.latent_width)), jnp.float32)
+    mask = jnp.arange(S) <= (10 + jnp.arange(C))[None, :, None] + jnp.zeros((B, 1, 1), jnp.int32)
+    whole = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
+    monkeypatch.setattr(xing4, "_QUERY_BLOCK", block)
+    blocks = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
+    assert _rel(blocks, whole) < 1e-6
+
+
+def test_the_window_decides_the_latent_path_at_the_published_widths():
+    cfg = xing4.Xing4Config()
+    assert [xing4.absorbs(cfg, c) for c in (1, 4, 170, 171, 256, 1024)] == [True, True, True, False, False, False]
+    model = rehearsal._load("configs", f"{CONFIG}.json")
+    fam = families.of(model)
+    assert fam.absorb_break_even_window(model) == pytest.approx(170.67, abs=0.01)
+    assert fam.attention_flops_per_pair(model, True) == 69632 and fam.attention_flops_per_pair(model, False) == 20480
+    assert fam.expansion_flops_per_position(model) == 8388608
+    path = xing4.MODEL.attention_path
+    assert path(cfg, 1, None) == ("latent.absorbed", "slots") and path(cfg, 1024, None) == ("latent.expanded", "table")
+
+
+# -- YaRN ------------------------------------------------------------------------------------
+
+@pytest.mark.parametrize("published", [True, False], ids=["published", "toy"])
+def test_yarn_table_and_softmax_scale_against_the_formula(model, cfg, published):
+    c = xing4.Xing4Config() if published else cfg
+    dr, theta, factor, orig = c.qk_rope_head_dim, c.rope_theta, c.rope_factor, c.rope_original_max
+    want = []
+    for i in range(dr // 2):
+        base = theta ** (-2 * i / dr)
+        dim = lambda rot: dr * math.log(orig / (rot * 2 * math.pi)) / (2 * math.log(theta))  # noqa: E731
+        low, high = max(math.floor(dim(c.rope_beta_fast)), 0), min(math.ceil(dim(c.rope_beta_slow)), dr - 1)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        want.append(base / factor * ramp + base * (1 - ramp))
+    got = np.asarray(xing4.yarn_inv_freq(c))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert got[0] == pytest.approx(1.0) and got[-1] == pytest.approx(theta ** (-(dr - 2) / dr) / factor, rel=1e-6)
+    m = 0.1 * math.log(factor) + 1
+    assert xing4.softmax_scale(c) == pytest.approx((c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5 * m * m)
+    if published:
+        assert xing4.softmax_scale(c) == pytest.approx(192 ** -0.5 * (0.1 * math.log(64) + 1) ** 2)
+    else:
+        z = reference.sizes(model)
+        np.testing.assert_allclose(reference.yarn_inv_freq(z), got, rtol=1e-6)
+        assert reference.softmax_scale(z) == pytest.approx(xing4.softmax_scale(c))
+
+
+# -- mHC -------------------------------------------------------------------------------------
+
+@pytest.mark.parametrize("group, sub", [("dense", "hc_attn"), ("dense", "hc_mlp"), ("moe", "hc_attn"), ("moe", "hc_mlp")])
+def test_sinkhorn_is_doubly_stochastic_and_the_seeded_maps_matter(cfg, params, group, sub):
+    p = {k: v[1] for k, v in params[group].items()}
+    X = jnp.asarray(np.random.default_rng(3).standard_normal((2, 50, cfg.hc_mult, cfg.dim)), jnp.float32)
+    pre, post, res = xing4.mhc_maps(cfg, p[f"{sub}_phi"], p[f"{sub}_b"], p[f"{sub}_alpha"], X)
+    res = np.asarray(res)
+    assert np.abs(res.sum(-1) - 1).max() < 1e-5 and np.abs(res.sum(-2) - 1).max() < 1e-5
+    # tens of per cent away from the identity and from 1/n; pre and post away from constants
+    n = cfg.hc_mult
+    assert np.abs(res - np.eye(n)).mean() > 0.1 and np.abs(res - 1 / n).mean() > 0.1
+    assert np.asarray(pre).std() > 0.02 and np.asarray(post).std() > 0.04  # they move with the token
+
+
+def test_the_residual_is_x_plus_f_of_x_when_the_maps_are_forced(cfg):
+    """``H_res = I``, ``H_pre = H_post = e_1``: stream 0 becomes ``x + F(norm(x))``, the others stay."""
+    n, D = cfg.hc_mult, cfg.dim
+    maps = 2 * n + n * n
+    e1 = np.where(np.arange(n) == 0, 1.0, 0.0)
+    b = np.concatenate([
+        np.where(e1 > 0, 40.0, -40.0),              # sigmoid -> e_1
+        np.where(e1 > 0, 0.0, -40.0),               # 2 sigmoid -> e_1
+        np.where(np.eye(n) > 0, 30.0, -30.0).reshape(-1),  # exp, Sinkhorn -> I
+    ])
+    norm = np.random.default_rng(0).uniform(0.5, 1.5, D).astype(np.float32)
+    p = {"hc_attn_phi": jnp.zeros((n * D, maps)), "hc_attn_b": jnp.asarray(b, jnp.float32),
+         "hc_attn_alpha": jnp.ones(3), "attn_norm": jnp.asarray(norm)}
+    X = jnp.asarray(np.random.default_rng(1).standard_normal((1, 6, n, D)), jnp.float32)
+    F = lambda h: (jnp.tanh(h) * 3.0, None)  # noqa: E731
+    out, _ = xing4._hyper(cfg, p, "hc_attn", "attn_norm", X, F)
+    x = np.asarray(X)
+    want0 = x[..., 0, :] + 3.0 * np.tanh(x[..., 0, :] / np.sqrt((x[..., 0, :] ** 2).mean(-1, keepdims=True) + cfg.norm_eps) * norm)
+    np.testing.assert_allclose(np.asarray(out)[..., 0, :], want0, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out)[..., 1:, :], x[..., 1:, :], atol=1e-5)
+
+
+# -- the router, and the controls -------------------------------------------------------------
+
+def _program_gates(cfg, p, h):
+    g, e, _ = moe_ops.route(p["router"], h, top_k=cfg.moe_top_k, renormalize=True, scoring="sigmoid",
+                            bias=p["router_bias"], scale=cfg.routed_scaling_factor)
+    dense = jnp.sum(jnp.where(e[..., None] == jnp.arange(cfg.n_routed_experts), g[..., None], 0.0), axis=-2)
+    return np.asarray(dense)
+
+
+@pytest.fixture(scope="module")
+def routed(cfg, params):
+    p = {k: v[0] for k, v in params["moe"].items()}
+    # a bias large enough to change the kept set of most rows at this size
+    p["router_bias"] = 0.3 * jnp.asarray(np.random.default_rng(2).standard_normal(cfg.n_routed_experts), jnp.float32)
+    h = jnp.asarray(np.random.default_rng(4).standard_normal((200, cfg.dim)), jnp.float32)
+    return p, h
+
+
+def test_the_router_chooses_with_the_bias_and_gates_without_it(model, cfg, routed):
+    p, h = routed
+    z = reference.sizes(model)
+    want, margin = reference.gates(z, p["router"], p["router_bias"], h)
+    sure = np.asarray(margin) > 1e-4
+    have = _program_gates(cfg, p, h)
+    assert np.abs(have - np.asarray(want))[sure].max() < 1e-5
+    # by hand: the kept are the top-k of s + b, a gate is 2 s_e / sum of the kept s
+    s = 1 / (1 + np.exp(-(np.asarray(h, np.float64) @ np.asarray(p["router"], np.float64))))
+    kept = np.argsort(-(s + np.asarray(p["router_bias"], np.float64)), axis=-1)[:, : cfg.moe_top_k]
+    for t in np.flatnonzero(sure)[:50]:
+        by_hand = np.zeros(cfg.n_routed_experts)
+        by_hand[kept[t]] = 2 * s[t, kept[t]] / s[t, kept[t]].sum()
+        np.testing.assert_allclose(have[t], by_hand, atol=1e-5)
+    assert np.allclose(have.sum(-1), cfg.routed_scaling_factor, atol=1e-5)
+    out, aux = moe_ops.dropless_moe_ffn(
+        {k: p[k] for k in ("router", "router_bias", "w_gate", "w_up", "w_down")}, h,
+        top_k=cfg.moe_top_k, renormalize=True, scoring="sigmoid", scale=2.0, held=cfg.held_experts)
+    # by hand: rows whose kept set is not the top-k of s alone (rows without a margin aside)
+    unbiased = np.argsort(-s, axis=-1)[:, : cfg.moe_top_k]
+    changed = np.array([set(a) != set(b) for a, b in zip(kept, unbiased)])
+    assert 0 < changed.sum() and abs(int(aux["bias_changed"]) - changed.sum()) <= (~sure).sum()
+    assert int(aux["load"].sum()) == 200 * cfg.moe_top_k
+
+
+FFN_CONTROLS = ["bias_out_of_the_choice", "bias_in_the_gate", "gates_not_normalised",
+                "shared_expert_0_times", "shared_expert_2_times", "weights_fp8"]
+
+
+@pytest.mark.parametrize("variant", [None] + FFN_CONTROLS, ids=lambda v: v or "the_reference")
+def test_the_expert_ffn_matches_the_reference_and_no_control(model, cfg, routed, variant):
+    """The program's FFN of an expert layer (shared expert, router, sort,
+    grouped matmuls over the held experts, combine, the valid mask) against
+    the reference's on the same activations: within the tolerance of the
+    reference, 50 tolerances away from every control."""
+    p, h = routed
+    valid = jnp.arange(200) < 180
+    have = np.asarray(xing4._ffn(cfg, p, h[None], valid[None], True)[0][0])
+    want, margin = controls.expert_ffn(model, p, h[:180], variant)
+    sure = np.asarray(margin) > 1e-4
+    err = np.max(np.abs(have[:180] - np.asarray(want)), axis=-1) / np.maximum(np.max(np.abs(np.asarray(want)), axis=-1), 1e-30)
+    assert np.all(np.isfinite(have))
+    if variant is None:
+        assert err[sure].max() < TOL
+    else:
+        assert err[sure].max() > 50 * TOL
+
+
+MODEL_CONTROLS = ["sinkhorn_1_round", "post_without_its_2", "scale_without_m2", "key_rope_unrotated",
+                  "bias_out_of_the_choice", "shared_expert_2_times"]
+
+
+@pytest.mark.parametrize("variant", MODEL_CONTROLS)
+def test_a_control_of_the_whole_model_is_told_from_it(model, cfg, params, tokens, variant):
+    # the seeded bias changes the choice for some tokens only: ten times it, so that leaving it out shows
+    params = {**params, "moe": {**params["moe"], "router_bias": 10 * params["moe"]["router_bias"]}}
+    full = np.asarray(jax.jit(lambda p, t: xing4.forward(cfg, p, t))(params, jnp.asarray(tokens[:1])))
+    picks = [(0, 59), (0, 30)]
+    wrong = controls.logits_at(model, params, tokens[:1], picks, variant)
+    assert min(_rel(full[i, t], w) for (i, t), w in zip(picks, wrong)) > 50 * TOL
+
+
+# -- the shares of an expert-parallel deployment sum to the whole layer -------------------------
+
+@pytest.mark.parametrize("side", ["program", "reference"])
+def test_eight_shares_of_eight_experts_sum_to_the_uncut_layer(side):
+    """Guide section 4's test: 64 experts over 8 ranks, each told its range
+    of 8; the routed parts of all shares, with the shared expert counted
+    ONCE, add up to the uncut reference's whole layer."""
+    E, k, D, Fm, T = 64, 4, 64, 32, 96
+    rng = np.random.default_rng(7)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s) / math.sqrt(s[-2] if len(s) > 1 else 1), jnp.float32)  # noqa: E731
+    whole = {"router": f(D, E), "router_bias": 0.3 * f(E), "w_gate": f(E, D, Fm), "w_up": f(E, D, Fm),
+             "w_down": f(E, Fm, D), "shared_gate": f(D, Fm), "shared_up": f(D, Fm), "shared_down": f(Fm, D)}
+    h = f(T, D) * math.sqrt(T)
+    toy = {"hc_mult": 2, "num_attention_heads": 1, "qk_nope_head_dim": 1, "qk_rope_head_dim": 2, "v_head_dim": 1,
+           "kv_lora_rank": 1, "rms_norm_eps": 1e-6, "rope_theta": 1e4, "max_position_embeddings": 8,
+           "num_experts_per_tok": k, "routed_scaling_factor": 2, "norm_topk_prob": True, "hc_sinkhorn_iters": 1,
+           "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30, "first_k_dense_replace": 0}
+    uncut, _ = reference.expert_ffn(reference.sizes({**toy, "deployment": {"held_experts": [0, E]}}), whole, h)
+    shared = reference.mlp(whole["shared_gate"], whole["shared_up"], whole["shared_down"], h)
+    total = np.asarray(shared)
+    for lo in range(0, E, 8):
+        share = {**whole, **{n: whole[n][lo : lo + 8] for n in ("w_gate", "w_up", "w_down")}}
+        if side == "program":
+            routed, aux = moe_ops.dropless_moe_ffn(
+                {n: share[n] for n in ("router", "router_bias", "w_gate", "w_up", "w_down")}, h,
+                top_k=k, renormalize=True, scoring="sigmoid", scale=2.0, held=(lo, lo + 8))
+            assert int(aux["load"].sum()) == T * k  # the load is over all 64, whatever is held
+        else:
+            z = reference.sizes({**toy, "deployment": {"held_experts": [lo, lo + 8]}})
+            routed = reference.expert_ffn(z, share, h)[0] - shared
+        total = total + np.asarray(routed)
+    assert _rel(total, uncut) < 1e-5
+
+@pytest.mark.parametrize("real", [1, 2, 4], ids=lambda n: f"{n}_of_4_slots_real")
+def test_the_absorbed_path_reads_nothing_for_a_padding_slot(cfg, params, tokens, real):
+    """A decode batch padded to its bucket of 4: the real slots' logits are
+    those of a bucket that holds them alone, and the runner counts the table's
+    width for the real slots only (``AttentionPath.reads == "slots"``)."""
+    from ray_tpu.inference.model_runner import PagedModelRunner
+
+    def runner(bucket):
+        r = PagedModelRunner(cfg, params, num_blocks=40, block_size=BS, prefill_buckets=(40,),
+                             decode_buckets=(bucket,))
+        full = r.table_widths[-1]
+        rows = [list(range(1 + 8 * slot, 9 + 8 * slot)) + [0] * (full - 8) for slot in range(real)]
+        for slot in range(real):
+            r.prefill_chunk(tokens[slot % 2, : 30 + slot].tolist(), rows[slot], 0)
+        ctx = [31 + slot for slot in range(real)]
+        return r, r.decode([7] * real, [c - 1 for c in ctx], rows, ctx)
+
+    padded, have = runner(4)
+    alone, want = runner(real)
+    np.testing.assert_allclose(have, want, rtol=0, atol=1e-5)
+    width = padded.table_widths[0] * BS
+    assert padded.decode_width["gathered_tokens"] == real * width == alone.decode_width["gathered_tokens"]
+    assert padded.decode_width["live_tokens"] == sum(31 + slot for slot in range(real))
+
+
+# -- export and import between two engines, on both layouts -------------------------------------
+
+@pytest.mark.parametrize("layout", ["kv", "latent"])
+def test_kv_export_then_import_on_a_second_engine(layout):
+    """Engine A prefills and exports its blocks; engine B imports them and
+    serves the same prompt from a prefix hit, token for token as a cold
+    engine does. The payload has the cache layout's shape."""
+    from ray_tpu.inference.engine import EngineConfig, InferenceEngine
+
+    cfg = LlamaConfig.tiny() if layout == "kv" else xing4.Xing4Config.tiny()
+    params = model_of(cfg).init_params(cfg, jax.random.PRNGKey(0))
+    ec = EngineConfig(num_blocks=32, block_size=8, prefill_buckets=(8, 32), decode_buckets=(1, 4),
+                      max_decode_batch=4, max_new_tokens_default=6, warmup=False, kv_transfer_enabled=True)
+    prompt = [int(t) for t in np.random.default_rng(9).integers(1, 200, size=27)]
+    a, b, cold = (InferenceEngine(cfg, params, ec).start() for _ in range(3))
+    try:
+        payload = a.prefill_kv(prompt)
+        kv = payload["kv"]
+        assert kv.shape == a.runner.cache_layout.payload_shape(3) and payload["block_size"] == 8
+        assert a.stats()["kv_layout"]["kind"] == layout
+        assert b.import_kv_blocks(payload["tokens"], kv) == 24
+        warm = list(b.generate(prompt, max_new_tokens=6))
+        assert warm == list(cold.generate(prompt, max_new_tokens=6))
+        assert b.blocks.prefix_stats()["tokens_saved_total"] >= 16  # 3 blocks imported; the last is copied on write
+    finally:
+        for e in (a, b, cold):
+            e.stop()
