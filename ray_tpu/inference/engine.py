@@ -2167,6 +2167,8 @@ class InferenceEngine:
             # how wide the decode and verify launches gathered (the target
             # runner's own; a draft model's runner keeps its own count)
             "decode_width": dict(self.runner.decode_width),
+            # what the prefill launches' attention read of the table, likewise
+            "prefill_width": dict(self.runner.prefill_width),
             # what a token leaves in the cache (the model's description)
             "kv_layout": self.runner.cache_layout.describe(),
             "request_stages": dict(self._request_stages),

@@ -37,6 +37,14 @@ latent paths both gather, the absorbed one for the real slots alone):
 ``decode_width`` counts what was handed over and what the launched program
 reads of the cache, either way.
 
+A prefill chunk is always handed the full-width row. ``models/llama.py``'s
+chunk gathers and attends over all of it (``table``); so does ``models/
+xing4.py``'s wherever its flash kernel (``ops/latent_flash.py``) does not
+serve, and where it does (a TPU, whole tiles) the chunk still gathers and
+expands the table whole but attends over the key tiles up to its own end
+alone (``live``). ``prefill_width`` counts the positions up to each chunk's
+end and the key positions its attention reads, for every model.
+
 A MoE config's steps return a third output, the expert loads
 ``[n_layers, E]`` of the launch's real rows (or a dict with them under
 ``load`` beside further counters a layer). It is copied to the host with
@@ -177,6 +185,14 @@ class PagedModelRunner:
         #: reads nothing; the kernel: each real slot's live blocks)
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
+        )
+        #: running sums over prefill launches: the width of the table handed
+        #: over (tokens), the positions up to the chunk's end, and the key
+        #: positions the program's attention reads: the table's width
+        #: (``reads`` = ``table``) or, through a flash kernel (``live``), the
+        #: positions up to the chunk's end in whole key tiles
+        self.prefill_width: Dict[str, int] = dict.fromkeys(
+            ("launches", "width_tokens", "live_tokens", "read_tokens"), 0
         )
         #: MoE configs only: what the experts saw, as running sums over
         #: decode and verify launches and, apart, prefill launches
@@ -429,9 +445,18 @@ class PagedModelRunner:
         clock = clock or self.clock
         true_len = len(tokens)
         bucket = _round_up_bucket(true_len, self.prefill_buckets)
+        path = self._path(bucket)
+        read = width = len(block_row) * self.block_size
+        if path.reads == "live":
+            tile = self.model.key_tile(self.cfg, bucket, self.cache)
+            read = -(-(ctx_len + true_len) // tile) * tile
+        pw = self.prefill_width
+        pw["launches"] += 1
+        pw["width_tokens"] += width
+        pw["live_tokens"] += ctx_len + true_len
+        pw["read_tokens"] += read
         with clock.phase(
-            "launch", program="paged_prefill_step", bucket=bucket,
-            path=self._path(bucket).name,
+            "launch", program="paged_prefill_step", bucket=bucket, path=path.name,
         ):
             padded = np.zeros(bucket, np.int32)
             padded[:true_len] = tokens

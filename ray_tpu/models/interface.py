@@ -167,11 +167,13 @@ class AttentionPath(NamedTuple):
 
     #: the model's name for the path, for the runner's launch span
     name: str
-    #: what a decode or verify launch reads of the cache: ``"table"`` (the
-    #: table as wide as it is handed over, for every slot of the batch
-    #: bucket), ``"slots"`` (as wide, for the real slots alone: a padding
-    #: slot reads nothing) or ``"blocks"`` (each real slot's own live blocks,
-    #: whatever the table's width: a kernel)
+    #: what a launch reads of the cache: ``"table"`` (the table as wide as
+    #: it is handed over, for every slot of the batch bucket), ``"slots"``
+    #: (as wide, for the real slots alone: a padding slot reads nothing),
+    #: ``"blocks"`` (each real slot's own live blocks, whatever the table's
+    #: width: a kernel) or ``"live"`` (a prefill chunk: the positions up to
+    #: the end of the chunk in whole key tiles, ``Model.key_tile``, whatever
+    #: the table's width: a kernel)
     reads: str
 
 
@@ -201,6 +203,9 @@ class Model:
     #: (cfg) -> ``None`` for a model without routed experts, else
     #: ``(lo, hi)``: the range of experts this process holds
     held_experts: Callable
+    #: (cfg, window, cache) -> positions a key tile of a program whose
+    #: ``attention_path`` reads ``"live"``
+    key_tile: Callable = lambda cfg, window, cache: 1
 
 
 def model_of(cfg) -> Model:

@@ -35,6 +35,12 @@ are the same mathematics at different costs, chosen at trace time from the
 query window (:func:`absorbs`): a prefill chunk EXPANDS K and V of its
 context from the latent rows; a decode or verify window ABSORBS ``W_kvb``
 into the query and the output and attends over the latent rows directly.
+The expanded path attends two ways, chosen at trace time from what the
+code can observe (:func:`_flash_serves`: backend, dtype, whole tiles): on a
+TPU through the flash kernel ``ops/latent_flash.py`` (the float32 scores stay
+in VMEM; key tiles past the live context are not read), elsewhere, and in
+:func:`forward` (training needs a gradient), through the materialised
+softmax of :func:`_attend_expanded`.
 
 Layers of one kind are stacked on a leading axis and run under
 ``jax.lax.scan`` (two scans: the dense layers, the expert layers), so that
@@ -52,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.interface import AttentionPath, CacheLayout, Model
+from ray_tpu.ops import latent_flash
 from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
 from ray_tpu.parallel.sharding import constrain
 
@@ -398,9 +405,12 @@ def absorbs(cfg: Xing4Config, window: int) -> bool:
     return window * (2 * kr - dn - dv) < kr * (dn + dv)
 
 
-#: queries of a prefill chunk that attend at a time on the expanded path: the
-#: float32 scores of 1024 queries x 32 heads over a table of 8192 are 1.07 GB
-#: at once (the compile-only rehearsal's largest temporary), 0.27 GB a block
+#: queries that attend at a time where :func:`_attend_expanded` materialises
+#: the softmax (training's ``forward``; a prefill chunk wherever the flash
+#: kernel does not serve: the CPU, odd widths): the float32 scores of 1024
+#: queries x 32 heads over a table of 8192 are 1.07 GB at once (the
+#: compile-only rehearsal's largest temporary), 0.27 GB a block. The kernel
+#: (:func:`_attend_flash`) keeps a tile of them in VMEM and has no use for it
 _QUERY_BLOCK = 256
 
 
@@ -484,6 +494,52 @@ def _attend_expanded(cfg: Xing4Config, p, q_nope, q_rope, rows, mask):
 
         out = jax.lax.map(lambda t: attend(*t), (split(q), split(mask)))
         return jnp.moveaxis(out, 0, 1).reshape(B, C, *out.shape[3:])
+
+
+def _flash_serves(cfg: Xing4Config, window: int, cache, keys=None, backend=None) -> bool:
+    """Whether a window of ``window`` queries attends through the flash
+    kernel on the expanded path (:func:`_attend_flash`) over ``keys`` key
+    positions (a table's width in tokens; the runner's full width where
+    none is given): ``ops/latent_flash.py::kernel_serves`` on what the code
+    can observe (backend, dtype, whole tiles, the head widths). Off a TPU
+    the cache is not looked at."""
+    if (backend or jax.default_backend()) != "tpu":
+        return False
+    return latent_flash.kernel_serves(
+        window, keys or _table_keys(cfg, cache), cfg.qk_nope_head_dim, cfg.v_head_dim,
+        cfg.qk_rope_head_dim, cache["latent"].dtype, "tpu",
+    )
+
+
+def _table_keys(cfg: Xing4Config, cache) -> int:
+    """Positions of the block table a prefill chunk is handed: ``max_seq_len``
+    in whole blocks (``model_runner.py``'s ``max_blocks_per_seq``)."""
+    bs = _block_size(cfg, cache)
+    return -(-cfg.max_seq_len // bs) * bs
+
+
+def _attend_flash(cfg: Xing4Config, p, q_nope, q_rope, rows, ctx_len, true_len):
+    """:func:`_attend_expanded` for ONE slot through the flash kernel
+    (``ops/latent_flash.py``): queries ``[C, H, .]`` over latent rows ``rows
+    [S, kr + dr]`` with the window's own rows laid over them, query ``c``
+    seeing row ``j`` iff ``j <= ctx_len + c``, the first ``true_len`` queries
+    real. K and V are expanded from ALL ``S`` rows by XLA as there, heads
+    leading (``k_rope`` stays ONE row a position: the kernel adds its product
+    to ``k_nope``'s); the float32 scores never leave VMEM, and of the
+    expanded tiles only those up to ``ctx_len + true_len`` are read.
+    Returns ``[C, H, dv]``; what a query past ``true_len`` gets is finite
+    and nobody's."""
+    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    c, k_rope = rows[:, :kr], rows[:, kr:]
+    with jax.named_scope("mla.expand"):
+        k_nope = jnp.einsum("sr,rhk->hsk", c, p["w_kvb"][..., :dn])
+        v = jnp.einsum("sr,rhk->hsk", c, p["w_kvb"][..., dn:])
+    with jax.named_scope("mla.attend"):
+        out = latent_flash.flash_attention(
+            q_nope.swapaxes(0, 1), k_nope, v, ctx_len, true_len, scale=softmax_scale(cfg),
+            q_shared=q_rope.swapaxes(0, 1), k_shared=k_rope,
+        )
+    return out.swapaxes(0, 1)
 
 
 def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
@@ -676,22 +732,29 @@ def _window_blocks(cfg: Xing4Config, cache, window: int) -> int:
     return (window + bs - 2) // bs + 1
 
 
-def _latent_attention(cfg: Xing4Config, p, q_nope, q_rope, row, cache, layer, block_tables, pos):
+def _latent_attention(
+    cfg: Xing4Config, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens
+):
     """Causal attention of a window's queries (``[B, C, H, .]``, rope
     applied) over the cached context of their slots through ``block_tables
     [B, M]`` AND the window's own rows ``row [B, C, kr + dr]``: the ONE
     place a serving step reads the cache for attention. A slot's window is
     CONTIGUOUS: ``pos[b, c] = pos[b, 0] + c`` (all three entry points).
     Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
-    c]``. The gathered context ``cache[layer, block_tables]`` is as wide as
-    the table handed over (a kernel over latent rows would replace that).
-    A window attends to itself as after the write (:func:`_paged_layers`
-    says why the write itself comes last), by the path chosen at trace time
-    from the window (:func:`absorbs`): a prefill chunk lays its rows over
-    the positions they will be written to (one ``dynamic_update_slice``)
-    and expands K and V of that context from the latent rows; a decode or
-    verify window absorbs ``W_kvb``, attends over the gathered rows before
-    it directly and over its own rows beside them.
+    c]``; the first ``true_lens[b]`` queries of a slot are real. The
+    gathered context ``cache[layer, block_tables]`` is as wide as the table
+    handed over (a kernel over latent rows would replace that). A window
+    attends to itself as after the write (:func:`_paged_layers` says why
+    the write itself comes last), by the path chosen at trace time from the
+    window (:func:`absorbs`): a prefill chunk lays its rows over the
+    positions they will be written to (one ``dynamic_update_slice``) and
+    expands K and V of that context from the latent rows, then attends
+    through the flash kernel where it serves (:func:`_flash_serves`: a TPU,
+    whole tiles; the scores stay in VMEM and the expanded tiles past the live
+    context are not read) and through :func:`_attend_expanded`'s
+    materialised softmax elsewhere; a decode or verify window absorbs
+    ``W_kvb``, attends over the gathered rows before it directly and over
+    its own rows beside them.
 
     Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
     ``blocks`` are the ``nblk`` (:func:`_window_blocks`) blocks from the
@@ -742,8 +805,22 @@ def _latent_attention(cfg: Xing4Config, p, q_nope, q_rope, row, cache, layer, bl
         return _absorb_output(cfg, p, o_lat), blocks
     rows = jax.vmap(context)(tables)
     rows = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a, 0)))(rows, row, first)
+    blocks = jax.vmap(window_blocks)(rows, first)
+    keys = block_tables.shape[1] * bs
+    if _flash_serves(cfg, C, cache, keys):
+        # a slot's real queries end inside the table: the null columns
+        # behind it hold padding rows alone, and no key for anybody
+
+        def slot(args):
+            q_n, q_r, r, at, n = args
+            return _attend_flash(cfg, p, q_n, q_r, r[:keys], at, n)
+
+        args = (q_nope, q_rope, rows, first, true_lens)
+        if B == 1:  # a prefill chunk
+            return slot(jax.tree_util.tree_map(lambda a: a[0], args))[None], blocks
+        return jax.lax.map(slot, args), blocks
     mask = key_pos <= pos[:, :, None]
-    return _attend_expanded(cfg, p, q_nope, q_rope, rows, mask), jax.vmap(window_blocks)(rows, first)
+    return _attend_expanded(cfg, p, q_nope, q_rope, rows, mask), blocks
 
 
 def _block_size(cfg: Xing4Config, cache) -> int:
@@ -791,11 +868,12 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     # a padding slot (no valid row) is pointed at the null block whatever
     # its table holds
     block_tables = jnp.where(valid.any(axis=1, keepdims=True), block_tables, 0)
+    true_lens = valid.sum(axis=1, dtype=jnp.int32)  # the valid rows lead (all three entry points)
 
     def attention(p, h, layer):
         q_nope, q_rope, row = _latent_qkv(cfg, p, h, pos)
         o, blocks = _latent_attention(
-            cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos
+            cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens
         )
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), blocks
 
@@ -943,6 +1021,20 @@ def batch_sharding(mesh, rules):
 # ---------------------------------------------------------------------------
 # what the runtime knows of this module (models/interface.py)
 
+
+def _attention_path(cfg: Xing4Config, window: int, cache, backend=None) -> AttentionPath:
+    """Both latent paths gather the table as wide as it is handed over: the
+    absorbed one a slot at a time, and nothing for a padding slot; the
+    expanded one whole, and then attends over ALL of it (the materialised
+    softmax) or, through the flash kernel, over the key tiles up to the
+    live context alone."""
+    if absorbs(cfg, window):
+        return AttentionPath("latent.absorbed", "slots")
+    if _flash_serves(cfg, window, cache, backend=backend):
+        return AttentionPath("latent.flash", "live")
+    return AttentionPath("latent.expanded", "table")
+
+
 MODEL = Model(
     name="xing4",
     init_params=init_params,
@@ -953,11 +1045,7 @@ MODEL = Model(
     paged_prefill_step=paged_prefill_step,
     paged_verify_step=paged_verify_step,
     paged_decode_step=paged_decode_step,
-    # both latent paths gather the table as wide as it is handed over; the
-    # absorbed one a slot at a time, and nothing for a padding slot
-    attention_path=lambda cfg, window, cache: (
-        AttentionPath("latent.absorbed", "slots") if absorbs(cfg, window)
-        else AttentionPath("latent.expanded", "table")
-    ),
+    attention_path=_attention_path,
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
+    key_tile=lambda cfg, window, cache: latent_flash.tiles(window, _table_keys(cfg, cache))[1],
 )

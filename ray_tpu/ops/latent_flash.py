@@ -1,0 +1,259 @@
+"""Flash attention for a LONG query window over keys it is handed whole (a
+prefill chunk over its context with its own rows laid over it): a Pallas TPU
+kernel whose causal diagonal is OFFSET by a traced scalar. Query ``c`` of the
+window stands at position ``ctx_len + c`` and sees key ``j`` iff ``j <=
+ctx_len + c``; of the window's ``C`` queries the first ``true_len`` are real.
+``ops/attention.py``'s mask (``q_pos >= k_pos`` from 0) cannot say that, and
+its tiles do not stop at a live length.
+
+What it keeps out of HBM is the score matrix: float32 scores ``[H, C, S]``
+written, read for the softmax, written again as probabilities and read for
+the second product were 5.6 GB a layer at Xing4's widths (PERF.md, PR 31),
+six to seven times what the two products cost the MXU. Here a ``[block_q,
+block_k]`` tile of them lives in VMEM and dies there.
+
+Design, as ``ops/attention.py::_fwd_kernel``: the grid is ``(heads, query
+tiles, key tiles)``, the key stream innermost, so Pallas double-buffers the
+next K / V tile against this one's products; online softmax with float32
+scores, state and accumulator in VMEM scratch across the key steps; the
+output tile is written on the last. ``ctx_len`` and ``true_len`` are
+scalar-prefetched, so the index maps read them: a query tile's key-tile
+index is CLAMPED at the last tile any of its real queries sees (a repeated
+block index costs no DMA) and ``pl.when`` skips the products, so **a key
+tile wholly past ``ctx_len + true_len - 1`` is never fetched and never
+multiplied**, and a query tile past ``true_len`` runs nothing and comes back
+as zeros. A key tile wholly under the query tile's diagonal takes no mask.
+The cost follows the live context, not ``S``.
+
+A head's key may have a part that is ONE row a position for all heads (MLA's
+rotary part): ``k_shared [S, ds]`` beside ``k [H, S, dk]``, and the score is
+the sum of two products, ``q . k + q_shared . k_shared``, each over whole
+lanes: nothing is concatenated to 192 columns and padded to 256. ``dk`` and
+``dv`` need not agree.
+
+The mathematics is the materialised softmax's to the letter
+(``models/xing4.py::_attend_expanded``): scores float32, times ``scale``,
+masked scores ``-1e30``, probabilities cast to V's dtype for the second
+product, float32 accumulation. A padded query (``c >= true_len``) sees what
+the last real one sees; its output is finite and nobody reads it. What lies
+past the live context may be stale or never written: it is masked out of the
+scores and zeroed in V before the second product, so a NaN there cannot
+reach the output.
+
+The call is jitted by itself: a model whose layers run under two scans
+lowers the kernel once, not once a scan.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+#: what a masked score is set to (``_attend_expanded``'s value)
+_MASKED = -1e30
+
+#: queries a tile (the whole window where it is shorter) and keys a tile: on
+#: the chip, at Xing4's widths (32 heads, 128 + 64 shared, 128, bf16, 1024
+#: queries; ms at a context of 0 / 2048 / 5120 / 7168), (1024, 1024) 0.28 /
+#: 0.61 / 1.10 / 1.40 beat (512, 1024) 0.32 / 0.67 / 1.17 / 1.45, (512, 512)
+#: 0.40 / 0.92 / 1.66 / 2.15 and (1024, 256) 0.70 / 1.74 / 3.28 / 4.32: a key
+#: step costs its fixed part whatever it multiplies; (512, 2048) 0.48 / 0.87 /
+#: 1.21 / 1.46 multiplies more dead columns and gains nothing back. 256
+#: queries: (256, 1024) 0.16 / 0.24 / 0.38 / 0.45 (PERF.md, PR 32)
+_QUERY_TILE = 1024
+_KEY_TILE = 1024
+
+
+def tiles(window: int, keys: int) -> tuple:
+    """``(block_q, block_k)`` the kernel runs a window of ``window`` queries
+    over ``keys`` key positions with."""
+    return min(window, _QUERY_TILE), min(keys, _KEY_TILE)
+
+
+def kernel_serves(
+    window: int, keys: int, dk: int, dv: int, ds: int, dtype, backend: str | None = None
+) -> bool:
+    """Whether :func:`flash_attention` runs the kernel for ``window`` queries
+    over ``keys`` key positions at head widths ``dk`` (+ ``ds`` shared, 0 for
+    none) and ``dv``: on a TPU, in a dtype the MXU multiplies, where the
+    window and the keys are whole tiles of whole ``(16, 128)`` registers and
+    every product runs over whole lanes (``ds``: half a register's lanes is
+    what Mosaic still takes as a block as wide as its array; compiled for a
+    described v5e, ``tests/test_olmoe.py``). Everything else (the CPU, odd
+    widths) keeps the materialised softmax. Decided at trace time; the model
+    runner asks the same question to know what a prefill launch reads."""
+    backend = backend or jax.default_backend()
+    block_q, block_k = tiles(window, keys)
+    return (
+        backend == "tpu"
+        and dtype in (jnp.bfloat16, jnp.float32)
+        and block_q % 128 == 0
+        and block_k % 128 == 0
+        and window % block_q == 0
+        and keys % block_k == 0
+        and dk % 128 == 0
+        and dv % 128 == 0
+        and ds % 64 == 0
+    )
+
+
+def _last_key(i, ctx_len, true_len, block_q: int):
+    """The last key position a REAL query of query tile ``i`` sees: -1 for a
+    tile with none."""
+    seen = ctx_len + jnp.minimum((i + 1) * block_q, true_len) - 1
+    return jnp.where(i * block_q < true_len, seen, -1)
+
+
+def _kernel(
+    ctx_ref,  # SMEM [1] int32: the first query's position
+    len_ref,  # SMEM [1] int32: real queries of the window
+    q_ref,  # VMEM [1, block_q, dk]
+    k_ref,  # VMEM [1, block_k, dk]
+    v_ref,  # VMEM [1, block_k, dv]
+    *rest,  # shared: qs_ref [1, block_q, ds], ks_ref [block_k, ds]; then
+    # o_ref [1, block_q, dv] and the scratch acc [block_q, dv], m, l [block_q, 1]
+    scale: float,
+    shared: bool,
+):
+    from jax.experimental import pallas as pl
+
+    (qs_ref, ks_ref), rest = (rest[:2], rest[2:]) if shared else ((None, None), rest)
+    o_ref, acc_ref, m_ref, l_ref = rest
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    i, j = pl.program_id(1), pl.program_id(2)
+    ctx_len, true_len = ctx_ref[0], len_ref[0]
+    n_live = ctx_len + true_len
+    first_q = ctx_len + i * block_q
+    lo = j * block_k
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def step(masked: bool):
+        v = v_ref[0]
+        contract_last = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], contract_last, preferred_element_type=jnp.float32)
+        if shared:
+            s += jax.lax.dot_general(
+                qs_ref[0], ks_ref[...], contract_last, preferred_element_type=jnp.float32
+            )
+        s = s * scale
+        if masked:
+            q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            k_pos = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos <= jnp.minimum(q_pos, n_live - 1), s, _MASKED)
+            # rows past the live context: stale, or never written
+            v = jnp.where(lo + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) < n_live, v, 0)
+        # key 0 is in tile 0 and every query of a tile that runs sees it: m
+        # is real from the first step on
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+
+    live = lo <= _last_key(i, ctx_len, true_len, block_q)
+    under_diagonal = lo + block_k - 1 <= first_q
+
+    @pl.when(live & under_diagonal)
+    def _():
+        step(masked=False)
+
+    @pl.when(live & jnp.logical_not(under_diagonal))
+    def _():
+        step(masked=True)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        l = l_ref[...]
+        # a query tile past true_len ran no step: zeros, not 0 / 0
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret"))
+def _call(q, k, v, q_shared, k_shared, ctx_len, true_len, *, scale, block_q, block_k, interpret):
+    # imported here, as ops/paged_attention.py does: a second of import that
+    # only a process which runs the kernel pays
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, C, dk = q.shape
+    S, dv = v.shape[1], v.shape[2]
+    shared = q_shared is not None
+
+    def key_tile(i, j, ctx, n):
+        # clamped at the last tile the query tile's real queries see: the
+        # tiles past it repeat its index and are not fetched
+        return jnp.minimum(j, jnp.maximum(_last_key(i, ctx[0], n[0], block_q), 0) // block_k)
+
+    q_tile = lambda h, i, j, ctx, n: (h, i, 0)  # noqa: E731
+    kv_tile = lambda h, i, j, ctx, n: (h, key_tile(i, j, ctx, n), 0)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((1, block_q, dk), q_tile),
+        pl.BlockSpec((1, block_k, dk), kv_tile),
+        pl.BlockSpec((1, block_k, dv), kv_tile),
+    ]
+    operands = [q, k, v]
+    if shared:
+        ds = q_shared.shape[2]
+        in_specs += [
+            pl.BlockSpec((1, block_q, ds), q_tile),
+            pl.BlockSpec((block_k, ds), lambda h, i, j, ctx, n: (key_tile(i, j, ctx, n), 0)),
+        ]
+        operands += [q_shared, k_shared]
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, shared=shared),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H, C // block_q, S // block_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, block_q, dv), q_tile),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((H, C, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        name="latent_flash",
+        interpret=interpret,
+    )(ctx_len.reshape(1), true_len.reshape(1), *operands)
+
+
+def flash_attention(
+    q, k, v, ctx_len, true_len, *, scale: float, q_shared=None, k_shared=None,
+    block_q=None, block_k=None, interpret=None,
+):
+    """Attention of ``q [H, C, dk]`` (+ ``q_shared [H, C, ds]``) over ``k [H,
+    S, dk]`` (+ ``k_shared [S, ds]``, one row a position for every head) and
+    ``v [H, S, dv]``: query ``c`` sees key ``j`` iff ``j <= min(ctx_len + c,
+    ctx_len + true_len - 1)`` (``ctx_len``, ``true_len`` int32 scalars, traced).
+    Returns ``[H, C, dv]`` in ``q``'s dtype; rows of a query tile past
+    ``true_len`` are zeros. Reads ``ctx_len + true_len`` keys rounded up to
+    ``block_k`` and no other, a query tile at most up to its own diagonal.
+
+    ``block_q`` / ``block_k``: the tiles (default :func:`tiles`; ``C`` and
+    ``S`` must be whole tiles). ``interpret``: run the kernel in Pallas'
+    interpreter (what the CPU tests do); by default wherever the backend is
+    not a TPU."""
+    default_q, default_k = tiles(q.shape[1], k.shape[1])
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _call(
+        q, k, v, q_shared, k_shared,
+        jnp.asarray(ctx_len, jnp.int32), jnp.asarray(true_len, jnp.int32),
+        scale=float(scale), block_q=block_q or default_q, block_k=block_k or default_k,
+        interpret=bool(interpret),
+    )

@@ -47,7 +47,9 @@ import jax.numpy as jnp
 _MASKED = -1e30
 
 #: query rows (window x heads) up to which the kernel serves; a prefill chunk
-#: (256 x 32 rows) wants a flash kernel over context + chunk, not this one
+#: (256 x 32 rows) wants a flash kernel over context + chunk, not this one:
+#: ``ops/latent_flash.py`` is that kernel (Xing4's chunk calls it over keys
+#: expanded from its latent rows; ``models/llama.py``'s chunk does not yet)
 _MAX_QUERY_ROWS = 256
 
 #: rows ``[bs * n_kv a block, hd]`` a DMA wave brings in, as whole blocks: on the

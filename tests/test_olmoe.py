@@ -449,3 +449,33 @@ def test_the_paged_attention_kernel_compiles_for_the_chip_at_the_benchmark_width
         assert compiled.out_info.shape == (32, window, heads, 128)
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize("window", [1024, 256], ids=["chunk_1024", "chunk_256"])
+def test_the_latent_flash_kernel_compiles_for_the_chip_at_xing4_widths(one_chip, window):
+    """The kernel of Xing4's prefill chunk (``ops/latent_flash.py``; here for
+    the same reason as the one above): 32 heads, keys 128 + 64 shared, values
+    128, a table of 8192 positions, at the tiles the module fixes. One Mosaic
+    call; the scores are nobody's temporary."""
+    from ray_tpu.ops import latent_flash as LF
+
+    H, S, dk, ds, dv = 32, 8192, 128, 64, 128
+    assert LF.kernel_serves(window, S, dk, dv, ds, jnp.bfloat16, backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        compiled = jax.jit(
+            lambda q, k, v, qs, ks, ctx, n: LF.flash_attention(
+                q, k, v, ctx, n, scale=0.1, q_shared=qs, k_shared=ks, interpret=False
+            )
+        ).lower(
+            shape((H, window, dk)), shape((H, S, dk)), shape((H, S, dv)), shape((H, window, ds)),
+            shape((S, ds)), shape((), jnp.int32), shape((), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_flash" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+        assert compiled.out_info.shape == (H, window, dv)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
