@@ -1,0 +1,164 @@
+"""The seam a ``model_config`` PR goes through, held open: the benchmark as
+such a PR would leave it (one configuration, one cell, the cell's name in
+``serve_tokens_per_s``'s ``workloads`` and two per-layer metrics with a suffix
+of their own, each APPENDED at the end of its list, as the driver demands) must
+pass every test of this folder that reads the lists of ``BENCHMARK.json``.
+
+PR 33 was refused because two tests held PR 31's entries to the LAST place of
+``configs`` and ``workloads``: appended to, the tests failed; with the new
+entries put before Xing4's the tests passed and the driver read a moved
+workload. A test here holds what its PR added RELATIVE to what was there
+(after a named earlier entry; membership; once), never a last place, a whole
+list or a count (``perfbench/README.md``, "Adding a family", step 6).
+
+Every ``test_perfbench_*.py`` beside this file is found by its name, so a file
+that a later PR adds is on trial without an edit here. Each is loaded under
+another name with ``cells.load_json`` answering for the appended benchmark
+and its new files, and every test function of it that reads the lists, starts
+no rehearsal and asks for no fixture is called (``pytest.mark.parametrize`` is
+unrolled). JSON and file reads only."""
+
+import copy
+import glob
+import importlib.util
+import inspect
+import itertools
+import os
+import sys
+import traceback
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, REPO)
+
+from perfbench import families  # noqa: E402
+from perfbench.harness import cells  # noqa: E402
+
+FILES = sorted(f for f in glob.glob(os.path.join(HERE, "test_perfbench_*.py"))
+               if os.path.abspath(f) != os.path.abspath(__file__))
+CONFIG, CELL, FAMILY, SUFFIX = "appended-olmoe-12l-v8", "appended-longprompt-batch", "appended_twin", "appended"
+#: a family outside ``perfbench/`` (as ``test_perfbench_families.py::twins`` makes them): OLMoE's members
+TWIN = "from perfbench.families.olmoe import *  # noqa: F401,F403\n"
+#: the per-layer metrics the throwaway cell brings under its own suffix -> the file each is a twin of
+NEW_METRICS = {f"{name}.{SUFFIX}": f"{name}.batch" for name in ("prefill_step_device_ms", "tokens_per_engine_step")}
+#: the last name of each list when this file was written (PR 34): where PR 33 put its entries BEFORE
+LAST_THEN = {"configs": "xing4.0-29b-a4b-ep8", "workloads": "mla-longdoc-batch",
+             "serve_tokens_per_s": "moe-chat-offline", "per_layer": "prefill_read_live_share.longdoc"}
+#: what a test that starts a rehearsal, a cluster or a process names
+STARTS_SOMETHING = ("rehearsal.", "bench_run.", "subprocess")
+
+
+def _appended(bench, at_the_end=True):
+    """``bench`` as a ``model_config`` PR would leave it, and the files that
+    PR would add (path -> content). ``at_the_end`` false: each new entry put
+    BEFORE the entry ``LAST_THEN`` names, which the driver refuses as a moved
+    entry (wherever later PRs have appended theirs since)."""
+    bench = copy.deepcopy(bench)
+
+    def put(which, items, new):
+        names = [item if isinstance(item, str) else item["name"] for item in items]
+        items.insert(len(items) if at_the_end else names.index(LAST_THEN[which]), new)
+
+    old = next(c for c in bench["configs"] if c["name"] == "olmoe-1b-7b-0125-12l")
+    model = cells.config_of(bench, old["name"])
+    # the guide's third cut beside depth: an eighth of the vocabulary held here
+    model.update(family=FAMILY, vocab_size=model["vocab_size"] // 8)
+    model["published"]["vocab_size"] = 8 * model["vocab_size"]
+    model["reduced"]["vocab_size"] = "an eighth of the vocabulary: this chip's slice"
+    file = f"perfbench/configs/{CONFIG}.json"
+    files = {os.path.join(cells.ROOT, file): model}
+    put("configs", bench["configs"], {**old, "name": CONFIG, "file": file, "reduced": sorted(model["reduced"])})
+    put("workloads", bench["workloads"], {"name": CELL, "config": CONFIG, "traffic": "longprompt-batch", "chips": 1,
+                                          "why": "a throwaway cell: what a model_config PR appends"})
+    put("serve_tokens_per_s", next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")["workloads"], CELL)
+    for name, twinned in NEW_METRICS.items():
+        entry = next(m for m in bench["per_layer"] if m["name"] == twinned)
+        put("per_layer", bench["per_layer"], {**entry, "name": name, "workloads": [CELL]})
+        files[os.path.join(cells.HERE, "layer_metrics", f"{name}.json")] = cells.layer_metric_spec(twinned)
+    files[os.path.join(cells.ROOT, "BENCHMARK.json")] = bench
+    return files
+
+
+@pytest.fixture
+def appended(tmp_path, monkeypatch):
+    """``install(at_the_end)``: from then on ``cells`` reads the appended
+    benchmark and its new files, and the throwaway family is importable."""
+    portion = tmp_path / "perfbench" / "families"
+    portion.mkdir(parents=True)
+    (portion / f"{FAMILY}.py").write_text(TWIN)
+    monkeypatch.setattr(families, "__path__", [*families.__path__, str(portion)])
+    monkeypatch.setattr(sys, "path", list(sys.path))  # the files under trial prepend to it as they load
+    read = cells.load_json
+
+    def install(at_the_end):
+        monkeypatch.setattr(cells, "load_json", read)  # a second call starts from the tree's own files again
+        files = _appended(cells.benchmark(), at_the_end)
+        monkeypatch.setattr(cells, "load_json", lambda path: copy.deepcopy(files[path]) if path in files else read(path))
+
+    yield install
+    sys.modules.pop(f"perfbench.families.{FAMILY}", None)
+
+
+def _cases(fn):
+    """The keyword arguments of each call ``pytest`` would make of ``fn``,
+    or nothing where it asks for a fixture."""
+    axes = []
+    for mark in getattr(fn, "pytestmark", []):
+        if mark.name != "parametrize":
+            continue
+        names, values = mark.args[:2]
+        names = [n.strip() for n in names.split(",")] if isinstance(names, str) else list(names)
+        values = [v.values if isinstance(v, type(pytest.param())) else v for v in values]
+        axes.append([dict(zip(names, v if len(names) > 1 else [v])) for v in values])
+    calls = [{k: v for part in combo for k, v in part.items()} for combo in itertools.product(*axes)]
+    wanted = set(inspect.signature(fn).parameters)
+    return [kwargs for kwargs in calls if set(kwargs) == wanted]
+
+
+def _failures(path):
+    """Load the test file under another name and call what reads the lists;
+    ``(how many calls were made, [the failures, one line each])``."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(f"appended_{stem}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    made, failed = 0, []
+    for name, fn in vars(module).items():
+        if not (name.startswith("test_") and inspect.isfunction(fn) and fn.__module__ == module.__name__):
+            continue
+        source = inspect.getsource(fn)
+        if not ("BENCH" in source or "cells." in source) or any(word in source for word in STARTS_SOMETHING):
+            continue
+        for i, kwargs in enumerate(_cases(fn)):
+            made += 1
+            try:
+                fn(**kwargs)
+            except Exception as e:  # noqa: BLE001 - whatever a test raises is that test's failure
+                line = traceback.extract_tb(e.__traceback__)[-1].lineno
+                failed.append(f"{stem}.py::{name}[{i}] line {line}: {type(e).__name__} {e}".strip())
+    return made, failed
+
+
+@pytest.mark.parametrize("path", FILES, ids=lambda p: os.path.basename(p)[len("test_perfbench_"):-len(".py")])
+def test_a_configuration_and_a_cell_appended_at_the_ends_pass(appended, path):
+    appended(at_the_end=True)
+    bench = cells.benchmark()
+    assert (bench["configs"][-1]["name"], bench["workloads"][-1]["name"]) == (CONFIG, CELL)
+    assert [m["name"] for m in bench["per_layer"][-len(NEW_METRICS):]] == list(NEW_METRICS)
+    _, failed = _failures(path)
+    assert not failed, "\n".join(failed)
+
+
+def test_the_trial_calls_tests_and_an_entry_put_before_one_that_was_there_fails(appended):
+    """The repair has not simply let go: the place of what was there is still
+    held, and the trial above does call tests (it is no empty loop)."""
+    appended(at_the_end=True)
+    assert sum(_failures(path)[0] for path in FILES) >= 100
+    appended(at_the_end=False)
+    for which, new in (("configs", CONFIG), ("workloads", CELL)):
+        names = [item["name"] for item in cells.benchmark()[which]]
+        assert names.index(new) + 1 == names.index(LAST_THEN[which])
+    failed = [line for path in FILES for line in _failures(path)[1]]
+    assert failed, "entries put before ones that were there were accepted by every test"

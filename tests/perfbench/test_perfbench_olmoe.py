@@ -87,8 +87,8 @@ def test_counts_agree_with_the_program_at_the_configurations_sizes():
 # -- the metric files -------------------------------------------------------------------
 
 def test_the_cell_reports_what_the_issue_names():
-    names = {m["name"] for m in MOE_METRICS}
-    assert names == {f"{n}.moe" for n in (
+    names = [m["name"] for m in MOE_METRICS]
+    added = {f"{n}.moe" for n in (
         "decode_step_device_ms", "prefill_step_device_ms", "device_idle_share", "tokens_per_engine_step",
         "step_host_serial_ms", "step_launch_ms", "step_device_wait_ms", "step_readback_ms",
         "kv_pool_peak_share", "preemptions", "recompiles_in_window", "decode_table_width_tokens",
@@ -98,8 +98,11 @@ def test_the_cell_reports_what_the_issue_names():
         # half of the moe account
         "step_schedule_ms", "step_sample_ms", "step_emit_ms", "moe_rows_per_expert_prefill",
     )}
+    # each there once. The set of ``.moe`` names is not closed and no place in a list is the last: a later
+    # PR appends its own (``test_perfbench_append.py``)
+    assert len(added) == 24 and all(names.count(name) == 1 for name in added)
     e2e = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert e2e["workloads"][-1] == CELL
+    assert e2e["workloads"].count(CELL) == 1
     cell = cells.cell(BENCH, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "chat-offline", 1)
 

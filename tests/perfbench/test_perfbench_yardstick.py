@@ -264,6 +264,39 @@ def test_benchmark_json_shape():
     assert used == {c["name"] for c in BENCH["configs"]}
 
 
+def _may_be_cut(key, held, published):
+    """Whether a configuration may list ``key`` under ``reduced`` at the
+    value ``held``. Never a width. What one chip of a stated deployment holds
+    of a layer may be its share (model-configs guide, section 4), down to the
+    guide's floors: an eighth of the vocabulary, 8 routed experts a layer."""
+    if key == "vocab_size":
+        return 8 * held >= published
+    if re.search(r"(_size|_dim|_rank|experts_per_tok)$", key):
+        return False
+    if re.search(r"(n_routed_experts|num_experts|num_local_experts)$", key):
+        return held >= 8
+    return True
+
+
+@pytest.mark.parametrize("key, held, published, may", [
+    ("num_hidden_layers", 12, 16, True),
+    ("max_position_embeddings", 8192, 262144, True),
+    ("vocab_size", 16384, 131072, True),          # an eighth: the floor itself
+    ("vocab_size", 16383, 131072, False),
+    ("n_routed_experts", 8, 64, True),            # Xing4's share of eight chips
+    ("n_routed_experts", 4, 64, False),
+    ("num_experts", 7, 64, False),
+    ("num_local_experts", 8, 128, True),
+    ("num_experts_per_tok", 8, 8, False),         # the widths, whatever the value
+    ("hidden_size", 2048, 4096, False),
+    ("moe_intermediate_size", 512, 1024, False),
+    ("kv_lora_rank", 256, 512, False),
+    ("qk_rope_head_dim", 32, 64, False),
+])
+def test_which_keys_a_configuration_may_cut(key, held, published, may):
+    assert _may_be_cut(key, held, published) is may
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_finds_its_files_and_its_arrows(cell):
     w = cells.cell(BENCH, cell)
@@ -277,7 +310,7 @@ def test_every_cell_finds_its_files_and_its_arrows(cell):
     assert sorted(entry["reduced"]) == sorted(config["reduced"])
     for key in entry["reduced"]:
         assert config[key] != config["published"][key]
-        assert not re.search(r"(_size|_dim|_rank|experts_per_tok)$", key), "a width may never be cut"
+        assert _may_be_cut(key, config[key], config["published"][key]), "a width may never be cut, nor a share fall under its floor"
     e2e = {m["name"] for m in cells.metrics_of(BENCH, cell, "end_to_end")}
     assert "setup_s" in e2e and len(e2e) >= 2
     for name in e2e:
