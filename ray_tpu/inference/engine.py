@@ -388,6 +388,7 @@ class InferenceEngine:
         #: by phase; _step() hands it to the runner's calls, which add
         #: their launch / device_wait / readback
         self._clock = timeline.PhaseClock("engine", STEP_PHASES)
+        state_slots = self._state_slots(model_cfg, ec)
         self.runner = PagedModelRunner(
             model_cfg,
             params,
@@ -397,6 +398,7 @@ class InferenceEngine:
             decode_buckets=decode_buckets,
             verify_buckets=ec.resolved_verify_buckets(),
             cache_dtype=ec.cache_dtype,
+            state_slots=state_slots,
         )
         #: the start-up account, written once while the replica comes up
         #: (LLMServer adds what it alone sees: the weights, and the whole
@@ -416,8 +418,11 @@ class InferenceEngine:
         self.blocks = PagedBlockManager(
             ec.num_blocks,
             ec.block_size,
-            prefix_cache_enabled=ec.prefix_cache_enabled,
+            # no state snapshot a block yet: a model with per-sequence state
+            # takes no prefix hit (:meth:`_state_slots`)
+            prefix_cache_enabled=ec.prefix_cache_enabled and not state_slots,
             prefix_cache_max_blocks=ec.prefix_cache_max_blocks,
+            state_slots=state_slots,
         )
         self.scheduler = ContinuousBatchingScheduler(
             self.blocks,
@@ -603,6 +608,41 @@ class InferenceEngine:
                 self.spec.mark_warm()
 
     # -- lifecycle --------------------------------------------------------
+    @staticmethod
+    def _state_slots(model_cfg, ec: "EngineConfig") -> int:
+        """State slots this engine's pool holds: 0 for a model whose layers
+        all attend. A model with recurrent layers (``Model.state_layout``)
+        gets ``max_decode_batch`` of them (every running sequence holds one
+        from admission on, and no more run at a time), and every feature
+        that would move a sequence's rows WITHOUT its state (export / import,
+        the tier, a verify window) is refused here, with the reason, instead
+        of answering wrongly. Prefix reuse, which is on by default, is
+        switched OFF for such a model where the block manager is made (no
+        block is indexed, no hit is taken: ``stats()["prefix_cache"]
+        ["enabled"]`` says so), because a hit would skip prefill over tokens
+        whose state nobody kept."""
+        from ray_tpu.models.interface import model_of
+
+        model = model_of(model_cfg)
+        if model.state_layout is None:
+            return 0
+        why = (
+            f"a {model.name} model keeps a per-sequence state in its recurrent layers beside "
+            "the rows a token: "
+        )
+        refused = {
+            "kv_transfer_enabled": (ec.kv_transfer_enabled,
+                "an exported prompt's blocks carry no state, so the importer would decode from zeros"),
+            "kv_tier_enabled": (ec.kv_tier_enabled,
+                "tier write-back and resume move blocks by their tokens' digest, without the state"),
+            "speculative_k": (ec.speculative_k > 0,
+                "a verify window needs the state after each of its positions for the roll-back"),
+        }
+        for field, (on, reason) in refused.items():
+            if on:
+                raise ValueError(f"{field} cannot run here: {why}{reason}")
+        return ec.max_decode_batch
+
     def start(self) -> "InferenceEngine":
         if self._thread is None or not self._thread.is_alive():
             self._stop.clear()
@@ -1084,7 +1124,9 @@ class InferenceEngine:
                 )
                 prompt = req.effective_prompt
                 tokens = prompt[start : start + chunk]
-            logits = self.runner.prefill_chunk(tokens, row, start, clock, wake)
+            logits = self.runner.prefill_chunk(
+                tokens, row, start, clock, wake, slot=self.blocks.slot_of(req.request_id)
+            )
             req.prefill_pos = start + chunk
             n_prefill_tokens += chunk
             if req.prefill_done and req.prefill_done_at is None:
@@ -1147,9 +1189,18 @@ class InferenceEngine:
                         for r in plain
                     ]
                     cls = [r.context_len for r in plain]
-                logits = self.runner.decode(toks, poss, rows, cls, clock, wake)
+                    slots = [self.blocks.slot_of(r.request_id) for r in plain]
+                    # an all-greedy batch reads back the device's picks (one
+                    # int a slot), not the logits: the same program either way
+                    greedy = all(r.temperature <= 0.0 for r in plain)
+                logits = self.runner.decode(
+                    toks, poss, rows, cls, clock, wake, slots=slots, greedy=greedy
+                )
                 with clock.phase("sample"):
-                    sampled = [self._sample(req, lg) for req, lg in zip(plain, logits)]
+                    if greedy:  # the device's picks, one a slot
+                        sampled = [int(t) for t in logits]
+                    else:
+                        sampled = [self._sample(req, lg) for req, lg in zip(plain, logits)]
                 with clock.phase("emit"):
                     for req, token in zip(plain, sampled):
                         self._emit_token(req, token)
@@ -1362,6 +1413,7 @@ class InferenceEngine:
         exportable — the caller falls back to plain generation). The
         prefill itself also populates THIS engine's radix index, so an
         exporting replica keeps the warm-prefix benefit locally."""
+        self._refuse_with_state("prefill_kv (KV export)")
         rid = self.submit(
             prompt,
             max_new_tokens=1,
@@ -1390,6 +1442,15 @@ class InferenceEngine:
             with self._lock:
                 self._out.pop(rid, None)
                 self._finished_at.pop(rid, None)
+
+    def _refuse_with_state(self, what: str) -> None:
+        """Blocks moved without their sequence's state would decode from
+        zeros: refuse on a model that has a state description."""
+        if self.runner.state_layout is not None:
+            raise RuntimeError(
+                f"{what} is refused: this model keeps a per-sequence state in its recurrent "
+                "layers, which the blocks do not carry"
+            )
 
     def _complete_prefill_export(self, req: Request, prompt) -> None:
         """Step-thread half of :meth:`prefill_kv`: the gather MUST run
@@ -1441,6 +1502,7 @@ class InferenceEngine:
         the immediately-following submit acquires them as a prefix hit.
         Raises on block-pool exhaustion or scatter failure (callers
         degrade to a plain prefill)."""
+        self._refuse_with_state("import_kv_blocks (KV import)")
         bs = self.blocks.block_size
         n = min(len(tokens) // bs, int(kv.shape[2]))
         if n <= 0:
@@ -2171,6 +2233,12 @@ class InferenceEngine:
             "prefill_width": dict(self.runner.prefill_width),
             # what a token leaves in the cache (the model's description)
             "kv_layout": self.runner.cache_layout.describe(),
+            # a model with recurrent layers: what a SEQUENCE holds beside
+            # its rows, and the pool of slots it is held in (None / zeros else)
+            "state_layout": (
+                self.runner.state_layout.describe() if self.runner.state_layout else None
+            ),
+            "state_pool": self.blocks.slot_stats(),
             "request_stages": dict(self._request_stages),
             "startup": {
                 **self.startup,

@@ -38,6 +38,15 @@ free list first, then reclaims the oldest unreferenced cached block.
 as free — it is reclaimable at zero cost, and admission control must
 see it that way or a warm cache would wedge the queue.
 
+State slots (a model with recurrent layers, ``models/interface.py::
+StateLayout``): beside its blocks a running request holds ONE slot of a
+second pool, the index of its fixed-size per-sequence arrays on the device.
+The same manager hands it out (:meth:`assign_slot`, at admission, after the
+blocks) and takes it back in :meth:`free`, which every way out of the
+running set goes through (finish, cancel, preemption's evict, the engine's
+fail-all), so a slot cannot outlive its request's blocks. Slot 0 is the
+null slot, as block 0 is the null block. ``state_slots`` 0: no such pool.
+
 Pure host-side python with no jax dependency: unit-testable without an
 accelerator, and cheap enough to run under the engine lock.
 """
@@ -87,6 +96,7 @@ class PagedBlockManager:
         *,
         prefix_cache_enabled: bool = False,
         prefix_cache_max_blocks: int = 0,
+        state_slots: int = 0,
     ):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null block)")
@@ -133,6 +143,17 @@ class PagedBlockManager:
         #: hook may read the device (the content dies with the return)
         #: but MUST NOT re-enter locked manager methods or block on IO.
         self._spill_hook = None
+        #: usable state slots (ids 1..state_slots; 0 = the model has no
+        #: per-sequence state and nobody asks)
+        self.state_slots = state_slots
+        self._free_slots: deque = deque(range(1, state_slots + 1))
+        self._slot: Dict[str, int] = {}
+        self.slots_peak_in_use = 0
+        self.slots_assigned_total = 0
+        self.slots_released_total = 0
+        #: requests that waited at the head of the admission queue for a
+        #: slot at least once (the scheduler counts each once)
+        self.slot_admission_waits = 0
         self._lock = threading.Lock()
         # lifetime accounting (engine /metrics + stats())
         self.total_allocs = 0
@@ -286,11 +307,52 @@ class PagedBlockManager:
             self.total_frees += released
             return released
 
+    # -- state slots ------------------------------------------------------
+    def has_free_slot(self) -> bool:
+        """Whether a request could be given a state slot now (always, for a
+        model without per-sequence state)."""
+        with self._lock:
+            return not self.state_slots or bool(self._free_slots)
+
+    def assign_slot(self, request_id: str) -> int:
+        """Hand ``request_id`` a free state slot (the one it holds, if any);
+        0 without a pool. The caller checked :meth:`has_free_slot`."""
+        with self._lock:
+            if not self.state_slots:
+                return 0
+            slot = self._slot.get(request_id)
+            if slot is None:
+                slot = self._slot[request_id] = self._free_slots.popleft()
+                self.slots_assigned_total += 1
+                self.slots_peak_in_use = max(self.slots_peak_in_use, len(self._slot))
+            return slot
+
+    def slot_of(self, request_id: str) -> int:
+        """The state slot the request holds; 0 (the null slot) if none."""
+        with self._lock:
+            return self._slot.get(request_id, 0)
+
+    def slot_stats(self) -> Dict[str, int]:
+        """What ``engine_stats()["state_pool"]`` says."""
+        with self._lock:
+            return {
+                "slots": self.state_slots,
+                "in_use": len(self._slot),
+                "peak_in_use": self.slots_peak_in_use,
+                "assigned": self.slots_assigned_total,
+                "released": self.slots_released_total,
+                "admission_waits": self.slot_admission_waits,
+            }
+
     def free(self, request_id: str) -> int:
         """Release every block the request holds (refcount-aware: shared
-        blocks survive for their other holders). Returns the number of
-        block references released."""
+        blocks survive for their other holders) and its state slot. Returns
+        the number of block references released."""
         with self._lock:
+            slot = self._slot.pop(request_id, None)
+            if slot is not None:
+                self._free_slots.append(slot)
+                self.slots_released_total += 1
             blocks = self._owned.pop(request_id, [])
             for blk in blocks:
                 self._release_block_locked(blk)

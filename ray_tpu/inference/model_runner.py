@@ -52,6 +52,13 @@ the logits, inside ``readback`` (the device has finished by then: no second
 sync), and summed into ``moe`` (:meth:`_account_moe`). A dense config's
 steps have two outputs and no such account.
 
+A model with recurrent layers (``Model.state_layout``: a per-sequence state
+beside the rows a token) has a second pool on the device, ``state``: arrays
+``[layers, slots, ...]``, slot 0 the null slot. Its entry points take it
+after the cache, both donated, and the slot index of each sequence last
+(``prefill_chunk(slot=...)``, ``decode(slots=...)``: the engine hands over
+what the block manager assigned); the other models' calls are as they were.
+
 The device cache lives here as functional state: every step donates the
 cache buffer (``donate_argnums``) and returns the new value, and the
 runner swaps its reference. Donation is unconditional — the CPU backend
@@ -128,6 +135,7 @@ class PagedModelRunner:
         decode_buckets: Sequence[int],
         verify_buckets: Sequence[int] = (),
         cache_dtype=None,
+        state_slots: int = 0,
     ):
         import jax
 
@@ -162,6 +170,19 @@ class PagedModelRunner:
         self.cache_layout = self.model.cache_layout(cfg, block_size, cache_dtype)
         t0 = time.perf_counter()
         self.cache = jax.block_until_ready(self.cache_layout.init(num_blocks))
+        #: what a SEQUENCE leaves in the model's recurrent layers, if it has
+        #: any (``None``: every layer attends), and the pool of it on the
+        #: device: ``state_slots`` usable slots behind the null slot 0
+        self.state_layout = self.model.state_layout(cfg) if self.model.state_layout else None
+        self.state_slots = state_slots if self.state_layout is not None else 0
+        self.state = None
+        if self.state_layout is not None:
+            if state_slots < 1:
+                raise ValueError(
+                    f"a {self.model.name} model keeps {self.state_layout.bytes_per_seq} B of state "
+                    "a sequence: the runner needs state_slots >= 1"
+                )
+            self.state = jax.block_until_ready(self.state_layout.init(state_slots + 1))
         #: start-up account: seconds to allocate the cache, and per warmed
         #: program its compile (or load from the compile cache) and first run
         self.cache_alloc_s = time.perf_counter() - t0
@@ -181,7 +202,8 @@ class PagedModelRunner:
         #: running sums over decode and verify launches: the width handed
         #: over (tokens), the longest context of the batch, the contexts of
         #: the real slots, and the positions the program reads (the gather:
-        #: batch bucket x rung, or real slots x rung where a padding slot
+        #: batch bucket x rung, or each real slot at its own rung
+        #: (``Model.gather_widths``) where a padding slot
         #: reads nothing; the kernel: each real slot's live blocks)
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
@@ -208,19 +230,34 @@ class PagedModelRunner:
         self.warmup_programs: Dict[str, float] = {}
 
         # argument 1 of the partials (cfg is bound) is the cache: donated,
-        # updated in place — at a real width a copied cache does not fit
+        # updated in place — at a real width a copied cache does not fit;
+        # argument 2 the state pool, where the model has one
+        donated = (1,) if self.state is None else (1, 2)
         self._prefill_jit = jax.jit(
-            partial(self.model.paged_prefill_step, cfg), donate_argnums=(1,)
+            partial(self.model.paged_prefill_step, cfg), donate_argnums=donated
         )
-        self._decode_jit = jax.jit(
-            partial(self.model.paged_decode_step, cfg), donate_argnums=(1,)
-        )
+        # ONE decode program, the model's step with the argmax of its logits
+        # beside them ([B] int32, the first largest as ``np.argmax``): a batch
+        # whose requests are all greedy reads back the picks and leaves the
+        # logits on the device (42 MB a step at 64 slots of a 163,840
+        # vocabulary), any other reads the logits. Named after the step: the
+        # trace tells programs apart by their launch's name
+        import jax.numpy as jnp
+
+        step, at = self.model.paged_decode_step, len(donated)  # outputs: the pools, then the logits
+
+        def paged_decode_step(*args):
+            out = step(cfg, *args)
+            picks = jnp.argmax(out[at], axis=-1).astype(jnp.int32)
+            return (*out[:at], (out[at], picks), *out[at + 1 :])
+
+        self._decode_jit = jax.jit(paged_decode_step, donate_argnums=donated)
         # speculative verification: prefill-shaped, all-position logits.
         # Always constructed (an uncalled jit holds zero cache entries so
         # compile accounting is unchanged), only warmed when the engine
         # passes verify buckets.
         self._verify_jit = jax.jit(
-            partial(self.model.paged_verify_step, cfg), donate_argnums=(1,)
+            partial(self.model.paged_verify_step, cfg), donate_argnums=donated
         )
         # COW block duplication (prefix cache): cache is arg 0 here.
         # partial() gives THIS runner its own jit identity — a bare
@@ -291,11 +328,19 @@ class PagedModelRunner:
         self.warmup_programs[label] = time.perf_counter() - t0
         return out
 
-    def _step(self, run, program: str, fn, *args):
+    def _step(self, run, program: str, fn, *args, slots=None):
         """One paged step through ``run`` (:meth:`_run` or :meth:`_warm`):
-        keeps the new cache and returns ``(logits, loads)``, both still on
-        the device; ``loads`` is None for a dense model."""
-        self.cache, logits, *loads = run(program, fn, self.params, self.cache, *args)
+        keeps the new cache (and state pool) and returns ``(logits, loads)``,
+        both still on the device (the decode program's ``logits`` is the pair
+        ``(logits, picks)``); ``loads`` is None for a dense model.
+        ``slots``: the state slot(s) of the step's sequence(s), handed to a
+        model that keeps per-sequence state and to no other."""
+        if self.state is None:
+            self.cache, logits, *loads = run(program, fn, self.params, self.cache, *args)
+        else:
+            self.cache, self.state, logits, *loads = run(
+                program, fn, self.params, self.cache, self.state, *args, slots
+            )
         return logits, (loads[0] if loads else None)
 
     def _path(self, window: int) -> AttentionPath:
@@ -329,7 +374,7 @@ class PagedModelRunner:
             row = np.zeros(M, np.int32)
             self._step(
                 partial(self._warm, bucket=c), "paged_prefill_step", self._prefill_jit,
-                tokens, row, np.int32(0), np.int32(0),
+                tokens, row, np.int32(0), np.int32(0), slots=np.int32(0),
             )
         batches = buckets_decode if buckets_decode is not None else self.decode_buckets
         for b in batches:
@@ -341,6 +386,7 @@ class PagedModelRunner:
                     np.zeros(b, np.int32),
                     np.zeros((b, w), np.int32),
                     np.ones(b, np.int32),
+                    slots=np.zeros(b, np.int32),
                 )
         # speculative-verify windows (only when the engine opted in via
         # verify_buckets — plain engines keep their exact compile count).
@@ -434,15 +480,20 @@ class PagedModelRunner:
         ctx_len: int,
         clock: Optional[timeline.PhaseClock] = None,
         launched: Optional[Callable[[], None]] = None,
+        slot: int = 0,
     ) -> np.ndarray:
         """Run one prefill chunk; returns logits [vocab] (fp32 numpy) for
-        the chunk's last valid token. ``clock``: the caller's account for
+        the chunk's last valid token. ``slot``: the request's state slot (a
+        model with per-sequence state; a chunk at ``ctx_len`` 0 starts the
+        slot's state from zeros inside the program). ``clock``: the caller's account for
         the call's phases, if it keeps one (the runner's own otherwise).
         ``launched``: called once when the program is on its way to the
         device, before this thread waits for it (here and in
         :meth:`decode` and :meth:`verify_batch`): the caller's chance to do
         host work that the device's run hides."""
         clock = clock or self.clock
+        if self.state is not None and not 1 <= slot <= self.state_slots:
+            raise ValueError(f"a sequence of this model needs a state slot in [1, {self.state_slots}], got {slot}")
         true_len = len(tokens)
         bucket = _round_up_bucket(true_len, self.prefill_buckets)
         path = self._path(bucket)
@@ -463,21 +514,25 @@ class PagedModelRunner:
             row = np.asarray(block_row, np.int32)
             out = self._step(
                 self._run, "paged_prefill_step", self._prefill_jit,
-                padded, row, np.int32(ctx_len), np.int32(true_len),
+                padded, row, np.int32(ctx_len), np.int32(true_len), slots=np.int32(slot),
             )
         return self._read(out, clock, "prefill", launched)
 
     def _read(
         self, out, clock: timeline.PhaseClock, kind: str,
-        launched: Optional[Callable[[], None]] = None,
+        launched: Optional[Callable[[], None]] = None, picks: bool = False,
     ) -> np.ndarray:
-        """Wait for a step's logits, then copy them to the host: two
+        """Wait for a step's logits, then copy them to the host (``picks``:
+        of a decode step's pair their argmax instead, and the logits stay
+        where they are): two
         phases, so that the device's time is told from the copy's. A MoE
         step's expert loads come over in the same ``readback`` and go into
         the ``kind`` (``decode`` or ``prefill``) half of :attr:`moe`.
         ``launched`` runs first: the launch has returned, the wait has not
         begun."""
         logits, loads = out
+        if isinstance(logits, tuple):
+            logits = logits[1 if picks else 0]
         if launched is not None:
             launched()
         with clock.phase("device_wait"):
@@ -516,27 +571,35 @@ class PagedModelRunner:
         acc["max_load"] += int(loads.max(axis=1).sum())
         acc["mean_load"] += float(loads.mean(axis=1).sum())
 
-    def _table_width(self, ctx_lens: Sequence[int], bucket: int, window: int = 1) -> int:
+    def _table_width(
+        self, ctx_lens: Sequence[int], bucket: int, window: int = 1,
+        reach: Optional[Sequence[int]] = None,
+    ) -> int:
         """The width, in blocks, of the table this decode (``window`` 1) or
         verify launch is handed: the first rung of :attr:`table_widths` that
         covers the longest of ``ctx_lens`` (the real slots' contexts
         INCLUDING what this step writes; padding slots fit any width).
         Counts the choice, and what the program reads at it, in
-        :attr:`decode_width`."""
+        :attr:`decode_width`. ``reach``: where each slot's window ends as the
+        program sees it (a verify window ends at its BUCKET's end), if that
+        is not ``ctx_lens``."""
         bs = self.block_size
         need = int(max(ctx_lens))
         width = _round_up_bucket(-(-need // bs), self.table_widths)
         reads = self._path(window).reads
         if reads == "blocks":
-            read = sum(-(-int(c) // bs) for c in ctx_lens)  # a padding slot reads none
+            read = sum(-(-int(c) // bs) for c in ctx_lens) * bs  # a padding slot reads none
+        elif reads == "slots":  # each real slot at its own rung under the table's
+            ladder = self.model.gather_widths(self.cfg, width * bs, bs)
+            read = sum(_round_up_bucket(min(int(c), ladder[-1]), ladder) for c in reach or ctx_lens)
         else:
-            read = (len(ctx_lens) if reads == "slots" else bucket) * width
+            read = bucket * width * bs
         dw = self.decode_width
         dw["launches"] += 1
         dw["width_tokens"] += width * bs
         dw["needed_tokens"] += need
         dw["live_tokens"] += int(sum(ctx_lens))
-        dw["gathered_tokens"] += read * bs
+        dw["gathered_tokens"] += read
         return width
 
     def verify_batch(
@@ -561,7 +624,8 @@ class PagedModelRunner:
         cbucket = _round_up_bucket(max(len(w) for w in windows), self.verify_buckets)
         bbucket = _round_up_bucket(n, self.decode_buckets)
         M = self._table_width(
-            [c + len(w) for c, w in zip(ctx_lens, windows)], bbucket, cbucket
+            [c + len(w) for c, w in zip(ctx_lens, windows)], bbucket, cbucket,
+            reach=[c + cbucket for c in ctx_lens],
         )
         with clock.phase(
             "launch", program="paged_verify_step",
@@ -591,9 +655,15 @@ class PagedModelRunner:
         ctx_lens: Sequence[int],
         clock: Optional[timeline.PhaseClock] = None,
         launched: Optional[Callable[[], None]] = None,
+        slots: Optional[Sequence[int]] = None,
+        greedy: bool = False,
     ) -> np.ndarray:
         """Advance a decode batch one token; returns logits [n, vocab]
-        for the n REAL slots (padding stripped). ``block_rows`` are
+        for the n REAL slots (padding stripped), or with ``greedy`` their
+        argmax ``[n]`` int32, which the same program takes on the device: the
+        logits stay there. ``slots``: each sequence's
+        state slot (a model with per-sequence state; padding rows take the
+        null slot). ``block_rows`` are
         ``max_blocks_per_seq`` wide; the step is handed them only as wide as
         the rung of :attr:`table_widths` that covers ``max(ctx_lens)``, a
         program :meth:`warmup` compiled. What is cut off lay past every
@@ -601,6 +671,8 @@ class PagedModelRunner:
         full width."""
         clock = clock or self.clock
         n = len(tokens)
+        if self.state is not None and (slots is None or len(slots) != n):
+            raise ValueError("a decode batch of this model needs the state slot of each sequence")
         bucket = _round_up_bucket(n, self.decode_buckets)
         M = self._table_width(ctx_lens, bucket)
         with clock.phase(
@@ -616,7 +688,10 @@ class PagedModelRunner:
             p[:n] = positions
             bt[:n] = np.asarray([row[:M] for row in block_rows], np.int32)
             cl[:n] = ctx_lens
+            sl = np.zeros(bucket, np.int32)
+            if slots is not None:
+                sl[:n] = slots
             out = self._step(
-                self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl
+                self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl, slots=sl
             )
-        return self._read(out, clock, "decode", launched)[:n]
+        return self._read(out, clock, "decode", launched, picks=greedy)[:n]

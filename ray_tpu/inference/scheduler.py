@@ -10,7 +10,10 @@ alongside the standing decode batch instead of draining it.
 Policies, all host-side and unit-testable without jax:
 
 * **admission control** — a request is admitted only when the block pool
-  can cover its full prompt plus one decode block of headroom; otherwise
+  can cover its full prompt plus one decode block of headroom and, on a
+  model with per-sequence state, a state slot is free (it holds the slot
+  across its prefill chunks and decode steps; every way out hands it back
+  with the blocks: ``PagedBlockManager.free``); otherwise
   it waits in the FIFO admission queue (bounded by ``max_queue_depth``).
 * **preemption** — when a decoding request needs one more block and the
   pool is dry, the lowest-priority latest-arrival running request is
@@ -127,6 +130,8 @@ class Request:
     #: is opportunistic, it never preempts and shrinks to zero whenever
     #: the pool can't cover the extra draft positions
     spec_step_k: int = 0
+    #: whether the request has been counted as having waited for a state slot
+    slot_waited: bool = False
 
     @property
     def effective_prompt(self) -> List[int]:
@@ -274,6 +279,12 @@ class ContinuousBatchingScheduler:
         while self.waiting:
             req = self.waiting[0]
             prompt = req.effective_prompt
+            if not self.blocks.has_free_slot():
+                # every state slot is held by a running request: wait, holding nothing
+                if not req.slot_waited:
+                    req.slot_waited = True
+                    self.blocks.slot_admission_waits += 1
+                break
             # prefix cache: attach shared blocks covering the longest
             # cached prefix; prefill then plans only the uncached tail.
             # A readmission re-queries too — its own blocks usually
@@ -289,6 +300,7 @@ class ContinuousBatchingScheduler:
                     self.blocks.free(req.request_id)
                 break  # FIFO: don't starve the head by admitting behind it
             self.waiting.pop(0)
+            self.blocks.assign_slot(req.request_id)
             req.state = PREFILL
             req.prefill_pos = cached
             req.pending_cow = list(cow)

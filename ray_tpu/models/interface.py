@@ -2,7 +2,7 @@
 serving engine (and the train programs) and a model module, and the
 description of the paged cache a model owns.
 
-Two small records and one dict, no plugin system:
+Three small records and one dict, no plugin system:
 
 * :class:`CacheLayout` says what ONE token leaves in the paged cache of
   each layer: named arrays ``[n_layers, num_blocks, block_size, *row]``
@@ -15,6 +15,14 @@ Two small records and one dict, no plugin system:
   :func:`scatter_paged_blocks`: the arrays stacked,
   ``[n_arrays, n_layers, P, *block]``) and the pool's byte
   arithmetic all read this one description.
+* :class:`StateLayout` says what one SEQUENCE leaves, whatever its length,
+  in the layers that recur instead of attending (a gated delta-rule layer's
+  matrix state a head and the last inputs of its short convolutions):
+  named arrays ``[n_layers, num_slots, *shape]``, a slot a running request,
+  slot 0 the null slot as block 0 is the null block. A model whose layers
+  all attend has none (``Model.state_layout`` is None), and for a model
+  that has one ``CacheLayout.n_layers`` counts the layers that WRITE rows,
+  not the model's depth.
 * :class:`Model` is what a model module registers: config -> params, the
   cache description, the three paged entry points, ``forward`` and the
   logical axes, plus the one thing the runner asks about a step's attention
@@ -104,6 +112,40 @@ class CacheLayout:
             "row_width": self.row_width,
             "bytes_per_token": self.bytes_per_token,
         }
+
+
+@dataclass(frozen=True)
+class StateLayout:
+    """What one sequence holds in the recurrent layers of a model, beside
+    its rows in the paged cache: fixed-size arrays a sequence a layer."""
+
+    #: the recurrence's name (``"kda"``: the gated delta rule a channel)
+    kind: str
+    #: the layers that recur (each holds every array below)
+    n_layers: int
+    #: name -> (the shape of one sequence's array in one layer, its dtype)
+    arrays: Tuple[Tuple[str, Tuple[int, ...], Any], ...]
+
+    @property
+    def bytes_per_seq(self) -> int:
+        import numpy as np
+
+        return self.n_layers * sum(
+            math.prod(shape) * np.dtype(dtype).itemsize for _, shape, dtype in self.arrays
+        )
+
+    def init(self, num_slots: int) -> Dict[str, Any]:
+        """The device-side pool: zeros, slot 0 reserved as the null slot."""
+        import jax.numpy as jnp
+
+        return {
+            name: jnp.zeros((self.n_layers, num_slots, *shape), dtype)
+            for name, shape, dtype in self.arrays
+        }
+
+    def describe(self) -> Dict[str, Any]:
+        """What ``engine_stats()["state_layout"]`` says."""
+        return {"kind": self.kind, "layers": self.n_layers, "bytes_per_seq": self.bytes_per_seq}
 
 
 def _rows_of(a, blocks):
@@ -206,13 +248,30 @@ class Model:
     #: (cfg, window, cache) -> positions a key tile of a program whose
     #: ``attention_path`` reads ``"live"``
     key_tile: Callable = lambda cfg, window, cache: 1
+    #: (cfg, table_keys, block_size) -> the widths, in positions and rising,
+    #: at which a program whose ``attention_path`` reads ``"slots"`` gathers a
+    #: slot's context under a table ``table_keys`` wide: each slot at the
+    #: first that holds its context and its window (the table's own alone
+    #: unless the model says otherwise)
+    gather_widths: Callable = lambda cfg, table_keys, block_size: (table_keys,)
+    #: ``None`` for a model whose layers all attend, else (cfg) -> the
+    #: :class:`StateLayout` of its recurrent layers. Such a model's paged
+    #: entry points take the state arrays after the cache (both donated) and
+    #: the slot index (prefill: a scalar; decode: ``[B]``, padding on the
+    #: null slot) as their last argument, and return ``(cache, state, logits
+    #: [, counters])``; a sequence's state reads as zeros where its context
+    #: starts (``ctx_len == 0``), whatever the slot held
+    state_layout: Optional[Callable] = None
 
 
 def model_of(cfg) -> Model:
     """The model a config object belongs to, by the config's type."""
-    from ray_tpu.models import llama, xing4
+    from ray_tpu.models import kimi_linear, llama, xing4
 
-    models = {llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL}
+    models = {
+        llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL,
+        kimi_linear.KimiLinearConfig: kimi_linear.MODEL,
+    }
     try:
         return models[type(cfg)]
     except KeyError:

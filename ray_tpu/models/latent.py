@@ -1,0 +1,354 @@
+"""Latent attention (MLA) over a paged cache of latent rows: what the model
+modules that cache ONE compressed row a token a layer share
+(``models/xing4.py``, ``models/kimi_linear.py``).
+
+Everything here knows only dimensions, read off the model's config object
+``cfg``: ``kv_lora_rank`` (kr), ``qk_nope_head_dim`` (dn), ``qk_rope_head_dim``
+(dr: the part of a key all heads share; rotated or not is the model's
+business, done before the row comes here), ``v_head_dim`` (dv),
+``latent_width`` (kr + dr), ``max_seq_len``, ``dtype`` and ``attn_scale`` (the
+factor on the float32 scores); and of a layer's weights ``p`` only ``w_kvb
+[kr, H, dn + dv]``. The cache row is ``[c | k_shared]``; the two paths
+(:func:`absorbs`), the flash kernel's predicate (:func:`flash_serves`), the
+ONE attention door of a paged body (:func:`latent_attention`) and the block
+write (:func:`write_blocks`) are the same mathematics for every such model.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.interface import CacheLayout
+from ray_tpu.ops import latent_flash
+
+F32 = jnp.float32
+
+
+def absorbs(cfg, window: int) -> bool:
+    """Whether a query window of ``window`` positions a slot attends over
+    the latent rows directly (``W_kvb`` absorbed into the query and the
+    output) or expands K and V of its context first. From the counts: a
+    (query, cached position) pair costs ``2 (kr + dr) + 2 kr`` a head
+    absorbed against ``2 (dn + dr) + 2 dv`` expanded, and the expansion
+    ``2 kr (dn + dv)`` a head a cached position a launch, which ``window``
+    queries share: absorbed while ``window (2 kr - dn - dv) < kr (dn +
+    dv)`` (under 171 queries at the published widths: decode and verify
+    absorb, a prefill chunk of 256 or 1024 expands)."""
+    kr, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+    return window * (2 * kr - dn - dv) < kr * (dn + dv)
+
+
+#: queries that attend at a time where :func:`attend_expanded` materialises
+#: the softmax (training's ``forward``; a prefill chunk wherever the flash
+#: kernel does not serve: the CPU, odd widths): the float32 scores of 1024
+#: queries x 32 heads over a table of 8192 are 1.07 GB at once (the
+#: compile-only rehearsal's largest temporary), 0.27 GB a block. The kernel
+#: (:func:`attend_flash`) keeps a tile of them in VMEM and has no use for it
+_QUERY_BLOCK = 256
+
+
+def probs(cfg, s, mask, dtype):
+    """Causal softmax of float32 scores ``s [B, C, H, S]`` under ``mask [B,
+    C, S]``, scaled by ``cfg.attn_scale``, as ``dtype``."""
+    s = jnp.where(mask[:, :, None, :], s * cfg.attn_scale, -1e30)
+    return jax.nn.softmax(s, axis=-1).astype(dtype)
+
+
+def absorb_query(cfg, p, q_nope, q_rope):
+    """``W_kvb``'s key part absorbed into the query: ``[q_nope W_k | q_rope]
+    [B, C, H, kr + dr]``, to be multiplied against a whole latent row ``[c |
+    k_rope]``: one product over ``kr + dr``, and no slice of gathered rows."""
+    with jax.named_scope("mla.absorb"):
+        w_k = p["w_kvb"][..., : cfg.qk_nope_head_dim]
+        return jnp.concatenate([jnp.einsum("bchk,rhk->bchr", q_nope, w_k), q_rope], axis=-1)
+
+
+def attend_rows(cfg, q_row, rows, mask, own):
+    """Absorbed queries ``q_row [B, C, H, kr + dr]`` over latent rows
+    directly: ``Σ_j p_ij c_j`` ``[B, C, H, kr]``, before ``W_kvb``'s value
+    part. Two sets of keys under one softmax: ``rows [B, S, kr + dr]``, the
+    context BEFORE the window (``mask [B, C, S]`` says which of it), and ``own
+    [B, C, kr + dr]``, the window's own rows, query ``c`` seeing ``c' <= c``:
+    the gathered context is never copied to lay the window over it."""
+    kr = cfg.kv_lora_rank
+    with jax.named_scope("mla.attend"):
+        s = jnp.einsum("bchw,bsw->bchs", q_row, rows, preferred_element_type=F32)
+        S, C = rows.shape[1], own.shape[1]
+        s_own = jnp.einsum("bchw,bdw->bchd", q_row, own, preferred_element_type=F32)
+        within = jnp.broadcast_to(jnp.tril(jnp.ones((C, C), bool)), (mask.shape[0], C, C))
+        pr = probs(
+            cfg, jnp.concatenate([s, s_own], axis=-1), jnp.concatenate([mask, within], axis=-1),
+            rows.dtype,
+        )
+        o_lat = jnp.einsum("bchs,bsw->bchw", pr[..., :S], rows)
+        return (o_lat + jnp.einsum("bchd,bdw->bchw", pr[..., S:], own))[..., :kr]
+
+
+def absorb_output(cfg, p, o_lat):
+    """``W_kvb``'s value part after the attention: ``[B, C, H, kr]`` ->
+    ``[B, C, H, dv]``."""
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("bchr,rhk->bchk", o_lat, p["w_kvb"][..., cfg.qk_nope_head_dim :])
+
+
+def attend_expanded(cfg, p, q_nope, q_rope, rows, mask):
+    """Attention of a window's queries over latent rows ``rows [B, S, kr +
+    dr]`` (``mask [B, C, S]``: which a query sees), K and V expanded from the
+    rows first: the same mathematics as ``W_kvb`` absorbed into the query and
+    the output (:func:`absorb_query`, :func:`attend_rows`,
+    :func:`absorb_output`; :func:`absorbs` says which costs less for a
+    window). Scores and softmax float32. Returns ``[B, C, H, dv]``."""
+    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    w_k, w_v = p["w_kvb"][..., :dn], p["w_kvb"][..., dn:]
+    c, k_rope = rows[..., :kr], rows[..., kr:]
+    with jax.named_scope("mla.expand"):
+        # a head's key is [k_nope | the ONE k_rope]: one product over dn + dr
+        # a (query, key) pair instead of two passes over the float32 scores
+        k_nope = jnp.einsum("bsr,rhk->bshk", c, w_k)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (*k_nope.shape[:3], k_rope.shape[-1]))],
+            axis=-1,
+        )
+        v = jnp.einsum("bsr,rhk->bshk", c, w_v)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+
+    def attend(q, mask):
+        s = jnp.einsum("bchk,bshk->bchs", q, k, preferred_element_type=F32)
+        return jnp.einsum("bchs,bshk->bchk", probs(cfg, s, mask, rows.dtype), v)
+
+    B, C = mask.shape[:2]
+    with jax.named_scope("mla.attend"):
+        if C <= _QUERY_BLOCK or C % _QUERY_BLOCK:
+            return attend(q, mask)
+        # a block of queries at a time, [blocks, B, block, ...] under lax.map
+
+        def split(a):
+            return jnp.moveaxis(a.reshape(B, C // _QUERY_BLOCK, _QUERY_BLOCK, *a.shape[2:]), 1, 0)
+
+        out = jax.lax.map(lambda t: attend(*t), (split(q), split(mask)))
+        return jnp.moveaxis(out, 0, 1).reshape(B, C, *out.shape[3:])
+
+
+def flash_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
+    """Whether a window of ``window`` queries attends through the flash
+    kernel on the expanded path (:func:`attend_flash`) over ``keys`` key
+    positions (a table's width in tokens; the runner's full width where
+    none is given): ``ops/latent_flash.py::kernel_serves`` on what the code
+    can observe (backend, dtype, whole tiles, the head widths). Off a TPU
+    the cache is not looked at."""
+    if (backend or jax.default_backend()) != "tpu":
+        return False
+    return latent_flash.kernel_serves(
+        window, keys or table_keys(cfg, cache), cfg.qk_nope_head_dim, cfg.v_head_dim,
+        cfg.qk_rope_head_dim, cache["latent"].dtype, "tpu",
+    )
+
+
+def table_keys(cfg, cache) -> int:
+    """Positions of the block table a prefill chunk is handed: ``max_seq_len``
+    in whole blocks (``model_runner.py``'s ``max_blocks_per_seq``)."""
+    bs = block_size_of(cfg, cache)
+    return -(-cfg.max_seq_len // bs) * bs
+
+
+def attend_flash(cfg, p, q_nope, q_rope, rows, ctx_len, true_len):
+    """:func:`attend_expanded` for ONE slot through the flash kernel
+    (``ops/latent_flash.py``): queries ``[C, H, .]`` over latent rows ``rows
+    [S, kr + dr]`` with the window's own rows laid over them, query ``c``
+    seeing row ``j`` iff ``j <= ctx_len + c``, the first ``true_len`` queries
+    real. K and V are expanded from ALL ``S`` rows by XLA as there, heads
+    leading (``k_rope`` stays ONE row a position: the kernel adds its product
+    to ``k_nope``'s); the float32 scores never leave VMEM, and of the
+    expanded tiles only those up to ``ctx_len + true_len`` are read.
+    Returns ``[C, H, dv]``; what a query past ``true_len`` gets is finite
+    and nobody's."""
+    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    c, k_rope = rows[:, :kr], rows[:, kr:]
+    with jax.named_scope("mla.expand"):
+        k_nope = jnp.einsum("sr,rhk->hsk", c, p["w_kvb"][..., :dn])
+        v = jnp.einsum("sr,rhk->hsk", c, p["w_kvb"][..., dn:])
+    with jax.named_scope("mla.attend"):
+        out = latent_flash.flash_attention(
+            q_nope.swapaxes(0, 1), k_nope, v, ctx_len, true_len, scale=cfg.attn_scale,
+            q_shared=q_rope.swapaxes(0, 1), k_shared=k_rope,
+        )
+    return out.swapaxes(0, 1)
+
+
+def cache_layout(cfg, block_size: int, dtype=None, n_layers=None) -> CacheLayout:
+    """The flat-block latent cache: ``n_layers`` layers WRITE a row a token
+    (every layer of the model unless told: a hybrid's attention layers alone)."""
+    return CacheLayout(
+        kind="latent", n_layers=cfg.n_layers if n_layers is None else n_layers, block_size=block_size,
+        arrays=(("latent", (cfg.latent_width,)),), dtype=dtype or cfg.dtype, flat_blocks=True,
+    )
+
+
+def block_at(block_tables, pos, bs: int):
+    """Id of the block that holds position ``pos[b, c]`` of slot ``b`` (a
+    position past the table reads its last column)."""
+    M = block_tables.shape[1]
+    return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
+
+
+def blocks_of_window(cfg, cache, window: int) -> int:
+    """Blocks a window of ``window`` CONTIGUOUS positions can touch."""
+    bs = block_size_of(cfg, cache)
+    return (window + bs - 2) // bs + 1
+
+
+#: the narrowest width, in positions, a slot's context is gathered at on the
+#: absorbed path (:func:`slot_widths`)
+_MIN_SLOT_WIDTH = 512
+
+
+def slot_widths(table_keys: int, bs: int = 1) -> Tuple[int, ...]:
+    """The widths, in positions, a decode or verify slot's context is
+    gathered at under a table ``table_keys`` positions wide: doublings of
+    ``_MIN_SLOT_WIDTH`` below the table's width (whole blocks of ``bs``), then
+    the table's own. Each slot takes the first that holds its context and its
+    window (:func:`latent_attention`; the runner counts the same rule:
+    ``Model.gather_widths``): a batch whose contexts differ fourfold pays for
+    each its own, not for every slot the longest's rung."""
+    out, w = [], _MIN_SLOT_WIDTH
+    while w < table_keys:
+        if w % bs == 0:
+            out.append(w)
+        w *= 2
+    return (*out, table_keys)
+
+
+def latent_attention(
+    cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens, flash=None,
+):
+    """Causal attention of a window's queries (``[B, C, H, .]``, rope
+    applied) over the cached context of their slots through ``block_tables
+    [B, M]`` AND the window's own rows ``row [B, C, kr + dr]``: the ONE
+    place a serving step reads the cache for attention. A slot's window is
+    CONTIGUOUS: ``pos[b, c] = pos[b, 0] + c`` (all three entry points).
+    Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
+    c]``; the first ``true_lens[b]`` queries of a slot are real. The
+    gathered context ``cache[layer, block_tables]`` is as wide as the table
+    handed over, or as a rung below it (a kernel over latent rows would replace that). A window
+    attends to itself as after the write (:func:`_paged_layers` says why
+    the write itself comes last), by the path chosen at trace time from the
+    window (:func:`absorbs`): a prefill chunk lays its rows over the
+    positions they will be written to (one ``dynamic_update_slice``) and
+    expands K and V of that context from the latent rows, then attends
+    through the flash kernel where it serves (:func:`flash_serves`: a TPU,
+    whole tiles; the scores stay in VMEM and the expanded tiles past the live
+    context are not read; ``flash``: the caller's answer to that question,
+    asked there so that a module's own predicate is the one asked) and through :func:`attend_expanded`'s
+    materialised softmax elsewhere; a decode or verify window absorbs
+    ``W_kvb``, attends over the gathered rows before it directly and over
+    its own rows beside them, a slot at a time, each slot's context gathered
+    only as wide as the first of :func:`slot_widths` that holds it
+    (attending 8 slots of like context at a time instead was SLOWER on the
+    chip, PR 35).
+
+    Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
+    ``blocks`` are the ``nblk`` (:func:`blocks_of_window`) blocks from the
+    window's first on, old rows and new, as the cache must hold them after
+    the step."""
+    B, C = pos.shape
+    L, N, K = cache["latent"].shape
+    W, bs, nblk = cfg.latent_width, block_size_of(cfg, cache), blocks_of_window(cfg, cache, C)
+    # ``nblk`` null columns behind the table: a window that ends at the
+    # table's end (a padded last chunk) spills into the null block, and no
+    # slice below is clamped
+    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
+    key_pos = jnp.arange(tables.shape[1] * bs, dtype=jnp.int32)
+    first = pos[:, 0]
+
+    def context(table):
+        # ONE gather of whole blocks out of the cache seen as [layers x
+        # blocks, block] (a free reshape where num_blocks is a multiple of 8)
+        return cache["latent"].reshape(L * N, K)[layer * N + table].reshape(-1, W)
+
+    def window_blocks(rows, at):
+        return jax.lax.dynamic_slice(rows, (at // bs * bs, 0), (nblk * bs, W))
+
+    if absorbs(cfg, C):
+        # a short window over many slots, ONE SLOT AT A TIME: a padding slot
+        # (its table is the null block's, :func:`_paged_layers`) reads
+        # nothing, as under ``ops/paged_attention.py``; a real slot's
+        # gathered context (9.4 MB at a table of 8192) stays as it is, the
+        # window's rows are a second set of keys, and only the window's
+        # blocks are rebuilt. ``W_kvb`` is absorbed for all slots at once
+        q_row = absorb_query(cfg, p, q_nope, q_rope)
+        # each slot at the first width that holds its own context and window
+        widths = slot_widths(block_tables.shape[1] * bs, bs)
+
+        def slot(args):
+            table, q, own, at = args
+
+            def read(width: int):
+                def branch():
+                    whole = width == widths[-1]
+                    rows = context(table if whole else table[: width // bs + nblk])
+                    blocks = jax.lax.dynamic_update_slice(window_blocks(rows, at), own, (at % bs, 0))
+                    seen = (key_pos if whole else key_pos[: rows.shape[0]]) < at
+                    mask = jnp.broadcast_to(seen, (1, C, rows.shape[0]))
+                    return attend_rows(cfg, q[None], rows[None], mask, own[None])[0], blocks
+
+                return branch
+
+            def nothing():
+                return (jnp.zeros((*q.shape[:2], cfg.kv_lora_rank), q.dtype), jnp.zeros((nblk * bs, W), own.dtype))
+
+            if len(widths) == 1:
+                return jax.lax.cond(table[0] != 0, read(widths[0]), nothing)
+            rung = jnp.searchsorted(jnp.asarray(widths, jnp.int32), at + C, side="left").astype(jnp.int32)
+            return jax.lax.switch(
+                jnp.where(table[0] != 0, 1 + jnp.minimum(rung, len(widths) - 1), 0),
+                [nothing] + [read(w) for w in widths],
+            )
+
+        o_lat, blocks = jax.lax.map(slot, (tables, q_row, row, first))
+        return absorb_output(cfg, p, o_lat), blocks
+    rows = jax.vmap(context)(tables)
+    rows = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a, 0)))(rows, row, first)
+    blocks = jax.vmap(window_blocks)(rows, first)
+    keys = block_tables.shape[1] * bs
+    if flash_serves(cfg, C, cache, keys) if flash is None else flash:
+        # a slot's real queries end inside the table: the null columns
+        # behind it hold padding rows alone, and no key for anybody
+
+        def slot(args):
+            q_n, q_r, r, at, n = args
+            return attend_flash(cfg, p, q_n, q_r, r[:keys], at, n)
+
+        args = (q_nope, q_rope, rows, first, true_lens)
+        if B == 1:  # a prefill chunk
+            return slot(jax.tree_util.tree_map(lambda a: a[0], args))[None], blocks
+        return jax.lax.map(slot, args), blocks
+    mask = key_pos <= pos[:, :, None]
+    return attend_expanded(cfg, p, q_nope, q_rope, rows, mask), blocks
+
+
+def block_size_of(cfg, cache) -> int:
+    return cache["latent"].shape[2] // cfg.latent_width
+
+
+def write_blocks(cfg, cache, block_tables, first, blocks):
+    """Every layer's updated blocks of a step, ``blocks [n_layers, B, nblk
+    * block_size, kr + dr]`` (:func:`latent_attention`), into the cache:
+    ONE scatter of whole rows of the cache seen as ``[layers x blocks,
+    block]``, in place in the donated argument (a scatter of one ``kr +
+    dr``-wide window a token was 40,960 sequential updates a prefill chunk:
+    160 ms on the chip; one whose window spans the layers copied the cache
+    whole). A block the window touches is rewritten with its old rows and
+    the new; what lies past a slot's blocks, and a padding slot, is the null
+    block: colliding trash writes are fine, nothing masked-in reads them."""
+    L, N, K = cache["latent"].shape
+    B = first.shape[0]
+    bs = block_size_of(cfg, cache)
+    nblk = blocks.shape[2] // bs
+    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
+    ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at,), (nblk,)))(tables, first // bs)
+    rows = (jnp.arange(L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
+    flat = cache["latent"].reshape(L * N, K).at[rows].set(blocks.reshape(L * B * nblk, K))
+    return {"latent": flat.reshape(L, N, K)}

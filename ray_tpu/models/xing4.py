@@ -58,6 +58,25 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.interface import AttentionPath, CacheLayout, Model
+# the latent paths, the cache's layout and the block write know only
+# dimensions: ``models/latent.py`` has them, for this module and ``models/
+# kimi_linear.py``; under their old names here for the callers there are
+from ray_tpu.models.latent import (  # noqa: F401
+    absorb_output as _absorb_output,
+    absorb_query as _absorb_query,
+    absorbs,
+    attend_expanded as _attend_expanded,
+    attend_flash as _attend_flash,
+    attend_rows as _attend_rows,
+    block_at as _block_at,
+    block_size_of as _block_size,
+    cache_layout,
+    flash_serves as _flash_serves,
+    latent_attention as _latent_attention,
+    slot_widths as _slot_widths,
+    table_keys as _table_keys,
+    write_blocks as _write_blocks,
+)
 from ray_tpu.ops import latent_flash
 from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
 from ray_tpu.parallel.sharding import constrain
@@ -120,6 +139,11 @@ class Xing4Config:
     def latent_width(self) -> int:
         """One token's cache row in one layer: the normed latent and the rotated rope part."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """The factor on the float32 scores (``models/latent.py`` reads it)."""
+        return softmax_scale(self)
 
     @staticmethod
     def tiny(**overrides) -> "Xing4Config":
@@ -391,157 +415,6 @@ def _latent_qkv(cfg: Xing4Config, p, h, pos):
     return q_nope, q_rope, row
 
 
-def absorbs(cfg: Xing4Config, window: int) -> bool:
-    """Whether a query window of ``window`` positions a slot attends over
-    the latent rows directly (``W_kvb`` absorbed into the query and the
-    output) or expands K and V of its context first. From the counts: a
-    (query, cached position) pair costs ``2 (kr + dr) + 2 kr`` a head
-    absorbed against ``2 (dn + dr) + 2 dv`` expanded, and the expansion
-    ``2 kr (dn + dv)`` a head a cached position a launch, which ``window``
-    queries share: absorbed while ``window (2 kr - dn - dv) < kr (dn +
-    dv)`` (under 171 queries at the published widths: decode and verify
-    absorb, a prefill chunk of 256 or 1024 expands)."""
-    kr, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
-    return window * (2 * kr - dn - dv) < kr * (dn + dv)
-
-
-#: queries that attend at a time where :func:`_attend_expanded` materialises
-#: the softmax (training's ``forward``; a prefill chunk wherever the flash
-#: kernel does not serve: the CPU, odd widths): the float32 scores of 1024
-#: queries x 32 heads over a table of 8192 are 1.07 GB at once (the
-#: compile-only rehearsal's largest temporary), 0.27 GB a block. The kernel
-#: (:func:`_attend_flash`) keeps a tile of them in VMEM and has no use for it
-_QUERY_BLOCK = 256
-
-
-def _probs(cfg: Xing4Config, s, mask, dtype):
-    """Causal softmax of float32 scores ``s [B, C, H, S]`` under ``mask [B,
-    C, S]``, scaled by :func:`softmax_scale`, as ``dtype``."""
-    s = jnp.where(mask[:, :, None, :], s * softmax_scale(cfg), -1e30)
-    return jax.nn.softmax(s, axis=-1).astype(dtype)
-
-
-def _absorb_query(cfg: Xing4Config, p, q_nope, q_rope):
-    """``W_kvb``'s key part absorbed into the query: ``[q_nope W_k | q_rope]
-    [B, C, H, kr + dr]``, to be multiplied against a whole latent row ``[c |
-    k_rope]``: one product over ``kr + dr``, and no slice of gathered rows."""
-    with jax.named_scope("mla.absorb"):
-        w_k = p["w_kvb"][..., : cfg.qk_nope_head_dim]
-        return jnp.concatenate([jnp.einsum("bchk,rhk->bchr", q_nope, w_k), q_rope], axis=-1)
-
-
-def _attend_rows(cfg: Xing4Config, q_row, rows, mask, own):
-    """Absorbed queries ``q_row [B, C, H, kr + dr]`` over latent rows
-    directly: ``Σ_j p_ij c_j`` ``[B, C, H, kr]``, before ``W_kvb``'s value
-    part. Two sets of keys under one softmax: ``rows [B, S, kr + dr]``, the
-    context BEFORE the window (``mask [B, C, S]`` says which of it), and ``own
-    [B, C, kr + dr]``, the window's own rows, query ``c`` seeing ``c' <= c``:
-    the gathered context is never copied to lay the window over it."""
-    kr = cfg.kv_lora_rank
-    with jax.named_scope("mla.attend"):
-        s = jnp.einsum("bchw,bsw->bchs", q_row, rows, preferred_element_type=F32)
-        S, C = rows.shape[1], own.shape[1]
-        s_own = jnp.einsum("bchw,bdw->bchd", q_row, own, preferred_element_type=F32)
-        within = jnp.broadcast_to(jnp.tril(jnp.ones((C, C), bool)), (mask.shape[0], C, C))
-        pr = _probs(
-            cfg, jnp.concatenate([s, s_own], axis=-1), jnp.concatenate([mask, within], axis=-1),
-            rows.dtype,
-        )
-        o_lat = jnp.einsum("bchs,bsw->bchw", pr[..., :S], rows)
-        return (o_lat + jnp.einsum("bchd,bdw->bchw", pr[..., S:], own))[..., :kr]
-
-
-def _absorb_output(cfg: Xing4Config, p, o_lat):
-    """``W_kvb``'s value part after the attention: ``[B, C, H, kr]`` ->
-    ``[B, C, H, dv]``."""
-    with jax.named_scope("mla.absorb"):
-        return jnp.einsum("bchr,rhk->bchk", o_lat, p["w_kvb"][..., cfg.qk_nope_head_dim :])
-
-
-def _attend_expanded(cfg: Xing4Config, p, q_nope, q_rope, rows, mask):
-    """Attention of a window's queries over latent rows ``rows [B, S, kr +
-    dr]`` (``mask [B, C, S]``: which a query sees), K and V expanded from the
-    rows first: the same mathematics as ``W_kvb`` absorbed into the query and
-    the output (:func:`_absorb_query`, :func:`_attend_rows`,
-    :func:`_absorb_output`; :func:`absorbs` says which costs less for a
-    window). Scores and softmax float32. Returns ``[B, C, H, dv]``."""
-    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    w_k, w_v = p["w_kvb"][..., :dn], p["w_kvb"][..., dn:]
-    c, k_rope = rows[..., :kr], rows[..., kr:]
-    with jax.named_scope("mla.expand"):
-        # a head's key is [k_nope | the ONE k_rope]: one product over dn + dr
-        # a (query, key) pair instead of two passes over the float32 scores
-        k_nope = jnp.einsum("bsr,rhk->bshk", c, w_k)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (*k_nope.shape[:3], k_rope.shape[-1]))],
-            axis=-1,
-        )
-        v = jnp.einsum("bsr,rhk->bshk", c, w_v)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-
-    def attend(q, mask):
-        s = jnp.einsum("bchk,bshk->bchs", q, k, preferred_element_type=F32)
-        return jnp.einsum("bchs,bshk->bchk", _probs(cfg, s, mask, rows.dtype), v)
-
-    B, C = mask.shape[:2]
-    with jax.named_scope("mla.attend"):
-        if C <= _QUERY_BLOCK or C % _QUERY_BLOCK:
-            return attend(q, mask)
-        # a block of queries at a time, [blocks, B, block, ...] under lax.map
-
-        def split(a):
-            return jnp.moveaxis(a.reshape(B, C // _QUERY_BLOCK, _QUERY_BLOCK, *a.shape[2:]), 1, 0)
-
-        out = jax.lax.map(lambda t: attend(*t), (split(q), split(mask)))
-        return jnp.moveaxis(out, 0, 1).reshape(B, C, *out.shape[3:])
-
-
-def _flash_serves(cfg: Xing4Config, window: int, cache, keys=None, backend=None) -> bool:
-    """Whether a window of ``window`` queries attends through the flash
-    kernel on the expanded path (:func:`_attend_flash`) over ``keys`` key
-    positions (a table's width in tokens; the runner's full width where
-    none is given): ``ops/latent_flash.py::kernel_serves`` on what the code
-    can observe (backend, dtype, whole tiles, the head widths). Off a TPU
-    the cache is not looked at."""
-    if (backend or jax.default_backend()) != "tpu":
-        return False
-    return latent_flash.kernel_serves(
-        window, keys or _table_keys(cfg, cache), cfg.qk_nope_head_dim, cfg.v_head_dim,
-        cfg.qk_rope_head_dim, cache["latent"].dtype, "tpu",
-    )
-
-
-def _table_keys(cfg: Xing4Config, cache) -> int:
-    """Positions of the block table a prefill chunk is handed: ``max_seq_len``
-    in whole blocks (``model_runner.py``'s ``max_blocks_per_seq``)."""
-    bs = _block_size(cfg, cache)
-    return -(-cfg.max_seq_len // bs) * bs
-
-
-def _attend_flash(cfg: Xing4Config, p, q_nope, q_rope, rows, ctx_len, true_len):
-    """:func:`_attend_expanded` for ONE slot through the flash kernel
-    (``ops/latent_flash.py``): queries ``[C, H, .]`` over latent rows ``rows
-    [S, kr + dr]`` with the window's own rows laid over them, query ``c``
-    seeing row ``j`` iff ``j <= ctx_len + c``, the first ``true_len`` queries
-    real. K and V are expanded from ALL ``S`` rows by XLA as there, heads
-    leading (``k_rope`` stays ONE row a position: the kernel adds its product
-    to ``k_nope``'s); the float32 scores never leave VMEM, and of the
-    expanded tiles only those up to ``ctx_len + true_len`` are read.
-    Returns ``[C, H, dv]``; what a query past ``true_len`` gets is finite
-    and nobody's."""
-    dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    c, k_rope = rows[:, :kr], rows[:, kr:]
-    with jax.named_scope("mla.expand"):
-        k_nope = jnp.einsum("sr,rhk->hsk", c, p["w_kvb"][..., :dn])
-        v = jnp.einsum("sr,rhk->hsk", c, p["w_kvb"][..., dn:])
-    with jax.named_scope("mla.attend"):
-        out = latent_flash.flash_attention(
-            q_nope.swapaxes(0, 1), k_nope, v, ctx_len, true_len, scale=softmax_scale(cfg),
-            q_shared=q_rope.swapaxes(0, 1), k_shared=k_rope,
-        )
-    return out.swapaxes(0, 1)
-
-
 def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
     """The FFN of one layer on normed activations ``h [B, C, D]``:
     ``(ffn(h), aux)``. A dense layer: the gated SiLU MLP, ``aux`` empty. An
@@ -712,142 +585,6 @@ def next_token_loss(cfg: Xing4Config, params, tokens, targets, *, remat=False,
 # ``key_pos <= pos``, so stale rows past a slot's context are never read.
 
 
-def cache_layout(cfg: Xing4Config, block_size: int, dtype=None) -> CacheLayout:
-    return CacheLayout(
-        kind="latent", n_layers=cfg.n_layers, block_size=block_size,
-        arrays=(("latent", (cfg.latent_width,)),), dtype=dtype or cfg.dtype, flat_blocks=True,
-    )
-
-
-def _block_at(block_tables, pos, bs: int):
-    """Id of the block that holds position ``pos[b, c]`` of slot ``b`` (a
-    position past the table reads its last column)."""
-    M = block_tables.shape[1]
-    return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
-
-
-def _window_blocks(cfg: Xing4Config, cache, window: int) -> int:
-    """Blocks a window of ``window`` CONTIGUOUS positions can touch."""
-    bs = _block_size(cfg, cache)
-    return (window + bs - 2) // bs + 1
-
-
-def _latent_attention(
-    cfg: Xing4Config, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens
-):
-    """Causal attention of a window's queries (``[B, C, H, .]``, rope
-    applied) over the cached context of their slots through ``block_tables
-    [B, M]`` AND the window's own rows ``row [B, C, kr + dr]``: the ONE
-    place a serving step reads the cache for attention. A slot's window is
-    CONTIGUOUS: ``pos[b, c] = pos[b, 0] + c`` (all three entry points).
-    Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
-    c]``; the first ``true_lens[b]`` queries of a slot are real. The
-    gathered context ``cache[layer, block_tables]`` is as wide as the table
-    handed over (a kernel over latent rows would replace that). A window
-    attends to itself as after the write (:func:`_paged_layers` says why
-    the write itself comes last), by the path chosen at trace time from the
-    window (:func:`absorbs`): a prefill chunk lays its rows over the
-    positions they will be written to (one ``dynamic_update_slice``) and
-    expands K and V of that context from the latent rows, then attends
-    through the flash kernel where it serves (:func:`_flash_serves`: a TPU,
-    whole tiles; the scores stay in VMEM and the expanded tiles past the live
-    context are not read) and through :func:`_attend_expanded`'s
-    materialised softmax elsewhere; a decode or verify window absorbs
-    ``W_kvb``, attends over the gathered rows before it directly and over
-    its own rows beside them.
-
-    Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
-    ``blocks`` are the ``nblk`` (:func:`_window_blocks`) blocks from the
-    window's first on, old rows and new, as the cache must hold them after
-    the step."""
-    B, C = pos.shape
-    L, N, K = cache["latent"].shape
-    W, bs, nblk = cfg.latent_width, _block_size(cfg, cache), _window_blocks(cfg, cache, C)
-    # ``nblk`` null columns behind the table: a window that ends at the
-    # table's end (a padded last chunk) spills into the null block, and no
-    # slice below is clamped
-    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
-    key_pos = jnp.arange(tables.shape[1] * bs, dtype=jnp.int32)
-    first = pos[:, 0]
-
-    def context(table):
-        # ONE gather of whole blocks out of the cache seen as [layers x
-        # blocks, block] (a free reshape where num_blocks is a multiple of 8)
-        return cache["latent"].reshape(L * N, K)[layer * N + table].reshape(-1, W)
-
-    def window_blocks(rows, at):
-        return jax.lax.dynamic_slice(rows, (at // bs * bs, 0), (nblk * bs, W))
-
-    if absorbs(cfg, C):
-        # a short window over many slots, ONE SLOT AT A TIME: a padding slot
-        # (its table is the null block's, :func:`_paged_layers`) reads
-        # nothing, as under ``ops/paged_attention.py``; a real slot's
-        # gathered context (9.4 MB at a table of 8192) stays as it is, the
-        # window's rows are a second set of keys, and only the window's
-        # blocks are rebuilt. ``W_kvb`` is absorbed for all slots at once
-        q_row = _absorb_query(cfg, p, q_nope, q_rope)
-
-        def slot(args):
-            table, q, own, at = args
-
-            def read():
-                rows = context(table)
-                blocks = jax.lax.dynamic_update_slice(window_blocks(rows, at), own, (at % bs, 0))
-                mask = jnp.broadcast_to(key_pos < at, (1, C, key_pos.shape[0]))
-                return _attend_rows(cfg, q[None], rows[None], mask, own[None])[0], blocks
-
-            return jax.lax.cond(
-                table[0] != 0, read,
-                lambda: (jnp.zeros((*q.shape[:2], cfg.kv_lora_rank), q.dtype), jnp.zeros((nblk * bs, W), own.dtype)),
-            )
-
-        o_lat, blocks = jax.lax.map(slot, (tables, q_row, row, first))
-        return _absorb_output(cfg, p, o_lat), blocks
-    rows = jax.vmap(context)(tables)
-    rows = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a, 0)))(rows, row, first)
-    blocks = jax.vmap(window_blocks)(rows, first)
-    keys = block_tables.shape[1] * bs
-    if _flash_serves(cfg, C, cache, keys):
-        # a slot's real queries end inside the table: the null columns
-        # behind it hold padding rows alone, and no key for anybody
-
-        def slot(args):
-            q_n, q_r, r, at, n = args
-            return _attend_flash(cfg, p, q_n, q_r, r[:keys], at, n)
-
-        args = (q_nope, q_rope, rows, first, true_lens)
-        if B == 1:  # a prefill chunk
-            return slot(jax.tree_util.tree_map(lambda a: a[0], args))[None], blocks
-        return jax.lax.map(slot, args), blocks
-    mask = key_pos <= pos[:, :, None]
-    return _attend_expanded(cfg, p, q_nope, q_rope, rows, mask), blocks
-
-
-def _block_size(cfg: Xing4Config, cache) -> int:
-    return cache["latent"].shape[2] // cfg.latent_width
-
-
-def _write_blocks(cfg: Xing4Config, cache, block_tables, first, blocks):
-    """Every layer's updated blocks of a step, ``blocks [n_layers, B, nblk
-    * block_size, kr + dr]`` (:func:`_latent_attention`), into the cache:
-    ONE scatter of whole rows of the cache seen as ``[layers x blocks,
-    block]``, in place in the donated argument (a scatter of one ``kr +
-    dr``-wide window a token was 40,960 sequential updates a prefill chunk:
-    160 ms on the chip; one whose window spans the layers copied the cache
-    whole). A block the window touches is rewritten with its old rows and
-    the new; what lies past a slot's blocks, and a padding slot, is the null
-    block: colliding trash writes are fine, nothing masked-in reads them."""
-    L, N, K = cache["latent"].shape
-    B = first.shape[0]
-    bs = _block_size(cfg, cache)
-    nblk = blocks.shape[2] // bs
-    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
-    ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at,), (nblk,)))(tables, first // bs)
-    rows = (jnp.arange(L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
-    flat = cache["latent"].reshape(L * N, K).at[rows].set(blocks.reshape(L * B * nblk, K))
-    return {"latent": flat.reshape(L, N, K)}
-
-
 def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tables):
     """Every layer of the model over the latent paged cache: the body of
     the three serving steps. ``tokens [B, C]``, ``pos [B, C]`` their global
@@ -869,11 +606,15 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     # its table holds
     block_tables = jnp.where(valid.any(axis=1, keepdims=True), block_tables, 0)
     true_lens = valid.sum(axis=1, dtype=jnp.int32)  # the valid rows lead (all three entry points)
+    # this module's own predicate (a test patches it), asked where it is not needed too
+    window, bs = pos.shape[1], _block_size(cfg, cache)
+    flash = not absorbs(cfg, window) and _flash_serves(cfg, window, cache, block_tables.shape[1] * bs)
 
     def attention(p, h, layer):
         q_nope, q_rope, row = _latent_qkv(cfg, p, h, pos)
         o, blocks = _latent_attention(
-            cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens
+            cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens,
+            flash=flash,
         )
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), blocks
 
@@ -1048,4 +789,5 @@ MODEL = Model(
     attention_path=_attention_path,
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
     key_tile=lambda cfg, window, cache: latent_flash.tiles(window, _table_keys(cfg, cache))[1],
+    gather_widths=lambda cfg, table_keys, bs: _slot_widths(table_keys, bs),
 )

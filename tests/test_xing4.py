@@ -25,7 +25,7 @@ import rehearsal  # noqa: E402
 import xing4_controls as controls  # noqa: E402
 from perfbench import families  # noqa: E402
 from perfbench.families.xing4 import reference  # noqa: E402
-from ray_tpu.models import xing4  # noqa: E402
+from ray_tpu.models import latent, xing4  # noqa: E402
 from ray_tpu.models.interface import model_of  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops import moe as moe_ops  # noqa: E402
@@ -72,12 +72,18 @@ def _prefill(cfg, params, cache, row_tokens, table, chunks, bucket=40):
     return cache, np.asarray(logits)
 
 
+@pytest.mark.parametrize("min_width", [512, 16], ids=["the_table_s_width", "rungs_16_32_64"])
 @pytest.mark.parametrize("chunks", [(37,), (13, 24), (16, 16, 5), (7, 9, 11, 10), (32, 5)],
                          ids=lambda c: "+".join(map(str, c)))
-def test_chunked_prefill_decode_and_verify_match_the_reference(model, cfg, params, tokens, chunks):
+def test_chunked_prefill_decode_and_verify_match_the_reference(model, cfg, params, tokens, chunks, min_width,
+                                                                monkeypatch):
     """Chunks whose boundaries split a block of 8, then a decode step, then
     a verify window of 3, all through the latent cache, against the
-    reference's full forward pass: logits, not tokens."""
+    reference's full forward pass: logits, not tokens. The absorbed path
+    gathers the slot's context as wide as the table (the toy's 64 positions
+    lie under the narrowest rung) and, with the ladder cut to the toy's size,
+    at the first of 16, 32, 64 that holds context and window."""
+    monkeypatch.setattr(latent, "_MIN_SLOT_WIDTH", min_width)
     n = sum(chunks)
     table = np.zeros(8, np.int32)
     table[:8] = np.arange(1, 9)
@@ -152,7 +158,7 @@ def test_the_expanded_path_attends_a_block_of_queries_at_a_time(cfg, params, mon
     rows = jnp.asarray(rng.standard_normal((B, S, cfg.latent_width)), jnp.float32)
     mask = jnp.arange(S) <= (10 + jnp.arange(C))[None, :, None] + jnp.zeros((B, 1, 1), jnp.int32)
     whole = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
-    monkeypatch.setattr(xing4, "_QUERY_BLOCK", block)
+    monkeypatch.setattr(latent, "_QUERY_BLOCK", block)  # where the lifted function reads it
     blocks = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
     assert _rel(blocks, whole) < 1e-6
 
