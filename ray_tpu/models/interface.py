@@ -8,7 +8,7 @@ Three small records and one dict, no plugin system:
   each layer: named arrays ``[n_layers, num_blocks, block_size, *row]``
   (``k`` and ``v`` rows ``[n_kv, hd]`` for a KV cache; one ``latent`` row
   of ``kv_lora_rank + rope`` numbers for a latent cache, a block's rows
-  stored as one: ``flat_blocks``). Block ids stay
+  laid flat in whole tiles: ``flat_blocks``). Block ids stay
   layer-agnostic: a block id names ``block_size`` positions of a sequence in
   EVERY layer and every array. The device tensors, the COW copy, the export
   / import / tier payload (:func:`gather_paged_blocks` /
@@ -47,12 +47,18 @@ class CacheLayout:
     #: name -> the shape of one token's row in one layer, in payload order
     arrays: Tuple[Tuple[str, Tuple[int, ...]], ...]
     dtype: Any
-    #: whether a block of a layer is stored as ONE row of ``block_size x
-    #: row`` numbers (``[n_layers, num_blocks, block_size * row]``) instead of
+    #: whether a block's rows are stored FLAT, ``T`` tokens a stored row
+    #: (``[n_layers, num_blocks, block_size / T, T * row]``), instead of
     #: ``[n_layers, num_blocks, block_size, *row]``: for a row that is not
     #: whole lanes of 128 (a latent row of 576), the tiled device layout of
     #: the latter pads every row and XLA re-lays the whole cache out to gather
-    #: from it; a block of 16 x 576 = 9216 numbers is 72 whole lanes
+    #: from it. ``T`` is the fewest tokens that fill whole lanes (two rows of
+    #: 576 are 1152 = 9 x 128): a block of 16 is then ``[8, 1152]``, nine
+    #: whole ``(8, 128)`` tiles, contiguous on the device and ONE DMA of a
+    #: kernel (``ops/latent_paged.py``; as one row ``[9216]`` of ``[blocks,
+    #: 9216]``, the form until PR 36, a block was 72 pieces of 256 B and no
+    #: slice Mosaic would copy). Where ``block_size / T`` is no multiple of 8
+    #: (the tests' toy widths) the block stays ONE row of ``block_size x row``
     flat_blocks: bool = False
 
     @property
@@ -89,7 +95,11 @@ class CacheLayout:
     def block_shape(self, row: Tuple[int, ...]) -> Tuple[int, ...]:
         """The shape of one block of one layer in a device array of that row."""
         if self.flat_blocks:
-            return (self.block_size * math.prod(row),)
+            width = math.prod(row)
+            t = math.lcm(width, 128) // width
+            if self.block_size % (8 * t) == 0:
+                return (self.block_size // t, t * width)
+            return (self.block_size * width,)
         return (self.block_size, *row)
 
     def init(self, num_blocks: int) -> Dict[str, Any]:

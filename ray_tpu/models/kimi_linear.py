@@ -673,6 +673,23 @@ def _paged_layers(cfg: KimiLinearConfig, params, cache, state, tokens, pos, vali
             S = jnp.where(fresh_of[:, None, None, None], 0.0, state["kda_state"][i_kda]).astype(F32)
             tail = jnp.where(fresh_of[:, None], 0, state["kda_conv"][i_kda])
             mix, S, tail = _kda_mix(cfg, p, h[row_of], S, tail.reshape(n_slots, keep, -1), held[:, None])
+            if i_kda == 0 and jax.default_backend() == "tpu":
+                # The FIRST in-place write into the donated pool must be a
+                # plain copy. Fused with its own read (``S`` of this layer is
+                # a function of the pool's slab 0), it is an instruction whose
+                # only large operand is an entry parameter, and XLA:TPU's
+                # rematerialisation clones such an instruction a user (the
+                # next layer reads the pool three times) WITHOUT knowing that
+                # it runs in place on the donated buffer: each clone then
+                # decays and updates slab 0 again. How many clones survive is
+                # the scheduler's (one in PR 35's programs, by luck; three
+                # beside ``latent_rows``: the check's state reading 1.02 and
+                # the logits from the second decode step on 0.4-1.0, PR 36).
+                # Behind the barrier the write is ``pool[0] = S``: a clone of
+                # that is the same bytes again. One slab (136 MB) more of
+                # temporaries and ~1 ms a step; the later layers' writes take
+                # the previous write's result, which no clone can recompute
+                S, tail = jax.lax.optimization_barrier((S, tail))
             state = {
                 "kda_state": state["kda_state"].at[i_kda].set(S.astype(state["kda_state"].dtype)),
                 "kda_conv": state["kda_conv"].at[i_kda].set(tail.reshape(n_slots, -1)),
@@ -759,6 +776,8 @@ def _attention_path(cfg: KimiLinearConfig, window: int, cache, backend=None) -> 
     the attending layers' (as ``models/xing4.py``); what a launch reads of
     the paged cache is the latter's."""
     kda = "kda.update" if window == 1 else "kda.chunk"
+    if latent.paged_serves(cfg, window, cache, backend=backend):
+        return AttentionPath(f"{kda}+latent.paged", "blocks")
     if latent.absorbs(cfg, window):
         return AttentionPath(f"{kda}+latent.absorbed", "slots")
     if latent.flash_serves(cfg, window, cache, backend=backend):

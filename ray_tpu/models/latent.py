@@ -9,20 +9,22 @@ business, done before the row comes here), ``v_head_dim`` (dv),
 ``latent_width`` (kr + dr), ``max_seq_len``, ``dtype`` and ``attn_scale`` (the
 factor on the float32 scores); and of a layer's weights ``p`` only ``w_kvb
 [kr, H, dn + dv]``. The cache row is ``[c | k_shared]``; the two paths
-(:func:`absorbs`), the flash kernel's predicate (:func:`flash_serves`), the
-ONE attention door of a paged body (:func:`latent_attention`) and the block
-write (:func:`write_blocks`) are the same mathematics for every such model.
+(:func:`absorbs`), the two kernels' predicates (:func:`flash_serves` for a
+prefill chunk, :func:`paged_serves` for decode and verify), the ONE attention
+door of a paged body (:func:`latent_attention`) and the block write
+(:func:`write_blocks`) are the same mathematics for every such model.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.interface import CacheLayout
-from ray_tpu.ops import latent_flash
+from ray_tpu.ops import latent_flash, latent_paged
 
 F32 = jnp.float32
 
@@ -85,6 +87,46 @@ def attend_rows(cfg, q_row, rows, mask, own):
         )
         o_lat = jnp.einsum("bchs,bsw->bchw", pr[..., :S], rows)
         return (o_lat + jnp.einsum("bchd,bdw->bchw", pr[..., S:], own))[..., :kr]
+
+
+def paged_serves(cfg, window: int, cache, backend=None) -> bool:
+    """Whether a decode or verify window of ``window`` queries a slot attends
+    through the kernel over latent rows (:func:`attend_paged`):
+    ``ops/latent_paged.py::kernel_serves`` on what the code can observe
+    (backend, dtype, window x heads, the widths, the cache's stored form).
+    Off a TPU the cache is not looked at."""
+    if (backend or jax.default_backend()) != "tpu" or not absorbs(cfg, window):
+        return False
+    return latent_paged.kernel_serves(
+        window, cfg.n_heads, cfg.latent_width, cfg.kv_lora_rank, cache["latent"], "tpu"
+    )
+
+
+def attend_paged(cfg, q_row, cache, layer, block_tables, first, own, interpret=None):
+    """:func:`attend_rows` with the context read by the kernel
+    (``ops/latent_paged.py``) from each slot's own live blocks: the cached
+    positions ``j < first[b]`` come back as an online softmax's state ``(acc,
+    m, l)``, and the window's own rows ``own [B, C, kr + dr]`` (query ``c``
+    seeing ``c' <= c``) are folded in here under the SAME softmax, ``B x C x
+    H`` numbers. A padding slot (its table starts on the null block) returns
+    zeros, as :func:`latent_attention`'s gather does."""
+    kr = cfg.kv_lora_rank
+    with jax.named_scope("mla.attend"):
+        acc, m, l = latent_paged.attend_paged(
+            q_row, cache["latent"], layer, block_tables, first,
+            kv_lora_rank=kr, scale=cfg.attn_scale, interpret=interpret,
+        )
+        C = own.shape[1]
+        s_own = jnp.einsum("bchw,bdw->bchd", q_row, own, preferred_element_type=F32)
+        s_own = jnp.where(jnp.tril(jnp.ones((C, C), bool))[None, :, None, :], s_own * cfg.attn_scale, -1e30)
+        m_new = jnp.maximum(m, s_own.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p_own = jnp.exp(s_own - m_new[..., None])
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bchd,bdw->bchw", p_own.astype(own.dtype), own[..., :kr], preferred_element_type=F32
+        )
+        o_lat = acc / (alpha * l + p_own.sum(axis=-1))[..., None]
+        return jnp.where((block_tables[:, 0] != 0)[:, None, None, None], o_lat, 0).astype(q_row.dtype)
 
 
 def absorb_output(cfg, p, o_lat):
@@ -232,7 +274,7 @@ def latent_attention(
     Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
     c]``; the first ``true_lens[b]`` queries of a slot are real. The
     gathered context ``cache[layer, block_tables]`` is as wide as the table
-    handed over, or as a rung below it (a kernel over latent rows would replace that). A window
+    handed over, or as a rung below it (where nothing is gathered: below). A window
     attends to itself as after the write (:func:`_paged_layers` says why
     the write itself comes last), by the path chosen at trace time from the
     window (:func:`absorbs`): a prefill chunk lays its rows over the
@@ -243,18 +285,22 @@ def latent_attention(
     context are not read; ``flash``: the caller's answer to that question,
     asked there so that a module's own predicate is the one asked) and through :func:`attend_expanded`'s
     materialised softmax elsewhere; a decode or verify window absorbs
-    ``W_kvb``, attends over the gathered rows before it directly and over
-    its own rows beside them, a slot at a time, each slot's context gathered
-    only as wide as the first of :func:`slot_widths` that holds it
-    (attending 8 slots of like context at a time instead was SLOWER on the
-    chip, PR 35).
+    ``W_kvb`` and attends over the rows before it directly and over its own
+    rows beside them under one softmax: through the kernel over latent rows
+    where it serves (:func:`paged_serves`: a TPU, a short window, the cache
+    stored in whole tiles; each slot's own live blocks are read from the
+    cache as it lies, :func:`attend_paged`, and XLA gathers only the window's
+    ``nblk`` blocks, for the write) and elsewhere a slot at a time, each
+    slot's context gathered only as wide as the first of :func:`slot_widths`
+    that holds it (attending 8 slots of like context at a time instead was
+    SLOWER on the chip, PR 35).
 
     Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
     ``blocks`` are the ``nblk`` (:func:`blocks_of_window`) blocks from the
     window's first on, old rows and new, as the cache must hold them after
     the step."""
     B, C = pos.shape
-    L, N, K = cache["latent"].shape
+    L, N, *block = cache["latent"].shape
     W, bs, nblk = cfg.latent_width, block_size_of(cfg, cache), blocks_of_window(cfg, cache, C)
     # ``nblk`` null columns behind the table: a window that ends at the
     # table's end (a padded last chunk) spills into the null block, and no
@@ -265,12 +311,23 @@ def latent_attention(
 
     def context(table):
         # ONE gather of whole blocks out of the cache seen as [layers x
-        # blocks, block] (a free reshape where num_blocks is a multiple of 8)
-        return cache["latent"].reshape(L * N, K)[layer * N + table].reshape(-1, W)
+        # blocks, *block] (a free reshape: the layers and the blocks are
+        # not tiled)
+        return cache["latent"].reshape(L * N, *block)[layer * N + table].reshape(-1, W)
 
     def window_blocks(rows, at):
         return jax.lax.dynamic_slice(rows, (at // bs * bs, 0), (nblk * bs, W))
 
+    if paged_serves(cfg, C, cache):
+        # the kernel reads each slot's own live blocks; of the cache XLA
+        # gathers only the ``nblk`` blocks the window will be written into
+        q_row = absorb_query(cfg, p, q_nope, q_rope)
+        o_lat = attend_paged(cfg, q_row, cache, layer, block_tables, first, row)
+        ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at // bs,), (nblk,)))(tables, first)
+        blocks = jax.vmap(context)(ids)
+        blocks = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a % bs, 0)))(blocks, row, first)
+        blocks = jnp.where((block_tables[:, 0] != 0)[:, None, None], blocks, 0)
+        return absorb_output(cfg, p, o_lat), blocks
     if absorbs(cfg, C):
         # a short window over many slots, ONE SLOT AT A TIME: a padding slot
         # (its table is the null block's, :func:`_paged_layers`) reads
@@ -330,7 +387,7 @@ def latent_attention(
 
 
 def block_size_of(cfg, cache) -> int:
-    return cache["latent"].shape[2] // cfg.latent_width
+    return math.prod(cache["latent"].shape[2:]) // cfg.latent_width
 
 
 def write_blocks(cfg, cache, block_tables, first, blocks):
@@ -343,12 +400,12 @@ def write_blocks(cfg, cache, block_tables, first, blocks):
     whole). A block the window touches is rewritten with its old rows and
     the new; what lies past a slot's blocks, and a padding slot, is the null
     block: colliding trash writes are fine, nothing masked-in reads them."""
-    L, N, K = cache["latent"].shape
+    L, N, *block = cache["latent"].shape
     B = first.shape[0]
     bs = block_size_of(cfg, cache)
     nblk = blocks.shape[2] // bs
     tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
     ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at,), (nblk,)))(tables, first // bs)
     rows = (jnp.arange(L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
-    flat = cache["latent"].reshape(L * N, K).at[rows].set(blocks.reshape(L * B * nblk, K))
-    return {"latent": flat.reshape(L, N, K)}
+    flat = cache["latent"].reshape(L * N, *block).at[rows].set(blocks.reshape(L * B * nblk, *block))
+    return {"latent": flat.reshape(L, N, *block)}

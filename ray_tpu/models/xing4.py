@@ -57,6 +57,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import latent
 from ray_tpu.models.interface import AttentionPath, CacheLayout, Model
 # the latent paths, the cache's layout and the block write know only
 # dimensions: ``models/latent.py`` has them, for this module and ``models/
@@ -764,11 +765,15 @@ def batch_sharding(mesh, rules):
 
 
 def _attention_path(cfg: Xing4Config, window: int, cache, backend=None) -> AttentionPath:
-    """Both latent paths gather the table as wide as it is handed over: the
-    absorbed one a slot at a time, and nothing for a padding slot; the
-    expanded one whole, and then attends over ALL of it (the materialised
-    softmax) or, through the flash kernel, over the key tiles up to the
-    live context alone."""
+    """The absorbed path reads each real slot's own live blocks through the
+    kernel over latent rows where it serves (``latent.paged_serves``: a TPU,
+    the cache in whole tiles) and gathers a slot at a time elsewhere, each
+    slot as wide as its rung, nothing for a padding slot; the expanded path
+    gathers the table as wide as it is handed over and then attends over ALL
+    of it (the materialised softmax) or, through the flash kernel, over the
+    key tiles up to the live context alone."""
+    if latent.paged_serves(cfg, window, cache, backend=backend):
+        return AttentionPath("latent.paged", "blocks")
     if absorbs(cfg, window):
         return AttentionPath("latent.absorbed", "slots")
     if _flash_serves(cfg, window, cache, backend=backend):

@@ -5,6 +5,7 @@ reference of its family, ``perfbench/families/kimi_linear/reference.py``, on
 the CPU at a small size: float32 against float32, seeded weights. And the
 state slots through the manager, the scheduler and the engine."""
 
+import dataclasses
 import os
 import sys
 
@@ -135,6 +136,56 @@ def test_decode_slots_gather_their_context_at_their_own_width(model, cfg, params
     picks = [(i, n + d) for d in range(4) for i, n in enumerate(lens)]
     for h, w in zip(have, reference.logits_at(model, params, tokens, picks)):
         assert _rel(h, w) < TOL
+
+
+def test_decode_through_the_kernel_over_latent_rows_equals_the_gather(cfg, tokens, monkeypatch):
+    """``paged_decode_step`` with the attending layers' absorbed path through
+    ``ops/latent_paged.py`` (forced: the predicate as it reads on a TPU; the
+    kernel then runs in Pallas' TPU interpreter) at widths the layout stores
+    in whole tiles: two sequences on scattered blocks and slots, a padding
+    row between them, one context crossing a block's edge while decoding. The
+    logits, both pools' live parts and the path's name against the gather's."""
+    cfg = dataclasses.replace(cfg, kv_lora_rank=48, qk_rope_head_dim=16)
+    params = kl.init_params(cfg, jax.random.PRNGKey(6))
+    bs, lens = 16, (37, 14)
+
+    def run():
+        cache, state = kl.cache_layout(cfg, bs).init(12), kl.state_layout(cfg).init(4)
+        assert cache["latent"].shape[2:] == (8, 128)
+        tables = np.zeros((4, 4), np.int32)
+        tables[0], tables[2, :2] = [5, 2, 9, 7], [3, 8]
+        prefill, decode = _steps(cfg)
+        for i, row, slot in ((0, 0, 3), (1, 2, 1)):
+            cache, state, _ = _prefill(prefill, params, cache, state, tokens[i], tables[row], (lens[i],), slot=slot)
+        slots, have = np.array([3, 0, 1, 0], np.int32), []
+        for d in range(3):
+            toks, pos = np.zeros(4, np.int32), np.zeros(4, np.int32)
+            toks[[0, 2]], pos[[0, 2]] = [tokens[0, 37 + d], tokens[1, 14 + d]], [37 + d, 14 + d]
+            cache, state, got, _ = decode(params, cache, state, toks, pos, tables, pos + 1, slots)
+            have.append(np.asarray(got)[[0, 2]])
+        return np.stack(have), np.asarray(cache["latent"])[:, 1:], np.asarray(state["kda_state"])[:, 1:]
+
+    want = run()
+    assert kl.MODEL.attention_path(cfg, 1, None) == ("kda.update+latent.absorbed", "slots")
+    monkeypatch.setattr(latent, "paged_serves", lambda cfg, window, cache, backend=None: latent.absorbs(cfg, window))
+    assert kl.MODEL.attention_path(cfg, 1, None) == ("kda.update+latent.paged", "blocks")
+    for h, w in zip(run(), want):
+        assert np.isfinite(h).all() and _rel(h, w) < TOL
+
+
+def test_the_attention_path_reads_blocks_where_the_kernel_serves():
+    """At the published widths on a TPU, over the cache as the layout stores
+    it (a block of 16 as ``[8, 1152]``), decode reads each slot's own live
+    blocks; off the chip, or over a cache stored a block a row, the gather."""
+    cfg = kl.KimiLinearConfig(dtype=jnp.bfloat16)
+    cache = jax.eval_shape(lambda: kl.cache_layout(cfg, 16).init(8))
+    assert cache["latent"].shape == (7, 8, 8, 1152)
+    path = kl.MODEL.attention_path
+    assert path(cfg, 1, cache, backend="tpu") == ("kda.update+latent.paged", "blocks")
+    assert path(cfg, 1, cache, backend="cpu") == ("kda.update+latent.absorbed", "slots")
+    rows = {"latent": jax.ShapeDtypeStruct((7, 8, 9216), jnp.bfloat16)}
+    assert path(cfg, 1, rows, backend="tpu") == ("kda.update+latent.absorbed", "slots")
+    assert path(cfg, 1024, cache, backend="cpu") == ("kda.chunk+latent.expanded", "table")
 
 
 def test_forward_matches_the_reference_and_the_counts(model, cfg, params, tokens):

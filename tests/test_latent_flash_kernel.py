@@ -130,7 +130,14 @@ def test_attention_path_answers_both_ways():
         assert path(cfg, window, cache, backend="cpu") == ("latent.expanded", "table")
         assert path(cfg, window, None) == ("latent.expanded", "table")  # the CPU never looks at the cache
         assert xing4.MODEL.key_tile(cfg, window, cache) == 1024
-    assert path(cfg, 1, cache, backend="tpu") == ("latent.absorbed", "slots")
+    # decode absorbs: on a TPU over the cache as the layout stores it, through
+    # the kernel over latent rows (each slot's own live blocks); elsewhere,
+    # and over a cache stored a block a row, a slot at a time by the gather
+    assert path(cfg, 1, cache, backend="cpu") == ("latent.absorbed", "slots")
+    stored = jax.eval_shape(lambda: xing4.cache_layout(cfg, 16).init(8))
+    assert path(cfg, 1, stored, backend="tpu") == ("latent.paged", "blocks")
+    assert path(cfg, 1, cache, backend="tpu") == ("latent.absorbed", "slots")  # ``cache``: a block a row
+    assert path(cfg, 1024, stored, backend="tpu") == ("latent.flash", "live")
     toy = xing4.Xing4Config.tiny(kv_lora_rank=32)  # a chunk of 32 expands, at widths that are no whole lanes
     assert path(toy, 32, _latent_cache(toy, jnp.float32, 4), backend="tpu") == ("latent.expanded", "table")
     odd = xing4.Xing4Config(dtype=jnp.bfloat16, max_seq_len=8192 + 16)

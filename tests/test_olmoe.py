@@ -479,3 +479,43 @@ def test_the_latent_flash_kernel_compiles_for_the_chip_at_xing4_widths(one_chip,
         assert compiled.out_info.shape == (H, window, dv)
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize(
+    "layers, blocks, slots, window",
+    [(7, 12664, 64, 1), (40, 2824, 32, 1), (40, 2824, 32, 4), (40, 2824, 32, 8)],
+    ids=["kimi_linear_decode", "xing4_decode", "xing4_verify_4", "xing4_verify_8"],
+)
+def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(one_chip, layers, blocks, slots, window):
+    """The kernel of both latent models' decode (``ops/latent_paged.py``;
+    here for the same reason as the ones above): 32 heads, rows of 512 + 64,
+    both configurations' whole caches as the layout stores them (a block of
+    16 as ``[8, 1152]``), the full-width table. The cache goes in as it lies
+    and there is one Mosaic call (Mosaic refused a DMA of one row of ``[blocks,
+    9216]``, the form until PR 36: no test can compile that)."""
+    from ray_tpu.models.interface import CacheLayout
+    from ray_tpu.ops import latent_paged as LP
+
+    layout = CacheLayout("latent", layers, 16, (("latent", (576,)),), jnp.bfloat16, flat_blocks=True)
+    cache_like = jax.eval_shape(lambda: layout.init(blocks))["latent"]
+    assert cache_like.shape == (layers, blocks, 8, 1152)
+    assert LP.kernel_serves(window, 32, 576, 512, cache_like, backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        compiled = jax.jit(
+            lambda q, cache, tables, ctx: LP.attend_paged(
+                q, cache, layers - 1, tables, ctx, kv_lora_rank=512, scale=0.07, interpret=False
+            )
+        ).lower(
+            shape((slots, window, 32, 576), jnp.bfloat16), shape(cache_like.shape, jnp.bfloat16),
+            shape((slots, 512), jnp.int32), shape((slots,), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_rows" in text
+        # the queries laid out twice are the one temporary: slots x 2 x window x 32 x 1152 bf16
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * slots * 2 * window * 32 * 1152 * 2 + 2**20
+        assert [o.shape for o in compiled.out_info] == [(slots, window, 32, 512)] + [(slots, window, 32)] * 2
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)

@@ -90,7 +90,11 @@ def test_the_flash_chunk_is_the_materialised_chunk_on_a_runner(cfg, params, requ
     live = _runner(cfg, params)
     have_logits, have_rows = _prefill(live)
     assert live.attention_paths[CHUNK] == ("latent.flash", "live")
-    assert live.attention_paths[1] == ("latent.absorbed", "slots")
+    assert live.attention_paths[1] == ("latent.absorbed", "slots")  # off the chip decode keeps the gather
+    # ... and on a TPU at the published widths reads each slot's own live blocks
+    big = xing4.Xing4Config(dtype=jax.numpy.bfloat16)
+    stored = jax.eval_shape(lambda: xing4.cache_layout(big, 16).init(8))
+    assert model_of(big).attention_path(big, 1, stored, backend="tpu") == ("latent.paged", "blocks")
     assert live.prefill_width == _expected(sum(-(-n // TILE) * TILE for n in LIVE)) == _expected(32 + 64 + 96 + 112)
     np.testing.assert_allclose(have_logits, want_logits, rtol=0, atol=2e-5 * np.abs(want_logits).max())
     np.testing.assert_allclose(have_rows, want_rows, rtol=0, atol=2e-5 * np.abs(want_rows).max())

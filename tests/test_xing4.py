@@ -6,6 +6,7 @@ latent cache layout through what handles blocks (export and import between
 two engines; the prefix cache, COW, preemption and the tier run on both
 layouts in ``test_inference.py`` / ``test_kv_tier.py``)."""
 
+import dataclasses
 import math
 import os
 import sys
@@ -173,6 +174,17 @@ def test_the_window_decides_the_latent_path_at_the_published_widths():
     assert fam.expansion_flops_per_position(model) == 8388608
     path = xing4.MODEL.attention_path
     assert path(cfg, 1, None) == ("latent.absorbed", "slots") and path(cfg, 1024, None) == ("latent.expanded", "table")
+    # on a TPU, over the cache as the layout stores it at these widths, decode
+    # and a verify window of up to 8 read their own live blocks through the
+    # kernel over latent rows; a cache stored a block a row keeps the gather
+    bf16 = xing4.Xing4Config(dtype=jnp.bfloat16)
+    cache = jax.eval_shape(lambda: xing4.cache_layout(bf16, 16).init(8))
+    assert cache["latent"].shape == (40, 8, 8, 1152)
+    assert [path(bf16, c, cache, backend="tpu") for c in (1, 8)] == [("latent.paged", "blocks")] * 2
+    assert path(bf16, 16, cache, backend="tpu") == ("latent.absorbed", "slots")  # 512 query rows: the gather
+    assert path(bf16, 1, cache, backend="cpu") == ("latent.absorbed", "slots")
+    rows = {"latent": jax.ShapeDtypeStruct((40, 8, 9216), jnp.bfloat16)}
+    assert path(bf16, 1, rows, backend="tpu") == ("latent.absorbed", "slots")
 
 
 # -- YaRN ------------------------------------------------------------------------------------
@@ -374,6 +386,90 @@ def test_the_absorbed_path_reads_nothing_for_a_padding_slot(cfg, params, tokens,
     width = padded.table_widths[0] * BS
     assert padded.decode_width["gathered_tokens"] == real * width == alone.decode_width["gathered_tokens"]
     assert padded.decode_width["live_tokens"] == sum(31 + slot for slot in range(real))
+
+
+# -- the kernel over latent rows, forced through Pallas' interpreter -------------------------------
+
+#: widths at which the layout stores a block in whole tiles (two rows of 64
+#: fill a lane row of 128; blocks of 16 are 8 such rows), so that the kernel
+#: can read the cache as it is stored; everything else the toy's
+TILED = dict(kv_lora_rank=48, qk_rope_head_dim=16)
+
+
+@pytest.fixture
+def paged_forced(monkeypatch):
+    """The predicate as it reads on a TPU: every window that absorbs takes
+    the kernel (which then runs in Pallas' TPU interpreter)."""
+    monkeypatch.setattr(latent, "paged_serves", lambda cfg, window, cache, backend=None: latent.absorbs(cfg, window))
+
+
+@pytest.fixture(scope="module")
+def tiled(cfg):
+    c = dataclasses.replace(cfg, **TILED)
+    return c, xing4.init_params(c, jax.random.PRNGKey(6))
+
+
+def _decode_and_verify(cfg, params, tokens, lens=(37, 16, 21)):
+    """Three sequences prefilled into a shuffled pool, a padding slot between
+    them, then a decode step and a verify window of 3: the logits of both and
+    the cache after each."""
+    bs = 16
+    cache = xing4.cache_layout(cfg, bs).init(20)
+    assert cache["latent"].shape[2:] == (8, 128)
+    tables = np.zeros((4, 4), np.int32)
+    ids = np.random.default_rng(3).permutation(np.arange(1, 13)).reshape(3, 4)
+    for i, row in enumerate((0, 2, 3)):  # slot 1 is padding
+        tables[row] = ids[i]
+        cache, _ = _prefill(cfg, params, cache, tokens[i % 2], tables[row], (lens[i],))
+    real = np.array([0, 2, 3])
+    toks, pos = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    toks[real], pos[real] = [tokens[i % 2, n] for i, n in enumerate(lens)], lens
+    decode = jax.jit(lambda p, c, *a: xing4.paged_decode_step(cfg, p, c, *a))
+    cache_d, logits_d, _ = decode(params, cache, toks, pos, tables, np.ones(4, np.int32))
+    window, ctx, true = np.zeros((4, 4), np.int32), np.zeros(4, np.int32), np.zeros(4, np.int32)
+    for i, (row, n) in enumerate(zip(real, lens)):
+        window[row, :3], ctx[row], true[row] = tokens[i % 2, n + 1 : n + 4], n + 1, 3
+    verify = jax.jit(lambda p, c, *a: xing4.paged_verify_step(cfg, p, c, *a))
+    cache_v, logits_v, _ = verify(params, cache_d, window, tables, ctx, true)
+    live = np.asarray(logits_v)[real][:, :3]
+    return np.asarray(logits_d)[real], live, np.asarray(cache_d["latent"])[:, 1:], np.asarray(cache_v["latent"])[:, 1:]
+
+
+def test_decode_and_verify_through_the_kernel_equal_the_gather(tiled, tokens, request):
+    """``paged_decode_step`` and ``paged_verify_step`` with the absorbed path
+    through ``ops/latent_paged.py`` (contexts that end mid-block, at a block's
+    edge, one slot padding): the logits and every block of the cache but the
+    null block equal the gather's."""
+    cfg, params = tiled
+    want = _decode_and_verify(cfg, params, tokens)
+    assert xing4.MODEL.attention_path(cfg, 1, None) == ("latent.absorbed", "slots")
+    request.getfixturevalue("paged_forced")
+    assert xing4.MODEL.attention_path(cfg, 1, None) == ("latent.paged", "blocks")
+    have = _decode_and_verify(cfg, params, tokens)
+    for h, w in zip(have, want):
+        assert np.isfinite(h).all() and _rel(h, w) < TOL
+
+
+def test_the_runner_compiles_one_decode_program_and_counts_live_blocks(tiled, tokens, paged_forced):
+    """Where the absorbed path reads blocks the width costs nothing: ONE
+    rung, the full table, and ``decode_width["gathered_tokens"]`` is each real
+    slot's live blocks (a padding slot reads none)."""
+    from ray_tpu.inference.model_runner import PagedModelRunner, table_width_ladder
+
+    cfg, params = tiled
+    cfg = dataclasses.replace(cfg, max_seq_len=4096)
+    assert len(table_width_ladder(cfg.max_seq_len, 16)) == 2
+    runner = PagedModelRunner(cfg, params, num_blocks=264, block_size=16, prefill_buckets=(48,), decode_buckets=(4,))
+    assert runner.attention_paths[1] == ("latent.paged", "blocks")
+    assert runner.table_widths == (256,)
+    rows = [list(range(1 + 4 * i, 5 + 4 * i)) + [0] * 252 for i in range(2)]
+    for i, n in enumerate((37, 16)):
+        runner.prefill_chunk(tokens[i, :n].tolist(), rows[i], 0)
+    logits = runner.decode([7, 8], [37, 16], rows, [38, 17])
+    assert np.isfinite(np.asarray(logits)).all()
+    dw = runner.decode_width
+    assert (dw["launches"], dw["width_tokens"], dw["live_tokens"]) == (1, 4096, 38 + 17)
+    assert dw["gathered_tokens"] == (3 + 2) * 16
 
 
 # -- export and import between two engines, on both layouts -------------------------------------
