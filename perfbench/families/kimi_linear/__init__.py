@@ -45,8 +45,9 @@ rest):
   ``assumed``.
 * ``train_program`` refuses: the program has no sharded training step for
   this family, and no training cell runs it.
-* The per-layer metrics are twins of readers that exist under the suffix
-  ``.kda`` (new files over the same counters) and three over
+* The cell is listed by the per-layer entries that already read its
+  counters (``.batch``, ``.moe``, ``.mla``, ``.longdoc``: one entry a reader
+  since PR 37) and brings three of its own under ``.kda``, over
   ``engine_stats()["state_layout"]`` / ``["state_pool"]``. On a checkout
   without those keys the readers find nothing and say nothing."""
 

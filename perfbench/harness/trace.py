@@ -5,13 +5,14 @@ the thin adapter from the profiler's ``.xplane.pb``.
 
 Busy time is the union of the intervals in which an operation ran on a
 device (line ``XLA Ops`` of a ``/device:TPU:n`` plane), averaged over the
-device planes; the idle share is 1 - busy / window. Long idle gaps are
-attributed to what a host thread was doing in them."""
+device planes; the idle share is 1 - busy / window. A long idle gap is
+divided among the spans of the host thread that was busiest in it."""
 
 from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 import re
 import statistics
@@ -267,40 +268,80 @@ def top_device_ops(trace: Dict[str, Any], n: int = 10) -> List[List[Any]]:
     return [[safe_name(k), v / 1e9 / max(1, len(planes))] for k, v in top]
 
 
+def _innermost(events: Sequence[Tuple[float, float, str]]) -> List[Tuple[float, float, str]]:
+    """One host thread's events ``(start, end, name)`` as segments that do
+    not overlap, each under the INNERMOST event at that instant: of the
+    events that cover it the one that began last (the shorter, where two
+    began together). A span's time is then its own, without its children's."""
+    bounds = sorted({t for s, e, _ in events for t in (s, e)})
+    order = sorted(events, key=lambda ev: (ev[0], ev[0] - ev[1]))
+    open_: List[Tuple[float, float, float, str]] = []  # a heap: the innermost on top
+    out: List[Tuple[float, float, str]] = []
+    nxt = 0
+    for t, t_next in zip(bounds, bounds[1:]):
+        while nxt < len(order) and order[nxt][0] <= t:
+            s, e, name = order[nxt]
+            heapq.heappush(open_, (-s, e - s, e, name))
+            nxt += 1
+        while open_ and open_[0][2] <= t:
+            heapq.heappop(open_)
+        if not open_:
+            continue
+        name = open_[0][3]
+        if out and out[-1][2] == name and out[-1][1] == t:
+            out[-1] = (out[-1][0], t_next, name)
+        else:
+            out.append((t, t_next, name))
+    return out
+
+
 def idle_gaps(trace: Dict[str, Any], n: int = 10) -> List[List[Any]]:
-    """The idle time of the first device, by what a host thread was doing:
-    each gap between device operations longer than ``GAP_FLOOR_NS`` goes to
-    the host event that overlaps it most (the shortest such event, so the
-    innermost span), or to ``unattributed``."""
+    """The idle time of the first device, by what the host was doing: each
+    gap between device operations longer than ``GAP_FLOOR_NS`` goes to the
+    host THREAD (a line of a ``/host:`` plane) whose events cover most of
+    it, and is DIVIDED among that thread's innermost events at each instant
+    in proportion to overlap; what no event of that thread covers is
+    ``unattributed``. A serving step's serial part is several spans in a
+    row under one gap: each gets its own share, none the whole."""
     planes = device_planes(trace)
     if not planes:
         return []
     w0, w1 = _window(trace)
     busy_iv = _merge((e[1], e[1] + e[2]) for e in _line(planes[0], OPS_LINE))
     gaps = _subtract([(w0, w1)], busy_iv)
-    host = sorted(
-        (e[1], e[1] + e[2], e[0])
-        for p in trace["planes"] if p["name"].startswith("/host:")
-        for line in p["lines"] for e in line["events"] if e[2] > 0
-    )
-    starts = [h[0] for h in host]
-    longest = max((h[1] - h[0] for h in host), default=0.0)
+    threads = []  # per host thread: its innermost segments and their ends, for bisect
+    for p in trace["planes"]:
+        if not p["name"].startswith("/host:"):
+            continue
+        for line in p["lines"]:
+            segments = _innermost([(e[1], e[1] + e[2], e[0]) for e in line["events"] if e[2] > 0])
+            if segments:
+                threads.append((segments, [seg[1] for seg in segments]))
     total: Dict[str, float] = {}
+
+    def add(name: str, ns: float) -> None:
+        total[name] = total.get(name, 0.0) + ns
+
     for s, e in gaps:
         if e - s < GAP_FLOOR_NS:
-            total["shorter_gaps_not_looked_at"] = total.get("shorter_gaps_not_looked_at", 0.0) + (e - s)
+            add("shorter_gaps_not_looked_at", e - s)
             continue
-        best, best_key = "unattributed", (0.0, 0.0)
-        lo = bisect.bisect_left(starts, s - longest)
-        hi = bisect.bisect_right(starts, e)
-        for hs, he, name in host[lo:hi]:
-            overlap = min(e, he) - max(s, hs)
-            if overlap <= 0:
-                continue
-            key = (round(overlap / (e - s), 2), -(he - hs))
-            if key > best_key:
-                best, best_key = name, key
-        total[best] = total.get(best, 0.0) + (e - s)
+        best: List[Tuple[str, float]] = []
+        best_cover = 0.0
+        for segments, ends in threads:
+            shares = []
+            i = bisect.bisect_right(ends, s)  # the first segment that ends inside or after the gap
+            while i < len(segments) and segments[i][0] < e:
+                seg_s, seg_e, name = segments[i]
+                shares.append((name, min(e, seg_e) - max(s, seg_s)))
+                i += 1
+            cover = sum(ns for _, ns in shares)
+            if cover > best_cover:
+                best, best_cover = shares, cover
+        for name, ns in best:
+            add(name, ns)
+        if e - s > best_cover:
+            add("unattributed", e - s - best_cover)
     top = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[safe_name(k), v / 1e9] for k, v in top]
 
@@ -348,8 +389,28 @@ def cut(trace: Dict[str, Any], seconds: float = 0.6) -> Dict[str, Any]:
     return {"planes": planes}
 
 
+def gaps_view(trace: Dict[str, Any]) -> Dict[str, Any]:
+    """All that ``idle_gaps`` reads of a trace, over the whole window: the
+    first device's busy intervals as one operation each, and the host
+    planes whole. ``idle_gaps`` of it is ``idle_gaps`` of the trace."""
+    w0, _ = _window(trace)
+    first = device_planes(trace)[0]
+    busy_iv = _merge((e[1], e[1] + e[2]) for e in _line(first, OPS_LINE))
+    planes = [{"name": first["name"], "lines": [
+        {"name": OPS_LINE, "events": [["busy", s - w0, e - s] for s, e in busy_iv]}]}]
+    for p in trace["planes"]:
+        if p["name"].startswith("/host:"):
+            planes.append({"name": p["name"], "lines": [
+                {"name": line["name"], "events": [[e[0], e[1] - w0, e[2]] for e in line["events"]]}
+                for line in p["lines"] if line["events"]]})
+    return {"planes": planes}
+
+
 def dump(trace: Dict[str, Any], directory: str) -> None:
-    """For a human: what the trace holds, and a small piece of it."""
+    """For a human: what the trace holds, a small piece of it, and what the
+    idle gaps are read from (so that a change to ``idle_gaps`` can be read
+    against the rule before it on one trace)."""
+    import gzip
     import json
 
     os.makedirs(directory, exist_ok=True)
@@ -357,3 +418,5 @@ def dump(trace: Dict[str, Any], directory: str) -> None:
         f.write("\n".join(structure(trace)) + "\n")
     with open(os.path.join(directory, "trace_cut.json"), "w") as f:
         json.dump(cut(trace), f)
+    with gzip.open(os.path.join(directory, "trace_gaps.json.gz"), "wt") as f:
+        json.dump(gaps_view(trace), f)

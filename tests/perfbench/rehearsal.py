@@ -2,6 +2,7 @@
 same structure and generator kinds, toy sizes. Never used on the chip."""
 
 import copy
+import functools
 import json
 import os
 
@@ -55,3 +56,25 @@ def tiny_traffic(name: str):
         # the loss by more than the batches' own noise
         t.update(seq_len=16, global_batch=8, warm_steps=1, trace_steps=2, lr=0.003)
     return t
+
+
+@functools.lru_cache(maxsize=None)
+def toy_engine_stats(config_name: str):
+    """``engine_stats()`` of a fresh replica of the configuration's family at
+    its toy sizes, built in this process (no cluster, no request): the tree
+    of counters the ``stats_delta`` readers dig into, as THAT family's
+    program has it. A few seconds a family on the CPU, once a process. The
+    configuration is found as the harness finds it (``cells.config_of``)."""
+    from perfbench import families
+    from perfbench.harness import cells
+    from perfbench.harness.program import engine_config
+
+    model = tiny(cells.config_of(cells.benchmark(), config_name))
+    fam = families.of(model)
+    cfg = fam.model_config(model, max_seq_len=int(model["max_position_embeddings"]),
+                           **model["serving"].get("model_overrides", {}))
+    server = fam.server_class()(cfg, engine_config(model["serving"]["engine"]), seed=7, export_metrics=False)
+    try:
+        return server.engine_stats()
+    finally:
+        server.engine.stop()

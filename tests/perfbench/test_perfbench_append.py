@@ -1,8 +1,12 @@
 """The seam a ``model_config`` PR goes through, held open: the benchmark as
 such a PR would leave it (one configuration, one cell, the cell's name in
-``serve_tokens_per_s``'s ``workloads`` and two per-layer metrics with a suffix
-of their own, each APPENDED at the end of its list, as the driver demands) must
-pass every test of this folder that reads the lists of ``BENCHMARK.json``.
+``serve_tokens_per_s``'s ``workloads`` and in those of three per-layer entries
+whose counters its program has, and two per-layer metrics of its own that NO
+entry reads yet, each APPENDED at the end of its list, as the driver demands)
+must pass every test of this folder that reads the lists of ``BENCHMARK.json``.
+A cell JOINS the entry that reads its counter (PR 37): a copy of an entry that
+is there under a suffix of the cell's own fails the guard of
+``test_perfbench_yardstick.py``.
 
 PR 33 was refused because two tests held PR 31's entries to the LAST place of
 ``configs`` and ``workloads``: appended to, the tests failed; with the new
@@ -41,8 +45,19 @@ FILES = sorted(f for f in glob.glob(os.path.join(HERE, "test_perfbench_*.py"))
 CONFIG, CELL, FAMILY, SUFFIX = "appended-olmoe-12l-v8", "appended-longprompt-batch", "appended_twin", "appended"
 #: a family outside ``perfbench/`` (as ``test_perfbench_families.py::twins`` makes them): OLMoE's members
 TWIN = "from perfbench.families.olmoe import *  # noqa: F401,F403\n"
-#: the per-layer metrics the throwaway cell brings under its own suffix -> the file each is a twin of
-NEW_METRICS = {f"{name}.{SUFFIX}": f"{name}.batch" for name in ("prefill_step_device_ms", "tokens_per_engine_step")}
+#: the entries the throwaway cell JOINS (its name at the end of each one's ``workloads``): OLMoE's program has their counters
+JOINS = ("prefill_step_device_ms.batch", "tokens_per_engine_step.batch", "moe_rows_per_expert.moe")
+#: the per-layer metrics it brings under its own suffix, readings no entry has -> (``better``, the file)
+NEW_METRICS = {
+    f"steps_with_prefill_share.{SUFFIX}": ("higher", {
+        "layer": "engine scheduler", "unit": "%", "moves": "serve_tokens_per_s", "kind": "stats_delta", "reduce": "ratio",
+        "key": ["scheduler", "steps_with_prefill_and_decode"], "per": ["total_steps"], "scale": 100.0}),
+    f"prefill_launches.{SUFFIX}": ("lower", {
+        "layer": "model runner", "unit": "launches", "moves": "serve_tokens_per_s", "kind": "stats_delta", "reduce": "delta",
+        "key": ["prefill_width", "launches"]}),
+}
+#: what a family brought before PR 37: the same reader as an entry that is there, under a suffix of its own
+TWIN_OF = "decode_step_device_ms.batch"
 #: the last name of each list when this file was written (PR 34): where PR 33 put its entries BEFORE
 LAST_THEN = {"configs": "xing4.0-29b-a4b-ep8", "workloads": "mla-longdoc-batch",
              "serve_tokens_per_s": "moe-chat-offline", "per_layer": "prefill_read_live_share.longdoc"}
@@ -50,11 +65,12 @@ LAST_THEN = {"configs": "xing4.0-29b-a4b-ep8", "workloads": "mla-longdoc-batch",
 STARTS_SOMETHING = ("rehearsal.", "bench_run.", "subprocess")
 
 
-def _appended(bench, at_the_end=True):
+def _appended(bench, at_the_end=True, twin=False):
     """``bench`` as a ``model_config`` PR would leave it, and the files that
     PR would add (path -> content). ``at_the_end`` false: each new entry put
     BEFORE the entry ``LAST_THEN`` names, which the driver refuses as a moved
-    entry (wherever later PRs have appended theirs since)."""
+    entry (wherever later PRs have appended theirs since). ``twin``: it also
+    brings a copy of ``TWIN_OF`` under its own suffix instead of joining it."""
     bench = copy.deepcopy(bench)
 
     def put(which, items, new):
@@ -73,10 +89,17 @@ def _appended(bench, at_the_end=True):
     put("workloads", bench["workloads"], {"name": CELL, "config": CONFIG, "traffic": "longprompt-batch", "chips": 1,
                                           "why": "a throwaway cell: what a model_config PR appends"})
     put("serve_tokens_per_s", next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")["workloads"], CELL)
-    for name, twinned in NEW_METRICS.items():
-        entry = next(m for m in bench["per_layer"] if m["name"] == twinned)
-        put("per_layer", bench["per_layer"], {**entry, "name": name, "workloads": [CELL]})
-        files[os.path.join(cells.HERE, "layer_metrics", f"{name}.json")] = cells.layer_metric_spec(twinned)
+    for name in JOINS:
+        next(m for m in bench["per_layer"] if m["name"] == name)["workloads"].append(CELL)
+    new = {name: ({"unit": spec["unit"], "better": better, "source": "program_counter", "layer": spec["layer"],
+                   "moves": spec["moves"]}, spec) for name, (better, spec) in NEW_METRICS.items()}
+    if twin:
+        entry = next(m for m in bench["per_layer"] if m["name"] == TWIN_OF)
+        new[TWIN_OF.replace(".batch", f".{SUFFIX}")] = (
+            {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}, cells.layer_metric_spec(TWIN_OF))
+    for name, (entry, spec) in new.items():
+        put("per_layer", bench["per_layer"], {"name": name, **entry, "workloads": [CELL]})
+        files[os.path.join(cells.HERE, "layer_metrics", f"{name}.json")] = spec
     files[os.path.join(cells.ROOT, "BENCHMARK.json")] = bench
     return files
 
@@ -92,10 +115,16 @@ def appended(tmp_path, monkeypatch):
     monkeypatch.setattr(sys, "path", list(sys.path))  # the files under trial prepend to it as they load
     read = cells.load_json
 
-    def install(at_the_end):
+    listdir = os.listdir
+
+    def install(at_the_end, twin=False):
         monkeypatch.setattr(cells, "load_json", read)  # a second call starts from the tree's own files again
-        files = _appended(cells.benchmark(), at_the_end)
+        files = _appended(cells.benchmark(), at_the_end, twin)
         monkeypatch.setattr(cells, "load_json", lambda path: copy.deepcopy(files[path]) if path in files else read(path))
+        # the guard also lists the folder of readers: the new files are "in" it
+        folder = os.path.join(cells.HERE, "layer_metrics")
+        added = [os.path.basename(path) for path in files if os.path.dirname(path) == folder]
+        monkeypatch.setattr(os, "listdir", lambda path=".": listdir(path) + (added if path == folder else []))
 
     yield install
     sys.modules.pop(f"perfbench.families.{FAMILY}", None)
@@ -147,6 +176,9 @@ def test_a_configuration_and_a_cell_appended_at_the_ends_pass(appended, path):
     bench = cells.benchmark()
     assert (bench["configs"][-1]["name"], bench["workloads"][-1]["name"]) == (CONFIG, CELL)
     assert [m["name"] for m in bench["per_layer"][-len(NEW_METRICS):]] == list(NEW_METRICS)
+    joined = [m for m in bench["per_layer"] if m["name"] in JOINS]
+    assert len(joined) == 3 and all(m["workloads"][-1] == CELL and len(m["workloads"]) > 1 for m in joined)
+    assert {m["name"] for m in cells.metrics_of(bench, CELL, "per_layer")} == {*JOINS, *NEW_METRICS, "peak_hbm_gb"}
     _, failed = _failures(path)
     assert not failed, "\n".join(failed)
 
@@ -162,3 +194,15 @@ def test_the_trial_calls_tests_and_an_entry_put_before_one_that_was_there_fails(
         assert names.index(new) + 1 == names.index(LAST_THEN[which])
     failed = [line for path in FILES for line in _failures(path)[1]]
     assert failed, "entries put before ones that were there were accepted by every test"
+
+
+def test_a_copy_of_an_entry_that_is_there_fails_the_guard(appended):
+    """What filled the contract's 128 entries by PR 35: the same reader
+    under a suffix of the cell's own. The guard names it; joined, it passes."""
+    yardstick = os.path.join(HERE, "test_perfbench_yardstick.py")
+    appended(at_the_end=True, twin=True)
+    failed = _failures(yardstick)[1]
+    assert len(failed) == 1 and "test_no_two_entries_are_the_same_reader" in failed[0], failed
+    assert TWIN_OF in failed[0] and TWIN_OF.replace(".batch", f".{SUFFIX}") in failed[0]
+    appended(at_the_end=True)
+    assert not _failures(yardstick)[1]

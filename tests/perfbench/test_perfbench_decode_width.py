@@ -42,10 +42,12 @@ NEW = {
 def test_new_metric_file_agrees_with_its_entry(name):
     unit, better, moves, where, key, per, scale = NEW[name]
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry == {
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": name, "unit": unit, "better": better, "source": "program_counter",
-        "layer": "model runner", "moves": moves, "workloads": where,
+        "layer": "model runner", "moves": moves,
     }
+    # the cells ISSUE 25 named, from the list's start; a later cell joins after them
+    assert entry["workloads"][: len(where)] == where
     spec = cells.layer_metric_spec(name)
     assert (spec["layer"], spec["unit"], spec["moves"]) == ("model runner", unit, moves)
     assert (spec["kind"], spec["reduce"]) == ("stats_delta", "ratio")

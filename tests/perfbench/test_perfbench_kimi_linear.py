@@ -1,9 +1,9 @@
 """The ``kimi_linear`` family and its cell without a chip: the configuration
 file against the catalog row and its ``BENCHMARK.json`` entry, the family's
-counts against the program's at the configuration's sizes, every ``.kda``
-metric file against its entry and its twin, the rehearsal of
-``kda-reason-offline`` printing every ``.kda`` metric that needs no device
-operation, and twin families whose reference is another model reading
+counts against the program's at the configuration's sizes, every per-layer
+reading of the cell against the ONE entry that reads it (``readings.py``),
+the rehearsal of ``kda-reason-offline`` printing every one of those readings
+that needs no device operation, and twin families whose reference is another model reading
 ``correct`` false. No number printed here is a speed.
 
 What this PR added is held RELATIVE to what was there (after a named earlier
@@ -22,6 +22,7 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 sys.path.insert(0, HERE)
 
+import readings  # noqa: E402
 import rehearsal  # noqa: E402
 from perfbench import families  # noqa: E402
 from perfbench import run as bench_run  # noqa: E402
@@ -30,21 +31,33 @@ from perfbench.harness import cells, layer_metrics as lm  # noqa: E402
 BENCH = cells.benchmark()
 CELL = "kda-reason-offline"
 CONFIG = "kimi-linear-48b-a3b-ep16"
-KDA_METRICS = [m for m in BENCH["per_layer"] if m["name"].endswith(".kda")]
-#: the readers PR 35 twinned under ``.kda`` -> the suffix of the file each is a twin of
-TWINNED = {
-    **{name: ".mla" for name in (
-        "decode_step_device_ms", "prefill_step_device_ms", "step_device_wait_ms", "step_host_serial_ms",
-        "tokens_per_engine_step", "device_idle_share", "kv_pool_peak_share", "preemptions",
-        "recompiles_in_window", "decode_gather_live_share", "moe_ffn_time_share", "moe_rows_per_expert",
-        "moe_held_assignment_share", "kv_bytes_per_token",
-        "step_readback_ms", "warmup_s", "decode_table_width_tokens")},
-    "prefill_read_live_share": ".longdoc", "latent_flash_time_share": ".longdoc",
+#: the 19 readings of other cells PR 35 gave this one. Until PR 37 each was a twin under ``.kda``; now the
+#: cell is listed by the entry that already read the counter, under that entry's name
+SHARED = [f"{n}.batch" for n in (
+    "decode_step_device_ms", "prefill_step_device_ms", "step_device_wait_ms", "step_host_serial_ms",
+    "tokens_per_engine_step", "device_idle_share", "kv_pool_peak_share", "preemptions",
+    "decode_gather_live_share", "step_readback_ms", "decode_table_width_tokens",
+)] + ["warmup_s", "recompiles_in_window.moe", "moe_ffn_time_share.moe", "moe_rows_per_expert.moe",
+      "moe_held_assignment_share.mla", "kv_bytes_per_token.mla",
+      "prefill_read_live_share.longdoc", "latent_flash_time_share.longdoc"]
+#: PR 35's own counters -> what each one's file must hold. ``.kda`` stays on them
+NEW_COUNTERS = {
+    "state_bytes_per_seq.kda": {"kind": "stats_delta", "key": ["state_layout", "bytes_per_seq"]},
+    "state_pool_peak_share.kda": {"kind": "stats_delta", "key": ["state_pool", "in_use"],
+                                  "per": ["state_pool", "slots"], "scale": 100.0},
+    "state_admission_waits.kda": {"kind": "stats_delta", "key": ["state_pool", "admission_waits"]},
 }
-NEW_COUNTERS = {"state_bytes_per_seq.kda", "state_pool_peak_share.kda", "state_admission_waits.kda"}
+READINGS = SHARED + list(NEW_COUNTERS)
+#: what PR 35 had no place for among the contract's 128 entries and the cell JOINED in PR 37: the rest of
+#: the step's host account (``engine.schedule`` is most of this cell's idle gaps), the wakes, the start-up
+#: stages, the expert account's other three readings; and the reader of PR 36's kernel over latent rows
+JOINED = [f"{n}.batch" for n in ("step_schedule_ms", "step_launch_ms", "step_sample_ms", "step_emit_ms",
+                                 "wakes_after_launch_share", "wake_hold_ms")] + [
+    "replica_init_s", "param_init_s", "moe_experts_touched_share.moe", "moe_load_imbalance.moe",
+    "moe_rows_per_expert_prefill.moe", "latent_rows_time_share"]
 #: read from the DEVICE's operations in the trace: the CPU rehearsal's trace has host threads only
-DEVICE_OPS = {"moe_ffn_time_share.kda", "decode_step_device_ms.kda", "prefill_step_device_ms.kda",
-              "latent_flash_time_share.kda"}
+DEVICE_OPS = {"moe_ffn_time_share.moe", "decode_step_device_ms.batch", "prefill_step_device_ms.batch",
+              "latent_flash_time_share.longdoc", "latent_rows_time_share"}
 
 ROW = {  # the catalog row's config (model-configs guide), every key under its own name
     "first_k_dense_replace": 1, "head_dim": 72, "hidden_act": "silu", "hidden_size": 2304,
@@ -134,12 +147,11 @@ def test_counts_agree_with_the_program_at_the_configurations_sizes():
 
 # -- the metric files -------------------------------------------------------------------
 
-def test_the_cell_reports_nineteen_twins_and_three_new_counters_each_once():
-    added = {f"{n}.kda" for n in TWINNED} | NEW_COUNTERS
+def test_the_cell_reports_nineteen_readings_of_other_cells_and_three_new_counters_each_once():
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert all(names.count(name) == 1 for name in added)  # each there once; more may follow
-    assert names.index("prefill_read_live_share.longdoc") < min(names.index(name) for name in added)
-    assert {m["name"] for m in KDA_METRICS} >= added  # the contract's 128 entries are all taken now
+    assert len(set(READINGS)) == 22 and not set(READINGS) & set(JOINED)
+    assert all(names.count(name) == 1 for name in READINGS + JOINED)  # each there once; more may follow
+    assert names.index("prefill_read_live_share.longdoc") < min(names.index(name) for name in NEW_COUNTERS)
     e2e = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tokens_per_s")
     assert e2e["workloads"].count(CELL) == 1
     assert e2e["workloads"].index("moe-chat-offline") < e2e["workloads"].index(CELL)
@@ -155,26 +167,15 @@ def test_the_cell_reports_nineteen_twins_and_three_new_counters_each_once():
     assert max(r.prompt_len + r.output_len for r in sch.closed_stream(traffic, 1)) <= 8192  # every request fits
 
 
-@pytest.mark.parametrize("entry", KDA_METRICS, ids=lambda m: m["name"])
-def test_each_kda_metric_file_agrees_with_its_entry_and_its_twin(entry):
-    spec = cells.layer_metric_spec(entry["name"])
-    assert (spec["layer"], spec["unit"], spec["moves"]) == (entry["layer"], entry["unit"], entry["moves"])
-    assert entry["workloads"] == [CELL] and spec["kind"] in lm.READERS  # held to the ONE cell
-    assert entry["source"] == {"device_trace": "device_trace", "stats_delta": "program_counter"}[spec["kind"]]
-    assert entry["moves"] == ("setup_s" if entry["name"] == "warmup_s.kda" else "serve_tokens_per_s")
-    if entry["name"] in NEW_COUNTERS:
-        assert spec["kind"] == "stats_delta" and spec["key"][0] in ("state_layout", "state_pool")
-        assert spec["layer"] == "state pool"
-        return
-    base = entry["name"][: -len(".kda")]
-    if base not in TWINNED:  # a later PR's own counter under this suffix: it has no twin to agree with
-        return
-    twin = cells.layer_metric_spec(base + TWINNED[base])
-    same = {k: v for k, v in twin.items() if k != "what"}
-    assert {k: spec[k] for k in same} == same  # a reader of a kind that exists, over the same counters
-    twin_entry = next(m for m in BENCH["per_layer"] if m["name"] == base + TWINNED[base])
-    assert {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")} == \
-        {k: twin_entry[k] for k in ("unit", "better", "source", "layer", "moves")}
+@pytest.mark.parametrize("name", READINGS + JOINED)
+def test_each_reading_of_the_cell_has_one_entry_whose_file_reads_what_is_expected(name):
+    entry = readings.check(BENCH, CELL, name, NEW_COUNTERS.get(name) or readings.WANT[name])
+    start_up = name in ("replica_init_s", "param_init_s", "warmup_s")
+    assert entry["moves"] == ("setup_s" if start_up else "serve_tokens_per_s")
+    if name in NEW_COUNTERS:
+        assert entry["workloads"][0] == CELL and entry["layer"] == "state pool"
+    else:  # a joined entry: the cells that were there come first
+        assert entry["workloads"].index("mla-longdoc-batch") < entry["workloads"].index(CELL)
 
 
 def _snapshot(in_use, waits):
@@ -292,18 +293,18 @@ def _rehearse(family, tmp_path, trace):
     return cell, out
 
 
-def test_the_rehearsal_of_the_cell_prints_every_kda_metric(cluster, tmp_path):
+def test_the_rehearsal_of_the_cell_prints_every_reading(cluster, tmp_path):
     cell, out = _rehearse("kimi_linear", tmp_path, trace=True)
     assert out["correct"] is True
     line = bench_run.result_line(BENCH, cell, out, True)
     printed = set(line["metrics"])
-    assert {m["name"] for m in KDA_METRICS} - DEVICE_OPS <= printed
+    assert set(READINGS + JOINED) - DEVICE_OPS <= printed
     assert "peak_hbm_gb" in printed  # no workloads key: every cell reports it
     value = {k: v["value"] for k, v in line["metrics"].items()}
-    assert value["kv_bytes_per_token.kda"] == 2 * (16 + 8) * 4  # 2 attending layers of 7, a row of 24 float32
+    assert value["kv_bytes_per_token.mla"] == 2 * (16 + 8) * 4  # 2 attending layers of 7, a row of 24 float32
     assert value["state_bytes_per_seq.kda"] == 5 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)  # 5 KDA layers
     assert value["state_pool_peak_share.kda"] == 100.0  # 4 clients on 4 slots
-    assert value["recompiles_in_window.kda"] == 0.0 and value["preemptions.kda"] == 0.0
+    assert value["recompiles_in_window.moe"] == 0.0 and value["preemptions.batch"] == 0.0
     end = out["observed"].stats_end
     assert end["kv_layout"]["kind"] == "latent" and end["state_layout"]["kind"] == "kda"
     pool = end["state_pool"]

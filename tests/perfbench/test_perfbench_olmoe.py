@@ -1,8 +1,9 @@
 """The ``olmoe`` family and its cell without a chip: the family's counts
-against the program's at the configuration's sizes, every ``.moe`` metric
-file against its entry, the ``ratio`` reader on two worked ``moe``
-snapshots, the rehearsal of ``moe-chat-offline`` printing every ``.moe``
-metric that needs no device operation, twin families whose reference is
+against the program's at the configuration's sizes, every per-layer reading
+of the cell against the ONE entry that reads it (``readings.py``), the
+``ratio`` reader on two worked ``moe`` snapshots, the rehearsal of
+``moe-chat-offline`` printing every one of those readings that needs no
+device operation, twin families whose reference is
 another model reading ``correct`` false, and the expert FFN's own reading
 of the correctness check. No number printed here is a speed."""
 
@@ -19,6 +20,7 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 sys.path.insert(0, HERE)
 
+import readings  # noqa: E402
 import rehearsal  # noqa: E402
 from perfbench import families  # noqa: E402
 from perfbench import run as bench_run  # noqa: E402
@@ -27,10 +29,13 @@ from perfbench.harness import cells, layer_metrics as lm  # noqa: E402
 BENCH = cells.benchmark()
 CELL = "moe-chat-offline"
 CONFIG = "olmoe-1b-7b-0125-12l"
-MOE_METRICS = [m for m in BENCH["per_layer"] if m["name"].endswith(".moe")]
+#: the 24 per-layer readings PR 27 gave this cell. Until PR 37 each was an entry of its own under ``.moe``;
+#: now the cell is listed by the entry that already read the counter (``.batch``: a cell judged by
+#: ``serve_tokens_per_s``), and ``.moe`` stays on the readings PR 27 was first to bring
+READINGS = readings.MOE_CHAT_OFFLINE
 #: read from the DEVICE's operations in the trace (a kernel's name, a jitted program's executions):
 #: the CPU rehearsal's trace has host threads only, the reader finds nothing and the line leaves them out
-DEVICE_OPS = {"moe_ffn_time_share.moe", "decode_step_device_ms.moe", "prefill_step_device_ms.moe"}
+DEVICE_OPS = {"moe_ffn_time_share.moe", "decode_step_device_ms.batch", "prefill_step_device_ms.batch"}
 
 
 # -- the configuration and the counts ------------------------------------------------
@@ -87,44 +92,23 @@ def test_counts_agree_with_the_program_at_the_configurations_sizes():
 # -- the metric files -------------------------------------------------------------------
 
 def test_the_cell_reports_what_the_issue_names():
-    names = [m["name"] for m in MOE_METRICS]
-    added = {f"{n}.moe" for n in (
-        "decode_step_device_ms", "prefill_step_device_ms", "device_idle_share", "tokens_per_engine_step",
-        "step_host_serial_ms", "step_launch_ms", "step_device_wait_ms", "step_readback_ms",
-        "kv_pool_peak_share", "preemptions", "recompiles_in_window", "decode_table_width_tokens",
-        "decode_gather_live_share", "replica_init_s", "param_init_s", "warmup_s",
-        "moe_experts_touched_share", "moe_load_imbalance", "moe_rows_per_expert", "moe_ffn_time_share",
-        # the rest of a step's host time (the sampler runs over a vocabulary of 50304), and the prefill
-        # half of the moe account
-        "step_schedule_ms", "step_sample_ms", "step_emit_ms", "moe_rows_per_expert_prefill",
-    )}
-    # each there once. The set of ``.moe`` names is not closed and no place in a list is the last: a later
+    names = [m["name"] for m in BENCH["per_layer"]]
+    # each there once. The cell's readings are not a closed set and no place in a list is the last: a later
     # PR appends its own (``test_perfbench_append.py``)
-    assert len(added) == 24 and all(names.count(name) == 1 for name in added)
+    assert len(set(READINGS)) == 24 and all(names.count(name) == 1 for name in READINGS)
     e2e = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tokens_per_s")
     assert e2e["workloads"].count(CELL) == 1
     cell = cells.cell(BENCH, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "chat-offline", 1)
 
 
-@pytest.mark.parametrize("entry", MOE_METRICS, ids=lambda m: m["name"])
-def test_each_moe_metric_file_agrees_with_its_entry(entry):
-    spec = cells.layer_metric_spec(entry["name"])
-    assert (spec["layer"], spec["unit"], spec["moves"]) == (entry["layer"], entry["unit"], entry["moves"])
-    assert entry["workloads"] == [CELL] and spec["kind"] in lm.READERS
-    assert entry["source"] == {"device_trace": "device_trace", "stats_delta": "program_counter"}[spec["kind"]]
-    start_up = entry["name"] in ("replica_init_s.moe", "param_init_s.moe", "warmup_s.moe")
+@pytest.mark.parametrize("name", READINGS)
+def test_each_reading_of_the_cell_has_one_entry_whose_file_reads_what_is_expected(name):
+    entry = readings.check(BENCH, CELL, name, readings.WANT[name])
+    start_up = name in ("replica_init_s", "param_init_s", "warmup_s")
     assert entry["moves"] == ("setup_s" if start_up else "serve_tokens_per_s")
-    twin = entry["name"][: -len(".moe")]
-    for other in (f"{twin}.batch", twin):  # a reader of a kind that exists, over the same counters
-        path = os.path.join(cells.HERE, "layer_metrics", f"{other}.json")
-        if os.path.exists(path):
-            old = cells.load_json(path)
-            same = {k: v for k, v in old.items() if k not in ("moves", "what")}
-            assert {k: spec[k] for k in same} == same
-            break
-    else:
-        assert entry["name"].startswith("moe_")
+    if name.endswith(".moe"):  # PR 27's own: this cell is the first the entry names
+        assert entry["workloads"][0] == CELL
 
 
 def _snapshot(launches, assignments, touched, max_load, mean_load, slots_per_launch=12 * 64, prefill=(0, 0)):
@@ -242,17 +226,17 @@ def _rehearse(family, tmp_path, trace):
     return cell, out
 
 
-def test_the_rehearsal_of_the_cell_prints_every_moe_metric(cluster, tmp_path):
+def test_the_rehearsal_of_the_cell_prints_every_reading(cluster, tmp_path):
     cell, out = _rehearse("olmoe", tmp_path, trace=True)
     assert out["correct"] is True
     line = bench_run.result_line(BENCH, cell, out, True)
     printed = set(line["metrics"])
-    assert {m["name"] for m in MOE_METRICS} - DEVICE_OPS <= printed
+    assert set(READINGS) - DEVICE_OPS <= printed
     assert "peak_hbm_gb" in printed  # no workloads key: every cell reports it
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert 0.0 < value["moe_experts_touched_share.moe"] <= 100.0
     assert value["moe_load_imbalance.moe"] >= 1.0 and value["moe_rows_per_expert.moe"] >= 1.0
-    assert value["recompiles_in_window.moe"] == 0.0 and value["preemptions.moe"] == 0.0
+    assert value["recompiles_in_window.moe"] == 0.0 and value["preemptions.batch"] == 0.0
     end = out["observed"].stats_end["moe"]
     assert end["decode"]["launches"] > 0 and end["prefill"]["launches"] > 0
     assert end["decode"]["assignments"] <= 4 * 2 * 2 * end["decode"]["launches"]  # slots x k x layers

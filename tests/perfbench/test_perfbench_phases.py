@@ -60,10 +60,13 @@ def _new_for(cell):
 def test_new_metric_file_agrees_with_its_entry(name):
     layer, unit, moves, where, key = NEW[name]
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry == {
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": name, "unit": unit, "better": "lower", "source": "program_counter",
-        "layer": layer, "moves": moves, "workloads": where,
+        "layer": layer, "moves": moves,
     }
+    # the cells ISSUE 24 named, in that order, from the list's start. What follows them is not
+    # pinned: a later cell whose program has the counter JOINS the entry (it brings no copy)
+    assert entry["workloads"][: len(where)] == where
     spec = cells.layer_metric_spec(name)
     assert (spec["layer"], spec["unit"], spec["moves"]) == (layer, unit, moves)
     assert spec["kind"] == "stats_delta" and spec["key"] == key

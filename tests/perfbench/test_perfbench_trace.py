@@ -62,15 +62,57 @@ def test_shares_by_operation_name():
     assert tr.exposed_share_pct(t, "reduce-scatter") == pytest.approx(0.0)
 
 
-def test_idle_gaps_go_to_the_innermost_host_span():
+def test_an_idle_gap_is_divided_among_the_innermost_host_spans():
     t = _hand_made()
     gaps = dict(tr.idle_gaps(t))
-    # 10-12 ms: nothing on the host; 20-30 ms: np.asarray covers 90%, "outer" all of it
-    # but a span ten times longer: the tie on share (rounded) goes to the shorter
-    assert gaps["unattributed"] == pytest.approx(0.002)
-    assert gaps["outer"] + gaps.get("np.asarray_jax.Array_", 0.0) == pytest.approx(0.010)
+    # 10-12 ms: nothing on the host. 20-30 ms, one thread: np.asarray is innermost until 29.5,
+    # step_b 29.6-29.9, and "outer", which covers all of it, keeps only what no child has
+    assert gaps == {
+        "unattributed": pytest.approx(0.002), "np.asarray_jax.Array_": pytest.approx(0.0095),
+        "PjitFunction_step_b_": pytest.approx(0.0003), "outer": pytest.approx(0.0002),
+    }
     top = tr.top_device_ops(t, 2)
     assert [n for n, _ in top] == ["fusion.1", "custom-call.7"]
+
+
+def _one_gap(*threads, gap=(10.0, 15.5)):
+    """A device busy 0-10 ms and again from the gap's end, and host threads
+    of ``[name, start_ms, duration_ms]`` events."""
+    ops = [["%fusion.1 = f32[] fusion()", 0.0, gap[0] * MS], ["%fusion.2 = f32[] fusion()", gap[1] * MS, 10 * MS]]
+    lines = [{"name": f"thread-{i}", "events": [[n, s * MS, d * MS] for n, s, d in events]}
+             for i, events in enumerate(threads)]
+    return {"planes": [{"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": ops}]},
+                       {"name": "/host:CPU", "lines": lines}]}
+
+
+def test_consecutive_spans_under_one_gap_each_get_their_own_share():
+    """A serving step's serial part: three spans in a row under one idle gap
+    of 5.5 ms. The old rule gave all 5.5 to the second, which overlaps most."""
+    step = [["engine.step", 9.0, 8.0],  # the parent of the three: nothing of the gap is its own
+            ["engine.schedule", 10.0, 1.0], ["engine.launch", 11.0, 2.3], ["engine.readback", 13.3, 2.2]]
+    gaps = dict(tr.idle_gaps(_one_gap(step)))
+    assert gaps == {"engine.schedule": pytest.approx(0.001), "engine.launch": pytest.approx(0.0023),
+                    "engine.readback": pytest.approx(0.0022)}
+    assert sum(gaps.values()) == pytest.approx(0.0055)
+
+
+def test_a_gap_goes_to_the_busier_thread_alone_and_the_rest_is_unattributed():
+    step = [["engine.schedule", 10.0, 1.0], ["engine.launch", 11.5, 3.0]]  # covers 4 of 5.5 ms
+    other = [["asyncio.select", 9.0, 3.0], ["asyncio.send", 14.9, 2.0]]    # covers 2.6
+    for threads in ((step, other), (other, step)):  # whichever the trace lists first
+        gaps = dict(tr.idle_gaps(_one_gap(*threads)))
+        assert gaps == {"engine.schedule": pytest.approx(0.001), "engine.launch": pytest.approx(0.003),
+                        "unattributed": pytest.approx(0.0015)}
+    # under the floor a gap is not looked at, whoever was busy in it
+    short = dict(tr.idle_gaps(_one_gap(step, gap=(10.0, 10.04))))
+    assert short == {"shorter_gaps_not_looked_at": pytest.approx(0.00004)}
+
+
+def test_spans_that_begin_together_or_overlap_without_nesting_are_still_divided_once():
+    # two spans begin together: the shorter is the inner one; "b" begins inside "a" and outlives it
+    thread = [["a", 10.0, 3.0], ["a.inner", 10.0, 1.0], ["b", 12.0, 3.5]]
+    gaps = dict(tr.idle_gaps(_one_gap(thread)))
+    assert gaps == {"a.inner": pytest.approx(0.001), "a": pytest.approx(0.001), "b": pytest.approx(0.0035)}
 
 
 def test_a_trace_without_device_operations_is_refused():
@@ -102,5 +144,7 @@ def test_reduction_on_the_recorded_trace(recorded):
     # what the chip showed in every run of PR 23: one decode step is 42 ms on the device
     assert got["decode"] == pytest.approx(42.0, abs=0.5)
     assert got["prefill"] == pytest.approx(30.2, abs=0.5)  # the one chunk in this piece: the 256 bucket
-    gaps = tr.idle_gaps(recorded)
+    gaps = tr.idle_gaps(recorded, n=1000)
     assert gaps[0][0] == "np.asarray_jax.Array_"  # the host reading logits and sampling
+    # divided, not awarded: the rows still sum to the first device's idle time, as under the rule before
+    assert sum(v for _, v in gaps) == pytest.approx(want["window_s"] - want["busy_s"])  # 0.2091 s: one device

@@ -1,9 +1,9 @@
 """The ``xing4`` family and its cell without a chip: the configuration file
 against the catalog row and its ``BENCHMARK.json`` entry, the family's
-counts against the program's at the configuration's sizes, every ``.mla``
-metric file against its entry and its twin, the rehearsal of
-``mla-longdoc-batch`` printing every ``.mla`` metric that needs no device
-operation, and twin families whose reference is another model reading
+counts against the program's at the configuration's sizes, every per-layer
+reading of the cell against the ONE entry that reads it (``readings.py``),
+the rehearsal of ``mla-longdoc-batch`` printing every one of those readings
+that needs no device operation, and twin families whose reference is another model reading
 ``correct`` false. No number printed here is a speed."""
 
 import os
@@ -19,6 +19,7 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 sys.path.insert(0, HERE)
 
+import readings  # noqa: E402
 import rehearsal  # noqa: E402
 from perfbench import families  # noqa: E402
 from perfbench import run as bench_run  # noqa: E402
@@ -27,26 +28,27 @@ from perfbench.harness import cells, layer_metrics as lm  # noqa: E402
 BENCH = cells.benchmark()
 CELL = "mla-longdoc-batch"
 CONFIG = "xing4.0-29b-a4b-ep8"
-MLA_METRICS = [m for m in BENCH["per_layer"] if m["name"].endswith(".mla")]
 #: what was there before PR 31, in its order: the place of each is still held, what follows PR 31's is not
 BEFORE_CONFIGS = ("mistral-7b-v0.3-16l", "codestral-22b-v0.1-8l-fsdp4", "olmoe-1b-7b-0125-12l")
 BEFORE_CELLS = ("chat-paced", "chat-offline", "train-fsdp4-2k", "longprompt-batch", "moe-chat-offline")
-#: the ``.moe`` readers PR 31 twinned under ``.mla``, as literals: a ``.moe`` metric added later is no business of this cell's
-MOE_TWINS = (
-    "decode_step_device_ms", "prefill_step_device_ms", "device_idle_share", "tokens_per_engine_step",
-    "step_host_serial_ms", "step_launch_ms", "step_device_wait_ms", "step_readback_ms",
-    "kv_pool_peak_share", "preemptions", "recompiles_in_window", "decode_table_width_tokens",
-    "decode_gather_live_share", "replica_init_s", "param_init_s", "warmup_s",
-    "moe_experts_touched_share", "moe_load_imbalance", "moe_rows_per_expert", "moe_ffn_time_share",
-    "step_schedule_ms", "step_sample_ms", "step_emit_ms", "moe_rows_per_expert_prefill",
-)
-NEW_COUNTERS = {"moe_held_assignment_share.mla", "moe_bias_changed_share.mla", "kv_bytes_per_token.mla"}
-#: the engine's hold-and-wake path runs on every step of this cell too; ``moe-chat-offline`` reads
-#: the ``.batch`` entries (``test_perfbench_wakes.py`` holds their lists to literals), so the twins
-#: here are the ``.batch`` files'
-WAKES = {"wakes_after_launch_share.mla", "wake_hold_ms.mla"}
+#: the 24 readings ``moe-chat-offline`` has that PR 31 gave this cell too, a literal list (a reading that cell
+#: gets later is no business of this one's). Until PR 37 each was a twin under ``.mla``; now the cell is
+#: listed by the entry that already read the counter
+SHARED = readings.MOE_CHAT_OFFLINE
+#: PR 31's own counters -> what each one's file must hold. ``.mla`` stays on them: this cell is their first
+NEW_COUNTERS = {
+    "moe_held_assignment_share.mla": readings.WANT["moe_held_assignment_share.mla"],
+    "moe_bias_changed_share.mla": {"kind": "stats_delta", "key": ["moe", "prefill", "bias_changed"],
+                                   "per": ["moe", "prefill", "assignments"], "scale": 400.0},
+    "kv_bytes_per_token.mla": readings.WANT["kv_bytes_per_token.mla"],
+}
+#: the engine's hold-and-wake path runs on every step of this cell too
+WAKES = ["wakes_after_launch_share.batch", "wake_hold_ms.batch"]
+READINGS = SHARED + list(NEW_COUNTERS) + WAKES
+#: the kernels this cell's trace names: PR 32's flash kernel over a prefill chunk, PR 36's over latent rows
+KERNELS = ["latent_flash_time_share.longdoc", "latent_rows_time_share"]
 #: read from the DEVICE's operations in the trace: the CPU rehearsal's trace has host threads only
-DEVICE_OPS = {"moe_ffn_time_share.mla", "decode_step_device_ms.mla", "prefill_step_device_ms.mla"}
+DEVICE_OPS = {"moe_ffn_time_share.moe", "decode_step_device_ms.batch", "prefill_step_device_ms.batch"}
 
 ROW = {  # the catalog row's config (model-configs guide), every key under its own name
     "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 2, "hidden_act": "silu",
@@ -122,11 +124,10 @@ def test_counts_agree_with_the_program_at_the_configurations_sizes():
 
 # -- the metric files -------------------------------------------------------------------
 
-def test_the_cell_reports_the_moe_sets_twins_the_wakes_and_three_new_counters():
-    added = {f"{n}.mla" for n in MOE_TWINS} | NEW_COUNTERS | WAKES
+def test_the_cell_reports_the_moe_cells_readings_the_wakes_and_three_new_counters():
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert len(added) == 29 and all(names.count(name) == 1 for name in added)  # each there once; more may follow
-    assert names.index("paged_attn_time_share.batch") < min(names.index(name) for name in added)
+    assert len(set(READINGS)) == 29 and all(names.count(name) == 1 for name in READINGS)  # each once; more may follow
+    assert names.index("paged_attn_time_share.batch") < min(names.index(name) for name in NEW_COUNTERS)
     e2e = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tokens_per_s")
     assert e2e["workloads"].count(CELL) == 1
     cell = cells.cell(BENCH, CELL)
@@ -137,28 +138,15 @@ def test_the_cell_reports_the_moe_sets_twins_the_wakes_and_three_new_counters():
     assert traffic["lengths"]["pairing_seed"] == 23 and traffic["lead_in_seconds"] == 8.0
 
 
-@pytest.mark.parametrize("entry", MLA_METRICS, ids=lambda m: m["name"])
-def test_each_mla_metric_file_agrees_with_its_entry_and_its_twin(entry):
-    spec = cells.layer_metric_spec(entry["name"])
-    assert (spec["layer"], spec["unit"], spec["moves"]) == (entry["layer"], entry["unit"], entry["moves"])
-    assert entry["workloads"] == [CELL] and spec["kind"] in lm.READERS
-    assert entry["source"] == {"device_trace": "device_trace", "stats_delta": "program_counter"}[spec["kind"]]
-    start_up = entry["name"] in ("replica_init_s.mla", "param_init_s.mla", "warmup_s.mla")
+@pytest.mark.parametrize("name", READINGS + KERNELS)
+def test_each_reading_of_the_cell_has_one_entry_whose_file_reads_what_is_expected(name):
+    entry = readings.check(BENCH, CELL, name, NEW_COUNTERS.get(name) or readings.WANT[name])
+    start_up = name in ("replica_init_s", "param_init_s", "warmup_s")
     assert entry["moves"] == ("setup_s" if start_up else "serve_tokens_per_s")
-    if entry["name"] in NEW_COUNTERS:
-        assert spec["kind"] == "stats_delta" and spec["key"][0] in ("moe", "kv_layout")
-        return
-    base, of = entry["name"][: -len(".mla")], ".batch" if entry["name"] in WAKES else ".moe"
-    path = os.path.join(cells.HERE, "layer_metrics", base + of + ".json")
-    if not os.path.exists(path):  # a later PR's own counter under this suffix: it has no twin to agree with
-        assert base not in MOE_TWINS and entry["name"] not in WAKES
-        return
-    twin = cells.load_json(path)
-    same = {k: v for k, v in twin.items() if k != "what"}
-    assert {k: spec[k] for k in same} == same  # a reader of a kind that exists, over the same counters
-    moe_entry = next(m for m in BENCH["per_layer"] if m["name"] == base + of)
-    assert {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")} == \
-        {k: moe_entry[k] for k in ("unit", "better", "source", "layer", "moves")}
+    if name in NEW_COUNTERS or name in KERNELS:  # this cell is the first the entry names
+        assert entry["workloads"][0] == CELL
+    else:  # a joined entry: the cells that were there come first
+        assert entry["workloads"].index("moe-chat-offline") < entry["workloads"].index(CELL)
 
 
 def _snapshot(assignments, held, changed, prefill):
@@ -272,18 +260,18 @@ def _rehearse(family, tmp_path, trace):
     return cell, out
 
 
-def test_the_rehearsal_of_the_cell_prints_every_mla_metric(cluster, tmp_path):
+def test_the_rehearsal_of_the_cell_prints_every_reading(cluster, tmp_path):
     cell, out = _rehearse("xing4", tmp_path, trace=True)
     assert out["correct"] is True
     line = bench_run.result_line(BENCH, cell, out, True)
     printed = set(line["metrics"])
-    assert {m["name"] for m in MLA_METRICS} - DEVICE_OPS <= printed
+    assert set(READINGS) - DEVICE_OPS <= printed
     assert "peak_hbm_gb" in printed  # no workloads key: every cell reports it
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert value["kv_bytes_per_token.mla"] == 4 * (16 + 8) * 4  # 4 layers, a row of 24 float32
     assert 20.0 < value["moe_held_assignment_share.mla"] < 80.0  # 4 of 8 held at the toy sizes
     assert 0.0 <= value["moe_bias_changed_share.mla"] <= 200.0  # the toy routes 2 a token, the scale says 4
-    assert value["recompiles_in_window.mla"] == 0.0 and value["preemptions.mla"] == 0.0
+    assert value["recompiles_in_window.moe"] == 0.0 and value["preemptions.batch"] == 0.0
     end = out["observed"].stats_end
     assert end["kv_layout"]["kind"] == "latent"
     moe = end["moe"]

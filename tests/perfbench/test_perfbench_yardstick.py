@@ -1,6 +1,8 @@
 """The benchmark's yardstick without a chip: the schedule, the estimators,
 the peaks, each family's counts, the layer-metric readers, and
-BENCHMARK.json against the files it names. Seconds, no cluster, no JAX."""
+BENCHMARK.json against the files it names. Seconds, no cluster; JAX only
+where a family's toy replica is built on the CPU for its ``engine_stats()``
+(``toy_engine_stats`` of ``rehearsal.py``: the (entry, cell) pairs)."""
 
 import json
 import math
@@ -11,8 +13,12 @@ import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
+sys.path.insert(0, HERE)
+
+from rehearsal import toy_engine_stats  # noqa: E402
 
 from perfbench import families  # noqa: E402
 from perfbench.harness import cells, layer_metrics as lm, peaks, schedule as sch, stats  # noqa: E402
@@ -322,6 +328,62 @@ def test_every_cell_finds_its_files_and_its_arrows(cell):
         assert spec["kind"] in lm.READERS
         assert (spec["layer"], spec["unit"], spec["moves"]) == (m["layer"], m["unit"], m["moves"])
         assert m["moves"] in e2e, f"{m['name']} moves {m['moves']}, which {cell} does not report"
+
+
+# -- one entry a reader: a cell JOINS the entry that reads its counter, it brings no copy (PR 37) -----
+
+PROSE = ("what", "why")
+
+
+def _reader(entry):
+    """What an entry reads and which way it points: its file without the
+    prose, and the entry without its name and its cells. Two entries that
+    agree in this are the SAME reader, whatever their names."""
+    spec = cells.layer_metric_spec(entry["name"])
+    return json.dumps([{k: v for k, v in spec.items() if k not in PROSE},
+                       [entry[k] for k in ("unit", "better", "moves", "layer", "source")]], sort_keys=True)
+
+
+def test_no_two_entries_are_the_same_reader_and_every_file_has_its_entry():
+    """Until PR 37 a family brought a copy of every reader under a suffix of
+    its own (63 of 128 entries were copies, and the contract's 128 were
+    full). A later cell appends its NAME to the ``workloads`` of the entry
+    that is there; an entry is new only where its reader is."""
+    by_reader = {}
+    for entry in BENCH["per_layer"]:
+        by_reader.setdefault(_reader(entry), []).append(entry["name"])
+    copies = [names for names in by_reader.values() if len(names) > 1]
+    assert not copies, f"the same reader under several names (join the first entry's workloads instead): {copies}"
+    files = sorted(f[: -len(".json")] for f in os.listdir(os.path.join(cells.HERE, "layer_metrics")))
+    assert files == sorted(m["name"] for m in BENCH["per_layer"])  # every file one entry, every entry its file
+    assert len(BENCH["per_layer"]) < 128  # the contract's limit: room is left for the next reader
+    for entry in BENCH["per_layer"]:
+        listed = entry.get("workloads", [])
+        assert len(set(listed)) == len(listed) and set(listed) <= {w["name"] for w in BENCH["workloads"]}
+
+
+PAIRS = [(m["name"], w["name"]) for w in BENCH["workloads"] for m in cells.metrics_of(BENCH, w["name"], "per_layer")]
+
+
+@pytest.mark.parametrize("name, cell", PAIRS, ids=[f"{n}@{c}" for n, c in PAIRS])
+def test_each_cell_an_entry_lists_reports_its_arrow_and_has_its_counter(name, cell):
+    """A cell that joins an entry must be judged by the end-to-end metric the
+    entry moves, and its program must keep the counter the entry digs for:
+    ``engine_stats()`` of the family's replica at the toy sizes, or, in a
+    training cell, what ``train_cell.run`` hands the readers."""
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    reported = {m["name"] for m in cells.metrics_of(BENCH, cell, "end_to_end")}
+    assert entry["moves"] in reported, f"{name} moves {entry['moves']}, which {cell} does not report"
+    spec = cells.layer_metric_spec(name)
+    if spec["kind"] != "stats_delta":
+        return
+    w = cells.cell(BENCH, cell)
+    if cells.traffic_of(w["traffic"])["kind"] == "train_job":
+        stats = {"device": {"peak_bytes_in_use": 0}}  # perfbench/harness/train_cell.py::run
+    else:
+        stats = toy_engine_stats(w["config"])
+    for path in (spec.get("key"), spec.get("per")):
+        assert path is None or lm._dig(stats, path) is not None, f"{cell}'s engine_stats() has no {path}"
 
 
 def test_every_file_under_paths_has_a_permitted_name():
