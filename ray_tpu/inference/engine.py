@@ -75,6 +75,16 @@ STEP_PHASES = (
     "bookkeeping", "loop_wait",
 )
 
+#: the parts of those phases (``stats()["step_parts"]["<phase>_<part>_s"]``,
+#: annotation ``engine.<phase>.<part>`` nested in the phase's): accounts of
+#: their own, never in a leaf; what they leave of a phase is its self time
+STEP_PARTS = (
+    "schedule.drain", "schedule.admit", "schedule.plan",
+    "launch.rows", "launch.inputs", "launch.call",
+    "readback.logits", "readback.loads",
+    "emit.commit", "emit.deliver",
+)
+
 logger = logging.getLogger(__name__)
 
 # -- replica chaos (util/chaos.py::ReplicaFaultPlan) -------------------------
@@ -387,7 +397,7 @@ class InferenceEngine:
         #: the step account: where the step-loop thread's time goes, phase
         #: by phase; _step() hands it to the runner's calls, which add
         #: their launch / device_wait / readback
-        self._clock = timeline.PhaseClock("engine", STEP_PHASES)
+        self._clock = timeline.PhaseClock("engine", STEP_PHASES, STEP_PARTS)
         state_slots = self._state_slots(model_cfg, ec)
         self.runner = PagedModelRunner(
             model_cfg,
@@ -1075,15 +1085,16 @@ class InferenceEngine:
     def _step(self, t0_us: float) -> bool:
         clock = self._clock
         with clock.phase("schedule", step=self.total_steps):
-            if self._draining and self._drain_deadline is not None and self._drain_deadline.expired:
-                self._fail_all(
-                    RequestFailedError("engine drain grace expired mid-generation")
-                )
-            if self._migrate_on_drain:
-                self._migrate_inflight()
-            did_import = self._drain_kv_imports()
-            self._drain_tier_spills()
-            plan = self.scheduler.schedule()
+            with clock.part("drain"):
+                if self._draining and self._drain_deadline is not None and self._drain_deadline.expired:
+                    self._fail_all(
+                        RequestFailedError("engine drain grace expired mid-generation")
+                    )
+                if self._migrate_on_drain:
+                    self._migrate_inflight()
+                did_import = self._drain_kv_imports()
+                self._drain_tier_spills()
+            plan = self.scheduler.schedule(clock)
             for req in plan.reaped:
                 # every reap here is a deadline expiry (queued or running) —
                 # a fault-cost class the SLO report breaks out explicitly
@@ -1118,7 +1129,7 @@ class InferenceEngine:
                     req.pending_cow = []
             if req.prefill_started_at is None:
                 req.prefill_started_at = time.monotonic()
-            with clock.phase("launch"):
+            with clock.phase("launch"), clock.part("rows"):
                 row = self.blocks.table_row(
                     req.request_id, self.runner.max_blocks_per_seq
                 )
@@ -1135,7 +1146,7 @@ class InferenceEngine:
                 if not req.prefill_only:
                     with clock.phase("sample"):
                         token = self._sample(req, logits)
-                with clock.phase("emit"):
+                with clock.phase("emit"), clock.part("commit"):
                     # the prompt's K/V is fully written: index its full
                     # blocks so later requests sharing the prefix skip them
                     self.blocks.register_prefix(req.request_id, prompt)
@@ -1179,7 +1190,7 @@ class InferenceEngine:
             # the two are two spans and not 2 x slots slivers; the tokens
             # and their order are those of sampling and emitting in turn
             if plain:
-                with clock.phase("launch"):
+                with clock.phase("launch"), clock.part("rows"):
                     toks = [r.generated[-1] for r in plain]
                     poss = [r.context_len - 1 for r in plain]
                     rows = [
@@ -1201,11 +1212,11 @@ class InferenceEngine:
                         sampled = [int(t) for t in logits]
                     else:
                         sampled = [self._sample(req, lg) for req, lg in zip(plain, logits)]
-                with clock.phase("emit"):
+                with clock.phase("emit"), clock.part("commit"):
                     for req, token in zip(plain, sampled):
                         self._emit_token(req, token)
             if spec_slots:
-                with clock.phase("launch"):
+                with clock.phase("launch"), clock.part("rows"):
                     windows = [[r.generated[-1]] + d for r, d in spec_slots]
                     rows = [
                         self.blocks.table_row(
@@ -1220,7 +1231,7 @@ class InferenceEngine:
                         self._spec_sample(req, drafts, logits)
                         for (req, drafts), logits in zip(spec_slots, all_logits)
                     ]
-                with clock.phase("emit"):
+                with clock.phase("emit"), clock.part("commit"):
                     for (req, drafts), tokens in zip(spec_slots, sampled):
                         self._spec_commit(req, drafts, tokens)
         with clock.phase("bookkeeping"):
@@ -2029,9 +2040,11 @@ class InferenceEngine:
 
     def _wake(self, where: str) -> None:
         """:meth:`_deliver_held` on the step account, for a caller that is
-        in no phase: the wake-ups are ``emit``'s second half."""
+        in no phase: the wake-ups are ``emit``'s second half, its part
+        ``deliver`` (the first, ``commit``, runs while the device is idle;
+        this one, after a launch, beside it)."""
         if self._held:
-            with self._clock.phase("emit"):
+            with self._clock.phase("emit"), self._clock.part("deliver"):
                 self._deliver_held(where)
 
     def _wake_after_launch(self) -> None:
@@ -2224,6 +2237,11 @@ class InferenceEngine:
             "tokens_per_s": round(self._tokens_per_s(), 2),
             "ttft": {k: round(v, 6) for k, v in self._ttft_quantiles().items()},
             "step_phases": self._step_phases(),
+            # the phases' parts: accounts of their own, in no leaf
+            "step_parts": {
+                f"{name.replace('.', '_')}_s": seconds
+                for name, seconds in self._clock.parts_total.items()
+            },
             # where the step thread's puts were delivered, and how long held
             "wakes": dict(self._wakes),
             # how wide the decode and verify launches gathered (the target
@@ -2265,11 +2283,16 @@ class InferenceEngine:
         """The step account: seconds of the step-loop thread's life per
         leaf phase (monotonic, engine lifetime: a reader differences two
         calls), their sum ``wall_s``, and ``host_serial_s``, the part in
-        which the device waits for the host."""
-        total = dict(self._clock.total)
+        which the device waits for the host. Outside that sum: the longest
+        step so far (``longest_wall_s``, without its ``loop_wait``) and the
+        ``device_wait`` of that same step."""
+        clock = self._clock
+        total = dict(clock.total)
         out = {f"{name}_s": seconds for name, seconds in total.items()}
         out["wall_s"] = sum(total.values())
         out["host_serial_s"] = out["wall_s"] - total["device_wait"] - total["loop_wait"]
+        out["longest_wall_s"] = clock.longest_wall_s
+        out["longest_device_wait_s"] = clock.longest_device_wait_s
         return out
 
     def routing_stats(self) -> Dict[str, Any]:

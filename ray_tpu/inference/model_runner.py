@@ -509,13 +509,15 @@ class PagedModelRunner:
         with clock.phase(
             "launch", program="paged_prefill_step", bucket=bucket, path=path.name,
         ):
-            padded = np.zeros(bucket, np.int32)
-            padded[:true_len] = tokens
-            row = np.asarray(block_row, np.int32)
-            out = self._step(
-                self._run, "paged_prefill_step", self._prefill_jit,
-                padded, row, np.int32(ctx_len), np.int32(true_len), slots=np.int32(slot),
-            )
+            with clock.part("inputs"):
+                padded = np.zeros(bucket, np.int32)
+                padded[:true_len] = tokens
+                row = np.asarray(block_row, np.int32)
+            with clock.part("call"):
+                out = self._step(
+                    self._run, "paged_prefill_step", self._prefill_jit,
+                    padded, row, np.int32(ctx_len), np.int32(true_len), slots=np.int32(slot),
+                )
         return self._read(out, clock, "prefill", launched)
 
     def _read(
@@ -538,10 +540,12 @@ class PagedModelRunner:
         with clock.phase("device_wait"):
             logits.block_until_ready()
         with clock.phase("readback"):
-            host = np.asarray(logits)
+            with clock.part("logits"):
+                host = np.asarray(logits)
             if loads is not None:
-                counters = loads if isinstance(loads, dict) else {"load": loads}
-                self._account_moe(kind, {k: np.asarray(v) for k, v in counters.items()})
+                with clock.part("loads"):
+                    counters = loads if isinstance(loads, dict) else {"load": loads}
+                    self._account_moe(kind, {k: np.asarray(v) for k, v in counters.items()})
         return host
 
     def _account_moe(self, kind: str, counters: Dict[str, np.ndarray]) -> None:
@@ -632,18 +636,20 @@ class PagedModelRunner:
             bucket=f"{bbucket}x{cbucket}x{M * self.block_size}",
             path=self._path(cbucket).name,
         ):
-            tokens = np.zeros((bbucket, cbucket), np.int32)
-            tables = np.zeros((bbucket, M), np.int32)
-            ctx = np.zeros(bbucket, np.int32)
-            tl = np.zeros(bbucket, np.int32)
-            for i, w in enumerate(windows):
-                tokens[i, : len(w)] = w
-                tables[i] = block_rows[i][:M]
-                ctx[i] = ctx_lens[i]
-                tl[i] = len(w)
-            out = self._step(
-                self._run, "paged_verify_step", self._verify_jit, tokens, tables, ctx, tl
-            )
+            with clock.part("inputs"):
+                tokens = np.zeros((bbucket, cbucket), np.int32)
+                tables = np.zeros((bbucket, M), np.int32)
+                ctx = np.zeros(bbucket, np.int32)
+                tl = np.zeros(bbucket, np.int32)
+                for i, w in enumerate(windows):
+                    tokens[i, : len(w)] = w
+                    tables[i] = block_rows[i][:M]
+                    ctx[i] = ctx_lens[i]
+                    tl[i] = len(w)
+            with clock.part("call"):
+                out = self._step(
+                    self._run, "paged_verify_step", self._verify_jit, tokens, tables, ctx, tl
+                )
         out = self._read(out, clock, "decode", launched)
         return [out[i, : len(w)] for i, w in enumerate(windows)]
 
@@ -680,18 +686,20 @@ class PagedModelRunner:
             bucket=f"{bucket}x{M * self.block_size}",
             path=self._path(1).name,
         ):
-            t = np.zeros(bucket, np.int32)
-            p = np.zeros(bucket, np.int32)
-            bt = np.zeros((bucket, M), np.int32)
-            cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
-            t[:n] = tokens
-            p[:n] = positions
-            bt[:n] = np.asarray([row[:M] for row in block_rows], np.int32)
-            cl[:n] = ctx_lens
-            sl = np.zeros(bucket, np.int32)
-            if slots is not None:
-                sl[:n] = slots
-            out = self._step(
-                self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl, slots=sl
-            )
+            with clock.part("inputs"):
+                t = np.zeros(bucket, np.int32)
+                p = np.zeros(bucket, np.int32)
+                bt = np.zeros((bucket, M), np.int32)
+                cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
+                t[:n] = tokens
+                p[:n] = positions
+                bt[:n] = np.asarray([row[:M] for row in block_rows], np.int32)
+                cl[:n] = ctx_lens
+                sl = np.zeros(bucket, np.int32)
+                if slots is not None:
+                    sl[:n] = slots
+            with clock.part("call"):
+                out = self._step(
+                    self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl, slots=sl
+                )
         return self._read(out, clock, "decode", launched, picks=greedy)[:n]

@@ -27,6 +27,7 @@ Policies, all host-side and unit-testable without jax:
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -34,6 +35,12 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 from ray_tpu.inference.kv_cache import PagedBlockManager
+
+
+def _no_part(name: str):
+    """``clock.part`` for a ``schedule()`` that was handed no clock."""
+    return contextlib.nullcontext()
+
 
 # request lifecycle states
 QUEUED = "QUEUED"
@@ -347,80 +354,85 @@ class ContinuousBatchingScheduler:
         self.total_preempted += 1
         return True
 
-    def schedule(self) -> StepPlan:
+    def schedule(self, clock=None) -> StepPlan:
+        """The step's plan. ``clock``: the caller's ``PhaseClock``, inside an
+        open phase, if it keeps one: admission and the plan are then its
+        parts ``admit`` and ``plan``; with none the scheduler keeps no time."""
+        part = clock.part if clock is not None else _no_part
         plan = StepPlan()
         with self._lock:
-            self._admit(plan.reaped)
+            with part("admit"):
+                self._admit(plan.reaped)
+            with part("plan"):
+                # deadline reaping for running work (budget exhausted mid-flight)
+                for req in list(self.running):
+                    if req.deadline is not None and getattr(req.deadline, "expired", False):
+                        self.running.remove(req)
+                        self.blocks.free(req.request_id)
+                        req.state = FAILED
+                        plan.reaped.append(req)
 
-            # deadline reaping for running work (budget exhausted mid-flight)
-            for req in list(self.running):
-                if req.deadline is not None and getattr(req.deadline, "expired", False):
-                    self.running.remove(req)
-                    self.blocks.free(req.request_id)
-                    req.state = FAILED
-                    plan.reaped.append(req)
+                # prefill chunks: oldest prefill-incomplete requests first
+                prefilling = sorted(
+                    (r for r in self.running if not r.prefill_done),
+                    key=lambda r: (-r.priority, r.arrival),
+                )
+                for req in prefilling[: self.max_prefills_per_step]:
+                    prompt = req.effective_prompt
+                    start = req.prefill_pos
+                    chunk = min(self.max_prefill_chunk, len(prompt) - start)
+                    plan.prefills.append((req, start, chunk))
 
-            # prefill chunks: oldest prefill-incomplete requests first
-            prefilling = sorted(
-                (r for r in self.running if not r.prefill_done),
-                key=lambda r: (-r.priority, r.arrival),
-            )
-            for req in prefilling[: self.max_prefills_per_step]:
-                prompt = req.effective_prompt
-                start = req.prefill_pos
-                chunk = min(self.max_prefill_chunk, len(prompt) - start)
-                plan.prefills.append((req, start, chunk))
-
-            # decode batch: fully-prefilled requests, highest priority /
-            # oldest first when the batch cap bites. Each needs this
-            # step's write position covered by a block — grow, preempting
-            # on exhaustion. A victim must never be something already in
-            # the plan: the engine would run it on freed (null) blocks.
-            planned_ids = {id(p[0]) for p in plan.prefills}
-            decodable = sorted(
-                (r for r in self.running if r.prefill_done),
-                key=lambda r: (-r.priority, r.arrival),
-            )
-            for req in decodable[: self.max_decode_batch]:
-                if req not in self.running:
-                    continue  # evicted by an earlier decode's growth
-                # the step writes KV at position context_len-1 (the token
-                # sampled LAST step): coverage of exactly context_len
-                # positions; the token emitted this step grows the table
-                # next step
-                need = req.context_len
-                # speculative slots want k extra positions (the verify
-                # window writes K/V at context_len-1 .. context_len+k-1).
-                # Opportunistic only: spec growth never preempts, and a
-                # dry pool degrades the slot to plain decode this step.
-                k = req.spec_k
-                if k > 0:
-                    if self.spec_k_live is not None:
-                        k = min(k, self.spec_k_live)
-                    k = min(k, req.max_new_tokens - len(req.generated) - 1)
-                    if self.spec_max_context is not None:
-                        k = min(k, self.spec_max_context - need)
-                    k = max(0, k)
-                req.spec_step_k = 0
-                if k > 0 and self.blocks.grow_to(req.request_id, need + k):
-                    req.spec_step_k = k
-                    plan.decodes.append(req)
-                    planned_ids.add(id(req))
-                    continue
-                grown = self.blocks.grow_to(req.request_id, need)
-                while not grown and self._preempt_one(req, planned_ids):
+                # decode batch: fully-prefilled requests, highest priority /
+                # oldest first when the batch cap bites. Each needs this
+                # step's write position covered by a block — grow, preempting
+                # on exhaustion. A victim must never be something already in
+                # the plan: the engine would run it on freed (null) blocks.
+                planned_ids = {id(p[0]) for p in plan.prefills}
+                decodable = sorted(
+                    (r for r in self.running if r.prefill_done),
+                    key=lambda r: (-r.priority, r.arrival),
+                )
+                for req in decodable[: self.max_decode_batch]:
+                    if req not in self.running:
+                        continue  # evicted by an earlier decode's growth
+                    # the step writes KV at position context_len-1 (the token
+                    # sampled LAST step): coverage of exactly context_len
+                    # positions; the token emitted this step grows the table
+                    # next step
+                    need = req.context_len
+                    # speculative slots want k extra positions (the verify
+                    # window writes K/V at context_len-1 .. context_len+k-1).
+                    # Opportunistic only: spec growth never preempts, and a
+                    # dry pool degrades the slot to plain decode this step.
+                    k = req.spec_k
+                    if k > 0:
+                        if self.spec_k_live is not None:
+                            k = min(k, self.spec_k_live)
+                        k = min(k, req.max_new_tokens - len(req.generated) - 1)
+                        if self.spec_max_context is not None:
+                            k = min(k, self.spec_max_context - need)
+                        k = max(0, k)
+                    req.spec_step_k = 0
+                    if k > 0 and self.blocks.grow_to(req.request_id, need + k):
+                        req.spec_step_k = k
+                        plan.decodes.append(req)
+                        planned_ids.add(id(req))
+                        continue
                     grown = self.blocks.grow_to(req.request_id, need)
-                if grown:
-                    plan.decodes.append(req)
-                    planned_ids.add(id(req))
-                # else: stalled this step — retried next step once a
-                # finishing request returns blocks
+                    while not grown and self._preempt_one(req, planned_ids):
+                        grown = self.blocks.grow_to(req.request_id, need)
+                    if grown:
+                        plan.decodes.append(req)
+                        planned_ids.add(id(req))
+                    # else: stalled this step — retried next step once a
+                    # finishing request returns blocks
 
-            if plan.prefills and plan.decodes:
-                self.steps_with_prefill_and_decode += 1
-            self.max_decode_batch_seen = max(
-                self.max_decode_batch_seen, len(plan.decodes)
-            )
+                if plan.prefills and plan.decodes:
+                    self.steps_with_prefill_and_decode += 1
+                self.max_decode_batch_seen = max(
+                    self.max_decode_batch_seen, len(plan.decodes)
+                )
         return plan
 
     # -- completion -------------------------------------------------------

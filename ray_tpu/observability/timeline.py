@@ -87,26 +87,71 @@ class PhaseClock:
     ``settle(since, rest)`` closes the account over ``[since, now]``:
     what no phase claimed of that wall time goes to ``rest`` and the lap
     is added to ``total``, so ``sum(total.values())`` is the wall time of
-    everything settled so far."""
+    everything settled so far.
 
-    def __init__(self, prefix: str, phases=()):
+    A phase has PARTS: ``with clock.part("rows"):`` inside an open phase
+    ``launch`` adds the block's seconds to ``clock.parts["launch.rows"]``
+    (``settle`` moves them into ``parts_total``) under a span
+    ``<prefix>.launch.rows`` nested in the phase's. A part's seconds are in
+    an account of their own, never in ``lap`` or ``total``: the leaves read
+    what they read without parts, and what a phase's parts leave of it is
+    its self time. Parts do not nest, and there is none outside a phase.
+
+    ``settle`` also keeps the longest lap so far without its ``loop_wait``
+    (``longest_wall_s``) and that same lap's ``device_wait``
+    (``longest_device_wait_s``): a window's means cannot tell one lap that
+    stood still from a loop that idles."""
+
+    def __init__(self, prefix: str, phases=(), parts=()):
         self.prefix = prefix
         self.lap: Dict[str, float] = dict.fromkeys(phases, 0.0)
         self.total: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        #: ``"<phase>.<part>"`` -> seconds since the last settle / settled
+        self.parts: Dict[str, float] = dict.fromkeys(parts, 0.0)
+        self.parts_total: Dict[str, float] = dict.fromkeys(parts, 0.0)
+        self.longest_wall_s = 0.0
+        self.longest_device_wait_s = 0.0
         #: perf_counter of the last settle: a loop resumes its account here
         self.settled_at = time.perf_counter()
+        self._open: Optional[str] = None  # the phase this thread is in
+        self._in_part = False
 
     def phase(self, name: str, **args) -> "_Phase":
         return _Phase(self, name, args)
 
+    def part(self, name: str) -> "_Part":
+        if self._open is None or self._in_part:
+            raise RuntimeError(
+                f"part {name!r} of clock {self.prefix!r} "
+                + ("inside another part" if self._in_part else "outside any phase")
+            )
+        return _Part(self, f"{self._open}.{name}")
+
     def settle(self, since: float, rest: str) -> None:
         now = time.perf_counter()
         lap, total = self.lap, self.total
-        lap[rest] = lap.get(rest, 0.0) + max(0.0, now - since - sum(lap.values()))
+        claimed = sum(lap.values())
+        unclaimed = max(0.0, now - since - claimed)
+        lap[rest] = lap.get(rest, 0.0) + unclaimed
+        wall = claimed + unclaimed - lap.get("loop_wait", 0.0)
+        if wall > self.longest_wall_s:
+            self.longest_wall_s = wall
+            self.longest_device_wait_s = lap.get("device_wait", 0.0)
         for name, seconds in lap.items():
             total[name] = total.get(name, 0.0) + seconds
             lap[name] = 0.0
+        parts, parts_total = self.parts, self.parts_total
+        for name, seconds in parts.items():
+            parts_total[name] = parts_total.get(name, 0.0) + seconds
+            parts[name] = 0.0
         self.settled_at = now
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    # sys.modules, not an import: the driver and the benchmark's own
+    # process stay off JAX
+    profiler = sys.modules.get("jax.profiler")
+    return profiler.TraceAnnotation(name, **args) if profiler is not None else None
 
 
 class _Phase:
@@ -115,24 +160,42 @@ class _Phase:
     def __init__(self, clock: PhaseClock, name: str, args: Dict[str, Any]):
         self._clock = clock
         self._name = name
-        # sys.modules, not an import: the driver and the benchmark's own
-        # process stay off JAX
-        profiler = sys.modules.get("jax.profiler")
-        self._span = (
-            profiler.TraceAnnotation(f"{clock.prefix}.{name}", **args)
-            if profiler is not None
-            else None
-        )
+        self._span = _annotation(f"{clock.prefix}.{name}", args)
 
     def __enter__(self) -> None:
         if self._span is not None:
             self._span.__enter__()
+        self._clock._open = self._name
         self._t0 = time.perf_counter()
 
     def __exit__(self, *exc) -> None:
         seconds = time.perf_counter() - self._t0
-        lap = self._clock.lap
-        lap[self._name] = lap.get(self._name, 0.0) + seconds
+        clock = self._clock
+        clock.lap[self._name] = clock.lap.get(self._name, 0.0) + seconds
+        clock._open = None
+        if self._span is not None:
+            self._span.__exit__(*exc)
+
+
+class _Part:
+    __slots__ = ("_clock", "_key", "_span", "_t0")
+
+    def __init__(self, clock: PhaseClock, key: str):
+        self._clock = clock
+        self._key = key
+        self._span = _annotation(f"{clock.prefix}.{key}", {})
+
+    def __enter__(self) -> None:
+        if self._span is not None:
+            self._span.__enter__()
+        self._clock._in_part = True
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self._t0
+        clock = self._clock
+        clock.parts[self._key] = clock.parts.get(self._key, 0.0) + seconds
+        clock._in_part = False
         if self._span is not None:
             self._span.__exit__(*exc)
 

@@ -78,7 +78,9 @@ def test_step_account_closes_and_is_monotonic(engine):
     engine.wait_idle()
     seen.append(engine.stats())
     last = seen[-1]["step_phases"]
-    assert set(last) == {f"{n}_s" for n in STEP_PHASES} | {"wall_s", "host_serial_s"}
+    assert set(last) == {f"{n}_s" for n in STEP_PHASES} | {
+        "wall_s", "host_serial_s", "longest_wall_s", "longest_device_wait_s",  # the last two: ISSUE 38
+    }
     assert last["wall_s"] > 0 and all(last[f"{n}_s"] > 0 for n in STEP_PHASES)
     # the account closes: the leaves sum to the loop's wall time
     assert _leaves(last) == pytest.approx(last["wall_s"], rel=0.02)
@@ -94,7 +96,8 @@ def test_step_account_closes_and_is_monotonic(engine):
     for a, b in zip(seen, seen[1:]):
         for group in ("step_phases", "request_stages", "wakes"):
             for key, value in a[group].items():
-                assert b[group][key] >= value, (group, key)
+                # one step's reading, not a sum: a longer step may have waited less
+                assert b[group][key] >= value or key == "longest_device_wait_s", (group, key)
         assert b["total_steps"] >= a["total_steps"]
 
 
@@ -282,7 +285,8 @@ def test_profiler_trace_holds_the_phases_and_no_enclosing_step(engine, tmp_path)
     # prefill: the chunk bucket; decode: batch bucket x table width in tokens
     assert {str(s["bucket"]) for s in launches} <= {"16", "32", "4x64"}
     # leaves: on the engine's thread no phase begins inside another
-    spans = sorted((s, s + d) for evs in events.values() for s, d, _ in evs)
+    # (their parts, ``engine.<phase>.<part>``, nest in them: tests/test_phase_parts.py)
+    spans = sorted((s, s + d) for name, evs in events.items() if name.count(".") == 1 for s, d, _ in evs)
     assert all(b[0] >= a[1] - 1 for a, b in zip(spans, spans[1:]))
     # the two clocks differ by a constant: the offset that lays the
     # timeline's engine_step events beside the trace
