@@ -7,6 +7,11 @@ and pushes them into per-request queues that :meth:`generate` drains —
 so tokens stream to the caller WHILE other requests keep decoding. The
 push waits until the NEXT launch is on its way (``_hold`` /
 ``_deliver_held``): the streams it wakes then run while the device does.
+The loop of a SATURATED engine leaves an all-greedy decode launch unread
+while it plans and launches the next step (all its programs before it waits
+for any), whose rows take their tokens from that launch's picks on the
+device (``_stays_unread``): the host's part of a step then runs beside the
+device's.
 
 Request lifecycle hooks the rest of the runtime:
 
@@ -170,6 +175,17 @@ class KvMigrationHandoff(RequestFailedError):
     (prompt + generated) into the cluster tier and handed the stream
     back: the router resumes it on a survivor, which faults the KV in
     instead of re-prefilling — client-invisible through the SeqGate."""
+
+
+@dataclass
+class _DecodeBatch:
+    """A plain decode launch and the requests of its rows, in row order."""
+
+    reqs: List[Request]
+    #: the runner's handle (``model_runner.Launched``): what a read waits for
+    launched: Any
+    #: every request greedy: the read gives the device's picks, not logits
+    greedy: bool
 
 
 @dataclass
@@ -459,6 +475,14 @@ class InferenceEngine:
         self._wakes = {
             "items": 0, "after_launch": 0, "at_idle": 0, "direct": 0, "held_s": 0.0,
         }
+        #: the one decode launch the step loop has not read yet (None: every
+        #: launch is read): its rows' tokens are in flight while the next
+        #: step is planned and launched (:meth:`_stays_unread`)
+        self._unread: Optional[_DecodeBatch] = None
+        #: plain decode launches, those launched while the one before was
+        #: unread, and results dropped before they reached a stream
+        #: (monotonic; stats()["decode_ahead"])
+        self._decode_ahead = {"launches": 0, "ahead": 0, "dropped": 0}
         # request id -> submitter's (trace_id, span_id): the step-loop
         # thread stamps per-request spans (admission→first-token,
         # admission→finish) under the serve caller's trace
@@ -1066,23 +1090,25 @@ class InferenceEngine:
         claimed is this step's bookkeeping.
 
         What the step commits reaches the requests' queues before it
-        returns, unless ``hold_wakes``: the step loop's own calls leave the
-        last launch's items held, for the next step to deliver after ITS
-        first launch."""
+        returns, and every program it launched has been read, unless
+        ``hold_wakes``: the step loop's own calls leave the last commits'
+        items held, for the next step to deliver after ITS launches, and on
+        a saturated engine the decode launch unread, for the next step to
+        read after them (:meth:`_stays_unread`)."""
         since = time.perf_counter()
         # timeline timestamps share the module's wall-clock epoch so
         # engine_step events merge with every other process's trace
         t0_us = timeline._now_us()
         did_work = False
         try:
-            did_work = self._step(t0_us)
+            did_work = self._step(t0_us, in_loop=hold_wakes)
             return did_work
         finally:
             if not hold_wakes:
                 self._wake("direct")
             self._clock.settle(since, "bookkeeping" if did_work else "schedule")
 
-    def _step(self, t0_us: float) -> bool:
+    def _step(self, t0_us: float, in_loop: bool) -> bool:
         clock = self._clock
         with clock.phase("schedule", step=self.total_steps):
             with clock.part("drain"):
@@ -1112,12 +1138,23 @@ class InferenceEngine:
             if not idle:
                 self._consult_replica_chaos(plan)
         if idle:
-            # no launch to wait for: nothing stays held
+            # nothing to launch: what the last step left unread is read now,
+            # and nothing stays held
+            read = self._read_unread()
             self._wake("at_idle")
-            return did_import or not plan.empty
+            return did_import or read or not plan.empty
         wake = self._wake_after_launch
 
-        n_prefill_tokens = 0
+        # While the last step's decode launch is unread the device has work
+        # queued, and every program of this step is launched before the
+        # thread waits for any: the chunk, then the decode batch right behind
+        # it (its plan never depended on the chunk's result; the device works
+        # through its queue in the order of the launches). With nothing
+        # unread the step runs in the order it always had, a chunk read
+        # before the decode batch goes out: an engine that is not looking
+        # ahead behaves as it did.
+        ahead = self._unread is not None
+        chunks: List[tuple] = []
         for req, start, chunk in plan.prefills:
             if req.pending_cow:
                 # prefix-cache COW: duplicate the shared block(s) BEFORE
@@ -1135,105 +1172,73 @@ class InferenceEngine:
                 )
                 prompt = req.effective_prompt
                 tokens = prompt[start : start + chunk]
-            logits = self.runner.prefill_chunk(
-                tokens, row, start, clock, wake, slot=self.blocks.slot_of(req.request_id)
+            launched = self.runner.launch_prefill(
+                tokens, row, start, clock, slot=self.blocks.slot_of(req.request_id)
             )
-            req.prefill_pos = start + chunk
-            n_prefill_tokens += chunk
-            if req.prefill_done and req.prefill_done_at is None:
-                req.prefill_done_at = time.monotonic()
-            if req.prefill_done:
-                if not req.prefill_only:
-                    with clock.phase("sample"):
-                        token = self._sample(req, logits)
-                with clock.phase("emit"), clock.part("commit"):
-                    # the prompt's K/V is fully written: index its full
-                    # blocks so later requests sharing the prefix skip them
-                    self.blocks.register_prefix(req.request_id, prompt)
-                    if self.engine_cfg.kv_tier_enabled and not req.prefill_only:
-                        # tier write-back trigger 1: the prompt's full
-                        # blocks become cluster-recoverable the moment they
-                        # exist — a replica killed one token later already
-                        # left its prefill in the tier
-                        self._tier_writeback_full_blocks(req, prompt, "prefill")
-                    if req.prefill_only:
-                        # KV-migration export: gather the full blocks to
-                        # host and hand the payload to the waiting exporter
-                        # — no token is ever sampled on this engine
-                        self._complete_prefill_export(req, prompt)
-                    else:
-                        req.state = DECODE
-                        self._emit_token(req, token)
+            chunks.append((req, prompt, start + chunk, launched))
+        n_prefill_tokens = 0 if ahead else self._commit_chunks(chunks)
 
-        if plan.decodes:
-            # speculative slots peel off the batch: each proposes drafts,
-            # then EVERY spec slot verifies in one batched target step
-            # (models.llama.paged_verify_step: B slots x k+1 positions
-            # per jit call). Slots whose proposer came up empty (no
-            # n-gram match, draft pool dry) ride the plain batched
-            # decode unchanged — speculation is an opportunistic
-            # throughput lever, never a dependency.
-            spec_slots: List[tuple] = []
-            plain: List[Request] = []
-            if self.spec is None:
-                plain = plan.decodes
-            else:
-                # proposing is deciding what this step runs: schedule's time
-                with clock.phase("schedule"):
-                    for r in plan.decodes:
-                        drafts = self._spec_propose(r) if r.spec_step_k > 0 else []
-                        if drafts:
-                            spec_slots.append((r, drafts))
-                        else:
-                            plain.append(r)
-            # each batch: sample every slot, then emit every slot, so that
-            # the two are two spans and not 2 x slots slivers; the tokens
-            # and their order are those of sampling and emitting in turn
-            if plain:
-                with clock.phase("launch"), clock.part("rows"):
-                    toks = [r.generated[-1] for r in plain]
-                    poss = [r.context_len - 1 for r in plain]
-                    rows = [
-                        self.blocks.table_row(
-                            r.request_id, self.runner.max_blocks_per_seq
-                        )
-                        for r in plain
-                    ]
-                    cls = [r.context_len for r in plain]
-                    slots = [self.blocks.slot_of(r.request_id) for r in plain]
-                    # an all-greedy batch reads back the device's picks (one
-                    # int a slot), not the logits: the same program either way
-                    greedy = all(r.temperature <= 0.0 for r in plain)
-                logits = self.runner.decode(
-                    toks, poss, rows, cls, clock, wake, slots=slots, greedy=greedy
-                )
-                with clock.phase("sample"):
-                    if greedy:  # the device's picks, one a slot
-                        sampled = [int(t) for t in logits]
+        # speculative slots peel off the batch: each proposes drafts,
+        # then EVERY spec slot verifies in one batched target step
+        # (models.llama.paged_verify_step: B slots x k+1 positions
+        # per jit call). Slots whose proposer came up empty (no
+        # n-gram match, draft pool dry) ride the plain batched
+        # decode unchanged — speculation is an opportunistic
+        # throughput lever, never a dependency.
+        spec_slots: List[tuple] = []
+        plain: List[Request] = []
+        if self.spec is None:
+            plain = plan.decodes
+        else:
+            # proposing is deciding what this step runs: schedule's time
+            with clock.phase("schedule"):
+                for r in plan.decodes:
+                    drafts = self._spec_propose(r) if r.spec_step_k > 0 else []
+                    if drafts:
+                        spec_slots.append((r, drafts))
                     else:
-                        sampled = [self._sample(req, lg) for req, lg in zip(plain, logits)]
-                with clock.phase("emit"), clock.part("commit"):
-                    for req, token in zip(plain, sampled):
-                        self._emit_token(req, token)
-            if spec_slots:
-                with clock.phase("launch"), clock.part("rows"):
-                    windows = [[r.generated[-1]] + d for r, d in spec_slots]
-                    rows = [
-                        self.blocks.table_row(
-                            r.request_id, self.runner.max_blocks_per_seq
-                        )
-                        for r, _ in spec_slots
-                    ]
-                    ctxs = [r.context_len - 1 for r, _ in spec_slots]
-                all_logits = self.runner.verify_batch(windows, rows, ctxs, clock, wake)
-                with clock.phase("sample"):
-                    sampled = [
-                        self._spec_sample(req, drafts, logits)
-                        for (req, drafts), logits in zip(spec_slots, all_logits)
-                    ]
-                with clock.phase("emit"), clock.part("commit"):
-                    for (req, drafts), tokens in zip(spec_slots, sampled):
-                        self._spec_commit(req, drafts, tokens)
+                        plain.append(r)
+        batch = self._launch_decode(plain) if plain else None
+        if batch is not None:
+            # the last commits go out while the device runs this step's
+            # programs; what the step commits from here on, before each of
+            # its later waits
+            wake()
+        if ahead:
+            # the decode launch the last step left unread: its tokens were in
+            # flight while this step was planned and launched
+            self._read_unread()
+            n_prefill_tokens = self._commit_chunks(chunks)
+
+        # each batch: sample every slot, then emit every slot, so that
+        # the two are two spans and not 2 x slots slivers; the tokens
+        # and their order are those of sampling and emitting in turn
+        if batch is not None:
+            if in_loop and not spec_slots and self._stays_unread(plan, batch):
+                for row, req in enumerate(batch.reqs):
+                    req.in_flight = row
+                self._unread = batch
+            else:
+                self._read_decode(batch)
+        if spec_slots:
+            with clock.phase("launch"), clock.part("rows"):
+                windows = [[r.generated[-1]] + d for r, d in spec_slots]
+                rows = [
+                    self.blocks.table_row(
+                        r.request_id, self.runner.max_blocks_per_seq
+                    )
+                    for r, _ in spec_slots
+                ]
+                ctxs = [r.context_len - 1 for r, _ in spec_slots]
+            all_logits = self.runner.verify_batch(windows, rows, ctxs, clock, wake)
+            with clock.phase("sample"):
+                sampled = [
+                    self._spec_sample(req, drafts, logits)
+                    for (req, drafts), logits in zip(spec_slots, all_logits)
+                ]
+            with clock.phase("emit"), clock.part("commit"):
+                for (req, drafts), tokens in zip(spec_slots, sampled):
+                    self._spec_commit(req, drafts, tokens)
         with clock.phase("bookkeeping"):
             if n_prefill_tokens:
                 self._prefill_token_times.append((time.monotonic(), n_prefill_tokens))
@@ -1259,6 +1264,134 @@ class InferenceEngine:
             )
         self.total_steps += 1
         return True
+
+    # -- launch before read ------------------------------------------------
+    def _launch_decode(self, plain: List[Request]) -> "_DecodeBatch":
+        """Launch the plain decode batch. A request whose newest token is in
+        flight (in the launch the last step left unread) is one position
+        further than ``generated`` says, and its row's token is named, not
+        given: ``-1 - j`` is row ``j`` of that launch's picks, which the
+        program takes on the device."""
+        clock = self._clock
+        unread = self._unread
+        with clock.phase("launch"), clock.part("rows"):
+            toks = [r.generated[-1] if r.in_flight is None else -1 - r.in_flight for r in plain]
+            poss = [r.context_len - 1 + r.ahead for r in plain]
+            rows = [
+                self.blocks.table_row(r.request_id, self.runner.max_blocks_per_seq)
+                for r in plain
+            ]
+            cls = [r.context_len + r.ahead for r in plain]
+            slots = [self.blocks.slot_of(r.request_id) for r in plain]
+            # an all-greedy batch reads back the device's picks (one
+            # int a slot), not the logits: the same program either way
+            greedy = all(r.temperature <= 0.0 for r in plain)
+        launched = self.runner.launch_decode(
+            toks, poss, rows, cls, clock, slots=slots, greedy=greedy,
+            after=unread.launched if unread is not None else None,
+        )
+        counts = self._decode_ahead
+        counts["launches"] += 1
+        counts["ahead"] += unread is not None
+        return _DecodeBatch(plain, launched, greedy)
+
+    def _commit_chunks(self, chunks: List[tuple]) -> int:
+        """Read the step's prefill chunks in the order of their launches and
+        commit each: the first token of a prompt that is through, or its
+        export. Returns the prompt tokens they ran."""
+        clock = self._clock
+        wake = self._wake_after_launch
+        n_prefill_tokens = 0
+        for req, prompt, end, launched in chunks:
+            logits = self.runner.read(launched, clock, wake)
+            n_prefill_tokens += end - req.prefill_pos
+            req.prefill_pos = end
+            if req.prefill_done and req.prefill_done_at is None:
+                req.prefill_done_at = time.monotonic()
+            if req.prefill_done:
+                if not req.prefill_only:
+                    with clock.phase("sample"):
+                        token = self._sample(req, logits)
+                with clock.phase("emit"), clock.part("commit"):
+                    # the prompt's K/V is fully written: index its full
+                    # blocks so later requests sharing the prefix skip them
+                    self.blocks.register_prefix(req.request_id, prompt)
+                    if self.engine_cfg.kv_tier_enabled and not req.prefill_only:
+                        # tier write-back trigger 1: the prompt's full
+                        # blocks become cluster-recoverable the moment they
+                        # exist — a replica killed one token later already
+                        # left its prefill in the tier
+                        self._tier_writeback_full_blocks(req, prompt, "prefill")
+                    if req.prefill_only:
+                        # KV-migration export: gather the full blocks to
+                        # host and hand the payload to the waiting exporter
+                        # — no token is ever sampled on this engine
+                        self._complete_prefill_export(req, prompt)
+                    else:
+                        req.state = DECODE
+                        self._emit_token(req, token)
+        return n_prefill_tokens
+
+    def _stays_unread(self, plan, batch: "_DecodeBatch") -> bool:
+        """Whether the step loop may go on to plan the next step before it
+        reads this decode launch. One predicate, read off the engine's own
+        state: the device holds the batch's next tokens (a plain, all-greedy
+        batch: the picks; nothing in it may speculate, and no chunk of the
+        step is an export), and the engine is SATURATED: an arrival could
+        not get a decode slot anyway, because the running requests (those in
+        prefill hold the slots they will decode in) fill the batch or
+        requests already wait. Then looking ahead costs nobody a slot it
+        could have had; on an engine with room it would cost an arrival up to
+        one decode step before its chunk can start."""
+        sched = self.scheduler
+        return (
+            batch.greedy
+            and all(r.spec_k == 0 for r in batch.reqs)
+            and not any(p[0].prefill_only for p in plan.prefills)
+            and (len(sched.running) >= sched.max_decode_batch or bool(sched.waiting))
+        )
+
+    def _read_unread(self) -> bool:
+        """Read the decode launch the last step left unread, if it left one."""
+        batch, self._unread = self._unread, None
+        if batch is None:
+            return False
+        self._read_decode(batch)
+        return True
+
+    def _read_decode(self, batch: "_DecodeBatch") -> None:
+        """Wait for a decode launch, sample and commit its rows. A row whose
+        request is no longer decoding is DROPPED, never emitted: it finished
+        on the token before (an EOS the host could only see in the token),
+        was cancelled, reaped, failed or preempted while this one was in
+        flight. Its blocks and state slot went back to the pool with that
+        launch still queued, and that is safe: the device runs its queue in
+        the order of the launches, so the dropped row's write lands before
+        any program launched after the blocks or the slot were handed on,
+        and every reader reads only what its own sequence wrote after that
+        (a chunk at context 0 starts its slot's state from zeros)."""
+        clock = self._clock
+        out = self.runner.read(batch.launched, clock, self._wake_after_launch)
+        with clock.phase("sample"):
+            if batch.greedy:  # the device's picks, one a slot
+                sampled = [int(t) for t in out]
+            else:
+                sampled = [self._sample(req, lg) for req, lg in zip(batch.reqs, out)]
+        with clock.phase("emit"), clock.part("commit"):
+            for req, token in zip(batch.reqs, sampled):
+                req.in_flight = None
+                if req.state != DECODE:
+                    self._decode_ahead["dropped"] += 1
+                    continue
+                self._emit_token(req, token)
+
+    def _drop_unread(self) -> None:
+        """Forget the unread launch: its requests have all been failed."""
+        batch, self._unread = self._unread, None
+        if batch is not None:
+            for req in batch.reqs:
+                req.in_flight = None
+            self._decode_ahead["dropped"] += len(batch.reqs)
 
     # -- speculative decoding (PR 19) -------------------------------------
     def _spec_propose(self, req: Request) -> List[int]:
@@ -2009,6 +2142,7 @@ class InferenceEngine:
             self.blocks.free(req.request_id)
             req.state = FAILED
             self._finish_request(req, FAILED, error=error)
+        self._drop_unread()
         # no launch follows a failure: the errors, and whatever an earlier
         # step left held, go out now
         self._deliver_held("direct")
@@ -2244,6 +2378,8 @@ class InferenceEngine:
             },
             # where the step thread's puts were delivered, and how long held
             "wakes": dict(self._wakes),
+            # how often the loop launched a decode step before it had read the last
+            "decode_ahead": dict(self._decode_ahead),
             # how wide the decode and verify launches gathered (the target
             # runner's own; a draft model's runner keeps its own count)
             "decode_width": dict(self.runner.decode_width),
@@ -2358,8 +2494,8 @@ class InferenceEngine:
     def wait_idle(self, timeout: float = 30.0) -> bool:
         """Block until no queued/running work remains (drain helper)."""
         deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
-            if not self.scheduler.has_work():
-                return True
+        while self.scheduler.has_work() or self._unread is not None:
+            if time.monotonic() >= deadline:
+                return False
             time.sleep(0.005)
-        return not self.scheduler.has_work()
+        return True

@@ -59,6 +59,14 @@ after the cache, both donated, and the slot index of each sequence last
 (``prefill_chunk(slot=...)``, ``decode(slots=...)``: the engine hands over
 what the block manager assigned); the other models' calls are as they were.
 
+Every step is two halves: a LAUNCH (``launch_prefill``, ``launch_decode``:
+inputs, the ``jit`` call, a :class:`Launched` back at once) and a READ
+(:meth:`PagedModelRunner.read`: the wait, then the copy); ``prefill_chunk``
+and ``decode`` are both at once. A decode launch may name, for any row, a row
+of the picks of the decode launch before it in place of a token the host has
+not read yet (:func:`decode_program`): the engine's loop launches step n + 1
+from step n's picks while n runs.
+
 The device cache lives here as functional state: every step donates the
 cache buffer (``donate_argnums``) and returns the new value, and the
 runner swaps its reference. Donation is unconditional — the CPU backend
@@ -71,8 +79,9 @@ from __future__ import annotations
 
 import logging
 import time
+from dataclasses import dataclass
 from functools import partial
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -123,6 +132,57 @@ def _round_up_bucket(n: int, buckets: Sequence[int]) -> int:
     raise ValueError(f"{n} exceeds largest bucket {buckets[-1]}")
 
 
+def decode_program(step, cfg, pools: int, widest: int):
+    """The ONE decode program a batch bucket: the model's ``step`` with the
+    argmax of its logits beside them (``picks``, int32, the first largest as
+    ``np.argmax``): a batch whose requests are all greedy reads back the picks
+    and leaves the logits on the device (42 MB a step at 64 slots of a 163,840
+    vocabulary), any other reads the logits. Named after the step: the trace
+    tells programs apart by their launch's name.
+
+    A row's token may be one the host has not seen: ``-1 - j`` names row ``j``
+    of the picks of the decode launch before this one (the LAST argument, a
+    device array already: a launch makes no further put), merged here and not
+    in the three models. The picks go out ``widest`` wide, the largest batch
+    bucket, so that any bucket's program takes any other's and there is still
+    one program a bucket. ``pools``: how many arguments after the parameters
+    are the donated pools, which are also the first outputs."""
+    import jax.numpy as jnp
+
+    def paged_decode_step(*args):
+        *args, earlier = args
+        tokens = args[pools + 1]  # after the parameters and the pools
+        args[pools + 1] = jnp.where(
+            tokens < 0, earlier[jnp.clip(-1 - tokens, 0, widest - 1)], tokens
+        )
+        out = step(cfg, *args)
+        picks = jnp.argmax(out[pools], axis=-1).astype(jnp.int32)
+        picks = jnp.pad(picks, (0, widest - picks.shape[0]))
+        return (*out[:pools], (out[pools], picks), *out[pools + 1 :])
+
+    return paged_decode_step
+
+
+@dataclass
+class Launched:
+    """A paged step on its way to the device: what :meth:`PagedModelRunner.
+    read` waits for and copies. It holds the step's outputs and nothing else
+    of it: the pools went back into the runner at the launch."""
+
+    #: which half of the runner's ``moe`` account its loads go to
+    kind: str
+    #: the device array ``read`` returns on the host: a step's logits, or of
+    #: a greedy decode batch the picks (its logits are then held by nobody)
+    out: Any
+    #: a MoE step's expert loads, still on the device (None: a dense model)
+    loads: Any = None
+    #: real rows of a decode batch: ``read`` strips the padding after them
+    n: Optional[int] = None
+    #: a decode launch's picks (``[largest decode bucket]`` int32, on the
+    #: device): what the NEXT decode launch may take a row's token from
+    picks: Any = None
+
+
 class PagedModelRunner:
     def __init__(
         self,
@@ -138,6 +198,7 @@ class PagedModelRunner:
         state_slots: int = 0,
     ):
         import jax
+        import jax.numpy as jnp
 
         #: where ``launch``, ``device_wait`` and ``readback`` of a call go
         #: unless the caller hands in its own account (the engine does, for
@@ -236,22 +297,12 @@ class PagedModelRunner:
         self._prefill_jit = jax.jit(
             partial(self.model.paged_prefill_step, cfg), donate_argnums=donated
         )
-        # ONE decode program, the model's step with the argmax of its logits
-        # beside them ([B] int32, the first largest as ``np.argmax``): a batch
-        # whose requests are all greedy reads back the picks and leaves the
-        # logits on the device (42 MB a step at 64 slots of a 163,840
-        # vocabulary), any other reads the logits. Named after the step: the
-        # trace tells programs apart by their launch's name
-        import jax.numpy as jnp
-
-        step, at = self.model.paged_decode_step, len(donated)  # outputs: the pools, then the logits
-
-        def paged_decode_step(*args):
-            out = step(cfg, *args)
-            picks = jnp.argmax(out[at], axis=-1).astype(jnp.int32)
-            return (*out[:at], (out[at], picks), *out[at + 1 :])
-
-        self._decode_jit = jax.jit(paged_decode_step, donate_argnums=donated)
+        #: what a decode launch hands over where no row names an earlier pick
+        self._no_picks = jnp.zeros(self.decode_buckets[-1], jnp.int32)
+        self._decode_jit = jax.jit(
+            decode_program(self.model.paged_decode_step, cfg, len(donated), self.decode_buckets[-1]),
+            donate_argnums=donated,
+        )
         # speculative verification: prefill-shaped, all-position logits.
         # Always constructed (an uncalled jit holds zero cache entries so
         # compile accounting is unchanged), only warmed when the engine
@@ -328,18 +379,19 @@ class PagedModelRunner:
         self.warmup_programs[label] = time.perf_counter() - t0
         return out
 
-    def _step(self, run, program: str, fn, *args, slots=None):
+    def _step(self, run, program: str, fn, *args, slots=None, last=()):
         """One paged step through ``run`` (:meth:`_run` or :meth:`_warm`):
         keeps the new cache (and state pool) and returns ``(logits, loads)``,
         both still on the device (the decode program's ``logits`` is the pair
         ``(logits, picks)``); ``loads`` is None for a dense model.
         ``slots``: the state slot(s) of the step's sequence(s), handed to a
-        model that keeps per-sequence state and to no other."""
+        model that keeps per-sequence state and to no other. ``last``: what
+        the runner's own wrapper of the step takes after the model's arguments."""
         if self.state is None:
-            self.cache, logits, *loads = run(program, fn, self.params, self.cache, *args)
+            self.cache, logits, *loads = run(program, fn, self.params, self.cache, *args, *last)
         else:
             self.cache, self.state, logits, *loads = run(
-                program, fn, self.params, self.cache, self.state, *args, slots
+                program, fn, self.params, self.cache, self.state, *args, slots, *last
             )
         return logits, (loads[0] if loads else None)
 
@@ -386,7 +438,7 @@ class PagedModelRunner:
                     np.zeros(b, np.int32),
                     np.zeros((b, w), np.int32),
                     np.ones(b, np.int32),
-                    slots=np.zeros(b, np.int32),
+                    slots=np.zeros(b, np.int32), last=(self._no_picks,),
                 )
         # speculative-verify windows (only when the engine opted in via
         # verify_buckets — plain engines keep their exact compile count).
@@ -473,24 +525,21 @@ class PagedModelRunner:
                 "scatter_paged_blocks", self._scatter_jit, self.cache, ids, buf
             )
 
-    def prefill_chunk(
+    def launch_prefill(
         self,
         tokens: Sequence[int],
         block_row: Sequence[int],
         ctx_len: int,
         clock: Optional[timeline.PhaseClock] = None,
-        launched: Optional[Callable[[], None]] = None,
         slot: int = 0,
-    ) -> np.ndarray:
-        """Run one prefill chunk; returns logits [vocab] (fp32 numpy) for
+    ) -> Launched:
+        """Put one prefill chunk on its way to the device and return without
+        waiting for it: :meth:`read` gives the logits [vocab] (fp32 numpy) of
         the chunk's last valid token. ``slot``: the request's state slot (a
         model with per-sequence state; a chunk at ``ctx_len`` 0 starts the
-        slot's state from zeros inside the program). ``clock``: the caller's account for
-        the call's phases, if it keeps one (the runner's own otherwise).
-        ``launched``: called once when the program is on its way to the
-        device, before this thread waits for it (here and in
-        :meth:`decode` and :meth:`verify_batch`): the caller's chance to do
-        host work that the device's run hides."""
+        slot's state from zeros inside the program). ``clock``: the caller's
+        account for the call's phases, if it keeps one (the runner's own
+        otherwise)."""
         clock = clock or self.clock
         if self.state is not None and not 1 <= slot <= self.state_slots:
             raise ValueError(f"a sequence of this model needs a state slot in [1, {self.state_slots}], got {slot}")
@@ -514,39 +563,54 @@ class PagedModelRunner:
                 padded[:true_len] = tokens
                 row = np.asarray(block_row, np.int32)
             with clock.part("call"):
-                out = self._step(
+                logits, loads = self._step(
                     self._run, "paged_prefill_step", self._prefill_jit,
                     padded, row, np.int32(ctx_len), np.int32(true_len), slots=np.int32(slot),
                 )
-        return self._read(out, clock, "prefill", launched)
+        return Launched("prefill", logits, loads)
 
-    def _read(
-        self, out, clock: timeline.PhaseClock, kind: str,
-        launched: Optional[Callable[[], None]] = None, picks: bool = False,
+    def prefill_chunk(
+        self,
+        tokens: Sequence[int],
+        block_row: Sequence[int],
+        ctx_len: int,
+        clock: Optional[timeline.PhaseClock] = None,
+        launched: Optional[Callable[[], None]] = None,
+        slot: int = 0,
     ) -> np.ndarray:
-        """Wait for a step's logits, then copy them to the host (``picks``:
-        of a decode step's pair their argmax instead, and the logits stay
-        where they are): two
-        phases, so that the device's time is told from the copy's. A MoE
-        step's expert loads come over in the same ``readback`` and go into
-        the ``kind`` (``decode`` or ``prefill``) half of :attr:`moe`.
-        ``launched`` runs first: the launch has returned, the wait has not
-        begun."""
-        logits, loads = out
-        if isinstance(logits, tuple):
-            logits = logits[1 if picks else 0]
-        if launched is not None:
-            launched()
+        """:meth:`launch_prefill` and :meth:`read` at once: the logits
+        [vocab] of the chunk's last valid token. ``launched``: called once
+        when the program is on its way to the device, before this thread
+        waits for it (here and in :meth:`decode` and :meth:`verify_batch`):
+        the caller's chance to do host work that the device's run hides."""
+        return self.read(self.launch_prefill(tokens, block_row, ctx_len, clock, slot), clock, launched)
+
+    def read(
+        self,
+        step: Launched,
+        clock: Optional[timeline.PhaseClock] = None,
+        before_wait: Optional[Callable[[], None]] = None,
+    ) -> np.ndarray:
+        """Wait for a launched step, then copy what it gives to the host (a
+        decode batch's real rows, its padding stripped): two phases, so that
+        the device's time is told from the copy's. A MoE step's expert loads
+        come over in the same ``readback`` and go into the step's half of
+        :attr:`moe`. ``before_wait`` runs first: the launch has returned, the
+        wait has not begun."""
+        clock = clock or self.clock
+        if before_wait is not None:
+            before_wait()
         with clock.phase("device_wait"):
-            logits.block_until_ready()
+            step.out.block_until_ready()
         with clock.phase("readback"):
             with clock.part("logits"):
-                host = np.asarray(logits)
-            if loads is not None:
+                host = np.asarray(step.out)
+            if step.loads is not None:
                 with clock.part("loads"):
+                    loads = step.loads
                     counters = loads if isinstance(loads, dict) else {"load": loads}
-                    self._account_moe(kind, {k: np.asarray(v) for k, v in counters.items()})
-        return host
+                    self._account_moe(step.kind, {k: np.asarray(v) for k, v in counters.items()})
+        return host if step.n is None else host[: step.n]
 
     def _account_moe(self, kind: str, counters: Dict[str, np.ndarray]) -> None:
         """Add one launch's expert loads ``[n_layers, E]`` (assignments of
@@ -647,34 +711,37 @@ class PagedModelRunner:
                     ctx[i] = ctx_lens[i]
                     tl[i] = len(w)
             with clock.part("call"):
-                out = self._step(
+                logits, loads = self._step(
                     self._run, "paged_verify_step", self._verify_jit, tokens, tables, ctx, tl
                 )
-        out = self._read(out, clock, "decode", launched)
+        out = self.read(Launched("decode", logits, loads), clock, launched)
         return [out[i, : len(w)] for i, w in enumerate(windows)]
 
-    def decode(
+    def launch_decode(
         self,
         tokens: Sequence[int],
         positions: Sequence[int],
         block_rows: Sequence[Sequence[int]],
         ctx_lens: Sequence[int],
         clock: Optional[timeline.PhaseClock] = None,
-        launched: Optional[Callable[[], None]] = None,
         slots: Optional[Sequence[int]] = None,
         greedy: bool = False,
-    ) -> np.ndarray:
-        """Advance a decode batch one token; returns logits [n, vocab]
-        for the n REAL slots (padding stripped), or with ``greedy`` their
-        argmax ``[n]`` int32, which the same program takes on the device: the
-        logits stay there. ``slots``: each sequence's
-        state slot (a model with per-sequence state; padding rows take the
-        null slot). ``block_rows`` are
-        ``max_blocks_per_seq`` wide; the step is handed them only as wide as
-        the rung of :attr:`table_widths` that covers ``max(ctx_lens)``, a
-        program :meth:`warmup` compiled. What is cut off lay past every
-        slot's context, which the step masks: the logits are those of the
-        full width."""
+        after: Optional[Launched] = None,
+    ) -> Launched:
+        """Put a decode batch on its way to advance one token and return
+        without waiting for it: :meth:`read` gives the logits [n, vocab] of
+        the n REAL slots or, with ``greedy``, their argmax ``[n]`` int32,
+        which the same program takes on the device (the logits then stay
+        there, held by nobody). ``after``: the decode launch before this one,
+        read or not; a token ``-1 - j`` then stands for row ``j`` of ITS
+        picks, which never leave the device (the launch span's ``ahead`` says
+        whether one was given). ``slots``: each sequence's state
+        slot (a model with per-sequence state; padding rows take the null
+        slot). ``block_rows`` are ``max_blocks_per_seq`` wide; the step is
+        handed them only as wide as the rung of :attr:`table_widths` that
+        covers ``max(ctx_lens)``, a program :meth:`warmup` compiled. What is
+        cut off lay past every slot's context, which the step masks: the
+        logits are those of the full width."""
         clock = clock or self.clock
         n = len(tokens)
         if self.state is not None and (slots is None or len(slots) != n):
@@ -684,7 +751,7 @@ class PagedModelRunner:
         with clock.phase(
             "launch", program="paged_decode_step",
             bucket=f"{bucket}x{M * self.block_size}",
-            path=self._path(1).name,
+            path=self._path(1).name, ahead=int(after is not None),
         ):
             with clock.part("inputs"):
                 t = np.zeros(bucket, np.int32)
@@ -698,8 +765,28 @@ class PagedModelRunner:
                 sl = np.zeros(bucket, np.int32)
                 if slots is not None:
                     sl[:n] = slots
+                if after is None and t.min() < 0:
+                    raise ValueError("a token names a row of an earlier decode launch, and none was given")
             with clock.part("call"):
-                out = self._step(
-                    self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl, slots=sl
+                (logits, picks), loads = self._step(
+                    self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl, slots=sl,
+                    last=(self._no_picks if after is None else after.picks,),
                 )
-        return self._read(out, clock, "decode", launched, picks=greedy)[:n]
+        return Launched("decode", picks if greedy else logits, loads, n, picks)
+
+    def decode(
+        self,
+        tokens: Sequence[int],
+        positions: Sequence[int],
+        block_rows: Sequence[Sequence[int]],
+        ctx_lens: Sequence[int],
+        clock: Optional[timeline.PhaseClock] = None,
+        launched: Optional[Callable[[], None]] = None,
+        slots: Optional[Sequence[int]] = None,
+        greedy: bool = False,
+    ) -> np.ndarray:
+        """:meth:`launch_decode` and :meth:`read` at once: logits [n, vocab]
+        for the n REAL slots (padding stripped), or with ``greedy`` their
+        argmax ``[n]`` int32."""
+        step = self.launch_decode(tokens, positions, block_rows, ctx_lens, clock, slots, greedy)
+        return self.read(step, clock, launched)

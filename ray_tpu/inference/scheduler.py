@@ -23,6 +23,11 @@ Policies, all host-side and unit-testable without jax:
   host<->HBM bandwidth is the scarce resource).
 * **cancellation** — frees blocks immediately, whether the request is
   queued, prefilling, or decoding.
+* **a token in flight** — the engine may plan a step while the decode launch
+  before it is unread (``Request.in_flight``): such a request counts one
+  position further (blocks, write position), is not planned again once its
+  last token is in flight, and a victim or a cancelled request simply loses
+  the unread result (the engine drops it).
 """
 
 from __future__ import annotations
@@ -139,6 +144,12 @@ class Request:
     spec_step_k: int = 0
     #: whether the request has been counted as having waited for a state slot
     slot_waited: bool = False
+    #: the row that holds this request's NEWEST token in the one decode
+    #: launch the engine has not read yet (None: every token it was launched
+    #: for is in ``generated``). The engine sets it when it leaves a launch
+    #: unread and clears it when it reads; the plan counts such a request one
+    #: position further (:attr:`ahead`)
+    in_flight: Optional[int] = None
 
     @property
     def effective_prompt(self) -> List[int]:
@@ -150,6 +161,11 @@ class Request:
     def context_len(self) -> int:
         """Token positions currently live in the KV cache."""
         return len(self.prompt) + len(self.generated)
+
+    @property
+    def ahead(self) -> int:
+        """Tokens launched for and not read yet: 0 or 1."""
+        return 0 if self.in_flight is None else 1
 
     @property
     def prefill_done(self) -> bool:
@@ -346,6 +362,9 @@ class ContinuousBatchingScheduler:
         victim.state = QUEUED
         victim.prefill_pos = 0
         victim.preemptions += 1
+        # a token in flight is not in ``generated``: the engine drops that
+        # result (the request is not decoding when it is read) and the
+        # re-prefill of prompt + generated samples the same token again
         victim.restart_prompt = victim.prompt + victim.generated
         # an unexecuted COW died with the eviction: readmission
         # re-acquires from the cache and plans a fresh copy if needed
@@ -389,8 +408,14 @@ class ContinuousBatchingScheduler:
                 # on exhaustion. A victim must never be something already in
                 # the plan: the engine would run it on freed (null) blocks.
                 planned_ids = {id(p[0]) for p in plan.prefills}
+                # a request whose LAST token is in flight (the engine has not
+                # read the launch that carries it) is not planned again: a
+                # length finish is known a step ahead, so no row is wasted
                 decodable = sorted(
-                    (r for r in self.running if r.prefill_done),
+                    (
+                        r for r in self.running
+                        if r.prefill_done and len(r.generated) + r.ahead < r.max_new_tokens
+                    ),
                     key=lambda r: (-r.priority, r.arrival),
                 )
                 for req in decodable[: self.max_decode_batch]:
@@ -399,13 +424,15 @@ class ContinuousBatchingScheduler:
                     # the step writes KV at position context_len-1 (the token
                     # sampled LAST step): coverage of exactly context_len
                     # positions; the token emitted this step grows the table
-                    # next step
-                    need = req.context_len
+                    # next step. A token in flight counts: the step writes ITS
+                    # K/V, one position further
+                    need = req.context_len + req.ahead
                     # speculative slots want k extra positions (the verify
                     # window writes K/V at context_len-1 .. context_len+k-1).
                     # Opportunistic only: spec growth never preempts, and a
                     # dry pool degrades the slot to plain decode this step.
-                    k = req.spec_k
+                    # Nothing is drafted after a token the host has not seen.
+                    k = 0 if req.ahead else req.spec_k
                     if k > 0:
                         if self.spec_k_live is not None:
                             k = min(k, self.spec_k_live)

@@ -1,0 +1,461 @@
+"""Launch before read (ISSUE 39): the loop of a saturated engine launches
+decode step n + 1 before it reads step n, the rows of n + 1 take their tokens
+from n's picks on the device, and every stream is token for token what the
+synchronous order gives (an engine driven by ``step()`` from outside the loop
+never looks ahead: it is the reference here). CPU, tiny configs, through the
+started loop: what is checked is tokens, order and counters, never a speed."""
+
+import os
+import sys
+import threading
+import time
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))
+sys.path.insert(0, os.path.join(HERE, "perfbench"))
+
+jax = pytest.importorskip("jax")
+
+import rehearsal  # noqa: E402
+from perfbench import families  # noqa: E402
+from ray_tpu.inference.engine import (  # noqa: E402
+    _END,
+    EngineConfig,
+    InferenceEngine,
+    RequestFailedError,
+)
+from ray_tpu.inference.kv_cache import PagedBlockManager  # noqa: E402
+from ray_tpu.inference.model_runner import PagedModelRunner  # noqa: E402
+from ray_tpu.inference.scheduler import (  # noqa: E402
+    DECODE,
+    QUEUED,
+    ContinuousBatchingScheduler,
+    Request,
+)
+from ray_tpu.models.interface import model_of  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.models.xing4 import Xing4Config  # noqa: E402
+
+#: 2 decode slots and 6 requests: the running requests fill the batch (on the
+#: model with a state pool 2 run and 4 wait), so the engine is saturated
+ENGINE = dict(
+    num_blocks=64, block_size=8, prefill_buckets=(16, 32), decode_buckets=(2,),
+    max_decode_batch=2, max_queue_depth=16, warmup=False,
+)
+PROMPTS = [[5, 6, 7, 8] * 3 + [5, 6, 7][: i % 4] + [9 + i] * (i % 3) for i in range(6)]
+MODELS = ["llama", "xing4", "kimi_linear"]
+KEYS = {"launches", "ahead", "dropped"}
+
+_built = {}
+
+
+def _model(name):
+    """(config, parameters) of a toy model: K and V rows, a latent cache, a
+    latent cache beside a state pool."""
+    if name not in _built:
+        if name == "kimi_linear":
+            toy = rehearsal.tiny_config("kimi-linear-48b-a3b-ep16")
+            cfg = families.of(toy).model_config(toy, max_seq_len=toy["max_position_embeddings"])
+        else:
+            cfg = LlamaConfig.tiny() if name == "llama" else Xing4Config.tiny()
+        _built[name] = cfg, model_of(cfg).init_params(cfg, jax.random.PRNGKey(0))
+    return _built[name]
+
+
+def _engine(name, **kw):
+    cfg, params = _model(name)
+    return InferenceEngine(cfg, params, EngineConfig(**{**ENGINE, **kw}))
+
+
+def _drain(eng, rid, timeout=60.0):
+    q, items = eng._out[rid], []
+    while not items or not (items[-1] is _END or isinstance(items[-1], Exception)):
+        items.append(q.get(timeout=timeout))
+    return items
+
+
+def _synchronous(name, submit, **kw):
+    """The streams of an engine stepped from outside the loop: every launch
+    read in the step that made it."""
+    eng = _engine(name, **kw)
+    rids = submit(eng)
+    while eng.scheduler.has_work():
+        assert eng.step()
+        assert eng._unread is None
+    assert eng.stats()["decode_ahead"]["ahead"] == 0
+    return [_drain(eng, r, timeout=1) for r in rids]
+
+
+def _looped(name, submit, **kw):
+    eng = _engine(name, **kw)
+    rids = submit(eng)  # before start(): the loop plans what the direct steps planned
+    eng.start()
+    try:
+        got = [_drain(eng, r) for r in rids]
+        assert eng.wait_idle() and eng._unread is None
+        return eng, got
+    finally:
+        eng.stop()
+
+
+def _until(cond, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.001)
+
+
+def _all_returned(eng):
+    st = eng.stats()
+    assert st["blocks"]["used_blocks"] == 0
+    pool = st["state_pool"]
+    assert pool["in_use"] == 0 and pool["assigned"] == pool["released"]
+    w = st["wakes"]
+    assert w["items"] == w["after_launch"] + w["at_idle"] + w["direct"]
+    return st["decode_ahead"]
+
+
+# -- the streams -------------------------------------------------------------------------------------
+
+@pytest.mark.parametrize("model", MODELS)
+def test_length_finishes_are_planned_ahead_and_no_row_is_wasted(model):
+    submit = lambda eng: [eng.submit(p, max_new_tokens=5 + i) for i, p in enumerate(PROMPTS)]  # noqa: E731
+    want = _synchronous(model, submit)
+    assert [len(items) for items in want] == [6 + i for i in range(6)]
+    eng, got = _looped(model, submit)
+    assert got == want
+    ahead = _all_returned(eng)
+    # a request whose last token is in flight is not launched again: every row
+    # of every launch reached its stream. The first token of each is prefill's
+    assert ahead["dropped"] == 0
+    assert ahead["launches"] > 0 and ahead["ahead"] >= ahead["launches"] // 2
+    rows = eng.stats()["decode_width"]["launches"]
+    assert rows == ahead["launches"]
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_an_eos_met_one_step_late_drops_one_result_and_streams_no_stray_token(model):
+    plain = lambda eng: [eng.submit(p, max_new_tokens=12) for p in PROMPTS]  # noqa: E731
+    free = _synchronous(model, plain)
+    # one request stops at a token of its stream that did not occur before (after
+    # its second): the host sees it only when it reads the launch, one step late
+    which, tokens, at = next(
+        (n, items[:-1], i) for n, items in enumerate(free)
+        for i in range(2, 11) if items[i] not in items[:i]
+    )
+    eos = tokens[at]
+
+    def submit(eng):
+        return [eng.submit(p, max_new_tokens=12, eos_token=eos if i == which else None)
+                for i, p in enumerate(PROMPTS)]
+
+    want = _synchronous(model, submit)
+    assert want[which] == tokens[: at + 1] + [_END]
+    assert all(want[i] == free[i] for i in range(6) if i != which)
+    eng, got = _looped(model, submit)
+    assert got == want
+    assert _all_returned(eng)["dropped"] == 1
+
+
+def _at_launch(eng, n, act):
+    """Run ``act`` on the step thread inside its ``n``-th decode launch made
+    while another is unread: a token of every row is in flight then."""
+    launch, seen = eng.runner.launch_decode, []
+
+    def hooked(*a, after=None, **kw):
+        if after is not None:
+            seen.append(1)
+            if len(seen) == n:
+                act()
+        return launch(*a, after=after, **kw)
+
+    eng.runner.launch_decode = hooked
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_a_request_ended_with_a_token_in_flight_streams_a_prefix_and_its_terminal(how):
+    submit = lambda eng: [eng.submit(p, max_new_tokens=12) for p in PROMPTS]  # noqa: E731
+    want = _synchronous("llama", submit)
+    eng = _engine("llama")
+    rids = submit(eng)
+
+    def end():
+        req = eng._unread.reqs[0]
+        assert req.in_flight == 0 and req.state == DECODE
+        if how == "cancel":
+            assert eng.cancel(req.request_id)
+        else:
+            req.deadline = SimpleNamespace(expired=True)  # reaped by the next plan
+        ended.append(req.request_id)
+
+    ended = []
+    _at_launch(eng, 2, end)
+    eng.start()
+    try:
+        got = [_drain(eng, r) for r in rids]
+        assert eng.wait_idle() and eng._unread is None
+    finally:
+        eng.stop()
+    for rid, have, full in zip(rids, got, want):
+        if rid in ended:
+            assert 1 <= len(have) - 1 < len(full) - 1 and have[:-1] == full[: len(have) - 1]
+            assert have[-1] is _END if how == "cancel" else isinstance(have[-1], RequestFailedError)
+        else:
+            assert have == full
+    # a cancel ends it at once: the unread launch and the one being made both
+    # carried a row of it. A deadline is met by the next plan: the second alone
+    assert len(ended) == 1 and _all_returned(eng)["dropped"] == (2 if how == "cancel" else 1)
+
+
+@pytest.mark.parametrize("model, num_blocks", [("llama", 13), ("kimi_linear", 17)])
+def test_a_preempted_request_loses_its_token_in_flight_and_samples_it_again(model, num_blocks):
+    rs = np.random.RandomState(4)
+    prompts = [[int(t) for t in rs.randint(1, 200, size=n)] for n in (33, 27)]
+    submit = lambda eng: [eng.submit(p, max_new_tokens=40) for p in prompts]  # noqa: E731
+    # the two sequences grow to 64 + 64 tokens (16 blocks of 8, 12 usable) and to
+    # 73 + 67 (19 blocks, 16 usable): the pool runs dry while both decode
+    tight = dict(num_blocks=num_blocks)
+    want = _synchronous(model, submit, **tight)
+    eng, got = _looped(model, submit, **tight)
+    assert got == want and all(len(items) > 20 and items[-1] is _END for items in got)
+    assert eng.stats()["scheduler"]["total_preempted"] >= 1
+    ahead = _all_returned(eng)
+    assert ahead["ahead"] > 0 and ahead["dropped"] >= 1
+
+
+@pytest.mark.parametrize("turn", ["sampled", "speculative"])
+def test_a_batch_that_turns_sampled_or_speculative_is_read_at_once(turn):
+    kw = dict(speculative_k=3, speculative_draft="ngram") if turn == "speculative" else {}
+    late = dict(temperature=0.9, seed=7) if turn == "sampled" else dict(speculative=True)
+
+    def submit(eng, then=lambda: None):
+        rids = [eng.submit(p, max_new_tokens=24, speculative=False) for p in PROMPTS[:3]]
+        then()
+        return rids + [eng.submit(PROMPTS[3], max_new_tokens=10, **late)]
+
+    want = _synchronous("llama", submit, **kw)
+    eng = _engine("llama", **kw)
+    decided = []
+    stays = eng._stays_unread
+
+    def logged(plan, batch):
+        plain = batch.greedy and all(r.spec_k == 0 for r in batch.reqs)
+        decided.append((plain, stays(plan, batch), eng._unread is None))
+        return decided[-1][1]
+
+    eng._stays_unread = logged
+
+    def started():
+        eng.start()
+        _until(lambda: eng.stats()["decode_ahead"]["ahead"] >= 2)
+
+    try:
+        rids = submit(eng, started)
+        got = [_drain(eng, r) for r in rids]
+        assert eng.wait_idle() and eng._unread is None
+    finally:
+        eng.stop()
+    assert got == want
+    # the engine looked ahead before the late request decoded, not while its
+    # batch was sampled or might speculate, and nothing was unread at the switch
+    assert any(plain and stayed for plain, stayed, _ in decided)
+    assert any(not plain for plain, _, _ in decided)
+    assert all(not stayed and none_unread for plain, stayed, none_unread in decided if not plain)
+    _all_returned(eng)
+
+
+# -- when it engages ---------------------------------------------------------------------------------
+
+def test_an_engine_with_room_reads_every_launch_in_its_own_step():
+    eng = _engine("llama", max_decode_batch=4, decode_buckets=(4,))
+    rids = [eng.submit(p, max_new_tokens=8) for p in PROMPTS[:2]]  # 2 of 4 slots, nobody waits
+    eng.start()
+    try:
+        assert all(len(_drain(eng, r)) == 9 for r in rids)
+        assert eng.wait_idle()
+    finally:
+        eng.stop()
+    ahead = _all_returned(eng)
+    assert ahead["launches"] >= 7 and ahead["ahead"] == 0 and ahead["dropped"] == 0
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_the_three_counters_exist_from_construction(model):
+    eng = _engine(model)
+    assert eng.stats()["decode_ahead"] == dict.fromkeys(KEYS, 0)
+    assert eng._unread is None
+
+
+@pytest.mark.parametrize("leave", ["stop", "wait_idle", "outside_step", "fail_all"])
+def test_nothing_stays_unread(leave):
+    eng = _engine("llama")
+    rids = [eng.submit(p, max_new_tokens=6 if leave == "wait_idle" else 300) for p in PROMPTS]
+    if leave == "outside_step":
+        # the loop's own step leaves its launch unread, a step from outside reads both
+        assert eng.step(hold_wakes=True) and eng._unread is None  # a chunk alone
+        while eng._unread is None:
+            assert eng.step(hold_wakes=True)
+        assert all(r.in_flight is not None for r in eng._unread.reqs)
+        assert eng.step() and eng._unread is None
+        assert all(r.in_flight is None for r in eng.scheduler.running)
+        assert eng.stats()["decode_ahead"]["ahead"] == 1
+        return
+    eng.start()
+    try:
+        _until(lambda: eng.stats()["decode_ahead"]["ahead"] >= 3)
+        if leave == "wait_idle":
+            assert eng.wait_idle(60)
+            assert all(_drain(eng, r)[-1] is _END for r in rids)
+        elif leave == "fail_all":
+            # from another thread, as a failing caller would: the loop goes on
+            eng._fail_all(RequestFailedError("failed"))
+            assert all(isinstance(_drain(eng, r)[-1], RequestFailedError) for r in rids)
+            assert eng.wait_idle(60)
+        else:
+            eng.stop()
+        assert eng._unread is None
+    finally:
+        eng.stop()
+    assert eng._unread is None and not eng._held
+    assert eng.blocks.used_blocks == 0
+
+
+def test_streams_survive_consumers_and_cancels_on_other_threads():
+    """Consumers on threads of their own, half of which cancel mid-stream,
+    while the loop looks ahead: each stream is a prefix of the synchronous
+    one's tokens."""
+    submit = lambda eng: [eng.submit(p, max_new_tokens=24) for p in PROMPTS]  # noqa: E731
+    want = [items[:-1] for items in _synchronous("llama", submit)]
+    eng = _engine("llama")
+    rids = submit(eng)
+    seen = {}
+
+    def consume(i, rid):
+        items = []
+        for token in eng.tokens(rid, timeout=60):
+            items.append(token)
+            if i % 2 and len(items) == 3 + i:
+                eng.cancel(rid)
+        seen[i] = items
+
+    threads = [threading.Thread(target=consume, args=(i, r)) for i, r in enumerate(rids)]
+    eng.start()
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert eng.wait_idle()
+    finally:
+        eng.stop()
+    for i in range(6):
+        # a cancelled stream may hold what was delivered before the cancel landed
+        assert seen[i] == want[i][: len(seen[i])] and len(seen[i]) >= (3 + i if i % 2 else 24), i
+    assert _all_returned(eng)["ahead"] > 0
+
+
+# -- the runner's two halves ---------------------------------------------------------------------------
+
+def _runner(name, decode_buckets=(2, 4)):
+    cfg, params = _model(name)
+    slots = 4 if name == "kimi_linear" else 0
+    return PagedModelRunner(cfg, params, num_blocks=32, block_size=8, prefill_buckets=(16,),
+                            decode_buckets=decode_buckets, state_slots=slots)
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_a_row_named_in_the_earlier_picks_decodes_as_the_token_itself(model):
+    stateful = model == "kimi_linear"
+    logits = []
+    for named in (False, True):
+        runner = _runner(model)
+        runner.warmup()
+        rows = [[1 + 2 * i, 2 + 2 * i] + [0] * (runner.max_blocks_per_seq - 2) for i in range(3)]
+        slots = [1, 2, 3] if stateful else None
+        for i in range(3):
+            runner.prefill_chunk(PROMPTS[i][:10], rows[i], 0, slot=i + 1 if stateful else 0)
+        first = runner.launch_decode([3, 4, 5], [10] * 3, rows, [11] * 3, slots=slots, greedy=True)
+        if named:
+            # two rows (out of order) take their tokens from the unread launch,
+            # a batch bucket of 2 after one of 4: the same picks, another program
+            second = runner.launch_decode([-1 - 2, -1 - 0], [11] * 2, [rows[2], rows[0]], [12] * 2,
+                                          slots=[3, 1] if stateful else None, after=first)
+            picks = runner.read(first)
+        else:
+            picks = runner.read(first)
+            second = runner.launch_decode([int(picks[2]), int(picks[0])], [11] * 2, [rows[2], rows[0]],
+                                          [12] * 2, slots=[3, 1] if stateful else None)
+        assert picks.shape == (3,) and picks.dtype == np.int32
+        logits.append(runner.read(second))
+        assert logits[-1].shape == (2, runner.cfg.vocab_size)
+        # one program a batch bucket, whatever bucket made the picks it is handed
+        assert runner.recompiles_after_warmup() == 0
+        assert runner.compile_count() == 1 + 2 * len(runner.table_widths) + 1
+    np.testing.assert_array_equal(logits[0], logits[1])
+
+
+def test_decode_is_launch_and_read_at_once_and_a_name_needs_the_launch_it_names():
+    runner = _runner("llama", decode_buckets=(2,))
+    row = [1] + [0] * (runner.max_blocks_per_seq - 1)
+    runner.prefill_chunk(PROMPTS[0][:8], row, 0)
+    # positional, as the benchmark's check calls it
+    picks = runner.decode([3], [8], [row], [9], None, None, None, True)
+    assert picks.shape == (1,)
+    logits = runner.decode([3], [8], [row], [9])
+    assert logits.shape == (1, runner.cfg.vocab_size) and int(np.argmax(logits[0])) == int(picks[0])
+    with pytest.raises(ValueError, match="names a row"):
+        runner.launch_decode([-1], [9], [row], [10])
+
+
+# -- the plan ------------------------------------------------------------------------------------------
+
+def _planner(num_blocks=16, **kw):
+    blocks = PagedBlockManager(num_blocks, 4)
+    return blocks, ContinuousBatchingScheduler(blocks, max_decode_batch=2, max_prefill_chunk=16, **kw)
+
+
+def _decoding(sched, rid, prompt_len, generated, max_new):
+    req = Request(request_id=rid, prompt=list(range(1, prompt_len + 1)), max_new_tokens=max_new)
+    sched.add(req)
+    sched.schedule()
+    req.prefill_pos, req.state, req.generated = prompt_len, DECODE, list(generated)
+    return req
+
+
+def test_a_token_in_flight_counts_one_position_further():
+    blocks, sched = _planner()
+    req = _decoding(sched, "a", 7, [1], 8)  # context 8: two blocks hold it
+    assert sched.schedule().decodes == [req] and len(blocks.owned("a")) == 2
+    req.in_flight = 0
+    assert req.ahead == 1
+    # the step writes the K/V of the token in flight at position 8: a third block
+    assert sched.schedule().decodes == [req] and len(blocks.owned("a")) == 3
+
+
+@pytest.mark.parametrize("generated, in_flight, planned", [
+    (2, None, True), (2, 0, False), (1, 0, True), (1, None, True),
+])
+def test_a_request_whose_last_token_is_in_flight_is_not_planned_again(generated, in_flight, planned):
+    _blocks, sched = _planner()
+    last = _decoding(sched, "a", 4, [1] * generated, 3)
+    other = _decoding(sched, "b", 4, [1], 9)
+    third = _decoding(sched, "c", 4, [1], 9)
+    last.in_flight = in_flight
+    # its place in the batch of 2 goes to the next request: no row is wasted
+    assert sched.schedule().decodes == ([last, other] if planned else [other, third])
+
+
+def test_a_preempted_request_restarts_without_its_token_in_flight():
+    blocks, sched = _planner(num_blocks=5)  # 4 usable blocks of 4
+    a = _decoding(sched, "a", 7, [1], 20)
+    b = _decoding(sched, "b", 7, [1], 20)
+    a.in_flight, b.in_flight = 0, 1
+    plan = sched.schedule()  # both need a third block, one is free: b is evicted
+    assert plan.decodes == [a] and b.state == QUEUED and sched.total_preempted == 1
+    assert b.restart_prompt == b.prompt + [1] and blocks.owned("b") == []
+    # the engine clears the mark when it reads (and drops) the launch
+    assert b.in_flight == 1 and b not in sched.running

@@ -305,9 +305,12 @@ def test_engine_step_event_covers_the_whole_step(engine):
     assert not [ev for ev in timeline.timeline_events() if ev.name.startswith("engine.")]
     for ev in steps:
         phases = ev.args["phases_us"]
-        assert {"schedule", "launch", "device_wait"} <= set(phases) <= set(STEP_PHASES)
+        assert {"schedule", "launch"} <= set(phases) <= set(STEP_PHASES)
         # one event a step, from the top of step(): schedule() is inside it
         assert 0 < sum(phases.values()) <= ev.end_us - ev.start_us + 50
+    # a step waits for the device, but for the one that leaves its decode launch
+    # unread and found none to read: the first of a saturated stretch (ISSUE 39)
+    assert sum("device_wait" in ev.args["phases_us"] for ev in steps) > len(steps) // 2
     assert any(ev.args["prefill_tokens"] and ev.args["decode_batch"] for ev in steps)
 
 

@@ -143,11 +143,17 @@ def test_items_of_one_launch_are_woken_after_the_next_launch_returns(cfg, params
             pairs += 1
             opened = [e for e in log[held_at:put_at] if e[0] in ("phase", "launch")]
             following = next(e for e in log[put_at:] if e[0] in ("phase", "launch"))
-            if following == ("phase", "device_wait"):
-                # launch n + 1, and no other, has returned; its wait has not begun
+            if following == ("phase", "device_wait") or ("launch",) in opened:
+                # a launch has returned and its wait has not begun (ISSUE 39:
+                # beside an unread decode launch a step launches its chunk AND
+                # its decode batch before it waits, and what it commits between
+                # two of its waits goes out before the second: no launch need
+                # lie between hold and put; and a launch that stays unread is
+                # followed by no wait in its own step)
                 after_launch += 1
-                assert opened.count(("launch",)) == 1
-                assert opened[-2:] == [("launch",), ("phase", "emit")]
+                before = log[:put_at]
+                assert before.count(("launch",)) > before.count(("phase", "device_wait"))
+                assert opened[-1] == ("phase", "emit")
             else:
                 # nothing to launch: delivered where the step found no work
                 assert ("launch",) not in opened
@@ -193,10 +199,10 @@ def _end_drain_expiry(eng, rid):
 
 def _end_step_raises(eng, rid):
     def decode(*a, **kw):
-        del eng.runner.decode  # once: the loop keeps serving
+        del eng.runner.launch_decode  # once: the loop keeps serving
         raise _Boom("step")
 
-    eng.runner.decode = decode
+    eng.runner.launch_decode = decode  # the engine's decode is two calls since ISSUE 39
     return _Boom
 
 
