@@ -260,9 +260,13 @@ class EngineConfig:
     #: stream is byte-identical to non-speculative decode and the
     #: resumable-stream contract survives unchanged.
     speculative_k: int = 0
-    #: draft mode: "ngram" (model-free prompt-lookup decoding, zero
-    #: device cost) or "model" (a scaled-down same-tokenizer draft model
-    #: on its own paged runner; requires draft_config)
+    #: draft mode, three proposers: "ngram" (model-free prompt-lookup
+    #: decoding on the host, a slot at a time, zero device cost), "model" (a
+    #: scaled-down same-tokenizer draft model on its OWN paged runner and
+    #: pool, batch-1 launches a slot; requires draft_config) or "mtp" (the
+    #: target's own multi-token-prediction module, ``Model.drafter``: it runs
+    #: inside the TARGET's step program for the whole batch, and decode is
+    #: that program; speculative_k must be the module's depth)
     speculative_draft: str = "ngram"
     #: LlamaConfig for speculative_draft="model" (same vocab as the
     #: target); ignored for "ngram"
@@ -415,6 +419,8 @@ class InferenceEngine:
         #: their launch / device_wait / readback
         self._clock = timeline.PhaseClock("engine", STEP_PHASES, STEP_PARTS)
         state_slots = self._state_slots(model_cfg, ec)
+        #: whether the model drafts for itself (speculative_draft "mtp")
+        self._mtp = self._drafts_for_itself(model_cfg, ec)
         self.runner = PagedModelRunner(
             model_cfg,
             params,
@@ -425,6 +431,7 @@ class InferenceEngine:
             verify_buckets=ec.resolved_verify_buckets(),
             cache_dtype=ec.cache_dtype,
             state_slots=state_slots,
+            drafter=self._mtp,
         )
         #: the start-up account, written once while the replica comes up
         #: (LLMServer adds what it alone sees: the weights, and the whole
@@ -580,6 +587,14 @@ class InferenceEngine:
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._spec_rollbacks = 0
+        #: a model that drafts for itself: slots x steps through its step,
+        #: the tokens they committed (1 to 2 a slot-step), the steps, and which
+        #: of them took the ONE program (an all-greedy batch) or the two around
+        #: the sampler
+        self._mtp_counts = {
+            "slot_steps": 0, "committed_tokens": 0, "step_launches": 0, "launches_fused": 0,
+            "launches_split": 0,
+        }
         #: (proposed, accepted) snapshot at the last gauge refresh — the
         #: adaptive controller steers on the window delta, not lifetime
         self._spec_window_seen = (0, 0)
@@ -587,10 +602,13 @@ class InferenceEngine:
         if ec.speculative_k > 0:
             from ray_tpu.inference.speculative import (
                 DraftModelProposer,
+                MtpDrafts,
                 NgramProposer,
             )
 
-            if ec.speculative_draft == "model":
+            if self._mtp:
+                self.spec = MtpDrafts()
+            elif ec.speculative_draft == "model":
                 if ec.draft_config is None:
                     raise ValueError(
                         "speculative_draft='model' requires draft_config"
@@ -625,7 +643,7 @@ class InferenceEngine:
             else:
                 raise ValueError(
                     f"unknown speculative_draft {ec.speculative_draft!r} "
-                    "(expected 'ngram' or 'model')"
+                    "(expected 'ngram', 'model' or 'mtp')"
                 )
             self.scheduler.spec_max_context = model_cfg.max_seq_len
             self.scheduler.spec_k_live = ec.speculative_k
@@ -676,6 +694,47 @@ class InferenceEngine:
             if on:
                 raise ValueError(f"{field} cannot run here: {why}{reason}")
         return ec.max_decode_batch
+
+    @staticmethod
+    def _drafts_for_itself(model_cfg, ec: "EngineConfig") -> bool:
+        """Whether decode is the model's own drafter's step (``speculative_draft``
+        ``"mtp"`` with ``speculative_k`` > 0). What that drafter cannot carry
+        yet is refused here, with the reason, instead of answering wrongly or
+        drafting from rows nobody wrote: its row at a position is made with
+        the token AFTER it, so a block shared through the prefix cache ends
+        on a row made with another request's continuation; an exported or
+        tiered prompt leaves before its last position's row exists (that row
+        waits for the first output token), and the importer's first step
+        would not know."""
+        if ec.speculative_k <= 0 or ec.speculative_draft != "mtp":
+            return False
+        from ray_tpu.models.interface import model_of
+
+        model = model_of(model_cfg)
+        drafter = model.drafter(model_cfg) if model.drafter else None
+        if drafter is None:
+            raise ValueError(
+                f"speculative_draft='mtp' needs a model with a drafter of its own: a {model.name} "
+                "model of this configuration keeps none"
+            )
+        if ec.speculative_k != drafter.window - 1:
+            raise ValueError(
+                f"speculative_k={ec.speculative_k} cannot run here: the {drafter.kind} drafter of this "
+                f"model drafts {drafter.window - 1} token(s) a step"
+            )
+        why = f"a {model.name} model's {drafter.kind} drafter writes a row a token beside the model's own, made with the NEXT token: "
+        refused = {
+            "prefix_cache_enabled": (ec.prefix_cache_enabled,
+                "a shared block's last row was made with another request's continuation (set it to False)"),
+            "kv_transfer_enabled": (ec.kv_transfer_enabled,
+                "an exported prompt leaves before its last position's row is written"),
+            "kv_tier_enabled": (ec.kv_tier_enabled,
+                "tier write-back and resume move blocks by their tokens' digest, whatever followed them"),
+        }
+        for field, (on, reason) in refused.items():
+            if on:
+                raise ValueError(f"{field} cannot run here: {why}{reason}")
+        return True
 
     def start(self) -> "InferenceEngine":
         if self._thread is None or not self._thread.is_alive():
@@ -1172,8 +1231,11 @@ class InferenceEngine:
                 )
                 prompt = req.effective_prompt
                 tokens = prompt[start : start + chunk]
+                # what a model that drafts for itself runs its drafter with
+                follows = prompt[start + chunk] if start + chunk < len(prompt) else -1
             launched = self.runner.launch_prefill(
-                tokens, row, start, clock, slot=self.blocks.slot_of(req.request_id)
+                tokens, row, start, clock, slot=self.blocks.slot_of(req.request_id),
+                next_token=follows,
             )
             chunks.append((req, prompt, start + chunk, launched))
         n_prefill_tokens = 0 if ahead else self._commit_chunks(chunks)
@@ -1187,8 +1249,13 @@ class InferenceEngine:
         # throughput lever, never a dependency.
         spec_slots: List[tuple] = []
         plain: List[Request] = []
+        drafting: List[Request] = []
         if self.spec is None:
             plain = plan.decodes
+        elif self._mtp:
+            # the model drafts for itself, in the step's own program, for
+            # every slot at once: nothing is proposed here
+            drafting = plan.decodes
         else:
             # proposing is deciding what this step runs: schedule's time
             with clock.phase("schedule"):
@@ -1239,6 +1306,8 @@ class InferenceEngine:
             with clock.phase("emit"), clock.part("commit"):
                 for (req, drafts), tokens in zip(spec_slots, sampled):
                     self._spec_commit(req, drafts, tokens)
+        if drafting:
+            self._mtp_step(drafting)
         with clock.phase("bookkeeping"):
             if n_prefill_tokens:
                 self._prefill_token_times.append((time.monotonic(), n_prefill_tokens))
@@ -1329,6 +1398,10 @@ class InferenceEngine:
                         self._complete_prefill_export(req, prompt)
                     else:
                         req.state = DECODE
+                        if self._mtp:
+                            # prefilled (again): whatever draft it had is not
+                            # the one after this context
+                            self.spec.release(req.request_id)
                         self._emit_token(req, token)
         return n_prefill_tokens
 
@@ -1474,6 +1547,73 @@ class InferenceEngine:
             self._spec_rollbacks += 1
             m["spec_rollbacks"].inc()
         self.blocks.trim_to(req.request_id, req.context_len)
+
+    def _mtp_step(self, reqs: List[Request]) -> None:
+        """The decode step of a model that drafts for itself. A slot's window
+        is ``[x_n, d]``, its committed last token and the draft the step
+        before handed back (``[x_n]`` alone where the plan left no room for a
+        draft), or, for a slot without a draft yet, the window one position
+        earlier with both tokens committed (``known`` 2: the drafter's row at
+        the first waits for exactly this step). An all-greedy batch is ONE
+        launch: the device verifies, picks, compares, runs the drafter over
+        what it committed and drafts again, and the host reads three small
+        integers a slot. Any other batch reads both rows' logits, accepts
+        with the engine's own sampler (:meth:`_spec_sample`: exact match) and
+        launches the drafter as a second program, whose run the commits
+        overlap. Either way :meth:`_spec_commit` emits."""
+        clock = self._clock
+        wake = self._wake_after_launch
+        with clock.phase("launch"), clock.part("rows"):
+            windows, known, ctxs, drafts = [], [], [], []
+            for r in reqs:
+                last, draft = r.generated[-1], self.spec.draft_of(r.request_id)
+                if draft is None:
+                    before = r.generated[-2] if len(r.generated) > 1 else r.prompt[-1]
+                    windows.append([before, last])
+                    ctxs.append(r.context_len - 2)
+                    drafts.append([])
+                else:
+                    drafts.append([draft] if r.spec_step_k > 0 else [])
+                    windows.append([last] + drafts[-1])
+                    ctxs.append(r.context_len - 1)
+                known.append(2 if draft is None else 1)
+            rows = [self.blocks.table_row(r.request_id, self.runner.max_blocks_per_seq) for r in reqs]
+            greedy = all(r.temperature <= 0.0 for r in reqs)
+        launched = self.runner.launch_mtp_step(windows, known, rows, ctxs, clock, greedy=greedy)
+        out = self.runner.read(launched, clock, wake)
+        second = None
+        with clock.phase("sample"):
+            if greedy:  # [new tokens.., accepted, the next draft] a slot
+                sampled = [
+                    [int(t) for t in (row[: 1 + row[-2]] if k == 1 else row[:1])]
+                    for row, k in zip(out, known)
+                ]
+                nxt = [int(row[-1]) for row in out]
+            else:
+                sampled = [
+                    self._spec_sample(r, d, lg[len(w) - 1 - len(d) : len(w)])
+                    for r, d, w, lg in zip(reqs, drafts, windows, out)
+                ]
+        if not greedy:
+            # the token after each position of a window, as far as committed
+            follows = [
+                (w[1:] if k == 2 else []) + toks for w, k, toks in zip(windows, known, sampled)
+            ]
+            second = self.runner.launch_mtp_draft(launched, follows, rows, ctxs, clock)
+        counts = self._mtp_counts
+        counts["launches_fused" if greedy else "launches_split"] += 1
+        counts["step_launches"] += 1
+        counts["slot_steps"] += len(reqs)
+        with clock.phase("emit"), clock.part("commit"):
+            for req, d, toks in zip(reqs, drafts, sampled):
+                had = len(req.generated)
+                self._spec_commit(req, d, toks)
+                counts["committed_tokens"] += len(req.generated) - had
+        if second is not None:
+            nxt = [int(t) for t in self.runner.read(second, clock, wake)]
+        for req, draft in zip(reqs, nxt):
+            if not req.finished:
+                self.spec.keep(req.request_id, draft)
 
     # -- internals --------------------------------------------------------
     def _sample(
@@ -2411,6 +2551,7 @@ class InferenceEngine:
                 "proposed_tokens": prop,
                 "accepted_tokens": acc,
                 "rollbacks": self._spec_rollbacks,
+                **(self._mtp_counts if self._mtp else {}),
                 "acceptance_rate": round(acc / prop, 4) if prop else 0.0,
             }
         return s

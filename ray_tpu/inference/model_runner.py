@@ -163,6 +163,31 @@ def decode_program(step, cfg, pools: int, widest: int):
     return paged_decode_step
 
 
+def mtp_programs(drafter, cfg):
+    """The three programs of a model's own drafter (``models/interface.py::
+    Drafter``), each named for the trace: the ONE program of an all-greedy
+    step, its three small results packed into one int32 array ``[B, window +
+    2]`` (the new tokens, how many drafts were accepted, the next draft: one
+    copy to the host), and the two of a step with the host's sampler between
+    (the second hands back the next drafts, its argmax, beside its logits,
+    which stay on the device unless asked for)."""
+    import jax.numpy as jnp
+
+    def paged_mtp_step(*args):
+        cache, (new, accepted, draft), counters = drafter.step(cfg, *args)
+        return cache, jnp.concatenate([new, accepted[:, None], draft[:, None]], axis=1), counters
+
+    def paged_mtp_verify(*args):
+        cache, logits, hidden, counters = drafter.verify(cfg, *args)
+        return cache, (logits, hidden), counters
+
+    def paged_mtp_draft(*args):
+        cache, logits, counters = drafter.draft(cfg, *args)
+        return cache, (jnp.argmax(logits, axis=-1).astype(jnp.int32), logits), counters
+
+    return paged_mtp_step, paged_mtp_verify, paged_mtp_draft
+
+
 @dataclass
 class Launched:
     """A paged step on its way to the device: what :meth:`PagedModelRunner.
@@ -181,6 +206,9 @@ class Launched:
     #: a decode launch's picks (``[largest decode bucket]`` int32, on the
     #: device): what the NEXT decode launch may take a row's token from
     picks: Any = None
+    #: the first half of a drafter's two-program step: the window's last
+    #: residuals, on the device, for the second half
+    hidden: Any = None
 
 
 class PagedModelRunner:
@@ -196,6 +224,7 @@ class PagedModelRunner:
         verify_buckets: Sequence[int] = (),
         cache_dtype=None,
         state_slots: int = 0,
+        drafter: bool = False,
     ):
         import jax
         import jax.numpy as jnp
@@ -209,6 +238,15 @@ class PagedModelRunner:
         #: the three paged entry points, the cache description, what a
         #: window's attention reads
         self.model = model_of(cfg)
+        #: the model's OWN drafter where the engine drafts with it
+        #: (``speculative_draft`` ``"mtp"``), else None: decode is then its
+        #: step programs (:meth:`launch_mtp_step`) and a prefill chunk runs it
+        #: over the prompt; plain decode and verify are not warmed
+        self.drafter = None
+        if drafter:
+            self.drafter = self.model.drafter(cfg) if self.model.drafter else None
+            if self.drafter is None:
+                raise ValueError(f"a {self.model.name} model of this configuration has no drafter of its own")
         self.params = params
         self.block_size = block_size
         self.num_blocks = num_blocks
@@ -286,7 +324,8 @@ class PagedModelRunner:
         self.held_experts: Optional[Tuple[int, int]] = self.model.held_experts(cfg)
         if self.held_experts is not None:
             keys = ("launches", "assignments", "held_assignments", "bias_changed",
-                    "expert_slots", "experts_touched", "max_load", "mean_load")
+                    "expert_slots", "experts_touched", "max_load", "mean_load",
+                    "routed_rows", "group_changed")
             self.moe = {kind: dict.fromkeys(keys, 0) for kind in ("decode", "prefill")}
         self.warmup_programs: Dict[str, float] = {}
 
@@ -310,6 +349,10 @@ class PagedModelRunner:
         self._verify_jit = jax.jit(
             partial(self.model.paged_verify_step, cfg), donate_argnums=donated
         )
+        if self.drafter is not None:
+            self._mtp_step_jit, self._mtp_verify_jit, self._mtp_draft_jit = (
+                jax.jit(f, donate_argnums=(1,)) for f in mtp_programs(self.drafter, cfg)
+            )
         # COW block duplication (prefix cache): cache is arg 0 here.
         # partial() gives THIS runner its own jit identity — a bare
         # module-level function would share one compiled-program cache
@@ -395,6 +438,12 @@ class PagedModelRunner:
             )
         return logits, (loads[0] if loads else None)
 
+    def _path_name(self, window: int) -> str:
+        """The launch span's ``path`` of that window's programs: the attention
+        path's name and, where the program runs the model's drafter, its kind."""
+        name = self._path(window).name
+        return name if self.drafter is None else f"{name}+{self.drafter.kind}"
+
     def _path(self, window: int) -> AttentionPath:
         """The attention path of the programs of that query window."""
         path = self.attention_paths.get(window)
@@ -427,8 +476,22 @@ class PagedModelRunner:
             self._step(
                 partial(self._warm, bucket=c), "paged_prefill_step", self._prefill_jit,
                 tokens, row, np.int32(0), np.int32(0), slots=np.int32(0),
+                last=() if self.drafter is None else (np.int32(-1),),
             )
         batches = buckets_decode if buckets_decode is not None else self.decode_buckets
+        if self.drafter is not None:
+            # decode IS the drafter's step: the one program of an all-greedy
+            # batch and the two of a sampled one, and no plain decode or verify
+            C = self.drafter.window
+            for b in batches:
+                for w in self.table_widths:
+                    warm = partial(self._warm, bucket=f"{b}x{C}x{w * bs}")
+                    window = (np.zeros((b, C), np.int32), np.zeros((b, w), np.int32),
+                              np.zeros(b, np.int32), np.zeros(b, np.int32))
+                    self._step(warm, "paged_mtp_step", self._mtp_step_jit, *window, np.ones(b, np.int32))
+                    (_, hidden), _ = self._step(warm, "paged_mtp_verify", self._mtp_verify_jit, *window)
+                    self._step(warm, "paged_mtp_draft", self._mtp_draft_jit, hidden, *window)
+            batches = ()
         for b in batches:
             for w in self.table_widths:
                 self._step(
@@ -445,7 +508,7 @@ class PagedModelRunner:
         # The batch axis rides the decode buckets: every (B-bucket,
         # window-bucket, table-width) triple a live engine can issue gets
         # compiled here.
-        for c in self.verify_buckets:
+        for c in self.verify_buckets if self.drafter is None else ():
             for b in batches:
                 for w in self.table_widths:
                     self._step(
@@ -532,12 +595,16 @@ class PagedModelRunner:
         ctx_len: int,
         clock: Optional[timeline.PhaseClock] = None,
         slot: int = 0,
+        next_token: int = -1,
     ) -> Launched:
         """Put one prefill chunk on its way to the device and return without
         waiting for it: :meth:`read` gives the logits [vocab] (fp32 numpy) of
         the chunk's last valid token. ``slot``: the request's state slot (a
         model with per-sequence state; a chunk at ``ctx_len`` 0 starts the
-        slot's state from zeros inside the program). ``clock``: the caller's
+        slot's state from zeros inside the program). ``next_token``: the
+        prompt's token after the chunk (-1: the chunk is the prompt's last),
+        for a runner whose model drafts for itself: the chunk then runs the
+        drafter over its positions too. ``clock``: the caller's
         account for the call's phases, if it keeps one (the runner's own
         otherwise)."""
         clock = clock or self.clock
@@ -556,7 +623,7 @@ class PagedModelRunner:
         pw["live_tokens"] += ctx_len + true_len
         pw["read_tokens"] += read
         with clock.phase(
-            "launch", program="paged_prefill_step", bucket=bucket, path=path.name,
+            "launch", program="paged_prefill_step", bucket=bucket, path=self._path_name(bucket),
         ):
             with clock.part("inputs"):
                 padded = np.zeros(bucket, np.int32)
@@ -566,6 +633,7 @@ class PagedModelRunner:
                 logits, loads = self._step(
                     self._run, "paged_prefill_step", self._prefill_jit,
                     padded, row, np.int32(ctx_len), np.int32(true_len), slots=np.int32(slot),
+                    last=() if self.drafter is None else (np.int32(next_token),),
                 )
         return Launched("prefill", logits, loads)
 
@@ -577,13 +645,15 @@ class PagedModelRunner:
         clock: Optional[timeline.PhaseClock] = None,
         launched: Optional[Callable[[], None]] = None,
         slot: int = 0,
+        next_token: int = -1,
     ) -> np.ndarray:
         """:meth:`launch_prefill` and :meth:`read` at once: the logits
         [vocab] of the chunk's last valid token. ``launched``: called once
         when the program is on its way to the device, before this thread
         waits for it (here and in :meth:`decode` and :meth:`verify_batch`):
         the caller's chance to do host work that the device's run hides."""
-        return self.read(self.launch_prefill(tokens, block_row, ctx_len, clock, slot), clock, launched)
+        step = self.launch_prefill(tokens, block_row, ctx_len, clock, slot, next_token)
+        return self.read(step, clock, launched)
 
     def read(
         self,
@@ -626,14 +696,18 @@ class PagedModelRunner:
         grouped matmul pays for). ``counters`` holds that array under
         ``load`` and, where the router's choice adds a per-expert bias to the
         scores, ``bias_changed`` ``[n_layers]``: real rows whose kept set
-        differs from the top-k of the scores alone."""
+        differs from the top-k of the scores alone; where the choice is
+        limited to groups of experts, ``routed_rows`` and ``group_changed``
+        ``[n_layers]``: the real rows, and those whose kept set differs from
+        the plain top-k of score + bias."""
         loads = counters["load"]
         lo, hi = self.held_experts
         acc = self.moe[kind]
         acc["launches"] += 1
         acc["assignments"] += int(loads.sum())
         acc["held_assignments"] += int(loads[:, lo:hi].sum())
-        acc["bias_changed"] += int(np.sum(counters.get("bias_changed", 0)))
+        for key in ("bias_changed", "routed_rows", "group_changed"):
+            acc[key] += int(np.sum(counters.get(key, 0)))
         acc["expert_slots"] += loads.size
         acc["experts_touched"] += int(np.count_nonzero(loads))
         acc["max_load"] += int(loads.max(axis=1).sum())
@@ -716,6 +790,98 @@ class PagedModelRunner:
                 )
         out = self.read(Launched("decode", logits, loads), clock, launched)
         return [out[i, : len(w)] for i, w in enumerate(windows)]
+
+    def _mtp_inputs(self, windows, block_rows, ctx_lens, bucket: int, M: int):
+        """A drafter step's padded inputs: ``(tokens [bucket, window], tables
+        [bucket, M], ctx [bucket], true_lens [bucket])``; a padding slot has
+        no row (``true_len`` 0) and the null block's table."""
+        C = self.drafter.window
+        tokens = np.zeros((bucket, C), np.int32)
+        tables = np.zeros((bucket, M), np.int32)
+        ctx = np.zeros(bucket, np.int32)
+        tl = np.zeros(bucket, np.int32)
+        for i, w in enumerate(windows):
+            tokens[i, : len(w)] = w
+            tables[i] = block_rows[i][:M]
+            ctx[i] = ctx_lens[i]
+            tl[i] = len(w)
+        return tokens, tables, ctx, tl
+
+    def launch_mtp_step(
+        self,
+        windows: Sequence[Sequence[int]],
+        known: Sequence[int],
+        block_rows: Sequence[Sequence[int]],
+        ctx_lens: Sequence[int],
+        clock: Optional[timeline.PhaseClock] = None,
+        greedy: bool = True,
+    ) -> Launched:
+        """Put a decode step of a model that drafts for itself on its way:
+        each slot's window (``[x_n, d]``: the committed last token and the
+        draft; ``[x_n]`` where no draft rides; ``known[i]`` 2: both tokens
+        committed, a slot whose drafter has no row at the first yet) at
+        positions ``ctx_lens[i] ..``. ``greedy``: the ONE program (verify, the
+        picks, the comparison, the drafter over what was committed, the next
+        drafts): :meth:`read` gives ``[n, window + 2]`` int32, a slot's new
+        tokens, how many drafts were accepted (its new tokens are the first 1
+        + accepted), its next draft. Otherwise the first of two: :meth:`read`
+        gives the logits ``[n, window, V]`` for the host's sampler, and
+        :meth:`launch_mtp_draft` goes on from what it sampled."""
+        clock = clock or self.clock
+        n, C = len(windows), self.drafter.window
+        bucket = _round_up_bucket(n, self.decode_buckets)
+        M = self._table_width(
+            [c + len(w) for c, w in zip(ctx_lens, windows)], bucket, C,
+            reach=[c + C for c in ctx_lens],
+        )
+        program = "paged_mtp_step" if greedy else "paged_mtp_verify"
+        with clock.phase(
+            "launch", program=program, bucket=f"{bucket}x{C}x{M * self.block_size}",
+            path=self._path_name(C),
+        ):
+            with clock.part("inputs"):
+                inputs = self._mtp_inputs(windows, block_rows, ctx_lens, bucket, M)
+                kn = np.ones(bucket, np.int32)
+                kn[:n] = known
+            with clock.part("call"):
+                if greedy:
+                    out, loads = self._step(self._run, program, self._mtp_step_jit, *inputs, kn)
+                    return Launched("decode", out, loads, n)
+                (logits, hidden), loads = self._step(self._run, program, self._mtp_verify_jit, *inputs)
+        return Launched("decode", logits, loads, n, hidden=hidden)
+
+    def launch_mtp_draft(
+        self,
+        verified: Launched,
+        follows: Sequence[Sequence[int]],
+        block_rows: Sequence[Sequence[int]],
+        ctx_lens: Sequence[int],
+        clock: Optional[timeline.PhaseClock] = None,
+        logits: bool = False,
+    ) -> Launched:
+        """The second program of a step with the host's sampler between:
+        the drafter over the positions of each window whose FOLLOWING token
+        the host has committed (``follows[i]``: those tokens, in order), from
+        the residuals ``verified`` left on the device. :meth:`read` gives the
+        next drafts ``[n]`` int32 (with ``logits`` the drafter's logits ``[n,
+        V]`` they are the argmax of: the same program)."""
+        clock = clock or self.clock
+        n, C = len(follows), self.drafter.window
+        bucket = _round_up_bucket(n, self.decode_buckets)
+        M = _round_up_bucket(
+            -(-max(c + len(f) for c, f in zip(ctx_lens, follows)) // self.block_size), self.table_widths
+        )
+        with clock.phase(
+            "launch", program="paged_mtp_draft", bucket=f"{bucket}x{C}x{M * self.block_size}",
+            path=self._path_name(C),
+        ):
+            with clock.part("inputs"):
+                inputs = self._mtp_inputs(follows, block_rows, ctx_lens, bucket, M)
+            with clock.part("call"):
+                (picks, lg), loads = self._step(
+                    self._run, "paged_mtp_draft", self._mtp_draft_jit, verified.hidden, *inputs
+                )
+        return Launched("decode", lg if logits else picks, loads, n)
 
     def launch_decode(
         self,

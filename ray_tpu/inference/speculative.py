@@ -15,7 +15,7 @@ decode by construction, for greedy AND seeded temperature>0 sampling.
 The proposer therefore only affects THROUGHPUT (acceptance rate), never
 content: any drafting strategy is sound.
 
-Two proposers:
+Three proposers, and where each runs:
 
 * :class:`NgramProposer` — model-free prompt-lookup decoding: find the
   most recent previous occurrence of the context's trailing n-gram and
@@ -30,8 +30,19 @@ Two proposers:
   purely by position, so stale slots past the committed context are
   inert until rewritten).
 
-Both expose the same surface the engine drives: ``propose(ctx, k)``,
-``release(request_id)``, ``compile_count()`` /
+* :class:`MtpDrafts` — the TARGET's own drafter (a multi-token-prediction
+  module kept after training: ``models/interface.py::Drafter``), which runs
+  inside the target's programs over the target's cache, for the whole batch at
+  once: the verify window, the acceptance, the drafter over what was
+  committed and the next drafts are ONE launch a step for an all-greedy
+  batch, two around the host's sampler otherwise
+  (``model_runner.py::launch_mtp_step``). Nothing is proposed on the host:
+  this class only keeps each request's next draft between steps.
+
+The first two run on the HOST, a slot at a time, inside the engine's
+``schedule`` phase (``propose(ctx, k)``; the draft model on its own runner,
+batch-1 launches a slot); the third on the device in the step itself. All
+expose ``release(request_id)`` and ``compile_count()`` /
 ``recompiles_after_warmup()`` for the zero-recompile gate.
 """
 
@@ -176,3 +187,31 @@ class DraftModelProposer:
         rid = request_id or "draft"
         self._written.pop(rid, None)
         self.blocks.free(rid)
+
+
+class MtpDrafts:
+    """The next draft of each request of an engine whose model drafts for
+    itself: what the step's program handed back, kept until the next step
+    puts it into that slot's verify window. A request without one (its
+    prefill just ended, or it was preempted and prefilled again) has no row
+    of the drafter's at its last position yet: the engine then verifies the
+    window one position earlier, both tokens committed."""
+
+    def __init__(self) -> None:
+        self._next: Dict[str, int] = {}
+
+    def draft_of(self, request_id: str) -> Optional[int]:
+        return self._next.get(request_id)
+
+    def keep(self, request_id: str, draft: int) -> None:
+        self._next[request_id] = int(draft)
+
+    def release(self, request_id: str) -> None:
+        self._next.pop(request_id, None)
+
+    # the drafter's programs are the target runner's own, counted there
+    def compile_count(self) -> int:
+        return 0
+
+    def recompiles_after_warmup(self) -> int:
+        return 0

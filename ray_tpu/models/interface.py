@@ -2,7 +2,8 @@
 serving engine (and the train programs) and a model module, and the
 description of the paged cache a model owns.
 
-Three small records and one dict, no plugin system:
+Four small records and one dict, no plugin system (the fourth,
+:class:`Drafter`, is what a model with a drafter of its own says of it):
 
 * :class:`CacheLayout` says what ONE token leaves in the paged cache of
   each layer: named arrays ``[n_layers, num_blocks, block_size, *row]``
@@ -214,6 +215,33 @@ def scatter_paged_blocks(cache, blocks, payload):
     return out
 
 
+@dataclass(frozen=True)
+class Drafter:
+    """A model's OWN drafter for speculative decoding (a multi-token-prediction
+    module kept after training), run by the target's programs over the
+    target's cache: ``EngineConfig.speculative_draft`` ``"mtp"``."""
+
+    #: the drafter's name, for ``engine_stats()`` and the launch span's path
+    kind: str
+    #: positions of a step's verify window: the committed last token and the drafts
+    window: int
+    #: rows a token it writes to the paged cache BESIDE the model's own
+    #: (they are the last ``cache_layers`` of ``CacheLayout.n_layers``)
+    cache_layers: int
+    #: the ONE program of an all-greedy step, ``(cfg, params, cache, tokens [B,
+    #: window], block_tables, ctx_lens, true_lens, known) -> (cache, (new [B,
+    #: window], accepted [B], draft [B]), counters)``: verify, the picks, the
+    #: comparison, the drafter over what was committed, the next drafts
+    step: Callable
+    #: the same step as two programs around the host's sampler: ``verify(cfg,
+    #: params, cache, tokens, block_tables, ctx_lens, true_lens) -> (cache,
+    #: logits [B, window, V], hidden, counters)``, then ``draft(cfg, params,
+    #: cache, hidden, next_tokens, block_tables, ctx_lens, true_lens) -> (cache,
+    #: logits [B, V], counters)``
+    verify: Callable
+    draft: Callable
+
+
 class AttentionPath(NamedTuple):
     """How a program of one query window attends over the paged cache."""
 
@@ -272,15 +300,22 @@ class Model:
     #: [, counters])``; a sequence's state reads as zeros where its context
     #: starts (``ctx_len == 0``), whatever the slot held
     state_layout: Optional[Callable] = None
+    #: ``None`` for a model without a drafter of its own, else (cfg) -> its
+    #: :class:`Drafter` (or None where the configuration keeps none). Such a
+    #: model's ``paged_prefill_step`` takes one more argument last, the token
+    #: that follows the chunk (-1: none yet), and then runs the drafter over
+    #: the chunk too; ``cache_layout`` counts the drafter's rows
+    drafter: Optional[Callable] = None
 
 
 def model_of(cfg) -> Model:
     """The model a config object belongs to, by the config's type."""
-    from ray_tpu.models import kimi_linear, llama, xing4
+    from ray_tpu.models import deepseek_v3, kimi_linear, llama, xing4
 
     models = {
         llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL,
         kimi_linear.KimiLinearConfig: kimi_linear.MODEL,
+        deepseek_v3.DeepseekV3Config: deepseek_v3.MODEL,
     }
     try:
         return models[type(cfg)]

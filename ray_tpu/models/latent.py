@@ -179,8 +179,10 @@ def flash_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
     kernel on the expanded path (:func:`attend_flash`) over ``keys`` key
     positions (a table's width in tokens; the runner's full width where
     none is given): ``ops/latent_flash.py::kernel_serves`` on what the code
-    can observe (backend, dtype, whole tiles, the head widths). Off a TPU
-    the cache is not looked at."""
+    can observe (backend, dtype, whole tiles, the head widths: a value head
+    of 192 beside keys of 128 + 64, DeepSeek-V3's, goes through as a value
+    tile as wide as its array; nothing is padded here). Off a TPU the cache
+    is not looked at."""
     if (backend or jax.default_backend()) != "tpu":
         return False
     return latent_flash.kernel_serves(
@@ -390,7 +392,7 @@ def block_size_of(cfg, cache) -> int:
     return math.prod(cache["latent"].shape[2:]) // cfg.latent_width
 
 
-def write_blocks(cfg, cache, block_tables, first, blocks):
+def write_blocks(cfg, cache, block_tables, first, blocks, layer0: int = 0):
     """Every layer's updated blocks of a step, ``blocks [n_layers, B, nblk
     * block_size, kr + dr]`` (:func:`latent_attention`), into the cache:
     ONE scatter of whole rows of the cache seen as ``[layers x blocks,
@@ -399,13 +401,15 @@ def write_blocks(cfg, cache, block_tables, first, blocks):
     160 ms on the chip; one whose window spans the layers copied the cache
     whole). A block the window touches is rewritten with its old rows and
     the new; what lies past a slot's blocks, and a padding slot, is the null
-    block: colliding trash writes are fine, nothing masked-in reads them."""
-    L, N, *block = cache["latent"].shape
+    block: colliding trash writes are fine, nothing masked-in reads them.
+    ``blocks`` may be those of the ``blocks.shape[0]`` layers from ``layer0``
+    on alone (a module that writes its own rows of a cache it shares)."""
+    (_, N, *block), L = cache["latent"].shape, blocks.shape[0]
     B = first.shape[0]
     bs = block_size_of(cfg, cache)
     nblk = blocks.shape[2] // bs
     tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
     ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at,), (nblk,)))(tables, first // bs)
-    rows = (jnp.arange(L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
-    flat = cache["latent"].reshape(L * N, *block).at[rows].set(blocks.reshape(L * B * nblk, *block))
-    return {"latent": flat.reshape(L, N, *block)}
+    rows = (jnp.arange(layer0, layer0 + L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
+    flat = cache["latent"].reshape(-1, *block).at[rows].set(blocks.reshape(L * B * nblk, *block))
+    return {"latent": flat.reshape(cache["latent"].shape)}

@@ -110,6 +110,12 @@ class Xing4Config:
     n_shared_experts: int = 1
     moe_top_k: int = 4
     routed_scaling_factor: float = 2.0
+    #: the choice limited to the ``topk_group`` best of ``n_group`` groups of
+    #: neighbouring experts (``ops/moe.py::route``); 1: no group stage
+    n_group: int = 1
+    topk_group: int = 1
+    #: streams of the hyper-connected residual; 0: the PLAIN residual ``x +
+    #: F(norm(x))`` (no maps, no stream axis: ``models/deepseek_v3.py``)
     hc_mult: int = 4
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -131,6 +137,12 @@ class Xing4Config:
     @property
     def n_moe_layers(self) -> int:
         return self.n_layers - self.n_dense_layers
+
+    @property
+    def depth(self) -> int:
+        """The blocks a token passes through: what the seeded weights' last
+        projections are scaled down by (:func:`init_params`)."""
+        return self.n_layers
 
     @property
     def n_held(self) -> int:
@@ -180,7 +192,7 @@ def _group_shapes(cfg: Xing4Config, moe: bool) -> Dict[str, Tuple[int, ...]]:
         "wo": (H, cfg.v_head_dim, D),
         "mlp_norm": (D,),
     }
-    for sub in ("hc_attn", "hc_mlp"):
+    for sub in ("hc_attn", "hc_mlp") if n else ():
         shapes.update({f"{sub}_phi": (n * D, maps), f"{sub}_b": (maps,), f"{sub}_alpha": (3,)})
     if moe:
         Fm, Fs = cfg.moe_hidden, cfg.n_shared_experts * cfg.moe_hidden
@@ -269,69 +281,71 @@ def init_params(cfg: Xing4Config, rng: jax.Array) -> Dict[str, Any]:
         return _init_params(cfg, rng)
 
 
-def _init_params(cfg: Xing4Config, rng: jax.Array) -> Dict[str, Any]:
+def _draw(key, shape, fan_in, dtype):
+    """Normal / sqrt(fan_in), drawn a slice of the leading axis at a time:
+    the float32 draw of a whole stacked weight (or of the embedding) is
+    gigabytes beside 12 GB of weights being made."""
+    def draw(k):
+        return (jax.random.normal(k, shape[1:], F32) / math.sqrt(fan_in)).astype(dtype)
+
+    return jax.lax.map(draw, jax.random.split(key, shape[0]))
+
+
+def _init_group(cfg: Xing4Config, key, count: int, moe: bool) -> Dict[str, Any]:
+    """``count`` stacked layers of a kind (:func:`init_params` says how drawn)."""
     n = cfg.hc_mult
+    shapes = _group_shapes(cfg, moe)
+    out = {}
+    for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes))):
+        full = (count, *shape)
+        if name.endswith("norm"):
+            out[name] = jnp.ones(full, cfg.dtype)
+        elif name.endswith("_phi"):
+            phi = _draw(k, full, shape[0], F32)
+            out[name] = phi.at[..., 2 * n:].multiply(0.25)
+        elif name.endswith("_alpha"):
+            out[name] = jax.random.uniform(k, full, F32, 0.5, 1.5)
+        elif name.endswith("_b"):
+            b = jax.random.normal(k, full, F32)
+            res = 0.2 * b[:, 2 * n:] + jnp.eye(n, dtype=F32).reshape(-1)
+            out[name] = jnp.concatenate([b[:, : 2 * n], res], axis=1)
+        elif name == "router":
+            # routing logits are precision-sensitive: keep f32
+            out[name] = _draw(k, full, shape[0], F32)
+        elif name == "router_bias":
+            out[name] = 0.03 * jax.random.normal(k, full, F32)
+        else:
+            # contraction dims: all but the last of a 2-D weight; the
+            # rank of the up-projections; heads x v of ``wo``; an
+            # expert's own input width
+            fan_in = {"w_qb": shape[0], "w_kvb": shape[0], "wo": shape[0] * shape[1]}.get(
+                name, shape[-2]
+            )
+            if name in ("wo", "w_down", "shared_down"):
+                # a sublayer's last projection, scaled down by the depth
+                # (the 1 / sqrt(2 L) of GPT-2's initialisation)
+                fan_in *= 2 * cfg.depth
+            if moe and name == "w_down":
+                fan_in *= 16  # a ROUTED expert's output a quarter of that
+            out[name] = _draw(k, full, fan_in, cfg.dtype)
+    return out
+
+
+def _init_params(cfg: Xing4Config, rng: jax.Array) -> Dict[str, Any]:
     k_embed, k_head, k_dense, k_moe = jax.random.split(rng, 4)
-
-    def dense(key, shape, fan_in, dtype=cfg.dtype):
-        """Normal / sqrt(fan_in), drawn a slice of the leading axis at a
-        time: the float32 draw of a whole stacked weight (or of the
-        embedding) is gigabytes beside 12 GB of weights being made."""
-        def draw(k):
-            return (jax.random.normal(k, shape[1:], F32) / math.sqrt(fan_in)).astype(dtype)
-
-        return jax.lax.map(draw, jax.random.split(key, shape[0]))
-
-    def group(key, count: int, moe: bool):
-        shapes = _group_shapes(cfg, moe)
-        out = {}
-        for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes))):
-            full = (count, *shape)
-            if name.endswith("norm"):
-                out[name] = jnp.ones(full, cfg.dtype)
-            elif name.endswith("_phi"):
-                phi = dense(k, full, shape[0], F32)
-                out[name] = phi.at[..., 2 * n:].multiply(0.25)
-            elif name.endswith("_alpha"):
-                out[name] = jax.random.uniform(k, full, F32, 0.5, 1.5)
-            elif name.endswith("_b"):
-                b = jax.random.normal(k, full, F32)
-                res = 0.2 * b[:, 2 * n:] + jnp.eye(n, dtype=F32).reshape(-1)
-                out[name] = jnp.concatenate([b[:, : 2 * n], res], axis=1)
-            elif name == "router":
-                # routing logits are precision-sensitive: keep f32
-                out[name] = dense(k, full, shape[0], F32)
-            elif name == "router_bias":
-                out[name] = 0.03 * jax.random.normal(k, full, F32)
-            else:
-                # contraction dims: all but the last of a 2-D weight; the
-                # rank of the up-projections; heads x v of ``wo``; an
-                # expert's own input width
-                fan_in = {"w_qb": shape[0], "w_kvb": shape[0], "wo": shape[0] * shape[1]}.get(
-                    name, shape[-2]
-                )
-                if name in ("wo", "w_down", "shared_down"):
-                    # a sublayer's last projection, scaled down by the depth
-                    # (the 1 / sqrt(2 L) of GPT-2's initialisation)
-                    fan_in *= 2 * cfg.n_layers
-                if moe and name == "w_down":
-                    fan_in *= 16  # a ROUTED expert's output a quarter of that
-                out[name] = dense(k, full, fan_in)
-        return out
-
     # the two vocabulary-sized matrices in (up to) 16 slices of their leading axis
     v, d = math.gcd(16, cfg.vocab_size), math.gcd(16, cfg.dim)
     params = {
-        "embed": dense(k_embed, (v, cfg.vocab_size // v, cfg.dim), cfg.dim).reshape(
+        "embed": _draw(k_embed, (v, cfg.vocab_size // v, cfg.dim), cfg.dim, cfg.dtype).reshape(
             cfg.vocab_size, cfg.dim
         ),
         "final_norm": jnp.ones((cfg.dim,), cfg.dtype),
-        "lm_head": dense(k_head, (d, cfg.dim // d, cfg.vocab_size), cfg.dim).reshape(
+        "lm_head": _draw(k_head, (d, cfg.dim // d, cfg.vocab_size), cfg.dim, cfg.dtype).reshape(
             cfg.dim, cfg.vocab_size
         ),
     }
     for (name, count, moe), key in zip(_groups(cfg), (k_dense, k_moe)):
-        params[name] = group(key, count, moe)
+        params[name] = _init_group(cfg, key, count, moe)
     return params
 
 
@@ -435,6 +449,7 @@ def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
         valid=None if valid is None else valid.reshape(-1),
         scoring="sigmoid", scale=cfg.routed_scaling_factor,
         held=None if cfg.n_held == cfg.n_routed_experts else cfg.held_experts,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
     )
     return shared + routed.reshape(h.shape), aux
 
@@ -463,7 +478,12 @@ def _hyper(cfg: Xing4Config, p, sub: str, norm: str, X, F: Callable):
     """One sublayer through the hyper-connected residual: ``X <- H_res X +
     H_post^T (x) F(norm(H_pre X))``. ``F`` returns ``(y [..., D], extra)``;
     returns ``(X, extra)``. The maps and the two mixes are float32 at the
-    matmul's highest precision; the state is kept in ``cfg.dtype``."""
+    matmul's highest precision; the state is kept in ``cfg.dtype``. A
+    configuration without streams (``hc_mult`` 0) has the plain residual, ``X
+    [..., D]``: ``X + F(norm(X))``."""
+    if not cfg.hc_mult:
+        y, extra = F(rms_norm(X, p[norm], cfg.norm_eps))
+        return X + y.astype(X.dtype), extra
     pre, post, res = mhc_maps(cfg, p[f"{sub}_phi"], p[f"{sub}_b"], p[f"{sub}_alpha"], X)
     with jax.named_scope("mhc.mix"):
         x_in = jnp.einsum("...n,...nd->...d", pre, X.astype(F32), precision=_HIGHEST)
@@ -484,17 +504,18 @@ def _layer(cfg: Xing4Config, p, X, attention: Callable, valid, moe: bool):
     return X, rows, aux
 
 
-def _scan_layers(cfg: Xing4Config, params, X, attention: Callable, valid, wrap=None):
+def _scan_layers(cfg: Xing4Config, params, X, attention: Callable, valid, wrap=None, layer0: int = 0):
     """Every layer over ``X``, the layers of a kind under one scan.
     ``attention(p, h, layer) -> (out, rows)`` (``layer`` the layer's index
     in the model, traced). Returns ``(X, rows, aux)``: ``rows`` what the
     layers' attentions returned, stacked over ALL layers in order (None
     where they return None), ``aux`` the expert layers' stacked ``load
     [n_moe, E]``, ``bias_changed [n_moe]``, ``aux_loss [n_moe]`` (empty
-    without expert layers)."""
-    layer0 = 0
+    without expert layers). ``layer0``: the index the first layer goes by."""
     rows, aux = [], {}
     for name, count, moe in _groups(cfg):
+        if name not in params:
+            continue
         def body(carry, p, moe=moe):
             X, layer = carry
             X, layer_rows, layer_aux = _layer(
@@ -517,8 +538,8 @@ def _scan_layers(cfg: Xing4Config, params, X, attention: Callable, valid, wrap=N
 
 def _lm_head(cfg: Xing4Config, params, X):
     """The residual state ``X [..., n, D]`` -> float32 logits ``[..., vocab]``:
-    the streams summed, the final norm, the head."""
-    x = X.astype(F32).sum(axis=-2).astype(X.dtype)
+    the streams summed (where there are streams), the final norm, the head."""
+    x = X.astype(F32).sum(axis=-2).astype(X.dtype) if cfg.hc_mult else X
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(F32)
 
@@ -526,6 +547,8 @@ def _lm_head(cfg: Xing4Config, params, X):
 def _embed(cfg: Xing4Config, params, tokens):
     """The embedding of each token, repeated over the ``hc_mult`` streams."""
     x = params["embed"][tokens]
+    if not cfg.hc_mult:
+        return x
     return jnp.broadcast_to(x[..., None, :], (*x.shape[:-1], cfg.hc_mult, cfg.dim))
 
 
@@ -586,7 +609,7 @@ def next_token_loss(cfg: Xing4Config, params, tokens, targets, *, remat=False,
 # ``key_pos <= pos``, so stale rows past a slot's context are never read.
 
 
-def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tables):
+def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tables, embed=None):
     """Every layer of the model over the latent paged cache: the body of
     the three serving steps. ``tokens [B, C]``, ``pos [B, C]`` their global
     positions (contiguous a slot), ``valid [B, C]`` bool (padding rows reach
@@ -594,7 +617,10 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     context, or in the null block), ``block_tables [B, M]``. Per layer: the
     projections, attention over the cache with the window's own rows laid
     over it, ``wo``, the FFN, each through the hyper-connected residual.
-    Returns ``(cache, X [B, C, n, D], aux)``.
+    Returns ``(cache, X [B, C, n, D], aux)``. ``embed``: what stands where
+    the embedding of ``tokens`` would (a module that is fed something else:
+    ``models/deepseek_v3.py``'s drafter), with the index of the cache layer
+    its ``params`` write first, ``(X0, layer0)``.
 
     The layers READ the cache they were handed and the step writes every
     layer's blocks in ONE scatter after the last layer: a cache carried
@@ -619,8 +645,9 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
         )
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), blocks
 
-    X, blocks, aux = _scan_layers(cfg, params, _embed(cfg, params, tokens), attention, valid)
-    return _write_blocks(cfg, cache, block_tables, pos[:, 0], blocks), X, aux
+    X0, layer0 = (_embed(cfg, params, tokens), 0) if embed is None else embed
+    X, blocks, aux = _scan_layers(cfg, params, X0, attention, valid, layer0=layer0)
+    return _write_blocks(cfg, cache, block_tables, pos[:, 0], blocks, layer0), X, aux
 
 
 def _step_outputs(cache, logits, aux):
@@ -628,8 +655,15 @@ def _step_outputs(cache, logits, aux):
     layers, the counters the runner reads with the logits: ``load
     [n_moe, E]`` and ``bias_changed [n_moe]`` of the step's valid rows."""
     if aux:
-        return cache, logits, {"load": aux["load"], "bias_changed": aux["bias_changed"]}
+        return cache, logits, _counters(aux)
     return cache, logits
+
+
+def _counters(aux):
+    """The counters of a step's expert layers that the runner reads (the
+    group limit's where there is one)."""
+    keys = ("load", "bias_changed", "group_changed", "routed_rows")
+    return {k: aux[k] for k in keys if k in aux}
 
 
 def paged_prefill_step(cfg: Xing4Config, params, cache, tokens, block_table, ctx_len, true_len):

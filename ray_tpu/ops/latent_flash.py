@@ -81,7 +81,13 @@ def kernel_serves(
     window and the keys are whole tiles of whole ``(16, 128)`` registers and
     every product runs over whole lanes (``ds``: half a register's lanes is
     what Mosaic still takes as a block as wide as its array; compiled for a
-    described v5e, ``tests/test_olmoe.py``). Everything else (the CPU, odd
+    described v5e, ``tests/test_olmoe.py``). A value head of one and a half
+    lane tiles (``dv`` 192: DeepSeek-V3-style heads whose value is as wide as
+    the whole key) goes through as it is, a value tile ``[block_k, 192]`` as
+    wide as its array: Mosaic takes it (compiled for the same v5e, at 64
+    heads), the array lies in HBM on 256 lanes either way, and nothing is
+    padded by hand or written back; ``dv`` is whole lanes or whole lanes and
+    a half. Everything else (the CPU, odd
     widths) keeps the materialised softmax. Decided at trace time; the model
     runner asks the same question to know what a prefill launch reads."""
     backend = backend or jax.default_backend()
@@ -94,7 +100,8 @@ def kernel_serves(
         and window % block_q == 0
         and keys % block_k == 0
         and dk % 128 == 0
-        and dv % 128 == 0
+        and dv % 128 in (0, 64)
+        and dv >= 128
         and ds % 64 == 0
     )
 

@@ -3,7 +3,8 @@
 :func:`route` is the one routing of every MoE path: float32 router logits,
 a score an expert (``scoring``: a softmax over the experts, or a sigmoid
 each), ``top_k`` of the scores (for the choice alone, plus a per-expert
-``bias``), and the kept gates renormalised only where the architecture says
+``bias``, and where the architecture says so among the experts of its best
+GROUPS alone: ``n_group`` / ``topk_group``), and the kept gates renormalised only where the architecture says
 so (``renormalize``; OLMoE does not) and scaled by ``scale``.
 
 :func:`dropless_moe_ffn` is what runs wherever the experts this process
@@ -83,6 +84,8 @@ def route(
     scoring: str = "softmax",
     bias: Optional[jnp.ndarray] = None,
     scale: float = 1.0,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The routing of every MoE path. x: [T, d], router: [d, E] →
     ``(gates [T, k] float32, experts [T, k] int32, probs [T, E] float32)``.
@@ -96,7 +99,17 @@ def route(
     ``top_k`` alone; a kept expert's gate is its score without it.
     ``renormalize`` divides the kept gates by their sum (Mixtral, GShard);
     without it the gates are the scores' own values (OLMoE:
-    ``norm_topk_prob`` false). ``scale`` multiplies the gates last."""
+    ``norm_topk_prob`` false). ``scale`` multiplies the gates last.
+
+    ``n_group`` > 1 LIMITS the choice to groups of experts (DeepSeek-V3's
+    ``noaux_tc``: the experts of a group live on one node, and a token may
+    reach ``topk_group`` nodes): the ``E`` experts are ``n_group`` runs of
+    ``E / n_group`` neighbours; a group's score is the sum of its TWO largest
+    ``score + bias``; the ``topk_group`` groups that score highest stay, and
+    the ``top_k`` are chosen among THEIR experts alone (every other expert's
+    ``score + bias`` reads ``-inf`` for the choice). The gates are still the
+    scores without the bias. ``n_group`` 1 is no group stage at all: the code
+    before it, bit for bit."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -109,7 +122,17 @@ def route(
         probs = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
-    if bias is None:
+    if n_group > 1:
+        with jax.named_scope("moe.groups"):
+            choice = probs if bias is None else probs + bias.astype(jnp.float32)
+            T, E = choice.shape
+            best_two, _ = jax.lax.top_k(choice.reshape(T, n_group, E // n_group), 2)
+            _, groups = jax.lax.top_k(best_two.sum(axis=-1), topk_group)  # [T, topk_group]
+            stays = (groups[:, :, None] == jnp.arange(n_group, dtype=groups.dtype)).any(axis=1)
+            choice = jnp.where(jnp.repeat(stays, E // n_group, axis=1), choice, -jnp.inf)
+        _, experts = jax.lax.top_k(choice, top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
+    elif bias is None:
         gates, experts = jax.lax.top_k(probs, top_k)  # [T, k]
     else:
         _, experts = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
@@ -169,6 +192,8 @@ def dropless_moe_ffn(
     scoring: str = "softmax",
     scale: float = 1.0,
     held: Optional[Tuple[int, int]] = None,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """x: [T, d] → (out [T, d], aux) with every valid row through all
     ``top_k`` of its experts: none dropped, no capacity.
@@ -176,8 +201,9 @@ def dropless_moe_ffn(
     ``valid``: [T] bool, absent = all. A row that is not valid is in no
     expert's group (its assignments sort behind the last group), costs no
     expert FLOPs beyond the grouped matmul's own tile padding, comes back
-    as zeros and is not counted. ``scoring``, ``scale`` and a
-    ``params["router_bias"]`` [E], where there is one: as in :func:`route`.
+    as zeros and is not counted. ``scoring``, ``scale``, ``n_group`` /
+    ``topk_group`` and a ``params["router_bias"]`` [E], where there is one:
+    as in :func:`route`.
 
     ``held = (lo, hi)``: this process holds the experts ``lo <= e < hi`` of
     the ``E`` the router chooses among (one chip's share of an
@@ -192,14 +218,17 @@ def dropless_moe_ffn(
     load-balance loss over ALL rows' routing: a training regulariser, and
     training has no padding rows) and, with a router bias, ``bias_changed``
     (int32: valid rows whose kept set differs from the ``top_k`` of the
-    scores alone)."""
+    scores alone) and, under a group limit (``n_group`` > 1),
+    ``group_changed`` (int32: valid rows whose kept set differs from the
+    plain ``top_k`` of ``score + bias``: the limit ENGAGED for them) beside
+    ``routed_rows`` (int32: the valid rows, what that is a share of)."""
     T, d = x.shape
     E = params["router"].shape[1]
     bias = params.get("router_bias")
     with jax.named_scope("moe.route"):
         gates, experts, probs = route(
             params["router"], x, top_k=top_k, renormalize=renormalize,
-            scoring=scoring, bias=bias, scale=scale,
+            scoring=scoring, bias=bias, scale=scale, n_group=n_group, topk_group=topk_group,
         )
     with jax.named_scope("moe.dispatch"):
         flat = experts.reshape(T * top_k)
@@ -233,15 +262,26 @@ def dropless_moe_ffn(
             # whatever the grouped matmul left there is replaced, not scaled
             out = jnp.where(valid[:, None], out, 0)
     aux = {"load": load, "aux_loss": load_balance_loss(probs, experts)}
-    if bias is not None:
-        # the kept are the top_k of the scores alone unless an expert that
-        # was not kept scores above the lowest kept: no second top_k
+
+    def differs_from_top_k_of(scores):
+        # the kept are the top_k of ``scores`` unless an expert that was not
+        # kept scores above the lowest kept: no second top_k
         kept = (experts[:, :, None] == jnp.arange(E, dtype=experts.dtype)).any(axis=1)
-        lowest_kept = jnp.take_along_axis(probs, experts, axis=-1).min(axis=-1)
-        changed = jnp.where(kept, -jnp.inf, probs).max(axis=-1) > lowest_kept
+        lowest_kept = jnp.take_along_axis(scores, experts, axis=-1).min(axis=-1)
+        changed = jnp.where(kept, -jnp.inf, scores).max(axis=-1) > lowest_kept
         if valid is not None:
             changed = changed & valid
-        aux["bias_changed"] = changed.sum().astype(jnp.int32)
+        return changed.sum().astype(jnp.int32)
+
+    if bias is not None:
+        aux["bias_changed"] = differs_from_top_k_of(probs)
+    if n_group > 1:
+        aux["group_changed"] = differs_from_top_k_of(
+            probs if bias is None else probs + bias.astype(jnp.float32)
+        )
+        aux["routed_rows"] = (
+            jnp.int32(T) if valid is None else valid.sum().astype(jnp.int32)
+        )
     return out, aux
 
 

@@ -481,14 +481,46 @@ def test_the_latent_flash_kernel_compiles_for_the_chip_at_xing4_widths(one_chip,
         jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
+@pytest.mark.parametrize("window", [1024, 256], ids=["chunk_1024", "chunk_256"])
+def test_the_latent_flash_kernel_takes_a_192_wide_value_at_gigachat_widths(one_chip, window):
+    """GigaChat3.1's prefill chunk through the same kernel: 64 heads, keys
+    128 + 64 shared, VALUES 192 (one and a half lane tiles: a value tile as
+    wide as its array), a table of 8192. One Mosaic call; what XLA adds
+    around it here is V re-laid onto 256 lanes (in the model's program the
+    expansion writes it so), never the scores."""
+    from ray_tpu.ops import latent_flash as LF
+
+    H, S, dk, ds, dv = 64, 8192, 128, 64, 192
+    assert LF.kernel_serves(window, S, dk, dv, ds, jnp.bfloat16, backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        compiled = jax.jit(
+            lambda q, k, v, qs, ks, ctx, n: LF.flash_attention(
+                q, k, v, ctx, n, scale=0.1, q_shared=qs, k_shared=ks, interpret=False
+            )
+        ).lower(
+            shape((H, window, dk)), shape((H, S, dk)), shape((H, S, dv)), shape((H, window, ds)),
+            shape((S, ds)), shape((), jnp.int32), shape((), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_flash" in text
+        assert compiled.memory_analysis().temp_size_in_bytes <= H * S * 256 * 2 + 2**20
+        assert compiled.out_info.shape == (H, window, dv)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
 @pytest.mark.parametrize(
-    "layers, blocks, slots, window",
-    [(7, 12664, 64, 1), (40, 2824, 32, 1), (40, 2824, 32, 4), (40, 2824, 32, 8)],
-    ids=["kimi_linear_decode", "xing4_decode", "xing4_verify_4", "xing4_verify_8"],
+    "layers, blocks, slots, window, heads",
+    [(7, 12664, 64, 1, 32), (40, 2824, 32, 1, 32), (40, 2824, 32, 4, 32), (40, 2824, 32, 8, 32),
+     (7, 16168, 64, 2, 64)],
+    ids=["kimi_linear_decode", "xing4_decode", "xing4_verify_4", "xing4_verify_8", "gigachat_mtp_window_2"],
 )
-def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(one_chip, layers, blocks, slots, window):
+def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(one_chip, layers, blocks, slots, window, heads):
     """The kernel of both latent models' decode (``ops/latent_paged.py``;
-    here for the same reason as the ones above): 32 heads, rows of 512 + 64,
+    here for the same reason as the ones above): 32 heads (GigaChat3.1: 64, a window of two), rows of 512 + 64,
     both configurations' whole caches as the layout stores them (a block of
     16 as ``[8, 1152]``), the full-width table. The cache goes in as it lies
     and there is one Mosaic call (Mosaic refused a DMA of one row of ``[blocks,
@@ -499,7 +531,7 @@ def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(on
     layout = CacheLayout("latent", layers, 16, (("latent", (576,)),), jnp.bfloat16, flat_blocks=True)
     cache_like = jax.eval_shape(lambda: layout.init(blocks))["latent"]
     assert cache_like.shape == (layers, blocks, 8, 1152)
-    assert LP.kernel_serves(window, 32, 576, 512, cache_like, backend="tpu")
+    assert LP.kernel_serves(window, heads, 576, 512, cache_like, backend="tpu")
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -509,13 +541,13 @@ def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(on
                 q, cache, layers - 1, tables, ctx, kv_lora_rank=512, scale=0.07, interpret=False
             )
         ).lower(
-            shape((slots, window, 32, 576), jnp.bfloat16), shape(cache_like.shape, jnp.bfloat16),
+            shape((slots, window, heads, 576), jnp.bfloat16), shape(cache_like.shape, jnp.bfloat16),
             shape((slots, 512), jnp.int32), shape((slots,), jnp.int32),
         ).compile()
         text = compiled.as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_rows" in text
-        # the queries laid out twice are the one temporary: slots x 2 x window x 32 x 1152 bf16
-        assert compiled.memory_analysis().temp_size_in_bytes < 2 * slots * 2 * window * 32 * 1152 * 2 + 2**20
-        assert [o.shape for o in compiled.out_info] == [(slots, window, 32, 512)] + [(slots, window, 32)] * 2
+        # the queries laid out twice are the one temporary: slots x 2 x window x heads x 1152 bf16
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * slots * 2 * window * heads * 1152 * 2 + 2**20
+        assert [o.shape for o in compiled.out_info] == [(slots, window, heads, 512)] + [(slots, window, heads)] * 2
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
