@@ -325,7 +325,7 @@ class PagedModelRunner:
         if self.held_experts is not None:
             keys = ("launches", "assignments", "held_assignments", "bias_changed",
                     "expert_slots", "experts_touched", "max_load", "mean_load",
-                    "routed_rows", "group_changed")
+                    "routed_rows", "group_changed", "expert_layers", "stacked_layers")
             self.moe = {kind: dict.fromkeys(keys, 0) for kind in ("decode", "prefill")}
         self.warmup_programs: Dict[str, float] = {}
 
@@ -699,7 +699,10 @@ class PagedModelRunner:
         differs from the top-k of the scores alone; where the choice is
         limited to groups of experts, ``routed_rows`` and ``group_changed``
         ``[n_layers]``: the real rows, and those whose kept set differs from
-        the plain top-k of score + bias."""
+        the plain top-k of score + bias. ``expert_layers`` = the layers;
+        ``stacked_layers`` = those of them whose grouped matmuls read the
+        layer's matrices in place in a scanned group's stack, as the model
+        says of the program (``Model.experts_in_place``: no device output)."""
         loads = counters["load"]
         lo, hi = self.held_experts
         acc = self.moe[kind]
@@ -708,6 +711,8 @@ class PagedModelRunner:
         acc["held_assignments"] += int(loads[:, lo:hi].sum())
         for key in ("bias_changed", "routed_rows", "group_changed"):
             acc[key] += int(np.sum(counters.get(key, 0)))
+        acc["expert_layers"] += loads.shape[0]
+        acc["stacked_layers"] += self.model.experts_in_place(self.cfg, loads.shape[0])
         acc["expert_slots"] += loads.size
         acc["experts_touched"] += int(np.count_nonzero(loads))
         acc["max_load"] += int(loads.max(axis=1).sum())

@@ -300,6 +300,8 @@ MODEL = Model(
     paged_decode_step=xing4.paged_decode_step,
     attention_path=xing4.MODEL.attention_path,
     held_experts=xing4.MODEL.held_experts,
+    # the module's layer too: ``xing4._paged_layers`` over a stack of one
+    experts_in_place=xing4.MODEL.experts_in_place,
     key_tile=xing4.MODEL.key_tile,
     gather_widths=xing4.MODEL.gather_widths,
     drafter=lambda cfg: Drafter(

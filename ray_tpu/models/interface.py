@@ -283,6 +283,13 @@ class Model:
     #: (cfg) -> ``None`` for a model without routed experts, else
     #: ``(lo, hi)``: the range of experts this process holds
     held_experts: Callable
+    #: (cfg, layers) -> of the ``layers`` expert layers a paged step ran, how
+    #: many read their three expert matrices IN PLACE in the stack of a
+    #: scanned group (``ops/moe.py::grouped_matmul`` told a ``layer``): what
+    #: the program was traced with, said on the host, no device output. 0
+    #: where every expert layer's matrices are operands of their own (layers
+    #: unrolled, or a Python loop over them)
+    experts_in_place: Callable = lambda cfg, layers: 0
     #: (cfg, window, cache) -> positions a key tile of a program whose
     #: ``attention_path`` reads ``"live"``
     key_tile: Callable = lambda cfg, window, cache: 1

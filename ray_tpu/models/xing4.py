@@ -430,7 +430,7 @@ def _latent_qkv(cfg: Xing4Config, p, h, pos):
     return q_nope, q_rope, row
 
 
-def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
+def _ffn(cfg: Xing4Config, p, h, valid, moe: bool, at=None):
     """The FFN of one layer on normed activations ``h [B, C, D]``:
     ``(ffn(h), aux)``. A dense layer: the gated SiLU MLP, ``aux`` empty. An
     expert layer: the shared expert on every row + this process's part of
@@ -438,7 +438,9 @@ def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
     ``cfg.held_experts``: sigmoid scores, the choice with the bias, gates
     normalised over the kept and scaled); ``aux``: ``load`` ``[E]``,
     ``bias_changed``, ``aux_loss``. ``valid [B, C]`` marks the real rows of
-    a padded serving step."""
+    a padded serving step. ``at``: ``p``'s three expert matrices are the
+    STACKS of the layer's group and this is the layer's index in them
+    (:func:`_scan_layers`); absent, they are the layer's own."""
     if not moe:
         return gated_mlp(h, p["w_gate"], p["w_up"], p["w_down"]), {}
     with jax.named_scope("moe.shared"):
@@ -449,7 +451,7 @@ def _ffn(cfg: Xing4Config, p, h, valid, moe: bool):
         valid=None if valid is None else valid.reshape(-1),
         scoring="sigmoid", scale=cfg.routed_scaling_factor,
         held=None if cfg.n_held == cfg.n_routed_experts else cfg.held_experts,
-        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        n_group=cfg.n_group, topk_group=cfg.topk_group, layer=at,
     )
     return shared + routed.reshape(h.shape), aux
 
@@ -494,39 +496,59 @@ def _hyper(cfg: Xing4Config, p, sub: str, norm: str, X, F: Callable):
     return out.astype(X.dtype), extra
 
 
-def _layer(cfg: Xing4Config, p, X, attention: Callable, valid, moe: bool):
+def _layer(cfg: Xing4Config, p, X, attention: Callable, valid, moe: bool, at=None):
     """One layer on the residual state ``X [B, C, n, D]``: the attention
     sublayer (``attention(p, h) -> (out [B, C, D], rows)``, ``rows`` what
-    the layer leaves for the cache, or None), then the FFN. Returns ``(X,
-    rows, aux)``."""
+    the layer leaves for the cache, or None), then the FFN (``at``: as
+    :func:`_ffn`). Returns ``(X, rows, aux)``."""
     X, rows = _hyper(cfg, p, "hc_attn", "attn_norm", X, partial(attention, p))
-    X, aux = _hyper(cfg, p, "hc_mlp", "mlp_norm", X, lambda h: _ffn(cfg, p, h, valid, moe))
+    X, aux = _hyper(cfg, p, "hc_mlp", "mlp_norm", X, lambda h: _ffn(cfg, p, h, valid, moe, at))
     return X, rows, aux
 
 
-def _scan_layers(cfg: Xing4Config, params, X, attention: Callable, valid, wrap=None, layer0: int = 0):
+#: an expert layer's three matrices: what a serving scan does NOT slice
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _scan_layers(cfg: Xing4Config, params, X, attention: Callable, valid, wrap=None, layer0: int = 0,
+                 experts_in_place: bool = False):
     """Every layer over ``X``, the layers of a kind under one scan.
     ``attention(p, h, layer) -> (out, rows)`` (``layer`` the layer's index
     in the model, traced). Returns ``(X, rows, aux)``: ``rows`` what the
     layers' attentions returned, stacked over ALL layers in order (None
     where they return None), ``aux`` the expert layers' stacked ``load
     [n_moe, E]``, ``bias_changed [n_moe]``, ``aux_loss [n_moe]`` (empty
-    without expert layers). ``layer0``: the index the first layer goes by."""
+    without expert layers). ``layer0``: the index the first layer goes by.
+
+    ``experts_in_place`` (the serving body): the expert group's three
+    matrices are not among the scan's ``xs`` but closed over whole, and a
+    layer reads its own in the stack by its index in the group
+    (``ops/moe.py::grouped_matmul``). As a slice of ``xs`` each was copied
+    out of the stack every layer, because a Pallas call takes a whole
+    operand. Everything else of a layer is sliced as before. ``forward``
+    keeps the slices: the gradient of a layer through a whole stack would be
+    a whole stack's."""
     rows, aux = [], {}
     for name, count, moe in _groups(cfg):
         if name not in params:
             continue
-        def body(carry, p, moe=moe):
+        sliced, whole = params[name], {}
+        if moe and experts_in_place:
+            whole = {k: sliced[k] for k in _EXPERT_STACKS}
+            sliced = {k: v for k, v in sliced.items() if k not in whole}
+
+        def body(carry, p, moe=moe, whole=whole, first=layer0):
             X, layer = carry
             X, layer_rows, layer_aux = _layer(
-                cfg, p, X, lambda p, h: attention(p, h, layer), valid, moe
+                cfg, {**p, **whole}, X, lambda p, h: attention(p, h, layer), valid, moe,
+                layer - first if whole else None,
             )
             return (X, layer + 1), (layer_rows, layer_aux)
 
         if wrap is not None:
             body = wrap(body)
         (X, _), (group_rows, group_aux) = jax.lax.scan(
-            body, (X, jnp.int32(layer0)), params[name]
+            body, (X, jnp.int32(layer0)), sliced
         )
         layer0 += count
         rows.append(group_rows)
@@ -646,7 +668,9 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), blocks
 
     X0, layer0 = (_embed(cfg, params, tokens), 0) if embed is None else embed
-    X, blocks, aux = _scan_layers(cfg, params, X0, attention, valid, layer0=layer0)
+    X, blocks, aux = _scan_layers(
+        cfg, params, X0, attention, valid, layer0=layer0, experts_in_place=True
+    )
     return _write_blocks(cfg, cache, block_tables, pos[:, 0], blocks, layer0), X, aux
 
 
@@ -827,6 +851,8 @@ MODEL = Model(
     paged_decode_step=paged_decode_step,
     attention_path=_attention_path,
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
+    # every expert layer of the paged body: ``_paged_layers`` scans them all in place
+    experts_in_place=lambda cfg, layers: layers,
     key_tile=lambda cfg, window, cache: latent_flash.tiles(window, _table_keys(cfg, cache))[1],
     gather_widths=lambda cfg, table_keys, bs: _slot_widths(table_keys, bs),
 )

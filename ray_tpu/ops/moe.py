@@ -153,11 +153,25 @@ def load_balance_loss(probs: jnp.ndarray, experts: jnp.ndarray) -> jnp.ndarray:
     return E * jnp.sum(probs.mean(axis=0) * first)
 
 
-def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) -> jnp.ndarray:
+def grouped_matmul(
+    xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray, layer: Optional[jnp.ndarray] = None
+) -> jnp.ndarray:
     """``xs [m, k]`` (rows sorted by group) times ``w [g, k, n]``, each row
     against its group's matrix → ``[m, n]``; ``group_sizes [g]`` int32 may
     sum to less than ``m`` (what the rows behind the last group hold is
     unspecified).
+
+    ``w`` may also be a STACK of layers ``[L, g, k, n]`` with ``layer`` (an
+    int32 scalar, traced under a ``lax.scan`` over the layers): the rows go
+    against ``w[layer]``, read where it lies in the stack. A Pallas call
+    wants a whole operand, so a scan that hands it a slice of its ``xs``
+    first COPIES the slice out (``dynamic-slice_bitcast_fusion``: 0.47 GB a
+    matrix a layer at GigaChat3.1's widths, 2.3 x the time of the matmuls
+    it fed; PERF.md, PR 43). Here the operand is the stack itself, seen as
+    ``[L g, k, n]`` (its leading dimensions merged: a bitcast), under group
+    sizes of length ``L g`` that are zero outside the layer's own ``g``:
+    ``gmm`` visits no tile of an empty group, so the kernel reads what it
+    read of the slice, at the same tiling.
 
     On a TPU: the Pallas grouped matmul ``megablox.gmm`` (device operations
     ``gmm.N``) at the tiling read on the chip at OLMoE's widths (PERF.md,
@@ -167,18 +181,27 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) ->
     wants ``m`` in whole tiles: the rows are padded up to one (behind the
     last group, in none), so every bucket runs the one kernel (64 rows,
     one matmul: 0.36 against 0.42 ms). Elsewhere,
-    and for widths that are not whole lanes: ``jax.lax.ragged_dot``."""
+    and for widths that are not whole lanes: ``jax.lax.ragged_dot`` (on
+    ``w[layer]`` of a stack)."""
     m, k = xs.shape
-    n = w.shape[2]
+    n = w.shape[-1]
     if jax.default_backend() == "tpu" and k % 128 == 0 and n % 128 == 0:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
+        if w.ndim == 4:
+            L, g = w.shape[:2]
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * g,), group_sizes.dtype), group_sizes, (layer * g,)
+            )
+            w = w.reshape(L * g, k, n)
         tm = 256 if m >= 4096 else 128
         out = gmm(
             jnp.pad(xs, ((0, -m % tm), (0, 0))), w, group_sizes,
             preferred_element_type=xs.dtype, tiling=(tm, min(k, 1024), min(n, 1024)),
         )
         return out[:m]
+    if w.ndim == 4:
+        w = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
     return jax.lax.ragged_dot(xs, w, group_sizes)
 
 
@@ -194,9 +217,15 @@ def dropless_moe_ffn(
     held: Optional[Tuple[int, int]] = None,
     n_group: int = 1,
     topk_group: int = 1,
+    layer: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """x: [T, d] → (out [T, d], aux) with every valid row through all
     ``top_k`` of its experts: none dropped, no capacity.
+
+    ``layer``: where ``w_gate`` / ``w_up`` / ``w_down`` are the STACKS of a
+    scanned model's layers (``[L, g, k, n]``), the layer whose experts these
+    are (:func:`grouped_matmul` reads them in place). The router and its
+    bias are the layer's own, as ever.
 
     ``valid``: [T] bool, absent = all. A row that is not valid is in no
     expert's group (its assignments sort behind the last group), costs no
@@ -244,9 +273,9 @@ def dropless_moe_ffn(
         sizes = load if held is None else load[lo:hi]
         xs = x[order // top_k]  # [T*k, d]: each token's row, once per expert
     with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(xs, params["w_gate"], sizes)
-        up = grouped_matmul(xs, params["w_up"], sizes)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, params["w_down"], sizes)
+        gate = grouped_matmul(xs, params["w_gate"], sizes, layer)
+        up = grouped_matmul(xs, params["w_up"], sizes, layer)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, params["w_down"], sizes, layer)
         if held is not None:
             # what the grouped matmul leaves behind the last group is
             # unspecified: an absent expert's assignment adds exactly nothing

@@ -415,6 +415,39 @@ def test_the_grouped_matmul_compiles_for_the_chip_at_olmoe_widths(one_chip, monk
         jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
+@pytest.mark.parametrize("L, g, d, f, rows", [(5, 16, 7168, 2048, 128 * 8), (5, 16, 7168, 2048, 1024 * 8),
+                                              (1, 16, 7168, 2048, 128 * 8), (38, 8, 3584, 1024, 32 * 4),
+                                              (38, 8, 3584, 1024, 1024 * 4)],
+                         ids=["gigachat_step", "gigachat_chunk", "gigachat_mtp_module_a_stack_of_one",
+                              "xing4_decode", "xing4_chunk"])
+def test_the_grouped_matmul_reads_a_layer_in_the_stack_at_the_latent_cells_widths(one_chip, monkeypatch, L, g, d, f, rows):
+    """A scanned model's expert stack ``[L, g, k, n]`` with a TRACED layer
+    (``ops/moe.py::grouped_matmul``, PR 43), compiled for the real chip at
+    both latent cells' widths: the kernel is still ``gmm``, the stack goes in
+    as it lies (a bitcast to ``[L g, k, n]``), and nothing the size of a
+    layer's matrices (0.47 GB on GigaChat3.1, 59 MB on Xing4) is among the
+    temporaries, where the parent's scan copied each slice out."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        for k, n in ((d, f), (f, d)):  # gate/up, then down
+            compiled = jax.jit(moe.grouped_matmul).lower(
+                shape((rows, k)), shape((L, g, k, n)), shape((g,), jnp.int32), shape((), jnp.int32)
+            ).compile()
+            text = compiled.as_text()
+            assert "tpu_custom_call" in text and "gmm" in text and "ragged-dot" not in text
+            assert f"bf16[{L * g},{k},{n}]" in text and "bitcast" in text
+            layer_bytes = g * k * n * 2
+            assert compiled.memory_analysis().temp_size_in_bytes < min(layer_bytes // 4, 64 * 2**20)
+            assert compiled.out_info.shape == (rows, n)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
 @pytest.mark.parametrize(
     "layers, blocks, n_kv, heads, window",
     [(16, 6144, 8, 32, 1), (16, 6144, 8, 32, 8), (12, 2240, 16, 16, 1), (12, 2240, 16, 16, 4)],
