@@ -193,7 +193,10 @@ class EngineConfig:
     """Knobs for the paged-KV continuous-batching engine (see README
     "inference" section)."""
 
-    #: device block pool size (block 0 is the reserved null block)
+    #: device block pool size (block 0 is the reserved null block): the pool
+    #: of the layer group that keeps a sequence whole. A group that keeps a
+    #: window (``models/interface.py::LayerGroup``) has a pool beside it whose
+    #: size follows from the fields here (``InferenceEngine._window_pools``)
     num_blocks: int = 128
     #: token positions per block
     block_size: int = 16
@@ -421,10 +424,13 @@ class InferenceEngine:
         state_slots = self._state_slots(model_cfg, ec)
         #: whether the model drafts for itself (speculative_draft "mtp")
         self._mtp = self._drafts_for_itself(model_cfg, ec)
+        #: the pools beside the first: ``(name, num_blocks, keeps)`` of each
+        #: layer group that keeps a window (none for a model of one group)
+        windows = self._window_pools(model_cfg, ec)
         self.runner = PagedModelRunner(
             model_cfg,
             params,
-            num_blocks=ec.num_blocks,
+            num_blocks=(ec.num_blocks, *(n for _, n, _ in windows)),
             block_size=ec.block_size,
             prefill_buckets=ec.resolved_prefill_buckets(model_cfg.max_seq_len),
             decode_buckets=decode_buckets,
@@ -453,10 +459,16 @@ class InferenceEngine:
             ec.block_size,
             # no state snapshot a block yet: a model with per-sequence state
             # takes no prefix hit (:meth:`_state_slots`)
-            prefix_cache_enabled=ec.prefix_cache_enabled and not state_slots,
+            prefix_cache_enabled=ec.prefix_cache_enabled and not state_slots and not windows,
             prefix_cache_max_blocks=ec.prefix_cache_max_blocks,
             state_slots=state_slots,
+            group=self.runner.cache_layout.groups[0].name,
+            windows=windows,
         )
+        #: running sums a decode launch: the block-layers out of the pools, and
+        #: those ONE table a request would hold for the same sequences (every
+        #: layer, every position: the first group's blocks times every layer)
+        self._kv_held = dict.fromkeys(("launches", "held_block_layers", "one_table_block_layers"), 0)
         self.scheduler = ContinuousBatchingScheduler(
             self.blocks,
             max_decode_batch=ec.max_decode_batch,
@@ -694,6 +706,60 @@ class InferenceEngine:
             if on:
                 raise ValueError(f"{field} cannot run here: {why}{reason}")
         return ec.max_decode_batch
+
+    @staticmethod
+    def _window_pools(model_cfg, ec: "EngineConfig") -> tuple:
+        """``(name, num_blocks, keeps)`` of each layer group of the model's
+        cache that keeps a window (``CacheLayout.groups`` after the first):
+        none for a model of one group. Every feature that names a block by
+        its tokens, or rolls a sequence back (export / import, the tier, a
+        verify window), is refused here with the reason instead of answering
+        wrongly: blocks behind a window are given back while the sequence
+        runs, so the tokens of a prefix no longer say which rows are held.
+        Prefix reuse, which is on by default, is switched OFF for such a model
+        where the block manager is made, as for a model with a state pool (a
+        hit would skip prefill over positions whose window rows were
+        released; the manager itself refuses the two together). The pool's
+        size is no option: it follows from the decode batch, the window, the
+        largest chunk and the block size."""
+        from ray_tpu.models.interface import model_of
+
+        model = model_of(model_cfg)
+        groups = model.cache_layout(model_cfg, ec.block_size, ec.cache_dtype).groups
+        if len(groups) < 2:
+            return ()
+        if groups[0].keeps or not all(g.keeps for g in groups[1:]):
+            raise ValueError(
+                f"a {model.name} model's cache groups {[(g.name, g.keeps) for g in groups]} cannot be "
+                "run: the first group keeps a sequence whole, every other a window"
+            )
+        why = (
+            f"a {model.name} model of this configuration has layers that keep the last "
+            f"{[g.keeps for g in groups[1:]]} positions alone and give back the blocks behind: "
+        )
+        refused = {
+            "kv_transfer_enabled": (ec.kv_transfer_enabled,
+                "a block's digest says nothing of the window rows no longer held, so the importer would attend over nulls"),
+            "kv_tier_enabled": (ec.kv_tier_enabled,
+                "tier write-back and resume move blocks by their tokens' digest, whatever was released"),
+            "speculative_k": (ec.speculative_k > 0,
+                "a rejected tail would have to come back from behind a released block"),
+        }
+        for field, (on, reason) in refused.items():
+            if on:
+                raise ValueError(f"{field} cannot run here: {why}{reason}")
+        # what the pool must hold so that no step of a full decode batch waits
+        # on it: every slot its window (and a block where the window starts
+        # inside one), and a largest chunk for each request between its chunks:
+        # the ones this step prefills, and the one whose last chunk ran and
+        # whose first decode step has not slid it yet
+        bs = ec.block_size
+        chunk = -(-max(ec.resolved_prefill_buckets(model_cfg.max_seq_len)) // bs)
+        return tuple(
+            (g.name, 1 + ec.max_decode_batch * (-(-g.keeps // bs) + 1) + (ec.max_prefills_per_step + 1) * chunk,
+             g.keeps)
+            for g in groups[1:]
+        )
 
     @staticmethod
     def _drafts_for_itself(model_cfg, ec: "EngineConfig") -> bool:
@@ -1352,6 +1418,7 @@ class InferenceEngine:
             ]
             cls = [r.context_len + r.ahead for r in plain]
             slots = [self.blocks.slot_of(r.request_id) for r in plain]
+            self._account_held()
             # an all-greedy batch reads back the device's picks (one
             # int a slot), not the logits: the same program either way
             greedy = all(r.temperature <= 0.0 for r in plain)
@@ -1363,6 +1430,18 @@ class InferenceEngine:
         counts["launches"] += 1
         counts["ahead"] += unread is not None
         return _DecodeBatch(plain, launched, greedy)
+
+    def _account_held(self) -> None:
+        """Add a decode launch to ``kv_held``: the block-layers out of the
+        groups' pools now, and the block-layers ONE table a request would hold
+        for the same sequences: the blocks of the group that keeps all, in
+        every layer."""
+        layers = [len(g.layers) for g in self.runner.cache_layout.groups]
+        in_use = self.blocks.blocks_in_use()
+        acc = self._kv_held
+        acc["launches"] += 1
+        acc["held_block_layers"] += sum(n * l for n, l in zip(in_use, layers, strict=True))
+        acc["one_table_block_layers"] += in_use[0] * sum(layers)
 
     def _commit_chunks(self, chunks: List[tuple]) -> int:
         """Read the step's prefill chunks in the order of their launches and
@@ -2533,12 +2612,16 @@ class InferenceEngine:
                 self.runner.state_layout.describe() if self.runner.state_layout else None
             ),
             "state_pool": self.blocks.slot_stats(),
+            # a pool a layer group: blocks, in use now and at the peak, taken,
+            # given back by sliding
+            "kv_pools": self.blocks.pool_stats(),
             "request_stages": dict(self._request_stages),
             "startup": {
                 **self.startup,
                 "warmup_programs": dict(self.startup["warmup_programs"]),
             },
         }
+        s["kv_held"] = dict(self._kv_held)
         if self.runner.moe is not None:
             # what the experts saw (the target runner's; absent for a dense model)
             s["moe"] = {kind: dict(acc) for kind, acc in self.runner.moe.items()}

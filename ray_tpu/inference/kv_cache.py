@@ -10,9 +10,18 @@ when a new request must wait in the admission queue instead.
 
 Block id 0 is reserved as the NULL block: padding positions in the
 fixed-shape prefill/decode steps write their trash there, so it is never
-handed to a request. Block ids are layer-agnostic — one id covers
-``block_size`` token positions in every layer at once, so the allocator
-deals in tokens, not layer-tokens.
+handed to a request. A block id covers ``block_size`` token positions in
+every layer of ONE layer group (``models/interface.py::LayerGroup``). A
+model whose layers all keep a sequence whole has one group, every layer in
+it, and the allocator deals in tokens, not layer-tokens. A model with
+window layers has a second group (or more), a pool beside the first:
+:class:`_WindowPool`, a free list, a table a request and counters of its
+own. :meth:`PagedBlockManager.grow_to` grows every group and, in a group
+that keeps the last ``W`` positions, RELEASES the blocks that lie wholly
+behind the window: the released entry of the request's table reads the null
+block. Every way out (:meth:`free`, :meth:`evict`) gives back every group's
+blocks. What cannot run over a window group yet (prefix reuse, export /
+import, the tier, a verify window) is refused where the engine is made.
 
 Prefix caching (the warm-TTFT tentpole): a FULL block whose token
 content has been completely written is immutable from then on — decode
@@ -57,7 +66,9 @@ import hashlib
 import struct
 import threading
 from collections import OrderedDict, deque
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 #: digest width for the chain hash. 16 bytes: block-content collisions
 #: would silently serve wrong KV, so this is sized for "never", not for
@@ -86,6 +97,89 @@ def prefix_block_hashes(tokens, block_size: int) -> List[int]:
     return out
 
 
+class _WindowPool:
+    """The blocks of one layer group that keeps the last ``keeps`` positions
+    of a sequence: a free list, a table a request (as long as the sequence
+    has run, a released entry 0) and counters. No block is shared, indexed or
+    copied: what could share one is refused where the engine is made. Not
+    locked: :class:`PagedBlockManager` calls it under its own lock."""
+
+    def __init__(self, name: str, num_blocks: int, block_size: int, keeps: int):
+        if num_blocks < 2:
+            raise ValueError(f"group {name!r} needs >= 2 blocks (block 0 is the null block)")
+        if keeps < 1:
+            raise ValueError(f"group {name!r} keeps the last {keeps} positions: need >= 1")
+        self.name, self.num_blocks, self.block_size, self.keeps = name, num_blocks, block_size, keeps
+        self.free: deque = deque(range(1, num_blocks))
+        #: request -> its table so far: entry ``i`` the block of positions
+        #: ``[i * bs, (i + 1) * bs)``, 0 once released (or never needed)
+        self.tables: Dict[str, List[int]] = {}
+        #: request -> the first entry that may still hold a block
+        self.first: Dict[str, int] = {}
+        self.peak_in_use = 0
+        self.taken = 0
+        #: blocks given back by sliding, not by a request's end
+        self.released_behind = 0
+
+    @property
+    def in_use(self) -> int:
+        return self.num_blocks - 1 - len(self.free)
+
+    def plan(self, request_id: str, first_query: int, num_tokens: int) -> Tuple[int, int, int]:
+        """``(first live entry, entries needed, blocks to take beyond what
+        sliding gives back)`` for queries from position ``first_query`` on
+        over ``num_tokens`` positions: the first key any of them sees is
+        ``first_query - keeps + 1``."""
+        bs = self.block_size
+        lo = max(0, first_query - self.keeps + 1) // bs
+        hi = max(lo + 1, -(-num_tokens // bs))
+        table = self.tables.get(request_id, ())
+        first = self.first.get(request_id, 0)
+        gives = sum(1 for blk in table[first:lo] if blk)
+        have = sum(1 for blk in table[lo:hi] if blk)
+        return lo, hi, (hi - lo) - have - gives
+
+    def slide(self, request_id: str, lo: int, hi: int) -> None:
+        """Release the entries behind ``lo``, then cover ``[lo, hi)``. The
+        caller checked :meth:`plan` against the free list."""
+        table = self.tables.setdefault(request_id, [])
+        for i in range(self.first.get(request_id, 0), min(lo, len(table))):
+            if table[i]:
+                self.free.append(table[i])
+                table[i] = 0
+                self.released_behind += 1
+        self.first[request_id] = lo
+        table.extend([0] * (hi - len(table)))
+        for i in range(lo, hi):
+            if not table[i]:
+                table[i] = self.free.popleft()
+                self.taken += 1
+        self.peak_in_use = max(self.peak_in_use, self.in_use)
+
+    def trim(self, request_id: str, num_tokens: int) -> None:
+        """Hand back the entries past ``num_tokens`` positions."""
+        table = self.tables.get(request_id)
+        keep = max(1, -(-num_tokens // self.block_size))
+        while table and len(table) > keep:
+            blk = table.pop()
+            if blk:
+                self.free.append(blk)
+
+    def release(self, request_id: str) -> int:
+        table = self.tables.pop(request_id, ())
+        self.first.pop(request_id, None)
+        held = [blk for blk in table if blk]
+        self.free.extend(held)
+        return len(held)
+
+    def stats(self) -> Dict[str, int]:
+        return {
+            "blocks": self.num_blocks, "keeps": self.keeps, "in_use": self.in_use,
+            "peak_in_use": self.peak_in_use, "taken": self.taken,
+            "released_behind": self.released_behind,
+        }
+
+
 class PagedBlockManager:
     """Allocation / free / eviction accounting for the shared block pool."""
 
@@ -97,11 +191,26 @@ class PagedBlockManager:
         prefix_cache_enabled: bool = False,
         prefix_cache_max_blocks: int = 0,
         state_slots: int = 0,
+        group: str = "all",
+        windows: Sequence[Tuple[str, int, int]] = (),
     ):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null block)")
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
+        if windows and prefix_cache_enabled:
+            raise ValueError(
+                "prefix reuse cannot run over a layer group that keeps a window: a hit would skip "
+                "prefill over positions whose window rows were released"
+            )
+        #: the name of the group these blocks belong to (the one that keeps a
+        #: sequence whole) and, beside it, the pools of the groups that keep a
+        #: window: ``(name, num_blocks, keeps)`` each, in the layout's order
+        self.group = group
+        self.windows: Tuple[_WindowPool, ...] = tuple(
+            _WindowPool(name, n, block_size, keeps) for name, n, keeps in windows
+        )
+        self.peak_in_use = 0
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.prefix_cache_enabled = prefix_cache_enabled
@@ -264,25 +373,40 @@ class PagedBlockManager:
             have = len(self._owned.get(request_id, ()))
             return need - have <= len(self._free) + len(self._lru)
 
-    def grow_to(self, request_id: str, num_tokens: int) -> bool:
+    def grow_to(self, request_id: str, num_tokens: int, chunk: Optional[Tuple[int, int]] = None) -> bool:
         """Extend the request's block list to cover ``num_tokens`` total
-        positions. All-or-nothing: returns False (nothing allocated) when
-        the free pool can't cover the extension."""
+        positions. All-or-nothing: returns False (nothing allocated, nothing
+        released) when the free pool of ANY group can't cover the extension.
+
+        A group that keeps the last ``W`` positions covers what the step's
+        queries see and no more: for a decode step (``chunk`` None: the query
+        stands at ``num_tokens - 1``) the positions from ``num_tokens - W`` on,
+        for a prefill chunk ``chunk = (start, end)`` those from ``start - W +
+        1`` up to ``end``; the blocks that lie WHOLLY behind that are given
+        back first, and their entries of the table read the null block."""
         need = self.blocks_for_tokens(num_tokens)
         with self._lock:
             blocks = self._owned.setdefault(request_id, [])
-            missing = need - len(blocks)
-            if missing <= 0:
-                return True
-            if missing > len(self._free) + len(self._lru):
+            missing = max(0, need - len(blocks))
+            first_query, upto = chunk if chunk is not None else (num_tokens - 1, num_tokens)
+            plans = [w.plan(request_id, first_query, upto) for w in self.windows]
+            if missing > len(self._free) + len(self._lru) or any(
+                take > len(w.free) for w, (_, _, take) in zip(self.windows, plans)
+            ):
                 if not blocks:
                     self._owned.pop(request_id, None)
                 return False
+            for w, (lo, hi, _) in zip(self.windows, plans):
+                w.slide(request_id, lo, hi)
             for _ in range(missing):
                 blk = self._take_block_locked()
                 blocks.append(blk)
                 self._ref[blk] = 1
             self.total_allocs += missing
+            if missing:
+                self.peak_in_use = max(
+                    self.peak_in_use, self.num_blocks - 1 - len(self._free) - len(self._lru)
+                )
             return True
 
     def trim_to(self, request_id: str, num_tokens: int) -> int:
@@ -304,6 +428,8 @@ class PagedBlockManager:
             while len(blocks) > keep:
                 self._release_block_locked(blocks.pop())
                 released += 1
+            for w in self.windows:
+                w.trim(request_id, num_tokens)
             self.total_frees += released
             return released
 
@@ -356,6 +482,8 @@ class PagedBlockManager:
             blocks = self._owned.pop(request_id, [])
             for blk in blocks:
                 self._release_block_locked(blk)
+            for w in self.windows:
+                w.release(request_id)
             # a pending COW that never executed releases its source pin
             for blk in self._cow_src.pop(request_id, ()):
                 self._release_block_locked(blk)
@@ -652,16 +780,58 @@ class PagedBlockManager:
         with self._lock:
             return self._ref.get(block_id, 0)
 
-    def table_row(self, request_id: str, max_blocks: int) -> List[int]:
+    def table_row(self, request_id: str, max_blocks: int):
         """The request's block-table row, right-padded with the null
-        block to the fixed ``max_blocks`` width the jitted steps expect."""
-        blocks = self.owned(request_id)
-        if len(blocks) > max_blocks:
-            raise ValueError(
-                f"request {request_id!r} holds {len(blocks)} blocks > "
-                f"max_blocks_per_seq {max_blocks}"
-            )
-        return blocks + [0] * (max_blocks - len(blocks))
+        block to the fixed ``max_blocks`` width the jitted steps expect.
+        With window groups: one such row a group, an int32 array ``[groups,
+        max_blocks]``, this pool's first (a released entry reads the null
+        block; an array, because at a table of a thousand entries the rows of
+        a decode batch cost milliseconds to convert from lists)."""
+        with self._lock:
+            rows = [self._owned.get(request_id, ())]
+            rows += [w.tables.get(request_id, ()) for w in self.windows]
+            if any(len(blocks) > max_blocks for blocks in rows):
+                raise ValueError(
+                    f"request {request_id!r} holds {max(len(blocks) for blocks in rows)} blocks > "
+                    f"max_blocks_per_seq {max_blocks}"
+                )
+            if not self.windows:
+                return list(rows[0]) + [0] * (max_blocks - len(rows[0]))
+            out = np.zeros((len(rows), max_blocks), np.int32)
+            for g, blocks in enumerate(rows):
+                out[g, : len(blocks)] = blocks
+            return out
+
+    def held_blocks(self, request_id: str) -> List[int]:
+        """Blocks the request holds now, a group (this pool's first)."""
+        with self._lock:
+            return [len(self._owned.get(request_id, ()))] + [
+                sum(1 for blk in w.tables.get(request_id, ()) if blk) for w in self.windows
+            ]
+
+    def blocks_in_use(self) -> List[int]:
+        """Blocks out of each group's pool now (this pool's first): what a
+        decode launch adds to the engine's ``kv_held``, two ints and no walk
+        over the requests."""
+        with self._lock:
+            return [self.num_blocks - 1 - len(self._free) - len(self._lru)] + [
+                w.in_use for w in self.windows
+            ]
+
+    def pool_stats(self) -> Dict[str, Dict[str, int]]:
+        """What ``engine_stats()["kv_pools"]`` says: a group's blocks, those
+        in use now and at the peak, blocks taken in all, and blocks given
+        back by sliding (a group that keeps everything gives none back so)."""
+        with self._lock:
+            in_use = self.num_blocks - 1 - len(self._free) - len(self._lru)
+            return {
+                self.group: {
+                    "blocks": self.num_blocks, "keeps": "all", "in_use": in_use,
+                    "peak_in_use": max(self.peak_in_use, in_use), "taken": self.total_allocs,
+                    "released_behind": 0,
+                },
+                **{w.name: w.stats() for w in self.windows},
+            }
 
     def stats(self) -> Dict[str, float]:
         with self._lock:

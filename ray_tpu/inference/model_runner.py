@@ -249,6 +249,8 @@ class PagedModelRunner:
                 raise ValueError(f"a {self.model.name} model of this configuration has no drafter of its own")
         self.params = params
         self.block_size = block_size
+        #: blocks of the pool; of each layer group's pool, in the layout's order,
+        #: where the model's cache has several (``CacheLayout.groups``)
         self.num_blocks = num_blocks
         self.prefill_buckets = tuple(sorted(prefill_buckets))
         self.decode_buckets = tuple(sorted(decode_buckets))
@@ -258,15 +260,19 @@ class PagedModelRunner:
         self.verify_buckets = tuple(sorted(verify_buckets))
         #: fixed block-table width every request/table row pads to
         self.max_blocks_per_seq = -(-cfg.max_seq_len // block_size)
-        if num_blocks - 1 < self.max_blocks_per_seq:
-            raise ValueError(
-                f"num_blocks={num_blocks} can't hold one max-length sequence "
-                f"({self.max_blocks_per_seq} blocks + null block)"
-            )
         #: what a token leaves in the cache, owned by the model: the device
         #: tensors, the block copy, the export / import / tier payload and
         #: the pool's bytes all follow it
         self.cache_layout = self.model.cache_layout(cfg, block_size, cache_dtype)
+        #: the layer groups as the accounts below read them: ``(layers,
+        #: positions kept (0: all))`` each, the group that keeps all first
+        self._groups = tuple((len(g.layers), g.keeps) for g in self.cache_layout.groups)
+        whole = num_blocks[0] if isinstance(num_blocks, (tuple, list)) else num_blocks
+        if whole - 1 < self.max_blocks_per_seq:
+            raise ValueError(
+                f"num_blocks={whole} can't hold one max-length sequence "
+                f"({self.max_blocks_per_seq} blocks + null block)"
+            )
         t0 = time.perf_counter()
         self.cache = jax.block_until_ready(self.cache_layout.init(num_blocks))
         #: what a SEQUENCE leaves in the model's recurrent layers, if it has
@@ -472,7 +478,7 @@ class PagedModelRunner:
         bs = self.block_size
         for c in buckets_prefill if buckets_prefill is not None else self.prefill_buckets:
             tokens = np.zeros(c, np.int32)
-            row = np.zeros(M, np.int32)
+            row = self._tables((), 0, M)
             self._step(
                 partial(self._warm, bucket=c), "paged_prefill_step", self._prefill_jit,
                 tokens, row, np.int32(0), np.int32(0), slots=np.int32(0),
@@ -486,7 +492,7 @@ class PagedModelRunner:
             for b in batches:
                 for w in self.table_widths:
                     warm = partial(self._warm, bucket=f"{b}x{C}x{w * bs}")
-                    window = (np.zeros((b, C), np.int32), np.zeros((b, w), np.int32),
+                    window = (np.zeros((b, C), np.int32), self._tables((), b, w),
                               np.zeros(b, np.int32), np.zeros(b, np.int32))
                     self._step(warm, "paged_mtp_step", self._mtp_step_jit, *window, np.ones(b, np.int32))
                     (_, hidden), _ = self._step(warm, "paged_mtp_verify", self._mtp_verify_jit, *window)
@@ -499,7 +505,7 @@ class PagedModelRunner:
                     "paged_decode_step", self._decode_jit,
                     np.zeros(b, np.int32),
                     np.zeros(b, np.int32),
-                    np.zeros((b, w), np.int32),
+                    self._tables((), b, w),
                     np.ones(b, np.int32),
                     slots=np.zeros(b, np.int32), last=(self._no_picks,),
                 )
@@ -515,7 +521,7 @@ class PagedModelRunner:
                         partial(self._warm, bucket=f"{b}x{c}x{w * bs}"),
                         "paged_verify_step", self._verify_jit,
                         np.zeros((b, c), np.int32),
-                        np.zeros((b, w), np.int32),
+                        self._tables((), b, w),
                         np.zeros(b, np.int32),
                         np.zeros(b, np.int32),
                     )
@@ -613,14 +619,23 @@ class PagedModelRunner:
         true_len = len(tokens)
         bucket = _round_up_bucket(true_len, self.prefill_buckets)
         path = self._path(bucket)
-        read = width = len(block_row) * self.block_size
-        if path.reads == "live":
-            tile = self.model.key_tile(self.cfg, bucket, self.cache)
-            read = -(-(ctx_len + true_len) // tile) * tile
+        bs = self.block_size
+        width = np.shape(block_row)[-1] * bs
+        end = ctx_len + true_len
+        tile = self.model.key_tile(self.cfg, bucket, self.cache) if path.reads == "live" else 0
+        # by the groups' layers: the positions a query of the chunk sees, and
+        # the key positions read: in whole key tiles where a kernel serves, from
+        # the block-aligned position the keys are handed from; else the table
+        firsts = [max(0, ctx_len - keeps + 1) if keeps else 0 for _, keeps in self._groups]
+        live = self._by_layers(end - first for first in firsts)
+        read = self._by_layers(
+            ((end - 1 - first // bs * bs) // tile - first % bs // tile + 1) * tile if tile else width
+            for first in firsts
+        )
         pw = self.prefill_width
         pw["launches"] += 1
         pw["width_tokens"] += width
-        pw["live_tokens"] += ctx_len + true_len
+        pw["live_tokens"] += live
         pw["read_tokens"] += read
         with clock.phase(
             "launch", program="paged_prefill_step", bucket=bucket, path=self._path_name(bucket),
@@ -718,6 +733,25 @@ class PagedModelRunner:
         acc["max_load"] += int(loads.max(axis=1).sum())
         acc["mean_load"] += float(loads.mean(axis=1).sum())
 
+    def _by_layers(self, per_group) -> float:
+        """The mean over the cache's layers of a number a layer group (an int
+        where it comes out whole, as it does for a model of one group)."""
+        total = sum(layers * value for (layers, _), value in zip(self._groups, per_group, strict=True))
+        whole, rest = divmod(total, self.cache_layout.n_layers)
+        return whole if not rest else total / self.cache_layout.n_layers
+
+    def _tables(self, block_rows, bucket: int, M: int) -> np.ndarray:
+        """The block tables a step is handed: the slots' rows cut to ``M``
+        blocks and padded to ``bucket`` slots with the null block's table: a
+        table a layer group, ``[groups, bucket, M]`` (each row then one row a
+        group), and for a model of ONE group its table as it always was,
+        ``[bucket, M]``. ``bucket`` 0: one row, no slot axis."""
+        lead = (len(self._groups),) if len(self._groups) > 1 else ()
+        bt = np.zeros((*lead, bucket, M) if bucket else (*lead, M), np.int32)
+        if len(block_rows):  # [n, M] or [n, groups, M] -> the slots' axis before the last
+            bt[..., : len(block_rows), :] = np.moveaxis(np.asarray(block_rows, np.int32)[..., :M], 0, -2)
+        return bt
+
     def _table_width(
         self, ctx_lens: Sequence[int], bucket: int, window: int = 1,
         reach: Optional[Sequence[int]] = None,
@@ -734,8 +768,16 @@ class PagedModelRunner:
         need = int(max(ctx_lens))
         width = _round_up_bucket(-(-need // bs), self.table_widths)
         reads = self._path(window).reads
-        if reads == "blocks":
-            read = sum(-(-int(c) // bs) for c in ctx_lens) * bs  # a padding slot reads none
+        # by the groups' layers: a group that keeps a window holds, and a
+        # kernel reads, each slot's blocks from the window's first on
+        live = self._by_layers(
+            sum(min(int(c), keeps) if keeps else int(c) for c in ctx_lens) for _, keeps in self._groups
+        )
+        if reads == "blocks":  # a padding slot reads none
+            read = bs * self._by_layers(
+                sum(-(-int(c) // bs) - (max(0, int(c) - keeps) // bs if keeps else 0) for c in ctx_lens)
+                for _, keeps in self._groups
+            )
         elif reads == "slots":  # each real slot at its own rung under the table's
             ladder = self.model.gather_widths(self.cfg, width * bs, bs)
             read = sum(_round_up_bucket(min(int(c), ladder[-1]), ladder) for c in reach or ctx_lens)
@@ -745,7 +787,7 @@ class PagedModelRunner:
         dw["launches"] += 1
         dw["width_tokens"] += width * bs
         dw["needed_tokens"] += need
-        dw["live_tokens"] += int(sum(ctx_lens))
+        dw["live_tokens"] += live
         dw["gathered_tokens"] += read
         return width
 
@@ -781,12 +823,11 @@ class PagedModelRunner:
         ):
             with clock.part("inputs"):
                 tokens = np.zeros((bbucket, cbucket), np.int32)
-                tables = np.zeros((bbucket, M), np.int32)
+                tables = self._tables(block_rows, bbucket, M)
                 ctx = np.zeros(bbucket, np.int32)
                 tl = np.zeros(bbucket, np.int32)
                 for i, w in enumerate(windows):
                     tokens[i, : len(w)] = w
-                    tables[i] = block_rows[i][:M]
                     ctx[i] = ctx_lens[i]
                     tl[i] = len(w)
             with clock.part("call"):
@@ -802,12 +843,11 @@ class PagedModelRunner:
         no row (``true_len`` 0) and the null block's table."""
         C = self.drafter.window
         tokens = np.zeros((bucket, C), np.int32)
-        tables = np.zeros((bucket, M), np.int32)
+        tables = self._tables(block_rows, bucket, M)
         ctx = np.zeros(bucket, np.int32)
         tl = np.zeros(bucket, np.int32)
         for i, w in enumerate(windows):
             tokens[i, : len(w)] = w
-            tables[i] = block_rows[i][:M]
             ctx[i] = ctx_lens[i]
             tl[i] = len(w)
         return tokens, tables, ctx, tl
@@ -927,11 +967,10 @@ class PagedModelRunner:
             with clock.part("inputs"):
                 t = np.zeros(bucket, np.int32)
                 p = np.zeros(bucket, np.int32)
-                bt = np.zeros((bucket, M), np.int32)
+                bt = self._tables(block_rows, bucket, M)
                 cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
                 t[:n] = tokens
                 p[:n] = positions
-                bt[:n] = np.asarray([row[:M] for row in block_rows], np.int32)
                 cl[:n] = ctx_lens
                 sl = np.zeros(bucket, np.int32)
                 if slots is not None:

@@ -314,7 +314,13 @@ class ContinuousBatchingScheduler:
             # still sit in the cache, making readmission near-free.
             cached, cow = self.blocks.acquire_prefix(req.request_id, prompt)
             need = len(prompt) + 1  # headroom: first decode token
-            if not self.blocks.grow_to(req.request_id, need):
+            # a group that keeps a window is asked for the first chunk's share
+            # alone (the chunks after it slide: :meth:`schedule`); the head of
+            # the queue waits while ANY pool lacks what it needs
+            first = None
+            if self.blocks.windows:
+                first = (cached, min(need, cached + self.max_prefill_chunk))
+            if not self.blocks.grow_to(req.request_id, need, first):
                 if cached or cow:
                     # roll the acquisition back: a QUEUED request must
                     # hold nothing, or pool accounting drifts while it
@@ -340,6 +346,20 @@ class ContinuousBatchingScheduler:
                 # the fault-cost ledger: prefill work this readmission
                 # must REDO (the cache-covered prefix costs nothing)
                 self.total_replay_prefill_tokens += max(0, len(prompt) - cached)
+
+    def _slide_for_chunk(self, req: Request, start: int, chunk: int, plan: "StepPlan") -> bool:
+        """Window groups hold a chunk's share alone: give back what lies
+        behind the chunk's first query's window and cover the chunk (the last
+        one with the first decode token's position), preempting as a decode
+        step does where a pool is dry."""
+        total = len(req.effective_prompt) + 1
+        end = start + chunk
+        span = (start, total if end + 1 == total else end)
+        planned = {id(p[0]) for p in plan.prefills}
+        grown = self.blocks.grow_to(req.request_id, total, span)
+        while not grown and self._preempt_one(req, planned):
+            grown = self.blocks.grow_to(req.request_id, total, span)
+        return grown
 
     def _preempt_one(self, exclude: Request, protected_ids=frozenset()) -> bool:
         """Evict the lowest-priority, latest-arrival running request
@@ -400,6 +420,8 @@ class ContinuousBatchingScheduler:
                     prompt = req.effective_prompt
                     start = req.prefill_pos
                     chunk = min(self.max_prefill_chunk, len(prompt) - start)
+                    if self.blocks.windows and not self._slide_for_chunk(req, start, chunk, plan):
+                        continue  # a window pool is dry: the chunk waits a step
                     plan.prefills.append((req, start, chunk))
 
                 # decode batch: fully-prefilled requests, highest priority /

@@ -9,10 +9,14 @@ Four small records and one dict, no plugin system (the fourth,
   each layer: named arrays ``[n_layers, num_blocks, block_size, *row]``
   (``k`` and ``v`` rows ``[n_kv, hd]`` for a KV cache; one ``latent`` row
   of ``kv_lora_rank + rope`` numbers for a latent cache, a block's rows
-  laid flat in whole tiles: ``flat_blocks``). Block ids stay
-  layer-agnostic: a block id names ``block_size`` positions of a sequence in
-  EVERY layer and every array. The device tensors, the COW copy, the export
-  / import / tier payload (:func:`gather_paged_blocks` /
+  laid flat in whole tiles: ``flat_blocks``). A block id names
+  ``block_size`` positions of a sequence in every layer of ONE layer group
+  (:class:`LayerGroup`) and every array of it. A model whose layers all keep
+  a sequence whole has one group, and a block id then covers every layer, as
+  it always did; a model with layer KINDS (window layers beside full ones)
+  names a group a kind, each with its own arrays, its own ``num_blocks`` and
+  its own block table a request. The device tensors, the COW copy, the
+  export / import / tier payload (:func:`gather_paged_blocks` /
   :func:`scatter_paged_blocks`: the arrays stacked,
   ``[n_arrays, n_layers, P, *block]``) and the pool's byte
   arithmetic all read this one description.
@@ -39,6 +43,23 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class LayerGroup:
+    """The layers of a model that keep the SAME part of a sequence in the
+    paged cache, and so share a pool of blocks and a block table."""
+
+    #: the group's name: the key of its pool in ``engine_stats()["kv_pools"]``
+    #: and the suffix of its arrays' names (:meth:`CacheLayout.array_name`)
+    name: str
+    #: the model's layers that write into the group's arrays, in order: layer
+    #: ``layers[i]`` is index ``i`` of them
+    layers: Tuple[int, ...]
+    #: how much of a sequence the group keeps: 0 = every position, ``W`` = the
+    #: last ``W`` (query ``i`` sees key ``j`` iff ``i - W < j <= i``): blocks
+    #: wholly behind that are given back while the sequence runs
+    keeps: int = 0
+
+
+@dataclass(frozen=True)
 class CacheLayout:
     #: ``"kv"`` (per-head keys and values) or ``"latent"`` (one compressed
     #: row a token, K and V at once)
@@ -61,6 +82,21 @@ class CacheLayout:
     #: slice Mosaic would copy). Where ``block_size / T`` is no multiple of 8
     #: (the tests' toy widths) the block stays ONE row of ``block_size x row``
     flat_blocks: bool = False
+    #: the layer groups, the one that keeps a sequence whole FIRST (its pool is
+    #: the block manager's own, with prefix reuse and the payloads; a group that
+    #: keeps the last ``W`` positions is a pool beside it). A layout that names
+    #: none has ONE, ``all``: every one of its ``n_layers`` layers, everything
+    #: kept, the arrays under their plain names. ``n_layers`` is the groups' sum
+    groups: Tuple[LayerGroup, ...] = ()
+
+    def __post_init__(self):
+        if not self.groups:
+            object.__setattr__(self, "groups", (LayerGroup("all", tuple(range(self.n_layers))),))
+
+    def array_name(self, name: str, group: int) -> str:
+        """The key of array ``name`` of group ``group`` in the device cache:
+        the plain name for the first group, ``name.group`` for the others."""
+        return name if group == 0 else f"{name}.{self.groups[group].name}"
 
     @property
     def row_shape(self) -> Tuple[int, ...]:
@@ -95,6 +131,12 @@ class CacheLayout:
 
     def block_shape(self, row: Tuple[int, ...]) -> Tuple[int, ...]:
         """The shape of one block of one layer in a device array of that row."""
+        if self.flat_blocks and len(row) == 2 and row[1] % 128 == 0:
+            # K/V heads of whole lanes, too few to fill a tile's sublanes by
+            # themselves: the heads join the tokens, ``[bs * n_kv, hd]``,
+            # token-major, whole ``(16, 128)`` tiles and nothing padded (as
+            # ``[bs, 4, hd]`` the device pads every token's 4 heads to a tile)
+            return (self.block_size * row[0], row[1])
         if self.flat_blocks:
             width = math.prod(row)
             t = math.lcm(width, 128) // width
@@ -103,12 +145,18 @@ class CacheLayout:
             return (self.block_size * width,)
         return (self.block_size, *row)
 
-    def init(self, num_blocks: int) -> Dict[str, Any]:
-        """The device-side cache: zeros, block 0 reserved as the null block."""
+    def init(self, num_blocks) -> Dict[str, Any]:
+        """The device-side cache: zeros, block 0 of every group reserved as
+        its null block. ``num_blocks``: the blocks of each group, in the groups'
+        order (an int: of the one group)."""
         import jax.numpy as jnp
 
+        sizes = num_blocks if isinstance(num_blocks, (tuple, list)) else (num_blocks,)
         return {
-            name: jnp.zeros((self.n_layers, num_blocks, *self.block_shape(row)), self.dtype)
+            self.array_name(name, g): jnp.zeros(
+                (len(group.layers), n, *self.block_shape(row)), self.dtype
+            )
+            for g, (group, n) in enumerate(zip(self.groups, sizes, strict=True))
             for name, row in self.arrays
         }
 
@@ -118,11 +166,21 @@ class CacheLayout:
 
     def describe(self) -> Dict[str, Any]:
         """What ``engine_stats()["kv_layout"]`` says."""
-        return {
+        import numpy as np
+
+        said = {
             "kind": self.kind,
             "row_width": self.row_width,
             "bytes_per_token": self.bytes_per_token,
         }
+        if len(self.groups) > 1:  # one group: what bytes_per_token says
+            row_bytes = self.row_width * np.dtype(self.dtype).itemsize
+            said["groups"] = {
+                g.name: {"layers": len(g.layers), "keeps": g.keeps or "all",
+                         "bytes_per_token": len(g.layers) * row_bytes}
+                for g in self.groups
+            }
+        return said
 
 
 @dataclass(frozen=True)

@@ -29,14 +29,31 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.models.interface import (  # noqa: F401 - the block functions are this module's too
     AttentionPath,
     CacheLayout,
+    LayerGroup,
     Model,
     copy_paged_blocks,
     gather_paged_blocks,
     scatter_paged_blocks,
 )
+from ray_tpu.ops import latent_flash
 from ray_tpu.ops import paged_attention as paged_attn
 from ray_tpu.ops.attention import flash_attention, flash_attention_sharded
 from ray_tpu.parallel.sharding import constrain
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN over ``rope_theta`` (as ``models/xing4.py::yarn_inv_freq``): the
+    table's own frequencies where a pair turns more than ``beta_fast`` times
+    over ``original_max`` positions, those divided by ``factor`` where it turns
+    fewer than ``beta_slow`` times, a linear ramp between; cos and sin both
+    times ``attention_factor`` (so a layer's scores by its square)."""
+
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -72,10 +89,30 @@ class LlamaConfig:
     #: dropless path has no capacity and drops nothing
     moe_capacity_factor: float = 1.25
     moe_aux_loss_coeff: float = 0.01
+    #: ``(lo, hi)``: this process holds the experts ``lo <= e < hi`` of the
+    #: ``moe_experts`` the router chooses among (one chip's share of an
+    #: expert-parallel deployment): the expert matrices stack those alone, the
+    #: router keeps its width, and a layer's FFN is THIS share's part of it
+    #: (``ops/moe.py::dropless_moe_ffn(held=)``). None = all held
+    moe_held: Optional[Tuple[int, int]] = None
+    #: the width of one head where it is not ``dim // n_heads`` (0: it is)
+    attn_head_dim: int = 0
+    #: layer KINDS: for each layer the window it attends over (``W``: query at
+    #: ``i`` sees key ``j`` iff ``i - W < j <= i``; 0: every ``j <= i``). Empty
+    #: = every layer full. A configuration that names kinds has a paged cache
+    #: of two layer groups (:func:`cache_layout`)
+    layer_windows: Tuple[int, ...] = ()
+    #: YaRN for the layers WITHOUT a window (a window layer reaches no
+    #: further back than its window and keeps the plain table of
+    #: ``rope_theta``). None = the plain table everywhere
+    rope_scaling: Optional[RopeScaling] = None
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
+
+    def window_of(self, layer: int) -> int:
+        return self.layer_windows[layer] if self.layer_windows else 0
 
     @staticmethod
     def llama2_7b(**overrides) -> "LlamaConfig":
@@ -113,12 +150,13 @@ def _layer_shapes(cfg: LlamaConfig) -> Dict[str, Tuple[int, ...]]:
         "mlp_norm": (cfg.dim,),
     }
     if cfg.moe_experts > 0:
+        held = cfg.moe_experts if cfg.moe_held is None else cfg.moe_held[1] - cfg.moe_held[0]
         shapes.update(
             {
                 "router": (cfg.dim, cfg.moe_experts),
-                "w_gate": (cfg.moe_experts, cfg.dim, cfg.mlp_hidden),
-                "w_up": (cfg.moe_experts, cfg.dim, cfg.mlp_hidden),
-                "w_down": (cfg.moe_experts, cfg.mlp_hidden, cfg.dim),
+                "w_gate": (held, cfg.dim, cfg.mlp_hidden),
+                "w_up": (held, cfg.dim, cfg.mlp_hidden),
+                "w_down": (held, cfg.mlp_hidden, cfg.dim),
             }
         )
     else:
@@ -268,12 +306,39 @@ def rms_norm(x, weight, eps: float):
     return (x32 * inv).astype(x.dtype) * weight
 
 
-def rope_tables(cfg: LlamaConfig, seq_len: int, offset: int = 0):
+def _inv_freq(cfg: LlamaConfig, window: int = 0):
+    """The rotary frequencies ``[hd / 2]`` of a layer and what multiplies its
+    cos and sin: the plain table of ``rope_theta`` and 1, or, for a layer
+    without a window under ``cfg.rope_scaling``, YaRN's blend and its
+    attention factor."""
     hd = cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
+    plain = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
+    y = cfg.rope_scaling
+    if y is None or window:
+        return plain, 1.0
+
+    def correction_dim(rotations: float) -> float:
+        return (hd * math.log(y.original_max / (rotations * 2 * math.pi))) / (
+            2 * math.log(cfg.rope_theta)
+        )
+
+    low = max(math.floor(correction_dim(y.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(y.beta_slow)), hd - 1)
+    ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / y.factor * ramp + plain * (1.0 - ramp), y.attention_factor
+
+
+def _cos_sin(ang, factor: float):
+    """cos and sin of the angles, times YaRN's attention factor where there is one."""
+    if factor == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * factor, jnp.sin(ang) * factor
+
+
+def rope_tables(cfg: LlamaConfig, seq_len: int, offset: int = 0, window: int = 0):
+    inv_freq, factor = _inv_freq(cfg, window)
     pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
-    ang = jnp.outer(pos, inv_freq)  # [S, hd/2]
-    return jnp.cos(ang), jnp.sin(ang)
+    return _cos_sin(jnp.outer(pos, inv_freq), factor)  # [S, hd/2] each
 
 
 def apply_rope(x, cos, sin):
@@ -338,6 +403,7 @@ def _ffn(cfg: LlamaConfig, p, h, valid=None, mesh=None, rules=None):
             experts, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k,
             renormalize=cfg.moe_renormalize,
             valid=None if valid is None else valid.reshape(-1),
+            held=cfg.moe_held,
         )
         return out.reshape(h.shape), aux
     gate = jnp.einsum("...d,dm->...m", h, p["w_gate"])
@@ -349,7 +415,23 @@ def _ffn(cfg: LlamaConfig, p, h, valid=None, mesh=None, rules=None):
     return jnp.einsum("...m,md->...d", jax.nn.silu(gate) * up, p["w_down"]), None
 
 
-def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
+def _attend_by_kind(cfg: LlamaConfig, q, k, v, window: int):
+    """``forward``'s attention for a configuration with layer kinds, on XLA:
+    ``q [B, S, H, hd]``, ``k`` / ``v`` ``[B, S, KV, hd]`` (rope applied) ->
+    ``[B, S, H, hd]``; float32 scores, the causal mask and, for a window
+    layer, ``j > i - window``. (Training such a model wants the mask in
+    ``ops/attention.py``'s kernel: ROADMAP R4.)"""
+    B, S, H, hd = q.shape
+    qg = q.reshape(B, S, cfg.n_kv_heads, H // cfg.n_kv_heads, hd)
+    s = jnp.einsum("bcgrh,bsgh->bgrcs", qg, k).astype(jnp.float32) / math.sqrt(hd)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    seen = (j <= i) & ((j > i - window) if window else True)
+    pattn = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    o = jnp.einsum("bgrcs,bsgh->bcgrh", pattn.astype(v.dtype), v)
+    return o.reshape(B, S, H, hd)
+
+
+def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None, window: int = 0):
     B, S, _ = x.shape
     q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
     # attention ENTRY pin: q/k/v leave the projection in the head-sharded
@@ -362,7 +444,14 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None):
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     rep = cfg.n_heads // cfg.n_kv_heads
-    if cfg.attention_impl in ("ring", "ulysses"):
+    if cfg.layer_windows:
+        if mesh is not None or cfg.attention_impl not in ("auto", "xla"):
+            raise ValueError(
+                "a configuration with layer kinds (layer_windows) runs forward() on one device "
+                "through XLA: the flash kernel of ops/attention.py has no window mask yet"
+            )
+        o = _attend_by_kind(cfg, q, k, v, window).transpose(0, 2, 1, 3)
+    elif cfg.attention_impl in ("ring", "ulysses"):
         if mesh is None:
             raise ValueError(
                 f"attention_impl={cfg.attention_impl!r} is sequence-parallel: "
@@ -477,24 +566,28 @@ def forward(cfg: LlamaConfig, params, tokens, *, remat=False, mesh=None,
     emb = constrain(params["embed"], mesh, rules, (None, None))
     x = emb[tokens]
     x = constrain(x, mesh, rules, ("act_batch", "act_seq", "act_embed"))
-    cos, sin = rope_tables(cfg, S)
-
-    def block(carry, p):
-        x, aux = carry
-        # remat-boundary pin: the carry is the tensor saved at every
-        # checkpoint boundary — its fwd sharding must be explicit so the
-        # recompute and the bwd accumulation land on the same layout
-        x = constrain(x, mesh, rules, ("act_batch", "act_seq", "act_embed"))
-        x = _attention_block(cfg, p, x, cos, sin, mesh=mesh, rules=rules)
-        x, layer_aux = _mlp_block(cfg, p, x, mesh=mesh, rules=rules)
-        return x, aux + layer_aux
-
     policy, do_remat = _remat_policy(remat)
-    if do_remat:
-        block = jax.checkpoint(block, policy=policy)
+
+    def block_of(window: int):
+        """One layer's block, with the mask and the rope table of its kind."""
+        cos, sin = rope_tables(cfg, S, window=window)
+
+        def block(carry, p):
+            x, aux = carry
+            # remat-boundary pin: the carry is the tensor saved at every
+            # checkpoint boundary — its fwd sharding must be explicit so the
+            # recompute and the bwd accumulation land on the same layout
+            x = constrain(x, mesh, rules, ("act_batch", "act_seq", "act_embed"))
+            x = _attention_block(cfg, p, x, cos, sin, mesh=mesh, rules=rules, window=window)
+            x, layer_aux = _mlp_block(cfg, p, x, mesh=mesh, rules=rules)
+            return x, aux + layer_aux
+
+        return jax.checkpoint(block, policy=policy) if do_remat else block
+
+    blocks = {w: block_of(w) for w in sorted({0, *cfg.layer_windows})}
     carry = (x, jnp.zeros((), jnp.float32))
-    for p in params["layers"]:
-        carry = block(carry, p)
+    for layer, p in enumerate(params["layers"]):
+        carry = blocks[cfg.window_of(layer)](carry, p)
     x, aux = carry
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
@@ -589,9 +682,14 @@ def _opt_state_shardings(cfg: LlamaConfig, mesh, rules, optimizer, params):
 #   [n_layers, num_blocks, block_size, n_kv_heads, head_dim]
 # shared by every request. A request owns a list of block ids (its block
 # table row); token position p lives at (blocks[p // block_size],
-# p % block_size) in EVERY layer — block ids are layer-agnostic so the
-# host-side allocator hands out one id per block_size tokens, not one per
-# layer. K/V stay at n_kv_heads (GQA kept compressed in HBM, exactly as
+# p % block_size) in every layer of ONE layer group, so the host-side
+# allocator hands out one id per block_size tokens a group, not one per
+# layer. A configuration whose layers are all of a kind has one group of
+# every layer; one with layer kinds (``layer_windows``) has a K and a V
+# tensor a group, each with its own ``num_blocks`` and its own table a
+# request (:func:`cache_layout`, :func:`_paged_layers`), and few KV heads
+# (4) are stored joined to the tokens, [.., block_size * n_kv_heads,
+# head_dim]. K/V stay at n_kv_heads (GQA kept compressed in HBM, exactly as
 # the flash kernel does): queries are grouped [n_kv, rep] at score time,
 # so cache traffic is 1/rep of the repeated layout.
 #
@@ -602,11 +700,35 @@ def _opt_state_shardings(cfg: LlamaConfig, mesh, rules, optimizer, params):
 
 def cache_layout(cfg: LlamaConfig, block_size: int, dtype=None) -> CacheLayout:
     """The cache description of this block (``models/interface.py``): a K
-    and a V row ``[n_kv_heads, head_dim]`` a token a layer."""
+    and a V row ``[n_kv_heads, head_dim]`` a token a layer. One group of every
+    layer (``all``); for a configuration with layer kinds (``layer_windows``) a
+    group a kind, the full layers' first (``full``: keeps a sequence whole) and
+    one a window width (``window``, or ``window<W>`` where there are several). Heads
+    of whole lanes that are too few to fill a tile (``n_kv`` 4) are stored
+    joined to the tokens (``CacheLayout.flat_blocks``), nothing padded."""
     row = (cfg.n_kv_heads, cfg.head_dim)
+    windows = cfg.layer_windows or (0,) * cfg.n_layers
+    widths = sorted(set(windows))
+    if widths[0] != 0 or len(windows) != cfg.n_layers:
+        raise ValueError(
+            f"layer_windows names {len(windows)} layers of {cfg.n_layers}, full ones "
+            f"among them: {widths[0] == 0} (the paged cache needs a group that keeps a sequence whole)"
+        )
+    names = {0: "all" if len(widths) == 1 else "full"}
+    groups = tuple(
+        LayerGroup(
+            names.get(w, "window" if len(widths) == 2 else f"window{w}"),
+            tuple(l for l, lw in enumerate(windows) if lw == w), w,
+        )
+        for w in widths
+    )
     return CacheLayout(
         kind="kv", n_layers=cfg.n_layers, block_size=block_size,
-        arrays=(("k", row), ("v", row)), dtype=dtype or cfg.dtype,
+        arrays=(("k", row), ("v", row)), dtype=dtype or cfg.dtype, groups=groups,
+        flat_blocks=(
+            cfg.head_dim % 128 == 0 and cfg.n_kv_heads % 8 != 0
+            and (block_size * cfg.n_kv_heads) % 16 == 0
+        ),
     )
 
 
@@ -617,12 +739,11 @@ def init_paged_kv_cache(
     return cache_layout(cfg, block_size, dtype).init(num_blocks)
 
 
-def _rope_at(cfg: LlamaConfig, positions):
-    """cos/sin tables at arbitrary int positions: [...] -> ([..., hd/2] x2)."""
-    hd = cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
+def _rope_at(cfg: LlamaConfig, positions, window: int = 0):
+    """cos/sin tables at arbitrary int positions: [...] -> ([..., hd/2] x2),
+    of a layer with that ``window`` (:func:`_inv_freq`)."""
+    inv_freq, factor = _inv_freq(cfg, window)
+    return _cos_sin(positions.astype(jnp.float32)[..., None] * inv_freq, factor)
 
 
 def _apply_rope_flat(x, cos, sin):
@@ -635,13 +756,27 @@ def _apply_rope_flat(x, cos, sin):
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _scatter_kv(cache, layer: int, blk, off, k, v):
+def _block_size(cfg: LlamaConfig, k_cache) -> int:
+    """Positions a block of ``k_cache``: ``[L, N, bs, n_kv, hd]``, or stored
+    flat, ``[L, N, bs * n_kv, hd]`` (``CacheLayout.flat_blocks``)."""
+    return k_cache.shape[2] if k_cache.ndim == 5 else k_cache.shape[2] // cfg.n_kv_heads
+
+
+def _scatter_kv(cache, layer: int, blk, off, k, v, names=("k", "v")):
     """Write per-token K/V into their cache slots. blk/off: [...] int32,
     k/v: [..., n_kv, hd]. Padding rows target the null block — colliding
-    trash writes are fine, nothing masked-in ever reads them."""
+    trash writes are fine, nothing masked-in ever reads them. ``names``: the
+    layer's group's arrays. A cache stored flat takes a token's heads at the
+    rows ``off * n_kv ..`` of its block."""
+    k_name, v_name = names
+    if cache[k_name].ndim == 4:
+        n_kv = k.shape[-2]
+        blk = blk[..., None]
+        off = off[..., None] * n_kv + jnp.arange(n_kv, dtype=off.dtype)
     return {
-        "k": cache["k"].at[layer, blk, off].set(k),
-        "v": cache["v"].at[layer, blk, off].set(v),
+        **cache,
+        k_name: cache[k_name].at[layer, blk, off].set(k),
+        v_name: cache[v_name].at[layer, blk, off].set(v),
     }
 
 
@@ -671,72 +806,180 @@ def _block_at(block_tables, pos, bs: int):
     return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
 
 
-def _paged_attention(cfg: LlamaConfig, q, cache, layer: int, block_tables, pos):
+def _kernel_serves(cfg: LlamaConfig, window: int, k_cache) -> bool:
+    """``ops/paged_attention.py::kernel_serves`` for this model's cache (a
+    cache stored flat does not say its KV heads by its shape)."""
+    if k_cache.ndim == 5:  # the call a plain configuration always made
+        return paged_attn.kernel_serves(window, cfg.n_heads, k_cache)
+    return paged_attn.kernel_serves(window, cfg.n_heads, k_cache, n_kv=cfg.n_kv_heads)
+
+
+def _chunk_keys(cfg: LlamaConfig, window: int, chunk: int, table_keys: int, bs: int) -> int:
+    """Key positions a prefill chunk of ``chunk`` queries is handed in a
+    layer of that ``window``: the table's width, or for a window layer the
+    window, the chunk and a block's slack (the keys start on a block), in whole
+    key tiles."""
+    if not window:
+        return table_keys
+    tile = latent_flash.tiles(chunk, table_keys)[1]
+    return min(table_keys, -(-(window + chunk + bs) // tile) * tile)
+
+
+def _flash_serves(cfg: LlamaConfig, k_cache, B: int, C: int, table_keys: int, window: int) -> bool:
+    """Whether a chunk's attention runs the flash kernel (``ops/latent_flash.py``)
+    over K and V gathered through the table: ONE sequence, whole tiles, and for
+    now a configuration with layer kinds.
+
+    TODO(ROADMAP R4): the last condition names a configuration, not a shape. A
+    plain GQA configuration of whole lanes (Mistral, Codestral: ``head_dim`` 128)
+    would be served by the same kernel and would stop writing its float32 score
+    matrix; it keeps the materialised softmax here because ISSUE 44 fenced every
+    program that ran before it, and no chip run has compared the two on those
+    cells. ``longprompt-batch`` (prompts of 2-4 k, chunks of 1024) is the cell
+    that would prove it: drop the condition in a ``perf_opt`` PR that runs it."""
+    if not cfg.layer_windows or B != 1:
+        return False
+    keys = _chunk_keys(cfg, window, C, table_keys, _block_size(cfg, k_cache))
+    return latent_flash.kernel_serves(C, keys, cfg.head_dim, cfg.head_dim, 0, k_cache.dtype)
+
+
+def _paged_attention(
+    cfg: LlamaConfig, q, cache, layer: int, block_tables, pos, valid=None,
+    names=("k", "v"), window: int = 0,
+):
     """Causal attention of ``q [B, C, H, hd]`` (rope applied) over the
     cached context of its slot through ``block_tables [B, M]``, so K/V of
     the step's own tokens must be in the cache already. Query ``(b, c)`` at
     global position ``pos[b, c]`` sees key position ``j`` of its slot iff
-    ``j <= pos[b, c]``. Returns ``[B, C, H, hd]``. GQA stays grouped
-    ``[n_kv, rep]``; scores, mask and softmax are float32.
+    ``j <= pos[b, c]`` and, in a layer that keeps a ``window``, ``j > pos[b,
+    c] - window``. Returns ``[B, C, H, hd]``. GQA stays grouped ``[n_kv,
+    rep]``; scores, mask and softmax are float32. ``names``: the arrays of the
+    layer's group in ``cache``, ``layer`` its index among them.
 
-    The ONE place a serving step reads the cache for attention, two ways,
-    chosen at trace time from the shapes
-    (``ops/paged_attention.py::kernel_serves``): a short window (decode,
-    verify) on a TPU runs the Pallas kernel, which reads each slot's own
-    live blocks out of the whole cache and gathers nothing; a prefill chunk,
-    and everything off the chip, gathers ``cache[layer, block_tables]`` for
-    every slot as wide as the table (a flash prefill over context + chunk
-    is what would replace that half)."""
+    The ONE place a serving step reads the cache for attention, three ways,
+    chosen at trace time from the shapes: a short window (decode, verify) on
+    a TPU runs the Pallas kernel (``ops/paged_attention.py::kernel_serves``),
+    which reads each slot's own live blocks out of the whole cache, from a
+    window's first on, and gathers nothing; a prefill chunk of a
+    configuration with layer kinds on a TPU gathers K and V through the table
+    (a window layer: from the block that holds the chunk's first visible key,
+    ``window + chunk`` positions, not the table's width) and runs the flash
+    kernel over them (``ops/latent_flash.py``: grouped heads, key tiles past
+    the diagonal or wholly behind the window never fetched), so no score
+    matrix is written; every other chunk, and everything off the chip,
+    gathers ``cache[layer, block_tables]`` for every slot as wide as the
+    table and materialises the softmax."""
     B, C = pos.shape
-    if paged_attn.kernel_serves(C, cfg.n_heads, cache["k"]):
-        return paged_attn.paged_attention(
-            q, cache["k"], cache["v"], layer, block_tables, pos
-        )
+    k_cache, v_cache = cache[names[0]], cache[names[1]]
+    n_kv, hd = cfg.n_kv_heads, cfg.head_dim
+    if _kernel_serves(cfg, C, k_cache):
+        said = {}  # what a plain configuration's call never says
+        if k_cache.ndim == 4:
+            said["n_kv"] = n_kv
+        if window:
+            said["keeps"] = window
+        return paged_attn.paged_attention(q, k_cache, v_cache, layer, block_tables, pos, **said)
     M = block_tables.shape[1]
-    bs = cache["k"].shape[2]
-    rep = cfg.n_heads // cfg.n_kv_heads
-    ks = cache["k"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
-    vs = cache["v"][layer, block_tables].reshape(B, M * bs, cfg.n_kv_heads, -1)
-    qg = q.reshape(B, C, cfg.n_kv_heads, rep, -1)
+    bs = _block_size(cfg, k_cache)
+    rep = cfg.n_heads // n_kv
+    if _flash_serves(cfg, k_cache, B, C, M * bs, window):
+        keys = _chunk_keys(cfg, window, C, M * bs, bs)
+        ctx_len = pos[0, 0]
+        table, first = block_tables[0], 0
+        if window:
+            # from the block that holds the first key the chunk's first query
+            # sees: the chunk's offset in what it is handed is its context
+            first = jnp.maximum(ctx_len - window + 1, 0) // bs
+            table = jax.lax.dynamic_slice(jnp.pad(table, (0, keys // bs)), (first,), (keys // bs,))
+        with jax.named_scope("attn.gather"):
+            ks = k_cache[layer, table].reshape(keys, n_kv, hd).transpose(1, 0, 2)
+            vs = v_cache[layer, table].reshape(keys, n_kv, hd).transpose(1, 0, 2)
+        o = latent_flash.flash_attention(
+            q[0].transpose(1, 0, 2), ks, vs, ctx_len - first * bs, valid[0].sum(dtype=jnp.int32),
+            scale=1.0 / math.sqrt(hd), group=rep, window=window or None,
+        )
+        return o.transpose(1, 0, 2)[None]
+    ks = k_cache[layer, block_tables].reshape(B, M * bs, n_kv, -1)
+    vs = v_cache[layer, block_tables].reshape(B, M * bs, n_kv, -1)
+    qg = q.reshape(B, C, n_kv, rep, -1)
     s = jnp.einsum("bcgrh,bsgh->bcgrs", qg, ks).astype(jnp.float32)
-    s = s * (1.0 / math.sqrt(cfg.head_dim))
+    s = s * (1.0 / math.sqrt(hd))
     key_pos = jnp.arange(M * bs, dtype=jnp.int32)
     mask = key_pos <= pos[:, :, None]  # [B, C, M*bs]
+    if window:
+        mask &= key_pos > pos[:, :, None] - window
     s = jnp.where(mask[:, :, None, None, :], s, -1e30)
     pattn = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bcgrs,bsgh->bcgrh", pattn.astype(vs.dtype), vs)
     return o.reshape(B, C, cfg.n_heads, -1)
 
 
+def _group_table(block_tables, group: int):
+    """The block table of layer group ``group``: ``block_tables [G, B, M]``,
+    a table a group in the layout's order; a model of ONE group is handed its
+    table as it always was, ``[B, M]``."""
+    return block_tables[group] if block_tables.ndim == 3 else block_tables
+
+
 def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
     """Every block of the model over a paged cache: the body of the three
     serving steps. ``x [B, C, D]`` embedded tokens, ``pos [B, C]`` their
     global positions, ``valid [B, C]`` bool (padding rows write K/V to the
-    null block and reach no expert), ``block_tables [B, M]``. Per layer:
+    null block and reach no expert), ``block_tables`` one table a layer group
+    of the cache (:func:`cache_layout`, :func:`_group_table`). Per layer:
     norm, q/k/v, rope at ``pos``, K/V written to the cache, attention over
     the cache (:func:`_paged_attention`, after the write so a window
-    attends to itself), ``wo``, the FFN. Returns ``(cache, x, loads)``,
-    ``loads`` a list of a MoE block's expert loads a layer.
+    attends to itself), ``wo`` (:func:`_paged_attention_block`), the FFN.
+    Returns ``(cache, x, loads)``, ``loads`` a list of a MoE block's expert
+    loads a layer.
+
+    Layer ``l`` writes and reads its GROUP's arrays through its group's
+    table, with its kind's rope table and mask. What is per group is made
+    once a group: the block a position is written to and the rope table. A
+    configuration without layer kinds has one group, every layer in it.
 
     Positions past a slot's committed context may hold stale K/V (the
     rejected tail of a verify window, a preempted chunk); that is safe by
     construction: every read masks on ``key_pos <= pos``, so nothing past
     the querying token is ever read, and the next write to a position
     overwrites it in place."""
-    bs = cache["k"].shape[2]
-    blk = jnp.where(valid, _block_at(block_tables, pos, bs), 0)
+    layout = cache_layout(cfg, _block_size(cfg, cache["k"]))
+    bs = layout.block_size
+    tables = [_group_table(block_tables, g) for g in range(len(layout.groups))]
+    blks = [jnp.where(valid, _block_at(table, pos, bs), 0) for table in tables]
     off = pos % bs
-    cos, sin = _rope_at(cfg, pos)
+    ropes = [_rope_at(cfg, pos, group.keeps) for group in layout.groups]
+    where = {l: (g, i) for g, group in enumerate(layout.groups) for i, l in enumerate(group.layers)}
     loads = []
     for layer, p in enumerate(params["layers"]):
+        g, index = where[layer]
+        names = (layout.array_name("k", g), layout.array_name("v", g))
+        cache, x = _paged_attention_block(
+            cfg, p, cache, x, pos, valid, tables[g], index, names, layout.groups[g].keeps,
+            blks[g], off, ropes[g],
+        )
+        x = _ffn_residual(cfg, p, x, valid, loads)
+    return cache, x, loads
+
+
+def _paged_attention_block(
+    cfg: LlamaConfig, p, cache, x, pos, valid, block_table, index: int, names, window: int,
+    blk, off, rope,
+):
+    """The attention half of one layer over its group's arrays (``names``,
+    the layer their index ``index``)
+    through its group's table ``block_table [B, M]``: the norm, q / k / v, the
+    kind's rope table ``rope`` (cos, sin at ``pos``), the write of the step's
+    K and V at ``(blk, off)``, the attention over the cache and ``wo``.
+    Returns ``(cache, x + attention)``."""
+    cos, sin = rope
+    with jax.named_scope("attn.window" if window else "attn.full"):
         q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
-        cache = _scatter_kv(cache, layer, blk, off, k, v)
-        o = _paged_attention(cfg, q, cache, layer, block_tables, pos)
-        x = x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
-        x = _ffn_residual(cfg, p, x, valid, loads)
-    return cache, x, loads
+        cache = _scatter_kv(cache, index, blk, off, k, v, names)
+        o = _paged_attention(cfg, q, cache, index, block_table, pos, valid, names, window)
+        return cache, x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
 
 
 def _lm_head(cfg: LlamaConfig, params, x):
@@ -751,7 +994,8 @@ def paged_prefill_step(
     """One prefill chunk for ONE request (``B = 1`` of :func:`_paged_layers`).
 
     tokens: [C] int32 (right-padded chunk), block_table: [M] int32 (padded
-    with 0 = null), ctx_len: scalar int32 tokens ALREADY cached (chunked
+    with 0 = null; ``[G, M]``, a row a layer group, for a configuration with
+    layer kinds, and ``[G, B, M]`` in the two steps below), ctx_len: scalar int32 tokens ALREADY cached (chunked
     prefill: >0 from the second chunk on), true_len: scalar int32 valid
     tokens in this chunk (``valid = idx < true_len``). Head: the chunk's
     last valid row only. Returns ``(cache, logits [vocab])``, and a MoE
@@ -760,7 +1004,8 @@ def paged_prefill_step(
     idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
     cache, x, loads = _paged_layers(
         cfg, params, cache, params["embed"][tokens][None],
-        (ctx_len + idx)[None], (idx < true_len)[None], block_table[None],
+        (ctx_len + idx)[None], (idx < true_len)[None],
+        jnp.expand_dims(block_table, -2),  # [M] -> [1, M]; a table a group: [G, M] -> [G, 1, M]
     )
     logits = _lm_head(cfg, params, x[0, jnp.maximum(true_len - 1, 0)])
     return _step_outputs(cache, logits, loads)
@@ -804,7 +1049,8 @@ def paged_decode_step(
     """
     del ctx_lens
     pos = positions[:, None]
-    valid = _block_at(block_tables, pos, cache["k"].shape[2]) != 0
+    whole = _group_table(block_tables, 0)  # the group that keeps all
+    valid = _block_at(whole, pos, _block_size(cfg, cache["k"])) != 0
     cache, x, loads = _paged_layers(
         cfg, params, cache, params["embed"][tokens][:, None], pos, valid, block_tables
     )
@@ -883,9 +1129,21 @@ def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = 
 
 
 def _attention_path(cfg: LlamaConfig, window: int, cache) -> AttentionPath:
-    if paged_attn.kernel_serves(window, cfg.n_heads, cache["k"]):
-        return AttentionPath("kernel", "blocks")
-    return AttentionPath("gather", "table")
+    """The path of the programs of that query window; a configuration with
+    layer kinds runs the same path in both kinds of layer and says so
+    (``kernel+window``: each group's kernel reads the slot's live blocks of
+    that group, a window's from its first live one on)."""
+    kinds = "+window" if cfg.layer_windows else ""
+    if _kernel_serves(cfg, window, cache["k"]):
+        return AttentionPath(f"kernel{kinds}", "blocks")
+    if _flash_serves(cfg, cache["k"], 1, window, _table_keys(cfg, cache), 0):
+        return AttentionPath(f"flash{kinds}", "live")
+    return AttentionPath(f"gather{kinds}", "table")
+
+
+def _table_keys(cfg: LlamaConfig, cache) -> int:
+    bs = _block_size(cfg, cache["k"])
+    return -(-cfg.max_seq_len // bs) * bs
 
 
 MODEL = Model(
@@ -899,5 +1157,10 @@ MODEL = Model(
     paged_verify_step=paged_verify_step,
     paged_decode_step=paged_decode_step,
     attention_path=_attention_path,
-    held_experts=lambda cfg: (0, cfg.moe_experts) if cfg.moe_experts > 0 else None,
+    held_experts=lambda cfg: (cfg.moe_held or (0, cfg.moe_experts)) if cfg.moe_experts > 0 else None,
+    # the key tile of the chunk's flash kernel, 1 where the chunk is not its to serve
+    key_tile=lambda cfg, window, cache: (
+        latent_flash.tiles(window, _table_keys(cfg, cache))[1]
+        if _flash_serves(cfg, cache["k"], 1, window, _table_keys(cfg, cache), 0) else 1
+    ),
 )

@@ -626,8 +626,8 @@ def next_token_loss(cfg: Xing4Config, params, tokens, targets, *, remat=False,
 # padded each row to 640 and XLA re-laid the whole 2 GB cache out, twice, to
 # gather from it, and copied it again to scatter into it; the compile-only
 # rehearsal of a prefill chunk then did not fit the chip).
-# Block ids are layer-agnostic as in ``models/llama.py`` (every layer is of
-# the one kind), block 0 is the null block, and every read masks on
+# A block id covers every layer (every layer is of the one kind: one layer
+# group, ``models/interface.py``), block 0 is the null block, and every read masks on
 # ``key_pos <= pos``, so stale rows past a slot's context are never read.
 
 

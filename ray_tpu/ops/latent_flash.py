@@ -31,6 +31,18 @@ the sum of two products, ``q . k + q_shared . k_shared``, each over whole
 lanes: nothing is concatenated to 192 columns and padded to 256. ``dk`` and
 ``dv`` need not agree.
 
+GROUPED heads (``group`` query heads a key head: ``k`` and ``v`` are ``[H /
+group, S, .]``) read their key head's tiles through the index map, ``h //
+group``: nothing is repeated in HBM. A layer that keeps a WINDOW (``window``
+= ``W``, static: query at ``i`` sees ``j`` iff ``i - W < j <= i``) masks the
+keys behind it, and a key tile WHOLLY behind the window of a query tile's
+first query is clamped out of the index map and skipped like one past the
+diagonal: never fetched, never multiplied. (``models/llama.py``'s chunk, for
+a configuration with layer kinds: K and V gathered through the table, a
+window layer's from the block that holds its first visible key on, so that
+``ctx_len`` is the chunk's offset in what it is handed.) Without either
+argument the call lowers to the program it always was.
+
 The mathematics is the materialised softmax's to the letter
 (``models/xing4.py::_attend_expanded``): scores float32, times ``scale``,
 masked scores ``-1e30``, probabilities cast to V's dtype for the second
@@ -123,6 +135,7 @@ def _kernel(
     # o_ref [1, block_q, dv] and the scratch acc [block_q, dv], m, l [block_q, 1]
     scale: float,
     shared: bool,
+    window: int,
 ):
     from jax.experimental import pallas as pl
 
@@ -153,11 +166,16 @@ def _kernel(
         if masked:
             q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_pos = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos <= jnp.minimum(q_pos, n_live - 1), s, _MASKED)
+            seen = k_pos <= jnp.minimum(q_pos, n_live - 1)
+            if window:
+                seen &= k_pos > q_pos - window
+            s = jnp.where(seen, s, _MASKED)
             # rows past the live context: stale, or never written
             v = jnp.where(lo + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) < n_live, v, 0)
         # key 0 is in tile 0 and every query of a tile that runs sees it: m
-        # is real from the first step on
+        # is real from the first step on (under a window: the tile's first
+        # query sees its first live key tile; what a later row sums before
+        # its own first key, its first real score wipes with alpha = 0)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -170,6 +188,11 @@ def _kernel(
 
     live = lo <= _last_key(i, ctx_len, true_len, block_q)
     under_diagonal = lo + block_k - 1 <= first_q
+    if window:
+        # wholly behind the first query's window: skipped; no mask only where
+        # the LAST query's window still reaches the tile's first key
+        live &= lo + block_k - 1 > first_q - window
+        under_diagonal &= lo > first_q + block_q - 1 - window
 
     @pl.when(live & under_diagonal)
     def _():
@@ -186,8 +209,13 @@ def _kernel(
         o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret"))
-def _call(q, k, v, q_shared, k_shared, ctx_len, true_len, *, scale, block_q, block_k, interpret):
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret", "group", "window")
+)
+def _call(
+    q, k, v, q_shared, k_shared, ctx_len, true_len, *, scale, block_q, block_k, interpret,
+    group=1, window=0,
+):
     # imported here, as ops/paged_attention.py does: a second of import that
     # only a process which runs the kernel pays
     from jax.experimental import pallas as pl
@@ -200,10 +228,16 @@ def _call(q, k, v, q_shared, k_shared, ctx_len, true_len, *, scale, block_q, blo
     def key_tile(i, j, ctx, n):
         # clamped at the last tile the query tile's real queries see: the
         # tiles past it repeat its index and are not fetched
-        return jnp.minimum(j, jnp.maximum(_last_key(i, ctx[0], n[0], block_q), 0) // block_k)
+        last = jnp.minimum(j, jnp.maximum(_last_key(i, ctx[0], n[0], block_q), 0) // block_k)
+        if not window:
+            return last
+        # and from below at the tile that holds the first query's first key
+        return jnp.maximum(last, jnp.maximum(ctx[0] + i * block_q - window + 1, 0) // block_k)
 
     q_tile = lambda h, i, j, ctx, n: (h, i, 0)  # noqa: E731
     kv_tile = lambda h, i, j, ctx, n: (h, key_tile(i, j, ctx, n), 0)  # noqa: E731
+    if group > 1:
+        kv_tile = lambda h, i, j, ctx, n: (h // group, key_tile(i, j, ctx, n), 0)  # noqa: E731
     in_specs = [
         pl.BlockSpec((1, block_q, dk), q_tile),
         pl.BlockSpec((1, block_k, dk), kv_tile),
@@ -218,7 +252,7 @@ def _call(q, k, v, q_shared, k_shared, ctx_len, true_len, *, scale, block_q, blo
         ]
         operands += [q_shared, k_shared]
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, shared=shared),
+        functools.partial(_kernel, scale=scale, shared=shared, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(H, C // block_q, S // block_k),
@@ -241,12 +275,16 @@ def _call(q, k, v, q_shared, k_shared, ctx_len, true_len, *, scale, block_q, blo
 
 def flash_attention(
     q, k, v, ctx_len, true_len, *, scale: float, q_shared=None, k_shared=None,
-    block_q=None, block_k=None, interpret=None,
+    block_q=None, block_k=None, interpret=None, group=1, window=None,
 ):
     """Attention of ``q [H, C, dk]`` (+ ``q_shared [H, C, ds]``) over ``k [H,
     S, dk]`` (+ ``k_shared [S, ds]``, one row a position for every head) and
     ``v [H, S, dv]``: query ``c`` sees key ``j`` iff ``j <= min(ctx_len + c,
     ctx_len + true_len - 1)`` (``ctx_len``, ``true_len`` int32 scalars, traced).
+    ``group``: query heads a key head, ``k`` and ``v`` then ``[H / group, S,
+    .]``. ``window``: ``W``, static, for keys no further back than ``j >
+    ctx_len + c - W``; a key tile wholly behind a query tile's window is not
+    read (absent: all).
     Returns ``[H, C, dv]`` in ``q``'s dtype; rows of a query tile past
     ``true_len`` are zeros. Reads ``ctx_len + true_len`` keys rounded up to
     ``block_k`` and no other, a query tile at most up to its own diagonal.
@@ -262,5 +300,5 @@ def flash_attention(
         q, k, v, q_shared, k_shared,
         jnp.asarray(ctx_len, jnp.int32), jnp.asarray(true_len, jnp.int32),
         scale=float(scale), block_q=block_q or default_q, block_k=block_k or default_k,
-        interpret=bool(interpret),
+        interpret=bool(interpret), group=int(group), window=int(window or 0),
     )

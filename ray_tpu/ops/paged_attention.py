@@ -30,6 +30,20 @@ may be stale or never written (the tail of a slot's last block, the blocks
 of a wave that were not fetched) is masked out of the scores and zeroed in V
 before the second matmul: a NaN there cannot reach the output.
 
+A cache of FEW KV heads (``n_kv`` 4: fewer than a tile's eight sublanes) is
+stored with a block's heads joined to its tokens, ``[n_layers, num_blocks, bs
+* n_kv, hd]`` (``CacheLayout.flat_blocks``): the same rows in the same order,
+so the kernel is the same; as ``[bs, 4, hd]`` the device would pad every
+token's heads to a whole tile. The caller then says ``n_kv``.
+
+A layer that keeps a WINDOW (``keeps`` = ``W``: query at ``i`` sees ``j`` iff
+``i - W < j <= i``) is told so, and each slot's waves then start at the
+slot's FIRST live block, the one that holds ``min_c pos[b, c] - W + 1``, not
+at block 0: the table's entries behind it were given back and read the null
+block, and are never touched. Rows of the first wave that lie behind a
+query's window are masked like those past it. Without ``keeps`` the call
+lowers to the program it always was.
+
 The layer is an operand, not a constant of the kernel, and the call is
 jitted by itself: the model's 16 calls are one traced and lowered kernel,
 which is what keeps a decode program's start-up at the gather's.
@@ -49,7 +63,8 @@ _MASKED = -1e30
 #: query rows (window x heads) up to which the kernel serves; a prefill chunk
 #: (256 x 32 rows) wants a flash kernel over context + chunk, not this one:
 #: ``ops/latent_flash.py`` is that kernel (Xing4's chunk calls it over keys
-#: expanded from its latent rows; ``models/llama.py``'s chunk does not yet)
+#: expanded from its latent rows; ``models/llama.py``'s chunk over K and V
+#: gathered through the table, where the configuration has layer kinds)
 _MAX_QUERY_ROWS = 256
 
 #: rows ``[bs * n_kv a block, hd]`` a DMA wave brings in, as whole blocks: on the
@@ -58,25 +73,35 @@ _MAX_QUERY_ROWS = 256
 _WAVE_ROWS = 2048
 
 
-def kernel_serves(window: int, n_heads: int, k_cache, backend: str | None = None) -> bool:
+def kernel_serves(
+    window: int, n_heads: int, k_cache, backend: str | None = None, n_kv: int | None = None
+) -> bool:
     """Whether :func:`paged_attention` runs the kernel for a query window of
-    ``window`` positions a slot over ``k_cache`` (anything with the shape
-    ``[n_layers, num_blocks, bs, n_kv, hd]`` and the dtype of the paged
-    cache): on a TPU, for a short window, where the heads of a token are
-    whole ``(8, 128)`` tiles and a block read as ``[bs * n_kv, hd]`` rows is
-    whole ``(16, 128)`` ones (what Mosaic compiles: probed for a described
-    v5e, PERF.md PR 30), in a dtype the MXU multiplies. Everything else (the
-    CPU, a prefill chunk, odd widths) takes the gather. Decided at trace
-    time; the model runner asks the same question to know what a launch
-    reads."""
+    ``window`` positions a slot over ``k_cache`` (anything with the shape and
+    the dtype of the paged cache): on a TPU, for a short window, where a block
+    read as ``[bs * n_kv, hd]`` rows is whole ``(16, 128)`` tiles, in a dtype
+    the MXU multiplies, and the block is stored so that nothing is padded:
+    ``[n_layers, num_blocks, bs, n_kv, hd]`` where the heads of a token are
+    whole ``(8, 128)`` tiles (``n_kv`` 8, 16: probed for a described v5e,
+    PERF.md PR 30), or FLAT, ``[n_layers, num_blocks, bs * n_kv, hd]`` with
+    ``n_kv`` said beside it (``n_kv`` 4, eight query heads a KV head: run
+    against the gather on the chip, PERF.md PR 44). Everything else (the CPU,
+    a prefill chunk, odd widths) takes the gather. Decided at trace time; the
+    model runner asks the same question to know what a launch reads."""
     backend = backend or jax.default_backend()
-    _, _, bs, n_kv, hd = k_cache.shape
+    if len(k_cache.shape) == 5:
+        _, _, bs, n_kv, hd = k_cache.shape
+        whole_heads = n_kv % 8 == 0
+    else:
+        _, _, rows, hd = k_cache.shape
+        whole_heads = bool(n_kv) and rows % n_kv == 0
+        bs = rows // n_kv if whole_heads else 0
     return (
         backend == "tpu"
         and window * n_heads <= _MAX_QUERY_ROWS
         and k_cache.dtype in (jnp.bfloat16, jnp.float32)
         and hd % 128 == 0
-        and n_kv % 8 == 0
+        and whole_heads
         and (bs * n_kv) % 16 == 0
         and n_heads % n_kv == 0
     )
@@ -85,25 +110,25 @@ def kernel_serves(window: int, n_heads: int, k_cache, backend: str | None = None
 def _kernel(
     tables_ref,  # SMEM [B * M] int32
     pos_ref,  # SMEM [B * C] int32
-    nblk_ref,  # SMEM [B] int32: live blocks of the slot, 0 for a padding slot
+    nblk_ref,  # SMEM [B] int32: the slot's blocks up to its last live one, 0 for a padding slot
     next_ref,  # SMEM [B + 1] int32: the first slot >= i that has live blocks (B: none)
     layer_ref,  # SMEM [1] int32
-    q_ref,  # VMEM [B, C * H, hd]
-    k_hbm,  # ANY [L, N, bs, n_kv, hd]
-    v_hbm,
-    o_ref,  # VMEM [B, C * H, hd]
-    kbuf,  # VMEM [2, P, bs, n_kv, hd]
-    vbuf,
-    sems,  # DMA [2 (k, v), 2 (buffer)]
-    *,
+    *refs,  # keeps: first_ref SMEM [B] int32, the slot's FIRST live block; then
+    # q_ref VMEM [B, C * H, hd]; k_hbm, v_hbm ANY [L, N, *block]; o_ref VMEM [B, C * H,
+    # hd]; kbuf, vbuf VMEM [2, P, *block]; sems DMA [2 (k, v), 2 (buffer)]
     window: int,
     table_width: int,
+    n_kv: int,
+    keeps: int,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    first_ref, refs = (refs[0], refs[1:]) if keeps else (None, refs)
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs
     B, rows, hd = q_ref.shape
-    _, P, bs, n_kv, _ = kbuf.shape
+    P = kbuf.shape[1]
+    bs = math.prod(kbuf.shape[2:-1]) // n_kv
     C, M = window, table_width
     H = rows // C
     rep = H // n_kv
@@ -111,8 +136,15 @@ def _kernel(
     scale = 1.0 / math.sqrt(hd)
     layer = layer_ref[0]
 
+    def from_first(b, blocks):
+        """A count of blocks from the slot's first live one, from block 0."""
+        return first_ref[b] + blocks if keeps else blocks
+
+    def live_blocks(b):
+        return nblk_ref[b] - first_ref[b] if keeps else nblk_ref[b]
+
     def wave_copies(b, w, buf, i):
-        blk = tables_ref[b * M + w * P + i]
+        blk = tables_ref[b * M + from_first(b, w * P + i)]
         return (
             pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[buf, i], sems.at[0, buf]),
             pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[buf, i], sems.at[1, buf]),
@@ -124,7 +156,7 @@ def _kernel(
                 do(copy)
             return carry
 
-        jax.lax.fori_loop(0, jnp.minimum(P, nblk_ref[b] - w * P), body, 0)
+        jax.lax.fori_loop(0, jnp.minimum(P, live_blocks(b) - w * P), body, 0)
 
     def start_wave(b, w, buf):
         each_live_block(b, w, buf, lambda copy: copy.start())
@@ -146,7 +178,7 @@ def _kernel(
         start_wave(next_ref[0], 0, 0)
 
     def slot(b, buf):
-        n_waves = pl.cdiv(nblk_ref[b], P)
+        n_waves = pl.cdiv(live_blocks(b), P)
         q = q_ref[b]
         # the last position each query row may see, and the slot's own last
         limit = jnp.full((rows, 1), pos_ref[b * C], jnp.int32)
@@ -165,7 +197,7 @@ def _kernel(
                 start_wave(nb, jnp.where(ends_slot, 0, w + 1), 1 - buf)
 
             wait_wave(b, w, buf)
-            base = w * (P * bs)
+            base = (first_ref[b] + w * P) * bs if keeps else w * (P * bs)
 
             @pl.when(base + P * bs > last + 1)
             def _():  # rows past the slot's context: stale, or never fetched
@@ -176,7 +208,10 @@ def _kernel(
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
-            s = jnp.where(same_head & (col_tok <= limit - base), s * scale, _MASKED)
+            seen = same_head & (col_tok <= limit - base)
+            if keeps:  # behind the query's window: the head of the first wave
+                seen &= col_tok > limit - base - keeps
+            s = jnp.where(seen, s * scale, _MASKED)
             m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -185,7 +220,10 @@ def _kernel(
             acc = alpha * acc + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             return m_new, l, acc, 1 - buf
 
-        # wave 0 holds position 0, which every row sees: m is real after it
+        # wave 0 holds position 0 (a window's first position), which every
+        # row sees (the first row's, which the others' windows reach or pass:
+        # what a row sums before its own first key, the next real score wipes
+        # with alpha = 0): m is real after it
         m, l, acc, buf = jax.lax.fori_loop(
             0, n_waves, wave,
             (
@@ -202,32 +240,43 @@ def _kernel(
     jax.lax.fori_loop(0, B, slot, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("wave_blocks", "interpret"))
-def _call(q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("wave_blocks", "interpret", "n_kv", "keeps"))
+def _call(
+    q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks: int, interpret: bool,
+    n_kv: int, keeps: int,
+):
     # imported here, as ops/attention.py does: a second of import that only a
     # process which runs the kernel pays (the model module is imported by all)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, C, H, hd = q.shape
-    _, _, bs, n_kv, _ = k_cache.shape
+    block = k_cache.shape[2:]  # [bs, n_kv, hd], or flat [bs * n_kv, hd]
+    bs = math.prod(block[:-1]) // n_kv
     M, P = block_tables.shape[1], wave_blocks
-    # block 0 is the null block: a table that starts on it is a padding slot's
-    nblk = jnp.where(block_tables[:, 0] == 0, 0, jnp.minimum(pos.max(axis=1) // bs + 1, M))
+    # block 0 is the null block: a table whose first live entry is it is a
+    # padding slot's (a window's entries behind its first live block are too)
+    first = ()
+    head = block_tables[:, 0]
+    if keeps:
+        first_block = jnp.minimum(jnp.maximum(pos.min(axis=1) - keeps + 1, 0) // bs, M - 1)
+        head = jnp.take_along_axis(block_tables, first_block[:, None], axis=1)[:, 0]
+        first = (first_block,)
+    nblk = jnp.where(head == 0, 0, jnp.minimum(pos.max(axis=1) // bs + 1, M))
     slots = jnp.arange(B, dtype=jnp.int32)
     first_live_from = jax.lax.cummin(jnp.where(nblk > 0, slots, B), reverse=True)
     any_space = pl.BlockSpec(memory_space=pl.ANY)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        functools.partial(_kernel, window=C, table_width=M),
+        functools.partial(_kernel, window=C, table_width=M, n_kv=n_kv, keeps=keeps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=5 + len(first),
             grid=(),
             in_specs=[vmem, any_space, any_space],
             out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((2, P, bs, n_kv, hd), k_cache.dtype),
-                pltpu.VMEM((2, P, bs, n_kv, hd), v_cache.dtype),
+                pltpu.VMEM((2, P, *block), k_cache.dtype),
+                pltpu.VMEM((2, P, *block), v_cache.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
@@ -240,6 +289,7 @@ def _call(q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks: int, in
         nblk,
         jnp.append(first_live_from, B),
         layer.reshape(1),
+        *first,
         q.reshape(B, C * H, hd),
         k_cache,
         v_cache,
@@ -248,7 +298,8 @@ def _call(q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks: int, in
 
 
 def paged_attention(
-    q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks=None, interpret=None
+    q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks=None, interpret=None,
+    n_kv=None, keeps=0,
 ):
     """Causal attention of ``q [B, C, H, hd]`` over each slot's cached
     context: query ``(b, c)`` sees key position ``j`` of slot ``b`` iff
@@ -259,15 +310,23 @@ def paged_attention(
     ``min(max_c pos[b, c] // bs + 1, M)`` blocks and no other; a padding slot
     (``block_tables[b, 0] == 0``) reads none and returns zeros.
 
+    ``n_kv``: the KV heads of a cache stored flat, ``[n_layers, num_blocks, bs
+    * n_kv, hd]`` (a 5-D cache says it by its shape). ``keeps``: ``W`` for a
+    layer that keeps a window (query at ``i`` sees ``j`` iff ``i - W < j <=
+    i``); a slot then reads from the block that holds ``min_c pos[b, c] - W +
+    1`` on, and is a padding slot if THAT entry of its table is the null block.
+
     ``wave_blocks``: blocks a DMA wave (default: ``_WAVE_ROWS`` rows of K).
     ``interpret``: run the kernel in Pallas' TPU interpreter (what the CPU
     tests do); by default wherever the backend is not a TPU."""
-    _, _, bs, n_kv, _ = k_cache.shape
+    if k_cache.ndim == 5:
+        n_kv = k_cache.shape[3]
+    rows = math.prod(k_cache.shape[2:-1])  # bs * n_kv
     M = block_tables.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _call(
         q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), block_tables, pos,
-        wave_blocks=wave_blocks or min(M, max(1, _WAVE_ROWS // (bs * n_kv))),
-        interpret=bool(interpret),
+        wave_blocks=wave_blocks or min(M, max(1, _WAVE_ROWS // rows)),
+        interpret=bool(interpret), n_kv=int(n_kv), keeps=int(keeps),
     )

@@ -12,6 +12,7 @@ hundred float32 roundings through two layers and a thousand times tighter
 than what leaving out a gate's renormalisation, a norm or an expert moves
 (the last three tests of this file read 1e-2 and more)."""
 
+import dataclasses
 import os
 import sys
 
@@ -582,5 +583,74 @@ def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(on
         # the queries laid out twice are the one temporary: slots x 2 x window x heads x 1152 bf16
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * slots * 2 * window * heads * 1152 * 2 + 2**20
         assert [o.shape for o in compiled.out_info] == [(slots, window, heads, 512)] + [(slots, window, heads)] * 2
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize("group, blocks, keeps", [("full", 17408, 0), ("window", 4480, 1024)],
+                         ids=["mellum2_full_layers", "mellum2_window_layers"])
+def test_the_paged_attention_kernel_compiles_over_a_flat_cache_of_four_kv_heads(one_chip, group, blocks, keeps):
+    """Mellum2's decode (``ops/paged_attention.py``; here for the same reason
+    as the ones above): 4 KV heads of 128, eight query heads each, a block
+    stored flat as ``[64, 128]`` (as ``[16, 4, 128]`` the device pads every
+    token's heads to a tile), 64 slots, the 16 k table; a window layer is told
+    each slot's first live block. Both groups' whole pools as the
+    configuration sizes them go in as they lie: one Mosaic call, no copy."""
+    from ray_tpu.ops import paged_attention as PA
+
+    layers = {"full": 7, "window": 21}[group]
+    cfg = dataclasses.replace(L.LlamaConfig.tiny(), n_heads=32, n_kv_heads=4, attn_head_dim=128, dtype=jnp.bfloat16)
+    layout = L.cache_layout(cfg, 16)
+    assert layout.flat_blocks and layout.block_shape((4, 128)) == (64, 128)
+    cache_like = jax.ShapeDtypeStruct((layers, blocks, 64, 128), jnp.bfloat16)
+    assert PA.kernel_serves(1, 32, cache_like, backend="tpu", n_kv=4)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        cache = shape(cache_like.shape, jnp.bfloat16)
+        compiled = jax.jit(
+            lambda q, k, v, tables, pos: PA.paged_attention(
+                q, k, v, layers - 1, tables, pos, interpret=False, n_kv=4, keeps=keeps
+            )
+        ).lower(
+            shape((64, 1, 32, 128), jnp.bfloat16), cache, cache, shape((64, 1024), jnp.int32), shape((64, 1), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "paged_attn" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+        assert compiled.out_info.shape == (64, 1, 32, 128)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize("chunk, keys, window", [(1024, 16384, 0), (256, 16384, 0), (1024, 3072, 1024), (256, 2048, 1024)],
+                         ids=["full_chunk_1024", "full_chunk_256", "window_chunk_1024", "window_chunk_256"])
+def test_the_flash_kernel_compiles_with_grouped_heads_and_a_window_at_mellum2_widths(one_chip, chunk, keys, window):
+    """Mellum2's prefill chunk through ``ops/latent_flash.py``: 32 query heads
+    over 4 key heads (the index map, nothing repeated), keys and values 128
+    wide, no shared part; a full layer over the 16 k table, a window layer over
+    the window, the chunk and a block's slack in whole key tiles
+    (``llama._chunk_keys``). One Mosaic call; the scores are nobody's temporary."""
+    from ray_tpu.ops import latent_flash as LF
+
+    cfg = dataclasses.replace(L.LlamaConfig.tiny(), n_heads=32, n_kv_heads=4, attn_head_dim=128, layer_windows=(1024, 0))
+    assert L._chunk_keys(cfg, window, chunk, 16384, 16) == keys
+    assert LF.kernel_serves(chunk, keys, 128, 128, 0, jnp.bfloat16, backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        compiled = jax.jit(
+            lambda q, k, v, ctx, n: LF.flash_attention(
+                q, k, v, ctx, n, scale=0.1, interpret=False, group=8, window=window or None
+            )
+        ).lower(
+            shape((32, chunk, 128)), shape((4, keys, 128)), shape((4, keys, 128)), shape((), jnp.int32), shape((), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_flash" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+        assert compiled.out_info.shape == (32, chunk, 128)
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)

@@ -152,9 +152,9 @@ def test_every_entry_point_attends_through_the_one_seam(monkeypatch, step):
     fn = getattr(L, f"paged_{step}_step")
     real, calls = L._paged_attention, []
 
-    def counting(cfg_, q, cache_, layer, block_tables, pos):
+    def counting(cfg_, q, cache_, layer, block_tables, pos, *of_the_group):
         calls.append((layer, q.shape, block_tables.shape, pos.shape))
-        return real(cfg_, q, cache_, layer, block_tables, pos)
+        return real(cfg_, q, cache_, layer, block_tables, pos, *of_the_group)
 
     monkeypatch.setattr(L, "_paged_attention", counting)
     logits = fn(cfg, params, cache, *args)[1]
@@ -210,9 +210,9 @@ def test_the_kernel_path_is_still_the_one_door(monkeypatch, step):
     doors, kernels = [], []
     real = L._paged_attention
 
-    def door(cfg_, q, cache_, layer, block_tables, pos):
+    def door(cfg_, q, cache_, layer, block_tables, pos, *of_the_group):
         doors.append(layer)
-        return real(cfg_, q, cache_, layer, block_tables, pos)
+        return real(cfg_, q, cache_, layer, block_tables, pos, *of_the_group)
 
     def kernel(q, k_cache, v_cache, layer, block_tables, pos):
         kernels.append((layer, len(doors), k_cache.shape, v_cache.shape, q.shape, pos.shape))
