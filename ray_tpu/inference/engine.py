@@ -9,8 +9,10 @@ push waits until the NEXT launch is on its way (``_hold`` /
 ``_deliver_held``): the streams it wakes then run while the device does.
 The loop of a SATURATED engine leaves an all-greedy decode launch unread
 while it plans and launches the next step (all its programs before it waits
-for any), whose rows take their tokens from that launch's picks on the
-device (``_stays_unread``): the host's part of a step then runs beside the
+for any), whose rows take their tokens from that launch's result on the
+device (``_stays_unread``): a plain batch's picks, or, where the model drafts
+for itself, the ONE-program step's new tokens, accepted count and next draft,
+which are the next window. The host's part of a step then runs beside the
 device's.
 
 Request lifecycle hooks the rest of the runtime:
@@ -178,14 +180,41 @@ class KvMigrationHandoff(RequestFailedError):
 
 
 @dataclass
+class _Windows:
+    """What the host knows of the rows of a drafter's step, in row order."""
+
+    #: each window as it was launched: ``[x, d]``, ``[x]`` where no draft
+    #: rides, ``[-1 - j, ..]`` where it is what row ``j`` of the unread step
+    #: before leaves on the device
+    tokens: List[List[int]]
+    #: how many of a window's tokens are committed (2: a slot without a draft yet)
+    known: List[int]
+    #: the drafts that ride each window; None: ONE rides whose value was on
+    #: the device at the launch (a named row), known once that step is read
+    drafts: List[Optional[List[int]]]
+    #: each row's block table and the context length its window starts at (of a
+    #: named row the least: the program adds what the step before accepted)
+    rows: List[Any]
+    ctxs: List[int]
+
+    def riding(self, row: int) -> int:
+        drafts = self.drafts[row]
+        return 1 if drafts is None else len(drafts)
+
+
+@dataclass
 class _DecodeBatch:
-    """A plain decode launch and the requests of its rows, in row order."""
+    """A decode launch and the requests of its rows, in row order: a plain
+    decode batch, or the step of a model that drafts for itself."""
 
     reqs: List[Request]
     #: the runner's handle (``model_runner.Launched``): what a read waits for
     launched: Any
     #: every request greedy: the read gives the device's picks, not logits
+    #: (a drafter's step: its ONE program ran, not the two around the sampler)
     greedy: bool
+    #: a drafter's step: its rows' windows (None: a plain decode launch)
+    windows: Optional[_Windows] = None
 
 
 @dataclass
@@ -495,8 +524,9 @@ class InferenceEngine:
             "items": 0, "after_launch": 0, "at_idle": 0, "direct": 0, "held_s": 0.0,
         }
         #: the one decode launch the step loop has not read yet (None: every
-        #: launch is read): its rows' tokens are in flight while the next
-        #: step is planned and launched (:meth:`_stays_unread`)
+        #: launch is read), a plain batch or a drafter's step: its rows' tokens
+        #: are in flight while the next step is planned and launched
+        #: (:meth:`_stays_unread`)
         self._unread: Optional[_DecodeBatch] = None
         #: plain decode launches, those launched while the one before was
         #: unread, and results dropped before they reached a stream
@@ -599,13 +629,15 @@ class InferenceEngine:
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._spec_rollbacks = 0
-        #: a model that drafts for itself: slots x steps through its step,
-        #: the tokens they committed (1 to 2 a slot-step), the steps, and which
-        #: of them took the ONE program (an all-greedy batch) or the two around
-        #: the sampler
+        #: a model that drafts for itself: slots x steps through its step that
+        #: were read AND committed, the tokens they committed (1 to 2 a
+        #: slot-step), the steps, which of them took the ONE program (an
+        #: all-greedy batch) or the two around the sampler, those launched while
+        #: the step before was unread (its result handed over on the device),
+        #: and the rows dropped before they reached a stream
         self._mtp_counts = {
             "slot_steps": 0, "committed_tokens": 0, "step_launches": 0, "launches_fused": 0,
-            "launches_split": 0,
+            "launches_split": 0, "launches_ahead": 0, "rows_dropped": 0,
         }
         #: (proposed, accepted) snapshot at the last gauge refresh — the
         #: adaptive controller steers on the window delta, not lifetime
@@ -659,6 +691,7 @@ class InferenceEngine:
                 )
             self.scheduler.spec_max_context = model_cfg.max_seq_len
             self.scheduler.spec_k_live = ec.speculative_k
+            self.scheduler.spec_drafts_on_device = self._mtp
         self.total_steps = 0
         if ec.warmup:
             t0 = time.perf_counter()
@@ -1331,7 +1364,11 @@ class InferenceEngine:
                         spec_slots.append((r, drafts))
                     else:
                         plain.append(r)
-        batch = self._launch_decode(plain) if plain else None
+        batch = None
+        if plain:
+            batch = self._launch_decode(plain)
+        elif drafting:
+            batch = self._launch_windows(drafting)
         if batch is not None:
             # the last commits go out while the device runs this step's
             # programs; what the step commits from here on, before each of
@@ -1340,7 +1377,7 @@ class InferenceEngine:
         if ahead:
             # the decode launch the last step left unread: its tokens were in
             # flight while this step was planned and launched
-            self._read_unread()
+            self._read_unread(batch)
             n_prefill_tokens = self._commit_chunks(chunks)
 
         # each batch: sample every slot, then emit every slot, so that
@@ -1350,6 +1387,7 @@ class InferenceEngine:
             if in_loop and not spec_slots and self._stays_unread(plan, batch):
                 for row, req in enumerate(batch.reqs):
                     req.in_flight = row
+                    req.in_flight_most = 1 + (batch.windows.riding(row) if batch.windows else 0)
                 self._unread = batch
             else:
                 self._read_decode(batch)
@@ -1372,8 +1410,6 @@ class InferenceEngine:
             with clock.phase("emit"), clock.part("commit"):
                 for (req, drafts), tokens in zip(spec_slots, sampled):
                     self._spec_commit(req, drafts, tokens)
-        if drafting:
-            self._mtp_step(drafting)
         with clock.phase("bookkeeping"):
             if n_prefill_tokens:
                 self._prefill_token_times.append((time.monotonic(), n_prefill_tokens))
@@ -1487,55 +1523,118 @@ class InferenceEngine:
     def _stays_unread(self, plan, batch: "_DecodeBatch") -> bool:
         """Whether the step loop may go on to plan the next step before it
         reads this decode launch. One predicate, read off the engine's own
-        state: the device holds the batch's next tokens (a plain, all-greedy
-        batch: the picks; nothing in it may speculate, and no chunk of the
-        step is an export), and the engine is SATURATED: an arrival could
-        not get a decode slot anyway, because the running requests (those in
-        prefill hold the slots they will decode in) fill the batch or
-        requests already wait. Then looking ahead costs nobody a slot it
+        state: the device holds the batch's next tokens and the next launch
+        can take them there (a plain, all-greedy batch in which nothing may
+        speculate: the picks; or the all-greedy step of a model that drafts
+        for itself, in its ONE-program form: its new tokens, accepted count
+        and next draft are the next window, and every request that could
+        decode next is greedy too, so that the next step is that program
+        again; a batch a proposer on the HOST drafts for never is; no chunk
+        of the step is an export), and the engine is SATURATED: an arrival
+        could not get a decode slot anyway, because the running requests
+        (those in prefill hold the slots they will decode in) fill the batch
+        or requests already wait. Then looking ahead costs nobody a slot it
         could have had; on an engine with room it would cost an arrival up to
         one decode step before its chunk can start."""
         sched = self.scheduler
+        if batch.windows is None:
+            on_device = all(r.spec_k == 0 for r in batch.reqs)
+        else:
+            on_device = all(r.temperature <= 0.0 for r in list(sched.running))
         return (
             batch.greedy
-            and all(r.spec_k == 0 for r in batch.reqs)
+            and on_device
             and not any(p[0].prefill_only for p in plan.prefills)
             and (len(sched.running) >= sched.max_decode_batch or bool(sched.waiting))
         )
 
-    def _read_unread(self) -> bool:
-        """Read the decode launch the last step left unread, if it left one."""
+    def _read_unread(self, later: Optional["_DecodeBatch"] = None) -> bool:
+        """Read the decode launch the last step left unread, if it left one.
+        ``later``: the launch made since, whose rows named this one's."""
         batch, self._unread = self._unread, None
         if batch is None:
             return False
-        self._read_decode(batch)
+        self._read_decode(batch, later)
         return True
 
-    def _read_decode(self, batch: "_DecodeBatch") -> None:
-        """Wait for a decode launch, sample and commit its rows. A row whose
-        request is no longer decoding is DROPPED, never emitted: it finished
-        on the token before (an EOS the host could only see in the token),
-        was cancelled, reaped, failed or preempted while this one was in
-        flight. Its blocks and state slot went back to the pool with that
-        launch still queued, and that is safe: the device runs its queue in
-        the order of the launches, so the dropped row's write lands before
+    def _read_decode(self, batch: "_DecodeBatch", later: Optional["_DecodeBatch"] = None) -> None:
+        """Wait for a decode launch, sample and commit its rows: a token a
+        row of a plain batch, the accepted prefix of its window of a
+        drafter's step (:meth:`_spec_commit`; the ONE program hands back
+        three small integers a slot, the two-program form both rows' logits
+        for the engine's own sampler, :meth:`_spec_sample`, and its second
+        program runs the drafter while the commits go out). A row whose
+        request is no longer decoding is DROPPED, never emitted, and its
+        draft released: it finished on the tokens before (an EOS the host
+        could only see in the token, a cap reached inside an accepted
+        window), was cancelled, reaped, failed or preempted while this one
+        was in flight. Its blocks and state slot went back to the pool with
+        that launch still queued, and that is safe: the device runs its queue
+        in the order of the launches, so the dropped row's write lands before
         any program launched after the blocks or the slot were handed on,
         and every reader reads only what its own sequence wrote after that
-        (a chunk at context 0 starts its slot's state from zeros)."""
+        (a chunk at context 0 starts its slot's state from zeros). ``later``:
+        the launch made after this one and not read yet; a request with a
+        window in it keeps its blocks as far as that window reaches."""
         clock = self._clock
-        out = self.runner.read(batch.launched, clock, self._wake_after_launch)
+        wake = self._wake_after_launch
+        out = self.runner.read(batch.launched, clock, wake)
+        w, reqs = batch.windows, batch.reqs
+        nxt = second = None
         with clock.phase("sample"):
-            if batch.greedy:  # the device's picks, one a slot
-                sampled = [int(t) for t in out]
+            if w is None:
+                if batch.greedy:  # the device's picks, one a slot
+                    sampled = [int(t) for t in out]
+                else:
+                    sampled = [self._sample(req, lg) for req, lg in zip(reqs, out)]
+            elif batch.greedy:  # [new tokens.., accepted, the next draft] a slot
+                sampled = [
+                    [int(t) for t in (row[: 1 + row[-2]] if k == 1 else row[:1])]
+                    for row, k in zip(out, w.known)
+                ]
+                nxt = [int(row[-1]) for row in out]
             else:
-                sampled = [self._sample(req, lg) for req, lg in zip(batch.reqs, out)]
+                sampled = [
+                    self._spec_sample(r, d, lg[len(t) - 1 - len(d) : len(t)])
+                    for r, d, t, lg in zip(reqs, w.drafts, w.tokens, out)
+                ]
+        if w is not None and not batch.greedy:
+            # the token after each position of a window, as far as committed
+            follows = [
+                (t[1:] if k == 2 else []) + toks for t, k, toks in zip(w.tokens, w.known, sampled)
+            ]
+            second = self.runner.launch_mtp_draft(batch.launched, follows, w.rows, w.ctxs, clock)
+        reach = {}
+        if later is not None and later.windows is not None:
+            reach = {id(r): later.windows.riding(i) for i, r in enumerate(later.reqs)}
+        counts = self._mtp_counts
         with clock.phase("emit"), clock.part("commit"):
-            for req, token in zip(batch.reqs, sampled):
+            for i, (req, tokens) in enumerate(zip(reqs, sampled)):
                 req.in_flight = None
                 if req.state != DECODE:
-                    self._decode_ahead["dropped"] += 1
+                    if w is None:
+                        self._decode_ahead["dropped"] += 1
+                    else:
+                        counts["rows_dropped"] += 1
+                        self.spec.release(req.request_id)
                     continue
-                self._emit_token(req, token)
+                if w is None:
+                    self._emit_token(req, tokens)
+                    continue
+                drafts = w.drafts[i]
+                if drafts is None:  # it rode on the device: the step before handed it back
+                    draft = self.spec.draft_of(req.request_id)
+                    drafts = [] if draft is None else [draft]
+                had = len(req.generated)
+                self._spec_commit(req, drafts, tokens, reach.get(id(req), 0))
+                counts["slot_steps"] += 1
+                counts["committed_tokens"] += len(req.generated) - had
+        if second is not None:
+            nxt = [int(t) for t in self.runner.read(second, clock, wake)]
+        if nxt is not None:
+            for req, draft in zip(reqs, nxt):
+                if req.state == DECODE:
+                    self.spec.keep(req.request_id, draft)
 
     def _drop_unread(self) -> None:
         """Forget the unread launch: its requests have all been failed."""
@@ -1543,7 +1642,10 @@ class InferenceEngine:
         if batch is not None:
             for req in batch.reqs:
                 req.in_flight = None
-            self._decode_ahead["dropped"] += len(batch.reqs)
+            if batch.windows is None:
+                self._decode_ahead["dropped"] += len(batch.reqs)
+            else:
+                self._mtp_counts["rows_dropped"] += len(batch.reqs)
 
     # -- speculative decoding (PR 19) -------------------------------------
     def _spec_propose(self, req: Request) -> List[int]:
@@ -1581,7 +1683,7 @@ class InferenceEngine:
         return tokens
 
     def _spec_commit(
-        self, req: Request, drafts: List[int], tokens: List[int]
+        self, req: Request, drafts: List[int], tokens: List[int], reach: int = 0
     ) -> None:
         """Commit the deterministically-accepted prefix of one slot's
         verify window ``[last_committed, d_1..d_k']`` from its
@@ -1608,7 +1710,9 @@ class InferenceEngine:
         read stops at the committed context length, and re-verification
         overwrites the slots in place. The prefix index and the KV tier
         only ever see positions below the verified cursor because both
-        derive from ``generated``."""
+        derive from ``generated``. ``reach``: the drafts that ride the
+        request's NEXT window where that is launched already (a drafter's
+        step read late): its blocks stay as far as that window writes."""
         m = self.metrics
         accepted = 0
         for i, tok in enumerate(tokens):
@@ -1625,74 +1729,57 @@ class InferenceEngine:
         if accepted < len(drafts):
             self._spec_rollbacks += 1
             m["spec_rollbacks"].inc()
-        self.blocks.trim_to(req.request_id, req.context_len)
+        self.blocks.trim_to(req.request_id, req.context_len + reach)
 
-    def _mtp_step(self, reqs: List[Request]) -> None:
-        """The decode step of a model that drafts for itself. A slot's window
-        is ``[x_n, d]``, its committed last token and the draft the step
-        before handed back (``[x_n]`` alone where the plan left no room for a
-        draft), or, for a slot without a draft yet, the window one position
-        earlier with both tokens committed (``known`` 2: the drafter's row at
-        the first waits for exactly this step). An all-greedy batch is ONE
-        launch: the device verifies, picks, compares, runs the drafter over
-        what it committed and drafts again, and the host reads three small
-        integers a slot. Any other batch reads both rows' logits, accepts
-        with the engine's own sampler (:meth:`_spec_sample`: exact match) and
-        launches the drafter as a second program, whose run the commits
-        overlap. Either way :meth:`_spec_commit` emits."""
+    def _launch_windows(self, reqs: List[Request]) -> "_DecodeBatch":
+        """Launch the decode step of a model that drafts for itself. A slot's
+        window is ``[x_n, d]``, its committed last token and the draft the
+        step before handed back (``[x_n]`` alone where the plan left no room
+        for a draft), or, for a slot without a draft yet, the window one
+        position earlier with both tokens committed (``known`` 2: the
+        drafter's row at the first waits for exactly this step). A request
+        with a window in flight (in the step the loop left unread) is NAMED,
+        not given: ``-1 - j`` is what row ``j`` of that step leaves on the
+        device, its last committed token and its draft, at least one position
+        on. An all-greedy batch is ONE launch: the device verifies, picks,
+        compares, runs the drafter over what it committed and drafts again.
+        Any other takes the two-program form, whose rows the host must know
+        (:meth:`_stays_unread` leaves nothing unread before such a batch).
+        :meth:`_read_decode` commits either."""
         clock = self._clock
-        wake = self._wake_after_launch
+        unread = self._unread
         with clock.phase("launch"), clock.part("rows"):
-            windows, known, ctxs, drafts = [], [], [], []
+            tokens, known, ctxs, drafts = [], [], [], []
             for r in reqs:
+                rides = r.spec_step_k > 0
+                if r.in_flight is not None:
+                    tokens.append([-1 - r.in_flight] + [0] * rides)
+                    ctxs.append(r.context_len - 1 + r.ahead)
+                    drafts.append(None if rides else [])
+                    known.append(1)
+                    continue
                 last, draft = r.generated[-1], self.spec.draft_of(r.request_id)
                 if draft is None:
                     before = r.generated[-2] if len(r.generated) > 1 else r.prompt[-1]
-                    windows.append([before, last])
+                    tokens.append([before, last])
                     ctxs.append(r.context_len - 2)
                     drafts.append([])
                 else:
-                    drafts.append([draft] if r.spec_step_k > 0 else [])
-                    windows.append([last] + drafts[-1])
+                    drafts.append([draft] if rides else [])
+                    tokens.append([last] + drafts[-1])
                     ctxs.append(r.context_len - 1)
                 known.append(2 if draft is None else 1)
             rows = [self.blocks.table_row(r.request_id, self.runner.max_blocks_per_seq) for r in reqs]
             greedy = all(r.temperature <= 0.0 for r in reqs)
-        launched = self.runner.launch_mtp_step(windows, known, rows, ctxs, clock, greedy=greedy)
-        out = self.runner.read(launched, clock, wake)
-        second = None
-        with clock.phase("sample"):
-            if greedy:  # [new tokens.., accepted, the next draft] a slot
-                sampled = [
-                    [int(t) for t in (row[: 1 + row[-2]] if k == 1 else row[:1])]
-                    for row, k in zip(out, known)
-                ]
-                nxt = [int(row[-1]) for row in out]
-            else:
-                sampled = [
-                    self._spec_sample(r, d, lg[len(w) - 1 - len(d) : len(w)])
-                    for r, d, w, lg in zip(reqs, drafts, windows, out)
-                ]
-        if not greedy:
-            # the token after each position of a window, as far as committed
-            follows = [
-                (w[1:] if k == 2 else []) + toks for w, k, toks in zip(windows, known, sampled)
-            ]
-            second = self.runner.launch_mtp_draft(launched, follows, rows, ctxs, clock)
+        launched = self.runner.launch_mtp_step(
+            tokens, known, rows, ctxs, clock, greedy=greedy,
+            after=unread.launched if unread is not None else None,
+        )
         counts = self._mtp_counts
         counts["launches_fused" if greedy else "launches_split"] += 1
         counts["step_launches"] += 1
-        counts["slot_steps"] += len(reqs)
-        with clock.phase("emit"), clock.part("commit"):
-            for req, d, toks in zip(reqs, drafts, sampled):
-                had = len(req.generated)
-                self._spec_commit(req, d, toks)
-                counts["committed_tokens"] += len(req.generated) - had
-        if second is not None:
-            nxt = [int(t) for t in self.runner.read(second, clock, wake)]
-        for req, draft in zip(reqs, nxt):
-            if not req.finished:
-                self.spec.keep(req.request_id, draft)
+        counts["launches_ahead"] += unread is not None
+        return _DecodeBatch(reqs, launched, greedy, _Windows(tokens, known, drafts, rows, ctxs))
 
     # -- internals --------------------------------------------------------
     def _sample(

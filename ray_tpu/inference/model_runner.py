@@ -64,8 +64,9 @@ inputs, the ``jit`` call, a :class:`Launched` back at once) and a READ
 (:meth:`PagedModelRunner.read`: the wait, then the copy); ``prefill_chunk``
 and ``decode`` are both at once. A decode launch may name, for any row, a row
 of the picks of the decode launch before it in place of a token the host has
-not read yet (:func:`decode_program`): the engine's loop launches step n + 1
-from step n's picks while n runs.
+not read yet (:func:`decode_program`), and a drafter's ONE-program step a row
+of the step before it in place of a window (:func:`mtp_programs`): the engine's
+loop launches step n + 1 from step n's result on the device while n runs.
 
 The device cache lives here as functional state: every step donates the
 cache buffer (``donate_argnums``) and returns the new value, and the
@@ -163,19 +164,43 @@ def decode_program(step, cfg, pools: int, widest: int):
     return paged_decode_step
 
 
-def mtp_programs(drafter, cfg):
+def mtp_programs(drafter, cfg, widest: int):
     """The three programs of a model's own drafter (``models/interface.py::
     Drafter``), each named for the trace: the ONE program of an all-greedy
-    step, its three small results packed into one int32 array ``[B, window +
-    2]`` (the new tokens, how many drafts were accepted, the next draft: one
-    copy to the host), and the two of a step with the host's sampler between
-    (the second hands back the next drafts, its argmax, beside its logits,
-    which stay on the device unless asked for)."""
+    step, its three small results packed into one int32 array ``[widest,
+    window + 2]`` (the new tokens, how many drafts were accepted, the next
+    draft: one copy to the host), and the two of a step with the host's
+    sampler between (the second hands back the next drafts, its argmax, beside
+    its logits, which stay on the device unless asked for).
+
+    The ONE program takes its window from the step before it, as
+    :func:`decode_program` takes a token from the picks: a row whose FIRST
+    token is ``-1 - j`` names row ``j`` of the packed result of the drafter
+    step before this one (the LAST argument, a device array already) and
+    stands for ``[new_j[accepted_j], draft_j]``, the last token that step
+    committed and the draft it handed back, ``accepted_j`` positions after the
+    context length the host gave (the host gives the least: a step commits at
+    least one token). Merged here and not in the model: the ``Drafter``
+    interface knows nothing of it. The result goes out ``widest`` rows, the
+    largest batch bucket, so that any bucket's program takes any other's."""
     import jax.numpy as jnp
 
-    def paged_mtp_step(*args):
-        cache, (new, accepted, draft), counters = drafter.step(cfg, *args)
-        return cache, jnp.concatenate([new, accepted[:, None], draft[:, None]], axis=1), counters
+    C = drafter.window
+
+    def paged_mtp_step(params, cache, tokens, tables, ctx_lens, true_lens, known, earlier):
+        named = tokens[:, 0] < 0
+        before = earlier[jnp.clip(-1 - tokens[:, 0], 0, widest - 1)]  # [B, window + 2]
+        accepted = before[:, C]
+        last = jnp.take_along_axis(before[:, :C], accepted[:, None], axis=1)[:, 0]
+        rides = named & (true_lens > 1)  # a window the plan left no room to draft in is [x] alone
+        tokens = tokens.at[:, 0].set(jnp.where(named, last, tokens[:, 0]))
+        tokens = tokens.at[:, 1].set(jnp.where(rides, before[:, C + 1], tokens[:, 1]))
+        ctx_lens = ctx_lens + jnp.where(named, accepted, 0)
+        cache, (new, accepted, draft), counters = drafter.step(
+            cfg, params, cache, tokens, tables, ctx_lens, true_lens, known
+        )
+        out = jnp.concatenate([new, accepted[:, None], draft[:, None]], axis=1)
+        return cache, jnp.pad(out, ((0, widest - out.shape[0]), (0, 0))), counters
 
     def paged_mtp_verify(*args):
         cache, logits, hidden, counters = drafter.verify(cfg, *args)
@@ -204,7 +229,9 @@ class Launched:
     #: real rows of a decode batch: ``read`` strips the padding after them
     n: Optional[int] = None
     #: a decode launch's picks (``[largest decode bucket]`` int32, on the
-    #: device): what the NEXT decode launch may take a row's token from
+    #: device), or the packed result of a drafter's ONE-program step (``[largest
+    #: decode bucket, window + 2]``): what the NEXT launch of its kind may take
+    #: a row's token, or window, from
     picks: Any = None
     #: the first half of a drafter's two-program step: the window's last
     #: residuals, on the device, for the second half
@@ -356,8 +383,11 @@ class PagedModelRunner:
             partial(self.model.paged_verify_step, cfg), donate_argnums=donated
         )
         if self.drafter is not None:
+            widest = self.decode_buckets[-1]
+            #: what a drafter's step hands over where no row names an earlier window
+            self._no_windows = jnp.zeros((widest, self.drafter.window + 2), jnp.int32)
             self._mtp_step_jit, self._mtp_verify_jit, self._mtp_draft_jit = (
-                jax.jit(f, donate_argnums=(1,)) for f in mtp_programs(self.drafter, cfg)
+                jax.jit(f, donate_argnums=(1,)) for f in mtp_programs(self.drafter, cfg, widest)
             )
         # COW block duplication (prefix cache): cache is arg 0 here.
         # partial() gives THIS runner its own jit identity — a bare
@@ -494,7 +524,8 @@ class PagedModelRunner:
                     warm = partial(self._warm, bucket=f"{b}x{C}x{w * bs}")
                     window = (np.zeros((b, C), np.int32), self._tables((), b, w),
                               np.zeros(b, np.int32), np.zeros(b, np.int32))
-                    self._step(warm, "paged_mtp_step", self._mtp_step_jit, *window, np.ones(b, np.int32))
+                    self._step(warm, "paged_mtp_step", self._mtp_step_jit, *window, np.ones(b, np.int32),
+                               last=(self._no_windows,))
                     (_, hidden), _ = self._step(warm, "paged_mtp_verify", self._mtp_verify_jit, *window)
                     self._step(warm, "paged_mtp_draft", self._mtp_draft_jit, hidden, *window)
             batches = ()
@@ -860,6 +891,7 @@ class PagedModelRunner:
         ctx_lens: Sequence[int],
         clock: Optional[timeline.PhaseClock] = None,
         greedy: bool = True,
+        after: Optional[Launched] = None,
     ) -> Launched:
         """Put a decode step of a model that drafts for itself on its way:
         each slot's window (``[x_n, d]``: the committed last token and the
@@ -869,29 +901,51 @@ class PagedModelRunner:
         picks, the comparison, the drafter over what was committed, the next
         drafts): :meth:`read` gives ``[n, window + 2]`` int32, a slot's new
         tokens, how many drafts were accepted (its new tokens are the first 1
-        + accepted), its next draft. Otherwise the first of two: :meth:`read`
-        gives the logits ``[n, window, V]`` for the host's sampler, and
+        + accepted), its next draft. ``after``: the ONE-program step before
+        this one, read or not; a window whose first token is ``-1 - j`` then
+        stands for what row ``j`` of ITS result leaves, which never leaves the
+        device: the last token it committed and its draft, ``ctx_lens[i]`` the
+        position after ONE committed token (the program adds what was
+        accepted; the launch span's ``ahead`` says whether one was given).
+        Without ``greedy`` the first of two: :meth:`read` gives the logits
+        ``[n, window, V]`` for the host's sampler, and
         :meth:`launch_mtp_draft` goes on from what it sampled."""
         clock = clock or self.clock
         n, C = len(windows), self.drafter.window
         bucket = _round_up_bucket(n, self.decode_buckets)
+        # a named window may stand as far on as that step accepted drafts
+        ctx_most = [c + (C - 1) * (w[0] < 0) for c, w in zip(ctx_lens, windows)]
         M = self._table_width(
-            [c + len(w) for c, w in zip(ctx_lens, windows)], bucket, C,
-            reach=[c + C for c in ctx_lens],
+            [c + len(w) for c, w in zip(ctx_most, windows)], bucket, C,
+            reach=[c + C for c in ctx_most],
         )
         program = "paged_mtp_step" if greedy else "paged_mtp_verify"
         with clock.phase(
             "launch", program=program, bucket=f"{bucket}x{C}x{M * self.block_size}",
-            path=self._path_name(C),
+            path=self._path_name(C), ahead=int(after is not None),
         ):
             with clock.part("inputs"):
                 inputs = self._mtp_inputs(windows, block_rows, ctx_lens, bucket, M)
                 kn = np.ones(bucket, np.int32)
                 kn[:n] = known
+                if inputs[0].min() < 0 and (after is None or not greedy):
+                    raise ValueError(
+                        "a window names a row of an earlier ONE-program drafter step, and none was given"
+                    )
             with clock.part("call"):
                 if greedy:
-                    out, loads = self._step(self._run, program, self._mtp_step_jit, *inputs, kn)
-                    return Launched("decode", out, loads, n)
+                    out, loads = self._step(
+                        self._run, program, self._mtp_step_jit, *inputs, kn,
+                        last=(self._no_windows if after is None else after.picks,),
+                    )
+                    # what the read copies to the host is a few small integers:
+                    # the copies start when the step ends, not when the host asks
+                    # (a step read late is asked for beside the NEXT running
+                    # program, where each copy takes 2-3 ms and they came in turn)
+                    for leaf in (out, *(loads.values() if isinstance(loads, dict) else (loads,))):
+                        if leaf is not None:
+                            leaf.copy_to_host_async()
+                    return Launched("decode", out, loads, n, out)
                 (logits, hidden), loads = self._step(self._run, program, self._mtp_verify_jit, *inputs)
         return Launched("decode", logits, loads, n, hidden=hidden)
 

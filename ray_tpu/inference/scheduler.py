@@ -23,11 +23,15 @@ Policies, all host-side and unit-testable without jax:
   host<->HBM bandwidth is the scarce resource).
 * **cancellation** — frees blocks immediately, whether the request is
   queued, prefilling, or decoding.
-* **a token in flight** — the engine may plan a step while the decode launch
-  before it is unread (``Request.in_flight``): such a request counts one
-  position further (blocks, write position), is not planned again once its
-  last token is in flight, and a victim or a cancelled request simply loses
-  the unread result (the engine drops it).
+* **tokens in flight** — the engine may plan a step while the decode launch
+  before it is unread (``Request.in_flight``): such a request counts further
+  on by what that launch commits: one token of a plain decode launch, one to
+  ``1 + k`` of a drafter's window with ``k`` drafts riding
+  (``Request.ahead`` to ``Request.ahead_most``). Its blocks and its drafts'
+  room are planned against the most, it is not planned again once its last
+  token is CERTAINLY in flight (the least reaches ``max_new_tokens``), and a
+  victim or a cancelled request simply loses the unread result (the engine
+  drops it).
 """
 
 from __future__ import annotations
@@ -144,12 +148,16 @@ class Request:
     spec_step_k: int = 0
     #: whether the request has been counted as having waited for a state slot
     slot_waited: bool = False
-    #: the row that holds this request's NEWEST token in the one decode
+    #: the row that holds this request's NEWEST token(s) in the one decode
     #: launch the engine has not read yet (None: every token it was launched
     #: for is in ``generated``). The engine sets it when it leaves a launch
-    #: unread and clears it when it reads; the plan counts such a request one
-    #: position further (:attr:`ahead`)
+    #: unread and clears it when it reads; the plan counts such a request
+    #: further on (:attr:`ahead`, :attr:`ahead_most`)
     in_flight: Optional[int] = None
+    #: the most tokens that launch may commit for this request: 1 for a plain
+    #: decode launch, 1 + the drafts that ride its window in a drafter's step
+    #: (each accepted draft is one more); set with ``in_flight``
+    in_flight_most: int = 1
 
     @property
     def effective_prompt(self) -> List[int]:
@@ -164,8 +172,16 @@ class Request:
 
     @property
     def ahead(self) -> int:
-        """Tokens launched for and not read yet: 0 or 1."""
+        """The LEAST tokens launched for and not read yet: 0, or 1 (every
+        launch commits at least one token a row). With :attr:`ahead_most`
+        the range the unread launch leaves the request in."""
         return 0 if self.in_flight is None else 1
+
+    @property
+    def ahead_most(self) -> int:
+        """The MOST tokens launched for and not read yet: 0, 1 after a plain
+        decode launch, ``1 + k`` after a drafter's window with ``k`` drafts."""
+        return 0 if self.in_flight is None else self.in_flight_most
 
     @property
     def prefill_done(self) -> bool:
@@ -218,6 +234,11 @@ class ContinuousBatchingScheduler:
         #: row width)
         self.spec_k_live: Optional[int] = None
         self.spec_max_context: Optional[int] = None
+        #: whether the proposer's next draft is ON THE DEVICE with the tokens
+        #: in flight (the model's own drafter): a request is then drafted for
+        #: after a launch nobody has read. A proposer on the host builds its
+        #: window from tokens it must have read
+        self.spec_drafts_on_device = False
         self._lock = threading.RLock()
         self.admitting = True
         # observability
@@ -430,9 +451,12 @@ class ContinuousBatchingScheduler:
                 # on exhaustion. A victim must never be something already in
                 # the plan: the engine would run it on freed (null) blocks.
                 planned_ids = {id(p[0]) for p in plan.prefills}
-                # a request whose LAST token is in flight (the engine has not
-                # read the launch that carries it) is not planned again: a
-                # length finish is known a step ahead, so no row is wasted
+                # a request whose LAST token is CERTAINLY in flight (the engine
+                # has not read the launch that carries it; the least it commits
+                # reaches the cap) is not planned again: a length finish is
+                # known a step ahead, so no row is wasted. One that only MAY
+                # finish there (an accepted draft would be its last token) is
+                # planned: the engine drops the row if it did
                 decodable = sorted(
                     (
                         r for r in self.running
@@ -446,19 +470,20 @@ class ContinuousBatchingScheduler:
                     # the step writes KV at position context_len-1 (the token
                     # sampled LAST step): coverage of exactly context_len
                     # positions; the token emitted this step grows the table
-                    # next step. A token in flight counts: the step writes ITS
-                    # K/V, one position further
-                    need = req.context_len + req.ahead
+                    # next step. Tokens in flight count, by the MOST the unread
+                    # launch may commit: the step writes after them
+                    need = req.context_len + req.ahead_most
                     # speculative slots want k extra positions (the verify
                     # window writes K/V at context_len-1 .. context_len+k-1).
                     # Opportunistic only: spec growth never preempts, and a
                     # dry pool degrades the slot to plain decode this step.
-                    # Nothing is drafted after a token the host has not seen.
-                    k = 0 if req.ahead else req.spec_k
+                    # Nothing is drafted after a token the host has not seen,
+                    # unless the draft is on the device with it
+                    k = req.spec_k if not req.ahead or self.spec_drafts_on_device else 0
                     if k > 0:
                         if self.spec_k_live is not None:
                             k = min(k, self.spec_k_live)
-                        k = min(k, req.max_new_tokens - len(req.generated) - 1)
+                        k = min(k, req.max_new_tokens - len(req.generated) - req.ahead_most - 1)
                         if self.spec_max_context is not None:
                             k = min(k, self.spec_max_context - need)
                         k = max(0, k)

@@ -459,3 +459,87 @@ def test_a_preempted_request_restarts_without_its_token_in_flight():
     assert b.restart_prompt == b.prompt + [1] and blocks.owned("b") == []
     # the engine clears the mark when it reads (and drops) the launch
     assert b.in_flight == 1 and b not in sched.running
+
+
+# -- the plan over a drafter's window in flight (ISSUE 45) ---------------------------------------------
+# A drafter's step commits 1 to 1 + k tokens a row: the plan counts such a request by that RANGE.
+
+def test_a_window_in_flight_counts_from_its_least_to_its_most():
+    req = Request(request_id="a", prompt=[1, 2, 3], max_new_tokens=8)
+    assert (req.ahead, req.ahead_most) == (0, 0)
+    req.in_flight = 1  # a plain decode launch: exactly one token
+    assert (req.ahead, req.ahead_most) == (1, 1)
+    req.in_flight_most = 2  # a window with one draft riding: one token, or two
+    assert (req.ahead, req.ahead_most) == (1, 2)
+    req.in_flight = None  # read: what the launch might have committed no longer counts
+    assert (req.ahead, req.ahead_most) == (0, 0)
+
+
+@pytest.mark.parametrize("left, most, planned, drafts", [
+    (1, 2, False, 0),  # its last token is CERTAINLY in flight: not planned, no row wasted
+    (1, 1, False, 0),
+    (2, 2, True, 0),   # it MAY finish in flight (an accepted draft): planned without a draft, dropped if it did
+    (2, 1, True, 0),   # one token left after the one in flight: nothing to draft for
+    (3, 2, True, 0),   # the most leaves one: no draft; the least would leave two
+    (3, 1, True, 1),
+    (4, 2, True, 1),   # two left even after the most: a draft rides
+])
+def test_a_request_near_its_cap_is_planned_by_the_range_in_flight(left, most, planned, drafts):
+    _blocks, sched = _planner(num_blocks=32)
+    sched.spec_drafts_on_device, sched.spec_max_context = True, 64
+    last = _decoding(sched, "a", 4, [1] * (9 - left), 9)
+    other = _decoding(sched, "b", 4, [1], 9)
+    third = _decoding(sched, "c", 4, [1], 9)
+    for r in (last, other, third):
+        r.spec_k = 1
+    last.in_flight, last.in_flight_most = 0, most
+    plan = sched.schedule()
+    # its place in the batch of 2 goes to the next request where it is not planned
+    assert plan.decodes == ([last, other] if planned else [other, third])
+    assert last.spec_step_k == drafts and other.spec_step_k == 1
+
+
+def test_blocks_grow_to_the_most_a_window_in_flight_may_commit_and_this_steps_window():
+    blocks, sched = _planner()
+    sched.spec_drafts_on_device, sched.spec_max_context = True, 64
+    req = _decoding(sched, "a", 6, [1], 20)  # context 7: two blocks of 4
+    req.spec_k = 1
+    assert sched.schedule().decodes == [req] and req.spec_step_k == 1
+    assert len(blocks.owned("a")) == 2  # positions 6 and 7: the window [x, d]
+    req.in_flight, req.in_flight_most = 0, 2
+    # the unread step may leave the context at 9, and this step's window writes positions 8 and 9:
+    # 10 positions, a third block. With a plain token in flight (most 1): 9, and the same blocks
+    assert sched.schedule().decodes == [req] and req.spec_step_k == 1 and len(blocks.owned("a")) == 3
+    blocks.trim_to("a", 7)
+    req.in_flight_most = 1
+    assert sched.schedule().decodes == [req] and len(blocks.owned("a")) == 3  # 7 + 1 + 1 = 9 positions
+    blocks.trim_to("a", 7)
+    req.in_flight = None
+    assert sched.schedule().decodes == [req] and len(blocks.owned("a")) == 2
+
+
+def test_a_proposer_on_the_host_drafts_nothing_after_a_token_it_has_not_seen():
+    _blocks, sched = _planner()
+    sched.spec_max_context = 64
+    assert sched.spec_drafts_on_device is False
+    req = _decoding(sched, "a", 6, [1], 20)
+    req.spec_k = 3
+    assert sched.schedule().decodes == [req] and req.spec_step_k == 3
+    req.in_flight = 0
+    assert sched.schedule().decodes == [req] and req.spec_step_k == 0
+    sched.spec_drafts_on_device = True  # the model's own drafter: the draft is where the token is
+    assert sched.schedule().decodes == [req] and req.spec_step_k == 3
+
+
+def test_a_preempted_request_restarts_without_its_window_in_flight():
+    blocks, sched = _planner(num_blocks=5)  # 4 usable blocks of 4
+    sched.spec_drafts_on_device, sched.spec_max_context = True, 64
+    a = _decoding(sched, "a", 7, [1], 20)
+    b = _decoding(sched, "b", 7, [1], 20)
+    for r, row in ((a, 0), (b, 1)):
+        r.spec_k, r.in_flight, r.in_flight_most = 1, row, 2
+    plan = sched.schedule()  # both need a third block, one is free: b is evicted
+    assert plan.decodes == [a] and b.state == QUEUED and sched.total_preempted == 1
+    # what the window might have committed is lost with it: the restart is from what the host has
+    assert b.restart_prompt == b.prompt + [1] and blocks.owned("b") == []
+    assert b.in_flight == 1 and b not in sched.running  # the engine clears the mark when it drops the row

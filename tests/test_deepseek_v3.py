@@ -350,10 +350,10 @@ def test_a_192_wide_value_through_the_flash_kernel_equals_the_materialised_softm
 # -- the drafter on the engine's normal path ------------------------------------------------------
 
 def _engine(cfg, params, k, **kw):
-    ec = EngineConfig(num_blocks=64, block_size=BS, prefill_buckets=(8, 16), decode_buckets=(4,),
-                      max_decode_batch=4, speculative_k=k, speculative_draft="mtp", speculative_adaptive=False,
-                      prefix_cache_enabled=False, **kw)
-    return InferenceEngine(cfg, params, ec)
+    fields = dict(num_blocks=64, block_size=BS, prefill_buckets=(8, 16), decode_buckets=(4,),
+                  max_decode_batch=4, speculative_k=k, speculative_draft="mtp", speculative_adaptive=False,
+                  prefix_cache_enabled=False)
+    return InferenceEngine(cfg, params, EngineConfig(**{**fields, **kw}))
 
 
 @pytest.fixture(scope="module")
@@ -472,3 +472,431 @@ def test_a_model_without_a_drafter_refuses_the_mtp_proposer(tiny):
         InferenceEngine(dataclasses.replace(cfg, n_mtp_layers=0), params, EngineConfig(
             num_blocks=64, block_size=BS, prefill_buckets=(8,), decode_buckets=(4,), max_decode_batch=4,
             speculative_k=1, speculative_draft="mtp", prefix_cache_enabled=False, warmup=False))
+
+
+# -- the drafter's step n + 1 launched before step n is read (ISSUE 45) -----------------------------
+# A saturated engine (2 decode slots, 6 requests) leaves the ONE-program step unread and names its
+# rows in the next one: every stream is what an engine stepped from outside its loop gives (which
+# reads every launch in its own step), and what plain decode gives, whatever is drafted.
+
+import time  # noqa: E402
+from types import SimpleNamespace  # noqa: E402
+
+from ray_tpu.inference.engine import _END, RequestFailedError  # noqa: E402
+from ray_tpu.inference.model_runner import PagedModelRunner, mtp_programs  # noqa: E402
+from ray_tpu.inference.scheduler import DECODE, QUEUED  # noqa: E402
+
+AHEAD = dict(num_blocks=64, decode_buckets=(2,), max_decode_batch=2, max_queue_depth=16, warmup=False)
+#: what is drafted: the module's own (seeded weights: about none accepted), the plain stream's next
+#: token (all accepted: two tokens a slot-step), that + 1 (none), and the two by the position's parity
+KINDS = ["seeded", "oracle", "wrong", "mixed"]
+NEW = [9, 12, 10, 13, 11, 12]
+
+
+@pytest.fixture(scope="module")
+def six(tiny):
+    """Six prompts of lengths apart and what plain decode (no drafter) makes of each: 13 tokens."""
+    rng = np.random.default_rng(3)
+    prompts = [[int(t) for t in rng.integers(1, 255, size=n)] for n in (5, 19, 30, 12, 23, 9)]
+    engine = _engine(*tiny, 0, **AHEAD).start()
+    try:
+        streams = [list(engine.generate(p, max_new_tokens=13)) for p in prompts]
+    finally:
+        engine.stop()
+    # the oracle's table: (position of the last committed token, that token) -> the next token
+    table = np.zeros((tiny[0].max_seq_len + 2, tiny[0].vocab_size), np.int32)
+    seen = {}
+    for prompt, stream in zip(prompts, streams):
+        for i in range(len(stream) - 1):
+            key = (len(prompt) + i, stream[i])
+            assert seen.setdefault(key, stream[i + 1]) == stream[i + 1], "two streams share a key: other prompts"
+            table[key] = stream[i + 1]
+    return prompts, streams, table
+
+
+def _drafting(engine, kind, table):
+    """Make the engine's ONE-program step draft ``kind``: the drafter's own step, its draft replaced
+    on the device by a lookup of the last committed token at its position."""
+    if kind == "seeded":
+        return engine
+    cfg, V = engine.cfg, engine.cfg.vocab_size
+    table = jnp.asarray(table)
+
+    def step(cfg_, params, cache, tokens, tables, ctx_lens, true_lens, known):
+        cache, (new, accepted, _), aux = dsv3.paged_mtp_step(cfg_, params, cache, tokens, tables, ctx_lens, true_lens, known)
+        both = known >= 2
+        last = jnp.take_along_axis(new, jnp.where(both, 0, accepted)[:, None], axis=1)[:, 0]
+        pos = ctx_lens + jnp.where(both, 2, 1 + accepted)
+        truth = table[jnp.clip(pos, 0, table.shape[0] - 1), last]
+        wrong = (truth + 1) % V
+        draft = {"oracle": truth, "wrong": wrong, "mixed": jnp.where(pos % 2 == 0, truth, wrong)}[kind]
+        return cache, (new, accepted, draft), aux
+
+    runner = engine.runner
+    one = mtp_programs(dataclasses.replace(runner.drafter, step=step), cfg, runner.decode_buckets[-1])[0]
+    runner._mtp_step_jit = jax.jit(one, donate_argnums=(1,))
+    return engine
+
+
+def _drain(eng, rid, timeout=120.0):
+    q, items = eng._out[rid], []
+    while not items or not (items[-1] is _END or isinstance(items[-1], Exception)):
+        items.append(q.get(timeout=timeout))
+    return items
+
+
+def _read_in_step(tiny, kind, table, submit, **kw):
+    """The streams of an engine stepped from outside the loop: every launch read in its own step."""
+    eng = _drafting(_engine(*tiny, 1, **{**AHEAD, **kw}), kind, table)
+    rids = submit(eng)
+    while eng.scheduler.has_work():
+        assert eng.step() and eng._unread is None
+    spec = eng.stats()["speculative"]
+    assert spec["launches_ahead"] == 0 and spec["rows_dropped"] == 0
+    return [_drain(eng, r, timeout=1) for r in rids], spec
+
+
+def _looped(tiny, kind, table, submit, hook=None, **kw):
+    eng = _drafting(_engine(*tiny, 1, **{**AHEAD, **kw}), kind, table)
+    rids = submit(eng)  # before start(): the loop plans what the direct steps planned
+    if hook is not None:
+        hook(eng, rids)
+    eng.start()
+    try:
+        got = [_drain(eng, r) for r in rids]
+        assert eng.wait_idle() and eng._unread is None
+    finally:
+        eng.stop()
+    st = eng.stats()
+    assert st["blocks"]["used_blocks"] == 0 and not eng.spec._next  # every block and draft went back
+    assert st["decode_ahead"] == {"launches": 0, "ahead": 0, "dropped": 0}  # _launch_decode's alone
+    return eng, rids, got, st["speculative"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_looking_ahead_streams_what_reading_every_step_streams_whatever_is_drafted(tiny, six, kind):
+    prompts, streams, table = six
+    submit = lambda eng: [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, NEW)]  # noqa: E731
+    want, sync = _read_in_step(tiny, kind, table, submit)
+    assert [items[:-1] for items in want] == [s[:n] for s, n in zip(streams, NEW)]  # plain decode's
+    _, _, got, spec = _looped(tiny, kind, table, submit)
+    assert got == want
+    # most steps were launched while the one before was unread, all in the ONE program
+    assert spec["launches_split"] == 0 and spec["launches_fused"] == spec["step_launches"]
+    assert spec["launches_ahead"] >= spec["step_launches"] // 2 > 0
+    # the books count the rows that were read AND committed: a dropped row is in neither
+    committed = sum(NEW) - len(NEW)  # the first token of each is the prefill's
+    assert spec["committed_tokens"] == committed == sync["committed_tokens"]
+    assert spec["committed_tokens"] == spec["slot_steps"] + spec["accepted_tokens"]
+    if kind == "oracle":
+        assert spec["accepted_tokens"] == spec["proposed_tokens"] > 0 and spec["rollbacks"] == 0
+        assert spec["committed_tokens"] > 1.5 * spec["slot_steps"]
+        # a draft accepted as a request's LAST token finished it with its next window in flight
+        assert spec["rows_dropped"] >= 1
+    elif kind == "mixed":
+        assert 0 < spec["accepted_tokens"] < spec["proposed_tokens"]
+    elif kind == "wrong":
+        # no draft accepted: a cap is then met a step ahead, and no row is wasted
+        assert spec["accepted_tokens"] == 0 and spec["rows_dropped"] == 0
+        assert spec["rollbacks"] == spec["proposed_tokens"] > 0
+
+
+def _find_eos(streams, upto):
+    """(which stream, its tokens, the index of one that did not occur in it before), after the second."""
+    return next(
+        (n, s, i) for n, s in enumerate(streams) for i in range(2, upto) if s[i] not in s[:i]
+    )
+
+
+@pytest.mark.parametrize("kind", ["wrong", "oracle"])
+def test_an_eos_met_a_step_late_drops_its_next_window_and_streams_no_stray_token(tiny, six, kind):
+    prompts, streams, table = six
+    which, tokens, at = _find_eos(streams, 11)
+    eos = tokens[at]
+
+    def submit(eng):
+        return [eng.submit(p, max_new_tokens=12, eos_token=eos if i == which else None)
+                for i, p in enumerate(prompts)]
+
+    want, _ = _read_in_step(tiny, kind, table, submit)
+    assert want[which] == tokens[: at + 1] + [_END]
+    assert all(want[i] == streams[i][:12] + [_END] for i in range(6) if i != which)
+    _, _, got, spec = _looped(tiny, kind, table, submit)
+    assert got == want
+    # the host sees the EOS only when it reads the step, and the next window was launched by then
+    # (with drafts that are never accepted nothing else is dropped: a cap is met a step ahead)
+    assert spec["rows_dropped"] == 1 if kind == "wrong" else spec["rows_dropped"] >= 1
+    assert spec["committed_tokens"] <= spec["slot_steps"] + spec["accepted_tokens"]
+
+
+def _loop_by_hand(eng, each=lambda: None):
+    """The loop's own steps (``hold_wakes``: a launch may stay unread) from this thread, ``each``
+    called after every one."""
+    while eng.scheduler.has_work() or eng._unread is not None:
+        eng.step(hold_wakes=True)
+        each()
+    eng.step()  # nothing to launch: whatever is held goes out
+
+
+def test_a_cap_reached_inside_an_accepted_window_drops_the_window_after_it(tiny, six):
+    """With every draft accepted a request two tokens short of its cap MAY finish in the unread
+    step: it is planned (without a draft), and where it did finish its row is dropped."""
+    prompts, streams, table = six
+    eng = _drafting(_engine(*tiny, 1, **AHEAD), "oracle", table)
+    rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, NEW)]
+    late, lengths = [], {}
+
+    def each():
+        for req in (eng._unread.reqs if eng._unread is not None else ()):
+            if req.finished:  # read (and ended) in this step with its next window launched before
+                late.append((req.request_id, len(req.generated) - lengths.get(req.request_id, 0)))
+        lengths.update((r.request_id, len(r.generated)) for r in eng.scheduler.running)
+
+    _loop_by_hand(eng, each)
+    assert [_drain(eng, r, timeout=1) for r in rids] == [s[:n] + [_END] for s, n in zip(streams, NEW)]
+    spec = eng.stats()["speculative"]
+    # each ended on the SECOND token of an accepted window, and each cost exactly its one row
+    assert late and all(grew == 2 for _, grew in late) and len({rid for rid, _ in late}) == len(late)
+    assert spec["rows_dropped"] == len(late)
+    assert spec["accepted_tokens"] == spec["proposed_tokens"] and spec["launches_ahead"] > 0
+    assert spec["committed_tokens"] == spec["slot_steps"] + spec["accepted_tokens"] == sum(NEW) - 6
+    assert eng.blocks.used_blocks == 0 and eng._unread is None and not eng._held
+
+
+def _at_launch_ahead(eng, n, act, seen=None):
+    """Run ``act`` on the step thread inside the ``n``-th drafter step launched while another is
+    unread (a window of every named row is in flight then); ``seen`` collects each such launch's
+    windows and ``known``."""
+    launch, count = eng.runner.launch_mtp_step, []
+
+    def hooked(windows, known, *a, after=None, **kw):
+        if after is not None:
+            count.append(1)
+            if seen is not None:
+                seen.append(([list(w) for w in windows], list(known)))
+            if len(count) == n:
+                act()
+        return launch(windows, known, *a, after=after, **kw)
+
+    eng.runner.launch_mtp_step = hooked
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_a_request_ended_with_a_window_in_flight_streams_a_prefix_and_its_terminal(tiny, six, how):
+    prompts, streams, table = six
+    submit = lambda eng: [eng.submit(p, max_new_tokens=12) for p in prompts]  # noqa: E731
+    ended = []
+
+    def hook(eng, rids):
+        def end():
+            req = eng._unread.reqs[0]
+            assert req.in_flight == 0 and req.state == DECODE
+            if how == "cancel":
+                assert eng.cancel(req.request_id)
+            else:
+                req.deadline = SimpleNamespace(expired=True)  # reaped by the next plan
+            ended.append(req.request_id)
+
+        _at_launch_ahead(eng, 2, end)
+
+    _, rids, got, spec = _looped(tiny, "wrong", table, submit, hook)
+    for rid, have, full in zip(rids, got, streams):
+        if rid in ended:
+            assert 1 <= len(have) - 1 < 12 and have[:-1] == full[: len(have) - 1]
+            assert have[-1] is _END if how == "cancel" else isinstance(have[-1], RequestFailedError)
+        else:
+            assert have == full[:12] + [_END]
+    # a cancel ends it at once: the unread step and the one being made both carried a row of it.
+    # A deadline is met by the next plan: the second alone
+    assert len(ended) == 1 and spec["rows_dropped"] == (2 if how == "cancel" else 1)
+
+
+@pytest.mark.parametrize("kind", ["wrong", "oracle"])
+def test_a_preempted_request_loses_its_window_in_flight_and_decodes_it_again(tiny, kind):
+    rs = np.random.RandomState(4)
+    prompts = [[int(t) for t in rs.randint(1, 200, size=n)] for n in (33, 27)]
+    plain = _engine(*tiny, 0, **AHEAD).start()
+    try:
+        streams = [list(plain.generate(p, max_new_tokens=28)) for p in prompts]
+    finally:
+        plain.stop()
+    table = np.zeros((tiny[0].max_seq_len + 2, tiny[0].vocab_size), np.int32)
+    for p, s in zip(prompts, streams):
+        for i in range(len(s) - 1):
+            table[len(p) + i, s[i]] = s[i + 1]
+    submit = lambda eng: [eng.submit(p, max_new_tokens=28) for p in prompts]  # noqa: E731
+    # the two sequences grow to 61 + 55 tokens (8 + 7 blocks of 8, 11 usable): the pool runs dry
+    tight = dict(num_blocks=12)
+    want, _ = _read_in_step(tiny, kind, table, submit, **tight)
+    assert [items[:-1] for items in want] == streams
+    eng, _, got, spec = _looped(tiny, kind, table, submit, **tight)
+    assert got == want
+    assert eng.stats()["scheduler"]["total_preempted"] >= 1
+    assert spec["launches_ahead"] > 0 and spec["rows_dropped"] >= 1
+
+
+def test_a_slot_fresh_from_prefill_rides_beside_named_rows(tiny, six):
+    prompts, streams, table = six
+    seen = []
+    submit = lambda eng: [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, NEW)]  # noqa: E731
+    _, _, got, spec = _looped(tiny, "mixed", table, submit, lambda eng, rids: _at_launch_ahead(eng, 0, None, seen))
+    assert got == [s[:n] + [_END] for s, n in zip(streams, NEW)]
+    # one launch held a window the unread step leaves on the device (named) AND one whose two
+    # tokens the host knows (a slot whose prompt just ended: no draft yet)
+    mixed = [(w, k) for w, k in seen if any(x[0] < 0 for x in w) and any(x[0] >= 0 for x in w)]
+    assert mixed and any(2 in k for _, k in mixed)
+    assert all(k[i] == 1 for w, k in seen for i, x in enumerate(w) if x[0] < 0)
+
+
+def test_a_batch_that_turns_sampled_is_read_at_once_and_takes_the_two_programs(tiny, six):
+    prompts, _, table = six
+
+    def submit(eng, then=lambda: None):
+        rids = [eng.submit(p, max_new_tokens=24) for p in prompts[:3]]
+        then()
+        return rids + [eng.submit(prompts[3], max_new_tokens=10, temperature=0.9, seed=7)]
+
+    want, _ = _read_in_step(tiny, "mixed", table, submit)
+    eng = _drafting(_engine(*tiny, 1, **AHEAD), "mixed", table)
+    decided, stays = [], eng._stays_unread
+
+    def logged(plan, batch):
+        sampled = any(r.temperature > 0.0 for r in eng.scheduler.running)
+        decided.append((batch.greedy, sampled, stays(plan, batch), eng._unread is None))
+        return decided[-1][2]
+
+    eng._stays_unread = logged
+
+    def started():
+        eng.start()
+        deadline = time.monotonic() + 60
+        while eng.stats()["speculative"]["launches_ahead"] < 2:
+            assert time.monotonic() < deadline
+
+    try:
+        rids = submit(eng, started)
+        got = [_drain(eng, r) for r in rids]
+        assert eng.wait_idle() and eng._unread is None
+    finally:
+        eng.stop()
+    assert got == want
+    spec = eng.stats()["speculative"]
+    assert spec["launches_split"] > 0 and spec["launches_fused"] > 0
+    # the loop looked ahead before the sampled request ran; from the plan that admitted it on (its
+    # prompt still in prefill) every launch was read in its own step, so that the two-program
+    # form, whose windows the host must know, found nothing unread
+    assert any(greedy and stayed for greedy, _, stayed, _ in decided)
+    assert any(not greedy for greedy, _, _, _ in decided)
+    assert all(not stayed for _, sampled, stayed, _ in decided if sampled)
+    assert all(none_unread for greedy, _, _, none_unread in decided if not greedy)
+
+
+def test_no_block_of_a_window_in_flight_is_trimmed(tiny, six):
+    prompts, streams, table = six
+    eng = _drafting(_engine(*tiny, 1, **AHEAD), "mixed", table)
+    rids = [eng.submit(p, max_new_tokens=13) for p in prompts]
+    read, crossed = eng._read_decode, []
+
+    def checked(batch, later=None):
+        read(batch, later)
+        for row, req in enumerate(later.reqs if later is not None else ()):
+            if req.state == DECODE and req in batch.reqs:
+                # its next window stands at its newest token and writes as far as its draft
+                reach = req.context_len + later.windows.riding(row)
+                held = len(eng.blocks.owned(req.request_id))
+                assert held >= eng.blocks.blocks_for_tokens(reach), (req.request_id, held, reach)
+                crossed.append(eng.blocks.blocks_for_tokens(reach) > eng.blocks.blocks_for_tokens(req.context_len))
+
+    eng._read_decode = checked
+    _loop_by_hand(eng)
+    assert [_drain(eng, r, timeout=1) for r in rids] == [s + [_END] for s in streams]
+    # the check had teeth: some window in flight wrote into a block past the committed context's
+    assert any(crossed) and eng.stats()["speculative"]["launches_ahead"] > 0
+    assert eng.blocks.used_blocks == 0
+
+
+@pytest.mark.parametrize("leave", ["stop", "wait_idle", "outside_step", "fail_all"])
+def test_no_drafter_step_stays_unread(tiny, six, leave):
+    prompts, _, table = six
+    eng = _drafting(_engine(*tiny, 1, **AHEAD), "mixed", table)
+    rids = [eng.submit(p, max_new_tokens=6 if leave == "wait_idle" else 30) for p in prompts]
+    if leave == "outside_step":
+        while eng._unread is None:
+            assert eng.step(hold_wakes=True)
+        assert all(r.in_flight is not None and 1 <= r.ahead <= r.ahead_most <= 2 for r in eng._unread.reqs)
+        assert eng.step() and eng._unread is None  # a step from outside reads both
+        assert all(r.in_flight is None and r.ahead_most == 0 for r in eng.scheduler.running)
+        assert eng.stats()["speculative"]["launches_ahead"] == 1
+        eng.stop()
+        return
+    eng.start()
+    try:
+        deadline = time.monotonic() + 60
+        while eng.stats()["speculative"]["launches_ahead"] < 3:
+            assert time.monotonic() < deadline
+        if leave == "wait_idle":
+            assert eng.wait_idle(60)
+            assert all(_drain(eng, r)[-1] is _END for r in rids)
+        elif leave == "fail_all":
+            eng._fail_all(RequestFailedError("failed"))
+            assert all(isinstance(_drain(eng, r)[-1], RequestFailedError) for r in rids)
+            assert eng.wait_idle(60)
+        else:
+            eng.stop()
+        assert eng._unread is None
+    finally:
+        eng.stop()
+    assert eng._unread is None and not eng._held
+    assert eng.blocks.used_blocks == 0 and not eng.spec._next
+
+
+def test_a_window_named_in_the_step_before_runs_as_the_window_itself(tiny):
+    """The ONE program's merge against the same windows given as plain integers, bit for bit: an
+    accepted row (its window stands one position further than the host said), a rejected one and
+    one without a draft, named out of order by a program of another batch bucket."""
+    cfg, params = tiny
+    rng = np.random.default_rng(5)
+    prompts = [[int(t) for t in rng.integers(1, 255, size=10)] for _ in range(3)]
+
+    def fresh():
+        runner = PagedModelRunner(cfg, params, num_blocks=32, block_size=BS, prefill_buckets=(16,),
+                                  decode_buckets=(2, 4), verify_buckets=(2,), drafter=True)
+        runner.warmup()
+        rows = [[1 + 2 * i, 2 + 2 * i] + [0] * (runner.max_blocks_per_seq - 2) for i in range(3)]
+        firsts = [int(np.argmax(runner.prefill_chunk(p, r, 0))) for p, r in zip(prompts, rows)]
+        # every slot first takes the known-2 step (the module's row at the prompt's end waits for it)
+        a = runner.read(runner.launch_mtp_step([[p[-1], t] for p, t in zip(prompts, firsts)], [2] * 3, rows, [9] * 3))
+        return runner, rows, a
+
+    runner, rows, a = fresh()
+    truth = runner.read(runner.launch_mtp_step([[int(r[0]), int(r[-1])] for r in a], [1] * 3, rows, [11] * 3))[:, 0]
+    out = []
+    for named in (False, True):
+        runner, rows, a = fresh()
+        x = [int(r[0]) for r in a]
+        windows = [[x[0], int(truth[0])], [x[1], (int(truth[1]) + 1) % 256], [x[2]]]
+        b = runner.launch_mtp_step(windows, [1] * 3, rows, [11] * 3)
+        if named:
+            c = runner.launch_mtp_step([[-1 - 2, 0], [-1 - 0, 0], [-1 - 1]], [1] * 3, [rows[2], rows[0], rows[1]],
+                                       [12] * 3, after=b)
+            got_b = runner.read(b)
+        else:
+            got_b = runner.read(b)
+            nxt = lambda r: [int(r[r[-2]]), int(r[-1])]  # noqa: E731  the last committed token, the draft
+            c = runner.launch_mtp_step(
+                [nxt(got_b[2]), nxt(got_b[0]), nxt(got_b[1])[:1]], [1] * 3, [rows[2], rows[0], rows[1]],
+                [12 + int(got_b[2][-2]), 12 + int(got_b[0][-2]), 12 + int(got_b[1][-2])])
+        assert [int(r[-2]) for r in got_b] == [1, 0, 0]
+        got_c = runner.read(c)
+        assert got_c.shape == (3, 4) and got_c.dtype == np.int32
+        out.append((got_b, got_c, {k: np.asarray(v) for k, v in runner.cache.items()}))
+        # one program a batch bucket, whatever bucket made the result it is handed
+        assert runner.recompiles_after_warmup() == 0
+    for have, want in zip(out[1][:2], out[0][:2]):
+        np.testing.assert_array_equal(have, want)
+    for name, arr in out[0][2].items():
+        np.testing.assert_array_equal(out[1][2][name], arr)
+    with pytest.raises(ValueError, match="names a row"):
+        runner.launch_mtp_step([[-1, 0]], [1], [rows[0]], [12])
+    with pytest.raises(ValueError, match="names a row"):
+        runner.launch_mtp_step([[-1, 0]], [1], [rows[0]], [12], greedy=False, after=c)
