@@ -26,7 +26,9 @@ KDA (``H`` heads of ``dk = dv``)::
     out = W_o [ RMSNorm_head(o_t) * sigmoid(W_g_up W_g_down x_t) ]
 
 served two ways that are the same mathematics: a decode step applies the
-recurrence once (:func:`kda_update`); a prefill chunk runs the chunked (WY)
+recurrence once (:func:`kda_update`; over a decode batch on a TPU the same
+four lines as ONE pass over the layer's slab of the pool, ``ops/kda.py``,
+chosen from the pool's shape and the backend); a prefill chunk runs the chunked (WY)
 form in sub-chunks of ``kda_chunk`` (:func:`kda_chunked`): with ``G_t`` the
 running sum of ``g`` inside a sub-chunk and ``S`` the state before it,
 
@@ -51,6 +53,7 @@ the donated argument, which a pool carried through a scan was not.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -60,7 +63,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent
 from ray_tpu.models.interface import AttentionPath, Model, StateLayout
-from ray_tpu.ops import latent_flash
+from ray_tpu.ops import kda, latent_flash
 from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
 from ray_tpu.parallel.sharding import constrain
 
@@ -463,30 +466,49 @@ def kda_chunked(S, q, k, v, g, beta, chunk: int):
     return S, jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, T, H, -1)
 
 
-def _kda_mix(cfg: KimiLinearConfig, p, h, S, tail, valid):
-    """The KDA mixer of one layer on normed activations ``h [B, C, D]`` from
-    a state ``S [B, H, dk, dv]`` and a convolution tail ``[B, K - 1, 3 W]``:
-    ``(out [B, C, D], S, tail)`` after the window's real rows (the first
-    ``valid.sum(1)`` of each slot). One position a slot: the recurrence
-    once; more: the chunked form."""
-    q, k, v, g, beta, window = _kda_inputs(cfg, p, h, tail, valid)
-    C = h.shape[1]
+def _kda_recur(cfg: KimiLinearConfig, S, q, k, v, g, beta):
+    """The recurrence over a window from a state ``S [B, H, dk, dv]``: ``(S,
+    o [B, C, H, dv])`` after it. One position a slot: the recurrence once;
+    more: the chunked form."""
+    C = q.shape[1]
     if C == 1:
         with jax.named_scope("kda.update"):
             S, o = kda_update(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
-            o = o[:, None]
-    else:
-        with jax.named_scope("kda.chunk"):
-            chunk = min(cfg.kda_chunk, C)
-            pad = -C % chunk  # positions past the window: beta = 0, g = 0, nothing moves
-            if pad:
-                q, k, v, g, beta = (
-                    jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta)
-                )
-            S, o = kda_chunked(S, q, k, v, g, beta, chunk)
-            o = o[:, :C]
+            return S, o[:, None]
+    with jax.named_scope("kda.chunk"):
+        chunk = min(cfg.kda_chunk, C)
+        pad = -C % chunk  # positions past the window: beta = 0, g = 0, nothing moves
+        if pad:
+            q, k, v, g, beta = (
+                jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta)
+            )
+        S, o = kda_chunked(S, q, k, v, g, beta, chunk)
+        return S, o[:, :C]
+
+
+def _kda_in_pool(layer: int, fresh, pool, q, k, v, g, beta):
+    """The recurrence once for EVERY slot of the pool ``[n_kda, slots, H, dk,
+    dv]`` (one position a slot, in slot order), in place in the layer's slab
+    through the kernel of ``ops/kda.py``: ``(pool, o [slots, 1, H, dv])``. A
+    slot that is ``fresh`` starts from zeros whatever lies there."""
+    with jax.named_scope("kda.kernel"):
+        pool, o = kda.update(pool, layer, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], fresh)
+        return pool, o[:, None]
+
+
+def _kda_mix(cfg: KimiLinearConfig, p, h, S, tail, valid, recur=None):
+    """The KDA mixer of one layer on normed activations ``h [B, C, D]`` from
+    a state ``S [B, H, dk, dv]`` and a convolution tail ``[B, K - 1, 3 W]``:
+    ``(out [B, C, D], S, tail)`` after the window's real rows (the first
+    ``valid.sum(1)`` of each slot). ``recur(S, q, k, v, g, beta) -> (S, o
+    [B, C, H, dv])`` is the recurrence over the window: :func:`_kda_recur`
+    unless given (a decode batch hands the pool as ``S`` and
+    :func:`_kda_in_pool`); the convolution, the gates and the output are the
+    same around either."""
+    q, k, v, g, beta, window = _kda_inputs(cfg, p, h, tail, valid)
+    S, o = (recur or functools.partial(_kda_recur, cfg))(S, q, k, v, g, beta)
     keep = cfg.conv_kernel - 1
-    if C == 1:  # a slot moves on by its one row or stands still: a select, not a gather a slot
+    if h.shape[1] == 1:  # a slot moves on by its one row or stands still: a select, not a gather a slot
         tail = jnp.where(valid[:, :, None], window[:, 1:], window[:, :keep])
     else:
         n = valid.sum(axis=1, dtype=jnp.int32)
@@ -660,6 +682,7 @@ def _paged_layers(cfg: KimiLinearConfig, params, cache, state, tokens, pos, vali
     if by_slot:
         row_of, held = _rows_of_slots(slots, real, n_slots)
         fresh_of = fresh[row_of] & held
+        in_kernel = kda.kernel_serves(state["kda_state"])
     x = params["embed"][tokens]
     blocks, aux = [], []
     i_kda = i_mla = 0
@@ -670,30 +693,31 @@ def _paged_layers(cfg: KimiLinearConfig, params, cache, state, tokens, pos, vali
             # read once and written once where it lies, the rows' activations
             # carried to their slots and the mixer's output back (a slot nobody
             # holds has no valid row: beta = 0, g = 0, nothing of it moves)
-            S = jnp.where(fresh_of[:, None, None, None], 0.0, state["kda_state"][i_kda]).astype(F32)
+            # ``S``: the slab with zeros where a sequence starts; where the kernel
+            # serves, the POOL itself, which comes back with the slab updated
+            pool = state["kda_state"]
+            S = pool if in_kernel else jnp.where(fresh_of[:, None, None, None], 0.0, pool[i_kda]).astype(F32)
             tail = jnp.where(fresh_of[:, None], 0, state["kda_conv"][i_kda])
-            mix, S, tail = _kda_mix(cfg, p, h[row_of], S, tail.reshape(n_slots, keep, -1), held[:, None])
-            if i_kda == 0 and jax.default_backend() == "tpu":
-                # The FIRST in-place write into the donated pool must be a
-                # plain copy. Fused with its own read (``S`` of this layer is
-                # a function of the pool's slab 0), it is an instruction whose
-                # only large operand is an entry parameter, and XLA:TPU's
-                # rematerialisation clones such an instruction a user (the
-                # next layer reads the pool three times) WITHOUT knowing that
-                # it runs in place on the donated buffer: each clone then
-                # decays and updates slab 0 again. How many clones survive is
-                # the scheduler's (one in PR 35's programs, by luck; three
-                # beside ``latent_rows``: the check's state reading 1.02 and
-                # the logits from the second decode step on 0.4-1.0, PR 36).
-                # Behind the barrier the write is ``pool[0] = S``: a clone of
-                # that is the same bytes again. One slab (136 MB) more of
-                # temporaries and ~1 ms a step; the later layers' writes take
-                # the previous write's result, which no clone can recompute
-                S, tail = jax.lax.optimization_barrier((S, tail))
-            state = {
-                "kda_state": state["kda_state"].at[i_kda].set(S.astype(state["kda_state"].dtype)),
-                "kda_conv": state["kda_conv"].at[i_kda].set(tail.reshape(n_slots, -1)),
-            }
+            mix, S, tail = _kda_mix(
+                cfg, p, h[row_of], S, tail.reshape(n_slots, keep, -1), held[:, None],
+                functools.partial(_kda_in_pool, i_kda, fresh_of) if in_kernel else None,
+            )
+            if not in_kernel:
+                if i_kda == 0 and jax.default_backend() == "tpu":
+                    # The FIRST in-place write into the donated pool must be a
+                    # plain copy. Fused with its own read (``S`` of this layer is
+                    # a function of the pool's slab 0), it is an instruction whose
+                    # only large operand is an entry parameter, and XLA:TPU's
+                    # rematerialisation clones such an instruction a user (the
+                    # next layer reads the pool three times) WITHOUT knowing that
+                    # it runs in place on the donated buffer: each clone then
+                    # decays and updates slab 0 again (PR 36: the check's state
+                    # reading 1.02). Behind the barrier the write is ``pool[0] =
+                    # S``: a clone of that is the same bytes again. (The kernel
+                    # aliases the pool in and out: there is no fusion to clone.)
+                    S, tail = jax.lax.optimization_barrier((S, tail))
+                S = pool.at[i_kda].set(S.astype(pool.dtype))
+            state = {"kda_state": S, "kda_conv": state["kda_conv"].at[i_kda].set(tail.reshape(n_slots, -1))}
             mix = mix[slots]
             i_kda += 1
         elif kind == "kda":
@@ -772,17 +796,20 @@ def paged_verify_step(cfg: KimiLinearConfig, *args, **kwargs):
 
 def _attention_path(cfg: KimiLinearConfig, window: int, cache, backend=None) -> AttentionPath:
     """The mixers' paths of a program of that window, named together: the
-    KDA layers' (``kda.update`` for one position a slot, ``kda.chunk``) and
-    the attending layers' (as ``models/xing4.py``); what a launch reads of
-    the paged cache is the latter's."""
-    kda = "kda.update" if window == 1 else "kda.chunk"
+    KDA layers' (one position a slot: ``kda.kernel`` where ``ops/kda.py``
+    serves the pool, else ``kda.update``; ``kda.chunk``) and the attending
+    layers' (as ``models/xing4.py``); what a launch reads of the paged cache
+    is the latter's."""
+    (_, head_state, dtype), _ = state_layout(cfg).arrays
+    pool = jax.ShapeDtypeStruct((cfg.n_kda_layers, 1, *head_state), dtype)  # any number of slots
+    kda_path = "kda.chunk" if window > 1 else "kda.kernel" if kda.kernel_serves(pool, backend) else "kda.update"
     if latent.paged_serves(cfg, window, cache, backend=backend):
-        return AttentionPath(f"{kda}+latent.paged", "blocks")
+        return AttentionPath(f"{kda_path}+latent.paged", "blocks")
     if latent.absorbs(cfg, window):
-        return AttentionPath(f"{kda}+latent.absorbed", "slots")
+        return AttentionPath(f"{kda_path}+latent.absorbed", "slots")
     if latent.flash_serves(cfg, window, cache, backend=backend):
-        return AttentionPath(f"{kda}+latent.flash", "live")
-    return AttentionPath(f"{kda}+latent.expanded", "table")
+        return AttentionPath(f"{kda_path}+latent.flash", "live")
+    return AttentionPath(f"{kda_path}+latent.expanded", "table")
 
 
 MODEL = Model(

@@ -181,11 +181,115 @@ def test_the_attention_path_reads_blocks_where_the_kernel_serves():
     cache = jax.eval_shape(lambda: kl.cache_layout(cfg, 16).init(8))
     assert cache["latent"].shape == (7, 8, 8, 1152)
     path = kl.MODEL.attention_path
-    assert path(cfg, 1, cache, backend="tpu") == ("kda.update+latent.paged", "blocks")
+    assert path(cfg, 1, cache, backend="tpu") == ("kda.kernel+latent.paged", "blocks")
     assert path(cfg, 1, cache, backend="cpu") == ("kda.update+latent.absorbed", "slots")
     rows = {"latent": jax.ShapeDtypeStruct((7, 8, 9216), jnp.bfloat16)}
-    assert path(cfg, 1, rows, backend="tpu") == ("kda.update+latent.absorbed", "slots")
+    assert path(cfg, 1, rows, backend="tpu") == ("kda.kernel+latent.absorbed", "slots")
     assert path(cfg, 1024, cache, backend="cpu") == ("kda.chunk+latent.expanded", "table")
+
+
+# -- the decode update of a layer's slab through the kernel (ops/kda.py) ---------------------------
+
+def _wide():
+    """One configuration with the published head width (128: whole lanes), 2
+    KDA layers and an attending one."""
+    return kl.KimiLinearConfig.tiny(
+        n_layers=3, mla_layers=(3,), kda_heads=2, kda_head_dim=128, kda_chunk=8
+    )
+
+
+def _primitives(jaxpr, name):
+    """How many equations of that primitive a jaxpr holds, its sub-jaxprs' too."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _primitives(sub, name)
+    return n
+
+
+def _decode_jaxpr(cfg, slots=4):
+    params = jax.eval_shape(lambda: kl.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: kl.cache_layout(cfg, 16).init(8))
+    state = jax.eval_shape(lambda: kl.state_layout(cfg).init(slots + 1))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    return jax.make_jaxpr(lambda *a: kl.paged_decode_step(cfg, *a))(
+        params, cache, state, i32(slots), i32(slots), i32(slots, 4), i32(slots), i32(slots)
+    )
+
+
+def test_the_kda_kernel_serves_the_published_head_width_and_the_toy_keeps_its_program(cfg, monkeypatch):
+    """``kda.kernel_serves`` reads the pool's shape and the backend, nothing
+    else: yes at 128-wide heads on a TPU; no off the chip and no at the toy's
+    16, whose decode program is the one it was (one ``kda.update`` a layer,
+    no kernel) whatever the predicate would say of another pool."""
+    published = jax.eval_shape(lambda: kl.state_layout(kl.KimiLinearConfig()).init(65))["kda_state"]
+    assert published.shape == (20, 65, 32, 128, 128)
+    assert kl.kda.kernel_serves(published, backend="tpu") and not kl.kda.kernel_serves(published, backend="cpu")
+    toy = jax.eval_shape(lambda: kl.state_layout(cfg).init(5))["kda_state"]
+    assert not kl.kda.kernel_serves(toy, backend="tpu")
+    was = _decode_jaxpr(cfg)
+    assert _primitives(was.jaxpr, "pallas_call") == 0 and _primitives(was.jaxpr, "optimization_barrier") == 0
+    monkeypatch.setattr(kl.kda, "kernel_serves", lambda state, backend=None: False)
+    assert str(_decode_jaxpr(cfg)) == str(was)
+
+
+def test_where_the_kernel_serves_a_decode_step_holds_one_call_a_kda_layer_and_no_barrier(cfg, monkeypatch):
+    """Traced as on a TPU (nothing runs): the 128-wide model's decode step
+    calls ``kda_update`` once a KDA layer and has no ``optimization_barrier``
+    (the kernel aliases the pool: there is no in-place fusion for XLA to
+    clone); the toy's, which the kernel does not serve, keeps the barrier
+    before its first write into the pool, as the parent's program on a TPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = _wide()
+    served = _decode_jaxpr(wide).jaxpr
+    assert _primitives(served, "pallas_call") == wide.n_kda_layers == 2
+    assert str(served).count("name=kda_update") == 1  # ONE traced kernel, the layer an operand
+    assert _primitives(served, "optimization_barrier") == 0
+    cache_of = lambda c: jax.eval_shape(lambda: kl.cache_layout(c, 16).init(8))  # noqa: E731
+    assert kl.MODEL.attention_path(wide, 1, cache_of(wide)).name.startswith("kda.kernel+")
+    assert kl.MODEL.attention_path(wide, 16, cache_of(wide)).name.startswith("kda.chunk+")
+    kept = _decode_jaxpr(cfg).jaxpr
+    assert _primitives(kept, "optimization_barrier") == 1 and "kda_update" not in str(kept)
+    assert kl.MODEL.attention_path(cfg, 1, cache_of(cfg)).name.startswith("kda.update+")
+
+
+def test_prefill_then_decode_through_the_kda_kernel_leave_the_logits_and_the_pool_where_kda_update_does(monkeypatch):
+    """A chunked prefill of two sequences onto scattered slots, then 8 decode
+    steps of the two together with two padding rows, at the published head
+    width: once through ``kda_update`` (the CPU's path) and once through the
+    kernel (the predicate as it reads on a TPU; Pallas' TPU interpreter).
+    The logits of every step AND both arrays of the state pool agree; a
+    sequence that STARTS in a decode step (its slot held garbage) does too."""
+    cfg = _wide()
+    params = kl.init_params(cfg, jax.random.PRNGKey(7))
+    tokens = np.random.default_rng(3).integers(1, 256, size=(3, 40)).astype(np.int32)
+
+    def run():
+        cache, state = kl.cache_layout(cfg, 16).init(12), kl.state_layout(cfg).init(5)
+        state = {k: jnp.full_like(v, 3.0) for k, v in state.items()}  # what earlier holders left
+        tables = np.zeros((4, 4), np.int32)
+        tables[0, :3], tables[2, :2], tables[3, :1] = [5, 2, 9], [3, 8], [7]
+        prefill, decode = _steps(cfg)
+        for i, row, slot, chunks in ((0, 0, 3, (16, 5)), (1, 2, 1, (9,))):
+            cache, state, _ = _prefill(prefill, params, cache, state, tokens[i], tables[row], chunks, slot=slot, bucket=16)
+        slots, have = np.array([3, 0, 1, 4], np.int32), []
+        for d in range(8):
+            toks, pos = np.zeros(4, np.int32), np.zeros(4, np.int32)
+            toks[[0, 2]], pos[[0, 2]] = [tokens[0, 21 + d], tokens[1, 9 + d]], [21 + d, 9 + d]
+            if d >= 3:  # the third sequence starts in the decode batch, at position 0, on slot 4
+                toks[3], pos[3] = tokens[2, d - 3], d - 3
+            live = tables if d >= 3 else np.where(np.arange(4)[:, None] == 3, 0, tables)
+            cache, state, got, _ = decode(params, cache, state, toks, pos, live, pos + 1, slots)
+            have.append(np.asarray(got)[[0, 2, 3] if d >= 3 else [0, 2]].ravel())
+        return np.concatenate(have), np.asarray(state["kda_state"])[:, 1:], np.asarray(state["kda_conv"], np.float32)[:, 1:]
+
+    want = run()
+    assert kl.MODEL.attention_path(cfg, 1, None).name == "kda.update+latent.absorbed"
+    monkeypatch.setattr(kl.kda, "kernel_serves", lambda state, backend=None: state.shape[-1] % 128 == 0)
+    assert kl.MODEL.attention_path(cfg, 1, None).name == "kda.kernel+latent.absorbed"
+    for h, w in zip(run(), want):
+        assert np.isfinite(h).all() and _rel(h, w) < 1e-5
 
 
 def test_forward_matches_the_reference_and_the_counts(model, cfg, params, tokens):

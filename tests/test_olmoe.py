@@ -654,3 +654,33 @@ def test_the_flash_kernel_compiles_with_grouped_heads_and_a_window_at_mellum2_wi
         assert compiled.out_info.shape == (32, chunk, 128)
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def test_the_kda_update_kernel_compiles_for_the_chip_in_place_in_kimi_linears_pool(one_chip):
+    """A decode step's update of one KDA layer's slab (``ops/kda.py``; here
+    for the same reason as the ones above) over ``kda-reason-offline``'s whole
+    state pool, 20 layers x 65 slots x 32 heads x 128 x 128 float32: one
+    Mosaic call, the pool aliased in and out (2.7 GB: a copy would not fit
+    beside the weights) and nothing large beside it."""
+    from ray_tpu.ops import kda
+
+    slots, heads, d = 65, 32, 128
+    pool = (20, slots, heads, d, d)
+    assert kda.kernel_serves(jax.ShapeDtypeStruct(pool, jnp.float32), backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        compiled = jax.jit(
+            lambda state, *a: kda.update(state, 19, *a, interpret=False), donate_argnums=0
+        ).lower(
+            shape(pool), shape((slots, heads, d)), shape((slots, heads, d)), shape((slots, heads, d)),
+            shape((slots, heads, d)), shape((slots, heads)), shape((slots,), jnp.bool_),
+        ).compile()
+        text, memory = compiled.as_text(), compiled.memory_analysis()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "kda_update" in text
+        assert memory.alias_size_in_bytes == 20 * slots * heads * d * d * 4
+        assert memory.temp_size_in_bytes < 2**20
+        assert [o.shape for o in compiled.out_info] == [pool, (slots, heads, d)]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
