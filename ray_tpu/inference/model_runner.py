@@ -13,27 +13,15 @@ cfg)`` gives the three paged entry points it jits, the cache description
 attention path each query window takes (``Model.attention_path``, asked
 once a window: ``attention_paths``).
 
-Decode and verify read the cache one of these ways (``AttentionPath.reads``;
-for ``models/llama.py`` the predicate ``ops/paged_attention.py::
-kernel_serves`` says which, from shapes and the backend; ``models/xing4.py``'s
-latent paths both gather, the absorbed one for the real slots alone):
-
-* the Pallas kernel (a TPU, whole tiles): every slot reads its own live
-  blocks and no other, so the table's width costs nothing. The runner hands
-  the full-width table, ``table_widths`` is the single full rung and there
-  is ONE program per batch bucket.
-* the gather (everywhere else): the step gathers ``cache[layer,
-  block_tables]`` for every slot, so it costs what the table's width costs,
-  whatever the contexts in it. Callers still hand in
-  ``max_blocks_per_seq``-wide rows; the runner cuts them to the narrowest
-  rung of :func:`table_width_ladder` that covers the longest context of the
-  batch (2048 tokens, then doublings, then the full width: derived from
-  ``max_seq_len`` and ``block_size``, not configured). Positions past a
-  slot's context were masked before the softmax anyway, so the logits are
-  those of the full width. ``warmup()`` compiles every (batch bucket x rung)
-  pair, so a batch that crosses a rung in either direction finds its program
-  compiled; the cache argument has the same shape in all of them.
-
+A decode or verify launch is handed the table at ONE width,
+``max_blocks_per_seq``, on every backend: ONE program a batch bucket (and a
+verify window), so a batch whose contexts grow or shrink finds its program
+compiled. How much of the cache the program then reads is the attention's
+business alone (``AttentionPath.reads``): a Pallas kernel reads each slot's own
+live blocks and no other (``blocks``: a TPU, whole tiles; ``ops/
+paged_attention.py::kernel_serves`` and ``models/latent.py::paged_serves`` say
+when, from shapes and the backend), the fallback gathers the table as wide as
+it is (``table``; ``slots`` where a padding slot reads nothing).
 ``decode_width`` counts what was handed over and what the launched program
 reads of the cache, either way.
 
@@ -103,27 +91,6 @@ _COW_WIDTH = 4
 #: blocks per compiled KV gather/scatter program (KV-cache migration);
 #: short chunks pad with the null block so the shape never varies
 _KV_IO_WIDTH = 8
-
-
-#: narrowest block-table width, in tokens, that decode and verify compile
-#: for. Every rung is one more program to load at start-up (1.6 s each at
-#: the benchmark's widths, PERF.md PR 25): a 1024 rung was measured and
-#: saved 8 ms a step under it, but cost start-up more than it was allowed
-_MIN_TABLE_WIDTH_TOKENS = 2048
-
-
-def table_width_ladder(max_seq_len: int, block_size: int) -> Tuple[int, ...]:
-    """Block-table widths, in BLOCKS, that decode and verify are compiled
-    for: ``min(2048, max_seq_len)`` tokens, doublings of it below the full
-    width, and last the full width ``ceil(max_seq_len / block_size)``
-    itself. One rung up to 2048 tokens; 128 and 256 blocks for 4096 / 16."""
-    full = -(-max_seq_len // block_size)
-    rungs = []
-    tokens = _MIN_TABLE_WIDTH_TOKENS
-    while -(-tokens // block_size) < full:
-        rungs.append(-(-tokens // block_size))
-        tokens *= 2
-    return (*rungs, full)
 
 
 def _round_up_bucket(n: int, buckets: Sequence[int]) -> int:
@@ -322,21 +289,16 @@ class PagedModelRunner:
         #: at its first launch: :meth:`_path`): the attention path its program
         #: takes, as the model names it (the launch span's ``path``), and what
         #: a decode or verify launch reads of the cache on it
-        #: (:meth:`_table_width`). Fixed a program, so asked once a window
+        #: (:meth:`_count_width`). Fixed a program, so asked once a window
         self.attention_paths: Dict[int, AttentionPath] = {
             c: self.model.attention_path(cfg, c, self.cache) for c in (1, *self.verify_buckets)
         }
-        #: block-table widths (blocks) decode and verify are compiled for:
-        #: the full width alone where the width costs none of them anything
-        self.table_widths = table_width_ladder(cfg.max_seq_len, block_size)
-        if all(self.attention_paths[c].reads == "blocks" for c in (1, *self.verify_buckets)):
-            self.table_widths = self.table_widths[-1:]
         #: running sums over decode and verify launches: the width handed
-        #: over (tokens), the longest context of the batch, the contexts of
-        #: the real slots, and the positions the program reads (the gather:
-        #: batch bucket x rung, or each real slot at its own rung
-        #: (``Model.gather_widths``) where a padding slot
-        #: reads nothing; the kernel: each real slot's live blocks)
+        #: over (tokens: the table's, every launch), the longest context of
+        #: the batch, the contexts of the real slots, and the positions the
+        #: program reads (the gather: batch bucket x table width, or real
+        #: slots x table width where a padding slot reads nothing; the
+        #: kernel: each real slot's live blocks)
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
         )
@@ -497,10 +459,9 @@ class PagedModelRunner:
     ) -> None:
         """Compile every (or the given) bucket up front with trash inputs
         aimed at the null block, then :meth:`mark_warm`. Decode compiles
-        once per (batch bucket x table width of :attr:`table_widths`) and
-        verify once per (batch bucket x window bucket x table width): the
-        live batch picks its width from its longest context, so every
-        width it can pick is here and crossing a rung compiles nothing.
+        once per batch bucket and verify once per (batch bucket x window
+        bucket), each at the table's full width: whatever its contexts, a
+        live batch finds its program here.
         ``kv_io`` also compiles the KV-migration gather/scatter programs
         (disaggregated serving opts in; plain engines keep their compile
         count)."""
@@ -508,7 +469,7 @@ class PagedModelRunner:
         bs = self.block_size
         for c in buckets_prefill if buckets_prefill is not None else self.prefill_buckets:
             tokens = np.zeros(c, np.int32)
-            row = self._tables((), 0, M)
+            row = self._tables((), 0)
             self._step(
                 partial(self._warm, bucket=c), "paged_prefill_step", self._prefill_jit,
                 tokens, row, np.int32(0), np.int32(0), slots=np.int32(0),
@@ -520,42 +481,38 @@ class PagedModelRunner:
             # batch and the two of a sampled one, and no plain decode or verify
             C = self.drafter.window
             for b in batches:
-                for w in self.table_widths:
-                    warm = partial(self._warm, bucket=f"{b}x{C}x{w * bs}")
-                    window = (np.zeros((b, C), np.int32), self._tables((), b, w),
-                              np.zeros(b, np.int32), np.zeros(b, np.int32))
-                    self._step(warm, "paged_mtp_step", self._mtp_step_jit, *window, np.ones(b, np.int32),
-                               last=(self._no_windows,))
-                    (_, hidden), _ = self._step(warm, "paged_mtp_verify", self._mtp_verify_jit, *window)
-                    self._step(warm, "paged_mtp_draft", self._mtp_draft_jit, hidden, *window)
+                warm = partial(self._warm, bucket=f"{b}x{C}x{M * bs}")
+                window = (np.zeros((b, C), np.int32), self._tables((), b),
+                          np.zeros(b, np.int32), np.zeros(b, np.int32))
+                self._step(warm, "paged_mtp_step", self._mtp_step_jit, *window, np.ones(b, np.int32),
+                           last=(self._no_windows,))
+                (_, hidden), _ = self._step(warm, "paged_mtp_verify", self._mtp_verify_jit, *window)
+                self._step(warm, "paged_mtp_draft", self._mtp_draft_jit, hidden, *window)
             batches = ()
         for b in batches:
-            for w in self.table_widths:
-                self._step(
-                    partial(self._warm, bucket=f"{b}x{w * bs}"),
-                    "paged_decode_step", self._decode_jit,
-                    np.zeros(b, np.int32),
-                    np.zeros(b, np.int32),
-                    self._tables((), b, w),
-                    np.ones(b, np.int32),
-                    slots=np.zeros(b, np.int32), last=(self._no_picks,),
-                )
+            self._step(
+                partial(self._warm, bucket=f"{b}x{M * bs}"),
+                "paged_decode_step", self._decode_jit,
+                np.zeros(b, np.int32),
+                np.zeros(b, np.int32),
+                self._tables((), b),
+                np.ones(b, np.int32),
+                slots=np.zeros(b, np.int32), last=(self._no_picks,),
+            )
         # speculative-verify windows (only when the engine opted in via
         # verify_buckets — plain engines keep their exact compile count).
         # The batch axis rides the decode buckets: every (B-bucket,
-        # window-bucket, table-width) triple a live engine can issue gets
-        # compiled here.
+        # window-bucket) pair a live engine can issue gets compiled here.
         for c in self.verify_buckets if self.drafter is None else ():
             for b in batches:
-                for w in self.table_widths:
-                    self._step(
-                        partial(self._warm, bucket=f"{b}x{c}x{w * bs}"),
-                        "paged_verify_step", self._verify_jit,
-                        np.zeros((b, c), np.int32),
-                        self._tables((), b, w),
-                        np.zeros(b, np.int32),
-                        np.zeros(b, np.int32),
-                    )
+                self._step(
+                    partial(self._warm, bucket=f"{b}x{c}x{M * bs}"),
+                    "paged_verify_step", self._verify_jit,
+                    np.zeros((b, c), np.int32),
+                    self._tables((), b),
+                    np.zeros(b, np.int32),
+                    np.zeros(b, np.int32),
+                )
         # the COW copy program (all-null pairs write the null block's
         # trash back onto itself)
         pad = np.zeros(_COW_WIDTH, np.int32)
@@ -771,33 +728,28 @@ class PagedModelRunner:
         whole, rest = divmod(total, self.cache_layout.n_layers)
         return whole if not rest else total / self.cache_layout.n_layers
 
-    def _tables(self, block_rows, bucket: int, M: int) -> np.ndarray:
-        """The block tables a step is handed: the slots' rows cut to ``M``
-        blocks and padded to ``bucket`` slots with the null block's table: a
-        table a layer group, ``[groups, bucket, M]`` (each row then one row a
-        group), and for a model of ONE group its table as it always was,
-        ``[bucket, M]``. ``bucket`` 0: one row, no slot axis."""
+    def _tables(self, block_rows, bucket: int) -> np.ndarray:
+        """The block tables a step is handed: the slots' rows (``M`` =
+        ``max_blocks_per_seq`` blocks each) padded to ``bucket`` slots with the
+        null block's table: a table a layer group, ``[groups, bucket, M]``
+        (each row then one row a group), and for a model of ONE group its
+        table as it always was, ``[bucket, M]``. ``bucket`` 0: one row, no
+        slot axis."""
+        M = self.max_blocks_per_seq
         lead = (len(self._groups),) if len(self._groups) > 1 else ()
         bt = np.zeros((*lead, bucket, M) if bucket else (*lead, M), np.int32)
         if len(block_rows):  # [n, M] or [n, groups, M] -> the slots' axis before the last
-            bt[..., : len(block_rows), :] = np.moveaxis(np.asarray(block_rows, np.int32)[..., :M], 0, -2)
+            bt[..., : len(block_rows), :] = np.moveaxis(np.asarray(block_rows, np.int32), 0, -2)
         return bt
 
-    def _table_width(
-        self, ctx_lens: Sequence[int], bucket: int, window: int = 1,
-        reach: Optional[Sequence[int]] = None,
-    ) -> int:
-        """The width, in blocks, of the table this decode (``window`` 1) or
-        verify launch is handed: the first rung of :attr:`table_widths` that
-        covers the longest of ``ctx_lens`` (the real slots' contexts
-        INCLUDING what this step writes; padding slots fit any width).
-        Counts the choice, and what the program reads at it, in
-        :attr:`decode_width`. ``reach``: where each slot's window ends as the
-        program sees it (a verify window ends at its BUCKET's end), if that
-        is not ``ctx_lens``."""
+    def _count_width(self, ctx_lens: Sequence[int], bucket: int, window: int = 1) -> None:
+        """Count one decode (``window`` 1) or verify launch in
+        :attr:`decode_width`: the table it is handed (``max_blocks_per_seq``
+        wide, every launch) and what its program reads of the cache.
+        ``ctx_lens``: the real slots' contexts INCLUDING what this step
+        writes."""
         bs = self.block_size
-        need = int(max(ctx_lens))
-        width = _round_up_bucket(-(-need // bs), self.table_widths)
+        width = self.max_blocks_per_seq * bs
         reads = self._path(window).reads
         # by the groups' layers: a group that keeps a window holds, and a
         # kernel reads, each slot's blocks from the window's first on
@@ -809,18 +761,16 @@ class PagedModelRunner:
                 sum(-(-int(c) // bs) - (max(0, int(c) - keeps) // bs if keeps else 0) for c in ctx_lens)
                 for _, keeps in self._groups
             )
-        elif reads == "slots":  # each real slot at its own rung under the table's
-            ladder = self.model.gather_widths(self.cfg, width * bs, bs)
-            read = sum(_round_up_bucket(min(int(c), ladder[-1]), ladder) for c in reach or ctx_lens)
+        elif reads == "slots":  # each real slot the table whole
+            read = len(ctx_lens) * width
         else:
-            read = bucket * width * bs
+            read = bucket * width
         dw = self.decode_width
         dw["launches"] += 1
-        dw["width_tokens"] += width * bs
-        dw["needed_tokens"] += need
+        dw["width_tokens"] += width
+        dw["needed_tokens"] += int(max(ctx_lens))
         dw["live_tokens"] += live
         dw["gathered_tokens"] += read
-        return width
 
     def verify_batch(
         self,
@@ -836,25 +786,20 @@ class PagedModelRunner:
         per valid window position. The batch axis pads to a decode
         bucket; padding slots carry ``true_len=0`` so every position is
         invalid and the writes land on the null block. ``block_rows`` are
-        ``max_blocks_per_seq`` wide; the step is handed them only as wide as
-        the rung of :attr:`table_widths` that covers the longest
-        ``ctx_len + len(window)``, a program :meth:`warmup` compiled."""
+        ``max_blocks_per_seq`` wide, and so handed to the step."""
         clock = clock or self.clock
         n = len(windows)
         cbucket = _round_up_bucket(max(len(w) for w in windows), self.verify_buckets)
         bbucket = _round_up_bucket(n, self.decode_buckets)
-        M = self._table_width(
-            [c + len(w) for c, w in zip(ctx_lens, windows)], bbucket, cbucket,
-            reach=[c + cbucket for c in ctx_lens],
-        )
+        self._count_width([c + len(w) for c, w in zip(ctx_lens, windows)], bbucket, cbucket)
         with clock.phase(
             "launch", program="paged_verify_step",
-            bucket=f"{bbucket}x{cbucket}x{M * self.block_size}",
+            bucket=f"{bbucket}x{cbucket}x{self.max_blocks_per_seq * self.block_size}",
             path=self._path(cbucket).name,
         ):
             with clock.part("inputs"):
                 tokens = np.zeros((bbucket, cbucket), np.int32)
-                tables = self._tables(block_rows, bbucket, M)
+                tables = self._tables(block_rows, bbucket)
                 ctx = np.zeros(bbucket, np.int32)
                 tl = np.zeros(bbucket, np.int32)
                 for i, w in enumerate(windows):
@@ -868,13 +813,13 @@ class PagedModelRunner:
         out = self.read(Launched("decode", logits, loads), clock, launched)
         return [out[i, : len(w)] for i, w in enumerate(windows)]
 
-    def _mtp_inputs(self, windows, block_rows, ctx_lens, bucket: int, M: int):
+    def _mtp_inputs(self, windows, block_rows, ctx_lens, bucket: int):
         """A drafter step's padded inputs: ``(tokens [bucket, window], tables
         [bucket, M], ctx [bucket], true_lens [bucket])``; a padding slot has
         no row (``true_len`` 0) and the null block's table."""
         C = self.drafter.window
         tokens = np.zeros((bucket, C), np.int32)
-        tables = self._tables(block_rows, bucket, M)
+        tables = self._tables(block_rows, bucket)
         ctx = np.zeros(bucket, np.int32)
         tl = np.zeros(bucket, np.int32)
         for i, w in enumerate(windows):
@@ -915,17 +860,14 @@ class PagedModelRunner:
         bucket = _round_up_bucket(n, self.decode_buckets)
         # a named window may stand as far on as that step accepted drafts
         ctx_most = [c + (C - 1) * (w[0] < 0) for c, w in zip(ctx_lens, windows)]
-        M = self._table_width(
-            [c + len(w) for c, w in zip(ctx_most, windows)], bucket, C,
-            reach=[c + C for c in ctx_most],
-        )
+        self._count_width([c + len(w) for c, w in zip(ctx_most, windows)], bucket, C)
         program = "paged_mtp_step" if greedy else "paged_mtp_verify"
         with clock.phase(
-            "launch", program=program, bucket=f"{bucket}x{C}x{M * self.block_size}",
+            "launch", program=program, bucket=f"{bucket}x{C}x{self.max_blocks_per_seq * self.block_size}",
             path=self._path_name(C), ahead=int(after is not None),
         ):
             with clock.part("inputs"):
-                inputs = self._mtp_inputs(windows, block_rows, ctx_lens, bucket, M)
+                inputs = self._mtp_inputs(windows, block_rows, ctx_lens, bucket)
                 kn = np.ones(bucket, np.int32)
                 kn[:n] = known
                 if inputs[0].min() < 0 and (after is None or not greedy):
@@ -967,15 +909,13 @@ class PagedModelRunner:
         clock = clock or self.clock
         n, C = len(follows), self.drafter.window
         bucket = _round_up_bucket(n, self.decode_buckets)
-        M = _round_up_bucket(
-            -(-max(c + len(f) for c, f in zip(ctx_lens, follows)) // self.block_size), self.table_widths
-        )
         with clock.phase(
-            "launch", program="paged_mtp_draft", bucket=f"{bucket}x{C}x{M * self.block_size}",
+            "launch", program="paged_mtp_draft",
+            bucket=f"{bucket}x{C}x{self.max_blocks_per_seq * self.block_size}",
             path=self._path_name(C),
         ):
             with clock.part("inputs"):
-                inputs = self._mtp_inputs(follows, block_rows, ctx_lens, bucket, M)
+                inputs = self._mtp_inputs(follows, block_rows, ctx_lens, bucket)
             with clock.part("call"):
                 (picks, lg), loads = self._step(
                     self._run, "paged_mtp_draft", self._mtp_draft_jit, verified.hidden, *inputs
@@ -1002,26 +942,23 @@ class PagedModelRunner:
         picks, which never leave the device (the launch span's ``ahead`` says
         whether one was given). ``slots``: each sequence's state
         slot (a model with per-sequence state; padding rows take the null
-        slot). ``block_rows`` are ``max_blocks_per_seq`` wide; the step is
-        handed them only as wide as the rung of :attr:`table_widths` that
-        covers ``max(ctx_lens)``, a program :meth:`warmup` compiled. What is
-        cut off lay past every slot's context, which the step masks: the
-        logits are those of the full width."""
+        slot). ``block_rows`` are ``max_blocks_per_seq`` wide, and so handed
+        to the step."""
         clock = clock or self.clock
         n = len(tokens)
         if self.state is not None and (slots is None or len(slots) != n):
             raise ValueError("a decode batch of this model needs the state slot of each sequence")
         bucket = _round_up_bucket(n, self.decode_buckets)
-        M = self._table_width(ctx_lens, bucket)
+        self._count_width(ctx_lens, bucket)
         with clock.phase(
             "launch", program="paged_decode_step",
-            bucket=f"{bucket}x{M * self.block_size}",
+            bucket=f"{bucket}x{self.max_blocks_per_seq * self.block_size}",
             path=self._path(1).name, ahead=int(after is not None),
         ):
             with clock.part("inputs"):
                 t = np.zeros(bucket, np.int32)
                 p = np.zeros(bucket, np.int32)
-                bt = self._tables(block_rows, bucket, M)
+                bt = self._tables(block_rows, bucket)
                 cl = np.ones(bucket, np.int32)  # padding slots: ctx=1 over the null block
                 t[:n] = tokens
                 p[:n] = positions

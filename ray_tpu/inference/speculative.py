@@ -2,7 +2,7 @@
 
 The engine's decode loop amortizes per-token step overhead by letting a
 cheap *proposer* guess k tokens ahead, then verifying all k+1 positions
-in ONE bucketed jitted target step (``models.llama.paged_verify_step`` —
+in ONE bucketed jitted target step (the model's ``paged_verify_step`` —
 chunked-prefill-shaped, all-position logits). Because PR 10's
 (request_seed, absolute-position) RNG pins the whole output stream given
 (seed, prompt), acceptance is **exact-match**: the target's

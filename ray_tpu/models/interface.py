@@ -351,12 +351,6 @@ class Model:
     #: (cfg, window, cache) -> positions a key tile of a program whose
     #: ``attention_path`` reads ``"live"``
     key_tile: Callable = lambda cfg, window, cache: 1
-    #: (cfg, table_keys, block_size) -> the widths, in positions and rising,
-    #: at which a program whose ``attention_path`` reads ``"slots"`` gathers a
-    #: slot's context under a table ``table_keys`` wide: each slot at the
-    #: first that holds its context and its window (the table's own alone
-    #: unless the model says otherwise)
-    gather_widths: Callable = lambda cfg, table_keys, block_size: (table_keys,)
     #: ``None`` for a model whose layers all attend, else (cfg) -> the
     #: :class:`StateLayout` of its recurrent layers. Such a model's paged
     #: entry points take the state arrays after the cache (both donated) and

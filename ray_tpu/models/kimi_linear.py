@@ -825,6 +825,5 @@ MODEL = Model(
     attention_path=_attention_path,
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
     key_tile=lambda cfg, window, cache: latent_flash.tiles(window, latent.table_keys(cfg, cache))[1],
-    gather_widths=lambda cfg, table_keys, bs: latent.slot_widths(table_keys, bs),
     state_layout=state_layout,
 )

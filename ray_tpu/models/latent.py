@@ -18,7 +18,6 @@ door of a paged body (:func:`latent_attention`) and the block write
 from __future__ import annotations
 
 import math
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -244,27 +243,6 @@ def blocks_of_window(cfg, cache, window: int) -> int:
     return (window + bs - 2) // bs + 1
 
 
-#: the narrowest width, in positions, a slot's context is gathered at on the
-#: absorbed path (:func:`slot_widths`)
-_MIN_SLOT_WIDTH = 512
-
-
-def slot_widths(table_keys: int, bs: int = 1) -> Tuple[int, ...]:
-    """The widths, in positions, a decode or verify slot's context is
-    gathered at under a table ``table_keys`` positions wide: doublings of
-    ``_MIN_SLOT_WIDTH`` below the table's width (whole blocks of ``bs``), then
-    the table's own. Each slot takes the first that holds its context and its
-    window (:func:`latent_attention`; the runner counts the same rule:
-    ``Model.gather_widths``): a batch whose contexts differ fourfold pays for
-    each its own, not for every slot the longest's rung."""
-    out, w = [], _MIN_SLOT_WIDTH
-    while w < table_keys:
-        if w % bs == 0:
-            out.append(w)
-        w *= 2
-    return (*out, table_keys)
-
-
 def latent_attention(
     cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens, flash=None,
 ):
@@ -276,7 +254,7 @@ def latent_attention(
     Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
     c]``; the first ``true_lens[b]`` queries of a slot are real. The
     gathered context ``cache[layer, block_tables]`` is as wide as the table
-    handed over, or as a rung below it (where nothing is gathered: below). A window
+    handed over (where nothing is gathered: below). A window
     attends to itself as after the write (:func:`_paged_layers` says why
     the write itself comes last), by the path chosen at trace time from the
     window (:func:`absorbs`): a prefill chunk lays its rows over the
@@ -292,10 +270,9 @@ def latent_attention(
     where it serves (:func:`paged_serves`: a TPU, a short window, the cache
     stored in whole tiles; each slot's own live blocks are read from the
     cache as it lies, :func:`attend_paged`, and XLA gathers only the window's
-    ``nblk`` blocks, for the write) and elsewhere a slot at a time, each
-    slot's context gathered only as wide as the first of :func:`slot_widths`
-    that holds it (attending 8 slots of like context at a time instead was
-    SLOWER on the chip, PR 35).
+    ``nblk`` blocks, for the write) and elsewhere a slot at a time, a real
+    slot's context gathered at the table's width (attending 8 slots of like
+    context at a time instead was SLOWER on the chip, PR 35).
 
     Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
     ``blocks`` are the ``nblk`` (:func:`blocks_of_window`) blocks from the
@@ -338,33 +315,20 @@ def latent_attention(
         # window's rows are a second set of keys, and only the window's
         # blocks are rebuilt. ``W_kvb`` is absorbed for all slots at once
         q_row = absorb_query(cfg, p, q_nope, q_rope)
-        # each slot at the first width that holds its own context and window
-        widths = slot_widths(block_tables.shape[1] * bs, bs)
 
         def slot(args):
             table, q, own, at = args
 
-            def read(width: int):
-                def branch():
-                    whole = width == widths[-1]
-                    rows = context(table if whole else table[: width // bs + nblk])
-                    blocks = jax.lax.dynamic_update_slice(window_blocks(rows, at), own, (at % bs, 0))
-                    seen = (key_pos if whole else key_pos[: rows.shape[0]]) < at
-                    mask = jnp.broadcast_to(seen, (1, C, rows.shape[0]))
-                    return attend_rows(cfg, q[None], rows[None], mask, own[None])[0], blocks
-
-                return branch
+            def read():
+                rows = context(table)
+                blocks = jax.lax.dynamic_update_slice(window_blocks(rows, at), own, (at % bs, 0))
+                mask = jnp.broadcast_to(key_pos < at, (1, C, rows.shape[0]))
+                return attend_rows(cfg, q[None], rows[None], mask, own[None])[0], blocks
 
             def nothing():
                 return (jnp.zeros((*q.shape[:2], cfg.kv_lora_rank), q.dtype), jnp.zeros((nblk * bs, W), own.dtype))
 
-            if len(widths) == 1:
-                return jax.lax.cond(table[0] != 0, read(widths[0]), nothing)
-            rung = jnp.searchsorted(jnp.asarray(widths, jnp.int32), at + C, side="left").astype(jnp.int32)
-            return jax.lax.switch(
-                jnp.where(table[0] != 0, 1 + jnp.minimum(rung, len(widths) - 1), 0),
-                [nothing] + [read(w) for w in widths],
-            )
+            return jax.lax.cond(table[0] != 0, read, nothing)
 
         o_lat, blocks = jax.lax.map(slot, (tables, q_row, row, first))
         return absorb_output(cfg, p, o_lat), blocks

@@ -30,17 +30,17 @@ Attention: ``c_q = rms(h W_qa)``; ``[q_nope | q_rope] = c_q W_qb`` a head;
 head; ``q_rope`` and the one ``k_rope`` rotated at the token's position with
 YaRN's frequencies; scores ``(q_nope k_nope + q_rope k_rope) (dn + dr)^-1/2
 m^2``. The cache holds ``(c, rotated k_rope)``. Behind the one attention
-door of the paged body (:func:`_latent_attention`) there are two paths that
+door of the paged body (``latent.latent_attention``) there are two paths that
 are the same mathematics at different costs, chosen at trace time from the
 query window (:func:`absorbs`): a prefill chunk EXPANDS K and V of its
 context from the latent rows; a decode or verify window ABSORBS ``W_kvb``
 into the query and the output and attends over the latent rows directly.
 The expanded path attends two ways, chosen at trace time from what the
-code can observe (:func:`_flash_serves`: backend, dtype, whole tiles): on a
+code can observe (``latent.flash_serves``: backend, dtype, whole tiles): on a
 TPU through the flash kernel ``ops/latent_flash.py`` (the float32 scores stay
 in VMEM; key tiles past the live context are not read), elsewhere, and in
 :func:`forward` (training needs a gradient), through the materialised
-softmax of :func:`_attend_expanded`.
+softmax of ``latent.attend_expanded``.
 
 Layers of one kind are stacked on a leading axis and run under
 ``jax.lax.scan`` (two scans: the dense layers, the expert layers), so that
@@ -58,26 +58,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import latent
-from ray_tpu.models.interface import AttentionPath, CacheLayout, Model
+from ray_tpu.models.interface import AttentionPath, Model
 # the latent paths, the cache's layout and the block write know only
 # dimensions: ``models/latent.py`` has them, for this module and ``models/
-# kimi_linear.py``; under their old names here for the callers there are
-from ray_tpu.models.latent import (  # noqa: F401
-    absorb_output as _absorb_output,
-    absorb_query as _absorb_query,
-    absorbs,
-    attend_expanded as _attend_expanded,
-    attend_flash as _attend_flash,
-    attend_rows as _attend_rows,
-    block_at as _block_at,
-    block_size_of as _block_size,
-    cache_layout,
-    flash_serves as _flash_serves,
-    latent_attention as _latent_attention,
-    slot_widths as _slot_widths,
-    table_keys as _table_keys,
-    write_blocks as _write_blocks,
-)
+# kimi_linear.py``
+from ray_tpu.models.latent import absorbs, cache_layout
 from ray_tpu.ops import latent_flash
 from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
 from ray_tpu.parallel.sharding import constrain
@@ -592,7 +577,7 @@ def forward(cfg: Xing4Config, params, tokens, *, remat=False, mesh=None, rules=N
 
     def attention(p, h, layer):
         q_nope, q_rope, rows = _latent_qkv(cfg, p, h, pos)
-        o = _attend_expanded(cfg, p, q_nope, q_rope, rows, causal)
+        o = latent.attend_expanded(cfg, p, q_nope, q_rope, rows, causal)
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), None
 
     wrap = jax.checkpoint if remat not in (False, None) else None
@@ -655,13 +640,13 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     # its table holds
     block_tables = jnp.where(valid.any(axis=1, keepdims=True), block_tables, 0)
     true_lens = valid.sum(axis=1, dtype=jnp.int32)  # the valid rows lead (all three entry points)
-    # this module's own predicate (a test patches it), asked where it is not needed too
-    window, bs = pos.shape[1], _block_size(cfg, cache)
-    flash = not absorbs(cfg, window) and _flash_serves(cfg, window, cache, block_tables.shape[1] * bs)
+    # the predicate asked HERE (a test patches it), where it is not needed too
+    window, bs = pos.shape[1], latent.block_size_of(cfg, cache)
+    flash = not absorbs(cfg, window) and latent.flash_serves(cfg, window, cache, block_tables.shape[1] * bs)
 
     def attention(p, h, layer):
         q_nope, q_rope, row = _latent_qkv(cfg, p, h, pos)
-        o, blocks = _latent_attention(
+        o, blocks = latent.latent_attention(
             cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens,
             flash=flash,
         )
@@ -671,7 +656,7 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     X, blocks, aux = _scan_layers(
         cfg, params, X0, attention, valid, layer0=layer0, experts_in_place=True
     )
-    return _write_blocks(cfg, cache, block_tables, pos[:, 0], blocks, layer0), X, aux
+    return latent.write_blocks(cfg, cache, block_tables, pos[:, 0], blocks, layer0), X, aux
 
 
 def _step_outputs(cache, logits, aux):
@@ -719,7 +704,7 @@ def paged_decode_step(cfg: Xing4Config, params, cache, tokens, positions, block_
     block is padding). Head: the one row a slot."""
     del ctx_lens
     pos = positions[:, None]
-    valid = _block_at(block_tables, pos, _block_size(cfg, cache)) != 0
+    valid = latent.block_at(block_tables, pos, latent.block_size_of(cfg, cache)) != 0
     cache, X, aux = _paged_layers(cfg, params, cache, tokens[:, None], pos, valid, block_tables)
     return _step_outputs(cache, _lm_head(cfg, params, X[:, 0]), aux)
 
@@ -825,8 +810,8 @@ def batch_sharding(mesh, rules):
 def _attention_path(cfg: Xing4Config, window: int, cache, backend=None) -> AttentionPath:
     """The absorbed path reads each real slot's own live blocks through the
     kernel over latent rows where it serves (``latent.paged_serves``: a TPU,
-    the cache in whole tiles) and gathers a slot at a time elsewhere, each
-    slot as wide as its rung, nothing for a padding slot; the expanded path
+    the cache in whole tiles) and gathers a slot at a time elsewhere, a real
+    slot as wide as the table, nothing for a padding slot; the expanded path
     gathers the table as wide as it is handed over and then attends over ALL
     of it (the materialised softmax) or, through the flash kernel, over the
     key tiles up to the live context alone."""
@@ -834,7 +819,7 @@ def _attention_path(cfg: Xing4Config, window: int, cache, backend=None) -> Atten
         return AttentionPath("latent.paged", "blocks")
     if absorbs(cfg, window):
         return AttentionPath("latent.absorbed", "slots")
-    if _flash_serves(cfg, window, cache, backend=backend):
+    if latent.flash_serves(cfg, window, cache, backend=backend):
         return AttentionPath("latent.flash", "live")
     return AttentionPath("latent.expanded", "table")
 
@@ -853,6 +838,5 @@ MODEL = Model(
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
     # every expert layer of the paged body: ``_paged_layers`` scans them all in place
     experts_in_place=lambda cfg, layers: layers,
-    key_tile=lambda cfg, window, cache: latent_flash.tiles(window, _table_keys(cfg, cache))[1],
-    gather_widths=lambda cfg, table_keys, bs: _slot_widths(table_keys, bs),
+    key_tile=lambda cfg, window, cache: latent_flash.tiles(window, latent.table_keys(cfg, cache))[1],
 )

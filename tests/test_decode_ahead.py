@@ -394,7 +394,7 @@ def test_a_row_named_in_the_earlier_picks_decodes_as_the_token_itself(model):
         assert logits[-1].shape == (2, runner.cfg.vocab_size)
         # one program a batch bucket, whatever bucket made the picks it is handed
         assert runner.recompiles_after_warmup() == 0
-        assert runner.compile_count() == 1 + 2 * len(runner.table_widths) + 1
+        assert runner.compile_count() == 1 + 2 + 1  # the chunk's, a decode program a bucket, the COW copy
     np.testing.assert_array_equal(logits[0], logits[1])
 
 

@@ -1,14 +1,15 @@
-"""Table-width buckets of the model runner (ISSUE 25): decode and verify
-gather the block table only as wide as the rung that covers the batch's
-longest context. Cluster-free and CPU-runnable; the model is tiny in every
-width but ``max_seq_len`` 4096, so the ladder has the benchmark's two rungs
-(2048 and 4096 tokens at ``block_size`` 16).
+"""What a decode or verify launch is handed and what it reads (ISSUE 25, 30,
+48): the block table at ONE width, ``max_blocks_per_seq``, whatever the
+batch's contexts, so ONE program a batch bucket on every backend; the gather
+reads the table as wide as it is, and ``decode_width`` counts that.
+Cluster-free and CPU-runnable; the model is tiny in every width but
+``max_seq_len`` 4096 (the benchmark's table at ``block_size`` 16).
 
-Where the paged-attention kernel serves decode and verify (ISSUE 30) the
-width costs nothing: one full-width program a batch bucket, and the counter
-says what the kernel reads. The last cases force that path on the CPU by
-patching the selection predicate (the kernel then runs in Pallas' TPU
-interpreter); the program has no option for it."""
+Where the paged-attention kernel serves decode and verify (ISSUE 30) a slot
+reads its own live blocks alone, and the counter says so. The last cases force
+that path on the CPU by patching the selection predicate (the kernel then runs
+in Pallas' TPU interpreter) and hold the gather, the only fallback, against
+it; the program has no option for either."""
 
 import numpy as np
 import pytest
@@ -17,31 +18,13 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.inference.engine import EngineConfig, InferenceEngine  # noqa: E402
-from ray_tpu.inference.model_runner import PagedModelRunner, table_width_ladder  # noqa: E402
+from ray_tpu.inference.model_runner import PagedModelRunner  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.ops import paged_attention as PA  # noqa: E402
 
 BS = 16
 NUM_BLOCKS = 400
 COUNTERS = ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens")
-
-
-@pytest.mark.parametrize(
-    "max_seq_len, tokens",
-    [
-        (64, (64,)),
-        (1024, (1024,)),
-        (2048, (2048,)),
-        (4096, (2048, 4096)),
-        (32768, (2048, 4096, 8192, 16384, 32768)),
-        (3000, (2048, 3008)),
-    ],
-)
-def test_ladder_is_one_rung_to_2048_then_doublings_then_the_full_width(max_seq_len, tokens):
-    ladder = table_width_ladder(max_seq_len, BS)
-    assert tuple(w * BS for w in ladder) == tokens
-    assert ladder[-1] == -(-max_seq_len // BS)  # the last rung is max_blocks_per_seq itself
-    assert list(ladder) == sorted(set(ladder))
 
 
 @pytest.fixture(scope="module")
@@ -62,12 +45,11 @@ def _runner(cfg, params):
 
 
 @pytest.fixture(scope="module")
-def runners(cfg, params):
-    """The runner as it is, and one held to the full width (its ladder cut to
-    the last rung): the program every batch ran before there were rungs."""
-    narrow, full = _runner(cfg, params), _runner(cfg, params)
-    full.table_widths = full.table_widths[-1:]
-    return narrow, full
+def gather(cfg, params):
+    """The runner as it is off the chip: decode and verify gather the table."""
+    runner = _runner(cfg, params)
+    assert [runner.attention_paths[c] for c in (1, 4)] == [("gather", "table")] * 2
+    return runner
 
 
 def _fill(runner, seed=0):
@@ -114,57 +96,13 @@ def _both(runners, seed, call):
     return out
 
 
-def _assert_same(narrow, full):
+def _assert_same(one, other):
     """Float32 round-off: the two sum the same terms, and zeros past the
     context. The same K/V rows written, to the same values."""
-    np.testing.assert_allclose(narrow[0], full[0], rtol=1e-5, atol=1e-5)
-    assert narrow[1] == full[1]
-    for x, y in zip(narrow[2], full[2]):
+    np.testing.assert_allclose(one[0], other[0], rtol=1e-5, atol=1e-5)
+    assert one[1] == other[1]
+    for x, y in zip(one[2], other[2]):
         np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize(
-    "ctx_lens, rung",
-    [((5, 300, 2048), 2048), ((5, 300, 2049), 4096), ((5, 4096, 300), 4096)],
-)
-def test_decode_at_the_rung_is_decode_at_the_full_width(runners, ctx_lens, rung):
-    narrow, full = _both(
-        runners, 0,
-        lambda r: r.decode(
-            [7, 8, 9], [c - 1 for c in ctx_lens], _rows(r, ctx_lens), list(ctx_lens)
-        ),
-    )
-    assert narrow[0].shape == (3, runners[0].cfg.vocab_size)
-    _assert_same(narrow, full)
-    # each slot's own position in every layer, K and V (the fourth slot is
-    # padding and writes the null block)
-    assert len(narrow[1]) == 2 * runners[0].cfg.n_layers * 4
-    assert narrow[3] == {
-        "launches": 1, "width_tokens": rung, "needed_tokens": max(ctx_lens),
-        "live_tokens": sum(ctx_lens), "gathered_tokens": 4 * rung,
-    }
-    assert full[3] == {**narrow[3], "width_tokens": 4096, "gathered_tokens": 4 * 4096}
-
-
-@pytest.mark.parametrize("ctx, rung", [(2044, 2048), (2046, 4096)])
-def test_verify_at_the_rung_is_verify_at_the_full_width(runners, ctx, rung):
-    """A window of 4 after ``ctx`` cached tokens: 2044 + 4 fits the 2048
-    rung, 2046 + 4 crosses it. The second slot's window is shorter than
-    the window bucket, the other two slots are padding."""
-    windows = [[3, 4, 5, 6], [9, 8]]
-    narrow, full = _both(
-        runners, 1,
-        lambda r: np.concatenate(
-            r.verify_batch(windows, _rows(r, [ctx + 4, 42]), [ctx, 40])
-        ),
-    )
-    assert narrow[0].shape == (4 + 2, runners[0].cfg.vocab_size)
-    _assert_same(narrow, full)
-    assert narrow[3] == {
-        "launches": 1, "width_tokens": rung, "needed_tokens": ctx + 4,
-        "live_tokens": ctx + 4 + 42, "gathered_tokens": 4 * rung,
-    }
-    assert full[3]["width_tokens"] == 4096
 
 
 def test_counter_matches_a_hand_count_for_a_scripted_batch(cfg, params):
@@ -176,16 +114,16 @@ def test_counter_matches_a_hand_count_for_a_scripted_batch(cfg, params):
             [1] * len(ctx_lens), [c - 1 for c in ctx_lens], _rows(runner, ctx_lens),
             list(ctx_lens),
         )
-    # by hand: rungs 2048, 4096, 2048, 4096; one decode bucket of 4 slots
+    # by hand: the table's 4096 a launch; one decode bucket of 4 slots, padding and all
     assert runner.decode_width == {
         "launches": 4,
-        "width_tokens": 2048 + 4096 + 2048 + 4096,
+        "width_tokens": 4 * 4096,
         "needed_tokens": 2048 + 2049 + 17 + 4096,
         "live_tokens": 2353 + 2354 + 17 + 4102,
-        "gathered_tokens": 4 * (2048 + 4096 + 2048 + 4096),
+        "gathered_tokens": 4 * 4 * 4096,
     }
-    # two shapes of one program, compiled as they came (no warm-up here)
-    assert runner.compile_count() == 2
+    # one shape of one program, whatever the contexts (no warm-up here)
+    assert runner.compile_count() == 1
 
 
 def _engine(cfg, params):
@@ -207,16 +145,17 @@ def engine(cfg, params):
         eng.stop()
 
 
-def test_a_request_growing_over_a_rung_compiles_nothing(engine):
-    """A full ``warmup()`` holds every (decode bucket x rung) pair: a context
-    that grows from under 2048 to over it changes program, not the count."""
+def test_a_request_growing_through_2048_compiles_nothing(engine):
+    """Off the chip too ``warmup()`` compiles ONE decode program a batch
+    bucket, at the table's width: a context that grows from under 2048 (once a
+    rung of its own) to over it runs that program throughout."""
     runner = engine.runner
-    assert runner.table_widths == (128, 256)
-    # prefill buckets + decode buckets x rungs + the COW copy
-    assert runner.compile_count() == 2 + 1 * 2 + 1
-    assert {f"paged_decode_step[4x{t}]" for t in (2048, 4096)} <= set(
-        engine.stats()["startup"]["warmup_programs"]
-    )
+    assert runner.attention_paths[1] == ("gather", "table")
+    # prefill buckets + decode buckets + the COW copy
+    assert runner.compile_count() == 2 + 1 + 1
+    assert [p for p in engine.stats()["startup"]["warmup_programs"] if p.startswith("paged_decode_step")] == [
+        "paged_decode_step[4x4096]"
+    ]
     calls = []
     decode = runner.launch_decode  # the engine launches and reads in two calls since ISSUE 39
     runner.launch_decode = lambda *a, **kw: calls.append(max(a[3])) or decode(*a, **kw)
@@ -236,15 +175,13 @@ def test_a_request_growing_over_a_rung_compiles_nothing(engine):
     assert 0 < under < len(calls)
     assert got == {
         "launches": len(calls),
-        "width_tokens": 2048 * under + 4096 * (len(calls) - under),
+        "width_tokens": 4096 * len(calls),
         "needed_tokens": sum(calls),
         "live_tokens": sum(calls),
-        "gathered_tokens": 4 * (2048 * under + 4096 * (len(calls) - under)),
+        "gathered_tokens": 4 * 4096 * len(calls),
     }
-    assert got["needed_tokens"] <= got["width_tokens"]
-    assert got["live_tokens"] <= got["gathered_tokens"]
     assert engine.stats()["recompiles_after_warmup"] == 0
-    assert runner.compile_count() == 2 + 1 * 2 + 1
+    assert runner.compile_count() == 2 + 1 + 1
 
 
 # -- the kernel path, forced: the width costs nothing ---------------------------------
@@ -259,74 +196,90 @@ def kernel_forced(monkeypatch):
     )
 
 
+def _but_the_null_block(side):
+    """A side of :func:`_both` without the null block: where a batch has
+    padding slots the two paths differ there alone (a padding slot's trash is
+    the kernel's zeros through a layer, the gather's attention elsewhere)."""
+    logits, written, cache, _ = side
+    return logits, {w for w in written if w[2] != 0}, [np.delete(x, 0, axis=1) for x in cache]
+
+
 @pytest.mark.parametrize(
     "script",
-    [[(5, 300, 2048, 1000), (5, 300, 2049, 1000)], [(17, 4096, 2, 33), (2047, 40, 2049, 900)]],
-    ids=["over_the_2048_rung", "the_full_width_and_back"],
+    [
+        [(5, 300, 2048, 1000), (5, 300, 2049, 1000)], [(17, 4096, 2, 33), (2047, 40, 2049, 900)],
+        [(5, 300, 2048)], [(5, 300, 2049)], [(5, 4096, 300)],
+    ],
+    ids=["through_2048", "the_full_width_and_back", "a_padding_slot_to_2048", "a_padding_slot_over_2048",
+         "a_padding_slot_at_the_full_width"],
 )
-def test_kernel_decode_is_one_program_and_counts_live_blocks(cfg, params, runners, kernel_forced, script):
-    """Full buckets whose longest context crosses what used to be a rung: the
-    logits are the ladder's, ONE program serves both, and the counter reads
-    each slot's live blocks."""
+def test_kernel_decode_is_one_program_and_counts_live_blocks(cfg, params, gather, kernel_forced, script):
+    """Batches whose longest context crosses 2048 or fills the table: the
+    gather at the table's width, the only fallback, gives the kernel's logits
+    and writes the kernel's rows; ONE program serves every batch on either
+    path; the kernel's counter reads each slot's live blocks, the gather's the
+    batch bucket at the table's width."""
     runner = _runner(cfg, params)
-    assert [runner.attention_paths[c] for c in (1, 4)] == [("kernel", "blocks")] * 2 and runner.table_widths == (256,)
+    assert [runner.attention_paths[c] for c in (1, 4)] == [("kernel", "blocks")] * 2
+    compiled = gather.compile_count()
     for ctx_lens in script:
         call = lambda r: r.decode(  # noqa: E731
-            [7, 8, 9, 10], [c - 1 for c in ctx_lens], _rows(r, ctx_lens), list(ctx_lens)
+            list(range(7, 7 + len(ctx_lens))), [c - 1 for c in ctx_lens], _rows(r, ctx_lens), list(ctx_lens)
         )
-        kernel, ladder = _both((runner, runners[0]), 2, call)
-        _assert_same(kernel, ladder)
+        kernel, table = _both((runner, gather), 2, call)
+        assert kernel[0].shape == (len(ctx_lens), cfg.vocab_size)
+        _assert_same(_but_the_null_block(kernel), _but_the_null_block(table))
+        # each slot's own position in every layer, K and V (a padding slot writes the null block)
+        assert len(table[1]) == 2 * cfg.n_layers * 4
         blocks = sum(-(-c // BS) for c in ctx_lens)
         assert kernel[3] == {
             "launches": 1, "width_tokens": 4096, "needed_tokens": max(ctx_lens),
             "live_tokens": sum(ctx_lens), "gathered_tokens": blocks * BS,
         }
+        assert table[3] == {**kernel[3], "gathered_tokens": 4 * 4096}
         mean = sum(ctx_lens) / len(ctx_lens)
         assert kernel[3]["live_tokens"] / kernel[3]["gathered_tokens"] >= 1 - BS / mean
-    assert runner.compile_count() == 1  # the ladder compiled one a rung
-    assert [runners[0].attention_paths[c] for c in (1, 4)] == [("gather", "table")] * 2
+    assert runner.compile_count() == 1
+    assert gather.compile_count() - compiled <= 1  # the one decode program, if no case before compiled it
 
 
-def test_kernel_reads_nothing_for_a_padding_slot_and_serves_verify(cfg, params, runners, kernel_forced):
+@pytest.mark.parametrize("ctx", [2046, 2044], ids=["over_2048", "to_2048"])
+def test_kernel_reads_nothing_for_a_padding_slot_and_serves_verify(cfg, params, gather, kernel_forced, ctx):
+    """A window of 4 after ``ctx`` cached tokens; the second slot's window is
+    shorter than the window bucket, the other two slots are padding."""
     runner = _runner(cfg, params)
     windows = [[3, 4, 5, 6], [9, 8]]
     call = lambda r: np.concatenate(  # noqa: E731
-        r.verify_batch(windows, _rows(r, [2046 + 4, 42]), [2046, 40])
+        r.verify_batch(windows, _rows(r, [ctx + 4, 42]), [ctx, 40])
     )
-    kernel, ladder = _both((runner, runners[0]), 1, call)
-    # the null block is where the two differ: a padding slot's trash is the
-    # kernel's zeros through a layer there, the gather's attention elsewhere
-    null = lambda written: {w for w in written if w[2] == 0}  # noqa: E731
-    assert null(kernel[1]) == null(ladder[1])
-    sides = [
-        (logits, written - null(written), [np.delete(x, 0, axis=1) for x in cache])
-        for logits, written, cache, _ in (kernel, ladder)
-    ]
-    _assert_same(*sides)
-    assert ladder[3]["width_tokens"] == 4096  # 2046 + 4 crossed the rung there
-    # 129 + 3 live blocks; the two padding slots read nothing
+    kernel, table = _both((runner, gather), 1, call)
+    assert kernel[0].shape == (4 + 2, cfg.vocab_size)
+    assert {w for w in kernel[1] if w[2] == 0} == {w for w in table[1] if w[2] == 0}
+    _assert_same(_but_the_null_block(kernel), _but_the_null_block(table))
+    # the first slot's live blocks + 3; the two padding slots read nothing
     assert kernel[3] == {
-        "launches": 1, "width_tokens": 4096, "needed_tokens": 2050,
-        "live_tokens": 2050 + 42, "gathered_tokens": (129 + 3) * BS,
+        "launches": 1, "width_tokens": 4096, "needed_tokens": ctx + 4,
+        "live_tokens": ctx + 4 + 42, "gathered_tokens": (-(-(ctx + 4) // BS) + 3) * BS,
     }
+    assert table[3] == {**kernel[3], "gathered_tokens": 4 * 4096}
 
 
 def test_kernel_engine_warms_one_decode_program_and_never_recompiles(cfg, params, engine, kernel_forced):
-    """The request of ``test_a_request_growing_over_a_rung_compiles_nothing``
-    on an engine whose decode takes the kernel: one decode program instead of
-    two, the same greedy tokens, nothing compiled on the way over 2048."""
+    """The request of ``test_a_request_growing_through_2048_compiles_nothing``
+    on an engine whose decode takes the kernel: the same one decode program a
+    bucket, the same greedy tokens, nothing compiled on the way over 2048."""
     eng = _engine(cfg, params)
     try:
         runner = eng.runner
-        assert runner.table_widths == (256,)
+        assert runner.attention_paths[1] == ("kernel", "blocks")
         assert runner.compile_count() == 2 + 1 + 1  # prefill buckets, ONE decode program, the COW copy
         programs = set(eng.stats()["startup"]["warmup_programs"])
-        assert "paged_decode_step[4x4096]" in programs and "paged_decode_step[4x2048]" not in programs
+        assert [p for p in programs if p.startswith("paged_decode_step")] == ["paged_decode_step[4x4096]"]
         prompt = [int(t) for t in np.random.RandomState(0).randint(1, 256, size=2034)]
         start = eng.stats()["decode_width"]
         tokens = list(eng.generate(prompt, max_new_tokens=30))
         end = eng.stats()["decode_width"]
-        assert tokens == list(engine.generate(prompt, max_new_tokens=30))  # the ladder's engine
+        assert tokens == list(engine.generate(prompt, max_new_tokens=30))  # the gather's engine
         got = {k: end[k] - start[k] for k in COUNTERS}
         contexts = range(2035, 2064)
         assert got == {

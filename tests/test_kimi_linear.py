@@ -112,14 +112,11 @@ def test_chunked_prefill_then_decode_match_the_reference(model, cfg, params, tok
     assert float(jnp.min(state["kda_state"][:, 1])) == 3.0 and float(jnp.min(state["kda_state"][:, 3])) == 3.0
 
 
-def test_decode_slots_gather_their_context_at_their_own_width(model, cfg, params, tokens, monkeypatch):
-    """A decode batch whose slots sit on different rungs of the ladder of
-    gather widths (``latent.slot_widths``, cut to the toy's size: contexts of
-    13 and 37 on 16 and the table's own 64, one crossing 16 while decoding),
-    against the reference: each slot reads its own context whole."""
-    assert latent.slot_widths(8192, 16) == (512, 1024, 2048, 4096, 8192) and latent.slot_widths(64, BS) == (64,)
-    monkeypatch.setattr(latent, "_MIN_SLOT_WIDTH", 16)
-    assert latent.slot_widths(64, BS) == (16, 32, 64)
+def test_decode_slots_gather_their_context_at_their_own_width(model, cfg, params, tokens):
+    """A decode batch whose slots hold contexts of unlike length (13 and 37
+    under a table of 64, one crossing a block's edge while decoding, padding
+    slots between them), against the reference: each slot reads its own
+    context whole, through the table gathered at its width."""
     lens, tables = (37, 13), np.zeros((4, 8), np.int32)
     tables[0, :6], tables[2, :3] = np.arange(1, 7), np.arange(7, 10)
     cache, state = kl.cache_layout(cfg, BS).init(16), kl.state_layout(cfg).init(4)
@@ -471,23 +468,21 @@ def test_the_pool_s_reading_tells_a_fault_on_the_serving_path(model, cfg, params
         assert state["worst"]["first"] > 1e-3 and state["worst"]["deep"] >= state["worst"]["first"]
 
 
-def test_the_runner_counts_each_decode_slot_at_its_own_gather_width(cfg, params, monkeypatch):
+def test_the_runner_counts_each_decode_slot_at_its_own_gather_width(cfg, params):
     """``decode_width["gathered_tokens"]`` follows the rule the program
-    gathers by (``Model.gather_widths``): each real slot at the first rung
-    that holds its context, a padding slot nothing; not slots x the table."""
+    gathers by: each REAL slot the table at its width, a padding slot
+    nothing; not the batch bucket x the table."""
     from ray_tpu.inference.model_runner import PagedModelRunner
 
-    monkeypatch.setattr(latent, "_MIN_SLOT_WIDTH", 16)
     runner = PagedModelRunner(cfg, params, num_blocks=64, block_size=BS, prefill_buckets=(16, 32),
                               decode_buckets=(4,), state_slots=4)
     assert runner.attention_paths[1].reads == "slots"
-    assert model_of(cfg).gather_widths(cfg, 128, BS) == (16, 32, 64, 128)
     width = runner.max_blocks_per_seq
     rows = [list(range(1 + 8 * i, 9 + 8 * i)) + [0] * (width - 8) for i in range(2)]
     runner.decode([5, 6], [37, 13], rows, [38, 14], slots=[1, 2])
     dw = runner.decode_width
-    assert (dw["launches"], dw["live_tokens"], dw["gathered_tokens"]) == (1, 52, 64 + 16)
-    assert dw["width_tokens"] == width * BS  # the table handed over is still the rung's
+    assert (dw["launches"], dw["live_tokens"], dw["gathered_tokens"]) == (1, 52, 2 * width * BS)
+    assert dw["width_tokens"] == width * BS
 
 
 # -- the slot pool: manager and scheduler (host only) ----------------------------------------------

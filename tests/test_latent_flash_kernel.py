@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import xing4
+from ray_tpu.models import latent, xing4
 from ray_tpu.ops import latent_flash
 
 KEYS, TILE = 96, 16
@@ -62,9 +62,9 @@ def test_the_kernel_is_the_materialised_softmax(monkeypatch, ctx, true_len, C, d
     if ctx_len + n > KEYS:
         n = KEYS - ctx_len  # the last chunk of a request that fills the table: padded
     p, q_nope, q_rope, rows, poisoned = _case(cfg, C, ctx_len, n, dtype)
-    have = xing4._attend_flash(cfg, p, q_nope, q_rope, poisoned[:KEYS], jnp.int32(ctx_len), jnp.int32(n))
+    have = latent.attend_flash(cfg, p, q_nope, q_rope, poisoned[:KEYS], jnp.int32(ctx_len), jnp.int32(n))
     mask = jnp.arange(KEYS + C) <= (ctx_len + jnp.arange(C))[None, :, None]
-    want = xing4._attend_expanded(cfg, p, q_nope[None], q_rope[None], rows[None], mask)[0]
+    want = latent.attend_expanded(cfg, p, q_nope[None], q_rope[None], rows[None], mask)[0]
     assert have.shape == want.shape == (C, cfg.n_heads, 32) and have.dtype == want.dtype == dtype
     have, want = np.asarray(have, np.float32), np.asarray(want, np.float32)
     assert np.isfinite(have).all()

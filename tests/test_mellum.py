@@ -673,7 +673,7 @@ def test_a_plain_configuration_of_four_wide_kv_heads_is_served_from_its_flat_cac
     cfg = dataclasses.replace(L.LlamaConfig.tiny(), n_heads=8, n_kv_heads=4, attn_head_dim=128, max_seq_len=64)
     params = L.init_params(cfg, jax.random.PRNGKey(3))
     runner = PagedModelRunner(cfg, params, num_blocks=20, block_size=4, prefill_buckets=(8,), decode_buckets=(2,))
-    assert runner.cache["k"].shape == (2, 20, 4 * 4, 128) and runner._tables((), 2, 4).shape == (2, 4)
+    assert runner.cache["k"].shape == (2, 20, 4 * 4, 128) and runner._tables((), 2).shape == (2, 16)
     tokens = _tokens(5, (1, 27))
     want = L.forward(cfg, params, jnp.asarray(tokens))[0]
     row = np.zeros(runner.max_blocks_per_seq, np.int32)

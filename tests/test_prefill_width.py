@@ -18,7 +18,7 @@ jax = pytest.importorskip("jax")
 
 from ray_tpu.inference.engine import EngineConfig, InferenceEngine  # noqa: E402
 from ray_tpu.inference.model_runner import PagedModelRunner  # noqa: E402
-from ray_tpu.models import xing4  # noqa: E402
+from ray_tpu.models import latent, xing4  # noqa: E402
 from ray_tpu.models.interface import model_of  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops import latent_flash  # noqa: E402
@@ -46,7 +46,7 @@ def params(cfg):
 def flash_forced(monkeypatch):
     """The predicate as it reads on a TPU at whole tiles, and tiles the toy
     table holds eight of."""
-    monkeypatch.setattr(xing4, "_flash_serves", lambda *a, **kw: True)
+    monkeypatch.setattr(latent, "flash_serves", lambda *a, **kw: True)
     monkeypatch.setattr(latent_flash, "_QUERY_TILE", TILE)
     monkeypatch.setattr(latent_flash, "_KEY_TILE", TILE)
 

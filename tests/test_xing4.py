@@ -73,18 +73,13 @@ def _prefill(cfg, params, cache, row_tokens, table, chunks, bucket=40):
     return cache, np.asarray(logits)
 
 
-@pytest.mark.parametrize("min_width", [512, 16], ids=["the_table_s_width", "rungs_16_32_64"])
 @pytest.mark.parametrize("chunks", [(37,), (13, 24), (16, 16, 5), (7, 9, 11, 10), (32, 5)],
-                         ids=lambda c: "+".join(map(str, c)))
-def test_chunked_prefill_decode_and_verify_match_the_reference(model, cfg, params, tokens, chunks, min_width,
-                                                                monkeypatch):
+                         ids=lambda c: "the_table_s_width-" + "+".join(map(str, c)))
+def test_chunked_prefill_decode_and_verify_match_the_reference(model, cfg, params, tokens, chunks):
     """Chunks whose boundaries split a block of 8, then a decode step, then
     a verify window of 3, all through the latent cache, against the
     reference's full forward pass: logits, not tokens. The absorbed path
-    gathers the slot's context as wide as the table (the toy's 64 positions
-    lie under the narrowest rung) and, with the ladder cut to the toy's size,
-    at the first of 16, 32, 64 that holds context and window."""
-    monkeypatch.setattr(latent, "_MIN_SLOT_WIDTH", min_width)
+    gathers the slot's context as wide as the table."""
     n = sum(chunks)
     table = np.zeros(8, np.int32)
     table[:8] = np.arange(1, 9)
@@ -135,12 +130,12 @@ def test_absorbed_attention_equals_expanded_on_the_same_rows(cfg, params, window
     q_rope = jnp.asarray(rng.standard_normal((B, window, cfg.n_heads, cfg.qk_rope_head_dim)), jnp.float32)
     rows = jnp.asarray(rng.standard_normal((B, S, cfg.latent_width)), jnp.float32)
     pos = jnp.asarray(rng.integers(window, S, size=(B, 1)) - np.arange(window)[::-1][None], jnp.int32)
-    e = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, jnp.arange(S) <= pos[:, :, None])
+    e = latent.attend_expanded(cfg, p, q_nope, q_rope, rows, jnp.arange(S) <= pos[:, :, None])
     # absorbed, as the paged step calls it: the context before the window, the window's own rows beside it
     own = jnp.take_along_axis(rows, pos[:, :, None], axis=1)
     before = jnp.broadcast_to((jnp.arange(S) < pos[:, :1])[:, None, :], (B, window, S))
-    q_row = xing4._absorb_query(cfg, p, q_nope, q_rope)
-    a = xing4._absorb_output(cfg, p, xing4._attend_rows(cfg, q_row, rows, before, own))
+    q_row = latent.absorb_query(cfg, p, q_nope, q_rope)
+    a = latent.absorb_output(cfg, p, latent.attend_rows(cfg, q_row, rows, before, own))
     assert a.shape == (B, window, cfg.n_heads, cfg.v_head_dim)
     assert _rel(a, e) < 1e-5
     assert xing4.absorbs(cfg, window)
@@ -158,9 +153,9 @@ def test_the_expanded_path_attends_a_block_of_queries_at_a_time(cfg, params, mon
     q_rope = jnp.asarray(rng.standard_normal((B, C, cfg.n_heads, cfg.qk_rope_head_dim)), jnp.float32)
     rows = jnp.asarray(rng.standard_normal((B, S, cfg.latent_width)), jnp.float32)
     mask = jnp.arange(S) <= (10 + jnp.arange(C))[None, :, None] + jnp.zeros((B, 1, 1), jnp.int32)
-    whole = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
+    whole = latent.attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
     monkeypatch.setattr(latent, "_QUERY_BLOCK", block)  # where the lifted function reads it
-    blocks = xing4._attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
+    blocks = latent.attend_expanded(cfg, p, q_nope, q_rope, rows, mask)
     assert _rel(blocks, whole) < 1e-6
 
 
@@ -373,7 +368,7 @@ def test_the_absorbed_path_reads_nothing_for_a_padding_slot(cfg, params, tokens,
     def runner(bucket):
         r = PagedModelRunner(cfg, params, num_blocks=40, block_size=BS, prefill_buckets=(40,),
                              decode_buckets=(bucket,))
-        full = r.table_widths[-1]
+        full = r.max_blocks_per_seq
         rows = [list(range(1 + 8 * slot, 9 + 8 * slot)) + [0] * (full - 8) for slot in range(real)]
         for slot in range(real):
             r.prefill_chunk(tokens[slot % 2, : 30 + slot].tolist(), rows[slot], 0)
@@ -383,7 +378,7 @@ def test_the_absorbed_path_reads_nothing_for_a_padding_slot(cfg, params, tokens,
     padded, have = runner(4)
     alone, want = runner(real)
     np.testing.assert_allclose(have, want, rtol=0, atol=1e-5)
-    width = padded.table_widths[0] * BS
+    width = padded.max_blocks_per_seq * BS
     assert padded.decode_width["gathered_tokens"] == real * width == alone.decode_width["gathered_tokens"]
     assert padded.decode_width["live_tokens"] == sum(31 + slot for slot in range(real))
 
@@ -451,17 +446,16 @@ def test_decode_and_verify_through_the_kernel_equal_the_gather(tiled, tokens, re
 
 
 def test_the_runner_compiles_one_decode_program_and_counts_live_blocks(tiled, tokens, paged_forced):
-    """Where the absorbed path reads blocks the width costs nothing: ONE
-    rung, the full table, and ``decode_width["gathered_tokens"]`` is each real
-    slot's live blocks (a padding slot reads none)."""
-    from ray_tpu.inference.model_runner import PagedModelRunner, table_width_ladder
+    """Where the absorbed path reads blocks the table's width costs nothing:
+    ONE decode program, handed the full table, and
+    ``decode_width["gathered_tokens"]`` is each real slot's live blocks (a
+    padding slot reads none)."""
+    from ray_tpu.inference.model_runner import PagedModelRunner
 
     cfg, params = tiled
     cfg = dataclasses.replace(cfg, max_seq_len=4096)
-    assert len(table_width_ladder(cfg.max_seq_len, 16)) == 2
     runner = PagedModelRunner(cfg, params, num_blocks=264, block_size=16, prefill_buckets=(48,), decode_buckets=(4,))
     assert runner.attention_paths[1] == ("latent.paged", "blocks")
-    assert runner.table_widths == (256,)
     rows = [list(range(1 + 4 * i, 5 + 4 * i)) + [0] * 252 for i in range(2)]
     for i, n in enumerate((37, 16)):
         runner.prefill_chunk(tokens[i, :n].tolist(), rows[i], 0)
@@ -470,6 +464,7 @@ def test_the_runner_compiles_one_decode_program_and_counts_live_blocks(tiled, to
     dw = runner.decode_width
     assert (dw["launches"], dw["width_tokens"], dw["live_tokens"]) == (1, 4096, 38 + 17)
     assert dw["gathered_tokens"] == (3 + 2) * 16
+    assert runner.compile_count() == 1 + 1  # the chunk's program and ONE decode program
 
 
 # -- export and import between two engines, on both layouts -------------------------------------
