@@ -74,6 +74,14 @@ from ray_tpu.observability import timeline
 from ray_tpu.observability import tracing as _tracing
 
 _END = object()  # stream sentinel
+#: streams a step's deliveries wake at most (a first token, an end and an
+#: error go out whatever the count). A wake-up is a stream item: its consumer
+#: takes the GIL to hand it to the RPC loop, which takes it to write it, and
+#: the owner's acknowledgement takes it again. 32 a step cost the step thread
+#: nothing it can see (8.9 ms of host under a 12 ms device step); 128 a step
+#: made its 10 ms of work last 35 (PERF.md, PR 49): it queued for the GIL
+#: behind the streams, and the device waited for it.
+_WAKES_PER_STEP = 32
 
 #: the step account's leaf phases (``stats()["step_phases"]["<phase>_s"]``,
 #: profiler annotation ``engine.<phase>``); README "Observability"
@@ -513,6 +521,7 @@ class InferenceEngine:
         #: consumers then want the GIL while the device runs and this
         #: thread waits, not while it launches.
         self._held: List[tuple] = []
+        self._wakes_left = _WAKES_PER_STEP
         #: orders appends and deliveries: cancel() and stop() finish
         #: requests from their callers' threads
         self._held_lock = threading.Lock()
@@ -1268,6 +1277,7 @@ class InferenceEngine:
 
     def _step(self, t0_us: float, in_loop: bool) -> bool:
         clock = self._clock
+        self._wakes_left = _WAKES_PER_STEP
         with clock.phase("schedule", step=self.total_steps):
             with clock.part("drain"):
                 if self._draining and self._drain_deadline is not None and self._drain_deadline.expired:
@@ -1936,7 +1946,7 @@ class InferenceEngine:
             with self._lock:
                 q = self._out.get(req.request_id)
             if q is not None:
-                self._hold(q, ("kv_export", payload))
+                self._hold(q, ("kv_export", payload), now=True)
         if self.scheduler.finish(req, FINISHED):
             self._finish_request(req, FINISHED, error=None)
 
@@ -2291,7 +2301,7 @@ class InferenceEngine:
                 cached_prefix_tokens=req.cached_prefix_tokens,
             )
         if q is not None:
-            self._hold(q, token)
+            self._hold(q, token, now=ttft is not None)
         done = (
             len(req.generated) >= req.max_new_tokens
             or (req.eos_token is not None and token == req.eos_token)
@@ -2344,7 +2354,7 @@ class InferenceEngine:
                 preemptions=req.preemptions,
             )
         if q is not None:
-            self._hold(q, error if error is not None else _END)
+            self._hold(q, error if error is not None else _END, now=True)
         self.metrics["requests_total"].inc(labels={"outcome": outcome})
 
     def _close_ledger(
@@ -2454,23 +2464,37 @@ class InferenceEngine:
         self._deliver_held("direct")
 
     # -- held wake-ups ----------------------------------------------------
-    def _hold(self, q: "queue.Queue", item: Any) -> None:
+    def _hold(self, q: "queue.Queue", item: Any, now: bool = False) -> None:
         """What ``q.put(item)`` was on the step thread: the item is
-        committed, its consumer is woken by the next delivery."""
+        committed, its consumer is woken by a later delivery; ``now``: by the
+        next one, however many streams wait (a first token, an end)."""
         with self._held_lock:
-            self._held.append((q, item, time.perf_counter()))
+            self._held.append((q, item, time.perf_counter(), now))
 
-    def _deliver_held(self, where: str) -> None:
+    def _deliver_held(self, where: str, most: Optional[int] = None) -> None:
         """Put every held item, in the order it was committed, and count
         it under ``where``. The puts run under the lock, so that two
-        deliverers cannot interleave one stream's items."""
+        deliverers cannot interleave one stream's items. ``most``: wake
+        that many streams and no more, those that wait longest first and
+        those held ``now`` among them; a stream that is woken gets all it
+        has, the others' items stay held, so that each wakes once for
+        several tokens (``tokens_chunked`` hands them on as one item)."""
         with self._held_lock:
             held = self._held
             if not held:
                 return
             self._held = []
+            if most is not None:
+                going = {id(q) for q, _, _, now in held if now}
+                for q, _, _, _ in held:  # in commit order: the longest wait first
+                    if len(going) >= most:
+                        break
+                    going.add(id(q))
+                self._wakes_left = max(0, most - len(going))
+                self._held = [entry for entry in held if id(entry[0]) not in going]
+                held = [entry for entry in held if id(entry[0]) in going]
             committed = 0.0
-            for q, item, at in held:
+            for q, item, at, _ in held:
                 q.put(item)
                 committed += at
             wakes = self._wakes
@@ -2478,20 +2502,21 @@ class InferenceEngine:
             wakes[where] += len(held)
             wakes["held_s"] += len(held) * time.perf_counter() - committed
 
-    def _wake(self, where: str) -> None:
+    def _wake(self, where: str, most: Optional[int] = None) -> None:
         """:meth:`_deliver_held` on the step account, for a caller that is
         in no phase: the wake-ups are ``emit``'s second half, its part
         ``deliver`` (the first, ``commit``, runs while the device is idle;
         this one, after a launch, beside it)."""
         if self._held:
             with self._clock.phase("emit"), self._clock.part("deliver"):
-                self._deliver_held(where)
+                self._deliver_held(where, most)
 
     def _wake_after_launch(self) -> None:
         """The runner's ``launched`` hook: the program is on its way and
         this thread is about to wait for it, so the consumers of the LAST
-        launch run while the device does."""
-        self._wake("after_launch")
+        launch run while the device does: as many of them as a device
+        step hides."""
+        self._wake("after_launch", self._wakes_left)
 
     def _reap_abandoned_streams(self) -> None:
         ttl = self.engine_cfg.finished_stream_ttl_s

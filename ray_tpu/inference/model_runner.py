@@ -973,6 +973,16 @@ class PagedModelRunner:
                     self._run, "paged_decode_step", self._decode_jit, t, p, bt, cl, slots=sl,
                     last=(self._no_picks if after is None else after.picks,),
                 )
+        if greedy:
+            # what the read copies to the host is a few small integers (the picks,
+            # the expert loads): the copies start when the step ends, not when the
+            # host asks, as the drafter's ONE-program step does (asked for beside
+            # the NEXT running program each took 4-5 ms at 128 slots and they came
+            # in turn: PERF.md, PR 49). A batch that reads its logits copies them
+            # when it asks: 33 MB a step nobody may want
+            for leaf in (picks, *(loads.values() if isinstance(loads, dict) else (loads,))):
+                if leaf is not None:
+                    leaf.copy_to_host_async()
         return Launched("decode", picks if greedy else logits, loads, n, picks)
 
     def decode(

@@ -464,8 +464,12 @@ class ContinuousBatchingScheduler:
                     ),
                     key=lambda r: (-r.priority, r.arrival),
                 )
+                # by identity: ``req not in self.running`` compares dataclasses field
+                # by field with every running request for every decodable one, 21 ms
+                # a step at 128 slots (4 at 64, 81 at 256: PERF.md, PR 49)
+                alive = {id(r) for r in self.running}
                 for req in decodable[: self.max_decode_batch]:
-                    if req not in self.running:
+                    if id(req) not in alive:
                         continue  # evicted by an earlier decode's growth
                     # the step writes KV at position context_len-1 (the token
                     # sampled LAST step): coverage of exactly context_len
@@ -495,6 +499,7 @@ class ContinuousBatchingScheduler:
                         continue
                     grown = self.blocks.grow_to(req.request_id, need)
                     while not grown and self._preempt_one(req, planned_ids):
+                        alive = {id(r) for r in self.running}  # a victim left
                         grown = self.blocks.grow_to(req.request_id, need)
                     if grown:
                         plan.decodes.append(req)

@@ -63,7 +63,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent
 from ray_tpu.models.interface import AttentionPath, Model, StateLayout
-from ray_tpu.ops import kda, latent_flash
+from ray_tpu.ops import kda, latent_flash, short_conv
 from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
 from ray_tpu.parallel.sharding import constrain
 
@@ -337,10 +337,7 @@ def _kda_inputs(cfg: KimiLinearConfig, p, h, tail, valid):
     H = cfg.kda_heads
     with jax.named_scope("kda.conv"):
         window = jnp.concatenate([tail, h @ p["kda_wqkv"]], axis=1)
-        C, taps = h.shape[1], p["kda_conv"].astype(F32)
-        mixed = sum(
-            window[:, j : j + C].astype(F32) * taps[j] for j in range(cfg.conv_kernel)
-        )
+        mixed = short_conv.taps_over(window, p["kda_conv"], h.shape[1])
         q, k, v = (_heads(a, H) for a in jnp.split(jax.nn.silu(mixed), 3, axis=-1))
         q, k = _l2_norm(q) * cfg.kda_head_dim ** -0.5, _l2_norm(k)
     with jax.named_scope("kda.gate"):
@@ -511,8 +508,7 @@ def _kda_mix(cfg: KimiLinearConfig, p, h, S, tail, valid, recur=None):
     if h.shape[1] == 1:  # a slot moves on by its one row or stands still: a select, not a gather a slot
         tail = jnp.where(valid[:, :, None], window[:, 1:], window[:, :keep])
     else:
-        n = valid.sum(axis=1, dtype=jnp.int32)
-        tail = jax.vmap(lambda w, at: jax.lax.dynamic_slice_in_dim(w, at, keep, axis=0))(window, n)
+        tail = short_conv.next_tail(window, valid.sum(axis=1, dtype=jnp.int32), keep)
     return _kda_output(cfg, p, h, o), S, tail
 
 

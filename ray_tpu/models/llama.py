@@ -899,19 +899,28 @@ def _paged_attention(
             scale=1.0 / math.sqrt(hd), group=rep, window=window or None,
         )
         return o.transpose(1, 0, 2)[None]
-    ks = k_cache[layer, block_tables].reshape(B, M * bs, n_kv, -1)
-    vs = v_cache[layer, block_tables].reshape(B, M * bs, n_kv, -1)
-    qg = q.reshape(B, C, n_kv, rep, -1)
+    return _attend_gathered(q, k_cache, v_cache, layer, block_tables, pos, n_kv, M * bs, window)
+
+
+def _attend_gathered(q, k_cache, v_cache, layer: int, block_tables, pos, n_kv: int, keys: int, window: int = 0):
+    """The fallback of :func:`_paged_attention` (and of ``models/lfm2.py``'s):
+    ``cache[layer, block_tables]`` gathered for every slot as wide as the
+    table (``keys`` positions of ``n_kv`` heads, whatever form a block is
+    stored in) and the softmax materialised, GQA grouped, float32."""
+    B, C, H, hd = q.shape
+    ks = k_cache[layer, block_tables].reshape(B, keys, n_kv, -1)
+    vs = v_cache[layer, block_tables].reshape(B, keys, n_kv, -1)
+    qg = q.reshape(B, C, n_kv, H // n_kv, -1)
     s = jnp.einsum("bcgrh,bsgh->bcgrs", qg, ks).astype(jnp.float32)
     s = s * (1.0 / math.sqrt(hd))
-    key_pos = jnp.arange(M * bs, dtype=jnp.int32)
-    mask = key_pos <= pos[:, :, None]  # [B, C, M*bs]
+    key_pos = jnp.arange(keys, dtype=jnp.int32)
+    mask = key_pos <= pos[:, :, None]  # [B, C, keys]
     if window:
         mask &= key_pos > pos[:, :, None] - window
     s = jnp.where(mask[:, :, None, None, :], s, -1e30)
     pattn = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bcgrs,bsgh->bcgrh", pattn.astype(vs.dtype), vs)
-    return o.reshape(B, C, cfg.n_heads, -1)
+    return o.reshape(B, C, H, -1)
 
 
 def _group_table(block_tables, group: int):

@@ -43,6 +43,15 @@ window layer's from the block that holds its first visible key on, so that
 ``ctx_len`` is the chunk's offset in what it is handed.) Without either
 argument the call lowers to the program it always was.
 
+NARROW heads (``dk`` = ``dv`` = 64: half a lane tile) go through in PAIRS
+(:func:`flash_attention` lays them out, the kernel is the same): two key heads
+side by side are one key head of 128 lanes, ``[Hkv / 2, S, 128]`` (a cache that
+stores a token's heads in one row gathers to that form for free), and a query
+head holds its 64 numbers in its own key head's half of a row of zeros, so
+``q . k`` over the 128 lanes is the head's own product; its output is its half
+of the pair's ``[.., 128]``. Twice the multiplies, every one over whole lanes,
+and ``group`` doubles.
+
 The mathematics is the materialised softmax's to the letter
 (``models/xing4.py::_attend_expanded``): scores float32, times ``scale``,
 masked scores ``-1e30``, probabilities cast to V's dtype for the second
@@ -85,7 +94,8 @@ def tiles(window: int, keys: int) -> tuple:
 
 
 def kernel_serves(
-    window: int, keys: int, dk: int, dv: int, ds: int, dtype, backend: str | None = None
+    window: int, keys: int, dk: int, dv: int, ds: int, dtype, backend: str | None = None,
+    kv_heads: int = 0,
 ) -> bool:
     """Whether :func:`flash_attention` runs the kernel for ``window`` queries
     over ``keys`` key positions at head widths ``dk`` (+ ``ds`` shared, 0 for
@@ -99,11 +109,15 @@ def kernel_serves(
     wide as its array: Mosaic takes it (compiled for the same v5e, at 64
     heads), the array lies in HBM on 256 lanes either way, and nothing is
     padded by hand or written back; ``dv`` is whole lanes or whole lanes and
-    a half. Everything else (the CPU, odd
+    a half. NARROW heads (``dk`` = ``dv`` = 64, no shared part) are served in
+    pairs where the caller says an even number of key heads (``kv_heads``).
+    Everything else (the CPU, odd
     widths) keeps the materialised softmax. Decided at trace time; the model
     runner asks the same question to know what a prefill launch reads."""
     backend = backend or jax.default_backend()
     block_q, block_k = tiles(window, keys)
+    if dk == dv == 64 and ds == 0 and kv_heads and kv_heads % 2 == 0:
+        dk = dv = 128  # a pair of them: what the kernel sees
     return (
         backend == "tpu"
         and dtype in (jnp.bfloat16, jnp.float32)
@@ -296,9 +310,22 @@ def flash_attention(
     default_q, default_k = tiles(q.shape[1], k.shape[1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _call(
+    H, C, d = q.shape
+    paired = d == 64 and v.shape[2] == 64 and q_shared is None and k.shape[0] % 2 == 0
+    if paired:
+        # two key heads side by side are one of 128 lanes; a query head's
+        # numbers in its own key head's half of a row of zeros
+        pairs, S = k.shape[0] // 2, k.shape[1]
+        k, v = (a.reshape(pairs, 2, S, d).transpose(0, 2, 1, 3).reshape(pairs, S, 2 * d) for a in (k, v))
+        own = (jnp.arange(H)[:, None] // group) % 2 == jnp.arange(2)[None]  # [H, 2]
+        q = jnp.where(own[:, None, :, None], q[:, :, None, :], 0).reshape(H, C, 2 * d)
+        group *= 2
+    out = _call(
         q, k, v, q_shared, k_shared,
         jnp.asarray(ctx_len, jnp.int32), jnp.asarray(true_len, jnp.int32),
         scale=float(scale), block_q=block_q or default_q, block_k=block_k or default_k,
         interpret=bool(interpret), group=int(group), window=int(window or 0),
     )
+    if paired:  # a head's output: its own half of the pair's
+        out = jnp.where(own[:, None, :, None], out.reshape(H, C, 2, d), 0).sum(axis=2)
+    return out

@@ -44,6 +44,19 @@ block, and are never touched. Rows of the first wave that lie behind a
 query's window are masked like those past it. Without ``keeps`` the call
 lowers to the program it always was.
 
+NARROW heads (``hd`` 64: half a lane tile) are served with a token's heads
+side by side in ONE row, ``[n_layers, num_blocks, bs, n_kv * hd]`` (``n_kv * hd``
+whole lanes: a block of 16 tokens of 8 heads is ``[16, 512]``, four whole lane
+tiles a token, nothing padded; ``CacheLayout.flat_blocks``). The kernel is
+then told ONE key head as wide as the row, and the caller's queries are laid
+out to match: head ``h`` of KV head ``g`` holds its 64 numbers in lanes ``g *
+hd ..`` of a row of zeros, so ``q . k`` over the whole row is the head's own
+product and every product runs over whole lanes. The scores are ``[rows,
+tokens]`` (no column of another head to mask), the accumulator a row wide, and
+a head's output its own lanes of it. That multiplies ``n_kv`` times what the
+mathematics needs, the same as a wave of parted heads above, with an ``n_kv``-th
+of its softmax work. The caller says ``n_kv`` and the queries say ``hd``.
+
 The layer is an operand, not a constant of the kernel, and the call is
 jitted by itself: the model's 16 calls are one traced and lowered kernel,
 which is what keeps a decode program's start-up at the gather's.
@@ -74,7 +87,8 @@ _WAVE_ROWS = 2048
 
 
 def kernel_serves(
-    window: int, n_heads: int, k_cache, backend: str | None = None, n_kv: int | None = None
+    window: int, n_heads: int, k_cache, backend: str | None = None, n_kv: int | None = None,
+    head_dim: int | None = None,
 ) -> bool:
     """Whether :func:`paged_attention` runs the kernel for a query window of
     ``window`` positions a slot over ``k_cache`` (anything with the shape and
@@ -85,11 +99,17 @@ def kernel_serves(
     whole ``(8, 128)`` tiles (``n_kv`` 8, 16: probed for a described v5e,
     PERF.md PR 30), or FLAT, ``[n_layers, num_blocks, bs * n_kv, hd]`` with
     ``n_kv`` said beside it (``n_kv`` 4, eight query heads a KV head: run
-    against the gather on the chip, PERF.md PR 44). Everything else (the CPU,
-    a prefill chunk, odd widths) takes the gather. Decided at trace time; the
+    against the gather on the chip, PERF.md PR 44), or, for NARROW heads said
+    beside it (``head_dim`` 64 with ``n_kv`` 8), a token's heads in one row of
+    whole lanes, ``[n_layers, num_blocks, bs, n_kv * head_dim]`` (PERF.md PR 49).
+    Everything else (the CPU, a prefill chunk, odd widths) takes the gather. Decided at trace time; the
     model runner asks the same question to know what a launch reads."""
     backend = backend or jax.default_backend()
-    if len(k_cache.shape) == 5:
+    if head_dim and n_kv and len(k_cache.shape) == 4 and k_cache.shape[3] == n_kv * head_dim != head_dim:
+        # heads in lanes: to the kernel ONE key head as wide as the row
+        _, _, bs, hd = k_cache.shape
+        whole_heads, n_kv = n_heads % n_kv == 0, 1
+    elif len(k_cache.shape) == 5:
         _, _, bs, n_kv, hd = k_cache.shape
         whole_heads = n_kv % 8 == 0
     else:
@@ -120,6 +140,7 @@ def _kernel(
     table_width: int,
     n_kv: int,
     keeps: int,
+    scale: float,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -133,7 +154,6 @@ def _kernel(
     H = rows // C
     rep = H // n_kv
     R = P * bs * n_kv
-    scale = 1.0 / math.sqrt(hd)
     layer = layer_ref[0]
 
     def from_first(b, blocks):
@@ -240,10 +260,10 @@ def _kernel(
     jax.lax.fori_loop(0, B, slot, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("wave_blocks", "interpret", "n_kv", "keeps"))
+@functools.partial(jax.jit, static_argnames=("wave_blocks", "interpret", "n_kv", "keeps", "scale"))
 def _call(
     q, k_cache, v_cache, layer, block_tables, pos, *, wave_blocks: int, interpret: bool,
-    n_kv: int, keeps: int,
+    n_kv: int, keeps: int, scale: float,
 ):
     # imported here, as ops/attention.py does: a second of import that only a
     # process which runs the kernel pays (the model module is imported by all)
@@ -268,7 +288,7 @@ def _call(
     any_space = pl.BlockSpec(memory_space=pl.ANY)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        functools.partial(_kernel, window=C, table_width=M, n_kv=n_kv, keeps=keeps),
+        functools.partial(_kernel, window=C, table_width=M, n_kv=n_kv, keeps=keeps, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5 + len(first),
             grid=(),
@@ -311,7 +331,10 @@ def paged_attention(
     (``block_tables[b, 0] == 0``) reads none and returns zeros.
 
     ``n_kv``: the KV heads of a cache stored flat, ``[n_layers, num_blocks, bs
-    * n_kv, hd]`` (a 5-D cache says it by its shape). ``keeps``: ``W`` for a
+    * n_kv, hd]`` (a 5-D cache says it by its shape), or, where the cache's rows
+    are ``n_kv`` times as wide as the queries' heads, with a token's heads in
+    one row, ``[n_layers, num_blocks, bs, n_kv * hd]`` (narrow heads: the
+    module's docstring). ``keeps``: ``W`` for a
     layer that keeps a window (query at ``i`` sees ``j`` iff ``i - W < j <=
     i``); a slot then reads from the block that holds ``min_c pos[b, c] - W +
     1`` on, and is a padding slot if THAT entry of its table is the null block.
@@ -321,12 +344,26 @@ def paged_attention(
     tests do); by default wherever the backend is not a TPU."""
     if k_cache.ndim == 5:
         n_kv = k_cache.shape[3]
+    hd = q.shape[-1]
+    in_lanes = k_cache.ndim == 4 and k_cache.shape[-1] == n_kv * hd != hd
+    if in_lanes:
+        # head h's numbers in the lanes of its KV head, zeros in the others';
+        # to the kernel ONE key head as wide as the row
+        H = q.shape[2]
+        own = jnp.arange(H)[:, None] // (H // n_kv) == jnp.arange(n_kv)[None]  # [H, n_kv]
+        q = jnp.where(own[:, :, None], q[..., None, :], 0).reshape(*q.shape[:-1], n_kv * hd)
+        heads, n_kv = n_kv, 1
     rows = math.prod(k_cache.shape[2:-1])  # bs * n_kv
     M = block_tables.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _call(
+    # as many bytes a wave whichever way the heads lie
+    wave_rows = _WAVE_ROWS * 128 // k_cache.shape[-1] if in_lanes else _WAVE_ROWS
+    out = _call(
         q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), block_tables, pos,
-        wave_blocks=wave_blocks or min(M, max(1, _WAVE_ROWS // rows)),
-        interpret=bool(interpret), n_kv=int(n_kv), keeps=int(keeps),
+        wave_blocks=wave_blocks or min(M, max(1, wave_rows // rows)),
+        interpret=bool(interpret), n_kv=int(n_kv), keeps=int(keeps), scale=1.0 / math.sqrt(hd),
     )
+    if in_lanes:  # a head's output: its own lanes of the row
+        out = jnp.where(own[:, :, None], out.reshape(*out.shape[:-1], heads, hd), 0).sum(axis=-2)
+    return out

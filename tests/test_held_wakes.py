@@ -12,6 +12,7 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from ray_tpu.inference import engine as engine_module  # noqa: E402
 from ray_tpu.inference.engine import (  # noqa: E402
     _END,
     EngineConfig,
@@ -99,9 +100,9 @@ def _record(eng, rids, log):
     each put into a request's queue."""
     hold, phase = eng._hold, eng._clock.phase
 
-    def logged_hold(q, item):
+    def logged_hold(q, item, now=False):
         log.append(("hold", id(q), item))
-        hold(q, item)
+        hold(q, item, now)
 
     def logged_phase(name, **args):
         log.append(("launch",) if "program" in args else ("phase", name))
@@ -160,6 +161,51 @@ def test_items_of_one_launch_are_woken_after_the_next_launch_returns(cfg, params
                 assert opened[-2:] == [("phase", "schedule"), ("phase", "emit")]
     assert after_launch == w["after_launch"] > 0
     assert pairs == w["items"] == 6 * 7 and w["direct"] == 0
+
+
+def test_a_step_wakes_so_many_streams_and_each_gets_all_it_has(cfg, params, monkeypatch):
+    """More streams than a step may wake: the longest-held go first and take
+    every token they have (one wake-up for several), a first token and an end
+    go out with the next launch whatever the count, no stream is passed over
+    twice in a row, and every stream reads what it read uncapped."""
+    direct = _engine(cfg, params)
+    rids = _submit_all(direct, new_tokens=12)
+    while direct.scheduler.has_work():
+        direct.step()
+    want = [_drain(direct, r, timeout=1) for r in rids]
+
+    monkeypatch.setattr(engine_module, "_WAKES_PER_STEP", 2)
+    eng = _engine(cfg, params)
+    rids = _submit_all(eng, new_tokens=12)
+    log = []
+    _record(eng, rids, log)
+    steps, bounds = [], [0]  # a step: the items put to each queue in it, in order; and where it ends in the log
+    while eng.scheduler.has_work():
+        assert eng.step(hold_wakes=True)
+        puts = {}
+        for entry in log[bounds[-1]:]:
+            if entry[0] == "put":
+                puts.setdefault(entry[1], []).append(entry[2])
+        steps.append(puts)
+        bounds.append(len(log))
+    assert eng._held and not eng.step(hold_wakes=True) and not eng._held  # the idle step: all that is left
+    assert [_drain(eng, r, timeout=1) for r in rids] == want
+    w = _wakes_add_up(eng)
+    assert w["items"] == 6 * 13 and w["direct"] == 0 and w["after_launch"] > 0
+    started, bursts = set(), 0
+    for puts in steps:
+        urgent = {q for q, items in puts.items() if q not in started or items[-1] is _END}
+        assert len(puts) <= 2 + len(urgent)  # an end committed after the step's two went out goes out too
+        bursts += sum(len(items) > 1 for q, items in puts.items() if q not in urgent)
+        started |= set(puts)
+    assert bursts > 0
+    step_of = lambda i: next(n for n, end in enumerate(bounds[1:] + [len(log)]) if i < end)  # noqa: E731
+    for q in started:
+        holds = [i for i, e in enumerate(log) if e[:2] == ("hold", q)]
+        puts = [i for i, e in enumerate(log) if e[:2] == ("put", q)]
+        assert [log[i][2] for i in holds] == [log[i][2] for i in puts]
+        # 4 decode at once, 2 a step: the step after the next one, and one more where first tokens or ends went first
+        assert max(step_of(p) - step_of(h) for h, p in zip(holds, puts)) <= 3
 
 
 def _until(cond, timeout=30.0):

@@ -75,17 +75,36 @@ def test_the_kernel_is_the_materialised_softmax(monkeypatch, ctx, true_len, C, d
     assert (have[first_padding_tile:] == 0).all()
 
 
-def test_the_kernel_without_a_shared_key_part():
+@pytest.mark.parametrize(
+    "H, group, d, ctx_len, true_len",
+    [(2, 1, 16, 20, 30), (8, 4, 64, 20, 30), (8, 4, 64, 0, 1), (8, 4, 64, 32, 32), (4, 1, 64, 17, 9)],
+    ids=["heads_of_16", "heads_of_64_in_pairs", "pairs_one_real_row", "pairs_the_table_full", "pairs_ungrouped"],
+)
+def test_the_kernel_without_a_shared_key_part(H, group, d, ctx_len, true_len):
     """Keys that are a head's own alone (``k_shared`` None): the same kernel,
-    one product a score."""
+    one product a score. Heads of 64 go through in PAIRS (two key heads side
+    by side in one row of 128 lanes, a query head in its own half of a row of
+    zeros): the numbers of the grouped softmax a head, and nothing planted
+    past the live context reaches them."""
     rng = np.random.default_rng(1)
-    H, C, S, d = 2, 32, 64, 16
-    q, k, v = (jnp.asarray(rng.standard_normal(s), jnp.float32) for s in ((H, C, d), (H, S, d), (H, S, d)))
-    have = latent_flash.flash_attention(q, k, v, 20, 30, scale=0.25, block_q=16, block_k=16)
-    s = jnp.einsum("hck,hsk->hcs", q, k) * 0.25
-    see = jnp.arange(S)[None, :] <= jnp.minimum(20 + jnp.arange(C), 49)[:, None]
-    want = jnp.einsum("hcs,hsk->hck", jax.nn.softmax(jnp.where(see, s, -1e30), axis=-1), v)
-    np.testing.assert_allclose(np.asarray(have), np.asarray(want), atol=2e-5)
+    C, S = 32, 64
+    q, k, v = (
+        jnp.asarray(rng.standard_normal(s), jnp.float32)
+        for s in ((H, C, d), (H // group, S, d), (H // group, S, d))
+    )
+    live = ctx_len + true_len
+    have = latent_flash.flash_attention(
+        q, k.at[:, live:].set(jnp.nan), v.at[:, live:].set(jnp.nan), ctx_len, true_len,
+        scale=0.25, block_q=16, block_k=16, group=group,
+    )
+    s = jnp.einsum("hck,hsk->hcs", q, jnp.repeat(k, group, axis=0)) * 0.25
+    see = jnp.arange(S)[None, :] <= jnp.minimum(ctx_len + jnp.arange(C), live - 1)[:, None]
+    want = jnp.einsum(
+        "hcs,hsk->hck", jax.nn.softmax(jnp.where(see, s, -1e30), axis=-1), jnp.repeat(v, group, axis=0)
+    )
+    assert have.shape == want.shape == (H, C, d)
+    np.testing.assert_allclose(np.asarray(have)[:, :true_len], np.asarray(want)[:, :true_len], atol=2e-5)
+    assert np.isfinite(np.asarray(have)).all()
 
 
 def _latent_cache(cfg, dtype=jnp.bfloat16, block_size=16, num_blocks=8):
@@ -106,6 +125,11 @@ def _latent_cache(cfg, dtype=jnp.bfloat16, block_size=16, num_blocks=8):
         (dict(keys=512), True),  # a table shorter than a key tile is one tile
         (dict(dk=96), False),
         (dict(dv=64), False),
+        (dict(dk=64, dv=64, ds=0), False),  # narrow heads: in pairs alone
+        (dict(dk=64, dv=64, ds=0, kv_heads=8), True),  # LFM2's chunk
+        (dict(dk=64, dv=64, ds=0, kv_heads=7), False),
+        (dict(dk=64, dv=64, ds=64, kv_heads=8), False),
+        (dict(dk=64, dv=64, ds=0, kv_heads=8, backend="cpu"), False),
         (dict(ds=32), False),
         (dict(ds=0), True),
         (dict(dtype=jnp.float32), True),

@@ -515,6 +515,46 @@ def test_the_latent_flash_kernel_compiles_for_the_chip_at_xing4_widths(one_chip,
         jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
+@pytest.mark.parametrize("window", [1, 1024, 256], ids=["decode_128_slots", "chunk_1024", "chunk_256"])
+def test_both_attention_kernels_compile_for_the_chip_at_heads_of_64(one_chip, window):
+    """LFM2-8B-A1B's attention (32 query heads of 64 over 8 KV heads, a cache
+    of ``[16, 512]`` blocks: a token's heads in one row) through the two
+    kernels that were there, at the benchmark's sizes: the decode kernel with
+    the heads in LANES (128 slots, the full-width table, the whole cache as it
+    lies), the chunk's flash kernel with the heads in PAIRS (a table of 8192).
+    One Mosaic call each; neither the cache nor the scores is a temporary."""
+    from ray_tpu.ops import latent_flash as LF
+    from ray_tpu.ops import paged_attention as PA
+
+    L, N, B, H, KV, hd, S = 6, 30160, 128, 32, 8, 64, 8192
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        if window == 1:
+            cache = shape((L, N, 16, KV * hd))
+            assert PA.kernel_serves(1, H, cache, backend="tpu", n_kv=KV, head_dim=hd)
+            compiled = jax.jit(
+                lambda q, k, v, tables, pos: PA.paged_attention(q, k, v, L - 1, tables, pos, interpret=False, n_kv=KV)
+            ).lower(shape((B, 1, H, hd)), cache, cache, shape((B, S // 16), jnp.int32), shape((B, 1), jnp.int32)).compile()
+            name, out = "paged_attn", (B, 1, H, hd)
+            temporaries = 4 * B * H * KV * hd * 2  # the queries and the outputs laid out in lanes
+        else:
+            assert LF.kernel_serves(window, S, hd, hd, 0, jnp.bfloat16, backend="tpu", kv_heads=KV)
+            compiled = jax.jit(
+                lambda q, k, v, ctx, n: LF.flash_attention(q, k, v, ctx, n, scale=hd ** -0.5, group=H // KV, interpret=False)
+            ).lower(shape((H, window, hd)), shape((KV, S, hd)), shape((KV, S, hd)), shape((), jnp.int32),
+                    shape((), jnp.int32)).compile()
+            name, out = "latent_flash", (H, window, hd)
+            temporaries = 2 * KV * S * hd * 2 + 4 * H * window * 2 * hd * 2  # K and V in pairs; q and o in halves
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and name in text
+        assert compiled.memory_analysis().temp_size_in_bytes <= temporaries + 2**20
+        assert compiled.out_info.shape == out
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
 @pytest.mark.parametrize("window", [1024, 256], ids=["chunk_1024", "chunk_256"])
 def test_the_latent_flash_kernel_takes_a_192_wide_value_at_gigachat_widths(one_chip, window):
     """GigaChat3.1's prefill chunk through the same kernel: 64 heads, keys

@@ -10,7 +10,11 @@ poison too. One read past the mask and the output is not finite. Block
 tables are a shuffle of the pool; the last two slots of every batch are
 padding on the null block: the kernel reads nothing for them and returns
 zeros (the gather attends to the null block's trash there; nobody reads
-either)."""
+either).
+
+NARROW heads (``head_dim`` 64) run the same cases with a token's heads side by
+side in one row of the cache the kernel is handed, ``[layers, blocks, bs, n_kv x
+64]`` (the same bytes as the gather's ``[layers, blocks, bs, n_kv, 64]``)."""
 
 import dataclasses
 
@@ -37,7 +41,7 @@ CONTEXTS = {
 }
 
 
-def _case(rep, window, contexts, seed=0, dtype=jnp.float32, step=1):
+def _case(rep, window, contexts, seed=0, dtype=jnp.float32, step=1, hd=HD):
     """``(cfg, q, clean cache, poisoned cache, tables, pos)``: the window's
     rows sit at ``context - 1 + step * c`` (clipped to the table), so the
     last row sees the most."""
@@ -46,7 +50,7 @@ def _case(rep, window, contexts, seed=0, dtype=jnp.float32, step=1):
     contexts = (*contexts, 0, 0)
     B = len(contexts)
     N = 1 + B * M
-    k, v = rng.standard_normal((2, LAYERS, N, BS, N_KV, HD)).astype(np.float32)
+    k, v = rng.standard_normal((2, LAYERS, N, BS, N_KV, hd)).astype(np.float32)
     shuffled = rng.permutation(np.arange(1, N))
     tables = np.zeros((B, M), np.int32)
     pos = np.zeros((B, window), np.int32)
@@ -60,8 +64,8 @@ def _case(rep, window, contexts, seed=0, dtype=jnp.float32, step=1):
             live[tables[b, p // BS], p % BS] = True
     kp, vp = k.copy(), v.copy()
     kp[:, ~live], vp[:, ~live] = np.nan, np.inf
-    q = rng.standard_normal((B, window, H, HD)).astype(np.float32)
-    cfg = dataclasses.replace(L.LlamaConfig.tiny(), n_heads=H, n_kv_heads=N_KV, dim=H * HD)
+    q = rng.standard_normal((B, window, H, hd)).astype(np.float32)
+    cfg = dataclasses.replace(L.LlamaConfig.tiny(), n_heads=H, n_kv_heads=N_KV, dim=H * hd)
     as_cache = lambda k_, v_: {"k": jnp.asarray(k_, dtype), "v": jnp.asarray(v_, dtype)}  # noqa: E731
     return cfg, jnp.asarray(q, dtype), as_cache(k, v), as_cache(kp, vp), jnp.asarray(tables), jnp.asarray(pos)
 
@@ -69,6 +73,9 @@ def _case(rep, window, contexts, seed=0, dtype=jnp.float32, step=1):
 def _both(case, **kw):
     cfg, q, clean, poisoned, tables, pos = case
     want = L._paged_attention(cfg, q, clean, LAYER, tables, pos)  # the CPU: the gather
+    if q.shape[-1] < 128:  # narrow heads: a token's heads in one row, said beside it
+        poisoned = {name: a.reshape(*a.shape[:3], -1) for name, a in poisoned.items()}
+        kw["n_kv"] = N_KV
     have = PA.paged_attention(
         q, poisoned["k"], poisoned["v"], LAYER, tables, pos, interpret=True, **kw
     )
@@ -81,19 +88,21 @@ def _both(case, **kw):
 @pytest.mark.parametrize("contexts", list(CONTEXTS))
 @pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify_window_of_4"])
 @pytest.mark.parametrize("rep", [4, 1], ids=["rep4", "rep1"])
-def test_kernel_is_the_gather_and_reads_nothing_past_the_mask(rep, window, contexts):
+@pytest.mark.parametrize("hd", [HD, 64], ids=["heads_of_128", "heads_of_64_in_lanes"])
+def test_kernel_is_the_gather_and_reads_nothing_past_the_mask(hd, rep, window, contexts):
     """Waves of 2 blocks, so a full table is 4 waves and a ragged batch ends
     each slot's loop somewhere else."""
-    have, want = _both(_case(rep, window, CONTEXTS[contexts]), wave_blocks=2)
+    have, want = _both(_case(rep, window, CONTEXTS[contexts], hd=hd), wave_blocks=2)
     assert np.isfinite(have).all()
     np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("wave_blocks", [1, 3, None], ids=["a_block_a_wave", "3_blocks", "the_default_wave"])
-def test_any_wave_size_gives_the_same_numbers(wave_blocks):
+@pytest.mark.parametrize("hd", [HD, 64], ids=["heads_of_128", "heads_of_64_in_lanes"])
+def test_any_wave_size_gives_the_same_numbers(hd, wave_blocks):
     """3 does not divide the table's 8 blocks; the default wave (2048 rows of
     K, cut to the table) is the whole table."""
-    have, want = _both(_case(4, 1, CONTEXTS["ragged"], seed=1), wave_blocks=wave_blocks)
+    have, want = _both(_case(4, 1, CONTEXTS["ragged"], seed=1, hd=hd), wave_blocks=wave_blocks)
     assert np.isfinite(have).all()
     np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
 
@@ -109,11 +118,12 @@ def test_each_row_of_a_window_masks_on_its_own_position():
     assert np.abs(np.asarray(blind)[:3] - want)[:, 0].max() > 1e-2
 
 
-def test_bfloat16_cache_accumulates_in_float32():
+@pytest.mark.parametrize("hd", [HD, 64], ids=["heads_of_128", "heads_of_64_in_lanes"])
+def test_bfloat16_cache_accumulates_in_float32(hd):
     """The serving dtype. The gather rounds its scores to bfloat16 out of the
     first matmul, the kernel keeps them float32: they agree to bfloat16's
     step, and the kernel is the nearer of the two to the float32 answer."""
-    case16 = _case(4, 1, CONTEXTS["ragged"], seed=3, dtype=jnp.bfloat16)
+    case16 = _case(4, 1, CONTEXTS["ragged"], seed=3, dtype=jnp.bfloat16, hd=hd)
     have, want = _both(case16, wave_blocks=2)
     assert np.isfinite(have).all()
     np.testing.assert_allclose(have, want, rtol=0, atol=3e-2)
@@ -128,6 +138,13 @@ def test_bfloat16_cache_accumulates_in_float32():
 @pytest.mark.parametrize(
     "backend, window, n_heads, cache_shape, dtype, serves",
     [
+        # narrow heads, a token's 8 heads of 64 in one row, said beside the cache: LFM2 decode
+        ("tpu", 1, 32, ((6, 27000, 16, 512), {"n_kv": 8, "head_dim": 64}), jnp.bfloat16, True),
+        ("tpu", 1024, 32, ((6, 27000, 16, 512), {"n_kv": 8, "head_dim": 64}), jnp.bfloat16, False),  # its chunk
+        ("cpu", 1, 32, ((6, 27000, 16, 512), {"n_kv": 8, "head_dim": 64}), jnp.bfloat16, False),
+        ("tpu", 1, 4, ((2, 24, 8, 32), {"n_kv": 2, "head_dim": 16}), jnp.bfloat16, False),  # a row that is no whole lanes
+        # the same array WITHOUT the head's width is heads of 512 joined to the tokens, as ever
+        ("tpu", 1, 32, ((6, 27000, 16, 512), {"n_kv": 8}), jnp.bfloat16, True),
         ("tpu", 1, 32, (16, 6144, 16, 8, 128), jnp.bfloat16, True),  # Mistral decode
         ("tpu", 8, 32, (16, 6144, 16, 8, 128), jnp.bfloat16, True),  # a verify window
         ("tpu", 1, 16, (12, 2240, 16, 16, 128), jnp.bfloat16, True),  # OLMoE decode
@@ -142,5 +159,6 @@ def test_bfloat16_cache_accumulates_in_float32():
     ],
 )
 def test_the_kernel_serves_short_windows_of_whole_tiles_on_a_tpu(backend, window, n_heads, cache_shape, dtype, serves):
+    cache_shape, said = cache_shape if isinstance(cache_shape[1], dict) else (cache_shape, {})
     cache_like = jax.ShapeDtypeStruct(cache_shape, dtype)
-    assert PA.kernel_serves(window, n_heads, cache_like, backend=backend) is serves
+    assert PA.kernel_serves(window, n_heads, cache_like, backend=backend, **said) is serves
