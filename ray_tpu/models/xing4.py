@@ -21,9 +21,14 @@ norm, acts on the residual state ``X [n, D]`` of a token (n = ``hc_mult``):
     X      = H_res X + H_post^T (x) F(norm(H_pre X))
 
 maps and Sinkhorn-Knopp (rows, then columns, each divided by its sum +
-``hc_eps``, ``hc_sinkhorn_iters`` rounds) in float32. The state is the
-embedding repeated n times at the start and the n streams summed before the
-final norm.
+``hc_eps``, ``hc_sinkhorn_iters`` rounds) in float32, with the TOKENS on the
+minor axis: a map's entry is one vector over the tokens, the rounds are
+``ops/mhc.py`` (one Pallas kernel on a TPU, ``lax.fori_loop`` elsewhere).
+What is float32 is the maps, their ``n^2`` vectors and the accumulators of the
+product and the mixes; never a whole state: a bfloat16 state meets ``phi`` as
+it lies (``phi`` in three bfloat16 pieces, every partial product exact), and
+``rms``'s scalar a token scales the product. The state is the embedding
+repeated n times at the start and the n streams summed before the final norm.
 
 Attention: ``c_q = rms(h W_qa)``; ``[q_nope | q_rope] = c_q W_qb`` a head;
 ``[c | k_rope] = h W_kva``, ``c = rms(c)``; ``[k_nope | v] = c W_kvb`` a
@@ -63,7 +68,7 @@ from ray_tpu.models.interface import AttentionPath, Model
 # dimensions: ``models/latent.py`` has them, for this module and ``models/
 # kimi_linear.py``
 from ray_tpu.models.latent import absorbs, cache_layout
-from ray_tpu.ops import latent_flash
+from ray_tpu.ops import latent_flash, mhc
 from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
 from ray_tpu.parallel.sharding import constrain
 
@@ -441,33 +446,68 @@ def _ffn(cfg: Xing4Config, p, h, valid, moe: bool, at=None):
     return shared + routed.reshape(h.shape), aux
 
 
+def _bf16_pieces(w):
+    """A float32 array as the sum of three bfloat16 ones (8 + 8 + 8 bits of
+    mantissa), side by side on the last axis: ``[..., 3 m]``. Rounded by
+    ``reduce_precision``, which the compiler keeps: a ``convert`` to bfloat16
+    and back inside one fusion is computed in the registers' float32 on a TPU
+    (excess precision), the remainder then reads 0 and the second and third
+    pieces are lost (on the chip, PR 50: the maps off by 2e-3)."""
+    def rounded(a):
+        return jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+
+    hi = rounded(w)
+    mid = rounded(w - hi)
+    lo = rounded(w - hi - mid)
+    return jnp.concatenate([hi, mid, lo], axis=-1).astype(jnp.bfloat16)
+
+
+def _state_dot(x, phi):
+    """``sum_{j, d} x[t, j, d] phi[j, d, m]`` as ``[maps, T]`` float32, to the
+    accuracy of float32 operands at the highest precision. On a TPU a
+    bfloat16 state is never converted: it is exact in bfloat16, so the float32
+    ``phi`` goes in as its three bfloat16 pieces and ONE product with float32
+    accumulation reads the state as it lies (every partial product is exact:
+    what ``HIGHEST`` computes from a bfloat16 operand). Elsewhere (the CPU
+    has no such product) and for any other dtype: float32 operands. The
+    product is taken ``[T, .]`` and turned: XLA lays it tokens-minor itself,
+    where asked for as ``[., T]`` it copied the whole state first."""
+    if x.dtype != jnp.bfloat16 or jax.default_backend() != "tpu":
+        return jnp.einsum("tjd,jdm->tm", x.astype(F32), phi, precision=_HIGHEST).T
+    z = jnp.einsum("tjd,jdm->tm", x, _bf16_pieces(phi), preferred_element_type=F32).T
+    maps = phi.shape[-1]
+    return z[:maps] + (z[maps : 2 * maps] + z[2 * maps :])
+
+
 def mhc_maps(cfg: Xing4Config, phi, b, alpha, X):
     """The three maps of one sublayer from the residual state ``X [..., n,
     D]``: ``(H_pre [..., n], H_post [..., n], H_res [..., n, n])`` float32,
-    ``H_res`` doubly stochastic by Sinkhorn-Knopp."""
-    n = cfg.hc_mult
+    ``H_res`` doubly stochastic by Sinkhorn-Knopp. Computed with the TOKENS
+    on the minor axis (``[maps, T]``: a layout the vector unit fills), the
+    rounds in ``ops/mhc.py``; ``rsqrt(mean x^2)``, a scalar a token, scales
+    the product and not the state, so no normalised copy of the state exists."""
+    n, lead = cfg.hc_mult, X.shape[:-2]
     with jax.named_scope("mhc.maps"):
-        x = X.reshape(*X.shape[:-2], -1).astype(F32)
-        xbar = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + cfg.norm_eps)
-        z = jnp.dot(xbar, phi.astype(F32), precision=_HIGHEST)
-        pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b[:n])
-        post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n : 2 * n] + b[n : 2 * n])
-        res = (alpha[2] * z[..., 2 * n :] + b[2 * n :]).reshape(*z.shape[:-1], n, n)
+        x = X.reshape(-1, *X.shape[-2:])
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x.astype(F32)), axis=(-2, -1)) + cfg.norm_eps)
+        z = _state_dot(x, phi.astype(F32).reshape(*x.shape[1:], -1)) * inv
+        pre = jax.nn.sigmoid(alpha[0] * z[:n] + b[:n, None])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * z[n : 2 * n] + b[n : 2 * n, None])
+        res = alpha[2] * z[2 * n :] + b[2 * n :, None]
     with jax.named_scope("mhc.sinkhorn"):
-        m = jnp.exp(jnp.clip(res, -cfg.hc_res_clamp, cfg.hc_res_clamp))
-        for _ in range(cfg.hc_sinkhorn_iters):
-            m = m / (m.sum(axis=-1, keepdims=True) + cfg.hc_eps)  # rows
-            m = m / (m.sum(axis=-2, keepdims=True) + cfg.hc_eps)  # then columns
-    return pre, post, m
+        res = mhc.sinkhorn(res, iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
+    return pre.T.reshape(*lead, n), post.T.reshape(*lead, n), res.T.reshape(*lead, n, n)
 
 
 def _hyper(cfg: Xing4Config, p, sub: str, norm: str, X, F: Callable):
     """One sublayer through the hyper-connected residual: ``X <- H_res X +
     H_post^T (x) F(norm(H_pre X))``. ``F`` returns ``(y [..., D], extra)``;
-    returns ``(X, extra)``. The maps and the two mixes are float32 at the
-    matmul's highest precision; the state is kept in ``cfg.dtype``. A
-    configuration without streams (``hc_mult`` 0) has the plain residual, ``X
-    [..., D]``: ``X + F(norm(X))``."""
+    returns ``(X, extra)``. What is float32: the maps, and inside the two
+    mixes' fusions the coefficients and the accumulators (the matmul's highest
+    precision); the state is kept in ``cfg.dtype``, each mix reads it as it
+    lies, and no float32 image of it is written. A configuration without
+    streams (``hc_mult`` 0) has the plain residual, ``X [..., D]``: ``X +
+    F(norm(X))``."""
     if not cfg.hc_mult:
         y, extra = F(rms_norm(X, p[norm], cfg.norm_eps))
         return X + y.astype(X.dtype), extra

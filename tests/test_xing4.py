@@ -9,7 +9,9 @@ layouts in ``test_inference.py`` / ``test_kv_tier.py``)."""
 import dataclasses
 import math
 import os
+import re
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -29,6 +31,7 @@ from perfbench.families.xing4 import reference  # noqa: E402
 from ray_tpu.models import latent, xing4  # noqa: E402
 from ray_tpu.models.interface import model_of  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.ops import mhc as mhc_ops  # noqa: E402
 from ray_tpu.ops import moe as moe_ops  # noqa: E402
 
 CONFIG = "xing4.0-29b-a4b-ep8"
@@ -243,6 +246,223 @@ def test_the_residual_is_x_plus_f_of_x_when_the_maps_are_forced(cfg):
     want0 = x[..., 0, :] + 3.0 * np.tanh(x[..., 0, :] / np.sqrt((x[..., 0, :] ** 2).mean(-1, keepdims=True) + cfg.norm_eps) * norm)
     np.testing.assert_allclose(np.asarray(out)[..., 0, :], want0, atol=1e-5)
     np.testing.assert_allclose(np.asarray(out)[..., 1:, :], x[..., 1:, :], atol=1e-5)
+
+
+def _seeded_sublayer(n, D, seed):
+    """One sublayer's maps as ``init_params`` draws them, and a norm vector."""
+    rng = np.random.default_rng(seed)
+    maps = 2 * n + n * n
+    phi = rng.standard_normal((n * D, maps)) / np.sqrt(n * D)
+    phi[:, 2 * n:] *= 0.25
+    b = rng.standard_normal(maps)
+    b[2 * n:] = 0.2 * b[2 * n:] + np.eye(n).reshape(-1)
+    p = {"hc_attn_phi": phi, "hc_attn_b": b, "hc_attn_alpha": rng.uniform(0.5, 1.5, 3),
+         "attn_norm": rng.uniform(0.5, 1.5, D)}
+    return {k: jnp.asarray(v, jnp.float32) for k, v in p.items()}
+
+
+def _numpy_hyper(c, p, X, iters):
+    """The sublayer around ``F`` = the identity in plain float64 NumPy, written
+    from the module's docstring: ``(H_pre, H_post, H_res, X_out)``."""
+    n = c.hc_mult
+    p = {k: np.asarray(v, np.float64) for k, v in p.items()}
+    X = np.asarray(X, np.float64)
+    x = X.reshape(*X.shape[:-2], -1)
+    xbar = x / np.sqrt((x * x).mean(-1, keepdims=True) + c.norm_eps)
+    z = xbar @ p["hc_attn_phi"]
+    a, b = p["hc_attn_alpha"], p["hc_attn_b"]
+    sigmoid = lambda v: 1.0 / (1.0 + np.exp(-v))  # noqa: E731
+    pre = sigmoid(a[0] * z[..., :n] + b[:n])
+    post = 2.0 * sigmoid(a[1] * z[..., n:2 * n] + b[n:2 * n])
+    res = np.exp(np.clip(a[2] * z[..., 2 * n:] + b[2 * n:], -c.hc_res_clamp, c.hc_res_clamp))
+    res = res.reshape(*z.shape[:-1], n, n)
+    for _ in range(iters):
+        res = res / (res.sum(-1, keepdims=True) + c.hc_eps)
+        res = res / (res.sum(-2, keepdims=True) + c.hc_eps)
+    h = (pre[..., None] * X).sum(-2)
+    y = h / np.sqrt((h * h).mean(-1, keepdims=True) + c.norm_eps) * p["attn_norm"]
+    out = np.einsum("...ij,...jd->...id", res, X) + post[..., None] * y[..., None, :]
+    return pre, post, res, out
+
+
+@pytest.fixture(params=["jnp", "kernel"])
+def sinkhorn_body(request, monkeypatch):
+    """Both bodies of ``ops/mhc.py::sinkhorn``: ``lax.fori_loop`` over ``[T]``
+    arrays (what the CPU and ``forward`` run) and the Pallas kernel as a TPU
+    takes it, here in Pallas' TPU interpreter."""
+    if request.param == "kernel":
+        monkeypatch.setattr(mhc_ops, "kernel_serves", lambda logits, backend=None: True)
+    return request.param
+
+
+MHC_TOL = 2e-5
+#: bytes a compiled sublayer of a 1024-token chunk may access by XLA's count
+#: (618 MB before PR 50, 329 after it: of those 88 are ONE prefetch of the 29 MB
+#: state into VMEM, counted as its operand and its result tuple, and the last
+#: mix's convolution counts its operands twice; PERF.md, PR 50)
+MHC_CHUNK_BYTES = 350e6
+
+
+@pytest.mark.parametrize("lead", [(1, 50), (32, 1), (7, 1)], ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("n", [2, 4])
+def test_the_lane_dense_residual_equals_a_float64_numpy_one(cfg, sinkhorn_body, n, lead):
+    """The maps with the tokens on the minor axis, the rounds as explicit adds
+    under one loop, the product scaled after the fact: against NumPy's float64
+    Sinkhorn and mix written here. One round instead of twenty is told apart."""
+    c = dataclasses.replace(cfg, hc_mult=n)
+    p = _seeded_sublayer(n, c.dim, 7 + n)
+    rng = np.random.default_rng([n, *lead])
+    X = rng.standard_normal((*lead, 1, c.dim)) + 0.5 * rng.standard_normal((*lead, n, c.dim))
+    X = jnp.asarray(X, jnp.float32)
+    maps = xing4.mhc_maps(c, p["hc_attn_phi"], p["hc_attn_b"], p["hc_attn_alpha"], X)
+    out, extra = xing4._hyper(c, p, "hc_attn", "attn_norm", X, lambda h: (h, "extra"))
+    assert extra == "extra" and out.shape == X.shape and out.dtype == X.dtype
+    assert [m.shape for m in maps] == [(*lead, n), (*lead, n), (*lead, n, n)]
+    *want_maps, want = _numpy_hyper(c, p, X, c.hc_sinkhorn_iters)
+    for have, ref in zip(maps, want_maps):
+        assert have.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(have), ref, atol=MHC_TOL, rtol=0)
+    assert np.abs(np.asarray(maps[2]).sum(-1) - 1).max() < 1e-5
+    assert np.abs(np.asarray(maps[2]).sum(-2) - 1).max() < 1e-5
+    assert _rel(out, want) < MHC_TOL
+    assert _rel(out, _numpy_hyper(c, p, X, 1)[-1]) > 50 * MHC_TOL  # one round is another residual
+
+
+def test_phi_goes_to_the_product_as_three_bfloat16_pieces_that_sum_to_it():
+    """What the chip's product of an unconverted bfloat16 state rests on
+    (``_state_dot``): under ``jit`` the three pieces are bfloat16 and their
+    float32 sum is the float32 array to the bit, over a wide range of exponents."""
+    rng = np.random.default_rng(8)
+    w = rng.standard_normal((4, 64, 24)) * np.exp(rng.uniform(-20.0, 20.0, (4, 64, 24)))
+    w = jnp.asarray(w, jnp.float32)
+    pieces = jax.jit(xing4._bf16_pieces)(w)
+    assert pieces.dtype == jnp.bfloat16 and pieces.shape == (4, 64, 72)
+    hi, mid, lo = (np.asarray(pieces[..., k * 24:(k + 1) * 24], np.float32) for k in range(3))
+    np.testing.assert_array_equal(hi + (mid + lo), np.asarray(w))
+    assert np.abs(mid).max() > 0 and np.abs(lo).max() > 0
+
+
+def test_the_residual_has_a_gradient_that_equals_a_finite_difference(cfg, sinkhorn_body):
+    """What ``forward`` needs of ``_hyper``: ``jax.grad`` of a scalar of it, with
+    respect to the state and to the maps' weights, finite, and equal to a central
+    difference along a random direction. Through the kernel the gradient is the
+    ``jnp`` body's (``jax.custom_vjp``)."""
+    n, D = cfg.hc_mult, cfg.dim
+    p = _seeded_sublayer(n, D, 3)
+    rng = np.random.default_rng(4)
+    X = jnp.asarray(rng.standard_normal((2, 5, 1, D)) + 0.5 * rng.standard_normal((2, 5, n, D)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((2, 5, n, D)) / np.sqrt(D), jnp.float32)
+
+    def scalar(p, X):
+        out, _ = xing4._hyper(cfg, p, "hc_attn", "attn_norm", X, lambda h: (jnp.tanh(h), None))
+        return jnp.sum(out * w)
+
+    grads = jax.grad(scalar, argnums=(0, 1))(p, X)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree_util.tree_leaves(grads))
+    direction = jax.tree_util.tree_map(  # a hundredth of each leaf's size a step
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), jnp.float32) * jnp.sqrt(jnp.mean(a * a)), (p, X)
+    )
+    along = sum(float(jnp.vdot(g, d)) for g, d in zip(*map(jax.tree_util.tree_leaves, (grads, direction))))
+    step = 1e-2
+    moved = lambda s: jax.tree_util.tree_map(lambda a, d: a + s * d, (p, X), direction)  # noqa: E731
+    central = float(scalar(*moved(step)) - scalar(*moved(-step))) / (2 * step)
+    assert abs(along) > 0.1 and abs(along - central) < 1e-3 * abs(along)
+
+
+# -- the residual's sublayer, compiled for a described v5e (no chip: nothing runs) ------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip, as ``tests/test_olmoe.py::one_chip``:
+    made inside a fixture, never at import."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _device_operations(text: str) -> int:
+    """Fusions + copies + kernels of a compiled module's entry computation, a
+    loop counted as its body x its trips."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif name is not None and " = " in line:
+            bodies[name].append(line)
+
+    def count(body):
+        total = 0
+        for line in bodies[body]:
+            op = re.search(r" (fusion|copy|custom-call|while)\(", line.split(" = ", 1)[1])
+            if op is None:
+                continue
+            if op.group(1) == "while":
+                trips = re.search(r'"known_trip_count":\{"n":"(\d+)"', line)
+                total += count(re.search(r"body=%?([\w.\-]+)", line).group(1)) * int(trips.group(1))
+            else:
+                total += 1
+        return total
+
+    return count("ENTRY")
+
+
+@pytest.mark.parametrize("lead, most_bytes", [((1, 1024), MHC_CHUNK_BYTES), ((32, 1), 40e6)], ids=["chunk_1024", "decode_32"])
+def test_a_sublayer_of_the_residual_compiles_to_a_few_operations_for_the_chip(one_chip, monkeypatch, lead, most_bytes):
+    """ONE sublayer around ``F`` = the identity at the published widths (the
+    state bf16 ``[.., 4, 3584]``: 29.4 MB a chunk), compiled for the real chip:
+    the gauge that says the lane-dense maps, the one kernel of the rounds and
+    the unconverted product engaged, and the one a JAX upgrade would trip. The
+    parent of PR 50 read 135 operations (91 fusions + 44 copies: each of the 40
+    normalisations a reduce over a trailing axis of 4 that ends a fusion and
+    flips a layout) and 618 MB accessed, with two float32 images of the state
+    (the ``convert`` and its re-tiled ``copy``); PR 50 reads 21 operations (16
+    fusions + 4 copies + the kernel), 329 MB and no such image. The compile has
+    a time limit of its own, so that a form that unrolls into minutes fails
+    here and not in a deployment's warm-up."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the branches; the test's business
+    c = xing4.Xing4Config(dtype=jnp.bfloat16)
+    n, D = c.hc_mult, c.dim
+    maps = 2 * n + n * n
+    shape = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    p = {"hc_attn_phi": shape((n * D, maps)), "hc_attn_b": shape((maps,)), "hc_attn_alpha": shape((3,)),
+         "attn_norm": shape((D,), jnp.bfloat16)}
+    sublayer = jax.jit(lambda p, X: xing4._hyper(c, p, "hc_attn", "attn_norm", X, lambda h: (h, None))[0])
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # such a compile cannot be read back without a chip
+    done = {}
+    try:
+        worker = threading.Thread(
+            target=lambda: done.update(compiled=sublayer.lower(p, shape((*lead, n, D), jnp.bfloat16)).compile()),
+            daemon=True,
+        )
+        worker.start()
+        worker.join(60)
+        assert "compiled" in done, "the sublayer did not compile for the chip within 60 s"
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    compiled = done["compiled"]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "mhc_sinkhorn" in text
+    assert _device_operations(text) <= 40
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["bytes accessed"] <= most_bytes
+    # whole float32 images of the state among the entry computation's values
+    entry = text[text.index("ENTRY"):]
+    images = [
+        m for m in re.finditer(r" = f32\[([\d,]+)\]\S* (?:fusion|copy|convert)\(", entry)
+        if math.prod(map(int, m.group(1).split(","))) >= math.prod(lead) * n * D
+    ]
+    assert len(images) <= 1
+    assert compiled.out_info.shape == (*lead, n, D) and compiled.out_info.dtype == jnp.bfloat16
 
 
 # -- the router, and the controls -------------------------------------------------------------
