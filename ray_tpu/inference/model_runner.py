@@ -25,13 +25,15 @@ it is (``table``; ``slots`` where a padding slot reads nothing).
 ``decode_width`` counts what was handed over and what the launched program
 reads of the cache, either way.
 
-A prefill chunk is always handed the full-width row. ``models/llama.py``'s
-chunk gathers and attends over all of it (``table``); so does ``models/
-xing4.py``'s wherever its flash kernel (``ops/latent_flash.py``) does not
-serve, and where it does (a TPU, whole tiles) the chunk still gathers and
-expands the table whole but attends over the key tiles up to its own end
-alone (``live``). ``prefill_width`` counts the positions up to each chunk's
-end and the key positions its attention reads, for every model.
+A prefill chunk is always handed the full-width row. Wherever the flash
+kernel (``ops/latent_flash.py``) does not serve (the CPU, a chunk that is no
+whole tile, odd head widths) a chunk of ``models/llama.py`` or ``models/
+xing4.py`` gathers and attends over all of it (``table``); where it does (a
+TPU, whole tiles: every model's own predicate, from shapes) the chunk still
+gathers (and Xing4's expands) the table whole but attends over the key tiles
+up to its own end alone (``live``). ``prefill_width`` counts the positions up
+to each chunk's end and the key positions its attention reads, for every
+model.
 
 A MoE config's steps return a third output, the expert loads
 ``[n_layers, E]`` of the launch's real rows (or a dict with them under

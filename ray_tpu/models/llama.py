@@ -827,17 +827,24 @@ def _chunk_keys(cfg: LlamaConfig, window: int, chunk: int, table_keys: int, bs: 
 
 def _flash_serves(cfg: LlamaConfig, k_cache, B: int, C: int, table_keys: int, window: int) -> bool:
     """Whether a chunk's attention runs the flash kernel (``ops/latent_flash.py``)
-    over K and V gathered through the table: ONE sequence, whole tiles, and for
-    now a configuration with layer kinds.
+    over K and V gathered through the table. SHAPES decide, and the backend:
+    ONE sequence (``B == 1``: a prefill chunk) and what
+    ``latent_flash.kernel_serves`` asks, a TPU, bf16 / float32, the chunk and
+    the keys in whole tiles, ``head_dim`` of whole lanes. Nothing of the
+    configuration's name, ``model_type`` or layer kinds: a plain GQA
+    configuration (Mistral, OLMoE) takes the lines a full layer of Mellum2
+    takes.
 
-    TODO(ROADMAP R4): the last condition names a configuration, not a shape. A
-    plain GQA configuration of whole lanes (Mistral, Codestral: ``head_dim`` 128)
-    would be served by the same kernel and would stop writing its float32 score
-    matrix; it keeps the materialised softmax here because ISSUE 44 fenced every
-    program that ran before it, and no chip run has compared the two on those
-    cells. ``longprompt-batch`` (prompts of 2-4 k, chunks of 1024) is the cell
-    that would prove it: drop the condition in a ``perf_opt`` PR that runs it."""
-    if not cfg.layer_windows or B != 1:
+    No shape that passes is kept back. A layer's call alone on a v5e, 4096
+    table keys, ms at a context of 0 / 1024 / 2048 / 3072, materialised ->
+    gather + kernel (PERF.md, PR 51): 32 heads over 8 of 128, 1024 queries
+    1.23 -> 0.22 / 0.34 / 0.45 / 0.56, 256 queries 0.33 -> 0.10 / 0.14 /
+    0.17 / 0.21; 16 over 16, 1024 queries 0.66 -> 0.18 / 0.24 / 0.29 / 0.35,
+    256 queries 0.160 -> 0.123 / 0.141 / 0.159 / 0.177: the one point that
+    loses (by a tenth, past half the table; 0.08 ms of it the gather of 16 KV
+    heads at the table's width, which the materialised way fuses) is a
+    CONTEXT, a traced scalar, not a shape, and over the table the shape gains."""
+    if B != 1:
         return False
     keys = _chunk_keys(cfg, window, C, table_keys, _block_size(cfg, k_cache))
     return latent_flash.kernel_serves(C, keys, cfg.head_dim, cfg.head_dim, 0, k_cache.dtype)
@@ -860,15 +867,18 @@ def _paged_attention(
     chosen at trace time from the shapes: a short window (decode, verify) on
     a TPU runs the Pallas kernel (``ops/paged_attention.py::kernel_serves``),
     which reads each slot's own live blocks out of the whole cache, from a
-    window's first on, and gathers nothing; a prefill chunk of a
-    configuration with layer kinds on a TPU gathers K and V through the table
-    (a window layer: from the block that holds the chunk's first visible key,
-    ``window + chunk`` positions, not the table's width) and runs the flash
-    kernel over them (``ops/latent_flash.py``: grouped heads, key tiles past
-    the diagonal or wholly behind the window never fetched), so no score
-    matrix is written; every other chunk, and everything off the chip,
-    gathers ``cache[layer, block_tables]`` for every slot as wide as the
-    table and materialises the softmax."""
+    window's first on, and gathers nothing; a prefill chunk on a TPU, in
+    whole tiles and at head widths the kernel takes (:func:`_flash_serves`),
+    gathers K and V through the table (a full layer: as wide as the table; a
+    window layer: from the block that holds the chunk's first visible key,
+    ``window + chunk`` positions) and runs the flash kernel over them
+    (``ops/latent_flash.py``: grouped heads, key tiles past the diagonal or
+    wholly behind the window never fetched or multiplied), so no score
+    matrix is computed past the live context or written; every other shape
+    (a verify window too wide for the decode kernel, a chunk that is no whole
+    tile, odd head widths), and everything off the chip, gathers
+    ``cache[layer, block_tables]`` for every slot as wide as the table and
+    materialises the softmax."""
     B, C = pos.shape
     k_cache, v_cache = cache[names[0]], cache[names[1]]
     n_kv, hd = cfg.n_kv_heads, cfg.head_dim
@@ -1138,10 +1148,14 @@ def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = 
 
 
 def _attention_path(cfg: LlamaConfig, window: int, cache) -> AttentionPath:
-    """The path of the programs of that query window; a configuration with
-    layer kinds runs the same path in both kinds of layer and says so
-    (``kernel+window``: each group's kernel reads the slot's live blocks of
-    that group, a window's from its first live one on)."""
+    """The path of the programs of that query window, from the shapes and
+    the backend as :func:`_paged_attention` chooses it: ``kernel`` (decode,
+    verify; reads ``blocks``), ``flash`` (a prefill chunk in whole tiles;
+    reads the ``live`` context in whole key tiles), ``gather`` (everything
+    else; reads the ``table``). A configuration with layer kinds runs the
+    same path in both kinds of layer and says so (``kernel+window``: each
+    group's kernel reads the slot's live blocks of that group, a window's
+    from its first live one on)."""
     kinds = "+window" if cfg.layer_windows else ""
     if _kernel_serves(cfg, window, cache["k"]):
         return AttentionPath(f"kernel{kinds}", "blocks")

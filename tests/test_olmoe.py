@@ -724,3 +724,47 @@ def test_the_kda_update_kernel_compiles_for_the_chip_in_place_in_kimi_linears_po
         assert [o.shape for o in compiled.out_info] == [pool, (slots, heads, d)]
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize("chunk", [1024, 256], ids=["chunk_1024", "chunk_256"])
+@pytest.mark.parametrize("name", ["mistral-7b-v0.3-16l", "olmoe-1b-7b-0125-12l"])
+def test_a_plain_configurations_prefill_chunk_compiles_for_the_chip_through_the_flash_kernel(
+    one_chip, monkeypatch, name, chunk
+):
+    """Both prefill programs of the benchmark's two plain configurations
+    (Mistral: 32 heads over 8 KV heads of 128; OLMoE: 16 over 16, experts
+    through ``gmm``), ONE layer of each at the file's widths over its pool and
+    its 4096-key table, compiled for the real chip (nothing runs): the chunk's
+    attention is ONE ``latent_flash`` call over K and V gathered through the
+    table (``llama._flash_serves`` from shapes, PR 51), and no program holds
+    the materialised way's float32 scores ``[chunk, heads, 4096]`` (537 MB a
+    layer at Mistral's 1024-chunk) among its temporaries."""
+    from perfbench import families
+    from perfbench.harness import cells
+
+    model = cells.config_of(cells.benchmark(), name)
+    cfg = families.of(model).model_config(model, max_seq_len=int(model["max_position_embeddings"]))
+    cfg = dataclasses.replace(cfg, n_layers=1)
+    engine = model["serving"]["engine"]
+    assert chunk in engine["prefill_buckets"] and not cfg.layer_windows
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the branch; the test's business
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        on_chip = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+        params = on_chip(jax.eval_shape(lambda: L.init_params(cfg, jax.random.PRNGKey(0))))
+        cache = on_chip(jax.eval_shape(lambda: L.init_paged_kv_cache(cfg, engine["num_blocks"], engine["block_size"])))
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+        assert L._attention_path(cfg, chunk, cache) == ("flash", "live")
+        compiled = jax.jit(lambda *args: L.paged_prefill_step(cfg, *args), donate_argnums=(1,)).lower(
+            params, cache, i32(chunk), i32(cfg.max_seq_len // engine["block_size"]), i32(), i32(),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count("latent_flash") >= 1 and text.count('custom_call_target="tpu_custom_call"') == (
+            1 + (3 if cfg.moe_experts else 0)  # the experts' three grouped matmuls
+        )
+        scores = chunk * cfg.n_heads * cfg.max_seq_len * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < scores // 2
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
