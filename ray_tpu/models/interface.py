@@ -369,12 +369,13 @@ class Model:
 
 def model_of(cfg) -> Model:
     """The model a config object belongs to, by the config's type."""
-    from ray_tpu.models import deepseek_v3, kimi_linear, lfm2, llama, xing4
+    from ray_tpu.models import deepseek_v3, jamba, kimi_linear, lfm2, llama, xing4
 
     models = {
         llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL,
         kimi_linear.KimiLinearConfig: kimi_linear.MODEL,
         deepseek_v3.DeepseekV3Config: deepseek_v3.MODEL, lfm2.Lfm2Config: lfm2.MODEL,
+        jamba.JambaConfig: jamba.MODEL,
     }
     try:
         return models[type(cfg)]

@@ -102,6 +102,18 @@ def kernel_serves(
     against the gather on the chip, PERF.md PR 44), or, for NARROW heads said
     beside it (``head_dim`` 64 with ``n_kv`` 8), a token's heads in one row of
     whole lanes, ``[n_layers, num_blocks, bs, n_kv * head_dim]`` (PERF.md PR 49).
+    ONE KV head (multi-query attention: 20 query heads a slot, no whole tile of
+    8 or 16 query rows) goes the flat way, ``[n_layers, num_blocks, bs * 1,
+    hd]`` with ``n_kv`` 1 said beside it: a block is one whole bf16 tile, the
+    20 query rows a slot ride as they are (Mosaic pads them inside its own
+    layout; nothing is padded by the caller), and the block table of 256 slots x
+    512 blocks is scalar-prefetched whole (512 KB of SMEM). Compiled AND run
+    against the gather on a v5e (PERF.md PR 52), one layer's call, 256 slots,
+    a table of 8192 positions: contexts log-normal about 1.5 k (415 k live
+    tokens) 1.59 ms against the gather's 7.76, 4096 a slot (1.04 M) 3.53
+    against 7.33, max|diff| / max|ref| 0.0055-0.0063 in bf16, a padding slot
+    zeros; a block is 4 KB a DMA, so the kernel reaches 16% of the HBM roofline
+    where 8 KV heads a block reached 86%.
     Everything else (the CPU, a prefill chunk, odd widths) takes the gather. Decided at trace time; the
     model runner asks the same question to know what a launch reads."""
     backend = backend or jax.default_backend()

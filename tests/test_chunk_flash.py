@@ -34,7 +34,7 @@ from ray_tpu.ops import latent_flash  # noqa: E402
 
 TILE, BS, KEYS, CHUNK, HD = 128, 16, 512, 128, 128
 M = KEYS // BS
-SHAPES = {"mistral_32_over_8": (32, 8), "olmoe_16_over_16": (16, 16)}
+SHAPES = {"mistral_32_over_8": (32, 8), "olmoe_16_over_16": (16, 16), "mqa_20_over_1": (20, 1)}
 BENCH_CONFIGS = ("mistral-7b-v0.3-16l", "olmoe-1b-7b-0125-12l")
 
 

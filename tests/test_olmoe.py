@@ -555,6 +555,72 @@ def test_both_attention_kernels_compile_for_the_chip_at_heads_of_64(one_chip, wi
         jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
+@pytest.mark.parametrize("kernel", ["paged_attn", "latent_flash_1024", "latent_flash_256", "ssm_scan_1024",
+                                    "ssm_scan_256", "ssm_update"])
+def test_the_kernels_of_the_jamba_cell_compile_for_the_chip_at_its_widths(one_chip, kernel):
+    """AI21-Jamba2-3B's kernels at the benchmark's sizes (here for the same
+    reason as the ones above): the two attention kernels at ONE KV head under
+    20 query heads (a cache of flat ``[16 x 1, 128]`` blocks, 256 slots, the
+    full-width table of 512 blocks; 20 query heads a key head over a table of
+    8192) and ``ops/selective_scan.py``'s two over the pool of 257 slots of
+    ``[16, 40, 128]`` float32: one Mosaic call each, the cache and the pool go
+    in as they lie (the pool aliased to its output), and no ``[chunk, 16,
+    5120]`` array is among the temporaries."""
+    from ray_tpu.ops import latent_flash as LF
+    from ray_tpu.ops import paged_attention as PA
+    from ray_tpu.ops import selective_scan as SS
+
+    B, H, hd, S, N, D = 256, 20, 128, 8192, 16, 5120
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        f32 = lambda s: shape(s, jnp.float32)  # noqa: E731
+        pool = f32((26, B + 1, *SS.state_shape(N, D)))
+        scalar = shape((), jnp.int32)
+        if kernel == "paged_attn":
+            cache = shape((2, 131073, 16, hd))
+            assert PA.kernel_serves(1, H, cache, backend="tpu", n_kv=1)
+            compiled = jax.jit(
+                lambda q, k, v, tables, pos: PA.paged_attention(q, k, v, 1, tables, pos, interpret=False, n_kv=1)
+            ).lower(shape((B, 1, H, hd)), cache, cache, shape((B, S // 16), jnp.int32), shape((B, 1), jnp.int32)).compile()
+            name, out, temporaries = "paged_attn", (B, 1, H, hd), 0
+        elif kernel.startswith("latent_flash"):
+            C = int(kernel.rsplit("_", 1)[1])
+            assert LF.kernel_serves(C, S, hd, hd, 0, jnp.bfloat16, backend="tpu")
+            compiled = jax.jit(
+                lambda q, k, v, ctx, n: LF.flash_attention(q, k, v, ctx, n, scale=hd ** -0.5, group=H, interpret=False)
+            ).lower(shape((H, C, hd)), shape((1, S, hd)), shape((1, S, hd)), scalar, scalar).compile()
+            name, out, temporaries = "latent_flash", (H, C, hd), 0
+        elif kernel.startswith("ssm_scan"):
+            C = int(kernel.rsplit("_", 1)[1])
+            assert SS.kernel_serves(pool, "tpu")
+            compiled = jax.jit(
+                lambda pool, layer, slot, *a: SS.chunk(pool, layer, slot, False, *a, kernel=True, interpret=False),
+                donate_argnums=0,
+            ).lower(pool, scalar, scalar, f32((C, D)), f32((C, D)), f32((C, N)), f32((C, N)), f32((N, D))).compile()
+            name, out, temporaries = "ssm_scan", (C, D), 3 * C * D * 4  # dt, x and y laid out a register a state index
+        else:
+            compiled = jax.jit(
+                lambda pool, layer, slots, *a: SS.step(pool, layer, slots, jnp.zeros((B,), bool), *a, kernel=True,
+                                                       interpret=False),
+                donate_argnums=0,
+            ).lower(pool, scalar, shape((B,), jnp.int32), f32((B, D)), f32((B, D)), f32((B, N)), f32((B, N)),
+                    f32((N, D))).compile()
+            name, out, temporaries = "ssm_update", (B, D), 3 * B * D * 4
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and name in text
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes <= temporaries + 2**20 < 1024 * N * D * 4
+        if name.startswith("ssm"):
+            assert memory.alias_size_in_bytes >= 26 * (B + 1) * N * D * 4  # the pool is updated where it lies
+            assert compiled.out_info[0].shape == out
+        else:
+            assert compiled.out_info.shape == out
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
 @pytest.mark.parametrize("window", [1024, 256], ids=["chunk_1024", "chunk_256"])
 def test_the_latent_flash_kernel_takes_a_192_wide_value_at_gigachat_widths(one_chip, window):
     """GigaChat3.1's prefill chunk through the same kernel: 64 heads, keys

@@ -97,6 +97,32 @@ def test_kernel_is_the_gather_and_reads_nothing_past_the_mask(hd, rep, window, c
     np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("contexts", ["ragged", "the_full_table"])
+@pytest.mark.parametrize("window", [1, 3], ids=["decode", "window_of_3"])
+def test_one_kv_head_under_twenty_query_heads_stored_flat(window, contexts):
+    """Multi-query attention as AI21-Jamba2-3B has it: 20 query heads (no
+    whole tile of 8 or 16 rows a slot) over ONE KV head, the cache stored flat
+    ``[layers, blocks, bs x 1, hd]`` with ``n_kv`` said beside it."""
+    rng = np.random.default_rng(20)
+    ctxs = (*CONTEXTS[contexts], 0, 0)
+    B, H, N = len(ctxs), 20, 1 + len(ctxs) * M
+    k, v = (jnp.asarray(a) for a in rng.standard_normal((2, LAYERS, N, BS, 1, HD)).astype(np.float32))
+    tables, pos = np.zeros((B, M), np.int32), np.zeros((B, window), np.int32)
+    shuffled = rng.permutation(np.arange(1, N))
+    for b, ctx in enumerate(ctxs):
+        if ctx:
+            tables[b] = shuffled[b * M:(b + 1) * M]
+            pos[b] = np.minimum(ctx - 1 + np.arange(window), FULL - 1)
+    q = jnp.asarray(rng.standard_normal((B, window, H, HD)).astype(np.float32))
+    want = L._attend_gathered(q, k, v, LAYER, jnp.asarray(tables), jnp.asarray(pos), 1, FULL)
+    flat = lambda a: a.reshape(LAYERS, N, BS, HD)  # noqa: E731
+    assert PA.kernel_serves(window, H, jax.ShapeDtypeStruct((LAYERS, N, 16, HD), jnp.bfloat16), backend="tpu", n_kv=1)
+    have = PA.paged_attention(q, flat(k), flat(v), LAYER, jnp.asarray(tables), jnp.asarray(pos), interpret=True,
+                              n_kv=1, wave_blocks=2)
+    assert (np.asarray(have)[-2:] == 0).all()  # the padding slots
+    np.testing.assert_allclose(np.asarray(have)[:-2], np.asarray(want)[:-2], rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("wave_blocks", [1, 3, None], ids=["a_block_a_wave", "3_blocks", "the_default_wave"])
 @pytest.mark.parametrize("hd", [HD, 64], ids=["heads_of_128", "heads_of_64_in_lanes"])
 def test_any_wave_size_gives_the_same_numbers(hd, wave_blocks):
@@ -145,6 +171,10 @@ def test_bfloat16_cache_accumulates_in_float32(hd):
         ("tpu", 1, 4, ((2, 24, 8, 32), {"n_kv": 2, "head_dim": 16}), jnp.bfloat16, False),  # a row that is no whole lanes
         # the same array WITHOUT the head's width is heads of 512 joined to the tokens, as ever
         ("tpu", 1, 32, ((6, 27000, 16, 512), {"n_kv": 8}), jnp.bfloat16, True),
+        # ONE KV head under 20 query heads, a block stored flat [16 x 1, 128]: Jamba2-3B decode at 256 slots (PR 52: run)
+        ("tpu", 1, 20, ((2, 131073, 16, 128), {"n_kv": 1}), jnp.bfloat16, True),
+        ("tpu", 1024, 20, ((2, 131073, 16, 128), {"n_kv": 1}), jnp.bfloat16, False),  # its chunk: the flash kernel's
+        ("cpu", 1, 20, ((2, 131073, 16, 128), {"n_kv": 1}), jnp.bfloat16, False),
         ("tpu", 1, 32, (16, 6144, 16, 8, 128), jnp.bfloat16, True),  # Mistral decode
         ("tpu", 8, 32, (16, 6144, 16, 8, 128), jnp.bfloat16, True),  # a verify window
         ("tpu", 1, 16, (12, 2240, 16, 16, 128), jnp.bfloat16, True),  # OLMoE decode
