@@ -28,12 +28,16 @@ reads of the cache, either way.
 A prefill chunk is always handed the full-width row. Wherever the flash
 kernel (``ops/latent_flash.py``) does not serve (the CPU, a chunk that is no
 whole tile, odd head widths) a chunk of ``models/llama.py`` or ``models/
-xing4.py`` gathers and attends over all of it (``table``); where it does (a
-TPU, whole tiles: every model's own predicate, from shapes) the chunk still
-gathers (and Xing4's expands) the table whole but attends over the key tiles
-up to its own end alone (``live``). ``prefill_width`` counts the positions up
-to each chunk's end and the key positions its attention reads, for every
-model.
+xing4.py`` attends over all of it (``table``); where it does (a TPU, whole
+tiles: every model's own predicate, from shapes) the chunk attends over the key
+tiles up to its own end alone (``live``). What it GATHERS in front of that is
+asked apart (``Model.gather_rungs``): a latent model's chunk gathers, overlays
+and expands K and V over the whole key tiles up to its last real query and no
+further, on either path (``models/latent.py::key_rungs``: one program, the
+rung chosen on the device); ``models/llama.py``'s, ``lfm2.py``'s and
+``jamba.py``'s still gather the table whole. ``prefill_width`` counts the
+positions up to each chunk's end, the key positions its attention reads and
+those its program gathers and expands, for every model.
 
 A MoE config's steps return a third output, the expert loads
 ``[n_layers, E]`` of the launch's real rows (or a dict with them under
@@ -308,9 +312,13 @@ class PagedModelRunner:
         #: over (tokens), the positions up to the chunk's end, and the key
         #: positions the program's attention reads: the table's width
         #: (``reads`` = ``table``) or, through a flash kernel (``live``), the
-        #: positions up to the chunk's end in whole key tiles
+        #: positions up to the chunk's end in whole key tiles; and the key
+        #: positions the program gathers (a latent model: and expands K and V
+        #: at) in front of its attention: the table's width or, where the
+        #: model's chunk takes rungs (``Model.gather_rungs``), the positions
+        #: up to the chunk's end in whole rungs
         self.prefill_width: Dict[str, int] = dict.fromkeys(
-            ("launches", "width_tokens", "live_tokens", "read_tokens"), 0
+            ("launches", "width_tokens", "live_tokens", "read_tokens", "expanded_tokens"), 0
         )
         #: MoE configs only: what the experts saw, as running sums over
         #: decode and verify launches and, apart, prefill launches
@@ -622,11 +630,13 @@ class PagedModelRunner:
             ((end - 1 - first // bs * bs) // tile - first % bs // tile + 1) * tile if tile else width
             for first in firsts
         )
+        rungs = self.model.gather_rungs(self.cfg, bucket, self.cache)
         pw = self.prefill_width
         pw["launches"] += 1
         pw["width_tokens"] += width
         pw["live_tokens"] += live
         pw["read_tokens"] += read
+        pw["expanded_tokens"] += next((rung for rung in rungs if rung >= end), width)
         with clock.phase(
             "launch", program="paged_prefill_step", bucket=bucket, path=self._path_name(bucket),
         ):

@@ -303,6 +303,7 @@ MODEL = Model(
     # the module's layer too: ``xing4._paged_layers`` over a stack of one
     experts_in_place=xing4.MODEL.experts_in_place,
     key_tile=xing4.MODEL.key_tile,
+    gather_rungs=xing4.MODEL.gather_rungs,
     drafter=lambda cfg: Drafter(
         kind="mtp", window=1 + cfg.n_mtp_layers, cache_layers=cfg.n_mtp_layers,
         step=paged_mtp_step, verify=paged_mtp_verify, draft=paged_mtp_draft,

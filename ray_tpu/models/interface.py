@@ -351,6 +351,12 @@ class Model:
     #: (cfg, window, cache) -> positions a key tile of a program whose
     #: ``attention_path`` reads ``"live"``
     key_tile: Callable = lambda cfg, window, cache: 1
+    #: (cfg, window, cache) -> the widths, in key positions and ascending, a
+    #: prefill chunk of ``window`` queries GATHERS its context at (a latent
+    #: model: and expands K and V at): a chunk takes the first that holds its
+    #: last real query, whatever its ``attention_path`` reads. Empty: the
+    #: table whole, whatever the context
+    gather_rungs: Callable = lambda cfg, window, cache: ()
     #: ``None`` for a model whose layers all attend, else (cfg) -> the
     #: :class:`StateLayout` of its recurrent layers. Such a model's paged
     #: entry points take the state arrays after the cache (both donated) and

@@ -821,5 +821,6 @@ MODEL = Model(
     attention_path=_attention_path,
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
     key_tile=lambda cfg, window, cache: latent_flash.tiles(window, latent.table_keys(cfg, cache))[1],
+    gather_rungs=latent.gather_rungs,
     state_layout=state_layout,
 )

@@ -13,10 +13,19 @@ factor on the float32 scores); and of a layer's weights ``p`` only ``w_kvb
 prefill chunk, :func:`paged_serves` for decode and verify), the ONE attention
 door of a paged body (:func:`latent_attention`) and the block write
 (:func:`write_blocks`) are the same mathematics for every such model.
+
+What a prefill chunk pays for in front of its attention follows its own end,
+not the table: it gathers the latent rows, lays its own over them and expands
+K and V over the whole key tiles up to its last real query and no further
+(:func:`key_rungs`: a rung a tile, 8 at a table of 8192; one ``lax.switch``
+inside the ONE program a bucket, the rung a traced scalar). At the table's
+width that was 69 GFLOP and 0.13 GB a layer whatever the context (PERF.md,
+PR 53).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -190,6 +199,12 @@ def flash_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
     )
 
 
+def gather_rungs(cfg, window: int, cache) -> tuple:
+    """``Model.gather_rungs`` of a model whose chunks go through
+    :func:`latent_attention`: :func:`key_rungs` at the runner's full table."""
+    return key_rungs(window, table_keys(cfg, cache), block_size_of(cfg, cache))
+
+
 def table_keys(cfg, cache) -> int:
     """Positions of the block table a prefill chunk is handed: ``max_seq_len``
     in whole blocks (``model_runner.py``'s ``max_blocks_per_seq``)."""
@@ -202,12 +217,13 @@ def attend_flash(cfg, p, q_nope, q_rope, rows, ctx_len, true_len):
     (``ops/latent_flash.py``): queries ``[C, H, .]`` over latent rows ``rows
     [S, kr + dr]`` with the window's own rows laid over them, query ``c``
     seeing row ``j`` iff ``j <= ctx_len + c``, the first ``true_len`` queries
-    real. K and V are expanded from ALL ``S`` rows by XLA as there, heads
-    leading (``k_rope`` stays ONE row a position: the kernel adds its product
-    to ``k_nope``'s); the float32 scores never leave VMEM, and of the
-    expanded tiles only those up to ``ctx_len + true_len`` are read.
-    Returns ``[C, H, dv]``; what a query past ``true_len`` gets is finite
-    and nobody's."""
+    real. K and V are expanded by XLA as there, heads leading (``k_rope``
+    stays ONE row a position: the kernel adds its product to ``k_nope``'s),
+    from every one of the ``S`` rows it is handed: the caller hands the key
+    tiles up to ``ctx_len + true_len`` and no more (:func:`latent_attention`'s
+    rung), which are the tiles the kernel reads; the float32 scores never
+    leave VMEM. Returns ``[C, H, dv]``; what a query past ``true_len`` gets
+    is finite and nobody's."""
     dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     c, k_rope = rows[:, :kr], rows[:, kr:]
     with jax.named_scope("mla.expand"):
@@ -252,19 +268,24 @@ def latent_attention(
     place a serving step reads the cache for attention. A slot's window is
     CONTIGUOUS: ``pos[b, c] = pos[b, 0] + c`` (all three entry points).
     Query ``(b, c)`` sees key position ``j`` of its slot iff ``j <= pos[b,
-    c]``; the first ``true_lens[b]`` queries of a slot are real. The
-    gathered context ``cache[layer, block_tables]`` is as wide as the table
-    handed over (where nothing is gathered: below). A window
+    c]``; the first ``true_lens[b]`` queries of a slot are real. A window
     attends to itself as after the write (:func:`_paged_layers` says why
     the write itself comes last), by the path chosen at trace time from the
-    window (:func:`absorbs`): a prefill chunk lays its rows over the
+    window (:func:`absorbs`): a prefill chunk gathers the blocks of the key
+    positions up to its RUNG (the first of :func:`key_rungs`' widths that
+    holds its last real query, ``first + true_len - 1``: a ``lax.switch`` on
+    a traced scalar inside the one program; the last rung is the table), lays
+    its rows over the
     positions they will be written to (one ``dynamic_update_slice``) and
-    expands K and V of that context from the latent rows, then attends
+    expands K and V of THAT context from the latent rows, then attends
     through the flash kernel where it serves (:func:`flash_serves`: a TPU,
-    whole tiles; the scores stay in VMEM and the expanded tiles past the live
-    context are not read; ``flash``: the caller's answer to that question,
-    asked there so that a module's own predicate is the one asked) and through :func:`attend_expanded`'s
-    materialised softmax elsewhere; a decode or verify window absorbs
+    whole tiles; the scores stay in VMEM; ``flash``: the caller's answer to
+    that question, asked there so that a module's own predicate is the one
+    asked) and through :func:`attend_expanded`'s materialised softmax
+    elsewhere, over the same rung (its mask is a prefix: the rows past the
+    rung were masked out of every real query's scores); a batch of such
+    windows (a verify window too long to absorb: the tests' alone) runs a
+    slot at a time, each on its own rung; a decode or verify window absorbs
     ``W_kvb`` and attends over the rows before it directly and over its own
     rows beside them under one softmax: through the kernel over latent rows
     where it serves (:func:`paged_serves`: a TPU, a short window, the cache
@@ -272,7 +293,9 @@ def latent_attention(
     cache as it lies, :func:`attend_paged`, and XLA gathers only the window's
     ``nblk`` blocks, for the write) and elsewhere a slot at a time, a real
     slot's context gathered at the table's width (attending 8 slots of like
-    context at a time instead was SLOWER on the chip, PR 35).
+    context at a time instead was SLOWER on the chip, PR 35). The window's
+    blocks for the write are gathered by themselves on every path but that
+    one (``nblk`` blocks, whatever the rung).
 
     Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
     ``blocks`` are the ``nblk`` (:func:`blocks_of_window`) blocks from the
@@ -294,17 +317,19 @@ def latent_attention(
         # not tiled)
         return cache["latent"].reshape(L * N, *block)[layer * N + table].reshape(-1, W)
 
-    def window_blocks(rows, at):
-        return jax.lax.dynamic_slice(rows, (at // bs * bs, 0), (nblk * bs, W))
+    def window_blocks():
+        # of the cache only the ``nblk`` blocks the window will be written
+        # into, the window's rows laid in
+        ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at // bs,), (nblk,)))(tables, first)
+        return jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a % bs, 0)))(
+            jax.vmap(context)(ids), row, first
+        )
 
     if paged_serves(cfg, C, cache):
-        # the kernel reads each slot's own live blocks; of the cache XLA
-        # gathers only the ``nblk`` blocks the window will be written into
+        # the kernel reads each slot's own live blocks
         q_row = absorb_query(cfg, p, q_nope, q_rope)
         o_lat = attend_paged(cfg, q_row, cache, layer, block_tables, first, row)
-        ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at // bs,), (nblk,)))(tables, first)
-        blocks = jax.vmap(context)(ids)
-        blocks = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a % bs, 0)))(blocks, row, first)
+        blocks = window_blocks()
         blocks = jnp.where((block_tables[:, 0] != 0)[:, None, None], blocks, 0)
         return absorb_output(cfg, p, o_lat), blocks
     if absorbs(cfg, C):
@@ -321,7 +346,8 @@ def latent_attention(
 
             def read():
                 rows = context(table)
-                blocks = jax.lax.dynamic_update_slice(window_blocks(rows, at), own, (at % bs, 0))
+                blocks = jax.lax.dynamic_slice(rows, (at // bs * bs, 0), (nblk * bs, W))
+                blocks = jax.lax.dynamic_update_slice(blocks, own, (at % bs, 0))
                 mask = jnp.broadcast_to(key_pos < at, (1, C, rows.shape[0]))
                 return attend_rows(cfg, q[None], rows[None], mask, own[None])[0], blocks
 
@@ -332,24 +358,57 @@ def latent_attention(
 
         o_lat, blocks = jax.lax.map(slot, (tables, q_row, row, first))
         return absorb_output(cfg, p, o_lat), blocks
-    rows = jax.vmap(context)(tables)
-    rows = jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a, 0)))(rows, row, first)
-    blocks = jax.vmap(window_blocks)(rows, first)
     keys = block_tables.shape[1] * bs
-    if flash_serves(cfg, C, cache, keys) if flash is None else flash:
-        # a slot's real queries end inside the table: the null columns
-        # behind it hold padding rows alone, and no key for anybody
+    if flash is None:
+        flash = flash_serves(cfg, C, cache, keys)
 
-        def slot(args):
-            q_n, q_r, r, at, n = args
-            return attend_flash(cfg, p, q_n, q_r, r[:keys], at, n)
+    widths = key_rungs(C, keys, bs)
 
-        args = (q_nope, q_rope, rows, first, true_lens)
-        if B == 1:  # a prefill chunk
-            return slot(jax.tree_util.tree_map(lambda a: a[0], args))[None], blocks
-        return jax.lax.map(slot, args), blocks
-    mask = key_pos <= pos[:, :, None]
-    return attend_expanded(cfg, p, q_nope, q_rope, rows, mask), blocks
+    def attend(b, width: int):
+        # slot ``b`` over the first ``width`` key positions of its table: the
+        # blocks that hold them and ``nblk`` columns more (the next blocks',
+        # or the null ones behind the table), so that the window's rows are
+        # laid where they will be written without a clamp, whatever of them
+        # spills past ``width``: padding rows, and no key for anybody
+        rows = context(tables[b, : width // bs + nblk])
+        rows = jax.lax.dynamic_update_slice(rows, row[b], (first[b], 0))[:width]
+        if flash:
+            return attend_flash(cfg, p, q_nope[b], q_rope[b], rows, first[b], true_lens[b])
+        mask = key_pos[:width] <= pos[b, :, None]
+        return attend_expanded(cfg, p, q_nope[b][None], q_rope[b][None], rows[None], mask[None])[0]
+
+    def slot(b):
+        # the first rung that holds the slot's last real query's position
+        n = jnp.sum(first[b] + true_lens[b] > jnp.asarray(widths[:-1], jnp.int32))
+        return jax.lax.switch(n, [functools.partial(attend, b, width) for width in widths])
+
+    if B == 1:  # a prefill chunk
+        return slot(0)[None], window_blocks()
+    return jax.lax.map(slot, jnp.arange(B)), window_blocks()
+
+
+def key_rungs(window: int, keys: int, block_size: int) -> tuple:
+    """The widths, in key positions and ascending, a prefill chunk of
+    ``window`` queries gathers and expands its context at, one of them a
+    launch, chosen on the device (:func:`latent_attention`): every whole
+    number of key tiles (``ops/latent_flash.py::tiles``: the kernel's, 1024
+    positions; the materialised softmax takes the same) up to a table of
+    ``keys`` positions, ``[tile, 2 tile, .., keys]``: eight rungs at 8192.
+    The table whole where it is no whole number of tiles of whole blocks (and
+    where it is one tile: the tests' toy tables). Read off shapes: no argument
+    of anybody's.
+
+    A rung is one more instance of the kernel in each of a program's layer
+    bodies, and a WARM start-up pays ≈ 0.7 s a rung for them (Xing4's
+    ``warmup_s`` 10.5 -> 15.6 s, GigaChat3.1's 20.1 -> 27.0). Doublings (1, 2,
+    4, 8 tiles; 12.5 and 22.0 s) were measured beside these: the same rate on
+    GigaChat3.1's prompts (1 to 4 tiles), but +1.6 to +4.3% where these read
+    +5.5 to +6.3% on ``mla-longdoc-batch``, whose chunks at 5 to 7 tiles of
+    context a doubling hands the whole table (PERF.md, PR 53)."""
+    tile = latent_flash.tiles(window, keys)[1]
+    if keys % tile or tile % block_size:
+        return (keys,)
+    return tuple(range(tile, keys + 1, tile))
 
 
 def block_size_of(cfg, cache) -> int:

@@ -852,9 +852,11 @@ def _attention_path(cfg: Xing4Config, window: int, cache, backend=None) -> Atten
     kernel over latent rows where it serves (``latent.paged_serves``: a TPU,
     the cache in whole tiles) and gathers a slot at a time elsewhere, a real
     slot as wide as the table, nothing for a padding slot; the expanded path
-    gathers the table as wide as it is handed over and then attends over ALL
-    of it (the materialised softmax) or, through the flash kernel, over the
-    key tiles up to the live context alone."""
+    gathers and expands the key tiles up to the chunk's end either way
+    (``latent.key_rungs``, ``Model.gather_rungs``) and says what its ATTENTION
+    is accounted at: the table (the materialised softmax: the CPU's, where a
+    toy table is one tile) or, through the flash kernel, the key tiles up to
+    the live context alone."""
     if latent.paged_serves(cfg, window, cache, backend=backend):
         return AttentionPath("latent.paged", "blocks")
     if absorbs(cfg, window):
@@ -879,4 +881,5 @@ MODEL = Model(
     # every expert layer of the paged body: ``_paged_layers`` scans them all in place
     experts_in_place=lambda cfg, layers: layers,
     key_tile=lambda cfg, window, cache: latent_flash.tiles(window, latent.table_keys(cfg, cache))[1],
+    gather_rungs=latent.gather_rungs,
 )

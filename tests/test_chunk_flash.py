@@ -213,7 +213,10 @@ def test_the_runner_counts_whole_key_tiles_for_a_plain_configuration(shape, requ
     table = make()
     want = _prefill(table, prompt)
     assert table.attention_paths[CHUNK] == ("gather", "table")
-    assert table.prefill_width == {"launches": 4, "width_tokens": 4 * KEYS, "live_tokens": sum(LIVE), "read_tokens": 4 * KEYS}
+    # (``expanded_tokens``, PR 53: what the program GATHERS in front of its attention; this model's
+    # chunk gathers the table whole on both paths, ROADMAP S3 iv)
+    account = {"launches": 4, "width_tokens": 4 * KEYS, "live_tokens": sum(LIVE), "expanded_tokens": 4 * KEYS}
+    assert table.prefill_width == {**account, "read_tokens": 4 * KEYS}
 
     calls = request.getfixturevalue("as_on_a_tpu")
     live = make()
@@ -223,7 +226,7 @@ def test_the_runner_counts_whole_key_tiles_for_a_plain_configuration(shape, requ
     assert len(calls) == cfg.n_layers  # traced once: one call a layer of the ONE prefill program
     read = sum(-(-n // TILE) * TILE for n in LIVE)
     assert read == 128 + 256 + 384 + 512
-    assert live.prefill_width == {"launches": 4, "width_tokens": 4 * KEYS, "live_tokens": sum(LIVE), "read_tokens": read}
+    assert live.prefill_width == {**account, "read_tokens": read}
     np.testing.assert_allclose(have, want, rtol=0, atol=2e-5 * np.abs(want).max())
     assert live.compile_count() == table.compile_count()
 

@@ -24,10 +24,12 @@ from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops import latent_flash  # noqa: E402
 
 BS, CHUNK, MAX_SEQ, TILE = 8, 32, 128, 16
-COUNTERS = ("launches", "width_tokens", "live_tokens", "read_tokens")
+COUNTERS = ("launches", "width_tokens", "live_tokens", "read_tokens", "expanded_tokens")
 PROMPT = [int(t) for t in np.random.RandomState(3).randint(1, 256, size=100)]
 #: context + chunk of the prompt's four launches (32, 32, 32, 4 tokens)
 LIVE = (32, 64, 96, 100)
+#: ... rounded up to ``latent.key_rungs``' whole tiles of 16: what the kernel reads (ISSUE 53)
+EXPANDED = 32 + 64 + 96 + 112
 
 
 @pytest.fixture(scope="module")
@@ -71,8 +73,15 @@ def _prefill(runner, prompt=PROMPT):
     return np.stack(logits), rows
 
 
-def _expected(read):
-    return {"launches": len(LIVE), "width_tokens": len(LIVE) * MAX_SEQ, "live_tokens": sum(LIVE), "read_tokens": read}
+def _expected(read, expanded=None):
+    """``expanded``: the key positions the program gathers (and a latent
+    model's expands) in front of its attention; what the attention reads
+    unless told (ISSUE 53: the latent chunk's rungs are the kernel's key
+    tiles; at the toy's own tile, the table's width, there is one rung)."""
+    return {
+        "launches": len(LIVE), "width_tokens": len(LIVE) * MAX_SEQ, "live_tokens": sum(LIVE),
+        "read_tokens": read, "expanded_tokens": read if expanded is None else expanded,
+    }
 
 
 def test_the_flash_chunk_is_the_materialised_chunk_on_a_runner(cfg, params, request):
@@ -95,7 +104,8 @@ def test_the_flash_chunk_is_the_materialised_chunk_on_a_runner(cfg, params, requ
     big = xing4.Xing4Config(dtype=jax.numpy.bfloat16)
     stored = jax.eval_shape(lambda: xing4.cache_layout(big, 16).init(8))
     assert model_of(big).attention_path(big, 1, stored, backend="tpu") == ("latent.paged", "blocks")
-    assert live.prefill_width == _expected(sum(-(-n // TILE) * TILE for n in LIVE)) == _expected(32 + 64 + 96 + 112)
+    # ... and gathers and expands the same whole key tiles, and no other
+    assert live.prefill_width == _expected(sum(-(-n // TILE) * TILE for n in LIVE), EXPANDED) == _expected(32 + 64 + 96 + 112, EXPANDED)
     np.testing.assert_allclose(have_logits, want_logits, rtol=0, atol=2e-5 * np.abs(want_logits).max())
     np.testing.assert_allclose(have_rows, want_rows, rtol=0, atol=2e-5 * np.abs(want_rows).max())
     assert live.compile_count() == table.compile_count()
@@ -132,19 +142,21 @@ def test_the_flash_chunk_gives_the_engine_the_same_greedy_tokens(cfg, params, re
     assert name == "latent.expanded" and table == _expected(len(LIVE) * MAX_SEQ)
     request.getfixturevalue("flash_forced")
     have, live, name = _generate(cfg, params)
-    assert name == "latent.flash" and live == _expected(32 + 64 + 96 + 112)
+    assert name == "latent.flash" and live == _expected(32 + 64 + 96 + 112, EXPANDED)
     assert len(want) == 12 and have == want
 
 
 def test_prefill_width_counts_every_model():
     """``models/llama.py``'s chunk gathers the table it is handed and attends
-    over all of it: ``read_tokens`` is the width, whatever the context."""
+    over all of it: ``read_tokens`` is the width, whatever the context, and
+    so is ``expanded_tokens`` (its gather takes no rungs)."""
     llama = LlamaConfig.tiny(max_seq_len=MAX_SEQ)
     runner = _runner(llama, model_of(llama).init_params(llama, jax.random.PRNGKey(0)))
     _prefill(runner)
     assert runner.attention_paths[CHUNK].reads == "table"
     assert runner.prefill_width == _expected(len(LIVE) * MAX_SEQ)
     assert model_of(llama).key_tile(llama, CHUNK, runner.cache) == 1
+    assert model_of(llama).gather_rungs(llama, CHUNK, runner.cache) == ()
 
 
 def test_a_long_verify_window_takes_the_kernel_a_slot_at_a_time(cfg, params, request):
