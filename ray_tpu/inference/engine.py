@@ -84,7 +84,11 @@ _END = object()  # stream sentinel
 _WAKES_PER_STEP = 32
 
 #: the step account's leaf phases (``stats()["step_phases"]["<phase>_s"]``,
-#: profiler annotation ``engine.<phase>``); README "Observability"
+#: profiler annotation ``engine.<phase>``); README "Observability". The
+#: step thread's OFF-CPU seconds, what it waited (the GIL, a blocking call
+#: into the runtime, the OS), are an account beside the wall clock's, the
+#: thread's own and each phase's and part's under the same names:
+#: ``stats()["step_offcpu"]``
 STEP_PHASES = (
     "schedule", "launch", "device_wait", "readback", "sample", "emit",
     "bookkeeping", "loop_wait",
@@ -99,6 +103,14 @@ STEP_PARTS = (
     "readback.logits", "readback.loads",
     "emit.commit", "emit.deliver",
 )
+
+#: the blocks that call nothing which blocks below Python: their off-CPU
+#: seconds can only be the GIL (or another Python thread's lock) and the OS
+#: (``stats()["step_offcpu"]["unblocked_s"]``). Not ``emit.commit``: with
+#: ``kv_tier_enabled``, and for a prefill export, it gathers the request's
+#: blocks from the device (``_tier_writeback_full_blocks``,
+#: ``_complete_prefill_export``), a transfer the thread waits for
+UNBLOCKED = ("schedule.admit", "schedule.plan", "launch.rows", "launch.inputs", "sample")
 
 logger = logging.getLogger(__name__)
 
@@ -931,7 +943,9 @@ class InferenceEngine:
                     self._work.wait(timeout=0.005)
                     self._work.clear()
             # from where step() settled to here: the loop's own overhead
-            clock.settle(clock.settled_at, "bookkeeping" if did_work else "loop_wait")
+            clock.settle(
+                clock.settled_at, "bookkeeping" if did_work else "loop_wait", rated=False
+            )
 
     # -- submission -------------------------------------------------------
     def submit(
@@ -1273,7 +1287,8 @@ class InferenceEngine:
         finally:
             if not hold_wakes:
                 self._wake("direct")
-            self._clock.settle(since, "bookkeeping" if did_work else "schedule")
+            # a step that found nothing to do is no lap to rate another against
+            self._clock.settle(since, "bookkeeping" if did_work else "schedule", rated=did_work)
 
     def _step(self, t0_us: float, in_loop: bool) -> bool:
         clock = self._clock
@@ -2707,6 +2722,12 @@ class InferenceEngine:
                 f"{name.replace('.', '_')}_s": seconds
                 for name, seconds in self._clock.parts_total.items()
             },
+            # what the step thread WAITED: its own, each phase's and part's
+            "step_offcpu": self._step_offcpu(),
+            # the laps that stood still: how many of how many, whose seconds
+            "step_stalls": dict(self._clock.stalls),
+            # the waits for the device, and those that found it done
+            "device_reads": dict(self._clock.reads),
             # where the step thread's puts were delivered, and how long held
             "wakes": dict(self._wakes),
             # how often the loop launched a decode step before it had read the last
@@ -2765,6 +2786,33 @@ class InferenceEngine:
         out["host_serial_s"] = out["wall_s"] - total["device_wait"] - total["loop_wait"]
         out["longest_wall_s"] = clock.longest_wall_s
         out["longest_device_wait_s"] = clock.longest_device_wait_s
+        return out
+
+    def _step_offcpu(self) -> Dict[str, float]:
+        """The step thread's off-CPU seconds (as ``step_phases``: engine
+        lifetime, a reader differences two calls), each clamped to ``[0,`` the
+        wall seconds of the same key``]``. ``wall_s``: the thread's own,
+        ``wall - cpu`` over its settled laps, one CPU reading a lap.
+        ``host_serial_s``: that less the two waiting leaves', as
+        ``step_phases.host_serial_s`` is ``wall_s`` less their wall.
+        ``<phase>_s`` a leaf and ``<phase>_<part>_s`` a part: its wall seconds
+        less its CPU seconds, read every lap where the CPU clock is cheap and
+        scaled up from a sample of laps where it is not
+        (``timeline._SAMPLE_EVERY``);
+        ``unblocked_s``: their sum over :data:`UNBLOCKED`."""
+        clock = self._clock
+        walls = {**clock.total, **clock.parts_total}
+        blocks = {
+            name: min(max(0.0, walls[name] - seconds), walls[name])
+            for name, seconds in {**clock.cpu_total, **clock.parts_cpu_total}.items()
+        }
+        out = {f"{name.replace('.', '_')}_s": seconds for name, seconds in blocks.items()}
+        wall = sum(clock.total.values())
+        out["wall_s"] = min(max(0.0, clock.thread_offcpu), wall)
+        host = wall - clock.total["device_wait"] - clock.total["loop_wait"]
+        waits = blocks["device_wait"] + blocks["loop_wait"]
+        out["host_serial_s"] = min(max(0.0, out["wall_s"] - waits), host)
+        out["unblocked_s"] = sum(blocks[name] for name in UNBLOCKED)
         return out
 
     def routing_stats(self) -> Dict[str, Any]:

@@ -94,6 +94,13 @@ logger = logging.getLogger(__name__)
 #: block-copy pairs per compiled COW program (pairs pad with null->null)
 _COW_WIDTH = 4
 
+#: a ``block_until_ready()`` that takes less found its array FINISHED: the
+#: device stood waiting for the host (:meth:`PagedModelRunner.read`). On a
+#: v5e a finished array's call takes 4-10 us (p95 14, the longest of 1,350
+#: 38.5; beside a running program the same) and an unfinished one's no less
+#: than the completion's way back to the thread, 270 us and more (PERF.md
+#: section 5, PR 54); under a busy GIL the threshold errs towards "not ready"
+_READY_S = 50e-6
 #: blocks per compiled KV gather/scatter program (KV-cache migration);
 #: short chunks pad with the null block so the shape never varies
 _KV_IO_WIDTH = 8
@@ -681,12 +688,17 @@ class PagedModelRunner:
         the device's time is told from the copy's. A MoE step's expert loads
         come over in the same ``readback`` and go into the step's half of
         :attr:`moe`. ``before_wait`` runs first: the launch has returned, the
-        wait has not begun."""
+        wait has not begun. Counts the wait on the clock (``clock.reads``), and
+        as ``ready`` if it returned at once: the step was done before the
+        host asked."""
         clock = clock or self.clock
         if before_wait is not None:
             before_wait()
-        with clock.phase("device_wait"):
+        with clock.phase("device_wait") as wait:
             step.out.block_until_ready()
+        reads = clock.reads
+        reads["reads"] += 1
+        reads["ready"] += wait.seconds < _READY_S
         with clock.phase("readback"):
             with clock.part("logits"):
                 host = np.asarray(step.out)

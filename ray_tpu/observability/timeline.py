@@ -16,6 +16,8 @@ cross-process causal arrows.
 from __future__ import annotations
 
 import json
+import logging
+import math
 import os
 import sys
 import threading
@@ -26,6 +28,51 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 _MAX_EVENTS = 100_000
+logger = logging.getLogger(__name__)
+#: the calling thread's CPU seconds (``CLOCK_THREAD_CPUTIME_ID``). Bound
+#: here, not looked up on ``time``: a test that scripts this module's
+#: ``time`` scripts the wall clock alone
+_cpu_now = time.thread_time
+
+
+def _cpu_read_cost() -> float:
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        _cpu_now()
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+#: what one reading of the CPU clock costs here, measured once: 0.4 us on a
+#: plain Linux host, 6.1 us in the sandbox that holds the chip, whose CPU
+#: clock also moves in ticks of 10 ms (PERF.md section 6, PR 54). A block's
+#: two readings lie inside its wall readings; about one reading's time lies
+#: between them and is taken off again
+_CPU_READ_S = _cpu_read_cost()
+#: the blocks of one lap in ``_SAMPLE_EVERY`` read the CPU clock, so that
+#: a lap's 25 blocks pay 25 us for it on average wherever a reading is
+#: dear: every lap at 0.4 us, one in 13 at 6.1. Odd, so that it does not
+#: fall in step with a load whose laps alternate. The lap's own reading at
+#: ``settle`` is taken every lap
+_SAMPLE_EVERY = max(1, math.ceil(50 * _CPU_READ_S / 25e-6)) | 1
+#: a lap that begins this long after the last settle did not follow it: a
+#: ``step()`` from outside the loop, with its caller's time between
+_LAP_GAP_S = 1e-3
+#: a settled lap is STALLED when its wall is this many times the mean of the
+#: rated laps before it: the rule ``step_longest_ms.*`` gives its reader
+#: ("ten times their sum is a stall, not a program that idles"). A prefill
+#: chunk beside decode steps is 3-8 x the mean lap in every cell
+#: (127 ms beside 15-40), a frozen machine 50-300 x (1.4-4.5 s)
+_STALL_TIMES = 10.0
+#: ... once the mean stands on this many laps: a replica's first laps are
+#: its lead-in (one request, then a few) and, where nothing was warmed, its
+#: compiles; 64 is two to four seconds of any cell's steps
+_STALL_AFTER_LAPS = 64
+#: this many stalled laps IN A ROW are the load's new shape (short chats,
+#: then long documents), not a stall: the mean starts anew, or every later
+#: lap would be rated against a load that is gone
+_STALL_RUN = 8
 
 
 @dataclass
@@ -97,10 +144,43 @@ class PhaseClock:
     what they read without parts, and what a phase's parts leave of it is
     its self time. Parts do not nest, and there is none outside a phase.
 
+    Beside the wall clock the thread's CPU clock (``time.thread_time``) is
+    read, and ``wall - cpu`` is OFF-CPU time: what the thread spent waiting,
+    for the GIL, in a blocking call into the runtime (a transfer, a lock),
+    for the OS. A C call that releases the GIL and computes is ON the CPU.
+    Two accounts, beside ``lap`` / ``total`` / ``parts`` and never in them.
+    The THREAD's: ``settle`` reads the CPU clock once a lap, and
+    ``thread_offcpu`` sums ``wall - cpu`` over the laps that followed one
+    another on one thread: one reading a lap, and exact to the CPU clock's
+    grain over any window, since the laps' readings telescope. The BLOCKS':
+    in one lap of ``_SAMPLE_EVERY`` every phase and part reads the CPU clock
+    at both ends and adds ``_SAMPLE_EVERY x cpu`` to ``cpu`` / ``parts_cpu``
+    (settled into ``cpu_total`` / ``parts_cpu_total``): an estimate of the
+    block's CPU seconds over ALL laps, every lap where a reading is cheap,
+    and a block's off-CPU seconds are its wall seconds less that. No block
+    is clamped: where the CPU clock moves in ticks a block reads 0 or a
+    whole tick, and only the sums mean anything; a reader clamps a
+    difference to ``[0, wall]`` (``InferenceEngine._step_offcpu``). What
+    ``settle`` hands to ``rest`` takes, in a sampled lap, the CPU seconds
+    that the lap's own reading has over its phases'.
+
     ``settle`` also keeps the longest lap so far without its ``loop_wait``
     (``longest_wall_s``) and that same lap's ``device_wait``
     (``longest_device_wait_s``): a window's means cannot tell one lap that
-    stood still from a loop that idles."""
+    stood still from a loop that idles. And it RATES every lap it is told
+    did work (``rated``): after ``_STALL_AFTER_LAPS`` rated laps, one whose
+    wall (without ``loop_wait``) is ``_STALL_TIMES`` the mean of the rated
+    laps before it is STALLED, and does not enter the mean. ``stalls`` holds
+    monotonic sums a reader differences: rated and stalled laps, and over
+    the stalled ones their wall, the ``device_wait`` in it (the device's or
+    the machine's) and the rest (the host's). A stalled lap also leaves a
+    timeline event ``<prefix>_stall``, a zero-length annotation
+    ``<prefix>.stall`` where it ended on the profiler's clock, outside every
+    phase, and a warning in the log, at most one a second.
+
+    ``reads``: what a reader of the device counts on the clock it is handed
+    (``PagedModelRunner.read``): its waits, and those that found the result
+    there."""
 
     def __init__(self, prefix: str, phases=(), parts=()):
         self.prefix = prefix
@@ -109,12 +189,39 @@ class PhaseClock:
         #: ``"<phase>.<part>"`` -> seconds since the last settle / settled
         self.parts: Dict[str, float] = dict.fromkeys(parts, 0.0)
         self.parts_total: Dict[str, float] = dict.fromkeys(parts, 0.0)
+        #: the same four for the CPU seconds of the same blocks (estimates from
+        #: the sampled laps), and the thread's off-CPU seconds over its laps
+        self.cpu: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        self.cpu_total: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        self.parts_cpu: Dict[str, float] = dict.fromkeys(parts, 0.0)
+        self.parts_cpu_total: Dict[str, float] = dict.fromkeys(parts, 0.0)
+        self.thread_offcpu = 0.0
         self.longest_wall_s = 0.0
         self.longest_device_wait_s = 0.0
+        self.stalls: Dict[str, float] = {
+            "laps": 0, "stalled": 0, "wall_s": 0.0, "device_wait_s": 0.0, "host_s": 0.0,
+        }
+        self.reads: Dict[str, int] = {"reads": 0, "ready": 0}
         #: perf_counter of the last settle: a loop resumes its account here
         self.settled_at = time.perf_counter()
         self._open: Optional[str] = None  # the phase this thread is in
         self._in_part = False
+        # whether this lap's blocks read the CPU clock (the first does: a
+        # clock nobody settles keeps one lap), and the laps settled
+        self._sampled = True
+        self._laps = 0
+        # of a sampled lap: the readings its blocks took, its phases, their CPU seconds
+        self._reads = 0
+        self._phases = 0
+        self._phases_cpu = 0.0
+        # the CPU clock at the last settle, and the thread that read it
+        self._cpu_at = 0.0
+        self._cpu_thread: Optional[int] = None
+        # the rated laps that were not stalled: how many, their wall
+        self._usual_laps = 0
+        self._usual_s = 0.0
+        self._stall_run = 0
+        self._warned_at = float("-inf")
 
     def phase(self, name: str, **args) -> "_Phase":
         return _Phase(self, name, args)
@@ -127,24 +234,86 @@ class PhaseClock:
             )
         return _Part(self, f"{self._open}.{name}")
 
-    def settle(self, since: float, rest: str) -> None:
+    def settle(self, since: float, rest: str, rated: bool = True) -> None:
         now = time.perf_counter()
+        cpu, thread = _cpu_now(), threading.get_ident()
+        lap_cpu = None  # unknown: the first lap, another thread's, one after a gap
+        if thread == self._cpu_thread and since - self.settled_at < _LAP_GAP_S:
+            lap_cpu = cpu - self._cpu_at
+            self.thread_offcpu += now - self.settled_at - lap_cpu
+        self._cpu_at, self._cpu_thread = cpu, thread
         lap, total = self.lap, self.total
         claimed = sum(lap.values())
         unclaimed = max(0.0, now - since - claimed)
         lap[rest] = lap.get(rest, 0.0) + unclaimed
+        accounts = [(lap, total), (self.parts, self.parts_total)]
+        if self._sampled:
+            if lap_cpu is not None:
+                # what the lap's own reading has over its phases': the rest's,
+                # with the half of each phase's two readings that lies outside it
+                rest_cpu = lap_cpu - self._phases_cpu - self._phases * _CPU_READ_S
+                self.cpu[rest] = self.cpu.get(rest, 0.0) + _SAMPLE_EVERY * rest_cpu
+            self._reads, self._phases, self._phases_cpu = 0, 0, 0.0
+            accounts += [(self.cpu, self.cpu_total), (self.parts_cpu, self.parts_cpu_total)]
         wall = claimed + unclaimed - lap.get("loop_wait", 0.0)
         if wall > self.longest_wall_s:
             self.longest_wall_s = wall
             self.longest_device_wait_s = lap.get("device_wait", 0.0)
-        for name, seconds in lap.items():
-            total[name] = total.get(name, 0.0) + seconds
-            lap[name] = 0.0
-        parts, parts_total = self.parts, self.parts_total
-        for name, seconds in parts.items():
-            parts_total[name] = parts_total.get(name, 0.0) + seconds
-            parts[name] = 0.0
+        if rated:
+            self._rate(wall, now, since, lap_cpu)
+        for since_settle, settled in accounts:
+            for name, seconds in since_settle.items():
+                settled[name] = settled.get(name, 0.0) + seconds
+                since_settle[name] = 0.0
         self.settled_at = now
+        self._laps += 1
+        self._sampled = self._laps % _SAMPLE_EVERY == 0
+
+    def _rate(self, wall: float, now: float, since: float, lap_cpu: Optional[float]) -> None:
+        stalls = self.stalls
+        stalls["laps"] += 1
+        if (
+            self._usual_laps < _STALL_AFTER_LAPS
+            or wall * self._usual_laps < _STALL_TIMES * self._usual_s
+        ):
+            self._usual_laps += 1
+            self._usual_s += wall
+            self._stall_run = 0
+            return
+        device_wait = min(wall, self.lap.get("device_wait", 0.0))
+        usual_ms = 1e3 * self._usual_s / self._usual_laps
+        stalls["stalled"] += 1
+        stalls["wall_s"] += wall
+        stalls["device_wait_s"] += device_wait
+        stalls["host_s"] += wall - device_wait
+        self._stall_run += 1
+        if self._stall_run >= _STALL_RUN:
+            self._usual_laps, self._usual_s, self._stall_run = 0, 0.0, 0
+        args = {
+            "wall_ms": round(1e3 * wall, 3),
+            "device_wait_ms": round(1e3 * device_wait, 3),
+            "usual_ms": round(usual_ms, 3),
+        }
+        if lap_cpu is not None:  # the lap's CPU seconds: a host that worked, or one that stood still
+            args["cpu_ms"] = round(1e3 * lap_cpu, 3)
+        end_us = _now_us()
+        record_event(
+            f"{self.prefix}_stall", "inference", end_us - 1e6 * (now - since), end_us,
+            args={**args, "phases_ms": {n: round(1e3 * s, 3) for n, s in self.lap.items() if s}},
+        )
+        span = _annotation(f"{self.prefix}.stall", args)
+        if span is not None:
+            with span:
+                pass
+        if now - self._warned_at >= 1.0:
+            self._warned_at = now
+            logger.warning(
+                "%s: a lap of %.0f ms, %.0f x the usual %.1f ms: %.0f ms waiting for the "
+                "device, %.0f ms on the host (stalled lap %d of %d)",
+                self.prefix, args["wall_ms"], args["wall_ms"] / usual_ms, usual_ms,
+                args["device_wait_ms"], args["wall_ms"] - args["device_wait_ms"],
+                stalls["stalled"], stalls["laps"],
+            )
 
 
 def _annotation(name: str, args: Dict[str, Any]):
@@ -154,31 +323,55 @@ def _annotation(name: str, args: Dict[str, Any]):
     return profiler.TraceAnnotation(name, **args) if profiler is not None else None
 
 
+def _sampled_cpu(cpu: float, inner: int = 0) -> float:
+    """What a sampled block adds to its CPU account, from the reading ``cpu``
+    between its two readings of the clock with ``inner`` readings of its
+    parts between them: its own CPU seconds (the reading less the clock's
+    own time in it: about one reading of its two, and the parts' whole) for
+    every lap it stands for, and all those readings once: they are in the
+    block's wall seconds this lap, and were not waited."""
+    return _SAMPLE_EVERY * (cpu - (1 + inner) * _CPU_READ_S) + (2 + inner) * _CPU_READ_S
+
+
 class _Phase:
-    __slots__ = ("_clock", "_name", "_span", "_t0")
+    __slots__ = ("_clock", "_name", "_span", "_t0", "_c0", "_n0", "seconds")
 
     def __init__(self, clock: PhaseClock, name: str, args: Dict[str, Any]):
         self._clock = clock
         self._name = name
         self._span = _annotation(f"{clock.prefix}.{name}", args)
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "_Phase":
         if self._span is not None:
             self._span.__enter__()
-        self._clock._open = self._name
+        clock = self._clock
+        clock._open = self._name
         self._t0 = time.perf_counter()
+        if clock._sampled:
+            self._n0 = clock._reads
+            self._c0 = _cpu_now()
+        else:
+            self._c0 = None
+        return self
 
     def __exit__(self, *exc) -> None:
-        seconds = time.perf_counter() - self._t0
-        clock = self._clock
-        clock.lap[self._name] = clock.lap.get(self._name, 0.0) + seconds
+        clock, name = self._clock, self._name
+        cpu = _cpu_now() - self._c0 if self._c0 is not None else None
+        #: the block's wall seconds, for the caller that asked ``as``
+        self.seconds = seconds = time.perf_counter() - self._t0
+        if cpu is not None:
+            clock.cpu[name] = clock.cpu.get(name, 0.0) + _sampled_cpu(cpu, clock._reads - self._n0)
+            clock._reads += 2
+            clock._phases += 1
+            clock._phases_cpu += cpu
+        clock.lap[name] = clock.lap.get(name, 0.0) + seconds
         clock._open = None
         if self._span is not None:
             self._span.__exit__(*exc)
 
 
 class _Part:
-    __slots__ = ("_clock", "_key", "_span", "_t0")
+    __slots__ = ("_clock", "_key", "_span", "_t0", "_c0")
 
     def __init__(self, clock: PhaseClock, key: str):
         self._clock = clock
@@ -188,13 +381,19 @@ class _Part:
     def __enter__(self) -> None:
         if self._span is not None:
             self._span.__enter__()
-        self._clock._in_part = True
+        clock = self._clock
+        clock._in_part = True
         self._t0 = time.perf_counter()
+        self._c0 = _cpu_now() if clock._sampled else None
 
     def __exit__(self, *exc) -> None:
+        clock, key = self._clock, self._key
+        cpu = _cpu_now() - self._c0 if self._c0 is not None else None
         seconds = time.perf_counter() - self._t0
-        clock = self._clock
-        clock.parts[self._key] = clock.parts.get(self._key, 0.0) + seconds
+        if cpu is not None:
+            clock.parts_cpu[key] = clock.parts_cpu.get(key, 0.0) + _sampled_cpu(cpu)
+            clock._reads += 2
+        clock.parts[key] = clock.parts.get(key, 0.0) + seconds
         clock._in_part = False
         if self._span is not None:
             self._span.__exit__(*exc)
