@@ -7,7 +7,9 @@ and pushes them into per-request queues that :meth:`generate` drains —
 so tokens stream to the caller WHILE other requests keep decoding. The
 push waits until the NEXT launch is on its way (``_hold`` /
 ``_deliver_held``): the streams it wakes then run while the device does.
-The loop of a SATURATED engine leaves an all-greedy decode launch unread
+Where an arrival could not have had the next step's place anyway (the slots
+are full or requests wait; or a running request's next prefill chunk is
+already due there) the loop leaves an all-greedy decode launch unread
 while it plans and launches the next step (all its programs before it waits
 for any), whose rows take their tokens from that launch's result on the
 device (``_stays_unread``): a plain batch's picks, or, where the model drafts
@@ -235,6 +237,9 @@ class _DecodeBatch:
     greedy: bool
     #: a drafter's step: its rows' windows (None: a plain decode launch)
     windows: Optional[_Windows] = None
+    #: left unread for the chunk due in the next step alone: the engine had
+    #: room (:meth:`InferenceEngine._next_step_taken_by`)
+    chunk_due: bool = False
 
 
 @dataclass
@@ -553,6 +558,9 @@ class InferenceEngine:
         #: unread, and results dropped before they reached a stream
         #: (monotonic; stats()["decode_ahead"])
         self._decode_ahead = {"launches": 0, "ahead": 0, "dropped": 0}
+        #: of ``ahead``, the launches behind one that a due chunk alone left
+        #: unread: the engine had room (stats()["decode_ahead_chunk_due"])
+        self._ahead_chunk_due = 0
         # request id -> submitter's (trace_id, span_id): the step-loop
         # thread stamps per-request spans (admission→first-token,
         # admission→finish) under the serve caller's trace
@@ -1273,9 +1281,10 @@ class InferenceEngine:
         What the step commits reaches the requests' queues before it
         returns, and every program it launched has been read, unless
         ``hold_wakes``: the step loop's own calls leave the last commits'
-        items held, for the next step to deliver after ITS launches, and on
-        a saturated engine the decode launch unread, for the next step to
-        read after them (:meth:`_stays_unread`)."""
+        items held, for the next step to deliver after ITS launches, and,
+        where no arrival could have had the next step's slot or chunk, the
+        decode launch unread, for the next step to read after them
+        (:meth:`_stays_unread`)."""
         since = time.perf_counter()
         # timeline timestamps share the module's wall-clock epoch so
         # engine_step events merge with every other process's trace
@@ -1409,10 +1418,12 @@ class InferenceEngine:
         # the two are two spans and not 2 x slots slivers; the tokens
         # and their order are those of sampling and emitting in turn
         if batch is not None:
-            if in_loop and not spec_slots and self._stays_unread(plan, batch):
+            stays = self._stays_unread(plan, batch) if in_loop and not spec_slots else None
+            if stays is not None:
                 for row, req in enumerate(batch.reqs):
                     req.in_flight = row
                     req.in_flight_most = 1 + (batch.windows.riding(row) if batch.windows else 0)
+                batch.chunk_due = stays == "chunk"
                 self._unread = batch
             else:
                 self._read_decode(batch)
@@ -1490,6 +1501,7 @@ class InferenceEngine:
         counts = self._decode_ahead
         counts["launches"] += 1
         counts["ahead"] += unread is not None
+        self._ahead_chunk_due += unread is not None and unread.chunk_due
         return _DecodeBatch(plain, launched, greedy)
 
     def _account_held(self) -> None:
@@ -1545,33 +1557,51 @@ class InferenceEngine:
                         self._emit_token(req, token)
         return n_prefill_tokens
 
-    def _stays_unread(self, plan, batch: "_DecodeBatch") -> bool:
+    def _stays_unread(self, plan, batch: "_DecodeBatch") -> Optional[str]:
         """Whether the step loop may go on to plan the next step before it
-        reads this decode launch. One predicate, read off the engine's own
-        state: the device holds the batch's next tokens and the next launch
+        reads this decode launch: what lets it (:meth:`_next_step_taken_by`'s
+        answer), or None: read it now. One predicate, read off the engine's
+        own state: the device holds the batch's next tokens and the next launch
         can take them there (a plain, all-greedy batch in which nothing may
         speculate: the picks; or the all-greedy step of a model that drafts
         for itself, in its ONE-program form: its new tokens, accepted count
         and next draft are the next window, and every request that could
         decode next is greedy too, so that the next step is that program
         again; a batch a proposer on the HOST drafts for never is; no chunk
-        of the step is an export), and the engine is SATURATED: an arrival
-        could not get a decode slot anyway, because the running requests
-        (those in prefill hold the slots they will decode in) fill the batch
-        or requests already wait. Then looking ahead costs nobody a slot it
-        could have had; on an engine with room it would cost an arrival up to
+        of the step is an export), and AN ARRIVAL COULD NOT HAVE HAD THE NEXT
+        STEP'S PLACE ANYWAY. Then looking ahead costs nobody a slot or a
+        chunk it could have had; anywhere else it would cost an arrival up to
         one decode step before its chunk can start."""
-        sched = self.scheduler
         if batch.windows is None:
             on_device = all(r.spec_k == 0 for r in batch.reqs)
         else:
-            on_device = all(r.temperature <= 0.0 for r in list(sched.running))
-        return (
-            batch.greedy
-            and on_device
-            and not any(p[0].prefill_only for p in plan.prefills)
-            and (len(sched.running) >= sched.max_decode_batch or bool(sched.waiting))
-        )
+            on_device = all(r.temperature <= 0.0 for r in list(self.scheduler.running))
+        if not (batch.greedy and on_device) or any(p[0].prefill_only for p in plan.prefills):
+            return None
+        return self._next_step_taken_by()
+
+    def _next_step_taken_by(self) -> Optional[str]:
+        """What keeps an arrival out of the next step, ``"slot"`` or
+        ``"chunk"``, or None where it could have a place there; called once
+        this step's chunks are committed. No SLOT: the running requests
+        (those in prefill hold the slots they will decode in) fill the decode
+        batch, or requests already wait. No CHUNK: as many running requests
+        still have prompt left as a step has chunks, and the plan gives a
+        step's chunks to the oldest of them, so the next step is ``[their
+        chunks, the decode batch]`` whoever arrives and an arrival's first
+        chunk lands in the same step either way.
+
+        Not promised: an arrival of HIGHER priority than the request in
+        prefill would have overtaken its chunk and now waits one chunk more,
+        as it already may behind a full batch. And where a window pool
+        refuses the due chunk the next step carries none: the arrival stands
+        behind the refused chunk for that step with or without its decode
+        launch."""
+        sched = self.scheduler
+        if len(sched.running) >= sched.max_decode_batch or sched.waiting:
+            return "slot"
+        in_prefill = sum(not r.prefill_done for r in list(sched.running))
+        return "chunk" if in_prefill >= sched.max_prefills_per_step else None
 
     def _read_unread(self, later: Optional["_DecodeBatch"] = None) -> bool:
         """Read the decode launch the last step left unread, if it left one.
@@ -2732,6 +2762,9 @@ class InferenceEngine:
             "wakes": dict(self._wakes),
             # how often the loop launched a decode step before it had read the last
             "decode_ahead": dict(self._decode_ahead),
+            # of those ahead, the ones a due chunk alone allowed: the slots
+            # had room (a key beside the dict: its three are pinned)
+            "decode_ahead_chunk_due": self._ahead_chunk_due,
             # how wide the decode and verify launches gathered (the target
             # runner's own; a draft model's runner keeps its own count)
             "decode_width": dict(self.runner.decode_width),

@@ -1,7 +1,8 @@
-"""Launch before read (ISSUE 39): the loop of a saturated engine launches
-decode step n + 1 before it reads step n, the rows of n + 1 take their tokens
-from n's picks on the device, and every stream is token for token what the
-synchronous order gives (an engine driven by ``step()`` from outside the loop
+"""Launch before read (ISSUE 39): where an arrival could not have had the next
+step's slot or chunk anyway (ISSUE 55) the loop launches decode step n + 1
+before it reads step n, the rows of n + 1 take their tokens from n's picks on
+the device, and every stream is token for token what the synchronous order
+gives (an engine driven by ``step()`` from outside the loop
 never looks ahead: it is the reference here). CPU, tiny configs, through the
 started loop: what is checked is tokens, order and counters, never a speed."""
 
@@ -270,23 +271,50 @@ def test_a_batch_that_turns_sampled_or_speculative_is_read_at_once(turn):
 
 # -- when it engages ---------------------------------------------------------------------------------
 
-def test_an_engine_with_room_reads_every_launch_in_its_own_step():
-    eng = _engine("llama", max_decode_batch=4, decode_buckets=(4,))
-    rids = [eng.submit(p, max_new_tokens=8) for p in PROMPTS[:2]]  # 2 of 4 slots, nobody waits
+@pytest.mark.parametrize("chunks", [1, 3])
+def test_an_engine_with_room_reads_every_launch_in_its_own_step_unless_a_chunk_is_due(chunks):
+    """2 of 4 slots and nobody waits. Prompts of one chunk: nothing is ever
+    left unread. Prompts of three (three times the largest prefill bucket): a
+    decode launch stays unread exactly where a request still has prompt left
+    once the step's chunk is committed, and the streams are the synchronous
+    engine's."""
+    kw = dict(max_decode_batch=4, decode_buckets=(4,), prefill_buckets=(8, 16))
+    prompts = [(p * chunks)[: 16 * chunks] for p in ([5, 6, 7, 8] * 4, [9, 8, 7, 6, 5] * 4)]
+    submit = lambda eng: [eng.submit(p, max_new_tokens=8) for p in prompts]  # noqa: E731
+    want = _synchronous("llama", submit, **kw)
+    assert [len(items) for items in want] == [9, 9]
+    eng = _engine("llama", **kw)
+    assert eng.scheduler.max_prefill_chunk == 16
+    decided, stays = [], eng._stays_unread
+
+    def logged(plan, batch):
+        due = any(not r.prefill_done for r in eng.scheduler.running)
+        decided.append((due, stays(plan, batch)))
+        return decided[-1][1]
+
+    eng._stays_unread = logged
+    rids = submit(eng)
     eng.start()
     try:
-        assert all(len(_drain(eng, r)) == 9 for r in rids)
-        assert eng.wait_idle()
+        got = [_drain(eng, r) for r in rids]
+        assert eng.wait_idle() and eng._unread is None
     finally:
         eng.stop()
+    assert got == want
     ahead = _all_returned(eng)
-    assert ahead["launches"] >= 7 and ahead["ahead"] == 0 and ahead["dropped"] == 0
+    assert ahead["launches"] == len(decided) >= 7 and ahead["dropped"] == 0
+    # the first prompt decodes beside the second's chunks: behind each but the
+    # last another is due. Every launch after the last chunk is read at once
+    assert [stayed for _due, stayed in decided] == ["chunk" if due else None for due, _ in decided]
+    assert [due for due, _ in decided] == [True] * (chunks - 1) + [False] * (len(decided) - chunks + 1)
+    assert ahead["ahead"] == eng.stats()["decode_ahead_chunk_due"] == chunks - 1
 
 
 @pytest.mark.parametrize("model", MODELS)
-def test_the_three_counters_exist_from_construction(model):
+def test_the_counters_exist_from_construction(model):
     eng = _engine(model)
     assert eng.stats()["decode_ahead"] == dict.fromkeys(KEYS, 0)
+    assert eng.stats()["decode_ahead_chunk_due"] == 0
     assert eng._unread is None
 
 
@@ -413,9 +441,11 @@ def test_decode_is_launch_and_read_at_once_and_a_name_needs_the_launch_it_names(
 
 # -- the plan ------------------------------------------------------------------------------------------
 
-def _planner(num_blocks=16, **kw):
+def _planner(num_blocks=16, max_decode_batch=2, **kw):
     blocks = PagedBlockManager(num_blocks, 4)
-    return blocks, ContinuousBatchingScheduler(blocks, max_decode_batch=2, max_prefill_chunk=16, **kw)
+    return blocks, ContinuousBatchingScheduler(
+        blocks, max_decode_batch=max_decode_batch, max_prefill_chunk=16, **kw
+    )
 
 
 def _decoding(sched, rid, prompt_len, generated, max_new):
@@ -459,6 +489,77 @@ def test_a_preempted_request_restarts_without_its_token_in_flight():
     assert b.restart_prompt == b.prompt + [1] and blocks.owned("b") == []
     # the engine clears the mark when it reads (and drops) the launch
     assert b.in_flight == 1 and b not in sched.running
+
+
+# -- the plan behind a chunk that is due (ISSUE 55) ----------------------------------------------------
+# A loop that looks ahead plans step n + 1 while step n runs: an arrival that lands meanwhile meets
+# plan n + 2 first. One that does not plans n + 1 once n is read: the same arrival meets plan n + 1.
+
+def _first_chunk_step(left, looks_ahead, priority=0):
+    """The number of the step that carries an arrival's first chunk, on an
+    engine with room (2 of 4 slots): one request decodes, another has ``left``
+    chunks of prompt left once step 0's chunk is committed, and the arrival
+    lands while step 0 runs. Also what the engine's predicate says after step 0."""
+    _blocks, sched = _planner(num_blocks=64, max_decode_batch=4)
+    _decoding(sched, "d", 4, [1], 99)
+    sched.add(Request(request_id="r", prompt=[1] * 16 * (left + 1), max_new_tokens=9))
+    arrival = Request(request_id="a", prompt=[2] * 16, max_new_tokens=9, priority=priority)
+    taken_by = None
+    for step in range(left + 3):
+        if step == 1 and not looks_ahead:
+            sched.add(arrival)  # step 0 was read before step 1 was planned
+        plan = sched.schedule()
+        if step == 1 and looks_ahead:
+            sched.add(arrival)  # step 1 was planned while step 0 ran
+        assert len(plan.prefills) <= 1 and len(plan.decodes) >= 1
+        for req, start, chunk in plan.prefills:  # committed inside their own step
+            if req is arrival:
+                return step, taken_by
+            req.prefill_pos = start + chunk
+            if req.prefill_done:
+                req.state, req.generated = DECODE, [1]
+        if step == 0:
+            taken_by = InferenceEngine._next_step_taken_by(SimpleNamespace(scheduler=sched))
+    raise AssertionError("the arrival's chunk was never planned")
+
+
+@pytest.mark.parametrize("left", [1, 2, 3])
+def test_an_arrival_behind_a_due_chunk_gets_its_chunk_in_the_same_step_either_way(left):
+    step, taken_by = _first_chunk_step(left, looks_ahead=True)
+    assert taken_by == "chunk"  # so the engine does look ahead
+    assert (step, taken_by) == _first_chunk_step(left, looks_ahead=False)
+    assert step == left + 1  # right behind the older request's last chunk
+
+
+def test_with_no_chunk_due_looking_ahead_would_cost_an_arrival_a_step_and_the_engine_does_not():
+    step, taken_by = _first_chunk_step(0, looks_ahead=False)
+    assert (step, taken_by) == (1, None)
+    assert _first_chunk_step(0, looks_ahead=True)[0] == 2
+
+
+def test_an_arrival_of_higher_priority_waits_one_chunk_more_behind_a_due_chunk():
+    """What the predicate does not promise: the plan would have given step 1's
+    chunk to the arrival of higher priority, and a loop that had planned step 1
+    already gives it step 2's, as a full batch's loop already may."""
+    assert _first_chunk_step(2, looks_ahead=False, priority=1) == (1, "chunk")
+    assert _first_chunk_step(2, looks_ahead=True, priority=1) == (2, "chunk")
+    assert _first_chunk_step(2, looks_ahead=True)[0] == 3  # and one of the same priority
+
+
+def test_every_chunk_of_a_step_must_be_taken_for_the_next_step_to_be():
+    _blocks, sched = _planner(num_blocks=64, max_decode_batch=4, max_prefills_per_step=2)
+    engine = SimpleNamespace(scheduler=sched)
+    _decoding(sched, "d", 4, [1], 99)
+    sched.add(Request(request_id="r", prompt=[1] * 48, max_new_tokens=9))
+    assert [p[0].request_id for p in sched.schedule().prefills] == ["r"]
+    # one request in prefill of two chunks a step: an arrival could have had the other
+    assert InferenceEngine._next_step_taken_by(engine) is None
+    sched.add(Request(request_id="s", prompt=[1] * 48, max_new_tokens=9))
+    assert [p[0].request_id for p in sched.schedule().prefills] == ["r", "s"]
+    assert InferenceEngine._next_step_taken_by(engine) == "chunk"
+    sched.add(Request(request_id="t", prompt=[1] * 8, max_new_tokens=9))
+    sched.schedule()  # four run of four slots
+    assert InferenceEngine._next_step_taken_by(engine) == "slot"
 
 
 # -- the plan over a drafter's window in flight (ISSUE 45) ---------------------------------------------
