@@ -315,6 +315,9 @@ class PagedModelRunner:
         self.decode_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens"), 0
         )
+        if any(keeps for _, keeps in self._groups):
+            #: of ``gathered_tokens``, what is read of the groups that keep a window (by layers)
+            self.decode_width["window_read_tokens"] = 0
         #: running sums over prefill launches: the width of the table handed
         #: over (tokens), the positions up to the chunk's end, and the key
         #: positions the program's attention reads: the table's width
@@ -781,15 +784,20 @@ class PagedModelRunner:
             sum(min(int(c), keeps) if keeps else int(c) for c in ctx_lens) for _, keeps in self._groups
         )
         if reads == "blocks":  # a padding slot reads none
-            read = bs * self._by_layers(
-                sum(-(-int(c) // bs) - (max(0, int(c) - keeps) // bs if keeps else 0) for c in ctx_lens)
+            per_group = [
+                bs * sum(-(-int(c) // bs) - (max(0, int(c) - keeps) // bs if keeps else 0) for c in ctx_lens)
                 for _, keeps in self._groups
-            )
+            ]
         elif reads == "slots":  # each real slot the table whole
-            read = len(ctx_lens) * width
+            per_group = [len(ctx_lens) * width] * len(self._groups)
         else:
-            read = bucket * width
+            per_group = [bucket * width] * len(self._groups)
+        read = self._by_layers(per_group)
         dw = self.decode_width
+        if "window_read_tokens" in dw:  # of ``read``, the part of the groups that keep a window
+            dw["window_read_tokens"] += self._by_layers(
+                value if keeps else 0 for (_, keeps), value in zip(self._groups, per_group)
+            )
         dw["launches"] += 1
         dw["width_tokens"] += width
         dw["needed_tokens"] += int(max(ctx_lens))

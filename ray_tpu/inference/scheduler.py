@@ -336,11 +336,18 @@ class ContinuousBatchingScheduler:
             cached, cow = self.blocks.acquire_prefix(req.request_id, prompt)
             need = len(prompt) + 1  # headroom: first decode token
             # a group that keeps a window is asked for the first chunk's share
-            # alone (the chunks after it slide: :meth:`schedule`); the head of
-            # the queue waits while ANY pool lacks what it needs
+            # alone (the chunks after it slide: :meth:`schedule`), and of that
+            # for no more than its window: what a decoding slot holds, and so
+            # what the engine sized the pool for a slot. The rest of a chunk
+            # WIDER than the window is taken when the chunk is planned
+            # (:meth:`_slide_for_chunk`): an admitted request that waits its
+            # turn to prefill would otherwise hold a chunk where it was counted
+            # for a window. The head of the queue waits while ANY pool lacks
+            # what it needs
             first = None
             if self.blocks.windows:
-                first = (cached, min(need, cached + self.max_prefill_chunk))
+                share = min(self.max_prefill_chunk, *(w.keeps for w in self.blocks.windows))
+                first = (cached, min(need, cached + share))
             if not self.blocks.grow_to(req.request_id, need, first):
                 if cached or cow:
                     # roll the acquisition back: a QUEUED request must

@@ -20,7 +20,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,26 @@ class RopeScaling:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """What a KIND of attention layer is: layers of one kind differ in their
+    weights alone. ``LlamaConfig.layer_kinds`` names one a layer; a configuration
+    that names none has the kinds its ``layer_windows`` and ``rope_scaling`` spell
+    (``LlamaConfig.kind_of``), every one with ``n_heads`` query heads."""
+
+    #: query at ``i`` sees key ``j`` iff ``i - window < j <= i``; 0: every ``j <= i``
+    window: int = 0
+    #: query heads (over the configuration's ``n_kv_heads``: grouped-query)
+    n_heads: int = 32
+    #: the base of the kind's rotary table
+    rope_theta: float = 10000.0
+    #: how many numbers of a head are rotated, the FIRST ones (the others pass
+    #: as they are); 0: the whole head. The table is computed for this width
+    rotary_dim: int = 0
+    #: YaRN over ``rope_theta``; None = the plain table
+    rope_scaling: Optional[RopeScaling] = None
 
 
 @dataclass(frozen=True)
@@ -106,6 +126,47 @@ class LlamaConfig:
     #: further back than its window and keeps the plain table of
     #: ``rope_theta``). None = the plain table everywhere
     rope_scaling: Optional[RopeScaling] = None
+    #: layer kinds that differ in MORE than their window (query heads, the
+    #: rope's base, its rotated width, YaRN or not): a :class:`LayerKind` a
+    #: layer. It then says everything of a layer's attention: ``layer_windows``
+    #: is read from it and ``n_heads`` / ``rope_theta`` / ``rope_scaling`` by no
+    #: layer. Empty = the kinds the four fields above spell
+    layer_kinds: Tuple[LayerKind, ...] = ()
+    #: a learned sigmoid gate a HEAD on the attention output, from the block's
+    #: normed input (``wg [dim, heads]``, no bias), before ``wo``
+    attn_gate: bool = False
+    #: the layers whose FFN is a dense gated MLP of width ``dense_mlp_hidden``
+    #: where the others route (``moe_experts`` > 0); with no experts every
+    #: layer is dense of ``mlp_hidden`` and this is read by none
+    dense_layers: Tuple[int, ...] = ()
+    dense_mlp_hidden: int = 0
+    #: a routed layer's scores: ``"softmax"`` over the experts or ``"sigmoid"``
+    #: each (``ops/moe.py::route``); ``moe_scale`` multiplies the kept gates last
+    moe_scoring: str = "softmax"
+    moe_scale: float = 1.0
+    #: >0: a SHARED expert of this width beside the routed ones, every row
+    #: through it, ungated (``shared_gate`` / ``shared_up`` / ``shared_down``)
+    moe_shared_hidden: int = 0
+    #: SEEDED weights alone (:func:`init_params`): each sublayer's LAST
+    #: projection (``wo`` over its own fan-in of heads x head_dim, ``w_down``,
+    #: ``shared_down``) a further 1 / sqrt(2 x layers) smaller, and a ROUTED
+    #: expert's an eighth of that: with independent random experts a hard
+    #: top-8 of 256 flips on bfloat16's rounding and through 39 routed layers
+    #: the flips feed on each other (``models/xing4.py::init_params`` says
+    #: why, ``kimi_linear.py`` why an eighth; here the logits read 0.10-0.41
+    #: against the float32 reference without it: PERF.md, PR 56). Off, as
+    #: every configuration before it was drawn
+    init_depth_scaled: bool = False
+
+    def __post_init__(self):
+        if self.layer_kinds:
+            windows = tuple(kind.window for kind in self.layer_kinds)
+            if len(windows) != self.n_layers or self.layer_windows not in ((), windows):
+                raise ValueError(
+                    f"layer_kinds names {len(windows)} layers of {self.n_layers}; layer_windows, "
+                    "where given beside it, must say the same windows"
+                )
+            object.__setattr__(self, "layer_windows", windows)
 
     @property
     def head_dim(self) -> int:
@@ -113,6 +174,33 @@ class LlamaConfig:
 
     def window_of(self, layer: int) -> int:
         return self.layer_windows[layer] if self.layer_windows else 0
+
+    def kind_of(self, layer: int) -> LayerKind:
+        """The kind of layer ``layer``: as ``layer_kinds`` names it, or the one
+        the plain fields spell (YaRN for a layer without a window alone)."""
+        if self.layer_kinds:
+            return self.layer_kinds[layer]
+        return self.kind_of_window(self.window_of(layer))
+
+    def kind_of_window(self, window: int) -> LayerKind:
+        """The kind of the layers that keep ``window``, for a caller that knows
+        a layer by its window alone; kinds that share a window are not told
+        apart that way."""
+        if not self.layer_kinds:
+            return LayerKind(window, self.n_heads, self.rope_theta, 0, None if window else self.rope_scaling)
+        kinds = {kind for kind in self.layer_kinds if kind.window == window}
+        if len(kinds) != 1:
+            raise ValueError(f"{len(kinds)} kinds of layer keep a window of {window}: name the layer's kind")
+        return kinds.pop()
+
+    @property
+    def kinds(self) -> Tuple[LayerKind, ...]:
+        """The distinct kinds, in the order of their first layers."""
+        return tuple(dict.fromkeys(self.kind_of(layer) for layer in range(self.n_layers)))
+
+    def routes(self, layer: int) -> bool:
+        """Whether layer ``layer``'s FFN is routed experts."""
+        return self.moe_experts > 0 and layer not in self.dense_layers
 
     @staticmethod
     def llama2_7b(**overrides) -> "LlamaConfig":
@@ -139,17 +227,20 @@ class LlamaConfig:
 # params + logical sharding axes
 
 
-def _layer_shapes(cfg: LlamaConfig) -> Dict[str, Tuple[int, ...]]:
+def _layer_shapes(cfg: LlamaConfig, layer: int = 0) -> Dict[str, Tuple[int, ...]]:
+    """name -> shape of layer ``layer``'s weights: its kind's query heads, its
+    FFN's kind. A configuration whose layers are all alike: any layer's."""
     hd = cfg.head_dim
+    heads = cfg.kind_of(layer).n_heads
     shapes = {
         "attn_norm": (cfg.dim,),
-        "wq": (cfg.dim, cfg.n_heads, hd),
+        "wq": (cfg.dim, heads, hd),
         "wk": (cfg.dim, cfg.n_kv_heads, hd),
         "wv": (cfg.dim, cfg.n_kv_heads, hd),
-        "wo": (cfg.n_heads, hd, cfg.dim),
+        "wo": (heads, hd, cfg.dim),
         "mlp_norm": (cfg.dim,),
     }
-    if cfg.moe_experts > 0:
+    if cfg.routes(layer):
         held = cfg.moe_experts if cfg.moe_held is None else cfg.moe_held[1] - cfg.moe_held[0]
         shapes.update(
             {
@@ -160,46 +251,51 @@ def _layer_shapes(cfg: LlamaConfig) -> Dict[str, Tuple[int, ...]]:
             }
         )
     else:
+        hidden = cfg.dense_mlp_hidden if cfg.moe_experts > 0 else cfg.mlp_hidden
         shapes.update(
             {
-                "w_gate": (cfg.dim, cfg.mlp_hidden),
-                "w_up": (cfg.dim, cfg.mlp_hidden),
-                "w_down": (cfg.mlp_hidden, cfg.dim),
+                "w_gate": (cfg.dim, hidden),
+                "w_up": (cfg.dim, hidden),
+                "w_down": (hidden, cfg.dim),
             }
         )
     if cfg.qk_norm:
         # last, so that the other weights of a layer draw the same keys
-        shapes.update({"q_norm": (cfg.n_heads * hd,), "k_norm": (cfg.n_kv_heads * hd,)})
+        shapes.update({"q_norm": (heads * hd,), "k_norm": (cfg.n_kv_heads * hd,)})
+    if cfg.attn_gate:
+        shapes["wg"] = (cfg.dim, heads)
+    if cfg.moe_shared_hidden and cfg.routes(layer):
+        shared = cfg.moe_shared_hidden
+        shapes.update(
+            {"shared_gate": (cfg.dim, shared), "shared_up": (cfg.dim, shared), "shared_down": (shared, cfg.dim)}
+        )
     return shapes
 
 
 def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     """Pytree (same structure as params) of logical-axis-name tuples."""
-    layer = {
+    from ray_tpu.ops.moe import moe_logical_axes
+
+    dense = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+    axes = {
         "attn_norm": (None,),
         "wq": ("embed", "heads", "head_dim"),
         "wk": ("embed", "kv_heads", "head_dim"),
         "wv": ("embed", "kv_heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
         "mlp_norm": (None,),
+        "q_norm": (None,), "k_norm": (None,),
+        "wg": ("embed", "heads"),
+        **{f"shared_{name[2:]}": spec for name, spec in dense.items()},
     }
-    if cfg.moe_experts > 0:
-        from ray_tpu.ops.moe import moe_logical_axes
 
-        layer.update(moe_logical_axes())
-    else:
-        layer.update(
-            {
-                "w_gate": ("embed", "mlp"),
-                "w_up": ("embed", "mlp"),
-                "w_down": ("mlp", "embed"),
-            }
-        )
-    if cfg.qk_norm:
-        layer.update({"q_norm": (None,), "k_norm": (None,)})
+    def layer(index: int):
+        ffn = moe_logical_axes() if cfg.routes(index) else dense
+        return {name: ffn.get(name) or axes[name] for name in _layer_shapes(cfg, index)}
+
     return {
         "embed": ("vocab", "embed"),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
+        "layers": [layer(index) for index in range(cfg.n_layers)],
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
@@ -223,17 +319,20 @@ def _init_params(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
 
     _MOE_PARAMS = ("router", "w_gate", "w_up", "w_down")
 
-    def layer(key):
-        shapes = _layer_shapes(cfg)
+    def layer(key, index):
+        shapes = _layer_shapes(cfg, index)
         ks = jax.random.split(key, len(shapes))
         out = {}
-        moe = cfg.moe_experts > 0
+        moe = cfg.routes(index)
         for (name, shape), k in zip(shapes.items(), ks):
             if name.endswith("norm"):
                 out[name] = jnp.ones(shape, cfg.dtype)
             elif moe and name == "router":
                 # routing logits are precision-sensitive: keep f32
                 out[name] = jax.random.normal(k, shape, jnp.float32) / math.sqrt(shape[0])
+            elif cfg.init_depth_scaled and name in ("wo", "w_down", "shared_down"):
+                fan_in = (shape[0] * shape[1] if name == "wo" else shape[-2]) * 2 * cfg.n_layers
+                out[name] = dense(k, shape, fan_in * (64 if moe and name == "w_down" else 1))
             elif moe and name in _MOE_PARAMS:
                 # (E, fan_in, fan_out): contraction dim is shape[-2]
                 out[name] = dense(k, shape, shape[-2])
@@ -243,7 +342,7 @@ def _init_params(cfg: LlamaConfig, rng: jax.Array) -> Dict[str, Any]:
 
     return {
         "embed": dense(keys[0], (cfg.vocab_size, cfg.dim), cfg.dim),
-        "layers": [layer(keys[i + 1]) for i in range(cfg.n_layers)],
+        "layers": [layer(keys[i + 1], i) for i in range(cfg.n_layers)],
         "final_norm": jnp.ones((cfg.dim,), cfg.dtype),
         "lm_head": dense(keys[-1], (cfg.dim, cfg.vocab_size), cfg.dim),
     }
@@ -257,6 +356,11 @@ def partition_rules(cfg: LlamaConfig, rules) -> list:
     adam's ``count`` are skipped by the matcher). Specs derive from the
     ``ShardingRules`` table, so swapping ddp/fsdp/tp re-derives the whole
     set. Overrides go in FRONT (first ``re.search`` hit wins)."""
+    if cfg.moe_experts > 0 and cfg.dense_layers:
+        raise ValueError(
+            "a configuration with dense layers beside routed ones has no partition rules: w_gate, w_up "
+            "and w_down are told apart by name alone here, and a name has one rank"
+        )
     sp = rules.spec
     out = [
         # factored second-moment stats (adafactor v_row/v_col) are
@@ -268,6 +372,9 @@ def partition_rules(cfg: LlamaConfig, rules) -> list:
         (r"(^|/)embed$", sp(("vocab", "embed"))),
         (r"(attn_norm|mlp_norm|final_norm|q_norm|k_norm)$", sp((None,))),
         (r"wq$", sp(("embed", "heads", "head_dim"))),
+        (r"wg$", sp(("embed", "heads"))),
+        (r"shared_(gate|up)$", sp(("embed", "mlp"))),
+        (r"shared_down$", sp(("mlp", "embed"))),
         (r"(wk|wv)$", sp(("embed", "kv_heads", "head_dim"))),
         (r"wo$", sp(("heads", "head_dim", "embed"))),
         (r"lm_head$", sp(("embed", "vocab"))),
@@ -287,11 +394,12 @@ def partition_rules(cfg: LlamaConfig, rules) -> list:
 
 
 def param_count(cfg: LlamaConfig) -> int:
-    shapes = list(_layer_shapes(cfg).values())
-    per_layer = sum(math.prod(s) for s in shapes)
+    layers = sum(
+        math.prod(shape) for layer in range(cfg.n_layers) for shape in _layer_shapes(cfg, layer).values()
+    )
     return (
         cfg.vocab_size * cfg.dim * 2  # embed + lm_head
-        + per_layer * cfg.n_layers
+        + layers
         + cfg.dim
     )
 
@@ -306,20 +414,23 @@ def rms_norm(x, weight, eps: float):
     return (x32 * inv).astype(x.dtype) * weight
 
 
-def _inv_freq(cfg: LlamaConfig, window: int = 0):
-    """The rotary frequencies ``[hd / 2]`` of a layer and what multiplies its
-    cos and sin: the plain table of ``rope_theta`` and 1, or, for a layer
-    without a window under ``cfg.rope_scaling``, YaRN's blend and its
-    attention factor."""
-    hd = cfg.head_dim
-    plain = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
-    y = cfg.rope_scaling
-    if y is None or window:
+def _inv_freq(cfg: LlamaConfig, kind: Union[int, LayerKind] = 0):
+    """The rotary frequencies ``[rotated / 2]`` of a kind of layer (or, an int,
+    of the kind that keeps that window) and what multiplies its cos and sin:
+    the plain table of the kind's ``rope_theta`` over the width it rotates and
+    1, or under the kind's ``rope_scaling`` YaRN's blend and its attention
+    factor."""
+    if not isinstance(kind, LayerKind):
+        kind = cfg.kind_of_window(kind)
+    hd = kind.rotary_dim or cfg.head_dim
+    plain = 1.0 / (kind.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
+    y = kind.rope_scaling
+    if y is None:
         return plain, 1.0
 
     def correction_dim(rotations: float) -> float:
         return (hd * math.log(y.original_max / (rotations * 2 * math.pi))) / (
-            2 * math.log(cfg.rope_theta)
+            2 * math.log(kind.rope_theta)
         )
 
     low = max(math.floor(correction_dim(y.beta_fast)), 0)
@@ -335,14 +446,18 @@ def _cos_sin(ang, factor: float):
     return jnp.cos(ang) * factor, jnp.sin(ang) * factor
 
 
-def rope_tables(cfg: LlamaConfig, seq_len: int, offset: int = 0, window: int = 0):
+def rope_tables(cfg: LlamaConfig, seq_len: int, offset: int = 0, window: Union[int, LayerKind] = 0):
     inv_freq, factor = _inv_freq(cfg, window)
     pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
     return _cos_sin(jnp.outer(pos, inv_freq), factor)  # [S, hd/2] each
 
 
 def apply_rope(x, cos, sin):
-    """x: [B, S, H, hd] — rotate pairs (even, odd)."""
+    """x: [B, S, H, hd] — rotate pairs (even, odd); tables ``[S, r / 2]``
+    narrower than the head rotate its first ``r`` numbers, the others pass."""
+    r = 2 * cos.shape[-1]
+    if r < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., :r], cos, sin), x[..., r:]], axis=-1)
     x1, x2 = x[..., ::2], x[..., 1::2]
     c = cos[None, :, None, :]
     s = sin[None, :, None, :]
@@ -383,28 +498,38 @@ def _expert_parallel(mesh) -> bool:
 def _ffn(cfg: LlamaConfig, p, h, valid=None, mesh=None, rules=None):
     """The FFN of one block on normed activations ``h [..., D]``: returns
     ``(ffn(h) [..., D], aux)``, the residual not added. Dense: the gated
-    SiLU MLP, ``aux`` None. MoE: ``aux["aux_loss"]`` and, on the dropless
+    SiLU MLP, ``aux`` None. MoE (a layer that has a ``router``; beside the routed
+    experts a shared one where it has ``shared_gate``): ``aux["aux_loss"]`` and, on the dropless
     path, ``aux["load"]`` ``[E]`` int32. ``valid [...]`` bool marks the
     real rows of a padded serving step: padding rows reach no expert and
     are not counted (the dense MLP is row-wise and needs no mask).
 
     ``perfbench/families/olmoe/server.py`` calls this by name: the expert
     FFN's own correctness reading runs what the steps run."""
-    if cfg.moe_experts > 0:
-        from ray_tpu.ops.moe import dropless_moe_ffn, moe_ffn
+    if "router" in p:  # the layer's own weights say its FFN's kind
+        from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp, moe_ffn
 
         experts = {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
         if _expert_parallel(mesh):
+            if (cfg.moe_scoring, cfg.moe_scale, cfg.moe_shared_hidden) != ("softmax", 1.0, 0):
+                raise ValueError("the expert-parallel path routes by softmax alone, with no shared expert")
             return moe_ffn(
                 experts, h, top_k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
                 capacity_factor=cfg.moe_capacity_factor,
             )
+        rows = h.reshape(-1, h.shape[-1])
         out, aux = dropless_moe_ffn(
-            experts, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k,
+            experts, rows, top_k=cfg.moe_top_k,
             renormalize=cfg.moe_renormalize,
             valid=None if valid is None else valid.reshape(-1),
-            held=cfg.moe_held,
+            held=cfg.moe_held, scoring=cfg.moe_scoring, scale=cfg.moe_scale,
         )
+        if "shared_gate" in p:
+            with jax.named_scope("moe.shared"):
+                shared = gated_mlp(rows, p["shared_gate"], p["shared_up"], p["shared_down"])
+                if valid is not None:  # a padding row comes back as zeros, as from the routed experts
+                    shared = jnp.where(valid.reshape(-1)[:, None], shared, 0)
+                out = out + shared
         return out.reshape(h.shape), aux
     gate = jnp.einsum("...d,dm->...m", h, p["w_gate"])
     up = jnp.einsum("...d,dm->...m", h, p["w_up"])
@@ -431,9 +556,18 @@ def _attend_by_kind(cfg: LlamaConfig, q, k, v, window: int):
     return o.reshape(B, S, H, hd)
 
 
+def _head_gate(p, h, o):
+    """``o [..., H, hd]`` times a sigmoid a head of the block's normed input
+    ``h [..., D]`` (``LlamaConfig.attn_gate``): float32, back in ``o``'s dtype."""
+    with jax.named_scope("attn.gate"):
+        g = jax.nn.sigmoid(jnp.einsum("...d,dh->...h", h, p["wg"], preferred_element_type=jnp.float32))
+        return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+
+
 def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None, window: int = 0):
     B, S, _ = x.shape
-    q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
+    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    q, k, v = _qkv(cfg, p, h)
     # attention ENTRY pin: q/k/v leave the projection in the head-sharded
     # layout the attention impl expects (ring attention's shard_map specs
     # are exactly these) — without it GSPMD picks per-op and the bwd
@@ -443,7 +577,7 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None, wi
     v = constrain(v, mesh, rules, ("act_batch", "act_seq", "act_kv_heads", None))
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    rep = cfg.n_heads // cfg.n_kv_heads
+    rep = q.shape[2] // cfg.n_kv_heads
     if cfg.layer_windows:
         if mesh is not None or cfg.attention_impl not in ("auto", "xla"):
             raise ValueError(
@@ -500,6 +634,8 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None, wi
         else:
             o = flash_attention(qt, kt, vt, causal=True, impl=cfg.attention_impl)
     o = o.transpose(0, 2, 1, 3)  # [B, S, H, hd]
+    if cfg.attn_gate:
+        o = _head_gate(p, h, o)
     # attention EXIT pin + name: the flash output is the expensive tensor
     # the selective-remat policy saves (recompute elementwise, never the
     # attention itself)
@@ -512,7 +648,7 @@ def _attention_block(cfg: LlamaConfig, p, x, cos, sin, mesh=None, rules=None, wi
 def _mlp_block(cfg: LlamaConfig, p, x, mesh=None, rules=None):
     """Dense or MoE FFN with its norm and residual. Returns (x, aux_loss)."""
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    if cfg.moe_experts > 0:
+    if "router" in p:
         # entry/exit pins bracket the expert compute (interior shardings
         # over the ``expert`` axis are moe_ffn's own business) so the
         # MoE FFN keeps the same replicated-residual contract as the
@@ -568,9 +704,10 @@ def forward(cfg: LlamaConfig, params, tokens, *, remat=False, mesh=None,
     x = constrain(x, mesh, rules, ("act_batch", "act_seq", "act_embed"))
     policy, do_remat = _remat_policy(remat)
 
-    def block_of(window: int):
+    def block_of(kind: LayerKind):
         """One layer's block, with the mask and the rope table of its kind."""
-        cos, sin = rope_tables(cfg, S, window=window)
+        cos, sin = rope_tables(cfg, S, window=kind)
+        window = kind.window
 
         def block(carry, p):
             x, aux = carry
@@ -584,10 +721,11 @@ def forward(cfg: LlamaConfig, params, tokens, *, remat=False, mesh=None,
 
         return jax.checkpoint(block, policy=policy) if do_remat else block
 
-    blocks = {w: block_of(w) for w in sorted({0, *cfg.layer_windows})}
+    # a kind's block is made once, the full layers' first (as ever)
+    blocks = {kind: block_of(kind) for kind in sorted(cfg.kinds, key=lambda kind: kind.window)}
     carry = (x, jnp.zeros((), jnp.float32))
     for layer, p in enumerate(params["layers"]):
-        carry = blocks[cfg.window_of(layer)](carry, p)
+        carry = blocks[cfg.kind_of(layer)](carry, p)
     x, aux = carry
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
@@ -739,15 +877,19 @@ def init_paged_kv_cache(
     return cache_layout(cfg, block_size, dtype).init(num_blocks)
 
 
-def _rope_at(cfg: LlamaConfig, positions, window: int = 0):
-    """cos/sin tables at arbitrary int positions: [...] -> ([..., hd/2] x2),
-    of a layer with that ``window`` (:func:`_inv_freq`)."""
-    inv_freq, factor = _inv_freq(cfg, window)
+def _rope_at(cfg: LlamaConfig, positions, kind: Union[int, LayerKind] = 0):
+    """cos/sin tables at arbitrary int positions: [...] -> ([..., rotated/2]
+    x2), of a layer of that kind, or with that window (:func:`_inv_freq`)."""
+    inv_freq, factor = _inv_freq(cfg, kind)
     return _cos_sin(positions.astype(jnp.float32)[..., None] * inv_freq, factor)
 
 
 def _apply_rope_flat(x, cos, sin):
-    """x: [..., H, hd] with per-row position tables [..., hd/2]."""
+    """x: [..., H, hd] with per-row position tables [..., r/2]: the first
+    ``r`` numbers of a head are rotated (:func:`apply_rope`)."""
+    r = 2 * cos.shape[-1]
+    if r < x.shape[-1]:
+        return jnp.concatenate([_apply_rope_flat(x[..., :r], cos, sin), x[..., r:]], axis=-1)
     x1, x2 = x[..., ::2], x[..., 1::2]
     c = cos[..., None, :]
     s = sin[..., None, :]
@@ -806,12 +948,15 @@ def _block_at(block_tables, pos, bs: int):
     return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
 
 
-def _kernel_serves(cfg: LlamaConfig, window: int, k_cache) -> bool:
+def _kernel_serves(cfg: LlamaConfig, window: int, k_cache, heads: int = 0) -> bool:
     """``ops/paged_attention.py::kernel_serves`` for this model's cache (a
-    cache stored flat does not say its KV heads by its shape)."""
+    cache stored flat does not say its KV heads by its shape) under ``heads``
+    query heads; 0: under every kind's, what a program of the whole model asks."""
+    if not heads:
+        return all(_kernel_serves(cfg, window, k_cache, kind.n_heads) for kind in cfg.kinds)
     if k_cache.ndim == 5:  # the call a plain configuration always made
-        return paged_attn.kernel_serves(window, cfg.n_heads, k_cache)
-    return paged_attn.kernel_serves(window, cfg.n_heads, k_cache, n_kv=cfg.n_kv_heads)
+        return paged_attn.kernel_serves(window, heads, k_cache)
+    return paged_attn.kernel_serves(window, heads, k_cache, n_kv=cfg.n_kv_heads)
 
 
 def _chunk_keys(cfg: LlamaConfig, window: int, chunk: int, table_keys: int, bs: int) -> int:
@@ -850,7 +995,16 @@ def _flash_serves(cfg: LlamaConfig, k_cache, B: int, C: int, table_keys: int, wi
     0's tiles) was compiled and RUN against the materialised way on a v5e, 8192
     table keys (PERF.md, PR 52): 1024 queries 1.69 -> 0.26 ms at a context of 0
     and 1.68 -> 0.32 at 2048; 256 queries 0.39 -> 0.22 and 0.40 -> 0.22;
-    max|diff| / max|ref| 0.005-0.010 in bf16."""
+    max|diff| / max|ref| 0.005-0.010 in bf16.
+
+    SIX and EIGHT query heads a KV head in one program (48 heads over every
+    key, 64 under a window of 512 narrower than the chunk: 2048 keys handed, in
+    whole tiles, of a table of 8192) were compiled and RUN against the
+    materialised way on a v5e (PERF.md, PR 56), ms at a context of 0 / 1024 /
+    3072: 48 heads, 1024 queries 3.70 -> 0.46 / 0.63 / 0.98, 256 queries 1.00
+    -> 0.29 / 0.31 / 0.43; 64 heads under the window, 1024 queries 5.11 -> 0.50
+    / 0.74 / 0.74, 256 queries 1.28 -> 0.29 / 0.30 / 0.29; max|diff| / max|ref|
+    0.005-0.020 in bf16."""
     if B != 1:
         return False
     keys = _chunk_keys(cfg, window, C, table_keys, _block_size(cfg, k_cache))
@@ -889,7 +1043,7 @@ def _paged_attention(
     B, C = pos.shape
     k_cache, v_cache = cache[names[0]], cache[names[1]]
     n_kv, hd = cfg.n_kv_heads, cfg.head_dim
-    if _kernel_serves(cfg, C, k_cache):
+    if _kernel_serves(cfg, C, k_cache, q.shape[2]):
         said = {}  # what a plain configuration's call never says
         if k_cache.ndim == 4:
             said["n_kv"] = n_kv
@@ -898,7 +1052,7 @@ def _paged_attention(
         return paged_attn.paged_attention(q, k_cache, v_cache, layer, block_tables, pos, **said)
     M = block_tables.shape[1]
     bs = _block_size(cfg, k_cache)
-    rep = cfg.n_heads // n_kv
+    rep = q.shape[2] // n_kv
     if _flash_serves(cfg, k_cache, B, C, M * bs, window):
         keys = _chunk_keys(cfg, window, C, M * bs, bs)
         ctx_len = pos[0, 0]
@@ -961,8 +1115,9 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
 
     Layer ``l`` writes and reads its GROUP's arrays through its group's
     table, with its kind's rope table and mask. What is per group is made
-    once a group: the block a position is written to and the rope table. A
-    configuration without layer kinds has one group, every layer in it.
+    once a group (the block a position is written to), what is per kind once
+    a kind (the rope table). A configuration without layer kinds has one
+    group, every layer in it.
 
     Positions past a slot's committed context may hold stale K/V (the
     rejected tail of a verify window, a preempted chunk); that is safe by
@@ -974,7 +1129,11 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
     tables = [_group_table(block_tables, g) for g in range(len(layout.groups))]
     blks = [jnp.where(valid, _block_at(table, pos, bs), 0) for table in tables]
     off = pos % bs
-    ropes = [_rope_at(cfg, pos, group.keeps) for group in layout.groups]
+    # a kind's rope table, in the groups' order (kinds may share a group: its window is what a group is)
+    ropes = {
+        kind: _rope_at(cfg, pos, kind)
+        for kind in dict.fromkeys(cfg.kind_of(l) for group in layout.groups for l in group.layers)
+    }
     where = {l: (g, i) for g, group in enumerate(layout.groups) for i, l in enumerate(group.layers)}
     loads = []
     for layer, p in enumerate(params["layers"]):
@@ -982,7 +1141,7 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
         names = (layout.array_name("k", g), layout.array_name("v", g))
         cache, x = _paged_attention_block(
             cfg, p, cache, x, pos, valid, tables[g], index, names, layout.groups[g].keeps,
-            blks[g], off, ropes[g],
+            blks[g], off, ropes[cfg.kind_of(layer)],
         )
         x = _ffn_residual(cfg, p, x, valid, loads)
     return cache, x, loads
@@ -995,16 +1154,21 @@ def _paged_attention_block(
     """The attention half of one layer over its group's arrays (``names``,
     the layer their index ``index``)
     through its group's table ``block_table [B, M]``: the norm, q / k / v, the
-    kind's rope table ``rope`` (cos, sin at ``pos``), the write of the step's
-    K and V at ``(blk, off)``, the attention over the cache and ``wo``.
+    kind's rope table ``rope`` (cos, sin at ``pos``, as wide as the kind
+    rotates), the write of the step's K and V at ``(blk, off)``, the attention
+    over the cache, the head gate where the configuration has one, and ``wo``;
+    the layer's query heads are its ``wq``'s.
     Returns ``(cache, x + attention)``."""
     cos, sin = rope
     with jax.named_scope("attn.window" if window else "attn.full"):
-        q, k, v = _qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
+        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, p, h)
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
         cache = _scatter_kv(cache, index, blk, off, k, v, names)
         o = _paged_attention(cfg, q, cache, index, block_table, pos, valid, names, window)
+        if cfg.attn_gate:  # on the kernel's output: no reason to leave the kernel
+            o = _head_gate(p, h, o)
         return cache, x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
 
 
@@ -1162,8 +1326,11 @@ def _attention_path(cfg: LlamaConfig, window: int, cache) -> AttentionPath:
     else; reads the ``table``). A configuration with layer kinds runs the
     same path in both kinds of layer and says so (``kernel+window``: each
     group's kernel reads the slot's live blocks of that group, a window's
-    from its first live one on)."""
+    from its first live one on), and the query heads of each kind where they
+    differ (``kernel+window[full:48h,window:64h]``)."""
     kinds = "+window" if cfg.layer_windows else ""
+    if len({kind.n_heads for kind in cfg.kinds}) > 1:  # the heads a kind, as window or full
+        kinds += "[" + ",".join(f"{'window' if k.window else 'full'}:{k.n_heads}h" for k in cfg.kinds) + "]"
     if _kernel_serves(cfg, window, cache["k"]):
         return AttentionPath(f"kernel{kinds}", "blocks")
     if _flash_serves(cfg, cache["k"], 1, window, _table_keys(cfg, cache), 0):

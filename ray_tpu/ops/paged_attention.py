@@ -114,6 +114,14 @@ def kernel_serves(
     against 7.33, max|diff| / max|ref| 0.0055-0.0063 in bf16, a padding slot
     zeros; a block is 4 KB a DMA, so the kernel reaches 16% of the HBM roofline
     where 8 KV heads a block reached 86%.
+    SIX query heads a KV head beside eight in ONE program (48 and 64 query
+    rows a slot over the 5-D cache of 8 KV heads, the 64-row calls with ``keeps``
+    512: layers of two kinds) compiled AND run against the gather on a v5e
+    (PERF.md PR 56), one layer's call, 32 slots, a table of 8192 positions,
+    contexts log-normal about 1.7 k (62 k live tokens): 48 rows 0.406 ms against
+    the gather's 4.21 (77% of the HBM roofline), 64 rows under ``keeps`` 512
+    (16 k live) 0.267 against 4.21 (32%: 33 blocks a slot are two short waves),
+    max|diff| / max|ref| 0.0066 / 0.0083 in bf16.
     Everything else (the CPU, a prefill chunk, odd widths) takes the gather. Decided at trace time; the
     model runner asks the same question to know what a launch reads."""
     backend = backend or jax.default_backend()
@@ -313,7 +321,8 @@ def _call(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, C * H, hd), q.dtype),
-        name="paged_attn",
+        # a window layer's call by its kind's name: the trace tells the two kinds apart
+        name="paged_attn_window" if keeps else "paged_attn",
         interpret=pltpu.InterpretParams() if interpret else False,
     )(
         block_tables.reshape(-1),
