@@ -23,7 +23,9 @@ paged_attention.py::kernel_serves`` and ``models/latent.py::paged_serves`` say
 when, from shapes and the backend), the fallback gathers the table as wide as
 it is (``table``; ``slots`` where a padding slot reads nothing).
 ``decode_width`` counts what was handed over and what the launched program
-reads of the cache, either way.
+reads of the cache, either way, and for a K/V cache the key positions it
+MULTIPLIES: the kernel's waves are whole (``paged_attention.blocks_a_wave``
+blocks each), so a slot's last wave multiplies columns it did not fetch.
 
 A prefill chunk is always handed the full-width row. Wherever the flash
 kernel (``ops/latent_flash.py``) does not serve (the CPU, a chunk that is no
@@ -88,6 +90,7 @@ from ray_tpu.models.interface import (
     scatter_paged_blocks,
 )
 from ray_tpu.observability import timeline
+from ray_tpu.ops import paged_attention
 
 logger = logging.getLogger(__name__)
 
@@ -318,6 +321,23 @@ class PagedModelRunner:
         if any(keeps for _, keeps in self._groups):
             #: of ``gathered_tokens``, what is read of the groups that keep a window (by layers)
             self.decode_width["window_read_tokens"] = 0
+        #: a K/V cache: the blocks a DMA wave of ``ops/paged_attention.py`` a layer
+        #: group (a window group's waves are cut to its span), and beside what a
+        #: launch reads the key positions its program MULTIPLIES (the kernel: each
+        #: real slot's waves, whole, the last one's unfetched columns too; the
+        #: gather: what it reads), the kernel's waves and those of them that were
+        #: full (ONE wait a buffer), all by the groups' layers
+        self._wave_blocks: Tuple[int, ...] = ()
+        if self.cache_layout.kind == "kv":
+            keys = self.cache_layout.arrays[0][0]
+            self._wave_blocks = tuple(
+                paged_attention.blocks_a_wave(
+                    self.cache[self.cache_layout.array_name(keys, g)].shape[2:], block_size,
+                    self.max_blocks_per_seq, keeps,
+                )
+                for g, (_, keeps) in enumerate(self._groups)
+            )
+            self.decode_width.update(multiplied_tokens=0, waves=0, single_wait_waves=0)
         #: running sums over prefill launches: the width of the table handed
         #: over (tokens), the positions up to the chunk's end, and the key
         #: positions the program's attention reads: the table's width
@@ -783,11 +803,12 @@ class PagedModelRunner:
         live = self._by_layers(
             sum(min(int(c), keeps) if keeps else int(c) for c in ctx_lens) for _, keeps in self._groups
         )
-        if reads == "blocks":  # a padding slot reads none
-            per_group = [
-                bs * sum(-(-int(c) // bs) - (max(0, int(c) - keeps) // bs if keeps else 0) for c in ctx_lens)
+        if reads == "blocks":  # a padding slot reads none; a real slot its live blocks
+            blocks = [
+                [-(-int(c) // bs) - (max(0, int(c) - keeps) // bs if keeps else 0) for c in ctx_lens]
                 for _, keeps in self._groups
             ]
+            per_group = [bs * sum(of_slots) for of_slots in blocks]
         elif reads == "slots":  # each real slot the table whole
             per_group = [len(ctx_lens) * width] * len(self._groups)
         else:
@@ -803,6 +824,14 @@ class PagedModelRunner:
         dw["needed_tokens"] += int(max(ctx_lens))
         dw["live_tokens"] += live
         dw["gathered_tokens"] += read
+        if self._wave_blocks and reads == "blocks":  # the kernel: whole waves of ``P`` blocks
+            groups = list(zip(blocks, self._wave_blocks))
+            waves = [sum(-(-n // P) for n in of_slots) for of_slots, P in groups]
+            dw["waves"] += self._by_layers(waves)
+            dw["multiplied_tokens"] += self._by_layers(w * P * bs for w, (_, P) in zip(waves, groups))
+            dw["single_wait_waves"] += self._by_layers(sum(n // P for n in of_slots) for of_slots, P in groups)
+        elif self._wave_blocks:
+            dw["multiplied_tokens"] += read
 
     def verify_batch(
         self,

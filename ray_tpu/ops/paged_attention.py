@@ -21,6 +21,14 @@ table's width). The block table, the positions and the layer's index are
 scalar-prefetched; each live block of a wave is one DMA for K and one for V
 into one of two VMEM buffers, and the next wave (of this slot, or the first
 of the next slot that has any) is in flight while this one is multiplied.
+A wave's copies signal ONE semaphore a buffer (K's, V's), and a DMA semaphore
+counts BYTES (probed on a v5e, PERF.md PR 57): a FULL wave, every wave of a
+slot but its last, is waited for ONCE a buffer, by a descriptor as large as
+the whole buffer, and its issue loop has a static count (unrolled by two);
+a slot's last, partial wave issues its live blocks in a loop and waits by the
+bits of their count (13 blocks: 8 + 4 + 1). A wave is multiplied WHOLE
+whatever it fetched, so its fixed price (the scalar work, the matmuls' and
+the softmax's ``R`` columns) is paid a wave, not a byte: :func:`blocks_a_wave`.
 Online softmax with float32 scores, state and accumulator; ``P`` is cast to
 the cache's dtype for the second matmul, as the gather path does.
 
@@ -41,8 +49,9 @@ A layer that keeps a WINDOW (``keeps`` = ``W``: query at ``i`` sees ``j`` iff
 slot's FIRST live block, the one that holds ``min_c pos[b, c] - W + 1``, not
 at block 0: the table's entries behind it were given back and read the null
 block, and are never touched. Rows of the first wave that lie behind a
-query's window are masked like those past it. Without ``keeps`` the call
-lowers to the program it always was.
+query's window are masked like those past it. Its span of ``W / bs + 1``
+blocks is cut into waves it fills (:func:`blocks_a_wave`: 33 blocks are 2 x
+17, not 16 + 16 + 1 with the third wave multiplied for one block).
 
 NARROW heads (``hd`` 64: half a lane tile) are served with a token's heads
 side by side in ONE row, ``[n_layers, num_blocks, bs, n_kv * hd]`` (``n_kv * hd``
@@ -85,6 +94,32 @@ _MAX_QUERY_ROWS = 256
 #: 72% of the HBM roofline) and tied 4096 on 16 (PERF.md, PR 30)
 _WAVE_ROWS = 2048
 
+#: copies of a FULL wave's issue loop laid out in a row (its count is static)
+_ISSUE_UNROLL = 2
+
+
+def blocks_a_wave(block: tuple, block_size: int, table_blocks: int, keeps: int = 0) -> int:
+    """Blocks a DMA wave of a call over a cache whose blocks are ``block``
+    (``k_cache.shape[2:]``) of ``block_size`` tokens under a table of
+    ``table_blocks``: what :func:`paged_attention` takes by default, and what
+    the model runner counts a launch's multiplied columns with.
+
+    A layer that keeps everything: ``_WAVE_ROWS`` rows of 128 lanes, as many
+    bytes whichever way the heads lie. A layer that keeps a window of ``keeps``
+    holds ``keeps // block_size + 1`` live blocks a slot in all steps but the
+    one in ``block_size`` that starts a block; that span is cut into the FEWEST
+    waves that are at most a block over the default, all the same size, in
+    whole lane tiles of score columns (512 in blocks of 16 x 8 heads: 33 blocks
+    are 2 x 17, not 16 + 16 + 1; 1024 in blocks of 16 x 4: 65 are 2 x 34)."""
+    rows, width = math.prod(block[:-1]), block[-1]
+    P = max(1, _WAVE_ROWS * 128 // (rows * width))
+    if keeps:
+        span = keeps // block_size + 1
+        waves = -(-span // (P + 1))
+        tile = 128 // math.gcd(rows, 128)  # blocks that make whole lane tiles
+        P = -(-(-(-span // waves)) // tile) * tile
+    return min(table_blocks, P)
+
 
 def kernel_serves(
     window: int, n_heads: int, k_cache, backend: str | None = None, n_kv: int | None = None,
@@ -122,6 +157,16 @@ def kernel_serves(
     the gather's 4.21 (77% of the HBM roofline), 64 rows under ``keeps`` 512
     (16 k live) 0.267 against 4.21 (32%: 33 blocks a slot are two short waves),
     max|diff| / max|ref| 0.0066 / 0.0083 in bf16.
+    Those times were a call a dispatch; under ≈ 0.25 ms that reads the HOST. By
+    32 calls in one device loop (PERF.md PR 57, a layer's call alone on a v5e,
+    the parent's kernel -> one wait a wave and a window's waves cut to its
+    span): Mistral 0.284 -> 0.280 ms (84 -> 85% of the HBM roofline); Laguna 48
+    rows 0.438 -> 0.428 (85 -> 87%), 64 rows under ``keeps`` 512 0.146 -> 0.112
+    (56 -> 72%: two waves of 17 for 16 + 16 + 1); Mellum2 (flat, 4 KV heads, 16
+    KB a block) a full layer 0.645 -> 0.507 (56 -> 72%), a window layer 0.311
+    -> 0.239 (48 -> 62%: two waves of 34); LFM2 (heads in lanes, 16 KB) 1.048 ->
+    0.831 (55 -> 70%); Jamba2 (ONE KV head, 4 KB) 1.514 -> 1.156 (17 -> 23%:
+    256 starts a wave at ≈ 11 ns each are what is left).
     Everything else (the CPU, a prefill chunk, odd widths) takes the gather. Decided at trace time; the
     model runner asks the same question to know what a launch reads."""
     backend = backend or jax.default_backend()
@@ -176,33 +221,61 @@ def _kernel(
     R = P * bs * n_kv
     layer = layer_ref[0]
 
-    def from_first(b, blocks):
-        """A count of blocks from the slot's first live one, from block 0."""
-        return first_ref[b] + blocks if keeps else blocks
-
-    def live_blocks(b):
-        return nblk_ref[b] - first_ref[b] if keeps else nblk_ref[b]
-
-    def wave_copies(b, w, buf, i):
-        blk = tables_ref[b * M + from_first(b, w * P + i)]
-        return (
-            pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[buf, i], sems.at[0, buf]),
-            pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[buf, i], sems.at[1, buf]),
-        )
-
-    def each_live_block(b, w, buf, do):
-        def body(i, carry):
-            for copy in wave_copies(b, w, buf, i):
-                do(copy)
-            return carry
-
-        jax.lax.fori_loop(0, jnp.minimum(P, live_blocks(b) - w * P), body, 0)
+    def wave_at(b, w):
+        """Where wave ``w`` of slot ``b`` starts in the flat table, and the live
+        blocks from there on (``P`` or more: a FULL wave)."""
+        first = first_ref[b] if keeps else 0
+        return b * M + first + w * P, nblk_ref[b] - first - w * P
 
     def start_wave(b, w, buf):
-        each_live_block(b, w, buf, lambda copy: copy.start())
+        at, left = wave_at(b, w)
+
+        def issue(i, carry=0):
+            blk = tables_ref[at + i]
+            pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[buf, i], sems.at[0, buf]).start()
+            pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[buf, i], sems.at[1, buf]).start()
+            return carry
+
+        @pl.when(left >= P)
+        def _():  # a known count: unrolled by hand (Mosaic unrolls a loop whole or not at all)
+            U = min(P, _ISSUE_UNROLL)
+
+            def some(j, carry):
+                for u in range(U):
+                    issue(j * U + u)
+                return carry
+
+            jax.lax.fori_loop(0, P // U, some, 0)
+            for i in range(P - P % U, P):
+                issue(i)
+
+        @pl.when(left < P)
+        def _():
+            jax.lax.fori_loop(0, left, issue, 0)
+
+    def wait_blocks(buf, n):
+        """Wait until ``n`` (static) blocks of K and of V have landed in ``buf``:
+        a DMA semaphore counts BYTES, so one wait as large as ``n`` blocks is
+        satisfied by the ``n`` copies of a block each that signalled it (the
+        descriptor is never started: its source only says the size)."""
+        for which, into in enumerate((kbuf, vbuf)):
+            some = into.at[buf, pl.ds(0, n)]
+            pltpu.make_async_copy(some, some, sems.at[which, buf]).wait()
 
     def wait_wave(b, w, buf):
-        each_live_block(b, w, buf, lambda copy: copy.wait())
+        _, left = wave_at(b, w)
+
+        @pl.when(left >= P)
+        def _():  # ONE wait a buffer
+            wait_blocks(buf, P)
+
+        # a slot's last, partial wave: by the bits of its count (at 17 live
+        # blocks of 32: 16 + 1), at most log2(P) waits a buffer
+        part = jnp.where(left < P, left, 0)
+        for bit in (1 << i for i in reversed(range((P - 1).bit_length()))):
+            @pl.when(part & bit > 0)
+            def _(bit=bit):
+                wait_blocks(buf, bit)
 
     # static over the call: which KV head a score's row and column belong to,
     # and the token of a column / of a V row inside its wave
@@ -218,7 +291,7 @@ def _kernel(
         start_wave(next_ref[0], 0, 0)
 
     def slot(b, buf):
-        n_waves = pl.cdiv(live_blocks(b), P)
+        n_waves = pl.cdiv(wave_at(b, 0)[1], P)
         q = q_ref[b]
         # the last position each query row may see, and the slot's own last
         limit = jnp.full((rows, 1), pos_ref[b * C], jnp.int32)
@@ -360,7 +433,7 @@ def paged_attention(
     i``); a slot then reads from the block that holds ``min_c pos[b, c] - W +
     1`` on, and is a padding slot if THAT entry of its table is the null block.
 
-    ``wave_blocks``: blocks a DMA wave (default: ``_WAVE_ROWS`` rows of K).
+    ``wave_blocks``: blocks a DMA wave (default: :func:`blocks_a_wave`).
     ``interpret``: run the kernel in Pallas' TPU interpreter (what the CPU
     tests do); by default wherever the backend is not a TPU."""
     if k_cache.ndim == 5:
@@ -374,15 +447,12 @@ def paged_attention(
         own = jnp.arange(H)[:, None] // (H // n_kv) == jnp.arange(n_kv)[None]  # [H, n_kv]
         q = jnp.where(own[:, :, None], q[..., None, :], 0).reshape(*q.shape[:-1], n_kv * hd)
         heads, n_kv = n_kv, 1
-    rows = math.prod(k_cache.shape[2:-1])  # bs * n_kv
-    M = block_tables.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # as many bytes a wave whichever way the heads lie
-    wave_rows = _WAVE_ROWS * 128 // k_cache.shape[-1] if in_lanes else _WAVE_ROWS
+    bs = math.prod(k_cache.shape[2:-1]) // n_kv
     out = _call(
         q, k_cache, v_cache, jnp.asarray(layer, jnp.int32), block_tables, pos,
-        wave_blocks=wave_blocks or min(M, max(1, wave_rows // rows)),
+        wave_blocks=wave_blocks or blocks_a_wave(k_cache.shape[2:], bs, block_tables.shape[1], keeps),
         interpret=bool(interpret), n_kv=int(n_kv), keeps=int(keeps), scale=1.0 / math.sqrt(hd),
     )
     if in_lanes:  # a head's output: its own lanes of the row

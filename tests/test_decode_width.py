@@ -24,7 +24,9 @@ from ray_tpu.ops import paged_attention as PA  # noqa: E402
 
 BS = 16
 NUM_BLOCKS = 400
-COUNTERS = ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens")
+COUNTERS = ("launches", "width_tokens", "needed_tokens", "live_tokens", "gathered_tokens", "multiplied_tokens")
+#: a K/V cache counts the kernel's waves too (ISSUE 57); the gather has none
+WAVES = ("waves", "single_wait_waves")
 
 
 @pytest.fixture(scope="module")
@@ -107,7 +109,7 @@ def _assert_same(one, other):
 
 def test_counter_matches_a_hand_count_for_a_scripted_batch(cfg, params):
     runner = _runner(cfg, params)
-    assert runner.decode_width == dict.fromkeys(COUNTERS, 0)
+    assert runner.decode_width == dict.fromkeys(COUNTERS + WAVES, 0)
     script = [(5, 300, 2048), (5, 300, 2049), (17,), (4096, 2, 2, 2)]
     for ctx_lens in script:
         runner.decode(
@@ -121,6 +123,8 @@ def test_counter_matches_a_hand_count_for_a_scripted_batch(cfg, params):
         "needed_tokens": 2048 + 2049 + 17 + 4096,
         "live_tokens": 2353 + 2354 + 17 + 4102,
         "gathered_tokens": 4 * 4 * 4096,
+        "multiplied_tokens": 4 * 4 * 4096,  # the gather multiplies what it reads
+        "waves": 0, "single_wait_waves": 0,
     }
     # one shape of one program, whatever the contexts (no warm-up here)
     assert runner.compile_count() == 1
@@ -179,6 +183,7 @@ def test_a_request_growing_through_2048_compiles_nothing(engine):
         "needed_tokens": sum(calls),
         "live_tokens": sum(calls),
         "gathered_tokens": 4 * 4096 * len(calls),
+        "multiplied_tokens": 4 * 4096 * len(calls),
     }
     assert engine.stats()["recompiles_after_warmup"] == 0
     assert runner.compile_count() == 2 + 1 + 1
@@ -235,8 +240,10 @@ def test_kernel_decode_is_one_program_and_counts_live_blocks(cfg, params, gather
         assert kernel[3] == {
             "launches": 1, "width_tokens": 4096, "needed_tokens": max(ctx_lens),
             "live_tokens": sum(ctx_lens), "gathered_tokens": blocks * BS,
+            # the toy's default wave is the whole table (256 blocks of [16, 2, 16]): one wave a real slot
+            "multiplied_tokens": len(ctx_lens) * 4096,
         }
-        assert table[3] == {**kernel[3], "gathered_tokens": 4 * 4096}
+        assert table[3] == {**kernel[3], "gathered_tokens": 4 * 4096, "multiplied_tokens": 4 * 4096}
         mean = sum(ctx_lens) / len(ctx_lens)
         assert kernel[3]["live_tokens"] / kernel[3]["gathered_tokens"] >= 1 - BS / mean
     assert runner.compile_count() == 1
@@ -260,8 +267,9 @@ def test_kernel_reads_nothing_for_a_padding_slot_and_serves_verify(cfg, params, 
     assert kernel[3] == {
         "launches": 1, "width_tokens": 4096, "needed_tokens": ctx + 4,
         "live_tokens": ctx + 4 + 42, "gathered_tokens": (-(-(ctx + 4) // BS) + 3) * BS,
+        "multiplied_tokens": 2 * 4096,
     }
-    assert table[3] == {**kernel[3], "gathered_tokens": 4 * 4096}
+    assert table[3] == {**kernel[3], "gathered_tokens": 4 * 4096, "multiplied_tokens": 4 * 4096}
 
 
 def test_kernel_engine_warms_one_decode_program_and_never_recompiles(cfg, params, engine, kernel_forced):
@@ -286,8 +294,79 @@ def test_kernel_engine_warms_one_decode_program_and_never_recompiles(cfg, params
             "launches": 29, "width_tokens": 29 * 4096, "needed_tokens": sum(contexts),
             "live_tokens": sum(contexts),
             "gathered_tokens": sum(-(-c // BS) for c in contexts) * BS,  # three padding slots read nothing
+            "multiplied_tokens": 29 * 4096,  # one wave as wide as the table
         }
         assert eng.stats()["recompiles_after_warmup"] == 0
         assert runner.compile_count() == 2 + 1 + 1
     finally:
         eng.stop()
+
+
+# -- what the kernel multiplies: whole waves, a group's own size (ISSUE 57) ---------------------------
+
+def test_multiplied_tokens_are_each_groups_waves_by_its_layers(kernel_forced, monkeypatch):
+    """Two layer groups, a full layer and two that keep 64: a wave of the
+    kernel is ``blocks_a_wave`` blocks (here 4 of the full group, 8 of the
+    window group: its span of 5 in ONE wave of whole lane tiles), every wave is
+    multiplied whole, and a FULL one is waited for once. By hand, for contexts
+    of 5, 300 and 130 in blocks of 16."""
+    monkeypatch.setattr(PA, "_WAVE_ROWS", 16)  # 16 x 128 numbers a wave: 4 of the toy's [16, 2, 16] blocks
+    cfg = LlamaConfig.tiny(max_seq_len=512, n_layers=3, layer_windows=(0, 64, 64))
+    runner = PagedModelRunner(
+        cfg, init_params(cfg, jax.random.PRNGKey(1)), num_blocks=(80, 40), block_size=BS,
+        prefill_buckets=(64,), decode_buckets=(4,),
+    )
+    assert runner.attention_paths[1].reads == "blocks" and runner._wave_blocks == (4, 8)
+    assert runner._wave_blocks == tuple(
+        PA.blocks_a_wave(runner.cache[name].shape[2:], BS, 32, keeps) for name, keeps in (("k", 0), ("k.window", 64))
+    )
+    ctx_lens = (5, 300, 130)
+    rows, nxt = [], [1, 1]
+    for ctx in ctx_lens:
+        row = np.zeros((2, 32), np.int32)
+        for g, first in enumerate((0, max(0, ctx - 64) // BS)):  # a window's blocks from its first live one on
+            n = -(-ctx // BS) - first
+            row[g, first:first + n] = np.arange(nxt[g], nxt[g] + n)
+            nxt[g] += n
+        rows.append(row)
+    start = dict(runner.decode_width)
+    logits = runner.decode([3, 4, 5], [c - 1 for c in ctx_lens], rows, list(ctx_lens))
+    assert np.isfinite(logits).all()
+    got = {k: runner.decode_width[k] - start[k] for k in runner.decode_width}
+    full, window = [1, 19, 9], [1, 5, 5]  # live blocks a slot: all of them; from the window's first on
+    waves = (sum(-(-n // 4) for n in full), sum(-(-n // 8) for n in window))
+    assert waves == (9, 3)
+    assert got == {
+        "launches": 1, "width_tokens": 512, "needed_tokens": 300,
+        "live_tokens": (435 + 2 * (5 + 64 + 64)) / 3, "gathered_tokens": (29 * 16 + 2 * 11 * 16) / 3,
+        "window_read_tokens": 2 * 11 * 16 / 3,
+        "multiplied_tokens": (9 * 4 * 16 + 2 * 3 * 8 * 16) // 3,
+        "waves": (9 + 2 * 3) // 3,
+        "single_wait_waves": (sum(n // 4 for n in full) + 2 * sum(n // 8 for n in window)) // 3,
+    }
+    assert got["multiplied_tokens"] == 448 and got["single_wait_waves"] == 2
+    assert got["live_tokens"] <= got["gathered_tokens"] <= got["multiplied_tokens"]
+
+
+@pytest.mark.parametrize("family", ["llama", "lfm2", "jamba"])
+def test_every_kv_cache_counts_what_it_multiplies(family):
+    """The counter is absent from no path the K/V kernel can serve: every
+    model whose cache is K/V rows has it from construction (the latent
+    models' kernel, ``ops/latent_paged.py``, keeps its own loops: no key)."""
+    from ray_tpu.models import interface, jamba, lfm2, xing4
+
+    cfgs = {"llama": LlamaConfig.tiny(), "lfm2": lfm2.Lfm2Config.tiny(), "jamba": jamba.JambaConfig.tiny()}
+    cfg = cfgs[family]
+    model = interface.model_of(cfg)
+    runner = PagedModelRunner(
+        cfg, model.init_params(cfg, jax.random.PRNGKey(0)), num_blocks=16, block_size=BS,
+        prefill_buckets=(16,), decode_buckets=(4,), state_slots=2 if model.state_layout else 0,
+    )
+    assert runner.cache_layout.kind == "kv"
+    assert {"multiplied_tokens", *WAVES} <= set(runner.decode_width) and len(runner._wave_blocks) == 1
+    latent = xing4.Xing4Config.tiny()
+    other = PagedModelRunner(
+        latent, interface.model_of(latent).init_params(latent, jax.random.PRNGKey(0)), num_blocks=16,
+        block_size=BS, prefill_buckets=(16,), decode_buckets=(4,),
+    )
+    assert other.cache_layout.kind == "latent" and "multiplied_tokens" not in other.decode_width

@@ -192,3 +192,137 @@ def test_the_kernel_serves_short_windows_of_whole_tiles_on_a_tpu(backend, window
     cache_shape, said = cache_shape if isinstance(cache_shape[1], dict) else (cache_shape, {})
     cache_like = jax.ShapeDtypeStruct(cache_shape, dtype)
     assert PA.kernel_serves(window, n_heads, cache_like, backend=backend, **said) is serves
+
+
+# -- waves: ONE wait a full wave, a window's span cut into waves it fills (ISSUE 57) -----------------
+
+def _window_case(contexts, keeps, block, n_kv, table_blocks, seed=0, window=1):
+    """Slots at ``contexts`` (0: a padding slot) under a layer that keeps
+    ``keeps``, blocks of ``block`` (``[bs, n_kv, hd]`` or flat ``[bs * n_kv,
+    hd]``) in a shuffled pool. Returns ``(q, clean K/V, poisoned K/V, tables,
+    pos, spans)``: the poisoned cache is NaN / inf past every slot's last
+    position, in every block no table holds and in every block wholly behind
+    the first query's window (whose table entries are the null block, as the
+    block manager leaves them); ``spans`` the live blocks a slot."""
+    rng = np.random.default_rng(seed)
+    bs = int(np.prod(block[:-1])) // n_kv
+    hd, B, Mt = block[-1], len(contexts), table_blocks
+    N = 1 + sum(-(-c // bs) for c in contexts)
+    k, v = rng.standard_normal((2, LAYERS, N, bs, n_kv, hd)).astype(np.float32)
+    pool = iter(rng.permutation(np.arange(1, N)))
+    tables, pos = np.zeros((B, Mt), np.int32), np.zeros((B, window), np.int32)
+    live, spans = np.zeros((N, bs), bool), []
+    for b, ctx in enumerate(contexts):
+        if not ctx:
+            spans.append(0)
+            continue
+        pos[b] = np.minimum(ctx - 1 + np.arange(window), Mt * bs - 1)
+        first = max(0, pos[b].min() - keeps + 1) // bs if keeps else 0
+        last = pos[b].max() // bs
+        tables[b, first:last + 1] = [next(pool) for _ in range(last + 1 - first)]
+        for p in range(first * bs, pos[b].max() + 1):
+            live[tables[b, p // bs], p % bs] = True
+        spans.append(last + 1 - first)
+    kp, vp = k.copy(), v.copy()
+    kp[:, ~live], vp[:, ~live] = np.nan, np.inf
+    q = rng.standard_normal((B, window, n_kv * 2, hd)).astype(np.float32)
+    to = lambda a: jnp.asarray(a.reshape(LAYERS, N, *block))  # noqa: E731
+    return jnp.asarray(q), (jnp.asarray(k), jnp.asarray(v)), (to(kp), to(vp)), jnp.asarray(tables), jnp.asarray(pos), spans
+
+
+def _kernel_and_gather(case, keeps, n_kv, capfd, **kw):
+    """The kernel over the poisoned cache, the gather over the clean one, for
+    the real slots; a padding slot is zeros; and the interpreter found every
+    DMA semaphore back at zero when the kernel ended (it prints one that is not)."""
+    q, clean, poisoned, tables, pos, spans = case
+    keys = tables.shape[1] * clean[0].shape[2]
+    want = np.asarray(L._attend_gathered(q, *clean, LAYER, tables, pos, n_kv, keys, keeps))
+    said = {} if poisoned[0].ndim == 5 else {"n_kv": n_kv}
+    capfd.readouterr()
+    have = np.asarray(PA.paged_attention(q, *poisoned, LAYER, tables, pos, interpret=True, keeps=keeps, **said, **kw))
+    assert "non-zero count" not in capfd.readouterr().out
+    real = np.asarray(spans) > 0
+    assert np.isfinite(have).all() and (have[~real] == 0).all()
+    return have[real], want[real]
+
+
+WAVE = 3
+
+
+@pytest.mark.parametrize("span", [1, WAVE, WAVE + 1, 2 * WAVE + 1], ids=["one_block", "a_wave", "a_wave_and_one", "two_waves_and_one"])
+@pytest.mark.parametrize("form", ["five_d", "flat"])
+def test_a_windows_span_in_waves_of_three_is_the_gather(form, span, capfd):
+    """A window whose live span is 1, P, P + 1 and 2P + 1 blocks (P = 3): full
+    waves wait once, the last by the bits of its count, and what a partial wave
+    did not fetch (NaN in the interpreter's VMEM, as in the pool) never reaches
+    the output. A slot short of its window, a padding slot and a slot of one
+    token share the batch."""
+    keeps = (span - 1) * BS + 1  # a query at a block's first row sees span - 1 blocks, elsewhere span
+    contexts = (12 * BS - 1, 12 * BS + 1, 0, 9 * BS, 1, 2, 11 * BS + 2, 0)
+    block = (BS, N_KV, HD) if form == "five_d" else (BS * N_KV, HD)
+    case = _window_case(contexts, keeps, block, N_KV, 16, seed=span)
+    assert span in case[-1] and max(case[-1]) == span
+    have, want = _kernel_and_gather(case, keeps, N_KV, capfd, wave_blocks=WAVE)
+    np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "block, n_kv, keeps, wave",
+    [((16, 8, 128), 8, 512, 17), ((64, 128), 4, 1024, 34)],
+    ids=["laguna_33_blocks_in_waves_of_17", "mellum2_65_blocks_in_waves_of_34"],
+)
+def test_the_default_wave_of_both_shipped_windows_is_the_gather(block, n_kv, keeps, wave, capfd):
+    """The shipped window shapes at their own block sizes and the default
+    wave: a slot far past its window holds ``keeps // 16 + 1`` blocks, two waves
+    (the second one block short); one whose last row ends a block a block fewer; one
+    short of its window; a verify-sized tail is the loop's business, not the
+    default's."""
+    span = keeps // 16 + 1
+    assert PA.blocks_a_wave(block, 16, span + 8, keeps) == wave
+    contexts = (keeps + 100, keeps + 16 * 3, 0, keeps - 40, 16 * wave)
+    case = _window_case(contexts, keeps, block, n_kv, span + 8, seed=keeps)
+    assert case[-1][:2] == [span, span - 1]
+    have, want = _kernel_and_gather(case, keeps, n_kv, capfd)
+    np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("keeps", [0, 5 * BS], ids=["keeps_all", "a_window"])
+def test_a_full_waves_single_wait_leaves_the_next_waves_bytes_alone(keeps, capfd):
+    """Full waves (waited for ONCE a buffer) followed by a padding slot, by a
+    slot of one block and by another full wave in the other buffer: each wait
+    takes its own wave's bytes and no more, so both buffers' semaphores are
+    back at zero when the kernel ends and every slot reads its own blocks."""
+    contexts = (WAVE * BS, 0, 1, 2 * WAVE * BS, 0, BS, WAVE * BS, WAVE * BS + 1, 0)
+    case = _window_case(contexts, keeps, (BS, N_KV, HD), N_KV, 8, seed=7)
+    have, want = _kernel_and_gather(case, keeps, N_KV, capfd, wave_blocks=WAVE)
+    np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
+
+
+#: the six shipped shapes: (a block of the cache, table blocks) -> the wave of a
+#: layer that keeps everything, and of one that keeps a window
+SHIPPED = {
+    "mistral": ((16, 8, 128), 256, 16, None),
+    "olmoe": ((16, 16, 128), 256, 8, None),
+    "mellum2": ((64, 128), 1024, 32, (1024, 34)),
+    "lfm2": ((16, 512), 512, 32, None),
+    "jamba2": ((16, 128), 512, 128, None),
+    "laguna": ((16, 8, 128), 512, 16, (512, 17)),
+}
+
+
+@pytest.mark.parametrize("name", list(SHIPPED))
+def test_blocks_a_wave_on_the_shipped_shapes(name):
+    """A layer that keeps everything takes the wave it always took (2048 rows
+    of 128 lanes); a window's span is cut into waves it fills: no wave of one
+    block for a full window, and the scores' columns are whole lane tiles."""
+    block, table_blocks, full, window = SHIPPED[name]
+    rows = int(np.prod(block[:-1]))
+    assert PA.blocks_a_wave(block, 16, table_blocks) == full
+    assert full == min(table_blocks, max(1, (2048 * 128 // block[-1] if block[-1] != 128 else 2048) // rows))
+    assert (full * rows) % 128 == 0
+    assert PA.blocks_a_wave(block, 16, 4) == 4  # never wider than the table
+    if window:
+        keeps, wave = window
+        span = keeps // 16 + 1
+        assert PA.blocks_a_wave(block, 16, table_blocks, keeps) == wave
+        assert -(-span // wave) == 2 < -(-span // full) and span % wave != 1 and (wave * rows) % 128 == 0
