@@ -476,6 +476,7 @@ class InferenceEngine:
         #: their launch / device_wait / readback
         self._clock = timeline.PhaseClock("engine", STEP_PHASES, STEP_PARTS)
         state_slots = self._state_slots(model_cfg, ec)
+        self._refuse_payloads(model_cfg, ec)
         #: whether the model drafts for itself (speculative_draft "mtp")
         self._mtp = self._drafts_for_itself(model_cfg, ec)
         #: the pools beside the first: ``(name, num_blocks, keeps)`` of each
@@ -822,6 +823,26 @@ class InferenceEngine:
              g.keeps)
             for g in groups[1:]
         )
+
+    @staticmethod
+    def _refuse_payloads(model_cfg, ec: "EngineConfig") -> None:
+        """A model whose token leaves rows of DIFFERENT widths in a layer (a
+        latent row and an indexer's key: ``CacheLayout.one_payload`` false)
+        has no stacked payload of a block: what would ship one (export and
+        import, the tier) is refused here, with the reason."""
+        from ray_tpu.models.interface import model_of
+
+        model = model_of(model_cfg)
+        layout = model.cache_layout(model_cfg, ec.block_size, ec.cache_dtype)
+        if layout.one_payload:
+            return
+        rows = ", ".join(f"{name} {math.prod(shape)}" for name, shape in layout.arrays)
+        for field in ("kv_transfer_enabled", "kv_tier_enabled"):
+            if getattr(ec, field):
+                raise ValueError(
+                    f"{field} cannot run here: a token of a {model.name} model leaves rows of different "
+                    f"widths in a layer ({rows}), and a block's payload is ONE stacked array"
+                )
 
     @staticmethod
     def _drafts_for_itself(model_cfg, ec: "EngineConfig") -> bool:
@@ -2772,6 +2793,11 @@ class InferenceEngine:
             "prefill_width": dict(self.runner.prefill_width),
             # what a token leaves in the cache (the model's description)
             "kv_layout": self.runner.cache_layout.describe(),
+            # a model whose attention selects: how much of the live context
+            # the launches' queries chose (None: every query sees all of it)
+            "sparse_attention": (
+                dict(self.runner.sparse_attention) if self.runner.sparse_attention else None
+            ),
             # a model with recurrent layers: what a SEQUENCE holds beside
             # its rows, and the pool of slots it is held in (None / zeros else)
             "state_layout": (

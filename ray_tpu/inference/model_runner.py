@@ -350,6 +350,15 @@ class PagedModelRunner:
         self.prefill_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "live_tokens", "read_tokens", "expanded_tokens"), 0
         )
+        #: a model whose attention SELECTS (``Model.selection`` positions a
+        #: query at most): running sums over every launch's real query
+        #: positions, from the host's own lengths: the queries, those whose
+        #: live context (the positions up to their own) is more than a
+        #: selection holds, the positions chosen and the positions live
+        self.selection: int = self.model.selection(cfg)
+        self.sparse_attention: Optional[Dict[str, int]] = None
+        if self.selection:
+            self.sparse_attention = dict.fromkeys(("queries", "queries_past_topk", "chosen", "live"), 0)
         #: MoE configs only: what the experts saw, as running sums over
         #: decode and verify launches and, apart, prefill launches
         #: (:meth:`_account_moe`); ``None`` for a dense model
@@ -667,6 +676,7 @@ class PagedModelRunner:
         pw["live_tokens"] += live
         pw["read_tokens"] += read
         pw["expanded_tokens"] += next((rung for rung in rungs if rung >= end), width)
+        self._count_selection(ctx_len, end)
         with clock.phase(
             "launch", program="paged_prefill_step", bucket=bucket, path=self._path_name(bucket),
         ):
@@ -768,6 +778,20 @@ class PagedModelRunner:
         acc["max_load"] += int(loads.max(axis=1).sum())
         acc["mean_load"] += float(loads.mean(axis=1).sum())
 
+    def _count_selection(self, first: int, end: int) -> None:
+        """Count the query positions ``[first, end)`` of one sequence in
+        :attr:`sparse_attention`: position ``t`` has ``t + 1`` live positions
+        and chooses ``min(t + 1, selection)`` of them."""
+        sa, K = self.sparse_attention, self.selection
+        if sa is None or end <= first:
+            return
+        full = max(first, min(end, K))  # positions under it choose everything
+        live = (end * (end + 1) - first * (first + 1)) // 2
+        sa["queries"] += end - first
+        sa["queries_past_topk"] += end - full
+        sa["live"] += live
+        sa["chosen"] += (full * (full + 1) - first * (first + 1)) // 2 + (end - full) * K
+
     def _by_layers(self, per_group) -> float:
         """The mean over the cache's layers of a number a layer group (an int
         where it comes out whole, as it does for a model of one group)."""
@@ -814,6 +838,8 @@ class PagedModelRunner:
         else:
             per_group = [bucket * width] * len(self._groups)
         read = self._by_layers(per_group)
+        for c in ctx_lens:  # a slot's window: the last ``window`` positions at most
+            self._count_selection(max(0, int(c) - window), int(c))
         dw = self.decode_width
         if "window_read_tokens" in dw:  # of ``read``, the part of the groups that keep a window
             dw["window_read_tokens"] += self._by_layers(

@@ -99,6 +99,12 @@ class CacheLayout:
         return name if group == 0 else f"{name}.{self.groups[group].name}"
 
     @property
+    def one_payload(self) -> bool:
+        """Whether the arrays share ONE row shape, so that a block's export /
+        import / tier payload is one stacked array."""
+        return len({shape for _, shape in self.arrays}) == 1
+
+    @property
     def row_shape(self) -> Tuple[int, ...]:
         """The one row shape all arrays share (what lets the payload be one
         stacked array)."""
@@ -173,6 +179,14 @@ class CacheLayout:
             "row_width": self.row_width,
             "bytes_per_token": self.bytes_per_token,
         }
+        if not self.one_payload:
+            # rows of different widths side by side (a latent row and an
+            # indexer's key): each array's share of a token's bytes
+            item = np.dtype(self.dtype).itemsize
+            said["arrays"] = {
+                name: {"row_width": math.prod(shape), "bytes_per_token": self.n_layers * math.prod(shape) * item}
+                for name, shape in self.arrays
+            }
         if len(self.groups) > 1:  # one group: what bytes_per_token says
             row_bytes = self.row_width * np.dtype(self.dtype).itemsize
             said["groups"] = {
@@ -357,6 +371,11 @@ class Model:
     #: last real query, whatever its ``attention_path`` reads. Empty: the
     #: table whole, whatever the context
     gather_rungs: Callable = lambda cfg, window, cache: ()
+    #: (cfg) -> 0 for a model whose attention sees every live position, else
+    #: the positions a query's attention SELECTS among them at most (a learned
+    #: sparse selection: ``index_topk``): the runner counts, from its own
+    #: lengths, how much of the live context the launches' queries chose
+    selection: Callable = lambda cfg: 0
     #: ``None`` for a model whose layers all attend, else (cfg) -> the
     #: :class:`StateLayout` of its recurrent layers. Such a model's paged
     #: entry points take the state arrays after the cache (both donated) and
@@ -375,13 +394,13 @@ class Model:
 
 def model_of(cfg) -> Model:
     """The model a config object belongs to, by the config's type."""
-    from ray_tpu.models import deepseek_v3, jamba, kimi_linear, lfm2, llama, xing4
+    from ray_tpu.models import deepseek_v3, glm_dsa, jamba, kimi_linear, lfm2, llama, xing4
 
     models = {
         llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL,
         kimi_linear.KimiLinearConfig: kimi_linear.MODEL,
         deepseek_v3.DeepseekV3Config: deepseek_v3.MODEL, lfm2.Lfm2Config: lfm2.MODEL,
-        jamba.JambaConfig: jamba.MODEL,
+        jamba.JambaConfig: jamba.MODEL, glm_dsa.GlmDsaConfig: glm_dsa.MODEL,
     }
     try:
         return models[type(cfg)]

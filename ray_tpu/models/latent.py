@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.interface import CacheLayout
-from ray_tpu.ops import latent_flash, latent_paged
+from ray_tpu.ops import latent_flash, latent_paged, sparse_index
 
 F32 = jnp.float32
 
@@ -202,7 +202,8 @@ def flash_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
 def gather_rungs(cfg, window: int, cache) -> tuple:
     """``Model.gather_rungs`` of a model whose chunks go through
     :func:`latent_attention`: :func:`key_rungs` at the runner's full table."""
-    return key_rungs(window, table_keys(cfg, cache), block_size_of(cfg, cache))
+    keys, bs = table_keys(cfg, cache), block_size_of(cfg, cache)
+    return index_rungs(cfg, keys, bs) if indexed(cfg) else key_rungs(window, keys, bs)
 
 
 def table_keys(cfg, cache) -> int:
@@ -240,9 +241,13 @@ def attend_flash(cfg, p, q_nope, q_rope, rows, ctx_len, true_len):
 def cache_layout(cfg, block_size: int, dtype=None, n_layers=None) -> CacheLayout:
     """The flat-block latent cache: ``n_layers`` layers WRITE a row a token
     (every layer of the model unless told: a hybrid's attention layers alone)."""
+    arrays = (("latent", (cfg.latent_width,)),)
+    if indexed(cfg):
+        # the indexer's key beside the latent row, under the same block table
+        arrays += (("index", (cfg.index_head_dim,)),)
     return CacheLayout(
         kind="latent", n_layers=cfg.n_layers if n_layers is None else n_layers, block_size=block_size,
-        arrays=(("latent", (cfg.latent_width,)),), dtype=dtype or cfg.dtype, flat_blocks=True,
+        arrays=arrays, dtype=dtype or cfg.dtype, flat_blocks=True,
     )
 
 
@@ -259,8 +264,210 @@ def blocks_of_window(cfg, cache, window: int) -> int:
     return (window + bs - 2) // bs + 1
 
 
+# ---------------------------------------------------------------------------
+# a learned sparse selection inside the attention (``cfg.index_topk`` > 0:
+# ``models/glm_dsa.py``): an indexer scores every earlier position of the slot
+# from a second cached row a token (``index``: its key), and the softmax runs
+# over the ``index_topk`` best positions of each query alone
+
+
+def indexed(cfg) -> bool:
+    """Whether the model's attention selects (``index_topk`` positions a query)."""
+    return bool(getattr(cfg, "index_topk", 0))
+
+
+def index_rungs(cfg, keys: int, block_size: int) -> tuple:
+    """:func:`key_rungs` of a model that selects: its chunk scores, selects
+    and attends over the key positions up to a rung, every whole number of
+    ``2 x index_topk`` positions up to the table (eight rungs of 4096 at a
+    table of 32,768: a chunk's three parts cost by the rung, and a rung a
+    tile, 32 of them, would be 32 instances of each in every layer body). The
+    table whole where that is no whole number of whole blocks."""
+    step = 2 * cfg.index_topk
+    if keys % step or step % block_size:
+        return (keys,)
+    return tuple(range(step, keys + 1, step))
+
+
+def _chosen(cfg, q_i, w_i, ikeys, limit):
+    """The selection of ONE slot's queries: ``q_i [R, Hi, di]``, ``w_i [R,
+    Hi]`` against index keys ``ikeys [S, di]``, query ``r`` choosing among the
+    positions ``<= limit[r]`` -> ``[R, S]`` bool (``ops/sparse_index.py``)."""
+    with jax.named_scope("dsa.index"):
+        scores = sparse_index.index_scores(q_i, w_i, ikeys)
+    with jax.named_scope("dsa.topk"):
+        return sparse_index.select_mask(scores, limit, cfg.index_topk)
+
+
+def selection(cfg, index, pos):
+    """What each query of whole sequences may attend to: ``index`` as
+    :func:`_sparse_attention` takes it over ``[B, S]`` positions ``pos`` (all
+    of a sequence's at once: ``forward``) -> ``[B, S, S]`` bool, causal and
+    chosen."""
+    q_i, k_i, w_i = index
+    return jax.vmap(lambda q, k, w, at: _chosen(cfg, q, w, k, at))(q_i, k_i, w_i, pos)
+
+
+def _query_block(cfg, queries: int, keys: int) -> int:
+    """Queries of a chunk that :func:`attend_masked` attends at a time: at
+    most 32, and fewer where their float32 scores over ``keys`` positions (all
+    heads) would pass 256 MB (a table past 32,768); a power of two that
+    divides ``queries``. Why 32: timed a layer on the chip at every rung
+    (PERF.md, PR 58), 32 queries at a time are as fast as 64 from a rung of
+    16,384 up and faster below it (4.8 against 6.1 and 9.4 ms at 4096 for 32,
+    64 and 256), and 128 or more over a rung of 8192 ran 10 x slower than
+    their operations (196 ms: XLA lays the scores out heads-major there)."""
+    block = queries
+    while (block > 32 or block * cfg.n_heads * keys * 4 > (256 << 20)) and block % 2 == 0 and block > 8:
+        block //= 2
+    return block
+
+
+def attend_masked(cfg, q_row, rows, mask):
+    """Absorbed queries ``q_row [C, H, kr + dr]`` of ONE slot over its latent
+    rows ``rows [S, kr + dr]`` (the chunk's own laid in), each query under its
+    own ``mask [C, S]`` (the selection: causal already): ``[C, H, kr]``. A
+    block of queries at a time (:func:`_query_block`); the unchosen positions
+    are multiplied and masked, not gathered around: 1024 queries x 2048 chosen
+    rows gathered by token would be 2.4 GB a layer."""
+    kr, C = cfg.kv_lora_rank, q_row.shape[0]
+
+    def attend(args):
+        q, m = args
+        s = jnp.einsum("chw,sw->chs", q, rows, preferred_element_type=F32)
+        pr = probs(cfg, s[None], m[None], rows.dtype)[0]
+        return jnp.einsum("chs,sw->chw", pr, rows[:, :kr])
+
+    block = _query_block(cfg, C, rows.shape[0])
+    if block == C:
+        return attend((q_row, mask))
+    out = jax.lax.map(attend, (q_row.reshape(C // block, block, *q_row.shape[1:]),
+                               mask.reshape(C // block, block, -1)))
+    return out.reshape(C, *out.shape[2:])
+
+
+def _token_rows(cfg, cache, layer, tables, positions):
+    """The latent rows of each slot at ``positions [B, C, k]`` (each under the
+    slot's context; ``tables [B, M]``), gathered BY TOKEN out of the cache as
+    it is stored: a stored row of ``T`` tokens a position
+    (``CacheLayout.flat_blocks``), the token's part cut out of it after (a
+    select a part: ``T`` is 2 at the published width). 2048 scattered
+    positions of 16 k touch nearly nine blocks in ten, so whole blocks would
+    be the whole context. Returns ``[B, C, k, kr + dr]``."""
+    L, N, *block = cache["latent"].shape
+    W, bs = cfg.latent_width, block_size_of(cfg, cache)
+    flat = cache["latent"].reshape(L * N, *block)
+    col = jnp.minimum(positions // bs, tables.shape[1] - 1)
+    blk = layer * N + jnp.take_along_axis(tables, col.reshape(col.shape[0], -1), axis=1).reshape(col.shape)
+    at = positions % bs
+    if len(block) == 2:  # [bs / T, T x W]
+        T = block[1] // W
+        stored, part = flat[blk, at // T].reshape(*positions.shape, T, W), at % T
+    else:  # one row of bs x W a block (the tests' toy widths)
+        T = bs
+        stored, part = flat[blk].reshape(*positions.shape, T, W), at
+    rows = stored[..., 0, :]
+    for t in range(1, T):
+        rows = jnp.where((part == t)[..., None], stored[..., t, :], rows)
+    return rows
+
+
+def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_tables, pos, true_lens):
+    """:func:`latent_attention` of a model that selects. ``index``: ``(q_i
+    [B, C, Hi, di], k_i [B, C, di], w [B, C, Hi])`` of the window (the
+    indexer's queries and key, rotated, and the heads' weights, scaled). Every
+    window goes through the three parts, whatever its context (under
+    ``index_topk`` positions all are chosen, and the result is the dense
+    one): ``dsa.index`` (the scores of each query against the slot's index
+    keys, the window's own laid in), ``dsa.topk`` (the exact choice,
+    ``ops/sparse_index.py``), ``dsa.attend``, ``W_kvb`` absorbed either way:
+
+    * a prefill chunk, one slot at a time over the key positions up to its
+      rung (:func:`index_rungs`): the softmax over ALL rows of the rung under
+      the selection as a mask (:func:`attend_masked`);
+    * a decode or verify window (:func:`absorbs`; a few positions: the
+      window's own are laid in by a select each): the index keys at the
+      table's width, the chosen latent rows gathered BY TOKEN
+      (:func:`_token_rows`; a chosen position of the window itself is the
+      window's own row), the softmax over ``index_topk`` rows a query.
+
+    Returns ``(out, blocks)`` as there, ``blocks [B, nblk * block_size, kr +
+    dr + di]``: a token's two rows side by side."""
+    B, C = pos.shape
+    q_i, k_i, w_i = index
+    W, di, K = cfg.latent_width, cfg.index_head_dim, cfg.index_topk
+    bs, nblk = block_size_of(cfg, cache), blocks_of_window(cfg, cache, C)
+    tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
+    first = pos[:, 0]
+    own = jnp.concatenate([row, k_i.astype(row.dtype)], axis=-1)  # [B, C, W + di]
+
+    def context(name, table, width):
+        a = cache[name]
+        L, N, *block = a.shape
+        return a.reshape(L * N, *block)[layer * N + table].reshape(-1, width)
+
+    def window_blocks():
+        ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at // bs,), (nblk,)))(tables, first)
+        old = jnp.concatenate(
+            [jax.vmap(lambda t: context("latent", t, W))(ids), jax.vmap(lambda t: context("index", t, di))(ids)],
+            axis=-1,
+        )
+        return jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a % bs, 0)))(old, own, first)
+
+    q_row = absorb_query(cfg, p, q_nope, q_rope)
+    if absorbs(cfg, C):
+        # the slots at once, on explicit batch axes: a padding slot (the null
+        # block's table) reads the null block's rows, finite and nobody's, and
+        # comes back as zeros. The window's own keys and rows are laid in by
+        # SELECTS over its ``C`` positions: a gather of them a chosen position
+        # (``own[sel - first]`` under ``vmap``) ran as 32,768 sequential slices
+        # on the chip, 53 of a layer's 57 ms (PERF.md, PR 58)
+        keys = tables.shape[1] * bs
+        k = min(K, keys)
+        key_pos = jnp.arange(keys, dtype=jnp.int32)
+        with jax.named_scope("dsa.index"):
+            ikeys = jax.vmap(lambda t: context("index", t, di))(tables)  # [B, keys, di]
+            for c in range(C):
+                at = (key_pos[None, :] == (first + c)[:, None])[..., None]
+                ikeys = jnp.where(at, k_i[:, c, None, :].astype(ikeys.dtype), ikeys)
+            scores = jax.vmap(sparse_index.index_scores)(q_i, w_i, ikeys)  # [B, C, keys]
+        with jax.named_scope("dsa.topk"):
+            chosen = sparse_index.select_mask(scores.reshape(B * C, keys), pos.reshape(-1), K)
+            sel, real = sparse_index.mask_positions(chosen, k)
+            sel, real = sel.reshape(B, C, k), real.reshape(B, C, k)
+        with jax.named_scope("dsa.attend"):
+            rows = _token_rows(cfg, cache, layer, tables, jnp.minimum(sel, jnp.maximum(first - 1, 0)[:, None, None]))
+            for c in range(C):
+                rows = jnp.where((sel == (first + c)[:, None, None])[..., None], row[:, c, None, None, :], rows)
+            s = jnp.einsum("bchw,bckw->bchk", q_row, rows, preferred_element_type=F32)
+            pr = probs(cfg, s.reshape(1, B * C, -1, k), real.reshape(1, B * C, k), rows.dtype).reshape(s.shape)
+            o_lat = jnp.einsum("bchk,bckw->bchw", pr, rows[..., : cfg.kv_lora_rank])
+        live = (block_tables[:, 0] != 0)[:, None, None]
+        o_lat = jnp.where(live[..., None], o_lat, 0)
+        return absorb_output(cfg, p, o_lat), jnp.where(live, window_blocks(), 0)
+
+    widths = index_rungs(cfg, block_tables.shape[1] * bs, bs)
+
+    def attend(b, width: int):
+        table = tables[b, : width // bs + nblk]
+        with jax.named_scope("dsa.index"):
+            ikeys = jax.lax.dynamic_update_slice(context("index", table, di), own[b][:, W:], (first[b], 0))[:width]
+        chosen = _chosen(cfg, q_i[b], w_i[b], ikeys, pos[b])
+        with jax.named_scope("dsa.attend"):
+            rows = jax.lax.dynamic_update_slice(context("latent", table, W), row[b], (first[b], 0))[:width]
+            return attend_masked(cfg, q_row[b], rows, chosen)
+
+    def slot(b):
+        n = jnp.sum(first[b] + true_lens[b] > jnp.asarray(widths[:-1], jnp.int32))
+        return jax.lax.switch(n, [functools.partial(attend, b, width) for width in widths])
+
+    o_lat = slot(0)[None] if B == 1 else jax.lax.map(slot, jnp.arange(B))
+    return absorb_output(cfg, p, o_lat), window_blocks()
+
+
+
 def latent_attention(
-    cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens, flash=None,
+    cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens, flash=None, index=None,
 ):
     """Causal attention of a window's queries (``[B, C, H, .]``, rope
     applied) over the cached context of their slots through ``block_tables
@@ -300,7 +507,10 @@ def latent_attention(
     Returns ``(out [B, C, H, dv], blocks [B, nblk * block_size, kr + dr])``:
     ``blocks`` are the ``nblk`` (:func:`blocks_of_window`) blocks from the
     window's first on, old rows and new, as the cache must hold them after
-    the step."""
+    the step. A model that SELECTS (:func:`indexed`) hands the window's
+    ``index`` over and goes :func:`_sparse_attention`'s way."""
+    if index is not None:
+        return _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_tables, pos, true_lens)
     B, C = pos.shape
     L, N, *block = cache["latent"].shape
     W, bs, nblk = cfg.latent_width, block_size_of(cfg, cache), blocks_of_window(cfg, cache, C)
@@ -417,7 +627,8 @@ def block_size_of(cfg, cache) -> int:
 
 def write_blocks(cfg, cache, block_tables, first, blocks, layer0: int = 0):
     """Every layer's updated blocks of a step, ``blocks [n_layers, B, nblk
-    * block_size, kr + dr]`` (:func:`latent_attention`), into the cache:
+    * block_size, kr + dr]`` (:func:`latent_attention`; ``kr + dr + di`` for
+    a model that selects: each array takes its part), into the cache:
     ONE scatter of whole rows of the cache seen as ``[layers x blocks,
     block]``, in place in the donated argument (a scatter of one ``kr +
     dr``-wide window a token was 40,960 sequential updates a prefill chunk:
@@ -434,5 +645,13 @@ def write_blocks(cfg, cache, block_tables, first, blocks, layer0: int = 0):
     tables = jnp.pad(block_tables, ((0, 0), (0, nblk)))
     ids = jax.vmap(lambda t, at: jax.lax.dynamic_slice(t, (at,), (nblk,)))(tables, first // bs)
     rows = (jnp.arange(layer0, layer0 + L, dtype=jnp.int32)[:, None, None] * N + ids[None]).reshape(-1)
-    flat = cache["latent"].reshape(-1, *block).at[rows].set(blocks.reshape(L * B * nblk, *block))
-    return {"latent": flat.reshape(cache["latent"].shape)}
+    parts = {"latent": blocks}
+    if "index" in cache:  # a model that selects: the indexer's key beside the row
+        W = cfg.latent_width
+        parts = {"latent": blocks[..., :W], "index": blocks[..., W:]}
+    out = {}
+    for name, part in parts.items():
+        block = cache[name].shape[2:]
+        flat = cache[name].reshape(-1, *block).at[rows].set(part.reshape(L * B * nblk, *block))
+        out[name] = flat.reshape(cache[name].shape)
+    return out

@@ -182,6 +182,17 @@ def _group_shapes(cfg: Xing4Config, moe: bool) -> Dict[str, Tuple[int, ...]]:
         "wo": (H, cfg.v_head_dim, D),
         "mlp_norm": (D,),
     }
+    if latent.indexed(cfg):
+        # the indexer of a learned sparse selection (``models/glm_dsa.py``): its
+        # queries from the query's latent, its ONE key and its heads' weights
+        # from the layer's input; the key's layer norm has a weight and a bias
+        shapes.update({
+            "idx_wq": (cfg.q_lora_rank, cfg.index_n_heads, cfg.index_head_dim),
+            "idx_wk": (D, cfg.index_head_dim),
+            "idx_k_norm": (cfg.index_head_dim,),
+            "idx_k_bias": (cfg.index_head_dim,),
+            "idx_ww": (D, cfg.index_n_heads),
+        })
     for sub in ("hc_attn", "hc_mlp") if n else ():
         shapes.update({f"{sub}_phi": (n * D, maps), f"{sub}_b": (maps,), f"{sub}_alpha": (3,)})
     if moe:
@@ -299,6 +310,8 @@ def _init_group(cfg: Xing4Config, key, count: int, moe: bool) -> Dict[str, Any]:
             b = jax.random.normal(k, full, F32)
             res = 0.2 * b[:, 2 * n:] + jnp.eye(n, dtype=F32).reshape(-1)
             out[name] = jnp.concatenate([b[:, : 2 * n], res], axis=1)
+        elif name == "idx_k_bias":
+            out[name] = jnp.zeros(full, cfg.dtype)
         elif name == "router":
             # routing logits are precision-sensitive: keep f32
             out[name] = _draw(k, full, shape[0], F32)
@@ -308,9 +321,8 @@ def _init_group(cfg: Xing4Config, key, count: int, moe: bool) -> Dict[str, Any]:
             # contraction dims: all but the last of a 2-D weight; the
             # rank of the up-projections; heads x v of ``wo``; an
             # expert's own input width
-            fan_in = {"w_qb": shape[0], "w_kvb": shape[0], "wo": shape[0] * shape[1]}.get(
-                name, shape[-2]
-            )
+            fan_in = {"w_qb": shape[0], "w_kvb": shape[0], "idx_wq": shape[0],
+                      "wo": shape[0] * shape[1]}.get(name, shape[-2])
             if name in ("wo", "w_down", "shared_down"):
                 # a sublayer's last projection, scaled down by the depth
                 # (the 1 / sqrt(2 L) of GPT-2's initialisation)
@@ -407,7 +419,9 @@ def _latent_qkv(cfg: Xing4Config, p, h, pos):
     """The projections of one attention on normed activations ``h [B, C,
     D]`` at positions ``pos [B, C]``: ``(q_nope [B, C, H, dn], q_rope [B, C,
     H, dr]`` rotated, ``row [B, C, kr + dr])``, the row being what the cache
-    holds of the token: the normed latent and the rotated rope part."""
+    holds of the token: the normed latent and the rotated rope part. Fourth,
+    for a model that selects (``latent.indexed``), what its indexer makes of
+    the token (:func:`_indexer`), else None."""
     dn, kr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     with jax.named_scope("mla.q"):
         c_q = rms_norm(h @ p["w_qa"], p["q_norm"], cfg.norm_eps)
@@ -417,7 +431,26 @@ def _latent_qkv(cfg: Xing4Config, p, h, pos):
         ckv = h @ p["w_kva"]
         c = rms_norm(ckv[..., :kr], p["kv_norm"], cfg.norm_eps)
         row = jnp.concatenate([c, _rope(cfg, ckv[..., kr:], pos)], axis=-1)
-    return q_nope, q_rope, row
+    return q_nope, q_rope, row, _indexer(cfg, p, h, c_q, pos) if latent.indexed(cfg) else None
+
+
+def _indexer(cfg: Xing4Config, p, h, c_q, pos):
+    """The indexer's part of a token: ``(q_i [B, C, Hi, di]`` from the
+    query's normed latent ``c_q``, ``k_i [B, C, di]`` the layer norm of ``h
+    W_k``, both with their first ``qk_rope_head_dim`` numbers rotated at the
+    token's position, ``w [B, C, Hi])`` float32, ``h W_w`` scaled by ``Hi^-1/2
+    di^-1/2``: ``k_i`` is what the cache's ``index`` row holds of the token,
+    and ``ops/sparse_index.py`` scores ``sum_j w_j relu(q_i_j . k_i)``."""
+    dr = cfg.qk_rope_head_dim
+    with jax.named_scope("dsa.project"):
+        q = jnp.einsum("bcr,rhk->bchk", c_q, p["idx_wq"])
+        q = jnp.concatenate([_rope(cfg, q[..., :dr], pos[:, :, None]), q[..., dr:]], axis=-1)
+        k = (h @ p["idx_wk"]).astype(F32)
+        k = (k - k.mean(-1, keepdims=True)) * jax.lax.rsqrt(k.var(-1, keepdims=True) + cfg.norm_eps)
+        k = k.astype(h.dtype) * p["idx_k_norm"] + p["idx_k_bias"]
+        k = jnp.concatenate([_rope(cfg, k[..., :dr], pos), k[..., dr:]], axis=-1)
+        w = (h @ p["idx_ww"]).astype(F32) * (cfg.index_n_heads * cfg.index_head_dim) ** -0.5
+    return q, k, w
 
 
 def _ffn(cfg: Xing4Config, p, h, valid, moe: bool, at=None):
@@ -616,8 +649,9 @@ def forward(cfg: Xing4Config, params, tokens, *, remat=False, mesh=None, rules=N
     X = _embed(cfg, {"embed": emb}, tokens)
 
     def attention(p, h, layer):
-        q_nope, q_rope, rows = _latent_qkv(cfg, p, h, pos)
-        o = latent.attend_expanded(cfg, p, q_nope, q_rope, rows, causal)
+        q_nope, q_rope, rows, index = _latent_qkv(cfg, p, h, pos)
+        seen = causal if index is None else latent.selection(cfg, index, pos)
+        o = latent.attend_expanded(cfg, p, q_nope, q_rope, rows, seen)
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), None
 
     wrap = jax.checkpoint if remat not in (False, None) else None
@@ -685,10 +719,10 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     flash = not absorbs(cfg, window) and latent.flash_serves(cfg, window, cache, block_tables.shape[1] * bs)
 
     def attention(p, h, layer):
-        q_nope, q_rope, row = _latent_qkv(cfg, p, h, pos)
+        q_nope, q_rope, row, index = _latent_qkv(cfg, p, h, pos)
         o, blocks = latent.latent_attention(
             cfg, p, q_nope, q_rope, row, cache, layer, block_tables, pos, true_lens,
-            flash=flash,
+            flash=flash, index=index,
         )
         return jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"]), blocks
 
