@@ -77,13 +77,21 @@ class GlmDsaConfig(DeepseekV3Config):
 
 
 def _attention_path(cfg: GlmDsaConfig, window: int, cache, backend=None) -> AttentionPath:
-    """Every window selects. A decode or verify window reads the index keys
-    at the table's width for every slot of the bucket and gathers the chosen
-    latent rows by token; a chunk reads both arrays up to its rung
-    (``latent.index_rungs``: ``Model.gather_rungs``) and attends under the
-    selection as a mask over all of it."""
-    del backend
-    return AttentionPath("latent.sparse" if latent.absorbs(cfg, window) else "latent.sparse_masked", "table")
+    """Every window selects. A decode or verify window (``latent.sparse``)
+    reads the index keys at the table's width for every slot of the bucket
+    and gathers the chosen latent rows by token. A chunk reads both arrays up
+    to its rung (``latent.index_rungs``), scores and selects there, and
+    attends under the selection as a mask over all of it, by what will run:
+    ``latent.sparse_flash`` where the kernel serves
+    (``latent.selected_serves``: a TPU, bf16, whole tiles: the EXPANDED form,
+    K and V expanded in VMEM from the key tiles up to the chunk's end alone,
+    the scores never in HBM), ``latent.sparse_masked`` elsewhere (the
+    absorbed form's materialised softmax over the rung, XLA's)."""
+    if latent.absorbs(cfg, window):
+        return AttentionPath("latent.sparse", "table")
+    if latent.selected_serves(cfg, window, cache, backend=backend):
+        return AttentionPath("latent.sparse_flash", "live")
+    return AttentionPath("latent.sparse_masked", "table")
 
 
 MODEL = replace(

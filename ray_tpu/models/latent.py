@@ -201,9 +201,18 @@ def flash_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
 
 def gather_rungs(cfg, window: int, cache) -> tuple:
     """``Model.gather_rungs`` of a model whose chunks go through
-    :func:`latent_attention`: :func:`key_rungs` at the runner's full table."""
+    :func:`latent_attention`: the widths K and V of a chunk's context are
+    EXPANDED at, which the runner counts (``prefill_width.expanded_tokens``).
+    :func:`key_rungs` at the runner's full table; a model that selects expands
+    where it gathers, at :func:`index_rungs`, wherever its chunk attends in
+    the absorbed form (:func:`attend_masked`: nothing is expanded, the rung is
+    what it multiplies), and a key tile at a time up to its own end where the
+    kernel expands them (:func:`selected_serves`; what it GATHERS in front is
+    still its rung)."""
     keys, bs = table_keys(cfg, cache), block_size_of(cfg, cache)
-    return index_rungs(cfg, keys, bs) if indexed(cfg) else key_rungs(window, keys, bs)
+    if indexed(cfg) and not selected_serves(cfg, window, cache, keys):
+        return index_rungs(cfg, keys, bs)
+    return key_rungs(window, keys, bs)
 
 
 def table_keys(cfg, cache) -> int:
@@ -313,7 +322,8 @@ def _query_block(cfg, queries: int, keys: int) -> int:
     most 32, and fewer where their float32 scores over ``keys`` positions (all
     heads) would pass 256 MB (a table past 32,768); a power of two that
     divides ``queries``. Why 32: timed a layer on the chip at every rung
-    (PERF.md, PR 58), 32 queries at a time are as fast as 64 from a rung of
+    (PERF.md, PR 58; the chip's chunk went that way until PR 60's kernel),
+    32 queries at a time are as fast as 64 from a rung of
     16,384 up and faster below it (4.8 against 6.1 and 9.4 ms at 4096 for 32,
     64 and 256), and 128 or more over a rung of 8192 ran 10 x slower than
     their operations (196 ms: XLA lays the scores out heads-major there)."""
@@ -326,10 +336,14 @@ def _query_block(cfg, queries: int, keys: int) -> int:
 def attend_masked(cfg, q_row, rows, mask):
     """Absorbed queries ``q_row [C, H, kr + dr]`` of ONE slot over its latent
     rows ``rows [S, kr + dr]`` (the chunk's own laid in), each query under its
-    own ``mask [C, S]`` (the selection: causal already): ``[C, H, kr]``. A
-    block of queries at a time (:func:`_query_block`); the unchosen positions
-    are multiplied and masked, not gathered around: 1024 queries x 2048 chosen
-    rows gathered by token would be 2.4 GB a layer."""
+    own ``mask [C, S]`` (the selection: causal already): ``[C, H, kr]``, the
+    materialised softmax between :func:`absorb_query` and
+    :func:`absorb_output`. What a selecting chunk runs wherever the kernel
+    does not serve (:func:`selected_serves`: the CPU, odd widths), and what
+    the kernel's tests hold it to. A block of queries at a time
+    (:func:`_query_block`); the unchosen positions are multiplied and masked,
+    not gathered around: 1024 queries x 2048 chosen rows gathered by token
+    would be 2.4 GB a layer."""
     kr, C = cfg.kv_lora_rank, q_row.shape[0]
 
     def attend(args):
@@ -344,6 +358,48 @@ def attend_masked(cfg, q_row, rows, mask):
     out = jax.lax.map(attend, (q_row.reshape(C // block, block, *q_row.shape[1:]),
                                mask.reshape(C // block, block, -1)))
     return out.reshape(C, *out.shape[2:])
+
+
+def selected_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
+    """Whether a selecting chunk of ``window`` queries attends in the
+    EXPANDED form through the kernel that takes the selection as a mask
+    (:func:`attend_selected`) over a table of ``keys`` positions (the
+    runner's full width where none is given):
+    ``ops/latent_flash.py::selected_serves`` on what the code can observe
+    (backend, dtype, whole tiles, the widths), for a window long enough to
+    expand (:func:`absorbs`). Off a TPU the cache is not looked at."""
+    if (backend or jax.default_backend()) != "tpu" or not indexed(cfg) or absorbs(cfg, window):
+        return False
+    return latent_flash.selected_serves(
+        window, keys or table_keys(cfg, cache), cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+        cfg.qk_rope_head_dim, cfg.v_head_dim, cache["latent"].dtype, "tpu",
+    )
+
+
+def attend_selected(cfg, p, q_nope, q_rope, rows, mask, ctx_len, true_len, interpret=None):
+    """ONE slot's chunk under its selection, in the expanded form, through
+    the kernel (``ops/latent_flash.py::attend_selected``): queries ``[C, H,
+    .]`` over latent rows ``rows [S, kr + dr]`` (the chunk's own laid in),
+    query ``c`` attending to row ``j`` iff ``mask[c, j]`` (``[C, S]``, causal
+    and chosen; ``S`` the TABLE's width: the kernel reads the key tiles up to
+    ``ctx_len + true_len`` and no other, so what lies past a chunk's rung is
+    padding nobody fetches). K and V of a key tile are expanded from the rows
+    INSIDE the kernel, a head at a time: they are never whole in HBM (at
+    24,576 positions x 64 heads x 256 they would be 1.6 GB), nor are the
+    scores. The mathematics is :func:`attend_masked`'s between
+    :func:`absorb_query` and :func:`absorb_output` with ``W_kvb`` multiplied
+    into the rows instead (K and V rounded to the rows' dtype, as
+    :func:`attend_flash`'s are). Returns ``[C, H, dv]``."""
+    dn = cfg.qk_nope_head_dim
+    with jax.named_scope("mla.expand"):
+        w = p["w_kvb"].swapaxes(0, 1)  # [H, kr, dn + dv]
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).swapaxes(0, 1)
+    with jax.named_scope("mla.attend"):
+        out = latent_flash.attend_selected(
+            q, rows, w[..., :dn], w[..., dn:], mask, ctx_len, true_len,
+            scale=cfg.attn_scale, interpret=interpret,
+        )
+    return out.swapaxes(0, 1)
 
 
 def _token_rows(cfg, cache, layer, tables, positions):
@@ -380,16 +436,27 @@ def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_ta
     ``index_topk`` positions all are chosen, and the result is the dense
     one): ``dsa.index`` (the scores of each query against the slot's index
     keys, the window's own laid in), ``dsa.topk`` (the exact choice,
-    ``ops/sparse_index.py``), ``dsa.attend``, ``W_kvb`` absorbed either way:
+    ``ops/sparse_index.py``), ``dsa.attend``:
 
-    * a prefill chunk, one slot at a time over the key positions up to its
-      rung (:func:`index_rungs`): the softmax over ALL rows of the rung under
-      the selection as a mask (:func:`attend_masked`);
+    * a prefill chunk, one slot at a time: the index keys and the latent rows
+      gathered up to its rung (:func:`index_rungs`, a ``lax.switch``), the
+      scores and the choice there, and the softmax over ALL rows of the rung
+      under the selection as a mask (gathering 2048 chosen rows a query by
+      token was slower at every context up to 32 k: PERF.md, PR 58). Where
+      the kernel serves (:func:`selected_serves`: a TPU, bf16, whole tiles)
+      in the EXPANDED form, half the operations a (query, key) pair
+      (:func:`absorbs`): the switch returns what is rung-sized, the mask and
+      the rows padded to the table's width, and ONE instance of the kernel
+      behind it (:func:`attend_selected`) expands K and V a key tile at a
+      time in VMEM up to the chunk's end and keeps the scores there.
+      Elsewhere (the CPU, odd widths) in the absorbed form, XLA's
+      materialised softmax inside the switch (:func:`attend_masked` between
+      :func:`absorb_query` and :func:`absorb_output`);
     * a decode or verify window (:func:`absorbs`; a few positions: the
-      window's own are laid in by a select each): the index keys at the
-      table's width, the chosen latent rows gathered BY TOKEN
-      (:func:`_token_rows`; a chosen position of the window itself is the
-      window's own row), the softmax over ``index_topk`` rows a query.
+      window's own are laid in by a select each), ``W_kvb`` absorbed: the
+      index keys at the table's width, the chosen latent rows gathered BY
+      TOKEN (:func:`_token_rows`; a chosen position of the window itself is
+      the window's own row), the softmax over ``index_topk`` rows a query.
 
     Returns ``(out, blocks)`` as there, ``blocks [B, nblk * block_size, kr +
     dr + di]``: a token's two rows side by side."""
@@ -414,8 +481,8 @@ def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_ta
         )
         return jax.vmap(lambda r, n, a: jax.lax.dynamic_update_slice(r, n, (a % bs, 0)))(old, own, first)
 
-    q_row = absorb_query(cfg, p, q_nope, q_rope)
     if absorbs(cfg, C):
+        q_row = absorb_query(cfg, p, q_nope, q_rope)
         # the slots at once, on explicit batch axes: a padding slot (the null
         # block's table) reads the null block's rows, finite and nobody's, and
         # comes back as zeros. The window's own keys and rows are laid in by
@@ -446,7 +513,10 @@ def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_ta
         o_lat = jnp.where(live[..., None], o_lat, 0)
         return absorb_output(cfg, p, o_lat), jnp.where(live, window_blocks(), 0)
 
-    widths = index_rungs(cfg, block_tables.shape[1] * bs, bs)
+    keys = block_tables.shape[1] * bs
+    widths = index_rungs(cfg, keys, bs)
+    flash = selected_serves(cfg, C, cache, keys)
+    q_row = None if flash else absorb_query(cfg, p, q_nope, q_rope)
 
     def attend(b, width: int):
         table = tables[b, : width // bs + nblk]
@@ -455,15 +525,24 @@ def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_ta
         chosen = _chosen(cfg, q_i[b], w_i[b], ikeys, pos[b])
         with jax.named_scope("dsa.attend"):
             rows = jax.lax.dynamic_update_slice(context("latent", table, W), row[b], (first[b], 0))[:width]
+            if flash:
+                # what is rung-sized, padded to the table: the ONE kernel
+                # behind the switch reads neither past the live length
+                return (jnp.pad(rows, ((0, keys - width), (0, 0))),
+                        jnp.pad(chosen.astype(jnp.int8), ((0, 0), (0, keys - width))))
             return attend_masked(cfg, q_row[b], rows, chosen)
 
     def slot(b):
         n = jnp.sum(first[b] + true_lens[b] > jnp.asarray(widths[:-1], jnp.int32))
-        return jax.lax.switch(n, [functools.partial(attend, b, width) for width in widths])
+        out = jax.lax.switch(n, [functools.partial(attend, b, width) for width in widths])
+        if not flash:
+            return out
+        rows, mask = out
+        with jax.named_scope("dsa.attend"):
+            return attend_selected(cfg, p, q_nope[b], q_rope[b], rows, mask, first[b], true_lens[b])
 
-    o_lat = slot(0)[None] if B == 1 else jax.lax.map(slot, jnp.arange(B))
-    return absorb_output(cfg, p, o_lat), window_blocks()
-
+    out = slot(0)[None] if B == 1 else jax.lax.map(slot, jnp.arange(B))
+    return (out if flash else absorb_output(cfg, p, out)), window_blocks()
 
 
 def latent_attention(

@@ -63,6 +63,12 @@ reach the output.
 
 The call is jitted by itself: a model whose layers run under two scans
 lowers the kernel once, not once a scan.
+
+A chunk of a model that SELECTS (``models/latent.py::_sparse_attention``: a
+learned choice of positions a query, handed over as a mask) has a kernel of
+its own, :func:`attend_selected`, which shares no line with the one above:
+it is handed the LATENT rows and expands a key tile's K and V from them in
+VMEM (see there). The calls above lower to the text they always did.
 """
 
 from __future__ import annotations
@@ -85,6 +91,11 @@ _MASKED = -1e30
 #: queries: (256, 1024) 0.16 / 0.24 / 0.38 / 0.45 (PERF.md, PR 32)
 _QUERY_TILE = 1024
 _KEY_TILE = 1024
+
+#: VMEM :func:`attend_selected`'s kernel may use: at GLM-5's widths and (1024,
+#: 1024) tiles its buffers and the tile's float32 scores and probabilities are
+#: past the 16 MB a kernel gets unasked (a v5e core has 128 MB)
+_SELECTED_VMEM = 64 * 1024 * 1024
 
 
 def tiles(window: int, keys: int) -> tuple:
@@ -330,3 +341,193 @@ def flash_attention(
     if paired:  # a head's output: its own half of the pair's
         out = jnp.where(own[:, None, :, None], out.reshape(H, C, 2, d), 0).sum(axis=2)
     return out
+
+
+# ---------------------------------------------------------------------------
+# a chunk under a SELECTION, over latent rows: K and V expanded in the kernel
+
+
+def selected_serves(
+    window: int, keys: int, kr: int, dn: int, dr: int, dv: int, dtype, backend: str | None = None
+) -> bool:
+    """Whether :func:`attend_selected` runs the kernel for a chunk of
+    ``window`` queries over ``keys`` latent rows ``[c (kr) | k_shared (dr)]``
+    at head widths ``dn`` + ``dr`` and ``dv``: on a TPU, in bf16 (what it was
+    compiled and timed in), the chunk ONE query tile (so a key tile of a head
+    is expanded once) and the keys whole key tiles, the mask's tile in whole
+    int8 registers ``(32, 128)``, and every product over whole lanes: a key
+    ``[k_nope | k_shared]`` and a value of whole lane tiles (GLM-5's 192 + 64
+    and 256), ``kr`` too (the value's product reads the row's first ``kr``
+    lanes). Everything else keeps the materialised softmax. Decided at trace
+    time, from shapes and the backend."""
+    backend = backend or jax.default_backend()
+    block_k = tiles(window, keys)[1]
+    return (
+        backend == "tpu"
+        and dtype == jnp.bfloat16
+        and window <= _QUERY_TILE
+        and window % 128 == 0
+        and block_k % 128 == 0
+        and keys % block_k == 0
+        and kr % 128 == 0
+        and (dn + dr) % 128 == 0
+        and dv % 128 == 0
+    )
+
+
+def _selected_kernel(
+    ctx_ref,  # SMEM [1] int32: the first query's position
+    len_ref,  # SMEM [1] int32: real queries of the chunk
+    q_ref,  # VMEM [1, C, dn + dr]: a head's queries, [q_nope | q_shared]
+    rows_ref,  # VMEM [block_k, kr + dr]: a key tile of latent rows
+    wk_ref,  # VMEM [1, kr + dr, dn + dr]: the head's [[W_k, 0], [0, I]]
+    wv_ref,  # VMEM [1, kr, dv]: the head's W_v
+    mask_ref,  # VMEM [C, block_k] int8: which keys each query attends to
+    o_ref,  # VMEM [1, C, dv]
+    acc_ref,  # scratch [C, dv] float32
+    m_ref,  # scratch [C, 1] float32
+    l_ref,  # scratch [C, 1] float32
+    *,
+    scale: float,
+    kr: int,
+):
+    from jax.experimental import pallas as pl
+
+    block_k = rows_ref.shape[0]
+    j = pl.program_id(1)
+    n_live = ctx_ref[0] + len_ref[0]
+    lo = j * block_k
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(lo < n_live)
+    def _():
+        rows = rows_ref[...]
+        # rows past the live context: stale, or never written. Zeros: K and V
+        # of them are zeros, and no real query's mask names them
+        rows = jnp.where(lo + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0) < n_live, rows, 0)
+        # the tile's K and V for this head, as XLA's expansion rounds them
+        k = jnp.dot(rows, wk_ref[0], preferred_element_type=jnp.float32).astype(rows.dtype)
+        v = jnp.dot(rows[:, :kr], wv_ref[0], preferred_element_type=jnp.float32).astype(rows.dtype)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        s = jnp.where(mask_ref[...].astype(jnp.int32) != 0, s * scale, _MASKED)
+        # a query's first chosen key may lie in a LATER tile: until then its
+        # m is the masked value, p is 1 for every key and l and acc sum
+        # nobody's rows; its first real score wipes them with alpha = 0
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]
+        # a chunk with no real query ran no step: zeros, not 0 / 0
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
+def _selected_call(q, rows, w_key, w_v, mask, ctx_len, true_len, *, scale, block_k, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, C, dk = q.shape
+    S, W = rows.shape
+    kr, dv = w_v.shape[1], w_v.shape[2]
+
+    def key_tile(j, ctx, n):
+        # clamped at the tile of the last real query's own position: the
+        # tiles past it repeat its index and are not fetched
+        return jnp.minimum(j, jnp.maximum(ctx[0] + n[0] - 1, 0) // block_k)
+
+    head = lambda h, j, ctx, n: (h, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_selected_kernel, scale=scale, kr=kr),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H, S // block_k),
+            in_specs=[
+                pl.BlockSpec((1, C, dk), head),
+                pl.BlockSpec((block_k, W), lambda h, j, ctx, n: (key_tile(j, ctx, n), 0)),
+                pl.BlockSpec((1, W, dk), head),
+                pl.BlockSpec((1, kr, dv), head),
+                pl.BlockSpec((C, block_k), lambda h, j, ctx, n: (0, key_tile(j, ctx, n))),
+            ],
+            out_specs=pl.BlockSpec((1, C, dv), head),
+            scratch_shapes=[
+                pltpu.VMEM((C, dv), jnp.float32),
+                pltpu.VMEM((C, 1), jnp.float32),
+                pltpu.VMEM((C, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((H, C, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_SELECTED_VMEM,
+        ),
+        name="latent_flash_selected",
+        interpret=interpret,
+    )(ctx_len.reshape(1), true_len.reshape(1), q, rows, w_key, w_v, mask)
+
+
+def attend_selected(
+    q, rows, w_k, w_v, mask, ctx_len, true_len, *, scale: float, block_k=None, interpret=None
+):
+    """Attention of ONE slot's chunk under a selection, in the EXPANDED form,
+    over the slot's latent rows: ``q [H, C, dn + dr]`` (a head's ``[q_nope |
+    q_shared]``), ``rows [S, kr + dr]`` (``[c | k_shared]``, the chunk's own
+    laid in), ``w_k [H, kr, dn]`` and ``w_v [H, kr, dv]`` (``W_kvb`` a head),
+    ``mask [C, S]`` (bool or int8): query ``c`` attends to key ``j`` iff
+    ``mask[c, j]``. The mask is the WHOLE predicate and must be causal already
+    (a real query names no key past its own position ``ctx_len + c``, which
+    lies under ``ctx_len + true_len``: ``ops/sparse_index.py::select_mask``'s
+    is), and every real query names at least one key. Returns ``[H, C, dv]``
+    in ``q``'s dtype; what a query past ``true_len`` gets is finite and
+    nobody's.
+
+    The grid is ``(heads, key tiles)`` and the chunk is ONE query tile, so
+    each (head, key tile) is expanded ONCE, in VMEM: ``K = rows [[W_k, 0], [0,
+    I]]`` (one product over the whole row gives ``[k_nope | k_shared]``: the
+    identity copies the shared part exactly, and nothing is concatenated at
+    192 lanes) and ``V = c W_v``, rounded to the rows' dtype as XLA's
+    expansion rounds them (``models/latent.py::attend_flash``); K and V are
+    never in HBM, nor are the scores. Then the online softmax of
+    :func:`flash_attention`, to the letter: scores float32, times ``scale``,
+    unchosen ones ``-1e30``, probabilities cast to V's dtype, float32
+    accumulation. A key tile past the live length ``ctx_len + true_len``
+    (prefetched scalars) is neither fetched (rows, mask) nor expanded nor
+    multiplied, so ONE instance at the table's width serves every context;
+    rows past the live length within the last tile are read as zeros, so what
+    is stale or NaN there cannot reach the output.
+
+    ``block_k``: the key tile (default :func:`tiles`'; ``S`` must be whole
+    tiles). ``interpret``: as :func:`flash_attention`."""
+    H, C, _ = q.shape
+    kr, dn = w_k.shape[1], w_k.shape[2]
+    dr = rows.shape[1] - kr
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    # [[W_k, 0], [0, I]] a head: [kr + dr, dn + dr]
+    w_key = jnp.concatenate(
+        [
+            jnp.pad(w_k, ((0, 0), (0, 0), (0, dr))),
+            jnp.broadcast_to(jnp.pad(jnp.eye(dr, dtype=w_k.dtype), ((0, 0), (dn, 0))), (H, dr, dn + dr)),
+        ],
+        axis=1,
+    )
+    return _selected_call(
+        q, rows, w_key, w_v, mask.astype(jnp.int8),
+        jnp.asarray(ctx_len, jnp.int32), jnp.asarray(true_len, jnp.int32),
+        scale=float(scale), block_k=block_k or tiles(C, rows.shape[0])[1], interpret=bool(interpret),
+    )

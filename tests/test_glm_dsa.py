@@ -186,6 +186,58 @@ def test_a_chunk_takes_the_first_rung_that_holds_it_and_every_rung_agrees(cfg, p
         np.testing.assert_allclose(cache[name][:, 1:20], cache_whole[name][:, 1:20], atol=1e-6)
 
 
+@pytest.fixture(scope="module")
+def expanding(cfg):
+    """The toy at widths in GLM-5's ratio (latent 32, key 12 + 4, value 16),
+    at which a chunk of 40 EXPANDS as the published chunk of 1024 does (at the
+    toy's own widths every window absorbs and a chunk takes the decode
+    window's way), over a table of 192 positions: rungs of 48."""
+    wide = dataclasses.replace(
+        cfg, kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16, max_seq_len=192
+    )
+    assert not latent.absorbs(wide, 40) and latent.absorbs(wide, 2)
+    assert latent.index_rungs(wide, 192, BS) == (48, 96, 144, 192)
+    return wide, MODEL.init_params(wide, jax.random.PRNGKey(7))
+
+
+def _rung_prefill(wide, params, chunks):
+    row = np.random.default_rng(3).integers(1, 256, size=160).astype(np.int32)
+    table = np.zeros(24, np.int32)
+    table[:20] = np.arange(1, 21)
+    cache, logits = _prefill(wide, params, MODEL.cache_layout(wide, BS).init(24), row, table, chunks)
+    return row, logits, {k: np.asarray(v) for k, v in cache.items()}
+
+
+@pytest.mark.parametrize("rung", [0, 1, 2, 3], ids=["rung_48", "rung_96", "rung_144", "rung_192"])
+def test_a_chunk_through_the_selecting_kernel_is_the_masked_softmax_at_every_rung(monkeypatch, expanding, rung):
+    """A prompt of 150 in chunks of 40, 40, 40, 30 (the last padded), each
+    at its own rung, two ways: the materialised softmax under the mask (what
+    the CPU runs) and the kernel's door (``latent.selected_serves`` answered
+    as a TPU answers it at GLM-5's widths; the kernel in Pallas' interpreter,
+    key tiles of 16, ONE instance behind the switch at the table's width).
+    The same logits after the last chunk and the same cache rows; and the
+    fallback's own last logits are the full forward pass's."""
+    wide, params = expanding
+    chunks = (40, 40, 40, 30)
+    row, want, want_cache = _rung_prefill(wide, params, chunks[: rung + 1])
+    if rung == 3:
+        full = np.asarray(MODEL.forward(wide, params, row[None, :150]))[0, 149]
+        assert _rel(want, full) < TOL
+    calls = []
+    real = latent.attend_selected
+    monkeypatch.setattr(latent, "selected_serves", lambda cfg, window, cache, keys=None, backend=None: not latent.absorbs(cfg, window))
+    monkeypatch.setattr(latent.latent_flash, "_KEY_TILE", 16)
+    monkeypatch.setattr(latent, "attend_selected", lambda *a, **kw: calls.append(a[4].shape) or real(*a, **kw))
+    _, have, have_cache = _rung_prefill(wide, params, chunks[: rung + 1])
+    # one kernel a layer body, at the table's width whatever the rung: 1 dense + 2 expert layers + the MTP module's
+    assert calls and set(calls) == {(192, wide.latent_width)}
+    assert _rel(have, want) < 1e-5
+    n = sum(chunks[: rung + 1])  # the rows of the real positions: a padded query's row is nobody's on either way
+    for name in want_cache:
+        rows = lambda c: c[name][:, 1:21].reshape(c[name].shape[0], 20 * BS, -1)[:, :n]  # noqa: E731
+        np.testing.assert_allclose(rows(have_cache), rows(want_cache), atol=1e-5)
+
+
 def test_the_one_program_step_equals_its_two_program_form(cfg, params, tokens):
     """``paged_mtp_step`` on a slot with an accepted draft, one with a wrong
     one, one without and a padding slot, at a context past ``index_topk``,

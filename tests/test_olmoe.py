@@ -834,3 +834,35 @@ def test_a_plain_configurations_prefill_chunk_compiles_for_the_chip_through_the_
         assert compiled.memory_analysis().temp_size_in_bytes < scores // 2
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def test_the_selecting_chunks_kernel_compiles_for_the_chip_at_glm5_widths(one_chip):
+    """GLM-5's chunk under its selection (``ops/latent_flash.py::attend_selected``;
+    here for the same reason as the ones above): 64 heads, latent rows of 512 +
+    64, keys 192 + 64 and values 256 expanded in VMEM, 1024 queries under an
+    int8 mask over a table of 32,768 positions, at the tiles the module fixes.
+    One Mosaic call at the TABLE's width whatever the context; K, V and the
+    scores are nobody's temporary (all that XLA adds is the head-major weights
+    and ``[[W_k, 0], [0, I]]``: 64 x 576 x 256)."""
+    from ray_tpu.ops import latent_flash as LF
+
+    H, C, S, kr, dn, dr, dv = 64, 1024, 32768, 512, 192, 64, 256
+    assert LF.selected_serves(C, S, kr, dn, dr, dv, jnp.bfloat16, backend="tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        compiled = jax.jit(
+            lambda q, rows, w_k, w_v, mask, ctx, n: LF.attend_selected(
+                q, rows, w_k, w_v, mask, ctx, n, scale=0.0625, interpret=False
+            )
+        ).lower(
+            shape((H, C, dn + dr)), shape((S, kr + dr)), shape((H, kr, dn)), shape((H, kr, dv)),
+            shape((C, S), jnp.int8), shape((), jnp.int32), shape((), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_flash_selected" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * H * (kr + dr) * (dn + dr) * 2
+        assert compiled.out_info.shape == (H, C, dv)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
