@@ -55,8 +55,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import latent, xing4
-from ray_tpu.models.interface import CacheLayout, Drafter, Model
-from ray_tpu.models.xing4 import Xing4Config, forward, rms_norm
+from ray_tpu.models.interface import CacheLayout, Drafter, Model, step_counters, step_outputs
+from ray_tpu.models.xing4 import Xing4Config, forward
+from ray_tpu.ops.layers import rms_norm
 
 F32 = jnp.float32
 
@@ -193,8 +194,8 @@ def _merge(main: Dict[str, Any], mtp: Dict[str, Any]) -> Dict[str, Any]:
     """The main layers' counters and the module's as ONE account, a row a
     layer (the module's last)."""
     return {
-        k: jnp.concatenate([main[k], mtp[k]]) for k in xing4._counters(main)
-    } if main else xing4._counters(mtp)
+        k: jnp.concatenate([main[k], mtp[k]]) for k in step_counters(main)
+    } if main else step_counters(mtp)
 
 
 def _window(tokens, ctx_lens, true_lens):
@@ -220,7 +221,7 @@ def paged_prefill_step(cfg: DeepseekV3Config, params, cache, tokens, block_table
     cache, X, aux = xing4._paged_layers(cfg, params, cache, tokens[None], pos, valid, block_table[None])
     logits = xing4._lm_head(cfg, params, X[0, jnp.maximum(true_len - 1, 0)])
     if next_token is None:
-        return xing4._step_outputs(cache, logits, aux)
+        return step_outputs(cache, logits, step_counters(aux))
     follows = jnp.where(idx + 1 < true_len, jnp.roll(tokens, -1), jnp.maximum(next_token, 0))
     rows = true_len - (next_token < 0)
     cache, _, mtp_aux = _mtp_layers(
@@ -233,7 +234,7 @@ def paged_verify_step(cfg: DeepseekV3Config, params, cache, tokens, block_tables
     """The main model over a window a slot, every row's logits: as
     ``models/xing4.py::paged_verify_step`` (the MTP module does not run)."""
     cache, logits, _, aux = paged_mtp_verify(cfg, params, cache, tokens, block_tables, ctx_lens, true_lens)
-    return xing4._step_outputs(cache, logits, aux)
+    return step_outputs(cache, logits, step_counters(aux))
 
 
 def paged_mtp_verify(cfg: DeepseekV3Config, params, cache, tokens, block_tables, ctx_lens, true_lens):
@@ -253,7 +254,7 @@ def paged_mtp_draft(cfg: DeepseekV3Config, params, cache, hidden, next_tokens, b
     slot's last committed position, aux)``: their argmax is the next draft."""
     pos, valid = _window(next_tokens, ctx_lens, true_lens)
     cache, X, aux = _mtp_layers(cfg, params, cache, hidden, next_tokens, pos, valid, block_tables)
-    return cache, _mtp_head(cfg, params, _last_valid(X, true_lens)), xing4._counters(aux)
+    return cache, _mtp_head(cfg, params, _last_valid(X, true_lens)), step_counters(aux)
 
 
 def paged_mtp_step(cfg: DeepseekV3Config, params, cache, tokens, block_tables, ctx_lens, true_lens, known):

@@ -287,6 +287,45 @@ def scatter_paged_blocks(cache, blocks, payload):
     return out
 
 
+def lm_head(params, x, eps: float, tied: bool):
+    """``x [..., D]`` -> float32 logits ``[..., vocab]`` through the final norm
+    and the head: ``params["lm_head"] [D, vocab]``, or, ``tied``, the embedding
+    read again (``params["embed"] [vocab, D]``)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.layers import rms_norm
+
+    x = rms_norm(x, params["final_norm"], eps)
+    if tied:
+        return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(jnp.float32)
+    return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(jnp.float32)
+
+
+def stack_aux(aux):
+    """The expert layers' counters (a dict a layer), stacked over the layers."""
+    import jax.numpy as jnp
+
+    return {k: jnp.stack([a[k] for a in aux]) for k in aux[0]} if aux else {}
+
+
+def step_counters(aux):
+    """Of the counters of a step's expert layers (:func:`stack_aux`) those the
+    runner reads (the group limit's where there is one)."""
+    keys = ("load", "bias_changed", "group_changed", "routed_rows")
+    return {k: aux[k] for k in keys if k in aux}
+
+
+def step_outputs(cache, logits, counters=None, state=None):
+    """What a paged step returns (:class:`Model`): ``(cache, logits)``, with
+    the state pool after the cache where the model has one, and last, where
+    experts were routed (``counters`` neither None nor empty), what the runner
+    reads with the logits: the expert loads ``[n_layers, E]`` int32 of the
+    step's valid rows, or a dict of ``load`` and further counters a layer
+    (:func:`step_counters`)."""
+    out = (cache, logits) if state is None else (cache, state, logits)
+    return out if counters is None or len(counters) == 0 else (*out, counters)
+
+
 @dataclass(frozen=True)
 class Drafter:
     """A model's OWN drafter for speculative decoding (a multi-token-prediction

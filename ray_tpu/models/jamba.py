@@ -30,9 +30,9 @@ padded row is handed ``D_t = 0`` and leaves ``h`` as it was; a decode batch
 one position a slot in place), float32 throughout: ``exp``, ``softplus``, the
 three inner norms and ``h``; the matmuls in the model's dtype with float32
 accumulation. The convolution is ``ops/short_conv.py``'s with the bias and the
-SiLU applied here. The attention reads the paged cache three ways, chosen at
-trace time from shapes and backend as in ``models/llama.py``, whose write and
-fallback it shares: a decode step on a TPU the Pallas kernel
+SiLU applied here. The attention reads the paged cache through
+``models/paged_kv.py``, the write and the three ways chosen at trace time from
+shapes and backend, as every K/V model: a decode step on a TPU the Pallas kernel
 ``ops/paged_attention.py`` (ONE KV head: a block is ``[bs, hd]`` rows, stored
 flat), a prefill chunk on a TPU the flash kernel ``ops/latent_flash.py`` over K
 and V gathered through the table (20 query heads a key head), everything else
@@ -51,10 +51,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.interface import AttentionPath, CacheLayout, Model, StateLayout
-from ray_tpu.models.llama import _attend_gathered, _block_at, _block_size, _scatter_kv, rms_norm
-from ray_tpu.ops import latent_flash, selective_scan, short_conv
-from ray_tpu.ops import paged_attention as paged_attn
+from ray_tpu.models import paged_kv
+from ray_tpu.models.interface import AttentionPath, CacheLayout, Model, StateLayout, lm_head
+from ray_tpu.ops import selective_scan, short_conv
+from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.moe import gated_mlp
 from ray_tpu.parallel.sharding import constrain
 
@@ -308,12 +308,6 @@ def _mlp(cfg: JambaConfig, p, x):
         return gated_mlp(rms_norm(x, p["ffn_norm"], cfg.norm_eps), p["w_gate"], p["w_up"], p["w_down"])
 
 
-def _lm_head(cfg: JambaConfig, params, x):
-    """The tied head: the final norm, then the embedding read again."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
-
-
 # ---------------------------------------------------------------------------
 # forward (the full sequence: the tests' other side; no cache, no slots)
 
@@ -347,7 +341,7 @@ def forward(cfg: JambaConfig, params, tokens, *, remat=False, mesh=None, rules=N
             mix = jnp.einsum("bchk,hkd->bcd", o.reshape(B, S, cfg.n_heads, -1), p["wo"])
         x = x + mix
         x = x + _mlp(cfg, p, x)
-    logits = constrain(_lm_head(cfg, params, x), mesh, rules, ("act_batch", "act_seq", "act_vocab"))
+    logits = constrain(lm_head(params, x, cfg.norm_eps, tied=True), mesh, rules, ("act_batch", "act_seq", "act_vocab"))
     if return_aux:
         return logits, jnp.zeros((), F32)
     return logits
@@ -390,37 +384,9 @@ def state_layout(cfg: JambaConfig) -> StateLayout:
     )
 
 
-def _kernel_serves(cfg: JambaConfig, window: int, k_cache, backend=None) -> bool:
-    return paged_attn.kernel_serves(window, cfg.n_heads, k_cache, backend, n_kv=cfg.n_kv_heads)
-
-
-def _flash_serves(cfg: JambaConfig, window: int, k_cache, table_keys: int, backend=None) -> bool:
-    return latent_flash.kernel_serves(
-        window, table_keys, cfg.head_dim, cfg.head_dim, 0, k_cache.dtype, backend
-    )
-
-
-def _paged_attention(cfg: JambaConfig, q, cache, layer: int, block_tables, pos, true_lens):
-    """Causal attention of ``q [B, C, H, hd]`` over the cached context of its
-    slot through ``block_tables [B, M]``; the step's own K and V are in the
-    cache already. The ONE place a serving step reads the cache for
-    attention, three ways (the module's docstring)."""
-    B, C = pos.shape
-    k_cache, v_cache = cache["k"], cache["v"]
-    n_kv, hd = cfg.n_kv_heads, cfg.head_dim
-    keys = block_tables.shape[1] * _block_size(cfg, k_cache)
-    if _kernel_serves(cfg, C, k_cache):
-        return paged_attn.paged_attention(q, k_cache, v_cache, layer, block_tables, pos, n_kv=n_kv)
-    if B == 1 and _flash_serves(cfg, C, k_cache, keys):
-        with jax.named_scope("attn.gather"):
-            ks = k_cache[layer, block_tables[0]].reshape(keys, n_kv, hd).transpose(1, 0, 2)
-            vs = v_cache[layer, block_tables[0]].reshape(keys, n_kv, hd).transpose(1, 0, 2)
-        o = latent_flash.flash_attention(
-            q[0].transpose(1, 0, 2), ks, vs, pos[0, 0], true_lens[0],
-            scale=1.0 / math.sqrt(hd), group=cfg.n_heads // n_kv,
-        )
-        return o.transpose(1, 0, 2)[None]
-    return _attend_gathered(q, k_cache, v_cache, layer, block_tables, pos, n_kv, keys)
+def _shapes(cfg: JambaConfig) -> Dict[str, int]:
+    """What ``models/paged_kv.py`` is told beside the cache's shape."""
+    return {"n_kv": cfg.n_kv_heads, "head_dim": cfg.head_dim}
 
 
 def _attention_mix(cfg: JambaConfig, p, cache, index: int, h, pos, valid, block_tables):
@@ -431,12 +397,15 @@ def _attention_mix(cfg: JambaConfig, p, cache, index: int, h, pos, valid, block_
     padding row's to the null block), the attention over the cache (after the
     write: a window attends to itself) and ``wo``. Returns ``(cache, out [B,
     C, D])``."""
-    bs = _block_size(cfg, cache["k"])
-    blk, off = jnp.where(valid, _block_at(block_tables, pos, bs), 0), pos % bs
+    bs = paged_kv.block_size(cache["k"], **_shapes(cfg))
+    blk, off = jnp.where(valid, paged_kv.block_at(block_tables, pos, bs), 0), pos % bs
     with jax.named_scope("attn.full"):
         q, k, v = _qkv(p, h)
-        cache = _scatter_kv(cache, index, blk, off, k, v)
-        o = _paged_attention(cfg, q, cache, index, block_tables, pos, valid.sum(axis=1, dtype=jnp.int32))
+        cache = paged_kv.scatter_kv(cache, index, blk, off, k, v)
+        o = paged_kv.attention_counted(
+            q, cache["k"], cache["v"], index, block_tables, pos, valid.sum(axis=1, dtype=jnp.int32),
+            **_shapes(cfg),
+        )
         return cache, jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"])
 
 
@@ -488,7 +457,7 @@ def paged_prefill_step(cfg: JambaConfig, params, cache, state, tokens, block_tab
         cfg, params, cache, state, tokens[None], (ctx_len + idx)[None], (idx < true_len)[None],
         block_table[None], jnp.reshape(slot, (1,)),
     )
-    return cache, state, _lm_head(cfg, params, x[0, jnp.maximum(true_len - 1, 0)])
+    return cache, state, lm_head(params, x[0, jnp.maximum(true_len - 1, 0)], cfg.norm_eps, tied=True)
 
 
 def paged_decode_step(cfg: JambaConfig, params, cache, state, tokens, positions, block_tables,
@@ -499,11 +468,11 @@ def paged_decode_step(cfg: JambaConfig, params, cache, state, tokens, positions,
     block is padding: it reads and writes the null slot)."""
     del ctx_lens
     pos = positions[:, None]
-    valid = _block_at(block_tables, pos, _block_size(cfg, cache["k"])) != 0
+    valid = paged_kv.block_at(block_tables, pos, paged_kv.block_size(cache["k"], **_shapes(cfg))) != 0
     cache, state, x = _paged_layers(
         cfg, params, cache, state, tokens[:, None], pos, valid, block_tables, slots
     )
-    return cache, state, _lm_head(cfg, params, x[:, 0])
+    return cache, state, lm_head(params, x[:, 0], cfg.norm_eps, tied=True)
 
 
 def paged_verify_step(cfg: JambaConfig, *args, **kwargs):
@@ -521,26 +490,25 @@ def paged_verify_step(cfg: JambaConfig, *args, **kwargs):
 # what the runtime knows of this module (models/interface.py)
 
 
-def _table_keys(cfg: JambaConfig, cache) -> int:
-    bs = _block_size(cfg, cache["k"])
-    return -(-cfg.max_seq_len // bs) * bs
+def _program_path(cfg: JambaConfig, window: int, cache, backend=None) -> tuple:
+    return paged_kv.program_path(
+        window, cache["k"], cfg.max_seq_len, (cfg.n_heads,), backend=backend, **_shapes(cfg)
+    )
 
 
 def _attention_path(cfg: JambaConfig, window: int, cache, backend=None) -> AttentionPath:
     """The mixers' paths of a program of that window, named together: the
     Mamba layers' (a chunk: ``ssm.scan``, one position a slot: ``ssm.update``,
     each ``.kernel`` where ``ops/selective_scan.py`` serves the pool) and the
-    attending layers'; what a launch reads of the paged cache is the latter's."""
+    attending layers' (``models/paged_kv.py::way``); what a launch reads of the
+    paged cache is the latter's."""
     (_, shape, dtype), _ = state_layout(cfg).arrays
     pool = jax.ShapeDtypeStruct((cfg.n_mamba_layers, 1, *shape), dtype)  # any number of slots
     ssm = "ssm.scan" if window > 1 else "ssm.update"
     if selective_scan.kernel_serves(pool, backend):
         ssm += ".kernel"
-    if _kernel_serves(cfg, window, cache["k"], backend):
-        return AttentionPath(f"{ssm}+kernel", "blocks")
-    if _flash_serves(cfg, window, cache["k"], _table_keys(cfg, cache), backend):
-        return AttentionPath(f"{ssm}+flash", "live")
-    return AttentionPath(f"{ssm}+gather", "table")
+    way, reads, _ = _program_path(cfg, window, cache, backend)
+    return AttentionPath(f"{ssm}+{way}", reads)
 
 
 MODEL = Model(
@@ -555,10 +523,6 @@ MODEL = Model(
     paged_decode_step=paged_decode_step,
     attention_path=_attention_path,
     held_experts=lambda cfg: None,
-    # the key tile of the chunk's flash kernel, 1 where the chunk is not its to serve
-    key_tile=lambda cfg, window, cache: (
-        latent_flash.tiles(window, _table_keys(cfg, cache))[1]
-        if _flash_serves(cfg, window, cache["k"], _table_keys(cfg, cache)) else 1
-    ),
+    key_tile=lambda cfg, window, cache: _program_path(cfg, window, cache)[2],
     state_layout=state_layout,
 )

@@ -61,10 +61,12 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import latent
+from ray_tpu.models import latent, paged_kv
 from ray_tpu.models.interface import AttentionPath, Model, StateLayout
+from ray_tpu.models.interface import lm_head, stack_aux, step_counters, step_outputs
 from ray_tpu.ops import kda, latent_flash, short_conv
-from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
+from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.moe import DENSE_AXES, MOE_AXES, gated_mlp, routed_ffn
 from ray_tpu.parallel.sharding import constrain
 
 F32 = jnp.float32
@@ -206,17 +208,13 @@ _AXES = {
     "w_kvb": (None, "heads", "head_dim"), "wo": ("heads", "head_dim", "embed"),
     "shared_gate": ("embed", "mlp"), "shared_up": ("embed", "mlp"), "shared_down": ("mlp", "embed"),
 }
-_DENSE_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
-# the held experts stay whole on each device, as in ``models/xing4.py``
-_MOE_AXES = {"w_gate": (None, "embed", "mlp"), "w_up": (None, "embed", "mlp"),
-             "w_down": (None, "mlp", "embed")}
 
 
 def logical_axes(cfg: KimiLinearConfig) -> Dict[str, Any]:
     """Pytree (same structure as params) of logical-axis-name tuples."""
     layers = []
     for kind, moe in _layers(cfg):
-        own = {**_AXES, **(_MOE_AXES if moe else _DENSE_AXES)}
+        own = {**_AXES, **(MOE_AXES if moe else DENSE_AXES)}
         layers.append({
             k: own.get(k, (None,) * len(shape)) for k, shape in _layer_shapes(cfg, kind, moe).items()
         })
@@ -310,12 +308,6 @@ def param_count(cfg: KimiLinearConfig) -> int:
 
 # ---------------------------------------------------------------------------
 # the pieces of a layer
-
-
-def rms_norm(x, weight, eps: float):
-    x32 = x.astype(F32)
-    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * inv).astype(x.dtype) * weight
 
 
 def _l2_norm(x):
@@ -529,32 +521,13 @@ def _mla_qkv(cfg: KimiLinearConfig, p, h):
 
 def _ffn(cfg: KimiLinearConfig, p, h, valid, moe: bool):
     """The FFN of one layer on normed activations ``h [B, C, D]``: ``(ffn(h),
-    aux)``, as ``models/xing4.py::_ffn``: a dense layer the gated SiLU MLP;
-    an expert layer the shared expert on every row + this process's part of
-    the routed experts (sigmoid scores, the choice with the bias, gates
-    normalised over the kept and scaled)."""
+    aux)``: a dense layer the gated SiLU MLP; an expert layer
+    ``ops/moe.py::routed_ffn`` with the shared expert."""
     if not moe:
         return gated_mlp(h, p["w_gate"], p["w_up"], p["w_down"]), {}
-    with jax.named_scope("moe.shared"):
-        shared = gated_mlp(h, p["shared_gate"], p["shared_up"], p["shared_down"])
-    experts = {k: p[k] for k in ("router", "router_bias", "w_gate", "w_up", "w_down")}
-    routed, aux = dropless_moe_ffn(
-        experts, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k, renormalize=True,
-        valid=None if valid is None else valid.reshape(-1),
-        scoring="sigmoid", scale=cfg.routed_scaling_factor,
-        held=None if cfg.n_held == cfg.n_routed_experts else cfg.held_experts,
+    return routed_ffn(
+        p, h, valid, top_k=cfg.moe_top_k, scale=cfg.routed_scaling_factor, held=cfg.held_experts, shared=True
     )
-    return shared + routed.reshape(h.shape), aux
-
-
-def _lm_head(cfg: KimiLinearConfig, params, x):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(F32)
-
-
-def _stack_aux(aux: List[Dict[str, Any]]) -> Dict[str, Any]:
-    """The expert layers' counters, stacked over the layers."""
-    return {k: jnp.stack([a[k] for a in aux]) for k in aux[0]} if aux else {}
 
 
 # ---------------------------------------------------------------------------
@@ -587,9 +560,9 @@ def forward(cfg: KimiLinearConfig, params, tokens, *, remat=False, mesh=None, ru
         x = x + y
         if a:
             aux.append(a)
-    logits = constrain(_lm_head(cfg, params, x), mesh, rules, ("act_batch", "act_seq", "act_vocab"))
+    logits = constrain(lm_head(params, x, cfg.norm_eps, tied=False), mesh, rules, ("act_batch", "act_seq", "act_vocab"))
     if return_aux:
-        return logits, (_stack_aux(aux)["aux_loss"].sum() if aux else jnp.zeros((), F32))
+        return logits, (stack_aux(aux)["aux_loss"].sum() if aux else jnp.zeros((), F32))
     return logits
 
 
@@ -736,13 +709,7 @@ def _paged_layers(cfg: KimiLinearConfig, params, cache, state, tokens, pos, vali
         if a:
             aux.append(a)
     cache = latent.write_blocks(cfg, cache, block_tables, pos[:, 0], jnp.stack(blocks))
-    return cache, state, x, _stack_aux(aux)
-
-
-def _step_outputs(cache, state, logits, aux):
-    if aux:
-        return cache, state, logits, {"load": aux["load"], "bias_changed": aux["bias_changed"]}
-    return cache, state, logits
+    return cache, state, x, stack_aux(aux)
 
 
 def paged_prefill_step(cfg: KimiLinearConfig, params, cache, state, tokens, block_table, ctx_len,
@@ -756,8 +723,8 @@ def paged_prefill_step(cfg: KimiLinearConfig, params, cache, state, tokens, bloc
         cfg, params, cache, state, tokens[None], (ctx_len + idx)[None], (idx < true_len)[None],
         block_table[None], jnp.reshape(slot, (1,)),
     )
-    logits = _lm_head(cfg, params, x[0, jnp.maximum(true_len - 1, 0)])
-    return _step_outputs(cache, state, logits, aux)
+    logits = lm_head(params, x[0, jnp.maximum(true_len - 1, 0)], cfg.norm_eps, tied=False)
+    return step_outputs(cache, logits, step_counters(aux), state)
 
 
 def paged_decode_step(cfg: KimiLinearConfig, params, cache, state, tokens, positions, block_tables,
@@ -768,11 +735,11 @@ def paged_decode_step(cfg: KimiLinearConfig, params, cache, state, tokens, posit
     block is padding: it reads and writes the null slot)."""
     del ctx_lens
     pos = positions[:, None]
-    valid = latent.block_at(block_tables, pos, latent.block_size_of(cfg, cache)) != 0
+    valid = paged_kv.block_at(block_tables, pos, latent.block_size_of(cfg, cache)) != 0
     cache, state, x, aux = _paged_layers(
         cfg, params, cache, state, tokens[:, None], pos, valid, block_tables, slots
     )
-    return _step_outputs(cache, state, _lm_head(cfg, params, x[:, 0]), aux)
+    return step_outputs(cache, lm_head(params, x[:, 0], cfg.norm_eps, tied=False), step_counters(aux), state)
 
 
 def paged_verify_step(cfg: KimiLinearConfig, *args, **kwargs):

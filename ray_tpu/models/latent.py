@@ -260,13 +260,6 @@ def cache_layout(cfg, block_size: int, dtype=None, n_layers=None) -> CacheLayout
     )
 
 
-def block_at(block_tables, pos, bs: int):
-    """Id of the block that holds position ``pos[b, c]`` of slot ``b`` (a
-    position past the table reads its last column)."""
-    M = block_tables.shape[1]
-    return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
-
-
 def blocks_of_window(cfg, cache, window: int) -> int:
     """Blocks a window of ``window`` CONTIGUOUS positions can touch."""
     bs = block_size_of(cfg, cache)

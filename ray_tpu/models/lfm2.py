@@ -27,8 +27,8 @@ A layer: ``x + mix(norm(x))``, ``x + ffn(norm(x))``. The mixers::
 The convolution is ``ops/short_conv.py``'s (a prefill chunk with the tail
 carried in from the sequence's slot and cut behind the chunk's last REAL
 input; a decode batch one step in place in the layer's slab of the pool). The
-attention reads the paged cache three ways, chosen at trace time from shapes
-and backend as in ``models/llama.py``, whose write and fallback it shares: a
+attention reads the paged cache through ``models/paged_kv.py``, the write and
+the three ways chosen at trace time from shapes and backend, as every K/V model: a
 decode step on a TPU the Pallas kernel ``ops/paged_attention.py`` (each slot's
 live blocks; narrow heads ride in lanes), a prefill chunk on a TPU the flash
 kernel ``ops/latent_flash.py`` over K and V gathered through the table
@@ -46,11 +46,12 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import paged_kv
 from ray_tpu.models.interface import AttentionPath, CacheLayout, Model, StateLayout
-from ray_tpu.models.llama import _attend_gathered, _block_at, _scatter_kv, rms_norm
-from ray_tpu.ops import latent_flash, short_conv
-from ray_tpu.ops import paged_attention as paged_attn
-from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
+from ray_tpu.models.interface import lm_head, stack_aux, step_counters, step_outputs
+from ray_tpu.ops import short_conv
+from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.moe import DENSE_AXES, MOE_AXES, gated_mlp, routed_ffn
 from ray_tpu.parallel.sharding import constrain
 
 F32 = jnp.float32
@@ -160,17 +161,13 @@ _AXES = {
     "wq": ("embed", "heads", "head_dim"), "wk": ("embed", "kv_heads", "head_dim"),
     "wv": ("embed", "kv_heads", "head_dim"), "wo": ("heads", "head_dim", "embed"),
 }
-_DENSE_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
-# the held experts stay whole on each device, as in ``models/xing4.py``
-_MOE_AXES = {"w_gate": (None, "embed", "mlp"), "w_up": (None, "embed", "mlp"),
-             "w_down": (None, "mlp", "embed")}
 
 
 def logical_axes(cfg: Lfm2Config) -> Dict[str, Any]:
     """Pytree (same structure as params) of logical-axis-name tuples."""
     layers = []
     for kind, moe in _layers(cfg):
-        own = {**_AXES, **(_MOE_AXES if moe else _DENSE_AXES)}
+        own = {**_AXES, **(MOE_AXES if moe else DENSE_AXES)}
         layers.append({
             k: own.get(k, (None,) * len(shape)) for k, shape in _layer_shapes(cfg, kind, moe).items()
         })
@@ -308,29 +305,13 @@ def _conv_step(p, h, pool, layer: int, slots, fresh):
 
 def _ffn(cfg: Lfm2Config, p, h, valid, moe: bool):
     """The FFN of one layer on normed activations ``h [B, C, D]``: ``(ffn(h),
-    aux)``: a dense layer the gated SiLU MLP; an expert layer this process's
-    part of the routed experts (sigmoid scores, the choice with the bias,
-    gates normalised over the kept and scaled; no shared expert)."""
+    aux)``: a dense layer the gated SiLU MLP; an expert layer
+    ``ops/moe.py::routed_ffn``, no shared expert."""
     if not moe:
         return gated_mlp(h, p["w_gate"], p["w_up"], p["w_down"]), {}
-    routed, aux = dropless_moe_ffn(
-        p, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k, renormalize=True,
-        valid=None if valid is None else valid.reshape(-1),
-        scoring="sigmoid", scale=cfg.routed_scaling_factor,
-        held=None if cfg.n_held == cfg.n_routed_experts else cfg.held_experts,
+    return routed_ffn(
+        p, h, valid, top_k=cfg.moe_top_k, scale=cfg.routed_scaling_factor, held=cfg.held_experts, shared=False
     )
-    return routed.reshape(h.shape), aux
-
-
-def _lm_head(cfg: Lfm2Config, params, x):
-    """The tied head: the final norm, then the embedding read again."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(F32)
-
-
-def _stack_aux(aux: List[Dict[str, Any]]) -> Dict[str, Any]:
-    """The expert layers' counters, stacked over the layers."""
-    return {k: jnp.stack([a[k] for a in aux]) for k in aux[0]} if aux else {}
 
 
 # ---------------------------------------------------------------------------
@@ -364,9 +345,9 @@ def forward(cfg: Lfm2Config, params, tokens, *, remat=False, mesh=None, rules=No
         x = x + y
         if a:
             aux.append(a)
-    logits = constrain(_lm_head(cfg, params, x), mesh, rules, ("act_batch", "act_seq", "act_vocab"))
+    logits = constrain(lm_head(params, x, cfg.norm_eps, tied=True), mesh, rules, ("act_batch", "act_seq", "act_vocab"))
     if return_aux:
-        return logits, (_stack_aux(aux)["aux_loss"].sum() if aux else jnp.zeros((), F32))
+        return logits, (stack_aux(aux)["aux_loss"].sum() if aux else jnp.zeros((), F32))
     return logits
 
 
@@ -402,45 +383,9 @@ def state_layout(cfg: Lfm2Config) -> StateLayout:
     )
 
 
-def _block_size(k_cache) -> int:
-    """Positions a block of ``k_cache``, ``[L, N, bs, n_kv, hd]`` or ``[L, N, bs, n_kv x hd]``."""
-    return k_cache.shape[2]
-
-
-def _kernel_serves(cfg: Lfm2Config, window: int, k_cache, backend=None) -> bool:
-    return paged_attn.kernel_serves(
-        window, cfg.n_heads, k_cache, backend, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim
-    )
-
-
-def _flash_serves(cfg: Lfm2Config, window: int, k_cache, table_keys: int, backend=None) -> bool:
-    return latent_flash.kernel_serves(
-        window, table_keys, cfg.head_dim, cfg.head_dim, 0, k_cache.dtype, backend,
-        kv_heads=cfg.n_kv_heads,
-    )
-
-
-def _paged_attention(cfg: Lfm2Config, q, cache, layer: int, block_tables, pos, true_lens):
-    """Causal attention of ``q [B, C, H, hd]`` (normalised, rotated) over the
-    cached context of its slot through ``block_tables [B, M]``; the step's own
-    K and V are in the cache already. The ONE place a serving step reads the
-    cache for attention, three ways (the module's docstring)."""
-    B, C = pos.shape
-    k_cache, v_cache = cache["k"], cache["v"]
-    n_kv, hd = cfg.n_kv_heads, cfg.head_dim
-    keys = block_tables.shape[1] * _block_size(k_cache)
-    if _kernel_serves(cfg, C, k_cache):
-        return paged_attn.paged_attention(q, k_cache, v_cache, layer, block_tables, pos, n_kv=n_kv)
-    if B == 1 and _flash_serves(cfg, C, k_cache, keys):
-        with jax.named_scope("attn.gather"):
-            ks = k_cache[layer, block_tables[0]].reshape(keys, n_kv, hd).transpose(1, 0, 2)
-            vs = v_cache[layer, block_tables[0]].reshape(keys, n_kv, hd).transpose(1, 0, 2)
-        o = latent_flash.flash_attention(
-            q[0].transpose(1, 0, 2), ks, vs, pos[0, 0], true_lens[0],
-            scale=1.0 / math.sqrt(hd), group=cfg.n_heads // n_kv,
-        )
-        return o.transpose(1, 0, 2)[None]
-    return _attend_gathered(q, k_cache, v_cache, layer, block_tables, pos, n_kv, keys)
+def _shapes(cfg: Lfm2Config) -> Dict[str, int]:
+    """What ``models/paged_kv.py`` is told beside the cache's shape."""
+    return {"n_kv": cfg.n_kv_heads, "head_dim": cfg.head_dim}
 
 
 def _attention_mix(cfg: Lfm2Config, p, cache, index: int, h, pos, valid, block_tables):
@@ -451,14 +396,17 @@ def _attention_mix(cfg: Lfm2Config, p, cache, index: int, h, pos, valid, block_t
     cache (after the write: a window attends to itself) and ``wo``. Returns
     ``(cache, out [B, C, D])``."""
     B, C = pos.shape
-    bs = _block_size(cache["k"])
-    blk, off = jnp.where(valid, _block_at(block_tables, pos, bs), 0), pos % bs
+    bs = paged_kv.block_size(cache["k"], **_shapes(cfg))
+    blk, off = jnp.where(valid, paged_kv.block_at(block_tables, pos, bs), 0), pos % bs
     with jax.named_scope("attn.full"):
         q, k, v = _qkv(cfg, p, h, pos)
         if cache["k"].ndim == 4:  # a token's heads in one row
             k, v = k.reshape(B, C, 1, -1), v.reshape(B, C, 1, -1)
-        cache = _scatter_kv(cache, index, blk, off, k, v)
-        o = _paged_attention(cfg, q, cache, index, block_tables, pos, valid.sum(axis=1, dtype=jnp.int32))
+        cache = paged_kv.scatter_kv(cache, index, blk, off, k, v)
+        o = paged_kv.attention_counted(
+            q, cache["k"], cache["v"], index, block_tables, pos, valid.sum(axis=1, dtype=jnp.int32),
+            **_shapes(cfg),
+        )
         return cache, jnp.einsum("bchk,hkd->bcd", o.astype(h.dtype), p["wo"])
 
 
@@ -504,13 +452,7 @@ def _paged_layers(cfg: Lfm2Config, params, cache, state, tokens, pos, valid, blo
         x = x + y
         if a:
             aux.append(a)
-    return cache, {"conv_tail": pool}, x, _stack_aux(aux)
-
-
-def _step_outputs(cache, state, logits, aux):
-    if aux:
-        return cache, state, logits, {"load": aux["load"], "bias_changed": aux["bias_changed"]}
-    return cache, state, logits
+    return cache, {"conv_tail": pool}, x, stack_aux(aux)
 
 
 def paged_prefill_step(cfg: Lfm2Config, params, cache, state, tokens, block_table, ctx_len,
@@ -524,8 +466,8 @@ def paged_prefill_step(cfg: Lfm2Config, params, cache, state, tokens, block_tabl
         cfg, params, cache, state, tokens[None], (ctx_len + idx)[None], (idx < true_len)[None],
         block_table[None], jnp.reshape(slot, (1,)),
     )
-    logits = _lm_head(cfg, params, x[0, jnp.maximum(true_len - 1, 0)])
-    return _step_outputs(cache, state, logits, aux)
+    logits = lm_head(params, x[0, jnp.maximum(true_len - 1, 0)], cfg.norm_eps, tied=True)
+    return step_outputs(cache, logits, step_counters(aux), state)
 
 
 def paged_decode_step(cfg: Lfm2Config, params, cache, state, tokens, positions, block_tables,
@@ -536,11 +478,11 @@ def paged_decode_step(cfg: Lfm2Config, params, cache, state, tokens, positions, 
     block is padding: it reads and writes the null slot)."""
     del ctx_lens
     pos = positions[:, None]
-    valid = _block_at(block_tables, pos, _block_size(cache["k"])) != 0
+    valid = paged_kv.block_at(block_tables, pos, paged_kv.block_size(cache["k"], **_shapes(cfg))) != 0
     cache, state, x, aux = _paged_layers(
         cfg, params, cache, state, tokens[:, None], pos, valid, block_tables, slots
     )
-    return _step_outputs(cache, state, _lm_head(cfg, params, x[:, 0]), aux)
+    return step_outputs(cache, lm_head(params, x[:, 0], cfg.norm_eps, tied=True), step_counters(aux), state)
 
 
 def paged_verify_step(cfg: Lfm2Config, *args, **kwargs):
@@ -558,22 +500,20 @@ def paged_verify_step(cfg: Lfm2Config, *args, **kwargs):
 # what the runtime knows of this module (models/interface.py)
 
 
-def _table_keys(cfg: Lfm2Config, cache) -> int:
-    bs = _block_size(cache["k"])
-    return -(-cfg.max_seq_len // bs) * bs
+def _program_path(cfg: Lfm2Config, window: int, cache, backend=None) -> tuple:
+    return paged_kv.program_path(
+        window, cache["k"], cfg.max_seq_len, (cfg.n_heads,), backend=backend, **_shapes(cfg)
+    )
 
 
 def _attention_path(cfg: Lfm2Config, window: int, cache, backend=None) -> AttentionPath:
     """The mixers' paths of a program of that window, named together: the
     convolution layers' (``conv.step`` one position a slot, ``conv.chunk``)
-    and the attending layers'; what a launch reads of the paged cache is the
-    latter's."""
+    and the attending layers' (``models/paged_kv.py::way``); what a launch
+    reads of the paged cache is the latter's."""
     conv = "conv.chunk" if window > 1 else "conv.step"
-    if _kernel_serves(cfg, window, cache["k"], backend):
-        return AttentionPath(f"{conv}+kernel", "blocks")
-    if _flash_serves(cfg, window, cache["k"], _table_keys(cfg, cache), backend):
-        return AttentionPath(f"{conv}+flash", "live")
-    return AttentionPath(f"{conv}+gather", "table")
+    way, reads, _ = _program_path(cfg, window, cache, backend)
+    return AttentionPath(f"{conv}+{way}", reads)
 
 
 MODEL = Model(
@@ -588,10 +528,6 @@ MODEL = Model(
     paged_decode_step=paged_decode_step,
     attention_path=_attention_path,
     held_experts=lambda cfg: cfg.held_experts if cfg.n_moe_layers > 0 else None,
-    # the key tile of the chunk's flash kernel, 1 where the chunk is not its to serve
-    key_tile=lambda cfg, window, cache: (
-        latent_flash.tiles(window, _table_keys(cfg, cache))[1]
-        if _flash_serves(cfg, window, cache["k"], _table_keys(cfg, cache)) else 1
-    ),
+    key_tile=lambda cfg, window, cache: _program_path(cfg, window, cache)[2],
     state_layout=state_layout,
 )

@@ -26,18 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.models.interface import (  # noqa: F401 - the block functions are this module's too
-    AttentionPath,
-    CacheLayout,
-    LayerGroup,
-    Model,
-    copy_paged_blocks,
-    gather_paged_blocks,
-    scatter_paged_blocks,
-)
-from ray_tpu.ops import latent_flash
-from ray_tpu.ops import paged_attention as paged_attn
+from ray_tpu.models import paged_kv
+from ray_tpu.models.interface import AttentionPath, CacheLayout, LayerGroup, Model, lm_head, step_outputs
 from ray_tpu.ops.attention import flash_attention, flash_attention_sharded
+from ray_tpu.ops.layers import rms_norm
 from ray_tpu.parallel.sharding import constrain
 
 
@@ -406,12 +398,6 @@ def param_count(cfg: LlamaConfig) -> int:
 
 # ---------------------------------------------------------------------------
 # forward
-
-
-def rms_norm(x, weight, eps: float):
-    x32 = x.astype(jnp.float32)
-    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * inv).astype(x.dtype) * weight
 
 
 def _inv_freq(cfg: LlamaConfig, kind: Union[int, LayerKind] = 0):
@@ -898,28 +884,19 @@ def _apply_rope_flat(x, cos, sin):
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _block_size(cfg: LlamaConfig, k_cache) -> int:
-    """Positions a block of ``k_cache``: ``[L, N, bs, n_kv, hd]``, or stored
-    flat, ``[L, N, bs * n_kv, hd]`` (``CacheLayout.flat_blocks``)."""
-    return k_cache.shape[2] if k_cache.ndim == 5 else k_cache.shape[2] // cfg.n_kv_heads
+#: ``perfbench/families/mellum/server.py`` reads this name (ROADMAP D20: an
+#: alias kept for the benchmark; the function is ``models/paged_kv.py``'s)
+_block_at = paged_kv.block_at
 
 
-def _scatter_kv(cache, layer: int, blk, off, k, v, names=("k", "v")):
-    """Write per-token K/V into their cache slots. blk/off: [...] int32,
-    k/v: [..., n_kv, hd]. Padding rows target the null block — colliding
-    trash writes are fine, nothing masked-in ever reads them. ``names``: the
-    layer's group's arrays. A cache stored flat takes a token's heads at the
-    rows ``off * n_kv ..`` of its block."""
-    k_name, v_name = names
-    if cache[k_name].ndim == 4:
-        n_kv = k.shape[-2]
-        blk = blk[..., None]
-        off = off[..., None] * n_kv + jnp.arange(n_kv, dtype=off.dtype)
-    return {
-        **cache,
-        k_name: cache[k_name].at[layer, blk, off].set(k),
-        v_name: cache[v_name].at[layer, blk, off].set(v),
-    }
+def _block_size(cfg: LlamaConfig, cache) -> int:
+    return paged_kv.block_size(cache["k"], cfg.n_kv_heads, cfg.head_dim)
+
+
+def _stacked(loads: list):
+    """A MoE configuration's expert loads ``[n_layers, E]`` int32 of a step's
+    valid rows, the third output of its paged steps; None where no layer routes."""
+    return jnp.stack(loads) if loads else None
 
 
 def _ffn_residual(cfg: LlamaConfig, p, x, valid, loads: list):
@@ -929,169 +906,6 @@ def _ffn_residual(cfg: LlamaConfig, p, x, valid, loads: list):
     if aux is not None:
         loads.append(aux["load"])
     return x + out
-
-
-def _step_outputs(cache, logits, loads: list):
-    """What a paged step returns: ``(cache, logits)``, and for a MoE
-    config a third output, the expert loads ``[n_layers, E]`` int32 of the
-    step's valid rows (the runner reads them with the logits)."""
-    if loads:
-        return cache, logits, jnp.stack(loads)
-    return cache, logits
-
-
-def _block_at(block_tables, pos, bs: int):
-    """Id of the block that holds position ``pos[b, c]`` of slot ``b``:
-    ``block_tables [B, M]``, ``pos [B, C]`` -> ``[B, C]`` (a position past
-    the table reads its last column)."""
-    M = block_tables.shape[1]
-    return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
-
-
-def _kernel_serves(cfg: LlamaConfig, window: int, k_cache, heads: int = 0) -> bool:
-    """``ops/paged_attention.py::kernel_serves`` for this model's cache (a
-    cache stored flat does not say its KV heads by its shape) under ``heads``
-    query heads; 0: under every kind's, what a program of the whole model asks."""
-    if not heads:
-        return all(_kernel_serves(cfg, window, k_cache, kind.n_heads) for kind in cfg.kinds)
-    if k_cache.ndim == 5:  # the call a plain configuration always made
-        return paged_attn.kernel_serves(window, heads, k_cache)
-    return paged_attn.kernel_serves(window, heads, k_cache, n_kv=cfg.n_kv_heads)
-
-
-def _chunk_keys(cfg: LlamaConfig, window: int, chunk: int, table_keys: int, bs: int) -> int:
-    """Key positions a prefill chunk of ``chunk`` queries is handed in a
-    layer of that ``window``: the table's width, or for a window layer the
-    window, the chunk and a block's slack (the keys start on a block), in whole
-    key tiles."""
-    if not window:
-        return table_keys
-    tile = latent_flash.tiles(chunk, table_keys)[1]
-    return min(table_keys, -(-(window + chunk + bs) // tile) * tile)
-
-
-def _flash_serves(cfg: LlamaConfig, k_cache, B: int, C: int, table_keys: int, window: int) -> bool:
-    """Whether a chunk's attention runs the flash kernel (``ops/latent_flash.py``)
-    over K and V gathered through the table. SHAPES decide, and the backend:
-    ONE sequence (``B == 1``: a prefill chunk) and what
-    ``latent_flash.kernel_serves`` asks, a TPU, bf16 / float32, the chunk and
-    the keys in whole tiles, ``head_dim`` of whole lanes. Nothing of the
-    configuration's name, ``model_type`` or layer kinds: a plain GQA
-    configuration (Mistral, OLMoE) takes the lines a full layer of Mellum2
-    takes.
-
-    No shape that passes is kept back. A layer's call alone on a v5e, 4096
-    table keys, ms at a context of 0 / 1024 / 2048 / 3072, materialised ->
-    gather + kernel (PERF.md, PR 51): 32 heads over 8 of 128, 1024 queries
-    1.23 -> 0.22 / 0.34 / 0.45 / 0.56, 256 queries 0.33 -> 0.10 / 0.14 /
-    0.17 / 0.21; 16 over 16, 1024 queries 0.66 -> 0.18 / 0.24 / 0.29 / 0.35,
-    256 queries 0.160 -> 0.123 / 0.141 / 0.159 / 0.177: the one point that
-    loses (by a tenth, past half the table; 0.08 ms of it the gather of 16 KV
-    heads at the table's width, which the materialised way fuses) is a
-    CONTEXT, a traced scalar, not a shape, and over the table the shape gains.
-
-    ONE KV head under 20 query heads (``models/jamba.py`` asks the same
-    predicate of the same kernel, ``group`` 20: every query head reads key head
-    0's tiles) was compiled and RUN against the materialised way on a v5e, 8192
-    table keys (PERF.md, PR 52): 1024 queries 1.69 -> 0.26 ms at a context of 0
-    and 1.68 -> 0.32 at 2048; 256 queries 0.39 -> 0.22 and 0.40 -> 0.22;
-    max|diff| / max|ref| 0.005-0.010 in bf16.
-
-    SIX and EIGHT query heads a KV head in one program (48 heads over every
-    key, 64 under a window of 512 narrower than the chunk: 2048 keys handed, in
-    whole tiles, of a table of 8192) were compiled and RUN against the
-    materialised way on a v5e (PERF.md, PR 56), ms at a context of 0 / 1024 /
-    3072: 48 heads, 1024 queries 3.70 -> 0.46 / 0.63 / 0.98, 256 queries 1.00
-    -> 0.29 / 0.31 / 0.43; 64 heads under the window, 1024 queries 5.11 -> 0.50
-    / 0.74 / 0.74, 256 queries 1.28 -> 0.29 / 0.30 / 0.29; max|diff| / max|ref|
-    0.005-0.020 in bf16."""
-    if B != 1:
-        return False
-    keys = _chunk_keys(cfg, window, C, table_keys, _block_size(cfg, k_cache))
-    return latent_flash.kernel_serves(C, keys, cfg.head_dim, cfg.head_dim, 0, k_cache.dtype)
-
-
-def _paged_attention(
-    cfg: LlamaConfig, q, cache, layer: int, block_tables, pos, valid=None,
-    names=("k", "v"), window: int = 0,
-):
-    """Causal attention of ``q [B, C, H, hd]`` (rope applied) over the
-    cached context of its slot through ``block_tables [B, M]``, so K/V of
-    the step's own tokens must be in the cache already. Query ``(b, c)`` at
-    global position ``pos[b, c]`` sees key position ``j`` of its slot iff
-    ``j <= pos[b, c]`` and, in a layer that keeps a ``window``, ``j > pos[b,
-    c] - window``. Returns ``[B, C, H, hd]``. GQA stays grouped ``[n_kv,
-    rep]``; scores, mask and softmax are float32. ``names``: the arrays of the
-    layer's group in ``cache``, ``layer`` its index among them.
-
-    The ONE place a serving step reads the cache for attention, three ways,
-    chosen at trace time from the shapes: a short window (decode, verify) on
-    a TPU runs the Pallas kernel (``ops/paged_attention.py::kernel_serves``),
-    which reads each slot's own live blocks out of the whole cache, from a
-    window's first on, and gathers nothing; a prefill chunk on a TPU, in
-    whole tiles and at head widths the kernel takes (:func:`_flash_serves`),
-    gathers K and V through the table (a full layer: as wide as the table; a
-    window layer: from the block that holds the chunk's first visible key,
-    ``window + chunk`` positions) and runs the flash kernel over them
-    (``ops/latent_flash.py``: grouped heads, key tiles past the diagonal or
-    wholly behind the window never fetched or multiplied), so no score
-    matrix is computed past the live context or written; every other shape
-    (a verify window too wide for the decode kernel, a chunk that is no whole
-    tile, odd head widths), and everything off the chip, gathers
-    ``cache[layer, block_tables]`` for every slot as wide as the table and
-    materialises the softmax."""
-    B, C = pos.shape
-    k_cache, v_cache = cache[names[0]], cache[names[1]]
-    n_kv, hd = cfg.n_kv_heads, cfg.head_dim
-    if _kernel_serves(cfg, C, k_cache, q.shape[2]):
-        said = {}  # what a plain configuration's call never says
-        if k_cache.ndim == 4:
-            said["n_kv"] = n_kv
-        if window:
-            said["keeps"] = window
-        return paged_attn.paged_attention(q, k_cache, v_cache, layer, block_tables, pos, **said)
-    M = block_tables.shape[1]
-    bs = _block_size(cfg, k_cache)
-    rep = q.shape[2] // n_kv
-    if _flash_serves(cfg, k_cache, B, C, M * bs, window):
-        keys = _chunk_keys(cfg, window, C, M * bs, bs)
-        ctx_len = pos[0, 0]
-        table, first = block_tables[0], 0
-        if window:
-            # from the block that holds the first key the chunk's first query
-            # sees: the chunk's offset in what it is handed is its context
-            first = jnp.maximum(ctx_len - window + 1, 0) // bs
-            table = jax.lax.dynamic_slice(jnp.pad(table, (0, keys // bs)), (first,), (keys // bs,))
-        with jax.named_scope("attn.gather"):
-            ks = k_cache[layer, table].reshape(keys, n_kv, hd).transpose(1, 0, 2)
-            vs = v_cache[layer, table].reshape(keys, n_kv, hd).transpose(1, 0, 2)
-        o = latent_flash.flash_attention(
-            q[0].transpose(1, 0, 2), ks, vs, ctx_len - first * bs, valid[0].sum(dtype=jnp.int32),
-            scale=1.0 / math.sqrt(hd), group=rep, window=window or None,
-        )
-        return o.transpose(1, 0, 2)[None]
-    return _attend_gathered(q, k_cache, v_cache, layer, block_tables, pos, n_kv, M * bs, window)
-
-
-def _attend_gathered(q, k_cache, v_cache, layer: int, block_tables, pos, n_kv: int, keys: int, window: int = 0):
-    """The fallback of :func:`_paged_attention` (and of ``models/lfm2.py``'s):
-    ``cache[layer, block_tables]`` gathered for every slot as wide as the
-    table (``keys`` positions of ``n_kv`` heads, whatever form a block is
-    stored in) and the softmax materialised, GQA grouped, float32."""
-    B, C, H, hd = q.shape
-    ks = k_cache[layer, block_tables].reshape(B, keys, n_kv, -1)
-    vs = v_cache[layer, block_tables].reshape(B, keys, n_kv, -1)
-    qg = q.reshape(B, C, n_kv, H // n_kv, -1)
-    s = jnp.einsum("bcgrh,bsgh->bcgrs", qg, ks).astype(jnp.float32)
-    s = s * (1.0 / math.sqrt(hd))
-    key_pos = jnp.arange(keys, dtype=jnp.int32)
-    mask = key_pos <= pos[:, :, None]  # [B, C, keys]
-    if window:
-        mask &= key_pos > pos[:, :, None] - window
-    s = jnp.where(mask[:, :, None, None, :], s, -1e30)
-    pattn = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bcgrs,bsgh->bcgrh", pattn.astype(vs.dtype), vs)
-    return o.reshape(B, C, H, -1)
 
 
 def _group_table(block_tables, group: int):
@@ -1108,7 +922,7 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
     null block and reach no expert), ``block_tables`` one table a layer group
     of the cache (:func:`cache_layout`, :func:`_group_table`). Per layer:
     norm, q/k/v, rope at ``pos``, K/V written to the cache, attention over
-    the cache (:func:`_paged_attention`, after the write so a window
+    the cache (``models/paged_kv.py::attention``, after the write so a window
     attends to itself), ``wo`` (:func:`_paged_attention_block`), the FFN.
     Returns ``(cache, x, loads)``, ``loads`` a list of a MoE block's expert
     loads a layer.
@@ -1124,10 +938,10 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
     construction: every read masks on ``key_pos <= pos``, so nothing past
     the querying token is ever read, and the next write to a position
     overwrites it in place."""
-    layout = cache_layout(cfg, _block_size(cfg, cache["k"]))
+    layout = cache_layout(cfg, _block_size(cfg, cache))
     bs = layout.block_size
     tables = [_group_table(block_tables, g) for g in range(len(layout.groups))]
-    blks = [jnp.where(valid, _block_at(table, pos, bs), 0) for table in tables]
+    blks = [jnp.where(valid, paged_kv.block_at(table, pos, bs), 0) for table in tables]
     off = pos % bs
     # a kind's rope table, in the groups' order (kinds may share a group: its window is what a group is)
     ropes = {
@@ -1165,17 +979,14 @@ def _paged_attention_block(
         q, k, v = _qkv(cfg, p, h)
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
-        cache = _scatter_kv(cache, index, blk, off, k, v, names)
-        o = _paged_attention(cfg, q, cache, index, block_table, pos, valid, names, window)
+        cache = paged_kv.scatter_kv(cache, index, blk, off, k, v, names)
+        o = paged_kv.attention(
+            q, cache[names[0]], cache[names[1]], index, block_table, pos, valid,
+            n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim, keeps=window,
+        )
         if cfg.attn_gate:  # on the kernel's output: no reason to leave the kernel
             o = _head_gate(p, h, o)
         return cache, x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
-
-
-def _lm_head(cfg: LlamaConfig, params, x):
-    """``x [..., D]`` -> float32 logits ``[..., vocab]`` through the final norm."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(jnp.float32)
 
 
 def paged_prefill_step(
@@ -1189,7 +1000,7 @@ def paged_prefill_step(
     prefill: >0 from the second chunk on), true_len: scalar int32 valid
     tokens in this chunk (``valid = idx < true_len``). Head: the chunk's
     last valid row only. Returns ``(cache, logits [vocab])``, and a MoE
-    config's expert loads (``_step_outputs``).
+    config's expert loads (``interface.step_outputs``).
     """
     idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
     cache, x, loads = _paged_layers(
@@ -1197,8 +1008,8 @@ def paged_prefill_step(
         (ctx_len + idx)[None], (idx < true_len)[None],
         jnp.expand_dims(block_table, -2),  # [M] -> [1, M]; a table a group: [G, M] -> [G, 1, M]
     )
-    logits = _lm_head(cfg, params, x[0, jnp.maximum(true_len - 1, 0)])
-    return _step_outputs(cache, logits, loads)
+    logits = lm_head(params, x[0, jnp.maximum(true_len - 1, 0)], cfg.norm_eps, tied=False)
+    return step_outputs(cache, logits, _stacked(loads))
 
 
 def paged_verify_step(
@@ -1213,14 +1024,14 @@ def paged_verify_step(
     lengths (``valid = idx < true_len``; 0 for a padding slot). Head:
     EVERY row, so the host accepts or rejects each drafted token by
     itself. Returns ``(cache, logits [B, C, vocab])``, and a MoE config's
-    expert loads (``_step_outputs``).
+    expert loads (``interface.step_outputs``).
     """
     idx = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     cache, x, loads = _paged_layers(
         cfg, params, cache, params["embed"][tokens],
         ctx_lens[:, None] + idx, idx < true_lens[:, None], block_tables,
     )
-    return _step_outputs(cache, _lm_head(cfg, params, x), loads)
+    return step_outputs(cache, lm_head(params, x, cfg.norm_eps, tied=False), _stacked(loads))
 
 
 def paged_decode_step(
@@ -1235,16 +1046,16 @@ def paged_decode_step(
     would be written to the null block is padding: ``valid`` is taken
     from the block table. Head: the one row a slot. Returns
     ``(cache, logits [B, vocab])``, and a MoE config's expert loads
-    (``_step_outputs``).
+    (``interface.step_outputs``).
     """
     del ctx_lens
     pos = positions[:, None]
     whole = _group_table(block_tables, 0)  # the group that keeps all
-    valid = _block_at(whole, pos, _block_size(cfg, cache["k"])) != 0
+    valid = paged_kv.block_at(whole, pos, _block_size(cfg, cache)) != 0
     cache, x, loads = _paged_layers(
         cfg, params, cache, params["embed"][tokens][:, None], pos, valid, block_tables
     )
-    return _step_outputs(cache, _lm_head(cfg, params, x[:, 0]), loads)
+    return step_outputs(cache, lm_head(params, x[:, 0], cfg.norm_eps, tied=False), _stacked(loads))
 
 
 def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = True,
@@ -1318,29 +1129,28 @@ def make_train_step(cfg: LlamaConfig, optimizer, *, remat=False, donate: bool = 
 # what the runtime knows of this module (models/interface.py)
 
 
+def _program_path(cfg: LlamaConfig, window: int, cache) -> tuple:
+    return paged_kv.program_path(
+        window, cache["k"], cfg.max_seq_len, {kind.n_heads for kind in cfg.kinds},
+        n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+    )
+
+
 def _attention_path(cfg: LlamaConfig, window: int, cache) -> AttentionPath:
     """The path of the programs of that query window, from the shapes and
-    the backend as :func:`_paged_attention` chooses it: ``kernel`` (decode,
-    verify; reads ``blocks``), ``flash`` (a prefill chunk in whole tiles;
-    reads the ``live`` context in whole key tiles), ``gather`` (everything
-    else; reads the ``table``). A configuration with layer kinds runs the
-    same path in both kinds of layer and says so (``kernel+window``: each
-    group's kernel reads the slot's live blocks of that group, a window's
+    the backend as ``models/paged_kv.py::attention`` chooses it: ``kernel``
+    (decode, verify; reads ``blocks``), ``flash`` (a prefill chunk in whole
+    tiles; reads the ``live`` context in whole key tiles), ``gather``
+    (everything else; reads the ``table``). A configuration with layer kinds
+    runs the same path in both kinds of layer and says so (``kernel+window``:
+    each group's kernel reads the slot's live blocks of that group, a window's
     from its first live one on), and the query heads of each kind where they
     differ (``kernel+window[full:48h,window:64h]``)."""
     kinds = "+window" if cfg.layer_windows else ""
     if len({kind.n_heads for kind in cfg.kinds}) > 1:  # the heads a kind, as window or full
         kinds += "[" + ",".join(f"{'window' if k.window else 'full'}:{k.n_heads}h" for k in cfg.kinds) + "]"
-    if _kernel_serves(cfg, window, cache["k"]):
-        return AttentionPath(f"kernel{kinds}", "blocks")
-    if _flash_serves(cfg, cache["k"], 1, window, _table_keys(cfg, cache), 0):
-        return AttentionPath(f"flash{kinds}", "live")
-    return AttentionPath(f"gather{kinds}", "table")
-
-
-def _table_keys(cfg: LlamaConfig, cache) -> int:
-    bs = _block_size(cfg, cache["k"])
-    return -(-cfg.max_seq_len // bs) * bs
+    way, reads, _ = _program_path(cfg, window, cache)
+    return AttentionPath(f"{way}{kinds}", reads)
 
 
 MODEL = Model(
@@ -1355,9 +1165,5 @@ MODEL = Model(
     paged_decode_step=paged_decode_step,
     attention_path=_attention_path,
     held_experts=lambda cfg: (cfg.moe_held or (0, cfg.moe_experts)) if cfg.moe_experts > 0 else None,
-    # the key tile of the chunk's flash kernel, 1 where the chunk is not its to serve
-    key_tile=lambda cfg, window, cache: (
-        latent_flash.tiles(window, _table_keys(cfg, cache))[1]
-        if _flash_serves(cfg, cache["k"], 1, window, _table_keys(cfg, cache), 0) else 1
-    ),
+    key_tile=lambda cfg, window, cache: _program_path(cfg, window, cache)[2],
 )

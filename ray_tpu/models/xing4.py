@@ -62,14 +62,15 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import latent
-from ray_tpu.models.interface import AttentionPath, Model
+from ray_tpu.models import latent, paged_kv
+from ray_tpu.models.interface import AttentionPath, Model, lm_head, step_counters, step_outputs
 # the latent paths, the cache's layout and the block write know only
 # dimensions: ``models/latent.py`` has them, for this module and ``models/
 # kimi_linear.py``
 from ray_tpu.models.latent import absorbs, cache_layout
 from ray_tpu.ops import latent_flash, mhc
-from ray_tpu.ops.moe import dropless_moe_ffn, gated_mlp
+from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.moe import DENSE_AXES, MOE_AXES, gated_mlp, routed_ffn
 from ray_tpu.parallel.sharding import constrain
 
 F32 = jnp.float32
@@ -222,11 +223,6 @@ _AXES = {
     "wo": ("heads", "head_dim", "embed"),
     "shared_gate": ("embed", "mlp"), "shared_up": ("embed", "mlp"), "shared_down": ("mlp", "embed"),
 }
-_DENSE_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
-# the held experts stay whole on each device: an ``expert`` mesh axis is the
-# deployment's, of which this process is one rank
-_MOE_AXES = {"w_gate": (None, "embed", "mlp"), "w_up": (None, "embed", "mlp"),
-             "w_down": (None, "mlp", "embed")}
 
 
 def _groups(cfg: Xing4Config):
@@ -245,7 +241,7 @@ def logical_axes(cfg: Xing4Config) -> Dict[str, Any]:
         "embed": ("vocab", "embed"), "final_norm": (None,), "lm_head": ("embed", "vocab"),
     }
     for name, _, moe in _groups(cfg):
-        own = {**_AXES, **(_MOE_AXES if moe else _DENSE_AXES)}
+        own = {**_AXES, **(MOE_AXES if moe else DENSE_AXES)}
         out[name] = {
             k: (None, *own.get(k, (None,) * len(shape)))
             for k, shape in _group_shapes(cfg, moe).items()
@@ -363,12 +359,6 @@ def param_count(cfg: Xing4Config) -> int:
 # the pieces of a layer
 
 
-def rms_norm(x, weight, eps: float):
-    x32 = x.astype(F32)
-    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * inv).astype(x.dtype) * weight
-
-
 def yarn_inv_freq(cfg: Xing4Config):
     """YaRN's rotary frequencies ``[dr / 2]``: the table's own ``theta^(-2i
     / dr)`` where a pair turns more than ``beta_fast`` times over the
@@ -454,29 +444,17 @@ def _indexer(cfg: Xing4Config, p, h, c_q, pos):
 
 
 def _ffn(cfg: Xing4Config, p, h, valid, moe: bool, at=None):
-    """The FFN of one layer on normed activations ``h [B, C, D]``:
-    ``(ffn(h), aux)``. A dense layer: the gated SiLU MLP, ``aux`` empty. An
-    expert layer: the shared expert on every row + this process's part of
-    the routed experts (``ops/moe.py::dropless_moe_ffn`` told
-    ``cfg.held_experts``: sigmoid scores, the choice with the bias, gates
-    normalised over the kept and scaled); ``aux``: ``load`` ``[E]``,
-    ``bias_changed``, ``aux_loss``. ``valid [B, C]`` marks the real rows of
-    a padded serving step. ``at``: ``p``'s three expert matrices are the
-    STACKS of the layer's group and this is the layer's index in them
-    (:func:`_scan_layers`); absent, they are the layer's own."""
+    """The FFN of one layer on normed activations ``h [B, C, D]``: ``(ffn(h),
+    aux)``. A dense layer: the gated SiLU MLP, ``aux`` empty. An expert layer:
+    ``ops/moe.py::routed_ffn`` with the shared expert and the group limit;
+    ``at``: the layer's index in its group's STACKS of expert matrices
+    (:func:`_scan_layers`), absent where ``p``'s are the layer's own."""
     if not moe:
         return gated_mlp(h, p["w_gate"], p["w_up"], p["w_down"]), {}
-    with jax.named_scope("moe.shared"):
-        shared = gated_mlp(h, p["shared_gate"], p["shared_up"], p["shared_down"])
-    experts = {k: p[k] for k in ("router", "router_bias", "w_gate", "w_up", "w_down")}
-    routed, aux = dropless_moe_ffn(
-        experts, h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k, renormalize=True,
-        valid=None if valid is None else valid.reshape(-1),
-        scoring="sigmoid", scale=cfg.routed_scaling_factor,
-        held=None if cfg.n_held == cfg.n_routed_experts else cfg.held_experts,
-        n_group=cfg.n_group, topk_group=cfg.topk_group, layer=at,
+    return routed_ffn(
+        p, h, valid, top_k=cfg.moe_top_k, scale=cfg.routed_scaling_factor, held=cfg.held_experts,
+        shared=True, n_group=cfg.n_group, topk_group=cfg.topk_group, layer=at,
     )
-    return shared + routed.reshape(h.shape), aux
 
 
 def _bf16_pieces(w):
@@ -620,8 +598,7 @@ def _lm_head(cfg: Xing4Config, params, X):
     """The residual state ``X [..., n, D]`` -> float32 logits ``[..., vocab]``:
     the streams summed (where there are streams), the final norm, the head."""
     x = X.astype(F32).sum(axis=-2).astype(X.dtype) if cfg.hc_mult else X
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("...d,dv->...v", x, params["lm_head"]).astype(F32)
+    return lm_head(params, x, cfg.norm_eps, tied=False)
 
 
 def _embed(cfg: Xing4Config, params, tokens):
@@ -733,22 +710,6 @@ def _paged_layers(cfg: Xing4Config, params, cache, tokens, pos, valid, block_tab
     return latent.write_blocks(cfg, cache, block_tables, pos[:, 0], blocks, layer0), X, aux
 
 
-def _step_outputs(cache, logits, aux):
-    """What a paged step returns: ``(cache, logits)`` and, with expert
-    layers, the counters the runner reads with the logits: ``load
-    [n_moe, E]`` and ``bias_changed [n_moe]`` of the step's valid rows."""
-    if aux:
-        return cache, logits, _counters(aux)
-    return cache, logits
-
-
-def _counters(aux):
-    """The counters of a step's expert layers that the runner reads (the
-    group limit's where there is one)."""
-    keys = ("load", "bias_changed", "group_changed", "routed_rows")
-    return {k: aux[k] for k in keys if k in aux}
-
-
 def paged_prefill_step(cfg: Xing4Config, params, cache, tokens, block_table, ctx_len, true_len):
     """One prefill chunk for ONE request: arguments and outputs as
     ``models/llama.py::paged_prefill_step``. Head: the chunk's last valid row."""
@@ -758,7 +719,7 @@ def paged_prefill_step(cfg: Xing4Config, params, cache, tokens, block_table, ctx
         block_table[None],
     )
     logits = _lm_head(cfg, params, X[0, jnp.maximum(true_len - 1, 0)])
-    return _step_outputs(cache, logits, aux)
+    return step_outputs(cache, logits, step_counters(aux))
 
 
 def paged_verify_step(cfg: Xing4Config, params, cache, tokens, block_tables, ctx_lens, true_lens):
@@ -769,7 +730,7 @@ def paged_verify_step(cfg: Xing4Config, params, cache, tokens, block_tables, ctx
         cfg, params, cache, tokens, ctx_lens[:, None] + idx, idx < true_lens[:, None],
         block_tables,
     )
-    return _step_outputs(cache, _lm_head(cfg, params, X), aux)
+    return step_outputs(cache, _lm_head(cfg, params, X), step_counters(aux))
 
 
 def paged_decode_step(cfg: Xing4Config, params, cache, tokens, positions, block_tables, ctx_lens):
@@ -778,9 +739,9 @@ def paged_decode_step(cfg: Xing4Config, params, cache, tokens, positions, block_
     block is padding). Head: the one row a slot."""
     del ctx_lens
     pos = positions[:, None]
-    valid = latent.block_at(block_tables, pos, latent.block_size_of(cfg, cache)) != 0
+    valid = paged_kv.block_at(block_tables, pos, latent.block_size_of(cfg, cache)) != 0
     cache, X, aux = _paged_layers(cfg, params, cache, tokens[:, None], pos, valid, block_tables)
-    return _step_outputs(cache, _lm_head(cfg, params, X[:, 0]), aux)
+    return step_outputs(cache, _lm_head(cfg, params, X[:, 0]), step_counters(aux))
 
 
 # ---------------------------------------------------------------------------

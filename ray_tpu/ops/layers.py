@@ -11,12 +11,12 @@ import jax.numpy as jnp
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
-    """RMSNorm in fp32 accumulation (Llama-style)."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    x = x * jax.lax.rsqrt(var + eps)
-    return (x * weight.astype(jnp.float32)).astype(dtype)
+    """RMSNorm: the mean square and its ``rsqrt`` in float32, the normalised
+    activations cast back and THEN times the weight (in the weight's dtype):
+    the one every model module runs."""
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * inv).astype(x.dtype) * weight
 
 
 def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0):

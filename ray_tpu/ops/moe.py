@@ -321,6 +321,45 @@ def gated_mlp(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jn
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+#: logical axes of a dense gated MLP's three matrices, and of a stack of HELD
+#: experts' (they stay whole on each device: an ``expert`` mesh axis is the
+#: deployment's, of which this process is one rank; :func:`moe_logical_axes`
+#: is the expert-PARALLEL path's)
+DENSE_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+MOE_AXES = {"w_gate": (None, "embed", "mlp"), "w_up": (None, "embed", "mlp"),
+            "w_down": (None, "mlp", "embed")}
+
+
+def routed_ffn(
+    p: Dict[str, Any], h: jnp.ndarray, valid: Optional[jnp.ndarray], *, top_k: int, scale: float,
+    held: Tuple[int, int], shared: bool, n_group: int = 1, topk_group: int = 1,
+    layer: Optional[jnp.ndarray] = None,
+):
+    """The SIGMOID-routed expert FFN of one layer on normed activations ``h
+    [B, C, D]``: ``(ffn(h), aux)``. This process's part of the routed experts
+    (:func:`dropless_moe_ffn` told the range ``held`` of them it holds, unless
+    that is all: sigmoid scores, the choice with ``p["router_bias"]``, gates
+    normalised over the kept and scaled by ``scale``) and, ``shared``, the
+    shared expert on every row beside them (``p["shared_gate" | "shared_up" |
+    "shared_down"]``). ``valid [B, C]`` marks the real rows of a padded
+    serving step; ``aux``: ``load [E]``, ``bias_changed``, ``aux_loss`` (and
+    the group limit's). ``layer``: ``p``'s three expert matrices are the
+    STACKS of a scanned group and this is the layer's index in them."""
+    if tuple(held) == (0, p["router"].shape[1]):
+        held = None
+    if shared:
+        with jax.named_scope("moe.shared"):
+            out = gated_mlp(h, p["shared_gate"], p["shared_up"], p["shared_down"])
+    experts = {k: p[k] for k in ("router", "router_bias", "w_gate", "w_up", "w_down")}
+    routed, aux = dropless_moe_ffn(
+        experts, h.reshape(-1, h.shape[-1]), top_k=top_k, renormalize=True,
+        valid=None if valid is None else valid.reshape(-1),
+        scoring="sigmoid", scale=scale, held=held, n_group=n_group, topk_group=topk_group, layer=layer,
+    )
+    routed = routed.reshape(h.shape)
+    return (out + routed if shared else routed), aux
+
+
 def moe_ffn(
     params: Dict[str, Any],
     x: jnp.ndarray,

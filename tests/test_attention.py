@@ -156,6 +156,13 @@ def test_layers():
     w = jnp.ones((8,))
     out = rms_norm(x, w)
     np.testing.assert_allclose(np.asarray(out), np.ones((2, 8)), rtol=1e-5)
+    # the one body every model runs: float32 inside, cast back, THEN times the weight in its own dtype
+    xb, wb = jnp.linspace(-3, 3, 16, dtype=jnp.bfloat16).reshape(2, 8), jnp.full((8,), 1.5, jnp.bfloat16)
+    x32 = xb.astype(jnp.float32)
+    normed = (x32 / jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-5)).astype(jnp.bfloat16)
+    have = rms_norm(xb, wb, 1e-5)
+    assert have.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(have, np.float32), np.asarray(normed * wb, np.float32), rtol=1e-2)
 
     cos, sin = rope_frequencies(64, 128)
     assert cos.shape == (128, 32)

@@ -197,7 +197,7 @@ def kernel_forced(monkeypatch):
     the kernel, a prefill chunk the gather."""
     monkeypatch.setattr(
         PA, "kernel_serves",
-        lambda window, n_heads, k_cache, backend=None: window * n_heads <= 64,
+        lambda window, n_heads, k_cache, backend=None, n_kv=None, head_dim=None: window * n_heads <= 64,
     )
 
 

@@ -35,8 +35,10 @@ from ray_tpu.inference.engine import EngineConfig, InferenceEngine  # noqa: E402
 from ray_tpu.inference.kv_cache import PagedBlockManager  # noqa: E402
 from ray_tpu.inference.model_runner import PagedModelRunner  # noqa: E402
 from ray_tpu.models import llama as L  # noqa: E402
+from ray_tpu.models import paged_kv  # noqa: E402
 from ray_tpu.models.interface import LayerGroup  # noqa: E402
 from ray_tpu.ops import latent_flash, paged_attention as PA  # noqa: E402
+from ray_tpu.ops.layers import rms_norm  # noqa: E402
 
 REL_TOL = 2e-4
 W, BS = 8, 4
@@ -620,16 +622,17 @@ def _paged_layers_as_they_were(cfg, params, cache, x, pos, valid, block_tables):
     """``llama._paged_layers`` of the parent of PR 44, word for word: one
     table, one rope table, every layer in the arrays ``k`` and ``v``."""
     bs = cache["k"].shape[2]
-    blk = jnp.where(valid, L._block_at(block_tables, pos, bs), 0)
+    blk = jnp.where(valid, paged_kv.block_at(block_tables, pos, bs), 0)
     off = pos % bs
     cos, sin = L._rope_at(cfg, pos)
     loads = []
     for layer, p in enumerate(params["layers"]):
-        q, k, v = L._qkv(cfg, p, L.rms_norm(x, p["attn_norm"], cfg.norm_eps))
+        q, k, v = L._qkv(cfg, p, rms_norm(x, p["attn_norm"], cfg.norm_eps))
         q = L._apply_rope_flat(q, cos, sin)
         k = L._apply_rope_flat(k, cos, sin)
-        cache = L._scatter_kv(cache, layer, blk, off, k, v)
-        o = L._paged_attention(cfg, q, cache, layer, block_tables, pos)
+        cache = paged_kv.scatter_kv(cache, layer, blk, off, k, v)
+        o = paged_kv.attention(
+            q, cache["k"], cache["v"], layer, block_tables, pos, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim)
         x = x + jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
         x = L._ffn_residual(cfg, p, x, valid, loads)
     return cache, x, loads

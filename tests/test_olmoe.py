@@ -29,6 +29,8 @@ import olmoe_controls as controls  # noqa: E402 - the reference's twin with the 
 from perfbench.families.olmoe import reference  # noqa: E402
 from ray_tpu.inference.model_runner import PagedModelRunner  # noqa: E402
 from ray_tpu.models import llama as L  # noqa: E402
+from ray_tpu.models import paged_kv  # noqa: E402
+from ray_tpu.ops.layers import rms_norm  # noqa: E402
 from ray_tpu.ops.moe import dropless_moe_ffn, init_moe_params, moe_ffn, route  # noqa: E402
 
 REL_TOL = 2e-4
@@ -150,7 +152,7 @@ def test_qk_norm_on_a_dense_config():
     assert _rel(with_norm, without) > 1e-2
     # by hand, one layer's q: the norm runs over all heads together
     p = params["layers"][0]
-    h = L.rms_norm(params["embed"][tokens], p["attn_norm"], cfg.norm_eps)
+    h = rms_norm(params["embed"][tokens], p["attn_norm"], cfg.norm_eps)
     q, k, _ = L._qkv(cfg, p, h)
     flat = jnp.einsum("bsd,dhk->bshk", h, p["wq"]).reshape(2, 16, -1)
     want = flat * jax.lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True) + cfg.norm_eps) * p["q_norm"]
@@ -737,11 +739,11 @@ def test_the_flash_kernel_compiles_with_grouped_heads_and_a_window_at_mellum2_wi
     over 4 key heads (the index map, nothing repeated), keys and values 128
     wide, no shared part; a full layer over the 16 k table, a window layer over
     the window, the chunk and a block's slack in whole key tiles
-    (``llama._chunk_keys``). One Mosaic call; the scores are nobody's temporary."""
+    (``paged_kv.chunk_keys``). One Mosaic call; the scores are nobody's temporary."""
     from ray_tpu.ops import latent_flash as LF
 
     cfg = dataclasses.replace(L.LlamaConfig.tiny(), n_heads=32, n_kv_heads=4, attn_head_dim=128, layer_windows=(1024, 0))
-    assert L._chunk_keys(cfg, window, chunk, 16384, 16) == keys
+    assert paged_kv.chunk_keys(window, chunk, 16384, 16) == keys
     assert LF.kernel_serves(chunk, keys, 128, 128, 0, jnp.bfloat16, backend="tpu")
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -802,7 +804,7 @@ def test_a_plain_configurations_prefill_chunk_compiles_for_the_chip_through_the_
     through ``gmm``), ONE layer of each at the file's widths over its pool and
     its 4096-key table, compiled for the real chip (nothing runs): the chunk's
     attention is ONE ``latent_flash`` call over K and V gathered through the
-    table (``llama._flash_serves`` from shapes, PR 51), and no program holds
+    table (``paged_kv.way`` from shapes, PR 51), and no program holds
     the materialised way's float32 scores ``[chunk, heads, 4096]`` (537 MB a
     layer at Mistral's 1024-chunk) among its temporaries."""
     from perfbench import families

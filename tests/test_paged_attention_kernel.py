@@ -1,5 +1,5 @@
 """The paged-attention kernel (``ops/paged_attention.py``) against the gather
-path of ``models/llama.py::_paged_attention``, on the CPU in Pallas' TPU
+path of ``models/paged_kv.py::attention``, on the CPU in Pallas' TPU
 interpreter at tiny widths with ``head_dim`` 128.
 
 Every case runs the KERNEL over a poisoned cache and the GATHER over the
@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import llama as L
+from ray_tpu.models import paged_kv
 from ray_tpu.ops import paged_attention as PA
 
 BS, M, HD, N_KV, LAYERS, LAYER = 4, 8, 128, 2, 2, 1
@@ -70,9 +71,14 @@ def _case(rep, window, contexts, seed=0, dtype=jnp.float32, step=1, hd=HD):
     return cfg, jnp.asarray(q, dtype), as_cache(k, v), as_cache(kp, vp), jnp.asarray(tables), jnp.asarray(pos)
 
 
+def _attention(cfg, q, cache, layer, tables, pos):
+    return paged_kv.attention(
+        q, cache["k"], cache["v"], layer, tables, pos, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim)
+
+
 def _both(case, **kw):
     cfg, q, clean, poisoned, tables, pos = case
-    want = L._paged_attention(cfg, q, clean, LAYER, tables, pos)  # the CPU: the gather
+    want = _attention(cfg, q, clean, LAYER, tables, pos)  # the CPU: the gather
     if q.shape[-1] < 128:  # narrow heads: a token's heads in one row, said beside it
         poisoned = {name: a.reshape(*a.shape[:3], -1) for name, a in poisoned.items()}
         kw["n_kv"] = N_KV
@@ -114,7 +120,7 @@ def test_one_kv_head_under_twenty_query_heads_stored_flat(window, contexts):
             tables[b] = shuffled[b * M:(b + 1) * M]
             pos[b] = np.minimum(ctx - 1 + np.arange(window), FULL - 1)
     q = jnp.asarray(rng.standard_normal((B, window, H, HD)).astype(np.float32))
-    want = L._attend_gathered(q, k, v, LAYER, jnp.asarray(tables), jnp.asarray(pos), 1, FULL)
+    want = paged_kv.attend_gathered(q, k, v, LAYER, jnp.asarray(tables), jnp.asarray(pos), 1, FULL)
     flat = lambda a: a.reshape(LAYERS, N, BS, HD)  # noqa: E731
     assert PA.kernel_serves(window, H, jax.ShapeDtypeStruct((LAYERS, N, 16, HD), jnp.bfloat16), backend="tpu", n_kv=1)
     have = PA.paged_attention(q, flat(k), flat(v), LAYER, jnp.asarray(tables), jnp.asarray(pos), interpret=True,
@@ -140,7 +146,7 @@ def test_each_row_of_a_window_masks_on_its_own_position():
     have, want = _both(case, wave_blocks=2)
     np.testing.assert_allclose(have, want, rtol=2e-5, atol=2e-5)
     cfg, q, clean, _, tables, pos = case
-    blind = L._paged_attention(cfg, q, clean, LAYER, tables, jnp.broadcast_to(pos[:, -1:], pos.shape))
+    blind = _attention(cfg, q, clean, LAYER, tables, jnp.broadcast_to(pos[:, -1:], pos.shape))
     assert np.abs(np.asarray(blind)[:3] - want)[:, 0].max() > 1e-2
 
 
@@ -154,7 +160,7 @@ def test_bfloat16_cache_accumulates_in_float32(hd):
     assert np.isfinite(have).all()
     np.testing.assert_allclose(have, want, rtol=0, atol=3e-2)
     cfg, q, clean, _, tables, pos = case16
-    exact = np.asarray(L._paged_attention(
+    exact = np.asarray(_attention(
         cfg, q.astype(jnp.float32), jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), clean),
         LAYER, tables, pos,
     ))[:-2]
@@ -236,7 +242,7 @@ def _kernel_and_gather(case, keeps, n_kv, capfd, **kw):
     DMA semaphore back at zero when the kernel ended (it prints one that is not)."""
     q, clean, poisoned, tables, pos, spans = case
     keys = tables.shape[1] * clean[0].shape[2]
-    want = np.asarray(L._attend_gathered(q, *clean, LAYER, tables, pos, n_kv, keys, keeps))
+    want = np.asarray(paged_kv.attend_gathered(q, *clean, LAYER, tables, pos, n_kv, keys, keeps))
     said = {} if poisoned[0].ndim == 5 else {"n_kv": n_kv}
     capfd.readouterr()
     have = np.asarray(PA.paged_attention(q, *poisoned, LAYER, tables, pos, interpret=True, keeps=keeps, **said, **kw))

@@ -1,5 +1,5 @@
 """The one paged block behind the three serving steps (``models/llama.py``:
-``_paged_layers`` over ``_paged_attention``), at ``LlamaConfig.tiny()`` on
+``_paged_layers`` over ``models/paged_kv.py::attention``), at ``LlamaConfig.tiny()`` on
 the CPU in float32 with seeded weights, for the dense block and for a MoE
 block with QK-norm. The three entry points differ in the rank of their
 arguments, in how ``valid`` is found and in which rows get logits: each
@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import llama as L
+from ray_tpu.ops import paged_attention as paged_attn
 
 REL_TOL = 2e-4
 BLOCK, NUM_BLOCKS, WIDTH = 4, 24, 8  # tokens a block, blocks in the pool, blocks a table row
@@ -142,7 +143,7 @@ STEP_ARGS = {  # every entry point on one request of block table (1, 2), nothing
 
 @pytest.mark.parametrize("step", list(STEP_ARGS))
 def test_every_entry_point_attends_through_the_one_seam(monkeypatch, step):
-    """``_paged_attention`` is the only door to the cache for attention: each
+    """``paged_kv.attention`` is the only door to the cache for attention: each
     entry point calls it once a layer, and what it returns is what the step
     computes with (a kernel put in its place is all three steps' kernel)."""
     cfg = L.LlamaConfig.tiny()
@@ -150,19 +151,19 @@ def test_every_entry_point_attends_through_the_one_seam(monkeypatch, step):
     cache = L.init_paged_kv_cache(cfg, NUM_BLOCKS, BLOCK)
     args = STEP_ARGS[step](lambda shape: np.ones(shape, np.int32))
     fn = getattr(L, f"paged_{step}_step")
-    real, calls = L._paged_attention, []
+    real, calls = L.paged_kv.attention, []
 
-    def counting(cfg_, q, cache_, layer, block_tables, pos, *of_the_group):
+    def counting(q, k_cache, v_cache, layer, block_tables, pos, *valid, **of_the_group):
         calls.append((layer, q.shape, block_tables.shape, pos.shape))
-        return real(cfg_, q, cache_, layer, block_tables, pos, *of_the_group)
+        return real(q, k_cache, v_cache, layer, block_tables, pos, *valid, **of_the_group)
 
-    monkeypatch.setattr(L, "_paged_attention", counting)
+    monkeypatch.setattr(L.paged_kv, "attention", counting)
     logits = fn(cfg, params, cache, *args)[1]
     assert [c[0] for c in calls] == list(range(cfg.n_layers))
     for _, q, tables, pos in calls:  # one form for all three: [B, C, H, hd], [B, M], [B, C]
         assert q == (*pos, cfg.n_heads, cfg.head_dim) and tables == (pos[0], WIDTH)
 
-    monkeypatch.setattr(L, "_paged_attention", lambda cfg_, q, *rest: jnp.zeros_like(q))
+    monkeypatch.setattr(L.paged_kv, "attention", lambda q, *rest, **said: jnp.zeros_like(q))
     assert _rel(fn(cfg, params, cache, *args)[1], logits) > 1e-2
 
 
@@ -188,10 +189,10 @@ def test_a_prefill_chunk_lowers_with_no_pallas_call_even_on_a_tpu(monkeypatch, c
     PR 30)."""
     cfg, params, cache = _tile_model()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # steer the predicate; the test's business
-    assert L.paged_attn.kernel_serves(1, cfg.n_heads, cache["k"])
-    assert not L.paged_attn.kernel_serves(chunk, cfg.n_heads, cache["k"])
+    assert paged_attn.kernel_serves(1, cfg.n_heads, cache["k"])
+    assert not paged_attn.kernel_serves(chunk, cfg.n_heads, cache["k"])
     monkeypatch.setattr(
-        L.paged_attn, "paged_attention",
+        paged_attn, "paged_attention",
         lambda *a, **kw: pytest.fail("a prefill chunk reached the paged-attention kernel"),
     )
     text = jax.jit(partial(L.paged_prefill_step, cfg)).lower(
@@ -202,24 +203,25 @@ def test_a_prefill_chunk_lowers_with_no_pallas_call_even_on_a_tpu(monkeypatch, c
 
 @pytest.mark.parametrize("step", ["verify", "decode"])
 def test_the_kernel_path_is_still_the_one_door(monkeypatch, step):
-    """Where the predicate says kernel, ``_paged_attention`` is still called
+    """Where the predicate says kernel, ``paged_kv.attention`` is still called
     once a layer and is the only caller of the kernel: once a layer, with the
     WHOLE cache (no layer of it sliced off outside) and the layer's index."""
     cfg, params, cache = _tile_model()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     doors, kernels = [], []
-    real = L._paged_attention
+    real = L.paged_kv.attention
 
-    def door(cfg_, q, cache_, layer, block_tables, pos, *of_the_group):
+    def door(q, k_cache, v_cache, layer, block_tables, pos, *valid, **of_the_group):
         doors.append(layer)
-        return real(cfg_, q, cache_, layer, block_tables, pos, *of_the_group)
+        return real(q, k_cache, v_cache, layer, block_tables, pos, *valid, **of_the_group)
 
-    def kernel(q, k_cache, v_cache, layer, block_tables, pos):
+    def kernel(q, k_cache, v_cache, layer, block_tables, pos, n_kv, keeps):
+        assert (n_kv, keeps) == (cfg.n_kv_heads, 0)
         kernels.append((layer, len(doors), k_cache.shape, v_cache.shape, q.shape, pos.shape))
         return jnp.zeros_like(q)
 
-    monkeypatch.setattr(L, "_paged_attention", door)
-    monkeypatch.setattr(L.paged_attn, "paged_attention", kernel)
+    monkeypatch.setattr(L.paged_kv, "attention", door)
+    monkeypatch.setattr(paged_attn, "paged_attention", kernel)
     args = {
         "verify": (_i32(2, 4), _i32(2, WIDTH), _i32(2), _i32(2)),
         "decode": (_i32(2), _i32(2), _i32(2, WIDTH), _i32(2)),
