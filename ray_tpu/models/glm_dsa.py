@@ -19,7 +19,10 @@ parameters are ``xing4._group_shapes``' with the indexer's five arrays a layer
 (``idx_*``, there because this configuration's ``index_topk`` is not 0), the
 projections ``xing4._indexer``, the scores and the exact choice
 ``ops/sparse_index.py``, the two attention paths and the second cached row
-``models/latent.py::_sparse_attention``; the entry points are
+``models/latent.py::_sparse_attention`` (on a TPU a chunk's attention is
+``ops/latent_flash.py::attend_selected`` and a decode or verify window reads
+its slots' live blocks through ``ops/index_paged.py`` and
+``ops/latent_paged.py``, the selection a mask in both); the entry points are
 ``deepseek_v3``'s, run with this configuration. No YaRN (``rope_factor`` 1: the
 plain table at ``rope_theta``), no group stage in the router (``n_group`` 1).
 
@@ -77,16 +80,23 @@ class GlmDsaConfig(DeepseekV3Config):
 
 
 def _attention_path(cfg: GlmDsaConfig, window: int, cache, backend=None) -> AttentionPath:
-    """Every window selects. A decode or verify window (``latent.sparse``)
-    reads the index keys at the table's width for every slot of the bucket
-    and gathers the chosen latent rows by token. A chunk reads both arrays up
-    to its rung (``latent.index_rungs``), scores and selects there, and
-    attends under the selection as a mask over all of it, by what will run:
+    """Every window selects. A decode or verify window, by what will run:
+    ``latent.sparse_paged`` where the paged kernels serve
+    (``latent.sparse_paged_serves``: a TPU, bf16, both arrays stored in whole
+    tiles, window x heads within the kernel's query rows): each real slot's
+    LIVE blocks of both arrays and no other, the index keys scored a wave at a
+    time, the latent rows attended under the selection as a mask;
+    ``latent.sparse`` elsewhere: the index keys at the table's width for every
+    slot of the bucket, the chosen latent rows gathered by token. A chunk
+    reads both arrays up to its rung (``latent.index_rungs``), scores and
+    selects there, and attends under the selection as a mask over all of it, by what will run:
     ``latent.sparse_flash`` where the kernel serves
     (``latent.selected_serves``: a TPU, bf16, whole tiles: the EXPANDED form,
     K and V expanded in VMEM from the key tiles up to the chunk's end alone,
     the scores never in HBM), ``latent.sparse_masked`` elsewhere (the
     absorbed form's materialised softmax over the rung, XLA's)."""
+    if latent.sparse_paged_serves(cfg, window, cache, backend=backend):
+        return AttentionPath("latent.sparse_paged", "blocks")
     if latent.absorbs(cfg, window):
         return AttentionPath("latent.sparse", "table")
     if latent.selected_serves(cfg, window, cache, backend=backend):

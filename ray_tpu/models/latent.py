@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.interface import CacheLayout
-from ray_tpu.ops import latent_flash, latent_paged, sparse_index
+from ray_tpu.ops import index_paged, latent_flash, latent_paged, sparse_index
 
 F32 = jnp.float32
 
@@ -110,23 +110,29 @@ def paged_serves(cfg, window: int, cache, backend=None) -> bool:
     )
 
 
-def attend_paged(cfg, q_row, cache, layer, block_tables, first, own, interpret=None):
+def attend_paged(cfg, q_row, cache, layer, block_tables, first, own, chosen=None, interpret=None):
     """:func:`attend_rows` with the context read by the kernel
     (``ops/latent_paged.py``) from each slot's own live blocks: the cached
     positions ``j < first[b]`` come back as an online softmax's state ``(acc,
     m, l)``, and the window's own rows ``own [B, C, kr + dr]`` (query ``c``
     seeing ``c' <= c``) are folded in here under the SAME softmax, ``B x C x
     H`` numbers. A padding slot (its table starts on the null block) returns
-    zeros, as :func:`latent_attention`'s gather does."""
+    zeros, as :func:`latent_attention`'s gather does. ``chosen [B, C, keys]``
+    bool, of a model that selects: query ``c`` sees a position, cached
+    (the kernel takes the selection as a mask) or the window's own (``first +
+    c'``, here), only if it chose it."""
     kr = cfg.kv_lora_rank
     with jax.named_scope("mla.attend"):
         acc, m, l = latent_paged.attend_paged(
             q_row, cache["latent"], layer, block_tables, first,
-            kv_lora_rank=kr, scale=cfg.attn_scale, interpret=interpret,
+            kv_lora_rank=kr, scale=cfg.attn_scale, chosen=chosen, interpret=interpret,
         )
         C = own.shape[1]
         s_own = jnp.einsum("bchw,bdw->bchd", q_row, own, preferred_element_type=F32)
-        s_own = jnp.where(jnp.tril(jnp.ones((C, C), bool))[None, :, None, :], s_own * cfg.attn_scale, -1e30)
+        within = jnp.tril(jnp.ones((C, C), bool))[None, :, None, :]
+        if chosen is not None:
+            within = within & window_columns(chosen, first, C)[:, :, None, :]
+        s_own = jnp.where(within, s_own * cfg.attn_scale, -1e30)
         m_new = jnp.maximum(m, s_own.max(axis=-1))
         alpha = jnp.exp(m - m_new)
         p_own = jnp.exp(s_own - m_new[..., None])
@@ -135,6 +141,16 @@ def attend_paged(cfg, q_row, cache, layer, block_tables, first, own, interpret=N
         )
         o_lat = acc / (alpha * l + p_own.sum(axis=-1))[..., None]
         return jnp.where((block_tables[:, 0] != 0)[:, None, None, None], o_lat, 0).astype(q_row.dtype)
+
+
+def window_columns(chosen, first, window: int):
+    """Of a selection ``chosen [B, C, keys]`` the columns of the window's own
+    positions, ``chosen[b, c, first[b] + c']`` -> ``[B, C, window]`` (False
+    past the table): a select a position, as everywhere a window's own are
+    picked out of the table's width."""
+    key_pos = jnp.arange(chosen.shape[-1], dtype=jnp.int32)
+    own = [jnp.any(chosen & (key_pos == (first + c)[:, None, None]), axis=-1) for c in range(window)]
+    return jnp.stack(own, axis=-1)
 
 
 def absorb_output(cfg, p, o_lat):
@@ -369,6 +385,21 @@ def selected_serves(cfg, window: int, cache, keys=None, backend=None) -> bool:
     )
 
 
+def sparse_paged_serves(cfg, window: int, cache, backend=None) -> bool:
+    """Whether a selecting decode or verify window of ``window`` queries a
+    slot reads its slots' LIVE blocks through the two paged kernels (the
+    index keys: ``ops/index_paged.py``; the latent rows under the selection
+    as a mask: ``ops/latent_paged.py``): :func:`paged_serves` and the index
+    kernel's ``kernel_serves`` on what the code can observe (backend, dtype,
+    window x heads, the widths, the stored form of both arrays). Off a TPU
+    the cache is not looked at."""
+    return (
+        indexed(cfg)
+        and paged_serves(cfg, window, cache, backend)
+        and index_paged.kernel_serves(window, cfg.index_n_heads, cfg.index_head_dim, cache["index"], "tpu")
+    )
+
+
 def attend_selected(cfg, p, q_nope, q_rope, rows, mask, ctx_len, true_len, interpret=None):
     """ONE slot's chunk under its selection, in the expanded form, through
     the kernel (``ops/latent_flash.py::attend_selected``): queries ``[C, H,
@@ -445,11 +476,24 @@ def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_ta
       Elsewhere (the CPU, odd widths) in the absorbed form, XLA's
       materialised softmax inside the switch (:func:`attend_masked` between
       :func:`absorb_query` and :func:`absorb_output`);
-    * a decode or verify window (:func:`absorbs`; a few positions: the
-      window's own are laid in by a select each), ``W_kvb`` absorbed: the
-      index keys at the table's width, the chosen latent rows gathered BY
-      TOKEN (:func:`_token_rows`; a chosen position of the window itself is
-      the window's own row), the softmax over ``index_topk`` rows a query.
+    * a decode or verify window (:func:`absorbs`; a few positions), ``W_kvb``
+      absorbed. Where the paged kernels serve (:func:`sparse_paged_serves`: a
+      TPU, bf16, both arrays in whole tiles) each slot's LIVE blocks are read
+      from the cache as it lies and nothing at the table's width: the scores
+      of the cached keys by ``ops/index_paged.py``, which lays the window's
+      own ``C x C`` (made beside it) in at ``first + c``; the exact choice
+      over those scores (``sparse_index.select_mask``: what lies past a slot's
+      context reads 0 and is over every query's limit); the attention over
+      the live latent blocks through ``ops/latent_paged.py`` with the selection as a MASK on its scores, the
+      window's own rows folded in under the same softmax, an own position only
+      if chosen (:func:`attend_paged`): the same sum as over the chosen rows
+      gathered, in another order. Elsewhere (the CPU, odd widths: the
+      fallback, and what the kernels' tests hold them to) the index keys at
+      the table's width, the chosen positions in order
+      (``sparse_index.mask_positions``: a sort), the chosen latent rows
+      gathered BY TOKEN (:func:`_token_rows`; a chosen position of the window
+      itself is the window's own row, laid in by a select each), the softmax
+      over ``index_topk`` rows a query.
 
     Returns ``(out, blocks)`` as there, ``blocks [B, nblk * block_size, kr +
     dr + di]``: a token's two rows side by side."""
@@ -476,6 +520,21 @@ def _sparse_attention(cfg, p, q_nope, q_rope, row, index, cache, layer, block_ta
 
     if absorbs(cfg, C):
         q_row = absorb_query(cfg, p, q_nope, q_rope)
+        if sparse_paged_serves(cfg, C, cache):
+            # the kernels read each slot's own live blocks; the window's own
+            # keys are scored beside them and laid in where they will be written
+            keys = block_tables.shape[1] * bs
+            with jax.named_scope("dsa.index"):
+                own_scores = jax.vmap(sparse_index.index_scores)(q_i, w_i, k_i.astype(cache["index"].dtype))
+                scores = index_paged.index_scores(q_i, w_i, own_scores, cache["index"], layer, block_tables, first)
+            with jax.named_scope("dsa.topk"):
+                chosen = sparse_index.select_mask(scores.reshape(B * C, keys), pos.reshape(-1), K)
+            with jax.named_scope("dsa.attend"):
+                o_lat = attend_paged(
+                    cfg, q_row, cache, layer, block_tables, first, row, chosen=chosen.reshape(B, C, keys)
+                )
+            live = (block_tables[:, 0] != 0)[:, None, None]
+            return absorb_output(cfg, p, o_lat), jnp.where(live, window_blocks(), 0)
         # the slots at once, on explicit batch axes: a padding slot (the null
         # block's table) reads the null block's rows, finite and nobody's, and
         # comes back as zeros. The window's own keys and rows are laid in by

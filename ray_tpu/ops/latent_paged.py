@@ -41,9 +41,22 @@ before the second product and the buffers start as zeros, so nothing but
 finite numbers ever meets a probability of 0: a NaN there cannot reach the
 output.
 
+A model that SELECTS (``models/latent.py::_sparse_attention``: the top
+``index_topk`` positions a query) hands its choice over as ONE more operand,
+``chosen``, laid out as a wave's scores are (a row a token-of-a-stored-row and
+query, ``T x C`` of them, every head's alike) and applied where the scores of
+what is stale are masked: the same blocks are read, the same two products
+run, and a probability is 0 by the mask itself (a wave may hold nothing a
+query chose, so the running maximum may still be the masked value after it).
+Without the operand the kernel is the program it was: it is absent at trace
+time, not switched off (``latent_rows`` in a trace; ``latent_rows_selected``
+with it).
+
 The layer is an operand, not a constant of the kernel, and the call is
 jitted by itself: a model's calls (7, or 40 under a ``lax.scan``) are one
-traced and lowered kernel.
+traced and lowered kernel. The wave's copies and waits
+(:func:`wave_copies`) and the slots' schedule (:func:`slot_waves`) are also
+``ops/index_paged.py``'s, over the other array of such a model's cache.
 """
 
 from __future__ import annotations
@@ -102,37 +115,20 @@ def kernel_serves(
     )
 
 
-def _kernel(
-    tables_ref,  # SMEM [B * M] int32
-    ctx_ref,  # SMEM [B] int32: cached positions the slot's queries see
-    nblk_ref,  # SMEM [B] int32: live blocks of the slot, 0 for a padding slot
-    next_ref,  # SMEM [B + 1] int32: the first slot >= i that has live blocks (B: none)
-    buf_ref,  # SMEM [B] int32: the buffer the slot's first wave lands in
-    layer_ref,  # SMEM [1] int32
-    q_ref,  # VMEM [1, T * C * H, T * W]: this slot's queries, laid out T times
-    cache_hbm,  # ANY [L, N, R, T * W]
-    acc_ref,  # VMEM [1, C * H, kr] float32
-    m_ref,  # VMEM [1, C * H, 1] float32
-    l_ref,  # VMEM [1, C * H, 1] float32
-    buf,  # VMEM [2, P, R, T * W]
-    sems,  # DMA [2 (buffer)]
-    *,
-    table_width: int,
-    row_width: int,
-    scale: float,
-):
+def wave_copies(tables_ref, nblk_ref, next_ref, layer, cache_hbm, buf, sems, table_width: int):
+    """``(start_wave, turn)`` of a kernel that reads each slot's live blocks
+    in waves (this file's, and ``ops/index_paged.py``'s over the index keys):
+    ``tables_ref`` SMEM ``[B * table_width]``, ``nblk_ref`` SMEM ``[B]`` (live
+    blocks a slot), ``next_ref`` SMEM ``[B + 1]`` (the first slot ``>= i`` that
+    has any; ``B``: none), ``cache_hbm [L, N, *block]``, ``buf [2, P,
+    *block]``, ``sems`` DMA ``[2]``. ``start_wave(slot, wave, buffer)`` starts
+    a wave's copies; ``turn(slot, wave, buffer, ends_slot)`` starts the NEXT
+    wave's (of this slot or, where this wave ``ends_slot``, the first of the
+    next slot that has any) into the other buffer and waits for this one's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B = nblk_ref.shape[0]
-    _, P, R, TW = buf.shape
-    _, rows, _ = q_ref.shape
-    _, CH, kr = acc_ref.shape
-    W, M = row_width, table_width
-    T = TW // W
-    bs = R * T
-    b = pl.program_id(0)
-    layer = layer_ref[0]
+    P, M = buf.shape[1], table_width
 
     def live_in_wave(slot, w):
         return jnp.minimum(P, nblk_ref[slot] - w * P)
@@ -177,6 +173,52 @@ def _kernel(
 
             size //= 2
 
+    def turn(slot, w, i_buf, ends_slot):
+        nb = jnp.where(ends_slot, next_ref[slot + 1], slot)
+
+        @pl.when(nb < nblk_ref.shape[0])
+        def _():
+            start_wave(nb, jnp.where(ends_slot, 0, w + 1), 1 - i_buf)
+
+        wait_wave(slot, w, i_buf)
+
+    return start_wave, turn
+
+
+def _kernel(
+    tables_ref,  # SMEM [B * M] int32
+    ctx_ref,  # SMEM [B] int32: cached positions the slot's queries see
+    nblk_ref,  # SMEM [B] int32: live blocks of the slot, 0 for a padding slot
+    next_ref,  # SMEM [B + 1] int32: the first slot >= i that has live blocks (B: none)
+    buf_ref,  # SMEM [B] int32: the buffer the slot's first wave lands in
+    layer_ref,  # SMEM [1] int32
+    q_ref,  # VMEM [1, T * C * H, T * W]: this slot's queries, laid out T times
+    cache_hbm,  # ANY [L, N, R, T * W]
+    acc_ref,  # VMEM [1, C * H, kr] float32
+    m_ref,  # VMEM [1, C * H, 1] float32
+    l_ref,  # VMEM [1, C * H, 1] float32
+    buf,  # VMEM [2, P, R, T * W]
+    sems,  # DMA [2 (buffer)]
+    *,
+    table_width: int,
+    row_width: int,
+    scale: float,
+    chosen_ref=None,  # :func:`_kernel_selected`'s one more operand
+):
+    from jax.experimental import pallas as pl
+
+    B = nblk_ref.shape[0]
+    _, P, R, TW = buf.shape
+    _, rows, _ = q_ref.shape
+    _, CH, kr = acc_ref.shape
+    W, M = row_width, table_width
+    T = TW // W
+    bs = R * T
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+
+    start_wave, turn = wave_copies(tables_ref, nblk_ref, next_ref, layer, cache_hbm, buf, sems, M)
+
     @pl.when(b == 0)
     def _():
         # never-fetched rows of a buffer must be finite: they meet P = 0
@@ -207,13 +249,7 @@ def _kernel(
     def wave(w, carry):
         m, l, acc, i_buf = carry
         ends_slot = w + 1 == n_waves
-        nb = jnp.where(ends_slot, next_ref[b + 1], b)
-
-        @pl.when(nb < B)
-        def _():
-            start_wave(nb, jnp.where(ends_slot, 0, w + 1), 1 - i_buf)
-
-        wait_wave(b, w, i_buf)
+        turn(b, w, i_buf, ends_slot)
         base = w * (P * bs)
 
         @pl.when(ends_slot & (n * bs > ctx))
@@ -228,10 +264,21 @@ def _kernel(
 
         k = buf[i_buf].reshape(P * R, TW)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        s = jnp.where(tok < ctx - base, s * scale, _MASKED)
+        seen = tok < ctx - base
+        if chosen_ref is not None:
+            # a row a (token of a stored row, query): every head's alike
+            mine = chosen_ref[0, w]
+            heads = CH // (mine.shape[0] // T)
+            mine = [jnp.broadcast_to(mine[i : i + 1], (heads, P * R)) for i in range(mine.shape[0])]
+            seen = seen & (jnp.concatenate(mine, axis=0) != 0)
+        s = jnp.where(seen, s * scale, _MASKED)
         m_new = jnp.maximum(m, groups(s.max(axis=1, keepdims=True), jnp.maximum))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - jnp.concatenate([m_new] * T, axis=0))
+        if chosen_ref is not None:
+            # a wave may hold nothing a query chose: while m is still the
+            # masked value, exp(s - m) of a masked score is 1, not 0
+            p = jnp.where(seen, p, 0.0)
         l = alpha * l + groups(p.sum(axis=1, keepdims=True), jnp.add)
         acc = jnp.concatenate([alpha] * T, axis=0) * acc + jnp.dot(
             p.astype(k.dtype), k, preferred_element_type=jnp.float32
@@ -239,6 +286,7 @@ def _kernel(
         return m_new, l, acc, 1 - i_buf
 
     # wave 0 holds position 0, which every query sees: m is real after it
+    # (under a selection: after the first wave that holds a chosen position)
     m, l, acc, _ = jax.lax.fori_loop(
         0, n_waves, wave,
         (
@@ -257,10 +305,32 @@ def _kernel(
     l_ref[0] = l
 
 
+def _kernel_selected(tables_ref, ctx_ref, nblk_ref, next_ref, buf_ref, layer_ref, q_ref, chosen_ref, *rest, **static):
+    """:func:`_kernel` under a selection: ONE more operand behind the
+    queries, laid out as the scores of a wave, VMEM ``[1, waves, T * C, P *
+    R]`` float32 (0 / 1): what query ``c`` chose of the wave's tokens."""
+    _kernel(tables_ref, ctx_ref, nblk_ref, next_ref, buf_ref, layer_ref, q_ref, *rest, chosen_ref=chosen_ref, **static)
+
+
+def slot_waves(block_tables, ctx_len, block_size: int, wave_blocks: int):
+    """What a kernel over the slots' live blocks (:func:`wave_copies`) takes
+    by scalar prefetch beside the table: ``(live blocks a slot [B], the first
+    slot >= i that has any [B] (B: none), the buffer a slot's first wave lands
+    in [B])``. Block 0 is the null block: a table that starts on it is a
+    padding slot's, and reads nothing."""
+    B, M = block_tables.shape
+    nblk = jnp.where(block_tables[:, 0] == 0, 0, jnp.minimum(-(-ctx_len // block_size), M))
+    slots = jnp.arange(B, dtype=jnp.int32)
+    first_live_from = jax.lax.cummin(jnp.where(nblk > 0, slots, B), reverse=True)
+    waves = -(-nblk // wave_blocks)
+    first_buf = (jnp.cumsum(waves) - waves) % 2
+    return nblk, first_live_from, first_buf
+
+
 @functools.partial(
     jax.jit, static_argnames=("kv_lora_rank", "scale", "wave_blocks", "interpret")
 )
-def _call(q_row, cache, layer, block_tables, ctx_len, *, kv_lora_rank, scale, wave_blocks, interpret):
+def _call(q_row, cache, layer, block_tables, ctx_len, chosen=None, *, kv_lora_rank, scale, wave_blocks, interpret):
     # imported here, as ops/paged_attention.py does: a second of import that
     # only a process which runs the kernel pays
     from jax.experimental import pallas as pl
@@ -271,24 +341,33 @@ def _call(q_row, cache, layer, block_tables, ctx_len, *, kv_lora_rank, scale, wa
     T, kr = TW // W, kv_lora_rank
     bs, CH = R * T, C * H
     M, P = block_tables.shape[1], wave_blocks
-    # block 0 is the null block: a table that starts on it is a padding slot's
-    nblk = jnp.where(block_tables[:, 0] == 0, 0, jnp.minimum(-(-ctx_len // bs), M))
-    slots = jnp.arange(B, dtype=jnp.int32)
-    first_live_from = jax.lax.cummin(jnp.where(nblk > 0, slots, B), reverse=True)
-    waves = -(-nblk // P)
-    first_buf = (jnp.cumsum(waves) - waves) % 2
+    nblk, first_live_from, first_buf = slot_waves(block_tables, ctx_len, bs, P)
     # queries laid out T times: row (t, c, h) holds q[c, h] in lanes [t W, (t + 1) W)
     q = q_row.reshape(B, 1, CH, 1, W).astype(cache.dtype)
     eye = jnp.eye(T, dtype=cache.dtype).reshape(1, T, 1, T, 1)
     q = (q * eye).reshape(B, T * CH, TW)
     vmem = pltpu.VMEM
+    selection = ()
+    if chosen is not None:
+        # ``[B, C, M bs]`` laid out as a wave's scores, ``[B, waves, (t, c), P x R]``: token ``col T + t``
+        # of a wave goes to column ``col`` of row group ``t``. A stride-``T`` de-interleave of lanes, which as
+        # XLA's reshape + transpose was 0.57 ms a layer at a table of 32,768 (PERF.md, PR 62); as a product
+        # with the permutation it is the MXU's and a few microseconds (0 and 1 are exact in bfloat16)
+        waves, PR = -(-M // P), P * R
+        to = jnp.arange(T * PR, dtype=jnp.int32)
+        permutation = (jnp.arange(T * PR, dtype=jnp.int32)[:, None] == (to % PR * T + to // PR)[None, :])
+        laid = jnp.pad(chosen, ((0, 0), (0, 0), (0, (waves * P - M) * bs))).reshape(B * C * waves, T * PR)
+        laid = jnp.dot(laid.astype(jnp.bfloat16), permutation.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+        laid = laid.reshape(B, C, waves, T, PR).transpose(0, 2, 3, 1, 4).reshape(B, waves, T * C, PR)
+        selection = ((laid, pl.BlockSpec((1, waves, T * C, PR), lambda b, *_: (b, 0, 0, 0), memory_space=vmem)),)
     acc, m, l = pl.pallas_call(
-        functools.partial(_kernel, table_width=M, row_width=W, scale=scale),
+        functools.partial(_kernel if chosen is None else _kernel_selected, table_width=M, row_width=W, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, T * CH, TW), lambda b, *_: (b, 0, 0), memory_space=vmem),
+                *(spec for _, spec in selection),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
@@ -307,17 +386,17 @@ def _call(q_row, cache, layer, block_tables, ctx_len, *, kv_lora_rank, scale, wa
             jax.ShapeDtypeStruct((B, CH, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        name="latent_rows",
+        name="latent_rows" if chosen is None else "latent_rows_selected",
         interpret=pltpu.InterpretParams() if interpret else False,
     )(
         block_tables.reshape(-1), ctx_len, nblk, jnp.append(first_live_from, B), first_buf,
-        layer.reshape(1), q, cache,
+        layer.reshape(1), q, *(laid for laid, _ in selection), cache,
     )
     return acc.reshape(B, C, H, kr), m.reshape(B, C, H), l.reshape(B, C, H)
 
 
 def attend_paged(
-    q_row, cache, layer, block_tables, ctx_len, *, kv_lora_rank, scale, wave_blocks=None,
+    q_row, cache, layer, block_tables, ctx_len, *, kv_lora_rank, scale, chosen=None, wave_blocks=None,
     interpret=None,
 ):
     """Absorbed queries ``q_row [B, C, H, W]`` over each slot's CACHED
@@ -333,6 +412,13 @@ def attend_paged(
     ctx_len / block_size), M)`` blocks and no other; a padding slot
     (``block_tables[b, 0] == 0``) reads none.
 
+    ``chosen [B, C, M x block_size]`` bool, of a model that selects: query
+    ``(b, c)``, every head of it, sees of those positions the ones it chose
+    and no other. The blocks read are the same (the selection is a mask on the
+    scores, laid out as the kernel lays them); a query that chose nothing
+    cached comes back as ``(0, -1e30, 0)``. Absent, the kernel has no such
+    operand and is the program it was.
+
     ``wave_blocks``: blocks a DMA wave (default: what keeps a wave's float32
     scores at ``_WAVE_SCORES``). ``interpret``: run the kernel in Pallas' TPU
     interpreter (what the CPU tests do); by default wherever the backend is
@@ -345,7 +431,7 @@ def attend_paged(
     if wave_blocks is None:
         wave_blocks = max(1, _WAVE_SCORES // ((TW // W) * C * H * R))
     return _call(
-        q_row, cache, jnp.asarray(layer, jnp.int32), block_tables, ctx_len,
+        q_row, cache, jnp.asarray(layer, jnp.int32), block_tables, ctx_len, chosen,
         kv_lora_rank=kv_lora_rank, scale=float(scale), wave_blocks=min(M, wave_blocks),
         interpret=bool(interpret),
     )

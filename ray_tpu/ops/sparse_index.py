@@ -20,7 +20,11 @@ Three functions, all plain ``jax.numpy`` (XLA carries them on every backend):
   ``jax.lax.top_k`` over ``[1024, 32768]`` is a sort of every row on a TPU and
   ``approx_max_k`` is another model.
 * :func:`mask_positions`: the chosen positions of a row in ascending order,
-  for the paths that gather rows by token (a decode or verify window).
+  for the path that gathers rows by token (a decode or verify window wherever
+  the paged kernels do not serve: ``models/latent.py::sparse_paged_serves``).
+
+``ops/index_paged.py`` is :func:`index_scores` of a short window with the
+cached keys read by a Pallas kernel from each slot's live blocks.
 """
 
 from __future__ import annotations

@@ -3,7 +3,12 @@ selection inside every attention and a second cached row a token) against the
 plain reference of its family, ``perfbench/families/glm_moe_dsa/reference.py``,
 on the CPU at a small size: float32 against float32, seeded weights, contexts on
 both sides of a toy ``index_topk``. And the model on the engine's normal path:
-the drafter's stream against plain decode, the counters of the selection."""
+the drafter's stream against plain decode, the counters of the selection.
+
+The decode / verify window's way on a TPU (both paged kernels, the selection a
+mask: ``latent.sparse_paged_serves``) is reached through the ``tiled`` fixture:
+the toy at widths of whole tiles, the predicate answered as a TPU answers it,
+the kernels in Pallas' interpreter."""
 
 import dataclasses
 import math
@@ -57,6 +62,41 @@ def tokens():
     return np.random.default_rng(11).integers(1, 256, size=(2, 72)).astype(np.int32)
 
 
+@pytest.fixture(scope="module")
+def tiled(model):
+    """The toy at widths the paged kernels serve (a latent row of 128 + 64:
+    two a stored row of 384 lanes; an index key of 128; 4 indexer heads; blocks
+    of 16), at which a window of two ABSORBS and a chunk of 40 expands, as the
+    published widths have it: ``(model, cfg, params, block_size)``."""
+    wide = dict(model, kv_lora_rank=128, qk_rope_head_dim=64, qk_head_dim=80, head_dim=64,
+                index_head_dim=128, index_n_heads=4)
+    cfg = families.of(wide).model_config(wide, max_seq_len=wide["max_position_embeddings"])
+    assert latent.absorbs(cfg, 2) and not latent.absorbs(cfg, 40)
+    cache = MODEL.cache_layout(cfg, 16).init(2)
+    assert latent.sparse_paged_serves(cfg, 2, cache, backend="tpu")
+    return wide, cfg, MODEL.init_params(cfg, jax.random.PRNGKey(5)), 16
+
+
+def _as_on_a_tpu(monkeypatch):
+    """``latent.sparse_paged_serves`` answers what it observes of the widths
+    and the cache, for a TPU whoever asks."""
+    asks = latent.sparse_paged_serves
+    monkeypatch.setattr(latent, "sparse_paged_serves", lambda cfg, window, cache, backend=None: asks(cfg, window, cache, "tpu"))
+
+
+def _doors(request, monkeypatch, door, by_token):
+    """``(model, cfg, params, block_size)`` of a step test's case: the toy as
+    it is (``by_token``: what the CPU runs), or ``tiled`` with the window's
+    predicate answered as on a TPU and every kernel call counted."""
+    if door == "by_token":
+        return (*by_token, BS), None
+    calls = []
+    _as_on_a_tpu(monkeypatch)
+    real = latent.index_paged.index_scores
+    monkeypatch.setattr(latent.index_paged, "index_scores", lambda *a, **kw: calls.append(a[0].shape) or real(*a, **kw))
+    return request.getfixturevalue("tiled"), calls
+
+
 def _rel(have, want):
     return float(np.max(np.abs(np.asarray(have) - np.asarray(want))) / np.max(np.abs(np.asarray(want))))
 
@@ -108,25 +148,35 @@ def _prefill(cfg, params, cache, row, table, chunks, bucket=40):
     return cache, np.asarray(logits)
 
 
-@pytest.mark.parametrize("chunks", [(12,), (37,), (13, 24), (16, 16, 16, 5), (40, 19)], ids=lambda c: "+".join(map(str, c)))
-def test_chunked_prefill_then_steps_match_the_reference_main_logits_and_the_modules(model, cfg, params, tokens, chunks):
+@pytest.mark.parametrize(
+    "chunks, door",
+    [((12,), "by_token"), ((37,), "by_token"), ((13, 24), "by_token"), ((16, 16, 16, 5), "by_token"), ((40, 19), "by_token"),
+     ((12,), "paged_kernels"), ((40, 19), "paged_kernels")],
+    ids=lambda v: v if isinstance(v, str) else "+".join(map(str, v)),
+)
+def test_chunked_prefill_then_steps_match_the_reference_main_logits_and_the_modules(
+    request, monkeypatch, model, cfg, params, tokens, chunks, door,
+):
     """Chunks whose edges split a block of 8 and straddle ``index_topk`` (24),
     then the step of a slot without a draft and windows of two with the
     sequence's own next token as the draft, through BOTH cached rows: the main
     model's logits at both rows of each window and the module's through ITS
     rows, against the reference's full forward pass. A prompt of 12 stays under
     ``index_topk`` through its steps; the others run past it. Slot 1 of a
-    bucket of 4; the others pad."""
+    bucket of 4; the others pad. ``paged_kernels``: the windows through the two
+    paged kernels (a window's scores from the slot's live blocks, its
+    attention under the selection as a mask), the chunks as the CPU runs them."""
+    (model, cfg, params, bs), calls = _doors(request, monkeypatch, door, (model, cfg, params))
     n = sum(chunks)
-    table = np.zeros(16, np.int32)
-    table[:10] = np.arange(1, 11)
-    cache = MODEL.cache_layout(cfg, BS).init(16)
+    table = np.zeros(128 // bs, np.int32)
+    table[: 80 // bs] = np.arange(1, 80 // bs + 1)
+    cache = MODEL.cache_layout(cfg, bs).init(16)
     assert cache["latent"].shape[0] == cache["index"].shape[0] == cfg.n_layers + 1
     cache, got_prefill = _prefill(cfg, params, cache, tokens[0], table, chunks)
     drafter = MODEL.drafter(cfg)
     verify = jax.jit(lambda p, c, *a: drafter.verify(cfg, p, c, *a), donate_argnums=(1,))
     draft = jax.jit(lambda p, c, *a: drafter.draft(cfg, p, c, *a), donate_argnums=(1,))
-    tables = np.zeros((4, 16), np.int32)
+    tables = np.zeros((4, len(table)), np.int32)
     tables[1] = table
     main, module = [(n - 1, got_prefill)], []
     at, first = n - 1, True
@@ -152,6 +202,10 @@ def test_chunked_prefill_then_steps_match_the_reference_main_logits_and_the_modu
         assert _rel(have, want) < TOL, ("main", p)
     for (p, have), want in zip(module, want_module):
         assert _rel(have, want) < TOL, ("mtp", p)
+    if calls is not None:
+        # a kernel a layer body of each traced program: 1 dense + the scanned expert layers' in ``verify``, the
+        # module's in ``draft``, each over the bucket's 4 slots x 2
+        assert len(calls) == 3 and set(calls) == {(4, 2, cfg.index_n_heads, cfg.index_head_dim)}
 
 
 def test_a_chunk_takes_the_first_rung_that_holds_it_and_every_rung_agrees(cfg, params, tokens):
@@ -238,20 +292,24 @@ def test_a_chunk_through_the_selecting_kernel_is_the_masked_softmax_at_every_run
         np.testing.assert_allclose(rows(have_cache), rows(want_cache), atol=1e-5)
 
 
-def test_the_one_program_step_equals_its_two_program_form(cfg, params, tokens):
+@pytest.mark.parametrize("door", ["by_token", "paged_kernels"])
+def test_the_one_program_step_equals_its_two_program_form(request, monkeypatch, model, cfg, params, tokens, door):
     """``paged_mtp_step`` on a slot with an accepted draft, one with a wrong
     one, one without and a padding slot, at a context past ``index_topk``,
     against verify + argmax + draft a slot at a time: the tokens, what was
-    accepted, the next drafts."""
+    accepted, the next drafts. ``paged_kernels``: the bucket's windows of two
+    through the paged kernels against the lone slot's (2 x 4 heads are whole
+    sublanes; a window of one is not, and keeps the by-token way)."""
+    (_, cfg, params, bs), calls = _doors(request, monkeypatch, door, (model, cfg, params))
     n = 45
     drafter = MODEL.drafter(cfg)
     verify = jax.jit(lambda p, c, *a: drafter.verify(cfg, p, c, *a))
     draft = jax.jit(lambda p, c, *a: drafter.draft(cfg, p, c, *a))
     step = jax.jit(lambda p, c, *a: drafter.step(cfg, p, c, *a))
-    base = MODEL.cache_layout(cfg, BS).init(40)
-    tables = np.zeros((4, 16), np.int32)
+    base = MODEL.cache_layout(cfg, bs).init(40)
+    tables = np.zeros((4, 128 // bs), np.int32)
     for slot in range(3):
-        tables[slot, :8] = np.arange(1, 9) + 8 * slot
+        tables[slot, : 64 // bs] = np.arange(1, 64 // bs + 1) + 64 // bs * slot
         base, _ = _prefill(cfg, params, base, tokens[0], tables[slot], (40, 5))
     one = lambda v: np.asarray([v], np.int32)  # noqa: E731
 
@@ -282,6 +340,7 @@ def test_the_one_program_step_equals_its_two_program_form(cfg, params, tokens):
     assert drafts[1] == drafts[2] == d_after
     # main rows 2 x 5 real rows x top_k x expert layers, + the module's 1 + accepted rows a slot
     assert int(counters["load"].sum()) == cfg.moe_top_k * (5 * cfg.n_moe_layers + 4)
+    assert calls is None or {c[:2] for c in calls} == {(4, 2), (1, 2)}
 
 
 def test_sixteen_shares_of_held_experts_sum_to_the_uncut_layer():
@@ -368,3 +427,38 @@ def test_the_runner_counts_a_selection_from_its_own_lengths(cfg, params):
     assert runner.sparse_attention["queries_past_topk"] == 8
     assert runner.sparse_attention["chosen"] == 136 + sum(range(17, 25)) + 8 * 24
     assert runner.sparse_attention["live"] == sum(range(1, 33))
+
+
+def test_a_window_on_a_tpu_reads_its_slots_live_blocks_and_the_runner_counts_them(monkeypatch, tiled):
+    """``_attention_path`` at the published widths for a described TPU: a
+    decode or verify window reads each slot's live blocks through the paged
+    kernels, a chunk the key tiles up to its end through the selecting flash
+    kernel; off the chip, and at widths the kernels refuse, the table. And the
+    runner's ``decode_width`` counts a launch on that path from the slots'
+    own lengths: whole blocks of the contexts, nothing for a padding slot."""
+    from ray_tpu.inference.model_runner import PagedModelRunner
+    from ray_tpu.models.interface import AttentionPath
+
+    full = glm_dsa.GlmDsaConfig(dtype=jnp.bfloat16, max_seq_len=32768)
+    cache = jax.eval_shape(lambda: MODEL.cache_layout(full, 16).init(64))
+    assert cache["latent"].shape[2:] == (8, 1152) and cache["index"].shape[2:] == (16, 128)
+    for window in (1, 2):
+        assert glm_dsa._attention_path(full, window, cache, backend="tpu") == AttentionPath("latent.sparse_paged", "blocks")
+        assert glm_dsa._attention_path(full, window, cache, backend="cpu") == AttentionPath("latent.sparse", "table")
+    assert glm_dsa._attention_path(full, 8, cache, backend="tpu") == AttentionPath("latent.sparse", "table")  # 512 query rows
+    assert glm_dsa._attention_path(full, 1024, cache, backend="tpu") == AttentionPath("latent.sparse_flash", "live")
+    odd = jax.eval_shape(lambda: MODEL.cache_layout(full, 8).init(64))  # blocks of 8: no whole tile
+    assert glm_dsa._attention_path(full, 2, odd, backend="tpu") == AttentionPath("latent.sparse", "table")
+
+    _, cfg, params, bs = tiled
+    _as_on_a_tpu(monkeypatch)
+    runner = PagedModelRunner(cfg, params, num_blocks=32, block_size=bs, prefill_buckets=(16,), decode_buckets=(4,),
+                              verify_buckets=(2,))
+    assert runner.attention_paths[2] == AttentionPath("latent.sparse_paged", "blocks")
+    assert runner.attention_paths[1] == AttentionPath("latent.sparse", "table")  # 4 query rows: no whole sublanes
+    runner._count_width([37, 70, 16], bucket=4, window=2)
+    width = runner.max_blocks_per_seq * bs
+    assert runner.decode_width == {"launches": 1, "width_tokens": width, "needed_tokens": 70,
+                                   "live_tokens": 37 + 70 + 16, "gathered_tokens": (3 + 5 + 1) * bs}
+    runner._count_width([37, 70, 16], bucket=4, window=1)  # the table, for every slot of the bucket
+    assert runner.decode_width["gathered_tokens"] == (3 + 5 + 1) * bs + 4 * width

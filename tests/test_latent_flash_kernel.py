@@ -308,20 +308,25 @@ def test_a_selecting_models_attention_path_answers_both_ways(monkeypatch):
     """GLM-5's chunk of 1024 on a TPU takes the kernel and says so
     (``latent.sparse_flash``, read up to its live key tiles); on the CPU, and
     at the toy widths anywhere, the materialised softmax under the mask; a
-    decode or verify window gathers by token either way. A model that does
+    decode or verify window reads its slots' live blocks through the paged
+    kernels on a TPU (PR 62) and gathers by token off it. A model that does
     not select is never asked."""
     cfg = glm_dsa.GlmDsaConfig(dtype=jnp.bfloat16, max_seq_len=32768)
-    cache = {"latent": jax.ShapeDtypeStruct((7, 8, 8, 2 * 576), jnp.bfloat16)}
+    cache = {"latent": jax.ShapeDtypeStruct((7, 8, 8, 2 * 576), jnp.bfloat16),
+             "index": jax.ShapeDtypeStruct((7, 8, 16, 128), jnp.bfloat16)}
     path = glm_dsa.MODEL.attention_path
     assert path(cfg, 1024, cache, backend="tpu") == ("latent.sparse_flash", "live")
     assert path(cfg, 1024, cache, backend="cpu") == ("latent.sparse_masked", "table")
     assert path(cfg, 1024, None) == ("latent.sparse_masked", "table")  # the CPU never looks at the cache
-    assert path(cfg, 2, cache, backend="tpu") == path(cfg, 2, cache, backend="cpu") == ("latent.sparse", "table")
+    assert path(cfg, 2, cache, backend="tpu") == ("latent.sparse_paged", "blocks")
+    assert path(cfg, 2, cache, backend="cpu") == path(cfg, 2, None) == ("latent.sparse", "table")
     assert glm_dsa.MODEL.key_tile(cfg, 1024, cache) == 1024
     toy = glm_dsa.GlmDsaConfig.tiny(kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16)
-    toy_cache = {"latent": jax.ShapeDtypeStruct((4, 8, 8 * 36), jnp.float32)}
+    toy_cache = {"latent": jax.ShapeDtypeStruct((4, 8, 8 * 36), jnp.float32),
+                 "index": jax.ShapeDtypeStruct((4, 8, 8 * 16), jnp.float32)}
     assert not latent.absorbs(toy, 40)
     assert path(toy, 40, toy_cache, backend="tpu") == ("latent.sparse_masked", "table")
+    assert path(toy, 2, toy_cache, backend="tpu") == ("latent.sparse", "table")  # a block one row: no kernel
     assert not latent.selected_serves(xing4.Xing4Config(dtype=jnp.bfloat16), 1024, _latent_cache(xing4.Xing4Config.tiny()), backend="tpu")
     # what the runner counts as expanded: the key tiles up to the chunk's end where the kernel expands them, the rungs elsewhere
     assert glm_dsa.MODEL.gather_rungs(cfg, 1024, cache)[:3] == (4096, 8192, 12288)

@@ -10,7 +10,14 @@ nobody wrote, so a wave's unfetched blocks are poison too. One read past the
 mask and the output is not finite. Block tables are a shuffle of the pool;
 a padding slot (its table on the null block) sits BETWEEN the real ones and
 two more end the batch: the kernel reads nothing for them and the door
-returns zeros."""
+returns zeros.
+
+Under a SELECTION (a model that chooses ``index_topk`` positions a query) the
+same kernel takes the chosen positions as a mask: held to the materialised
+softmax under that mask (``latent.attend_masked``, the window's own rows laid
+in), to ``attend_rows`` where the window's own are all chosen, and, the whole
+window of ``latent._sparse_attention`` through both paged kernels, to the
+by-token path (``mask_positions`` + ``_token_rows``) it stands in for."""
 
 import types
 
@@ -19,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import kimi_linear, latent, xing4
+from ray_tpu.models import glm_dsa, kimi_linear, latent, xing4
 from ray_tpu.models.interface import CacheLayout
 from ray_tpu.ops import latent_paged as LP
 
@@ -146,6 +153,184 @@ def test_the_windows_own_rows_are_under_the_same_softmax():
     some = real & (np.asarray(first) > 0)
     without = np.asarray(acc / l[..., None])[some]
     assert np.abs(without - want[np.asarray(first)[real] > 0]).max() > 1e-2
+
+
+# -- under a selection: the chosen positions as a mask --------------------------------------------
+
+def _chosen(first, window, seed, share=0.3):
+    """A selection as ``select_mask`` leaves one: causal (``j <= first + c``),
+    a random ``share`` of the rest; ``[B, window, FULL]`` bool numpy."""
+    rng = np.random.default_rng(seed)
+    pos = np.asarray(first)[:, None] + np.arange(window)[None]
+    return (rng.random((len(pos), window, FULL)) < share) & (np.arange(FULL)[None, None] <= pos[..., None])
+
+
+def _want_masked(cfg, q_row, own, cache, tables, first, chosen, layer=LAYER):
+    """The materialised softmax under the mask, a slot at a time: the slot's
+    gathered context with the window's own rows laid in where they will be
+    written (``latent.attend_masked``: what a selecting chunk runs on the CPU)."""
+    L, N, *block = cache["latent"].shape
+    W, C = cfg.latent_width, q_row.shape[1]
+    out = []
+    for b in range(tables.shape[0]):
+        rows = cache["latent"].reshape(L * N, *block)[layer * N + tables[b]].reshape(-1, W)
+        at = min(int(first[b]), FULL - C)  # a padding slot: anywhere
+        rows = jax.lax.dynamic_update_slice(rows, own[b], (at, 0))
+        out.append(latent.attend_masked(cfg, q_row[b], rows, jnp.asarray(chosen[b])))
+    return jnp.stack(out)
+
+
+@pytest.mark.parametrize("wave_blocks", [1, 2, 4, None], ids=["a_block_a_wave", "2_blocks", "4_blocks", "the_default_wave"])
+@pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify_window_of_4"])
+@pytest.mark.parametrize("widths", list(WIDTHS))
+def test_the_kernel_under_a_selection_is_the_masked_softmax(widths, window, wave_blocks):
+    """A third of the causal positions chosen, the window's own among them
+    or not as the draw has it; one query's first 40 positions left out
+    whole, so that its first wave (of 1 or 2 blocks) holds nothing it chose
+    and the softmax's state must wait for a wave that does. A query that chose
+    nothing at all (the draw leaves a few at short contexts) is nobody's."""
+    cfg, q_row, own, clean, poisoned, tables, first, real = _case(widths, window, CONTEXTS["ragged"], seed=3)
+    chosen = _chosen(first, window, seed=1)
+    chosen[2, 0, :40] = False  # slot 2 holds FULL - 4 positions
+    want = np.asarray(_want_masked(cfg, q_row, own, clean, tables, first, chosen))
+    have = np.asarray(latent.attend_paged(
+        cfg, q_row, poisoned, LAYER, tables, first, own, chosen=jnp.asarray(chosen), interpret=True,
+    ) if wave_blocks is None else _folded(cfg, q_row, poisoned, tables, first, own, chosen, wave_blocks))
+    some = real[:, None] & chosen.any(-1)
+    assert np.isfinite(have).all() and (have[~real] == 0).all() and some[2, 0]
+    np.testing.assert_allclose(have[some], want[some], rtol=2e-5, atol=2e-5)
+
+
+def _folded(cfg, q_row, cache, tables, first, own, chosen, wave_blocks):
+    """``latent.attend_paged`` at a wave size of the test's choosing."""
+    real_call = LP.attend_paged
+    try:
+        LP.attend_paged = lambda *a, **kw: real_call(*a, **{**kw, "wave_blocks": wave_blocks})
+        return latent.attend_paged(cfg, q_row, cache, LAYER, tables, first, own, chosen=jnp.asarray(chosen), interpret=True)
+    finally:
+        LP.attend_paged = real_call
+
+
+@pytest.mark.parametrize("own_chosen", [True, False], ids=["own_chosen", "own_not_chosen"])
+def test_an_own_position_is_under_the_same_softmax_only_if_chosen(own_chosen):
+    """The window's own positions all chosen: ``attend_rows`` under the
+    cached part of the mask (its ``within`` is the window's causal triangle).
+    None of them chosen: the kernel's state alone, normalised. The two differ."""
+    cfg, q_row, own, clean, poisoned, tables, first, real = _case("two_a_row", 4, CONTEXTS["ragged"], seed=5)
+    some = real & (np.asarray(first) > 0)  # a window at the start of its sequence has only its own rows
+    chosen = _chosen(first, 4, seed=2, share=0.5)
+    chosen[:, :, 0] = np.asarray(first)[:, None] > 0  # every query of a slot with a context chose something cached
+    for c in range(4):
+        chosen[np.arange(len(real)), :, np.minimum(np.asarray(first) + c, FULL - 1)] = own_chosen
+    chosen &= np.arange(FULL)[None, None] <= (np.asarray(first)[:, None] + np.arange(4)[None])[..., None]
+    cached = jnp.asarray(chosen) & (jnp.arange(FULL)[None, None, :] < first[:, None, None])
+    have = np.asarray(latent.attend_paged(cfg, q_row, poisoned, LAYER, tables, first, own, chosen=jnp.asarray(chosen), interpret=True))
+    L, N, *block = clean["latent"].shape
+    rows = clean["latent"].reshape(L * N, *block)[LAYER * N + tables].reshape(tables.shape[0], -1, cfg.latent_width)
+    with_own = np.asarray(latent.attend_rows(cfg, q_row, rows, cached, own))
+    acc, _, l = LP.attend_paged(
+        q_row, clean["latent"], LAYER, tables, first, kv_lora_rank=cfg.kv_lora_rank, scale=cfg.attn_scale,
+        chosen=cached, interpret=True,
+    )
+    without = np.asarray(acc / l[..., None])
+    want, other = (with_own, without) if own_chosen else (without, with_own)
+    assert np.isfinite(have).all()
+    np.testing.assert_allclose(have[some], want[some], rtol=2e-5, atol=2e-5)
+    assert np.abs(have[some] - other[some]).max() > 1e-2
+
+
+def test_a_selection_of_everything_is_the_kernel_without_a_mask():
+    """A context under ``index_topk``: every causal position is chosen, and
+    the answer is ``attend_paged``'s without the operand, to the last bit."""
+    cfg, q_row, own, _, poisoned, tables, first, real = _case("two_a_row", 4, CONTEXTS["ragged"], seed=6)
+    everything = jnp.asarray(_chosen(first, 4, seed=0, share=2.0))
+    have = latent.attend_paged(cfg, q_row, poisoned, LAYER, tables, first, own, chosen=everything, interpret=True)
+    want = latent.attend_paged(cfg, q_row, poisoned, LAYER, tables, first, own, interpret=True)
+    np.testing.assert_array_equal(np.asarray(have), np.asarray(want))
+
+
+def _tiled_selecting_case(seed, contexts, dtype=jnp.float32):
+    """GLM-5's block at toy widths of whole tiles (a latent row of 128 + 64,
+    two a stored row; an index key of 128; 4 heads, 4 indexer heads, the top
+    24 of a table of 96) with a decode window of two over ``contexts`` (None:
+    a padding slot): everything ``latent._sparse_attention`` takes, a clean
+    cache and one poisoned past every slot's context."""
+    cfg = glm_dsa.GlmDsaConfig.tiny(
+        dtype=dtype, max_seq_len=FULL, kv_lora_rank=128, qk_rope_head_dim=64, index_head_dim=128,
+        index_n_heads=4, index_topk=24,
+    )
+    rng = np.random.default_rng(seed)
+    B, C, N = len(contexts), 2, 1 + len(contexts) * M
+    layout = latent.cache_layout(cfg, BS, dtype, n_layers=LAYERS)
+    clean = {name: rng.standard_normal(a.shape).astype(np.float32) for name, a in layout.init(N).items()}
+    assert clean["latent"].shape[2:] == (8, 384) and clean["index"].shape[2:] == (16, 128)
+    tables, first, live = np.zeros((B, M), np.int32), np.zeros(B, np.int32), np.zeros((N, BS), bool)
+    shuffled = rng.permutation(np.arange(1, N))
+    for b, ctx in enumerate(contexts):
+        if ctx is None:
+            continue
+        tables[b], first[b] = shuffled[b * M : (b + 1) * M], ctx
+        for p in range(ctx):
+            live[tables[b, p // BS], p % BS] = True
+    poisoned = {}
+    for name, a in clean.items():
+        rows = a.reshape(LAYERS, N, BS, -1).copy()
+        rows[:, ~live] = np.nan
+        poisoned[name] = jnp.asarray(rows.reshape(a.shape), dtype)
+    clean = {name: jnp.asarray(a, dtype) for name, a in clean.items()}
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)  # noqa: E731
+    H, Hi, di, W = cfg.n_heads, cfg.index_n_heads, cfg.index_head_dim, cfg.latent_width
+    p = {"w_kvb": f(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim + cfg.v_head_dim) / 11.0}
+    window = dict(
+        q_nope=f(B, C, H, cfg.qk_nope_head_dim), q_rope=f(B, C, H, cfg.qk_rope_head_dim), row=f(B, C, W),
+        index=(f(B, C, Hi, di), f(B, C, di), jnp.asarray(rng.standard_normal((B, C, Hi)), jnp.float32) / 23.0),
+    )
+    pos = jnp.asarray(first)[:, None] + jnp.arange(C)[None]
+    real = np.asarray([c is not None for c in contexts])
+    return cfg, p, window, clean, poisoned, jnp.asarray(tables), pos, real
+
+
+@pytest.mark.parametrize(
+    "contexts",
+    [(37, None, 90, 16, 5, 0, None), (FULL - 2, 70)],
+    ids=["ragged_on_both_sides_of_the_topk", "the_table_less_the_window"],
+)
+def test_a_selecting_window_through_both_paged_kernels_is_the_by_token_path(monkeypatch, contexts):
+    """``latent._sparse_attention``'s decode / verify branch two ways: as the
+    CPU runs it (the index keys at the table's width, ``mask_positions``,
+    ``_token_rows``: over the CLEAN cache, it reads the table) and as a TPU
+    does (``sparse_paged_serves`` answered as there; both kernels in Pallas'
+    interpreter over the POISONED cache). The same output and the same blocks
+    for the write; contexts under the top 24 (everything chosen) and past it."""
+    cfg, p, w, clean, poisoned, tables, pos, real = _tiled_selecting_case(7, contexts)
+    true_lens = jnp.full((len(contexts),), 2, jnp.int32)
+
+    def run(cache):
+        return latent._sparse_attention(
+            cfg, p, w["q_nope"], w["q_rope"], w["row"], w["index"], cache, jnp.int32(LAYER), tables, pos, true_lens
+        )
+
+    want, want_blocks = run(clean)
+    calls = []
+    monkeypatch.setattr(latent, "sparse_paged_serves", lambda cfg, window, cache, backend=None: True)
+    real_scores, real_attend = latent.index_paged.index_scores, latent.latent_paged.attend_paged
+    monkeypatch.setattr(latent.index_paged, "index_scores", lambda *a, **kw: calls.append("index") or real_scores(*a, **kw))
+    monkeypatch.setattr(
+        latent.latent_paged, "attend_paged",
+        lambda *a, **kw: calls.append("rows" if kw.get("chosen") is not None else "unmasked") or real_attend(*a, **kw),
+    )
+    have, have_blocks = run(poisoned)
+    assert calls == ["index", "rows"]
+    have, want = np.asarray(have), np.asarray(want)
+    assert np.isfinite(have).all() and (have[~real] == 0).all()
+    np.testing.assert_allclose(have[real], want[real], rtol=3e-5, atol=3e-5)
+    # the window's blocks as the cache must hold them after the step: the old
+    # rows under the context, the window's own two; what lies past is nobody's
+    have_blocks, want_blocks = np.asarray(have_blocks), np.asarray(want_blocks)
+    assert (have_blocks[~real] == 0).all()
+    for b in np.flatnonzero(real):
+        n = int(pos[b, 0]) % BS + 2
+        np.testing.assert_array_equal(have_blocks[b, :n], want_blocks[b, :n])
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])

@@ -695,6 +695,54 @@ def test_the_latent_rows_kernel_compiles_for_the_chip_at_the_benchmark_widths(on
         jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
+@pytest.mark.parametrize("kernel", ["index_rows", "latent_rows_selected"])
+def test_a_selecting_windows_paged_kernels_compile_for_the_chip_at_glm5s_widths(one_chip, kernel):
+    """GLM-5's verify window (``dsa-longctx-batch``: 8 slots x 2 under a table
+    of 32,768, both arrays of the cell's pool of 12,288 blocks as the layout
+    stores them, 7 rows a token): the indexer's scores from the slots' live
+    blocks (``ops/index_paged.py``: 32 heads of 128, a block ``[16, 128]``) and
+    ``ops/latent_paged.py`` with the selection as ONE more operand (64 heads,
+    256 laid-out query rows against ``[8, 1152]`` blocks). Here for the same
+    reason as the ones above: Mosaic refuses shapes the interpreter takes."""
+    from ray_tpu.models import glm_dsa, latent
+    from ray_tpu.ops import index_paged as IP, latent_paged as LP
+
+    cfg = glm_dsa.GlmDsaConfig(dtype=jnp.bfloat16, max_seq_len=32768)
+    cache_like = jax.eval_shape(lambda: latent.cache_layout(cfg, 16, n_layers=7).init(12289))
+    assert latent.sparse_paged_serves(cfg, 2, cache_like, backend="tpu")
+    B, C, M = 8, 2, 2048
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        tables, ctx = shape((B, M), jnp.int32), shape((B,), jnp.int32)
+        if kernel == "index_rows":
+            compiled = jax.jit(
+                lambda q, w, own, cache, tables, ctx: IP.index_scores(q, w, own, cache, 6, tables, ctx, interpret=False)
+            ).lower(
+                shape((B, C, 32, 128), jnp.bfloat16), shape((B, C, 32), jnp.float32), shape((B, C, C), jnp.float32),
+                shape(cache_like["index"].shape, jnp.bfloat16), tables, ctx,
+            ).compile()
+            assert (compiled.out_info.shape, compiled.out_info.dtype) == ((B, C, M * 16), jnp.float32)
+            room = 2 * B * C * M * 16 * 4  # the scores a wave at a time, and as the caller takes them
+        else:
+            compiled = jax.jit(
+                lambda q, cache, tables, ctx, chosen: LP.attend_paged(
+                    q, cache, 6, tables, ctx, kv_lora_rank=512, scale=0.07, chosen=chosen, interpret=False
+                )
+            ).lower(
+                shape((B, C, 64, 576), jnp.bfloat16), shape(cache_like["latent"].shape, jnp.bfloat16), tables, ctx,
+                shape((B, C, M * 16), jnp.bool_),
+            ).compile()
+            assert [o.shape for o in compiled.out_info] == [(B, C, 64, 512)] + [(B, C, 64)] * 2
+            room = 2 * B * 2 * C * 64 * 1152 * 2 + 2 * B * C * M * 16 * 4  # the queries laid out twice, the mask as int32
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and kernel in text
+        assert compiled.memory_analysis().temp_size_in_bytes < room + 2**20
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
 @pytest.mark.parametrize("group, blocks, keeps", [("full", 17408, 0), ("window", 4480, 1024)],
                          ids=["mellum2_full_layers", "mellum2_window_layers"])
 def test_the_paged_attention_kernel_compiles_over_a_flat_cache_of_four_kv_heads(one_chip, group, blocks, keeps):
