@@ -2800,8 +2800,12 @@ class InferenceEngine:
             ),
             # a model with recurrent layers: what a SEQUENCE holds beside
             # its rows, and the pool of slots it is held in (None / zeros else)
+            # (``stored_bytes_per_seq``: what the pool's layout really holds a
+            # sequence on the device, ``bytes_per_seq`` where nothing is padded)
             "state_layout": (
-                self.runner.state_layout.describe() if self.runner.state_layout else None
+                {**self.runner.state_layout.describe(),
+                 "stored_bytes_per_seq": self.runner.state_layout.stored_bytes_per_seq}
+                if self.runner.state_layout else None
             ),
             "state_pool": self.blocks.slot_stats(),
             # a pool a layer group: blocks, in use now and at the peak, taken,

@@ -202,7 +202,7 @@ class StateLayout:
     """What one sequence holds in the recurrent layers of a model, beside
     its rows in the paged cache: fixed-size arrays a sequence a layer."""
 
-    #: the recurrence's name (``"kda"``: the gated delta rule a channel)
+    #: the recurrence's name (``"kda"``: the gated delta rule a channel; ``"gdn"``: a head)
     kind: str
     #: the layers that recur (each holds every array below)
     n_layers: int
@@ -216,6 +216,29 @@ class StateLayout:
         return self.n_layers * sum(
             math.prod(shape) * np.dtype(dtype).itemsize for _, shape, dtype in self.arrays
         )
+
+    @property
+    def stored_bytes_per_seq(self) -> int:
+        """What the pool's layout really HOLDS a sequence on a device that
+        stores an array's last two dimensions in tiles of 8 sublanes (of 32 bits:
+        16 rows of bfloat16) x 128 lanes: :attr:`bytes_per_seq` where nothing is
+        padded. An array of one dimension a sequence lies with the slots on the
+        sublanes, so only its lanes pad (a state of ``[H, 96, 192]`` is stored as
+        ``[H, 96, 256]``, a third more; the same heads joined along the lanes,
+        ``[96, H x 192]``, as they are)."""
+        import numpy as np
+
+        total = 0
+        for _, shape, dtype in self.arrays:
+            item = np.dtype(dtype).itemsize
+            lanes = -(-shape[-1] // 128) * 128
+            if len(shape) == 1:
+                rows = 1
+            else:
+                sublanes = 8 * 4 // item
+                rows = math.prod(shape[:-2]) * (-(-shape[-2] // sublanes) * sublanes)
+            total += rows * lanes * item
+        return self.n_layers * total
 
     def init(self, num_slots: int) -> Dict[str, Any]:
         """The device-side pool: zeros, slot 0 reserved as the null slot."""
@@ -433,13 +456,14 @@ class Model:
 
 def model_of(cfg) -> Model:
     """The model a config object belongs to, by the config's type."""
-    from ray_tpu.models import deepseek_v3, glm_dsa, jamba, kimi_linear, lfm2, llama, xing4
+    from ray_tpu.models import deepseek_v3, glm_dsa, jamba, kimi_linear, lfm2, llama, olmo_hybrid, xing4
 
     models = {
         llama.LlamaConfig: llama.MODEL, xing4.Xing4Config: xing4.MODEL,
         kimi_linear.KimiLinearConfig: kimi_linear.MODEL,
         deepseek_v3.DeepseekV3Config: deepseek_v3.MODEL, lfm2.Lfm2Config: lfm2.MODEL,
         jamba.JambaConfig: jamba.MODEL, glm_dsa.GlmDsaConfig: glm_dsa.MODEL,
+        olmo_hybrid.OlmoHybridConfig: olmo_hybrid.MODEL,
     }
     try:
         return models[type(cfg)]

@@ -25,11 +25,16 @@ KDA (``H`` heads of ``dk = dv``)::
     o_t = S_t^T q_t
     out = W_o [ RMSNorm_head(o_t) * sigmoid(W_g_up W_g_down x_t) ]
 
-served two ways that are the same mathematics: a decode step applies the
-recurrence once (:func:`kda_update`; over a decode batch on a TPU the same
-four lines as ONE pass over the layer's slab of the pool, ``ops/kda.py``,
-chosen from the pool's shape and the backend); a prefill chunk runs the chunked (WY)
-form in sub-chunks of ``kda_chunk`` (:func:`kda_chunked`): with ``G_t`` the
+served two ways that are the same mathematics, both of ``ops/delta_rule.py``
+(the ONE place of the gated delta rule since PR 64: ``kda_update``,
+``kda_chunked``, ``_unit_lower_inverse`` and ``_l2_norm`` moved there from this
+module, which imports them; ``models/olmo_hybrid.py``'s Gated DeltaNet, the
+same rule with one gate a head, runs its sibling ``gdn_chunked``): a decode
+step applies the recurrence once (``kda_update``; over a decode batch on a TPU
+the same four lines as ONE pass over the layer's slab of the pool,
+``ops/kda.py``, whose kernel serves both models' states, chosen from the pool's
+shape and the backend); a prefill chunk runs the chunked (WY) form in
+sub-chunks of ``kda_chunk`` (``kda_chunked``): with ``G_t`` the
 running sum of ``g`` inside a sub-chunk and ``S`` the state before it,
 
     W = (I + Diag(beta) stril(A^kk))^-1 Diag(beta) (V - (K * e^G) S)
@@ -65,12 +70,14 @@ from ray_tpu.models import latent, paged_kv
 from ray_tpu.models.interface import AttentionPath, Model, StateLayout
 from ray_tpu.models.interface import lm_head, stack_aux, step_counters, step_outputs
 from ray_tpu.ops import kda, latent_flash, short_conv
+from ray_tpu.ops.delta_rule import _l2_norm, _unit_lower_inverse, kda_chunked, kda_update  # noqa: F401
+from ray_tpu.ops.delta_rule import rows_of_slots as _rows_of_slots
+from ray_tpu.ops.delta_rule import slot_state, write_slot_state
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.moe import DENSE_AXES, MOE_AXES, gated_mlp, routed_ffn
 from ray_tpu.parallel.sharding import constrain
 
 F32 = jnp.float32
-_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
@@ -310,10 +317,6 @@ def param_count(cfg: KimiLinearConfig) -> int:
 # the pieces of a layer
 
 
-def _l2_norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
 def _heads(x, heads: int):
     return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
 
@@ -349,110 +352,6 @@ def _kda_output(cfg: KimiLinearConfig, p, h, o):
         gate = jax.nn.sigmoid(((h @ p["kda_g_down"]) @ p["kda_g_up"]).astype(F32))
         y = (o * inv).astype(h.dtype) * p["kda_o_norm"] * _heads(gate, cfg.kda_heads).astype(h.dtype)
         return y.reshape(*y.shape[:2], -1) @ p["kda_wo"]
-
-
-def kda_update(S, q, k, v, g, beta):
-    """The recurrence, once: ``S [B, H, dk, dv]`` float32 and one token a
-    slot (``q, k, g [B, H, dk]``, ``v [B, H, dv]``, ``beta [B, H]``) ->
-    ``(S_t, o_t [B, H, dv])``. Sums on the vector unit in float32: nothing
-    of the state goes through a bfloat16 product."""
-    S = S * jnp.exp(g)[..., None]
-    u = jnp.sum(S * k[..., None], axis=-2)
-    S = S + (beta[..., None] * k)[..., None] * (v - u)[..., None, :]
-    return S, jnp.sum(S * q[..., None], axis=-2)
-
-
-#: the largest block :func:`_unit_lower_inverse` inverts by the product formula
-_INVERSE_BLOCK = 16
-
-
-def _unit_lower_inverse(L, mm):
-    """``(I + L)^-1`` of a strictly lower triangular ``L [..., n, n]``
-    (``mm``: the matmul). Diagonal blocks of at most 16 by the product
-    formula ``(I - L)(I + L^2)(I + L^4)...`` (exact: ``L^16 = 0``), all blocks in
-    one batched product; then pairs of neighbours merged, ``[[A, 0], [C,
-    B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]``, until one block is left. The
-    formula over the whole sub-chunk of 64 is exact too, but its powers reach
-    ``C(63, 32) |L|^32``: with keys of one head alike (cosine 0.8: a residual
-    stream's common part does that in the deeper layers) and beta near 1 its
-    float32 sum cancelled to nothing and a head's state came out 1e16 times
-    too large, which the head norm hid from the logits (PR 35: the pool's
-    reading of the check found it on the chip)."""
-    n = L.shape[-1]
-    m = 0
-    while (n >> m) > _INVERSE_BLOCK and (n >> m) % 2 == 0:
-        m += 1
-    b = n >> m
-    if b > _INVERSE_BLOCK:  # an odd size: zeros up to 16 x a power of two (its inverse: this one beside an identity)
-        pad = _INVERSE_BLOCK * (1 << math.ceil(math.log2(-(-n // _INVERSE_BLOCK)))) - n
-        padded = jnp.pad(L, ((0, 0),) * (L.ndim - 2) + ((0, pad), (0, pad)))
-        return _unit_lower_inverse(padded, mm)[..., :n, :n]
-    X = -jnp.stack([L[..., i * b : (i + 1) * b, i * b : (i + 1) * b] for i in range(1 << m)], axis=-3)
-    inv, power = jnp.eye(b, dtype=L.dtype) + X, X
-    for _ in range(max(0, math.ceil(math.log2(b)) - 1)):
-        power = mm("...ij,...jk->...ik", power, power)
-        inv = inv + mm("...ij,...jk->...ik", inv, power)
-    while inv.shape[-3] > 1:  # [..., blocks, b, b] -> [..., blocks / 2, 2 b, 2 b]
-        pairs = inv.shape[-3] // 2
-        A, B = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
-        C = jnp.stack(
-            [L[..., (2 * j + 1) * b : (2 * j + 2) * b, 2 * j * b : (2 * j + 1) * b] for j in range(pairs)],
-            axis=-3,
-        )
-        low = -mm("...ij,...jk->...ik", mm("...ij,...jk->...ik", B, C), A)
-        inv = jnp.concatenate([
-            jnp.concatenate([A, jnp.zeros_like(A)], axis=-1), jnp.concatenate([low, B], axis=-1),
-        ], axis=-2)
-        b *= 2
-    return inv[..., 0, :, :]
-
-
-def kda_chunked(S, q, k, v, g, beta, chunk: int):
-    """The chunked (WY) form of the same recurrence over ``T`` positions in
-    sub-chunks of ``chunk`` (the module's docstring has the equations): ``S
-    [B, H, dk, dv]`` float32 before the first position, ``q, k, g [B, T, H,
-    dk]``, ``v [B, T, H, dv]``, ``beta [B, T, H]`` float32, ``T`` a multiple
-    of ``chunk`` -> ``(S after the last position, o [B, T, H, dv])``. The
-    sub-chunks run in turn (the state passes from one to the next); inside
-    one, every position at once. Products at the matmul's highest
-    precision: the state is float32 and stays so."""
-    B, T, H, dk = q.shape
-    n = T // chunk
-
-    def split(a):  # [B, T, H, .] -> [n, B, H, chunk, .]
-        return jnp.moveaxis(a.reshape(B, n, chunk, H, -1), (1, 3), (0, 2))
-
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-
-    def mm(eq, a, b):
-        return jnp.einsum(eq, a, b, precision=_HIGHEST, preferred_element_type=F32)
-
-    def body(S, xs):
-        q, k, v, g, beta = xs
-        beta = beta[..., 0]                                    # [B, H, c]
-        G = jnp.cumsum(g, axis=-2)                             # [B, H, c, dk], <= 0
-        # A_ts = sum_c x_tc k_sc e^(G_tc - G_sc) for x = k and x = q, s <= t:
-        # ONE reduction over the channels for both (the decays are shared)
-        rows = jnp.concatenate([k, q], axis=-2)                # [B, H, 2c, dk]
-        diff = jnp.concatenate([G, G], axis=-2)[..., :, None, :] - G[..., None, :, :]
-        seen = jnp.concatenate([lower, lower], axis=0)[..., None]
-        decay = jnp.exp(jnp.where(seen, diff, -jnp.inf))
-        A = jnp.sum(rows[..., :, None, :] * decay * k[..., None, :, :], axis=-1)
-        A_kk, A_qk = A[..., :chunk, :], A[..., chunk:, :]
-        inv = _unit_lower_inverse(beta[..., :, None] * jnp.where(strict, A_kk, 0.0), mm)
-        Tb = inv * beta[..., None, :]
-        e_G = jnp.exp(G)
-        W = mm("...ts,...sv->...tv", Tb, v - mm("...sk,...kv->...sv", k * e_G, S))
-        o = mm("...tk,...kv->...tv", q * e_G, S) + mm("...ts,...sv->...tv", A_qk, W)
-        last = G[..., -1:, :]
-        S = jnp.swapaxes(jnp.exp(last), -1, -2) * S + mm(
-            "...sk,...sv->...kv", k * jnp.exp(last - G), W
-        )
-        return S, o
-
-    S, o = jax.lax.scan(body, S, tuple(split(a) for a in (q, k, v, g, beta[..., None])))
-    return S, jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, T, H, -1)
 
 
 def _kda_recur(cfg: KimiLinearConfig, S, q, k, v, g, beta):
@@ -594,37 +493,6 @@ def state_layout(cfg: KimiLinearConfig) -> StateLayout:
     )
 
 
-def _slot_state(state, layer: int, slot, fresh):
-    """A prefill chunk's view of the pool: one layer's state of ONE slot,
-    ``(S [1, H, dk, dv], tail [1, (K - 1) x 3 W])`` by a dynamic slice, zeros
-    where ``fresh`` (the sequence starts in this window: whatever the slot's
-    last holder left is not read)."""
-    out = []
-    for a in (state["kda_state"], state["kda_conv"]):
-        rows = jax.lax.dynamic_slice_in_dim(a[layer], slot, 1, axis=0)
-        out.append(jnp.where(fresh, jnp.zeros_like(rows), rows))
-    return out
-
-
-def _write_slot_state(state, layer: int, slot, S, tail):
-    """That slot's new state of one layer, in place in the donated pool."""
-    out = {}
-    for name, rows in (("kda_state", S), ("kda_conv", tail)):
-        a = state[name]
-        start = (jnp.int32(layer), slot) + (jnp.int32(0),) * (a.ndim - 2)
-        out[name] = jax.lax.dynamic_update_slice(a, rows[None].astype(a.dtype), start)
-    return out
-
-
-def _rows_of_slots(slots, real, n_slots: int):
-    """A decode batch seen from the pool: for each of the pool's ``n_slots``
-    slots the batch row that holds it, and whether a REAL row does (never
-    the null slot 0). By comparison, ``[n_slots, B]`` booleans: no scatter."""
-    hit = (slots[None, :] == jnp.arange(n_slots, dtype=slots.dtype)[:, None]) & real[None, :]
-    hit = hit & (jnp.arange(n_slots) > 0)[:, None]
-    return jnp.argmax(hit, axis=1), hit.any(axis=1)
-
-
 def _paged_layers(cfg: KimiLinearConfig, params, cache, state, tokens, pos, valid, block_tables, slots):
     """Every layer of the model over the two pools: the body of the serving
     steps. ``tokens [B, C]``, ``pos [B, C]`` (contiguous a slot), ``valid [B,
@@ -691,9 +559,9 @@ def _paged_layers(cfg: KimiLinearConfig, params, cache, state, tokens, pos, vali
             i_kda += 1
         elif kind == "kda":
             assert pos.shape[0] == 1, "a window of several positions is ONE request's prefill chunk"
-            S, tail = _slot_state(state, i_kda, slots[0], fresh[0])
+            S, tail = slot_state(state, ("kda_state", "kda_conv"), i_kda, slots[0], fresh[0])
             mix, S, tail = _kda_mix(cfg, p, h, S.astype(F32), tail.reshape(1, keep, -1), valid)
-            state = _write_slot_state(state, i_kda, slots[0], S, tail.reshape(1, -1))
+            state = write_slot_state(state, i_kda, slots[0], {"kda_state": S, "kda_conv": tail.reshape(1, -1)})
             i_kda += 1
         else:
             q_nope, q_shared, row = _mla_qkv(cfg, p, h)

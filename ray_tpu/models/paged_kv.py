@@ -143,7 +143,15 @@ def way(
     3072: 48 heads, 1024 queries 3.70 -> 0.46 / 0.63 / 0.98, 256 queries 1.00
     -> 0.29 / 0.31 / 0.43; 64 heads under the window, 1024 queries 5.11 -> 0.50
     / 0.74 / 0.74, 256 queries 1.28 -> 0.29 / 0.30 / 0.29; max|diff| / max|ref|
-    0.005-0.020 in bf16."""
+    0.005-0.020 in bf16.
+
+    THIRTY KV heads under one query head each (Olmo-Hybrid-7B: ``group`` 1, the
+    cache stored flat ``[.., bs * 30, 128]``; the decode kernel's numbers are
+    in ``ops/paged_attention.py::kernel_serves``) were compiled and RUN against
+    the materialised way on a v5e (PERF.md, PR 64), 4096 table keys, ms at a
+    context of 0 / 1024 / 2048: 1024 queries 1.35 -> 0.44 / 0.57 / 0.69, 256
+    queries 0.52 -> 0.34 / 0.39 / 0.42; max|diff| / max|ref| 0.004-0.011 in
+    bf16."""
     if paged_attn.kernel_serves(window, q_heads, k_cache, backend, n_kv=n_kv, head_dim=head_dim):
         return "kernel"
     keys = chunk_keys(keeps, window, table_keys, block_size(k_cache, n_kv, head_dim))

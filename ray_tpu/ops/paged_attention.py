@@ -167,6 +167,18 @@ def kernel_serves(
     -> 0.239 (48 -> 62%: two waves of 34); LFM2 (heads in lanes, 16 KB) 1.048 ->
     0.831 (55 -> 70%); Jamba2 (ONE KV head, 4 KB) 1.514 -> 1.156 (17 -> 23%:
     256 starts a wave at ≈ 11 ns each are what is left).
+    THIRTY KV heads under ONE query row each (Olmo-Hybrid-7B's attending
+    layers: plain multi-head attention at 128, 30 no multiple of 8, so the 5-d
+    cache is refused) go the flat way too, ``[n_layers, num_blocks, bs * 30,
+    hd]`` with ``n_kv`` 30 said beside it: a block of 16 is ``[480, 128]``, 30
+    whole bf16 tiles and 120 KB a DMA (the largest block of any configuration),
+    a wave 4 blocks = 1920 rows, the 30 query rows multiplied against every row
+    of it and 29 of 30 columns masked (the products of 32 rows over 8 KV heads,
+    a byte for a byte). Compiled AND run against the gather on a v5e (PERF.md
+    PR 64), one layer's call, 64 slots, a table of 4096 positions, contexts
+    log-normal about 810 (52,103 live tokens), 32 calls in one device loop:
+    1.122 ms against the gather's 26.6 (87% of the HBM roofline: 1.60 GB of K
+    and V), max|diff| / max|ref| 0.0057 in bf16, a padding slot zeros.
     Everything else (the CPU, a prefill chunk, odd widths) takes the gather. Decided at trace time; the
     model runner asks the same question to know what a launch reads."""
     backend = backend or jax.default_backend()

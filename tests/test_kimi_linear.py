@@ -564,7 +564,8 @@ def test_the_engine_serves_through_slots_and_tells_of_both_layouts(cfg, params, 
         assert [list(eng.tokens(r)) for r in rids] == wanted
         st = eng.stats()
         assert st["kv_layout"] == {"kind": "latent", "row_width": 24, "bytes_per_token": 2 * 24 * 4}
-        assert st["state_layout"] == kl.state_layout(cfg).describe()
+        assert st["state_layout"] == {**kl.state_layout(cfg).describe(),
+                                      "stored_bytes_per_seq": kl.state_layout(cfg).stored_bytes_per_seq}
         pool = st["state_pool"]
         assert pool["slots"] == 2 and pool["peak_in_use"] == 2 and pool["in_use"] == 0
         assert pool["assigned"] == pool["released"] == 5 and pool["admission_waits"] == 3

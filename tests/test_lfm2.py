@@ -388,6 +388,7 @@ def test_the_engine_serves_through_slots_and_tells_of_both_layouts(cfg, params, 
         assert [list(eng.tokens(r)) for r in rids] == wanted
         st = eng.stats()
         assert st["kv_layout"] == {"kind": "kv", "row_width": 2 * 2 * 64, "bytes_per_token": 2 * 2 * 2 * 64 * 4}
+        assert st["state_layout"].pop("stored_bytes_per_seq") >= lfm2.state_layout(cfg).bytes_per_seq  # the toy's lanes pad
         assert st["state_layout"] == lfm2.state_layout(cfg).describe() == {
             "kind": "short_conv", "layers": 5, "bytes_per_seq": 5 * 2 * 256 * 4}
         pool = st["state_pool"]

@@ -916,3 +916,60 @@ def test_the_selecting_chunks_kernel_compiles_for_the_chip_at_glm5_widths(one_ch
         assert compiled.out_info.shape == (H, C, dv)
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize("kernel", ["state_update", "decode_attention", "chunk_1024", "chunk_256"])
+def test_olmo_hybrid_s_kernels_compile_for_the_chip_at_the_published_widths(one_chip, kernel):
+    """Olmo-Hybrid-7B's three kernels at the benchmark's sizes (here for the
+    same reason as the ones above): the decode update of a Gated DeltaNet
+    layer's slab whose heads are JOINED along the lanes (``ops/kda.py``: 65
+    slots x 30 heads of 96 x 192 float32 as ``[96, 5760]``, ten heads a grid
+    step, the pool aliased in and out); the decode attention over 30 KV heads of
+    128 stored flat under ONE query row each (64 slots, a table of 4096, the
+    whole cache as it lies); the chunk's flash kernel over 30 heads. One Mosaic
+    call each; neither pool nor the scores is a temporary."""
+    from ray_tpu.ops import kda
+    from ray_tpu.ops import latent_flash as LF
+    from ray_tpu.ops import paged_attention as PA
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+        if kernel == "state_update":
+            n, slots, H, dk, dv = 12, 65, 30, 96, 192
+            pool = jax.ShapeDtypeStruct((n, slots, dk, H * dv), jnp.float32)
+            assert kda.kernel_serves(pool, "tpu", heads=H) and kda._joined_block(H, dk, dv) == 10
+            f32 = lambda *s: shape(s, jnp.float32)  # noqa: E731
+            compiled = jax.jit(
+                lambda pool, layer, q, k, v, g, beta, fresh: kda.update(pool, layer, q, k, v, g, beta, fresh, interpret=False),
+                donate_argnums=0,
+            ).lower(
+                f32(n, slots, dk, H * dv), shape((), jnp.int32), f32(slots, H, dk), f32(slots, H, dk), f32(slots, H, dv),
+                f32(slots, H), f32(slots, H), shape((slots,), jnp.bool_),
+            ).compile()
+            text, name, out = compiled.as_text(), "kda_update", (slots, H, dv)
+            assert compiled.memory_analysis().alias_size_in_bytes == n * slots * dk * H * dv * 4  # in place
+            assert compiled.out_info[1].shape == out
+        elif kernel == "decode_attention":
+            L, N, B, H, hd = 4, 5001, 64, 30, 128
+            cache = shape((L, N, 16 * H, hd))
+            assert PA.kernel_serves(1, H, cache, "tpu", n_kv=H, head_dim=hd)
+            compiled = jax.jit(
+                lambda q, k, v, tables, pos: PA.paged_attention(q, k, v, L - 1, tables, pos, interpret=False, n_kv=H)
+            ).lower(shape((B, 1, H, hd)), cache, cache, shape((B, 256), jnp.int32), shape((B, 1), jnp.int32)).compile()
+            text, name = compiled.as_text(), "paged_attn"
+            assert compiled.out_info.shape == (B, 1, H, hd)
+        else:
+            window, H, S, hd = int(kernel.split("_")[1]), 30, 4096, 128
+            assert LF.kernel_serves(window, S, hd, hd, 0, jnp.bfloat16, backend="tpu", kv_heads=H)
+            compiled = jax.jit(
+                lambda q, k, v, ctx, n: LF.flash_attention(q, k, v, ctx, n, scale=hd ** -0.5, group=1, interpret=False)
+            ).lower(shape((H, window, hd)), shape((H, S, hd)), shape((H, S, hd)), shape((), jnp.int32),
+                    shape((), jnp.int32)).compile()
+            text, name = compiled.as_text(), "latent_flash"
+            assert compiled.out_info.shape == (H, window, hd)
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 and name in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
