@@ -36,7 +36,9 @@ recurrence itself is the ONE of ``ops/delta_rule.py``: a decode step applies it
 once (``kda_update`` with the gate broadcast; over a decode batch on a TPU ONE
 pass over the layer's slab of the pool, ``ops/kda.py``), a prefill chunk runs
 the chunked (WY) form for a gate a head (``gdn_chunked``: the decays of a pair of
-positions are one number, so ``A^kk`` and ``A^qk`` are matmuls). The convolution
+positions are one number, so ``A^kk`` and ``A^qk`` are matmuls; on a TPU at widths
+of whole sublanes ONE kernel that keeps the state in VMEM over the sub-chunks,
+``ops/gdn_chunk.py``). The convolution
 is ``ops/short_conv.py``'s. The attention reads and writes the paged cache
 through ``models/paged_kv.py``, the way chosen at trace time from shapes and
 backend as for every K/V model: 30 KV heads of 128 under ONE query row each are
@@ -68,7 +70,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import paged_kv
 from ray_tpu.models.interface import AttentionPath, CacheLayout, Model, StateLayout, lm_head
-from ray_tpu.ops import delta_rule, kda, short_conv
+from ray_tpu.ops import delta_rule, gdn_chunk, kda, short_conv
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.moe import DENSE_AXES, gated_mlp
 from ray_tpu.parallel.sharding import constrain
@@ -268,6 +270,20 @@ def _heads(x, heads: int):
     return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
 
 
+def _once(value, window: int):
+    """``value`` of a prefill chunk (``window`` positions) made ONCE on a TPU, behind
+    an optimization barrier. The compiler's rematerialisation runs on every
+    program whose donated pools, counted as arguments AND as results, pass the
+    device's memory (this model's 6.7 GB of pools beside 8.2 GB of weights do),
+    can never get under that limit, and so recomputes what it can for each
+    consumer: in the parent's chunk of 1024 the product ``x W_qkv`` three and four
+    times a layer, the convolution behind it twice, a layer's write of K and V
+    twice: 24 of 110 ms (PERF.md, PR 65). What stands behind a barrier is not
+    recomputed. A decode step's program, and every program off a TPU, is what
+    it was."""
+    return jax.lax.optimization_barrier(value) if window > 1 and jax.default_backend() == "tpu" else value
+
+
 def _gdn_inputs(cfg: OlmoHybridConfig, p, x, tail, valid):
     """Everything a Gated DeltaNet layer's recurrence takes, from the layer's
     input ``x [B, C, D]``, the last ``conv_kernel - 1`` inputs of the
@@ -278,8 +294,8 @@ def _gdn_inputs(cfg: OlmoHybridConfig, p, x, tail, valid):
     in front (the next tail is cut from it)."""
     H, Wk = cfg.gdn_heads, cfg.key_width
     with jax.named_scope("gdn.conv"):
-        window = jnp.concatenate([tail, x @ p["gdn_wqkv"]], axis=1)
-        mixed = jax.nn.silu(short_conv.taps_over(window, p["gdn_conv"], x.shape[1]))
+        window = _once(jnp.concatenate([tail, x @ p["gdn_wqkv"]], axis=1), x.shape[1])
+        mixed = _once(jax.nn.silu(short_conv.taps_over(window, p["gdn_conv"], x.shape[1])), x.shape[1])
         q, k, v = (_heads(a, H) for a in jnp.split(mixed, (Wk, 2 * Wk), axis=-1))
         q, k = delta_rule._l2_norm(q) * cfg.gdn_key_dim ** -0.5, delta_rule._l2_norm(k)
     with jax.named_scope("gdn.gate"):
@@ -303,7 +319,8 @@ def _gdn_output(cfg: OlmoHybridConfig, p, x, o):
 def _gdn_recur(cfg: OlmoHybridConfig, S, q, k, v, g, beta):
     """The recurrence over a window from a state ``S [B, H, dk, dv]``: ``(S,
     o [B, C, H, dv])`` after it. One position a slot: the recurrence once;
-    more: the chunked form for a gate a head."""
+    more: the chunked form for a gate a head, through the kernel of
+    ``ops/gdn_chunk.py`` where it serves the shapes and the backend."""
     C = q.shape[1]
     if C == 1:
         with jax.named_scope("gdn.update"):
@@ -316,7 +333,8 @@ def _gdn_recur(cfg: OlmoHybridConfig, S, q, k, v, g, beta):
             q, k, v, g, beta = (
                 jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta)
             )
-        S, o = delta_rule.gdn_chunked(S, q, k, v, g, beta, chunk)
+        in_kernel = gdn_chunk.kernel_serves(S, q, v, chunk)
+        S, o = (gdn_chunk.chunked if in_kernel else delta_rule.gdn_chunked)(S, q, k, v, g, beta, chunk)
         return S, o[:, :C]
 
 
@@ -453,9 +471,24 @@ def _attention_mix(cfg: OlmoHybridConfig, p, cache, index: int, x, pos, valid, b
     blk, off = jnp.where(valid, paged_kv.block_at(block_tables, pos, bs), 0), pos % bs
     with jax.named_scope("attn.full"):
         q, k, v = _qkv(cfg, p, x)
-        cache = paged_kv.scatter_kv(cache, index, blk, off, k, v)
+        cache = _once(paged_kv.scatter_kv(cache, index, blk, off, k, v), x.shape[1])
         o = paged_kv.attention(q, cache["k"], cache["v"], index, block_tables, pos, valid, **_shapes(cfg))
         return cache, jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
+
+
+def _slot_rows(state, names, layer: int, slot, fresh):
+    """A prefill chunk's view of the state pool, as ``delta_rule.slot_state``:
+    one layer's rows of ONE slot in each array of ``names``, ``[1, *shape]``,
+    zeros where ``fresh``. By ONE dynamic slice of the pool an array: behind
+    ``a[layer]`` the slice of the slot is a copy of the layer's WHOLE slab first
+    (144 MB of a pool of 65 slots: 0.43 ms a layer on a v5e, and held beside a
+    program whose memory is full: PERF.md, PR 65)."""
+    out = []
+    for a in (state[name] for name in names):
+        start = (jnp.int32(layer), slot) + (jnp.int32(0),) * (a.ndim - 2)
+        rows = jax.lax.dynamic_slice(a, start, (1, 1, *a.shape[2:]))[0]
+        out.append(jnp.where(fresh, jnp.zeros_like(rows), rows))
+    return out
 
 
 def _paged_layers(cfg: OlmoHybridConfig, params, cache, state, tokens, pos, valid, block_tables, slots):
@@ -507,7 +540,7 @@ def _paged_layers(cfg: OlmoHybridConfig, params, cache, state, tokens, pos, vali
             i_gdn += 1
         elif kind == "linear_attention":
             assert pos.shape[0] == 1, "a window of several positions is ONE request's prefill chunk"
-            S, tail = delta_rule.slot_state(state, ("gdn_state", "gdn_conv"), i_gdn, slots[0], fresh[0])
+            S, tail = _slot_rows(state, ("gdn_state", "gdn_conv"), i_gdn, slots[0], fresh[0])
             mix, S, tail = _gdn_mix(cfg, p, x, kda.heads_apart(S, H), tail.reshape(1, keep, -1), valid)
             state = delta_rule.write_slot_state(
                 state, i_gdn, slots[0], {"gdn_state": kda.heads_joined(S), "gdn_conv": tail.reshape(1, -1)}
@@ -574,13 +607,20 @@ def _program_path(cfg: OlmoHybridConfig, window: int, cache, backend=None) -> tu
 def _attention_path(cfg: OlmoHybridConfig, window: int, cache, backend=None) -> AttentionPath:
     """The mixers' paths of a program of that window, named together: the
     Gated DeltaNet layers' (one position a slot: ``gdn.kernel`` where
-    ``ops/kda.py`` serves the pool, else ``gdn.update``; ``gdn.chunk``) and the
+    ``ops/kda.py`` serves the pool, else ``gdn.update``; a chunk: ``gdn.chunk_kernel``
+    where ``ops/gdn_chunk.py`` serves a slot's state, else ``gdn.chunk``) and the
     attending layers' (``models/paged_kv.py::way``, as ``kv.<way>``); what a
     launch reads of the paged cache is the latter's."""
-    (_, shape, dtype), _ = state_layout(cfg).arrays
-    pool = jax.ShapeDtypeStruct((cfg.n_gdn_layers, 1, *shape), dtype)  # any number of slots
-    in_kernel = kda.kernel_serves(pool, backend, heads=cfg.gdn_heads)
-    gdn = "gdn.chunk" if window > 1 else "gdn.kernel" if in_kernel else "gdn.update"
+    if window > 1:  # one slot's chunk, as ``_gdn_recur`` hands it over
+        H, chunk = cfg.gdn_heads, min(cfg.gdn_chunk, window)
+        f32 = lambda *width: jax.ShapeDtypeStruct((1, -(-window // chunk) * chunk, H, *width), F32)  # noqa: E731
+        S = jax.ShapeDtypeStruct((1, H, cfg.gdn_key_dim, cfg.gdn_value_dim), F32)
+        in_kernel = gdn_chunk.kernel_serves(S, f32(cfg.gdn_key_dim), f32(cfg.gdn_value_dim), chunk, backend)
+        gdn = "gdn.chunk_kernel" if in_kernel else "gdn.chunk"
+    else:
+        (_, shape, dtype), _ = state_layout(cfg).arrays
+        pool = jax.ShapeDtypeStruct((cfg.n_gdn_layers, 1, *shape), dtype)  # any number of slots
+        gdn = "gdn.kernel" if kda.kernel_serves(pool, backend, heads=cfg.gdn_heads) else "gdn.update"
     way, reads, _ = _program_path(cfg, window, cache, backend)
     return AttentionPath(f"{gdn}+kv.{way}", reads)
 

@@ -222,6 +222,19 @@ KIMI_V5E = {
     "tpu paged_prefill_step[256]": "6b8d7eb50777d13a", "tpu paged_prefill_step[1024]": "e6f9b35c0c04cee0",
     "tpu paged_decode_step[64x8192]": "b31c91bb31c538f3",
 }
+#: Olmo-Hybrid's warmed programs since PR 65: the DECODE rows are the parent's (PR 64's: the tool read them
+#: the same in both trees); the prefill rows are new with it (the chunk's recurrence through
+#: ``ops/gdn_chunk.py`` on a TPU at the published widths, a slot's rows by one dynamic slice everywhere, the
+#: chunk's large values behind barriers on a TPU)
+OLMO_TOY = {
+    "tpu paged_prefill_step[16]": "3b8a70a3b26645a4", "tpu paged_prefill_step[32]": "176eb545a5081cba",
+    "tpu paged_decode_step[4x128]": "cd13d270acb8deef", "cpu paged_prefill_step[16]": "27dbd0c65eb75feb",
+    "cpu paged_prefill_step[32]": "bb26233dd543dd62", "cpu paged_decode_step[4x128]": "24238c9a3d92dd0f",
+}
+OLMO_V5E = {
+    "tpu paged_prefill_step[256]": "82cb31cefe752c9a", "tpu paged_prefill_step[1024]": "966e1b72c2adc998",
+    "tpu paged_decode_step[64x4096]": "9efe54251bd40bc3",
+}
 #: ``ops/kda.py``'s call over Kimi-Linear's pool, 65 slots x 32 heads of 128 x 128, lowered for a TPU
 KDA_128 = "ce43cf9ae5c80c1f269c82f3463215a1cf4c2da2e8f6340bd8b8e6a385cb12fc"
 
@@ -237,13 +250,17 @@ def _tool():
     return repo, tool
 
 
-@pytest.mark.parametrize("toy, want", [(True, KIMI_TOY), (False, KIMI_V5E)], ids=["toy", "published_for_a_tpu"])
-def test_kimi_linear_s_warmed_programs_keep_the_lowered_text_they_had(toy, want):
+@pytest.mark.parametrize("config, toy, want", [
+    ("kimi-linear-48b-a3b-ep16", True, KIMI_TOY), ("kimi-linear-48b-a3b-ep16", False, KIMI_V5E),
+    ("olmo-hybrid-7b-16l", True, OLMO_TOY), ("olmo-hybrid-7b-16l", False, OLMO_V5E),
+], ids=["toy", "published_for_a_tpu", "olmo_hybrid.toy", "olmo_hybrid.published_for_a_tpu"])
+def test_the_delta_rule_models_warmed_programs_keep_the_lowered_text_they_had(config, toy, want):
     """The recurrence MOVED and the chunked form's tail became a function two
     forms share: the programs Kimi-Linear warms are, text for text, what they
-    were (the operations are traced in the order they were)."""
+    were (the operations are traced in the order they were). Beside a kernel
+    for Gated DeltaNet's chunk (PR 65) they still are, and so is Olmo-Hybrid's
+    decode program; its prefill programs are pinned as that PR left them."""
     repo, tool = _tool()
-    config = "kimi-linear-48b-a3b-ep16"
     rows = tool.config_hashes(repo, config, ("tpu", "cpu") if toy else ("tpu",), toy=toy)
     have = {key[len(config) + 1:]: digest[:16] for key, digest in rows.items()}
     assert {label: have[label] for label in want} == want

@@ -323,7 +323,7 @@ def test_thirty_kv_heads_under_one_query_row_each_take_the_kernels_stored_flat()
     assert path(cfg, 1, cache, backend="tpu") == ("gdn.kernel+kv.kernel", "blocks")
     assert path(cfg, 1, cache, backend="cpu") == ("gdn.update+kv.gather", "table")
     for window in (256, 1024):
-        assert path(cfg, window, cache, backend="tpu") == ("gdn.chunk+kv.flash", "live")
+        assert path(cfg, window, cache, backend="tpu") == ("gdn.chunk_kernel+kv.flash", "live")
         assert path(cfg, window, cache, backend="cpu") == ("gdn.chunk+kv.gather", "table")
     toy = oh.OlmoHybridConfig.tiny()
     toy_cache = jax.eval_shape(lambda: oh.cache_layout(toy, 8).init(8))

@@ -918,21 +918,24 @@ def test_the_selecting_chunks_kernel_compiles_for_the_chip_at_glm5_widths(one_ch
         jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-@pytest.mark.parametrize("kernel", ["state_update", "decode_attention", "chunk_1024", "chunk_256"])
+@pytest.mark.parametrize("kernel", ["state_update", "decode_attention", "chunk_1024", "chunk_256",
+                                    "recurrence_1024", "recurrence_256"])
 def test_olmo_hybrid_s_kernels_compile_for_the_chip_at_the_published_widths(one_chip, kernel):
-    """Olmo-Hybrid-7B's three kernels at the benchmark's sizes (here for the
+    """Olmo-Hybrid-7B's four kernels at the benchmark's sizes (here for the
     same reason as the ones above): the decode update of a Gated DeltaNet
     layer's slab whose heads are JOINED along the lanes (``ops/kda.py``: 65
     slots x 30 heads of 96 x 192 float32 as ``[96, 5760]``, ten heads a grid
     step, the pool aliased in and out); the decode attention over 30 KV heads of
     128 stored flat under ONE query row each (64 slots, a table of 4096, the
-    whole cache as it lies); the chunk's flash kernel over 30 heads. One Mosaic
-    call each; neither pool nor the scores is a temporary."""
-    from ray_tpu.ops import kda
+    whole cache as it lies); the chunk's flash kernel over 30 heads; the chunk's
+    Gated DeltaNet recurrence (``ops/gdn_chunk.py``: one slot's 30 heads of 96 x
+    192 over 16 or 4 sub-chunks of 64, the operands laid heads first around
+    it). One Mosaic call each; neither pool nor the scores is a temporary."""
+    from ray_tpu.ops import gdn_chunk, kda
     from ray_tpu.ops import latent_flash as LF
     from ray_tpu.ops import paged_attention as PA
 
-    cache_was = jax.config.jax_enable_compilation_cache
+    cache_was, temporaries = jax.config.jax_enable_compilation_cache, 2 * 2**20
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
@@ -960,6 +963,16 @@ def test_olmo_hybrid_s_kernels_compile_for_the_chip_at_the_published_widths(one_
             ).lower(shape((B, 1, H, hd)), cache, cache, shape((B, 256), jnp.int32), shape((B, 1), jnp.int32)).compile()
             text, name = compiled.as_text(), "paged_attn"
             assert compiled.out_info.shape == (B, 1, H, hd)
+        elif kernel.startswith("recurrence"):
+            T, H, dk, dv = int(kernel.split("_")[1]), 30, 96, 192
+            f32 = lambda *s: shape(s, jnp.float32)  # noqa: E731
+            operands = f32(1, H, dk, dv), f32(1, T, H, dk), f32(1, T, H, dk), f32(1, T, H, dv), f32(1, T, H), f32(1, T, H)
+            assert gdn_chunk.kernel_serves(operands[0], operands[1], operands[3], 64, "tpu")
+            compiled = jax.jit(lambda *a: gdn_chunk.chunked(*a, 64, interpret=False)).lower(*operands).compile()
+            text, name = compiled.as_text(), "gdn_chunk"
+            assert [o.shape for o in compiled.out_info] == [(1, H, dk, dv), (1, T, H, dv)]
+            # the operands heads first and the output back: five arrays of the chunk's size, nothing of a sub-chunk's
+            temporaries = 5 * T * H * 256 * 4
         else:
             window, H, S, hd = int(kernel.split("_")[1]), 30, 4096, 128
             assert LF.kernel_serves(window, S, hd, hd, 0, jnp.bfloat16, backend="tpu", kv_heads=H)
@@ -970,6 +983,6 @@ def test_olmo_hybrid_s_kernels_compile_for_the_chip_at_the_published_widths(one_
             text, name = compiled.as_text(), "latent_flash"
             assert compiled.out_info.shape == (H, window, hd)
         assert text.count('custom_call_target="tpu_custom_call"') == 1 and name in text
-        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
+        assert compiled.memory_analysis().temp_size_in_bytes < temporaries
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
