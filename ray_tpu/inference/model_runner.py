@@ -82,6 +82,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ray_tpu.models import paged_kv
 from ray_tpu.models.interface import (
     AttentionPath,
     copy_paged_blocks,
@@ -350,6 +351,10 @@ class PagedModelRunner:
         self.prefill_width: Dict[str, int] = dict.fromkeys(
             ("launches", "width_tokens", "live_tokens", "read_tokens", "expanded_tokens"), 0
         )
+        if self.cache_layout.kind == "kv":
+            #: a K/V cache: the scatter updates the launches' K/V writes issue
+            #: (:meth:`_chunk_write_updates` a launch)
+            self.prefill_width["written_updates"] = 0
         #: a model whose attention SELECTS (``Model.selection`` positions a
         #: query at most): running sums over every launch's real query
         #: positions, from the host's own lengths: the queries, those whose
@@ -676,6 +681,8 @@ class PagedModelRunner:
         pw["live_tokens"] += live
         pw["read_tokens"] += read
         pw["expanded_tokens"] += next((rung for rung in rungs if rung >= end), width)
+        if "written_updates" in pw:
+            pw["written_updates"] += self._chunk_write_updates(bucket)
         self._count_selection(ctx_len, end)
         with clock.phase(
             "launch", program="paged_prefill_step", bucket=bucket, path=self._path_name(bucket),
@@ -791,6 +798,17 @@ class PagedModelRunner:
         sa["queries_past_topk"] += end - full
         sa["live"] += live
         sa["chosen"] += (full * (full + 1) - first * (first + 1)) // 2 + (end - full) * K
+
+    def _chunk_write_updates(self, bucket: int) -> int:
+        """The scatter updates the K/V write of ONE prefill launch of ``bucket``
+        rows issues: ``paged_kv.write_updates`` an array, over K and V of every
+        layer of every group."""
+        return sum(
+            layers * paged_kv.write_updates(
+                1, bucket, self.cache[self.cache_layout.array_name(name, g)].shape[2:], self.block_size
+            )
+            for g, (layers, _) in enumerate(self._groups) for name, _ in self.cache_layout.arrays
+        )
 
     def _by_layers(self, per_group) -> float:
         """The mean over the cache's layers of a number a layer group (an int

@@ -918,8 +918,10 @@ def _group_table(block_tables, group: int):
 def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
     """Every block of the model over a paged cache: the body of the three
     serving steps. ``x [B, C, D]`` embedded tokens, ``pos [B, C]`` their
-    global positions, ``valid [B, C]`` bool (padding rows write K/V to the
-    null block and reach no expert), ``block_tables`` one table a layer group
+    global positions, ``valid [B, C]`` bool (a padding row's K/V goes to the
+    null block, or, in a chunk written by whole blocks, nowhere: its position
+    keeps what it held (``paged_kv.write_kv``); it reaches no expert),
+    ``block_tables`` one table a layer group
     of the cache (:func:`cache_layout`, :func:`_group_table`). Per layer:
     norm, q/k/v, rope at ``pos``, K/V written to the cache, attention over
     the cache (``models/paged_kv.py::attention``, after the write so a window
@@ -929,7 +931,8 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
 
     Layer ``l`` writes and reads its GROUP's arrays through its group's
     table, with its kind's rope table and mask. What is per group is made
-    once a group (the block a position is written to), what is per kind once
+    once a group (the block a position is written to, where the window is
+    written by rows), what is per kind once
     a kind (the rope table). A configuration without layer kinds has one
     group, every layer in it.
 
@@ -941,8 +944,10 @@ def _paged_layers(cfg: LlamaConfig, params, cache, x, pos, valid, block_tables):
     layout = cache_layout(cfg, _block_size(cfg, cache))
     bs = layout.block_size
     tables = [_group_table(block_tables, g) for g in range(len(layout.groups))]
-    blks = [jnp.where(valid, paged_kv.block_at(table, pos, bs), 0) for table in tables]
-    off = pos % bs
+    # the rows way's addresses, once a group (a chunk of whole blocks is written by blocks: paged_kv.write_kv)
+    by_rows = paged_kv.write_way(*pos.shape, bs) == "rows"
+    blks = [jnp.where(valid, paged_kv.block_at(table, pos, bs), 0) if by_rows else None for table in tables]
+    off = pos % bs if by_rows else None
     # a kind's rope table, in the groups' order (kinds may share a group: its window is what a group is)
     ropes = {
         kind: _rope_at(cfg, pos, kind)
@@ -969,7 +974,8 @@ def _paged_attention_block(
     the layer their index ``index``)
     through its group's table ``block_table [B, M]``: the norm, q / k / v, the
     kind's rope table ``rope`` (cos, sin at ``pos``, as wide as the kind
-    rotates), the write of the step's K and V at ``(blk, off)``, the attention
+    rotates), the write of the step's K and V (``paged_kv.write_kv``: at
+    ``(blk, off)`` by rows, through the table by blocks), the attention
     over the cache, the head gate where the configuration has one, and ``wo``;
     the layer's query heads are its ``wq``'s.
     Returns ``(cache, x + attention)``."""
@@ -979,7 +985,7 @@ def _paged_attention_block(
         q, k, v = _qkv(cfg, p, h)
         q = _apply_rope_flat(q, cos, sin)
         k = _apply_rope_flat(k, cos, sin)
-        cache = paged_kv.scatter_kv(cache, index, blk, off, k, v, names)
+        cache = paged_kv.write_kv(cache, index, block_table, pos, valid, k, v, names, at=(blk, off))
         o = paged_kv.attention(
             q, cache[names[0]], cache[names[1]], index, block_table, pos, valid,
             n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim, keeps=window,

@@ -464,14 +464,15 @@ def _attention_mix(cfg: OlmoHybridConfig, p, cache, index: int, x, pos, valid, b
     """The attention mixer of one layer (index ``index`` of the attending
     ones) on its input ``x [B, C, D]`` at positions ``pos`` (which place the
     rows in the cache and bound what a query sees, and enter nothing else): q /
-    k / v, the write of the window's K and V where ``valid`` (a padding row's to
-    the null block), the attention over the cache (after the write: a window
-    attends to itself) and ``wo``. Returns ``(cache, out [B, C, D])``."""
+    k / v, the write of the window's K and V where ``valid``
+    (``paged_kv.write_kv``: a padding row's to the null block, or, in a chunk
+    written by whole blocks, nowhere), the attention over the cache (after the
+    write: a window attends to itself) and ``wo``. Returns ``(cache, out [B, C, D])``."""
     bs = paged_kv.block_size(cache["k"], **_shapes(cfg))
-    blk, off = jnp.where(valid, paged_kv.block_at(block_tables, pos, bs), 0), pos % bs
+    at = paged_kv.rows_at(block_tables, pos, valid, bs)
     with jax.named_scope("attn.full"):
         q, k, v = _qkv(cfg, p, x)
-        cache = _once(paged_kv.scatter_kv(cache, index, blk, off, k, v), x.shape[1])
+        cache = _once(paged_kv.write_kv(cache, index, block_tables, pos, valid, k, v, at=at), x.shape[1])
         o = paged_kv.attention(q, cache["k"], cache["v"], index, block_tables, pos, valid, **_shapes(cfg))
         return cache, jnp.einsum("bchk,hkd->bcd", o.astype(x.dtype), p["wo"])
 

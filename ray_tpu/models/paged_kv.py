@@ -1,5 +1,5 @@
-"""A K and a V row a token in a paged cache: where a block lies, the write of
-a step's rows, and the ONE place a serving step attends over that cache. The
+"""A K and a V row a token in a paged cache: where a block lies, the ONE place a
+serving step writes its rows, and the ONE place it attends over that cache. The
 sibling of ``models/latent.py`` (the same for a latent row a token).
 
 Every function takes SHAPES, and the few numbers a cache stored flat does not
@@ -14,8 +14,16 @@ or, where that would pad (``CacheLayout.flat_blocks``), a block's rows laid
 flat: few KV heads of whole lanes joined to the tokens, ``[.., block_size *
 n_kv, head_dim]`` (``n_kv`` 4, 1), narrow heads joined in one row of whole
 lanes, ``[.., block_size, n_kv * head_dim]`` (``head_dim`` 64). Block id 0 is
-the NULL block: never allocated, a padding position writes into it and a
-masked read of it never reaches the softmax.
+the NULL block: never allocated, a masked read of it never reaches the
+softmax, and it is the sink of every write that has no live block.
+
+A step's rows are written two WAYS (:func:`write_way`, from ``B``, ``C`` and
+the block's size; :func:`write_kv` runs it): a row a token (decode, a verify
+window of several slots, a chunk that is no whole number of blocks: a padding
+row goes to the null block), or, ONE sequence's chunk of whole blocks, the
+blocks its span touches read, overlaid and written back whole (a padding row
+keeps what its position held; what lies past the sequence's blocks is the null
+block).
 
 A window of queries attends three WAYS (:func:`way`, from the shapes and the
 backend at trace time; :func:`attention` runs it, :func:`program_path` says
@@ -70,13 +78,66 @@ def block_at(block_tables, pos, bs: int):
     return jnp.take_along_axis(block_tables, jnp.minimum(pos // bs, M - 1), axis=1)
 
 
+def write_way(batch: int, window: int, bs: int) -> str:
+    """``"blocks"`` | ``"rows"``: how :func:`write_kv` lays ``batch`` windows
+    of ``window`` rows into blocks of ``bs`` positions. Whole blocks for ONE
+    sequence's window of a whole number of blocks (a prefill chunk), a row a
+    token for every other (decode, a verify window of several slots, a toy
+    chunk that ends inside a block). SHAPES decide, on every backend."""
+    return "blocks" if batch == 1 and window % bs == 0 else "rows"
+
+
+def write_updates(batch: int, window: int, block, bs: int) -> int:
+    """Scatter updates ONE array's write of a step's rows issues, blocks
+    stored as ``block`` (a cache array's ``shape[2:]``) of ``bs`` positions:
+    by blocks the ``window / bs + 1`` blocks the window can touch, by rows a
+    token's rows of its block (``n_kv`` in a cache of few heads stored flat,
+    else one) a row. What ``prefill_width.written_updates`` sums a launch,
+    over K and V of every attending layer."""
+    if write_way(batch, window, bs) == "blocks":
+        return window // bs + 1
+    return batch * window * (block[0] // bs)
+
+
+def rows_at(block_tables, pos, valid, bs: int):
+    """Where the rows way writes a window's rows: ``(blk, off)``, each ``[B,
+    C]``, the block id (the null block where not ``valid``) and the position
+    in it; ``None`` where the window is written by blocks. A caller that
+    writes several layers through one table makes it once."""
+    if write_way(*pos.shape, bs) == "blocks":
+        return None
+    return jnp.where(valid, block_at(block_tables, pos, bs), 0), pos % bs
+
+
+def write_kv(cache, layer: int, block_tables, pos, valid, k, v, names=("k", "v"), at=None):
+    """Write a window's K and V, ``k`` / ``v [B, C, n_kv, hd]`` at positions
+    ``pos [B, C]`` (contiguous a slot) of the slots of ``block_tables [B,
+    M]``, into layer ``layer`` of the arrays ``names`` (the layer's group's) of
+    ``cache``, the rows that are not ``valid [B, C]`` left out. Heads in lanes:
+    ``k`` and ``v`` come as ONE head as wide as the row. ``at``: what
+    :func:`rows_at` gave for this table, where the caller made it already.
+
+    The ONE place a serving step writes a K/V cache, the way
+    :func:`write_way` chooses from ``B``, ``C`` and the block's size:
+    :func:`scatter_kv` by rows, :func:`write_blocks` by blocks. After either
+    the cache is the same bit for bit everywhere but the null block
+    (``tests/test_kv_block_write.py``)."""
+    block = cache[names[0]].shape[2:]
+    bs = math.prod(block) // math.prod(k.shape[2:])
+    if write_way(*pos.shape, bs) == "blocks":
+        return write_blocks(cache, layer, block_tables[0], pos[0, 0], valid[0], k[0], v[0], names)
+    blk, off = at if at is not None else rows_at(block_tables, pos, valid, bs)
+    return scatter_kv(cache, layer, blk, off, k, v, names)
+
+
 def scatter_kv(cache, layer: int, blk, off, k, v, names=("k", "v")):
-    """Write per-token K/V into their cache slots. blk/off: [...] int32,
+    """The rows way of :func:`write_kv`: per-token K/V into their cache slots,
+    an update a token. blk/off: [...] int32 (:func:`rows_at`),
     k/v: [..., n_kv, hd]. Padding rows target the null block — colliding
     trash writes are fine, nothing masked-in ever reads them. ``names``: the
     layer's group's arrays. A cache stored flat takes a token's heads at the
-    rows ``off * n_kv ..`` of its block (heads in lanes: ``k`` and ``v`` come
-    as ONE head as wide as the row)."""
+    rows ``off * n_kv ..`` of its block, an update a token a head (heads in
+    lanes: ``k`` and ``v`` come as ONE head as wide as the row)."""
     k_name, v_name = names
     if cache[k_name].ndim == 4:
         n_kv = k.shape[-2]
@@ -87,6 +148,51 @@ def scatter_kv(cache, layer: int, blk, off, k, v, names=("k", "v")):
         k_name: cache[k_name].at[layer, blk, off].set(k),
         v_name: cache[v_name].at[layer, blk, off].set(v),
     }
+
+
+def write_blocks(cache, layer: int, table, start, valid, k, v, names=("k", "v")):
+    """The blocks way of :func:`write_kv` (``models/latent.py::write_blocks``'
+    form): ONE sequence's window ``k`` / ``v [C, n_kv, hd]``, ``C`` a whole
+    number of blocks, at the positions ``start ..`` of the sequence of ``table
+    [M]``. The ``C / bs + 1`` blocks the table names from ``start // bs`` on
+    (the null block behind its end) are gathered, the window's rows laid over
+    them from ``start % bs`` on, the span's OLD rows kept wherever the
+    window's row is not ``valid [C]``, and the span written back as ONE
+    scatter of whole blocks into the array seen as ``[layers x blocks,
+    *block]``, in place in the donated argument. A block is contiguous in all
+    three stored forms, so one body serves them: a token is ``block[0] / bs``
+    leading rows of its block.
+
+    Read-modify-write and not an aligned overwrite: a chunk starts wherever
+    the cached context ends, and a full prefix hit prefills ONE token at
+    ``len - 1`` into a copied block whose earlier rows are the prefix
+    (``inference/kv_cache.py::acquire_prefix``). A block of the span past the
+    window's last valid row is rewritten with what it held; the null block
+    takes colliding writes of its own old rows and of whatever lies past the
+    sequence's blocks: nothing masked-in ever reads them.
+
+    Why: a row a token a head is a sequential update, on a v5e 74 ns each
+    whatever its width. One call, K and V of a layer, a chunk of 1024, rows ->
+    blocks, ms on a v5e (PERF.md, PR 66): a flat cache of 30 heads ``[.., 480,
+    128]`` 4.54 -> 0.16, of 4 heads ``[.., 64, 128]`` 0.61 -> 0.04, of one
+    ``[.., 16, 128]`` 0.16 -> 0.03; ``[.., 16, 8, 128]`` 0.14 -> 0.05; heads in
+    lanes ``[.., 16, 512]`` 0.21 -> 0.04; a chunk of 256 gains in each too
+    (0.044-1.13 -> 0.015-0.054), so no shape is kept back."""
+    (_, N, *block), C = cache[names[0]].shape, k.shape[0]
+    bs = math.prod(block) // math.prod(k.shape[1:])
+    nblk, per = C // bs + 1, block[0] // bs  # the span's blocks; a token's rows of its block
+    ids = layer * N + jax.lax.dynamic_slice(jnp.pad(table, (0, nblk)), (start // bs,), (nblk,))
+    first = (start % bs * per, *(0,) * (len(block) - 1))
+    keep = jnp.repeat(valid, per).reshape(-1, *(1,) * (len(block) - 1))
+    out = {}
+    for name, new in zip(names, (k, v)):
+        flat = cache[name].reshape(-1, *block)
+        span = flat[ids].reshape(nblk * block[0], *block[1:])
+        new = new.reshape(C * per, *block[1:]).astype(span.dtype)
+        new = jnp.where(keep, new, jax.lax.dynamic_slice(span, first, new.shape))
+        span = jax.lax.dynamic_update_slice(span, new, first)
+        out[name] = flat.at[ids].set(span.reshape(nblk, *block)).reshape(cache[name].shape)
+    return {**cache, **out}
 
 
 def chunk_keys(keeps: int, chunk: int, table_keys: int, bs: int) -> int:

@@ -234,7 +234,9 @@ def test_the_runner_counts_whole_key_tiles_for_a_plain_configuration(shape, requ
     assert table.attention_paths[CHUNK] == ("gather", "table")
     # (``expanded_tokens``, PR 53: what the program GATHERS in front of its attention; this model's
     # chunk gathers the table whole on both paths, ROADMAP S3 iv)
-    account = {"launches": 4, "width_tokens": 4 * KEYS, "live_tokens": sum(LIVE), "expanded_tokens": 4 * KEYS}
+    # (``written_updates``, PR 66: a chunk of whole blocks is written by blocks, K and V of every layer, on both paths)
+    account = {"launches": 4, "width_tokens": 4 * KEYS, "live_tokens": sum(LIVE), "expanded_tokens": 4 * KEYS,
+               "written_updates": 4 * 2 * cfg.n_layers * (CHUNK // BS + 1)}
     assert table.prefill_width == {**account, "read_tokens": 4 * KEYS}
 
     calls = request.getfixturevalue("as_on_a_tpu")

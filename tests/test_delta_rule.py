@@ -225,14 +225,15 @@ KIMI_V5E = {
 #: Olmo-Hybrid's warmed programs since PR 65: the DECODE rows are the parent's (PR 64's: the tool read them
 #: the same in both trees); the prefill rows are new with it (the chunk's recurrence through
 #: ``ops/gdn_chunk.py`` on a TPU at the published widths, a slot's rows by one dynamic slice everywhere, the
-#: chunk's large values behind barriers on a TPU)
+#: chunk's large values behind barriers on a TPU), and since PR 66 a chunk's K and V reach the cache as whole
+#: blocks (``paged_kv.write_blocks``), which changed the six prefill rows and no decode row
 OLMO_TOY = {
-    "tpu paged_prefill_step[16]": "3b8a70a3b26645a4", "tpu paged_prefill_step[32]": "176eb545a5081cba",
-    "tpu paged_decode_step[4x128]": "cd13d270acb8deef", "cpu paged_prefill_step[16]": "27dbd0c65eb75feb",
-    "cpu paged_prefill_step[32]": "bb26233dd543dd62", "cpu paged_decode_step[4x128]": "24238c9a3d92dd0f",
+    "tpu paged_prefill_step[16]": "45fc650983d300e0", "tpu paged_prefill_step[32]": "6e823f9ca62c040e",
+    "tpu paged_decode_step[4x128]": "cd13d270acb8deef", "cpu paged_prefill_step[16]": "c498c5e47d3ffb50",
+    "cpu paged_prefill_step[32]": "ef0bfcacf0de8c6a", "cpu paged_decode_step[4x128]": "24238c9a3d92dd0f",
 }
 OLMO_V5E = {
-    "tpu paged_prefill_step[256]": "82cb31cefe752c9a", "tpu paged_prefill_step[1024]": "966e1b72c2adc998",
+    "tpu paged_prefill_step[256]": "fc57a92a459ca8bd", "tpu paged_prefill_step[1024]": "9a5e5c69f66477fa",
     "tpu paged_decode_step[64x4096]": "9efe54251bd40bc3",
 }
 #: ``ops/kda.py``'s call over Kimi-Linear's pool, 65 slots x 32 heads of 128 x 128, lowered for a TPU
@@ -259,7 +260,8 @@ def test_the_delta_rule_models_warmed_programs_keep_the_lowered_text_they_had(co
     forms share: the programs Kimi-Linear warms are, text for text, what they
     were (the operations are traced in the order they were). Beside a kernel
     for Gated DeltaNet's chunk (PR 65) they still are, and so is Olmo-Hybrid's
-    decode program; its prefill programs are pinned as that PR left them."""
+    decode program; its prefill programs are pinned as PR 66 left them (the
+    chunk's K/V written by blocks)."""
     repo, tool = _tool()
     rows = tool.config_hashes(repo, config, ("tpu", "cpu") if toy else ("tpu",), toy=toy)
     have = {key[len(config) + 1:]: digest[:16] for key, digest in rows.items()}

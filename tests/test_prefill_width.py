@@ -154,7 +154,9 @@ def test_prefill_width_counts_every_model():
     runner = _runner(llama, model_of(llama).init_params(llama, jax.random.PRNGKey(0)))
     _prefill(runner)
     assert runner.attention_paths[CHUNK].reads == "table"
-    assert runner.prefill_width == _expected(len(LIVE) * MAX_SEQ)
+    # ... and a K/V cache counts the updates of its chunks' writes: 32 / 8 + 1 whole blocks, K and V of every layer (PR 66)
+    written = len(LIVE) * 2 * llama.n_layers * (CHUNK // BS + 1)
+    assert runner.prefill_width == {**_expected(len(LIVE) * MAX_SEQ), "written_updates": written}
     assert model_of(llama).key_tile(llama, CHUNK, runner.cache) == 1
     assert model_of(llama).gather_rungs(llama, CHUNK, runner.cache) == ()
 
